@@ -66,17 +66,11 @@ def run(scale: int = SCALE, edgefactor: int = EDGEFACTOR,
         out_path = os.path.join(tempfile.gettempdir(), "obs_smoke.jsonl")
     obs.enable(jsonl_path=out_path, device_sync=True)
 
-    # persistent compile cache into a scratch dir so cache hit/miss
-    # events fire without touching the repo's .jax_cache — reusing the
-    # process's already-committed dir when there is one (the cache dir
-    # is process-global and idempotence-guarded; a second run() in the
-    # same process must not look like a retarget)
-    from combblas_tpu.utils.compile_cache import configured_dir
-
-    enable_compile_cache(
-        cache_dir or configured_dir()
-        or tempfile.mkdtemp(prefix="obs_smoke_cache_")
-    )
+    # persistent compile cache so cache hit/miss events fire: the dir
+    # the caller names, else the one the process already committed to
+    # (or the fixed default / JAX_COMPILATION_CACHE_DIR placement) —
+    # never a fresh temporary name, which could never hit
+    enable_compile_cache(cache_dir)
 
     with obs.span("obs_smoke", scale=scale, edgefactor=edgefactor):
         # compile-cache probe: compile, drop the in-process executable,

@@ -134,6 +134,8 @@ sys.path.insert(
     0, os.path.join(os.path.dirname(os.path.abspath(__file__)), "..")
 )
 
+from combblas_tpu.utils import device_fields  # noqa: E402
+
 SCALE = int(os.environ.get("BENCH_SERVE_SCALE", "9"))
 EDGEFACTOR = int(os.environ.get("BENCH_SERVE_EDGEFACTOR", "8"))
 WIDTH = int(os.environ.get("BENCH_SERVE_WIDTH", "16"))
@@ -1751,6 +1753,7 @@ def _emit_pool_summary(out: dict) -> int:
         "warning": out.get("warning"),
         "rc": rc,
         "per_tenant": out.get("per_tenant"),
+        **device_fields(),
     }
     if out.get("wire") is not None:
         # shard scenario: per-hop wire-bytes + hop-latency breakdown
@@ -1775,7 +1778,7 @@ def main():
 
         sys.exit(loadgen.main())
     if os.environ.get("BENCH_SERVE_POOL") == "1":
-        out = run_pool()
+        out = {**run_pool(), **device_fields()}
         print(json.dumps(out), flush=True)
         if os.environ.get("BENCH_EMIT_SUMMARY", "1") != "0":
             # STANDALONE contract: compact summary as the final line +
@@ -1788,7 +1791,9 @@ def main():
             sys.exit(_emit_pool_summary(out))
         return
     if os.environ.get("BENCH_SERVE_SHARD") == "1":
-        out = run_shard()
+        # device fields are read AFTER the run: the slices are gone, so
+        # the router touching its own backend takes nothing from them
+        out = {**run_shard(), **device_fields()}
         print(json.dumps(out), flush=True)
         if os.environ.get("BENCH_EMIT_SUMMARY", "1") != "0":
             # standalone contract (see the pool branch): summary line
@@ -1806,7 +1811,7 @@ def main():
             out = run_recovery()
     else:
         out = run()
-    print(json.dumps(out), flush=True)
+    print(json.dumps({**out, **device_fields()}), flush=True)
 
 
 if __name__ == "__main__":

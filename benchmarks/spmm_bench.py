@@ -301,13 +301,16 @@ def run_serve():
 
 
 def main():
-    out = {"metric": "spmm_khop_speedup", "unit": "x"}
+    from combblas_tpu.utils import device_fields
+
+    dev = device_fields()
+    out = {"metric": "spmm_khop_speedup", "unit": "x", **dev}
     golden = run_golden()
-    print(json.dumps({"phase": "golden", **golden}), flush=True)
+    print(json.dumps({"phase": "golden", **golden, **dev}), flush=True)
     perf = run_perf()
-    print(json.dumps({"phase": "perf", **perf}), flush=True)
+    print(json.dumps({"phase": "perf", **perf, **dev}), flush=True)
     serve = run_serve()
-    print(json.dumps({"phase": "serve", **serve}), flush=True)
+    print(json.dumps({"phase": "serve", **serve, **dev}), flush=True)
     out.update(
         value=perf["speedup"],
         golden=golden, perf=perf, serve=serve,

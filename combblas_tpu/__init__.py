@@ -18,8 +18,6 @@ Layer map (mirrors SURVEY.md §1, re-designed for JAX/XLA):
                    profiling timers, checkpointing.
 """
 
-from . import _compat  # jax.shard_map adapter for older runtimes (first!)
-
 from .semiring import (
     MAX_MIN,
     MIN_PLUS,

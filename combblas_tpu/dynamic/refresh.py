@@ -42,7 +42,6 @@ REFRESH_KINDS = ("bfs", "cc", "pagerank")
 
 #: Sentinel for unreached vertices in refresh("bfs") level vectors.
 UNREACHED = np.int32(-1)
-_INF = jnp.float32(jnp.inf)
 
 
 # -- BFS level repair --------------------------------------------------------

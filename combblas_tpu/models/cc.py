@@ -29,6 +29,7 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..semiring import SELECT2ND_MIN
 from ..parallel.spmat import SpParMat
@@ -100,7 +101,7 @@ def _connected_components_impl(A: SpParMat):
     return fb, niter
 
 
-_STAR, _NONSTAR, _CONVERGED = jnp.int32(1), jnp.int32(0), jnp.int32(2)
+_STAR, _NONSTAR, _CONVERGED = np.int32(1), np.int32(0), np.int32(2)
 
 
 def lacc(A: SpParMat) -> tuple[DistVec, jax.Array]:

@@ -11,7 +11,7 @@ VPU adds/mins over an accumulator — giving dense-block tropical products
 for APSP-style repeated squaring and dense subproblems of semiring SpGEMM.
 
 ``plus_times`` is included for completeness (it lowers to the MXU via
-jnp.dot inside the kernel). Use ``interpret=True`` on CPU (tests).
+jnp.dot inside the kernel). The CPU tests pass ``interpret``.
 """
 
 from __future__ import annotations
@@ -21,6 +21,10 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+#: k's folded per fori step of the tropical kernels (the lane width:
+#: dynamic slices of the A block must stay 128-aligned).
+_KCHUNK = 128
 
 _FOLDS = {
     "min_plus": (jnp.minimum, jnp.add, jnp.inf),
@@ -41,24 +45,24 @@ def _semiring_mm_kernel(a_ref, b_ref, o_ref, *, add, mul, zero, bk: int):
         )
         return
 
-    # Chunked static-slice contraction: each step broadcasts a [bm, CH, 1] x
-    # [1, CH, bn] semiring product and folds the CH axis — static shapes
-    # only (Mosaic rejects the dynamic-slice fori formulation), VMEM held to
-    # bm*CH*bn floats per step.
-    CH = 8
-    acc = o_ref[...]
-    for kk0 in range(0, bk, CH):
-        a_blk = a_ref[:, kk0 : kk0 + CH]  # [bm, CH]
-        b_blk = b_ref[kk0 : kk0 + CH, :]  # [CH, bn]
-        prods = mul(a_blk[:, :, None], b_blk[None, :, :])  # [bm, CH, bn]
-        if add is jnp.minimum:
-            step = jnp.min(prods, axis=1)
-        elif add is jnp.maximum:
-            step = jnp.max(prods, axis=1)
-        else:
-            step = jnp.sum(prods, axis=1)
-        acc = add(acc, step)
-    o_ref[...] = acc
+    # Rank-1 contraction, 128 k's at a time: for each k the column
+    # a[:, k] (lane-broadcast) meets the row b[k, :] (sublane-broadcast)
+    # and folds into the [bm, bn] accumulator, so the only live
+    # temporaries are [bm, bn]-sized.  The chunks run as a fori_loop
+    # over 128-ALIGNED dynamic slices (which Mosaic accepts), the 128
+    # steps inside a chunk are static.  The earlier formulation — a
+    # static unroll of [bm, 8, bn] broadcast products — needed 36.8 MB
+    # of scoped VMEM at the callers' 256/512/256 blocks, past the 16 MB
+    # limit (v5e, jax 0.9.0 / libtpu 0.0.34).
+    def chunk(c, acc):
+        k0 = pl.multiple_of(c * _KCHUNK, _KCHUNK)
+        a_c = a_ref[:, pl.ds(k0, _KCHUNK)]  # [bm, 128]
+        b_c = b_ref[pl.ds(k0, _KCHUNK), :]  # [128, bn]
+        for k in range(_KCHUNK):
+            acc = add(acc, mul(a_c[:, k:k + 1], b_c[k:k + 1, :]))
+        return acc
+
+    o_ref[...] = jax.lax.fori_loop(0, bk // _KCHUNK, chunk, o_ref[...])
 
 
 def semiring_matmul(
@@ -80,6 +84,9 @@ def semiring_matmul(
     assert k == k2, (a.shape, b.shape)
     assert m % bm == 0 and k % bk == 0 and n % bn == 0, (
         f"dims {(m, k, n)} must divide blocks {(bm, bk, bn)}"
+    )
+    assert kind == "plus_times" or bk % _KCHUNK == 0, (
+        f"tropical kinds fold k in chunks of {_KCHUNK}; bk={bk}"
     )
     grid = (m // bm, n // bn, k // bk)
     kernel = functools.partial(

@@ -229,11 +229,8 @@ def sparsify_windowed(
                the residual rank — no take_along_axis anywhere)
 
     Exact, sorted row-major, ~2 window ops + ~40 lanes of vector work per
-    output slot.  The Pallas butterfly-pack alternative
-    (``ops/pallas_sparsify``) is bound by the same chip's ~1 G elem-op/s
-    vector wall across its ~100+ routing passes and measures 4-10x slower
-    at bench densities; it remains available for the high-density regime
-    and as the documented routing-network experiment.
+    output slot.  (A Pallas butterfly-pack alternative measured 4-10x
+    slower at bench densities in round 4 — PERF_NOTES_r4 — and is gone.)
     """
     from .segment import expand_ranges
 

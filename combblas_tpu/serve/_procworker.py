@@ -2,11 +2,12 @@
 
 ``python -m combblas_tpu.serve._procworker --fd N`` is what
 ``ProcessFleet`` spawns: one OS process hosting one ``Server`` with
-its OWN JAX runtime (the parent exports ``JAX_PLATFORMS=cpu`` and a
-per-replica ``XLA_FLAGS --xla_force_host_platform_device_count``
-before exec, so the child's mesh is genuinely its own — no shared
-exec lock, no cross-process XLA rendezvous: the deadlock that forces
-the thread fleet to serialize replicas does not exist here).
+its OWN JAX runtime.  The platform and this child's share of it (a
+virtual CPU partition, or one chip) arrive through the environment
+the launcher prepared (``procfleet.child_env``); nothing here chooses
+a device.  The child's mesh is genuinely its own — no shared exec
+lock, no cross-process XLA rendezvous: the deadlock that forces the
+thread fleet to serialize replicas does not exist here.
 
 Protocol (``serve/ipc.py`` framing) — the parent sends requests
 ``{"id": n, "op": ..., ...}``; the child replies ``{"id": n, "ok":
@@ -35,22 +36,10 @@ import threading
 import time
 import traceback
 
-# The parent pins the child's runtime through env BEFORE exec; these
-# defaults only matter for hand-run workers.  Both must be set before
-# jax is imported anywhere below.
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-if "--xla_force_host_platform_device_count" not in os.environ.get(
-    "XLA_FLAGS", ""
-):
-    os.environ["XLA_FLAGS"] = (
-        os.environ.get("XLA_FLAGS", "")
-        + " --xla_force_host_platform_device_count=8"
-    )
-
 # obs is import-light (no jax at module level) and reads COMBBLAS_OBS
 # — which the parent pinned into our env — at import time, so the
 # child's telemetry armed/unarmed state mirrors the router's.
-from .. import obs  # noqa: E402
+from .. import obs
 
 
 def _cfg_from_json(d: dict):
@@ -235,19 +224,21 @@ class ProcWorker:
         threading.Thread(
             target=self._hb_loop, name="combblas-proc-hb", daemon=True
         ).start()
+        import jax
+
+        devs = jax.devices()
         return {
             "pid": os.getpid(),
-            "devices": self._device_count(),
+            "devices": len(devs),
+            # which devices THIS process owns: the launcher's proof
+            # that replicas sit on distinct chips (or the CPU)
+            "platform": devs[0].platform,
+            "device_kind": devs[0].device_kind,
+            "device_ids": [int(d.id) for d in devs],
             "warmed": {f"{k}": w for k, w in warmed.items()},
             "graph_version": self.srv.engine.version_id,
             "durable": self.srv.durable,
         }
-
-    @staticmethod
-    def _device_count() -> int:
-        import jax
-
-        return len(jax.devices())
 
     def dispatch(self, m: dict) -> bool:
         """Handle one request; returns False when the loop should

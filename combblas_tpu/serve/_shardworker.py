@@ -2,11 +2,11 @@
 
 ``python -m combblas_tpu.serve._shardworker --fd N`` is what
 ``ProcSlice`` spawns: one OS process hosting ONE row slab of the
-sharded graph (a ``shard.SliceRuntime``) with its OWN JAX runtime —
-the parent pins ``JAX_PLATFORMS=cpu`` and a per-slice
-``--xla_force_host_platform_device_count`` (1: a slice IS the host in
-the multi-host story; the virtual mesh lives across processes, not
-inside one) before exec.
+sharded graph (a ``shard.SliceRuntime``) with its OWN JAX runtime on
+ONE device of the platform the router was launched under (a slice IS
+the host in the multi-host story; the mesh lives across processes, not
+inside one).  The launcher prepares that environment
+(``procfleet.child_env``); nothing here chooses a device.
 
 Protocol: the ``_procworker`` conventions verbatim — framed request/
 reply on the inherited socketpair (``{"id": n, "op": ...}`` →
@@ -34,19 +34,8 @@ import threading
 import time
 import traceback
 
-# Pin the runtime BEFORE jax is imported anywhere below; the parent
-# exports these through env, the defaults cover hand-run workers.
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-if "--xla_force_host_platform_device_count" not in os.environ.get(
-    "XLA_FLAGS", ""
-):
-    os.environ["XLA_FLAGS"] = (
-        os.environ.get("XLA_FLAGS", "")
-        + " --xla_force_host_platform_device_count=1"
-    )
-
 # import-light; reads COMBBLAS_OBS (pinned by the parent) at import
-from .. import obs  # noqa: E402
+from .. import obs
 
 
 class ShardWorker:

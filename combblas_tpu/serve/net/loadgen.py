@@ -330,7 +330,13 @@ def main() -> int:
     chaos = os.environ.get("BENCH_NET_CHAOS", "0") not in ("", "0")
     scale = int(os.environ.get("BENCH_SERVE_SCALE", "8") or 8)
     replicas = int(os.environ.get("BENCH_NET_REPLICAS", "2") or 2)
+    from ...utils import device_fields
+
     out = run(chaos=chaos, scale=scale, replicas=replicas)
+    # read after run(): the fleet is closed, so the router starting its
+    # own backend takes nothing from a replica
+    dev = device_fields()
+    out.update(dev)
     print(json.dumps(out), flush=True)
     if os.environ.get("BENCH_EMIT_SUMMARY", "1") == "0":
         return 0
@@ -346,6 +352,7 @@ def main() -> int:
         "achieved_qps": out.get("achieved_qps"),
         "availability": out.get("availability"),
         "decomposition": out.get("decomposition"),
+        **dev,
     }
     path = os.environ.get("BENCH_SUMMARY_PATH", "BENCH_SUMMARY.json")
     try:

@@ -7,7 +7,9 @@ mesh deadlock XLA's all-reduce rendezvous (PR 12) — all of its
 replicas serialize on ONE shared exec lock.  This module is the real
 thing on one machine: each replica is a subprocess hosting a
 ``Server`` with its OWN JAX runtime (``serve/_procworker.py``; the
-parent exports per-child ``JAX_PLATFORMS``/``XLA_FLAGS``), so
+platform is whatever the router was launched under — ``child_env``
+only partitions it: a virtual device count per child on the CPU, one
+chip per child on a TPU host), so
 
 * replica death is PROCESS death (``SIGKILL`` kills a real crash
   domain: heap, device buffers, locks, threads — nothing to clean up,
@@ -56,6 +58,7 @@ from .. import obs
 from ..obs.fleetlog import FleetLog
 from ..obs.recorder import FlightRecorder
 from ..tuner import config as tuner_config
+from ..utils import inherited_platform
 from .batcher import settle
 from .faults import ProcessFaultPlan
 from .ipc import Channel, ChannelClosed
@@ -73,7 +76,65 @@ from .scheduler import BackpressureError, ServeConfig
 _stitch = threading.local()
 
 __all__ = ["ProcessFleet", "ReplicaProc", "IpcTimeoutError",
-           "ReplicaDeadError"]
+           "ReplicaDeadError", "child_env"]
+
+_HOST_DEVICES_FLAG = "--xla_force_host_platform_device_count"
+
+
+def child_env(index: int, devices: int = 1) -> dict:
+    """Environment for child ``index`` of a launcher (``ProcessFleet``
+    replicas, ``ShardedEngine`` slices), each owning ``devices``
+    devices of the platform the ROUTER was launched under — the
+    platform itself passes through untouched, as does
+    ``JAX_COMPILATION_CACHE_DIR``.
+
+    * inherited ``cpu``: the child gets its own virtual partition
+      (``--xla_force_host_platform_device_count=devices``);
+    * anything else (a chip host): a chip belongs to one process at a
+      time, so child ``index`` is confined to its own chip through the
+      variables libtpu reads at load (``TPU_VISIBLE_CHIPS`` + 1x1x1
+      process bounds).  One chip per child; a machine with fewer chips
+      than children fails the surplus child's boot — it neither
+      contends for a held chip nor falls back to the CPU.
+    """
+    env = dict(os.environ)
+    if inherited_platform() == "cpu":
+        flags = [
+            f for f in env.get("XLA_FLAGS", "").split()
+            if not f.startswith(_HOST_DEVICES_FLAG)
+        ]
+        flags.append(f"{_HOST_DEVICES_FLAG}={int(devices)}")
+        env["XLA_FLAGS"] = " ".join(flags)
+    else:
+        if int(devices) != 1:
+            raise ValueError(
+                f"child {index} asks for {devices} devices: on an "
+                "accelerator host a launcher gives each child exactly "
+                "one chip (run a multi-chip grid in ONE process "
+                "instead — Grid.make(pr, pc))"
+            )
+        env["TPU_VISIBLE_CHIPS"] = str(int(index))
+        env["TPU_CHIPS_PER_PROCESS_BOUNDS"] = "1,1,1"
+        env["TPU_PROCESS_BOUNDS"] = "1,1,1"
+        env["ALLOW_MULTIPLE_LIBTPU_LOAD"] = "1"
+    # hermetic durability: only the boot message's wal_dir attaches a
+    # log, never ambient env
+    env["COMBBLAS_WAL"] = "0"
+    # the child's telemetry arms with the ROUTER's current state,
+    # not whatever COMBBLAS_OBS the operator's shell had: a fleet
+    # whose parent enabled obs at runtime still federates
+    env["COMBBLAS_OBS"] = "1" if obs.ENABLED else "0"
+    # the child must import THIS package wherever the parent found
+    # it — a parent that path-hacked sys.path (or runs from another
+    # cwd) would otherwise spawn children that die on import
+    pkg_root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)
+    )))
+    pp = env.get("PYTHONPATH")
+    env["PYTHONPATH"] = (
+        pkg_root if not pp else pkg_root + os.pathsep + pp
+    )
+    return env
 
 
 class IpcTimeoutError(RuntimeError):
@@ -146,6 +207,9 @@ class ReplicaProc:
         self.admitted_t = time.monotonic()
         self.last_hb_t: float | None = None
         self.last_hb: dict = {}
+        #: the child's boot reply (pid, platform, device ids, warmed
+        #: plans) once admitted
+        self.boot_info: dict = {}
         self.rpcs = 0
         self.ipc_timeouts = 0
         # federation: the child's last piggybacked registry snapshot
@@ -526,6 +590,19 @@ class ProcessFleet(ReplicaFleetBase):
                 "process replicas die for real, and respawn/promotion "
                 "recover from checkpoint+WAL"
             )
+        if inherited_platform() != "cpu":
+            import jax
+
+            if jax.default_backend() != "cpu":
+                raise RuntimeError(
+                    "ProcessFleet.build constructs the boot checkpoint "
+                    "on the ROUTER's runtime, and on "
+                    f"{jax.default_backend()!r} a chip belongs to one "
+                    "process at a time — the router would hold the "
+                    "chips its replicas need.  Stage the checkpoint in "
+                    "a process of its own (utils.checkpoint."
+                    "save_version) and use ProcessFleet.from_checkpoint"
+                )
         workdir = workdir or os.path.join(
             os.path.abspath(wal_dir), os.pardir, "procfleet"
         )
@@ -600,34 +677,6 @@ class ProcessFleet(ReplicaFleetBase):
         self._init_policy()
         obs.gauge("serve.procfleet.replicas", len(self.replicas))
 
-    def _child_env(self) -> dict:
-        env = dict(os.environ)
-        # the child's OWN runtime: its own cpu client, its own virtual
-        # device partition — and hermetic durability (only the boot
-        # message's wal_dir attaches a log, never ambient env)
-        env["JAX_PLATFORMS"] = "cpu"
-        env["XLA_FLAGS"] = (
-            f"--xla_force_host_platform_device_count={self.devices}"
-        )
-        env["COMBBLAS_WAL"] = "0"
-        # the child's telemetry arms with the ROUTER's current state,
-        # not whatever COMBBLAS_OBS the operator's shell had: a fleet
-        # whose parent enabled obs at runtime still federates
-        env["COMBBLAS_OBS"] = "1" if obs.ENABLED else "0"
-        # the child must import THIS package wherever the parent found
-        # it — a parent that path-hacked sys.path (or runs from another
-        # cwd) would otherwise spawn children that die on import
-        import combblas_tpu
-
-        pkg_root = os.path.dirname(os.path.dirname(
-            os.path.abspath(combblas_tpu.__file__)
-        ))
-        pp = env.get("PYTHONPATH")
-        env["PYTHONPATH"] = (
-            pkg_root if not pp else pkg_root + os.pathsep + pp
-        )
-        return env
-
     def _launch(self, i: int) -> ReplicaProc:
         """Fork one replica child (socketpair + Popen) — cheap; the
         expensive initialization happens when its ``boot`` RPC runs."""
@@ -643,7 +692,7 @@ class ProcessFleet(ReplicaFleetBase):
                     "--fd", str(child_sock.fileno()),
                 ],
                 pass_fds=(child_sock.fileno(),),
-                env=self._child_env(),
+                env=child_env(i, self.devices),
                 stdout=log, stderr=subprocess.STDOUT,
                 start_new_session=True,  # chaos signals hit the
                 # replica, never the router's process group
@@ -675,6 +724,7 @@ class ProcessFleet(ReplicaFleetBase):
 
     @staticmethod
     def _admit_boot(rp: ReplicaProc, boot: dict) -> None:
+        rp.boot_info = boot
         rp.last_hb = {"depth": 0, "serving": True,
                       "pid": boot.get("pid")}
         rp.last_hb_t = time.monotonic()

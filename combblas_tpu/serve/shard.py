@@ -105,7 +105,7 @@ from ..utils import checkpoint as ckpt
 from .frame import SparseFrontier, pack_bf16, unpack_bf16
 from .ipc import Channel
 from .policy import ReplicaDeadError, StaleEpochError
-from .procfleet import IpcTimeoutError, ReplicaProc
+from .procfleet import IpcTimeoutError, ReplicaProc, child_env
 
 #: Manifest schema tag (refused at recovery when mismatched — the
 #: plan-store convention: never guess at an incompatible layout).
@@ -1174,25 +1174,6 @@ class ProcSlice:
         }
         self.rp.last_hb_t = time.monotonic()
 
-    def _child_env(self) -> dict:
-        env = dict(os.environ)
-        env["JAX_PLATFORMS"] = "cpu"
-        env["XLA_FLAGS"] = (
-            f"--xla_force_host_platform_device_count={self._devices}"
-        )
-        env["COMBBLAS_WAL"] = "0"
-        env["COMBBLAS_OBS"] = "1" if obs.ENABLED else "0"
-        import combblas_tpu
-
-        pkg_root = os.path.dirname(os.path.dirname(
-            os.path.abspath(combblas_tpu.__file__)
-        ))
-        pp = env.get("PYTHONPATH")
-        env["PYTHONPATH"] = (
-            pkg_root if not pp else pkg_root + os.pathsep + pp
-        )
-        return env
-
     def _launch(self) -> ReplicaProc:
         parent_sock, child_sock = socket.socketpair()
         log = open(
@@ -1206,7 +1187,7 @@ class ProcSlice:
                     "--fd", str(child_sock.fileno()),
                 ],
                 pass_fds=(child_sock.fileno(),),
-                env=self._child_env(),
+                env=child_env(self.idx, self._devices),
                 stdout=log, stderr=subprocess.STDOUT,
                 start_new_session=True,  # chaos signals hit the
                 # slice, never the router's process group

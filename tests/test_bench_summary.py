@@ -57,7 +57,7 @@ def test_emit_summary_is_parseable_with_required_keys(
     assert s["value"] == 14.5
     assert s["median"] == 246.4
     assert s["rc"] == 0
-    assert len(lines[-1]) < 400, "summary must be truncation-proof small"
+    assert len(lines[-1]) < 500, "summary must be truncation-proof small"
     sidecar = json.loads((tmp_path / "BENCH_SUMMARY.json").read_text())
     assert sidecar == s
 
@@ -73,18 +73,38 @@ def test_emit_summary_survives_unwritable_sidecar(benchmod, monkeypatch):
     assert s["rc"] == 1 and "summary_write_error" in s
 
 
-def test_variance_block_names_the_suspect(benchmod):
-    runs = [{"mteps": 40.0, "warmup_s": 5.0}] * 3
-    v = benchmod.diagnose_variance(runs, {"mteps": 280.0})
-    assert v["suspect"] == "warmup_contamination"
-    v = benchmod.diagnose_variance(
-        [{"mteps": 40.0, "warmup_s": 120.0}], {"mteps": 50.0}
+def test_rc_is_nonzero_when_nothing_was_measured(
+    benchmod, capsys, tmp_path, monkeypatch
+):
+    """D.4: a record whose every run carries "error" gives rc != 0 —
+    and so does one measured on a platform that was not asked for."""
+    monkeypatch.setenv(
+        "BENCH_SUMMARY_PATH", str(tmp_path / "BENCH_SUMMARY.json")
     )
-    assert v["suspect"] == "cache_cold"
-    v = benchmod.diagnose_variance(runs, {"mteps": 50.0})
-    assert v["suspect"] == "degraded_regime"
-    assert {"median_mteps", "operating_point_mteps", "rerun_mteps",
-            "detail"} <= set(v)
+    failed = [{"mteps": 0.0, "error": "no chip"}] * 2
+    official = benchmod.emit(failed, [], 1.0, {}, 0.0)
+    assert "error" in official
+    assert benchmod.emit_summary(official) == 1
+    s = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert s["rc"] == 1 and s["value"] == 0.0 and s["warning"]
+    # measured, but on a CPU nobody asked for by name: not a chip number
+    cpu = {"platform": "cpu", "device_kind": "cpu", "n_devices": 1}
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    official = benchmod.emit([{"mteps": 5.0, **cpu}], [], 1.0, {}, 0.0)
+    assert official["metric"].endswith("_cpu_MTEPS")
+    assert benchmod.emit_summary(official) == 1
+    # the same record under an explicit JAX_PLATFORMS=cpu passes, and a
+    # TPU record is the only one named "1chip"
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    official = benchmod.emit([{"mteps": 5.0, **cpu}], [], 1.0, {}, 0.0)
+    assert benchmod.emit_summary(official) == 0
+    tpu = {"platform": "tpu", "device_kind": "TPU v5 lite", "n_devices": 1}
+    official = benchmod.emit([{"mteps": 5.0, **tpu}], [], 1.0, {}, 0.0)
+    capsys.readouterr()
+    assert official["metric"].endswith("_1chip_MTEPS")
+    assert benchmod.emit_summary(official) == 0
+    s = json.loads(capsys.readouterr().out.strip().splitlines()[-1])
+    assert s["platform"] == "tpu" and s["device_kind"] == "TPU v5 lite"
 
 
 def test_emit_reports_median_and_spread(benchmod, capsys):
@@ -97,12 +117,6 @@ def test_emit_reports_median_and_spread(benchmod, capsys):
     sp = out["repeats_spread"]
     assert sp["min"] == 90.0 and sp["max"] == 130.0
     assert sp["rel_spread"] == pytest.approx(0.4)
-    # a variance block rides the official record when provided
-    out = benchmod.emit(
-        runs, [], 1.0, {}, 0.0, {"suspect": "degraded_regime"}
-    )
-    capsys.readouterr()
-    assert out["variance"]["suspect"] == "degraded_regime"
 
 
 def test_spgemm_bench_summary_fields():
