@@ -155,7 +155,7 @@ def _restores_trace_rate(fn):
     """Scenario decorator: whatever sampling rate the scenario sets,
     the PROCESS-GLOBAL rate is restored on every exit path (exception
     included) — a later scenario or test in the same process must not
-    inherit it (the obs_smoke try/finally pattern)."""
+    inherit it."""
     import functools
 
     @functools.wraps(fn)

@@ -1,0 +1,5 @@
+"""Algorithms + local kernels: device time of one iteration of the
+``bfs_batch_compact`` program's ``bfs.level`` loop, median over the levels of
+whole executions (ms)."""
+
+from chipbench.scopes import level_ms as read  # noqa: F401

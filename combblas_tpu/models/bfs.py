@@ -33,6 +33,27 @@ from ..parallel.spmat import SpParMat, ones_i32
 from ..parallel.spmv import dist_spmspv_masked, dist_spmv_masked
 from ..parallel.vec import DistVec
 
+#: The ``jax.named_scope`` names of the two batch programs the served
+#: path and ``bfs_batch_compact`` run (``_bfs_batch_impl``,
+#: ``_bfs_batch_compact_program``), outermost first; ``ell.bucket<i>`` is
+#: one scope per degree class.  Trace-time metadata only: the device
+#: trace's per-scope and per-level times are read by these names
+#: (docs/observability.md "Named scopes"), so a rename is a change of
+#: yardstick.  ``bfs.parents`` exists in the compact program only.
+BFS_SCOPES = (
+    "bfs.init",
+    "bfs.level",  # the whole while loop; one iteration = one level
+    "ell.bucket<i>",  # inside it: gather, fold, scatter_rows
+    "gather",
+    "fold",
+    "scatter_rows",
+    "ell.reduce",  # the COL_AXIS fold of the tile partials
+    "bfs.update",
+    "vec.realign",
+    "bfs.active",
+    "bfs.parents",
+)
+
 
 def _global_ids(grid, nblocks, block_len, length, align):
     gids = jnp.arange(nblocks * block_len, dtype=jnp.int32).reshape(
@@ -98,79 +119,6 @@ def bfs(
         cond, step, (parents0, levels0, x0, jnp.int32(0), jnp.bool_(True))
     )
     return mk_row(parents), mk_row(levels), niter
-
-
-@partial(jax.jit, static_argnames=("sr",))
-def _bfs_level_step(sr, A, parents, levels, x, row_gids, level):
-    """ONE level of the dense-frontier BFS as its own jitted program —
-    the host-stepped unit ``bfs_levels_instrumented`` drives. Returns
-    (parents, levels, x_next, new-vertex count)."""
-    grid = A.grid
-    n = A.nrows
-    unvisited = DistVec(blocks=parents < 0, length=n, align="row", grid=grid)
-    xv = DistVec(blocks=x, length=A.ncols, align="col", grid=grid)
-    y = dist_spmv_masked(sr, A, xv, unvisited)
-    new = (y.blocks >= 0) & (parents < 0) & (row_gids >= 0)
-    parents = jnp.where(new, y.blocks, parents)
-    levels = jnp.where(new, level + 1, levels)
-    frontier_row = DistVec(
-        blocks=jnp.where(new, row_gids, -1), length=n, align="row", grid=grid,
-    )
-    x_next = frontier_row.realign("col").blocks
-    return parents, levels, x_next, jnp.sum(new).astype(jnp.int32)
-
-
-def bfs_levels_instrumented(
-    A,
-    source,
-    max_iters: int | None = None,
-    sr: "Semiring" = SELECT2ND_MAX,
-):
-    """Host-stepped level-synchronous BFS with one ``obs`` span PER HOP,
-    each carrying a ``frontier`` event with the discovered-vertex count —
-    the per-iteration table of the reference's TIMING builds
-    (``TopDownBFS.cpp:472-479``), structured.
-
-    DEBUG/OBSERVABILITY TOOL, not the benchmark kernel: every level pays
-    a device→host sync for the frontier count (which also terminates the
-    loop), exactly what the one-launch kernels (``bfs``, ``bfs_single``,
-    ``bfs_batch``) exist to avoid on readback-poisoned hardware. Use it
-    on CPU, in tests, or in a throwaway diagnostic process; the spans
-    line up with ``jax.profiler`` traces via their TraceAnnotations.
-
-    Works for SpParMat and EllParMat (``dist_spmv_masked`` dispatches).
-    Returns (parents, levels, num_levels) like ``bfs``.
-    """
-    grid = A.grid
-    n = A.nrows
-    pr_, lr = grid.pr, grid.local_rows(n)
-    pc_, lc = grid.pc, grid.local_cols(A.ncols)
-    iters = max_iters if max_iters is not None else n
-
-    row_gids = _global_ids(grid, pr_, lr, n, "row")
-    col_gids = _global_ids(grid, pc_, lc, A.ncols, "col")
-    parents = jnp.where(row_gids == source, jnp.int32(source), -1)
-    levels = jnp.where(row_gids == source, 0, -1).astype(jnp.int32)
-    x = jnp.where(col_gids == source, jnp.int32(source), -1)
-
-    niter = 0
-    with obs.span("bfs", source=int(source), nrows=int(n)):
-        for hop in range(iters):
-            with obs.span("bfs.hop", hop=hop):
-                parents, levels, x, nnew = _bfs_level_step(
-                    sr, A, parents, levels, x, row_gids, jnp.int32(hop)
-                )
-                frontier_nnz = int(nnew)  # the level's host sync
-                obs.span_event(
-                    "frontier", hop=hop + 1, nnz=frontier_nnz
-                )
-            # executed-iteration count, matching ``bfs``'s while_loop
-            # semantics (the terminal empty level is counted too)
-            niter = hop + 1
-            if frontier_nnz == 0:
-                break
-    mk = lambda b: DistVec(blocks=b, length=n, align="row", grid=grid)
-    return mk(parents), mk(levels), niter
 
 
 @partial(jax.jit, static_argnames=("frontier_capacity", "exp_capacity"))
@@ -551,24 +499,25 @@ def _bfs_batch_impl(
     W = sources.shape[0]
     iters = max_iters if max_iters is not None else n
 
-    row_gids = _global_ids(grid, pr_, lr, n, "row")  # [pr, lr]
-    col_gids = _global_ids(grid, pc_, lc, A.ncols, "col")
+    with jax.named_scope("bfs.init"):
+        row_gids = _global_ids(grid, pr_, lr, n, "row")  # [pr, lr]
+        col_gids = _global_ids(grid, pc_, lc, A.ncols, "col")
 
-    src = sources.astype(jnp.int32)[None, None, :]  # [1, 1, W]
-    # PAD_ROOT lanes (the serve batcher's lane padding) are inert: the
-    # live guard keeps a pad source from matching the -1 padding slots
-    # of the gid tables, so a pad lane starts (and stays) empty.
-    live = src != PAD_ROOT
-    is_src = (row_gids[:, :, None] == src) & live
-    parents0 = jnp.where(is_src, src, jnp.int32(-1))  # [pr, lr, W]
-    levels0 = (
-        jnp.where(is_src, 0, -1).astype(jnp.int32)
-        if track_levels
-        else jnp.zeros((1, 1, 1), jnp.int32)  # placeholder carry
-    )
-    x0 = jnp.where(
-        (col_gids[:, :, None] == src) & live, src, jnp.int32(-1)
-    )
+        src = sources.astype(jnp.int32)[None, None, :]  # [1, 1, W]
+        # PAD_ROOT lanes (the serve batcher's lane padding) are inert:
+        # the live guard keeps a pad source from matching the -1 padding
+        # slots of the gid tables, so a pad lane starts (and stays) empty.
+        live = src != PAD_ROOT
+        is_src = (row_gids[:, :, None] == src) & live
+        parents0 = jnp.where(is_src, src, jnp.int32(-1))  # [pr, lr, W]
+        levels0 = (
+            jnp.where(is_src, 0, -1).astype(jnp.int32)
+            if track_levels
+            else jnp.zeros((1, 1, 1), jnp.int32)  # placeholder carry
+        )
+        x0 = jnp.where(
+            (col_gids[:, :, None] == src) & live, src, jnp.int32(-1)
+        )
 
     def mk(b, align):
         return DistMultiVec(blocks=b, length=n, align=align, grid=grid)
@@ -581,19 +530,27 @@ def _bfs_batch_impl(
         parents, levels, x, level, _ = state
         unvisited = mk(parents < 0, "row")
         y = dist_spmv_ell_masked_multi(sr, A, mk(x, "col"), unvisited)
-        new = (y.blocks >= 0) & (parents < 0) & (row_gids[:, :, None] >= 0)
-        parents = jnp.where(new, y.blocks, parents)
-        if track_levels:
-            levels = jnp.where(new, level + 1, levels)
-        x_next = mk(
-            jnp.where(new, row_gids[:, :, None], -1), "row"
-        ).realign("col").blocks
-        active = jnp.any(new)
+        with jax.named_scope("bfs.update"):
+            new = (
+                (y.blocks >= 0) & (parents < 0)
+                & (row_gids[:, :, None] >= 0)
+            )
+            parents = jnp.where(new, y.blocks, parents)
+            if track_levels:
+                levels = jnp.where(new, level + 1, levels)
+            frontier = jnp.where(new, row_gids[:, :, None], -1)
+        x_next = mk(frontier, "row").realign("col").blocks
+        with jax.named_scope("bfs.active"):
+            active = jnp.any(new)
         return parents, levels, x_next, level + 1, active
 
-    parents, levels, _, niter, _ = jax.lax.while_loop(
-        cond, step, (parents0, levels0, x0, jnp.int32(0), jnp.bool_(True))
-    )
+    # the whole loop, condition included, is one scope: a level is one
+    # iteration of it in the device trace
+    with jax.named_scope("bfs.level"):
+        parents, levels, _, niter, _ = jax.lax.while_loop(
+            cond, step,
+            (parents0, levels0, x0, jnp.int32(0), jnp.bool_(True)),
+        )
     if not track_levels:
         # levels were not tracked: return discovery indicator (0 for the
         # sources / discovered? -1 undiscovered) — parents' sign carries it.
@@ -1092,10 +1049,22 @@ def bfs_batch_compact(A, sources, max_iters: int | None = None,
     this wrapper rebuilds the DistMultiVecs outside."""
     from ..parallel.vec import DistMultiVec
 
-    p, l, niter = _bfs_batch_compact_impl(
-        A, sources, max_iters=max_iters, ring=ring, csc=csc,
+    opts = dict(
+        max_iters=max_iters, ring=ring, csc=csc,
         frontier_capacity=frontier_capacity, edge_capacity=edge_capacity,
     )
+    if obs.ENABLED:
+        # this entry has no warm-up of its own: the first traced call of
+        # a shape publishes the program's op names (obs/opnames.py)
+        obs.opnames.publish_once(
+            ("bfs_batch_compact", A.grid, A.nrows, A.ncols,
+             len(A.buckets), sources.shape, max_iters, ring,
+             csc is not None, frontier_capacity, edge_capacity),
+            lambda: _bfs_batch_compact_program.lower(
+                A, sources, **opts
+            ).compile().as_text(),
+        )
+    p, l, niter = _bfs_batch_compact_program(A, sources, **opts)
     mk = lambda b: DistMultiVec(
         blocks=b, length=A.nrows, align="row", grid=A.grid
     )
@@ -1107,10 +1076,10 @@ def bfs_batch_compact(A, sources, max_iters: int | None = None,
     static_argnames=("max_iters", "ring", "frontier_capacity",
                      "edge_capacity"),
 )
-def _bfs_batch_compact_impl(A, sources, max_iters: int | None = None,
-                            ring: bool = False, csc=None,
-                            frontier_capacity: int | None = None,
-                            edge_capacity: int | None = None):
+def _bfs_batch_compact_program(A, sources, max_iters: int | None = None,
+                               ring: bool = False, csc=None,
+                               frontier_capacity: int | None = None,
+                               edge_capacity: int | None = None):
     """Level-compressed multi-source BFS: int8 frontiers, parents
     reconstructed in ONE pass after the search.
 
@@ -1167,18 +1136,19 @@ def _bfs_batch_compact_impl(A, sources, max_iters: int | None = None,
         )
     iters = max_iters if max_iters is not None else 126
 
-    row_gids = _global_ids(grid, pr_, lr, n, "row")
-    col_gids = _global_ids(grid, pc_, lc, A.ncols, "col")
-    src = sources.astype(jnp.int32)[None, None, :]
-    # PAD_ROOT lanes stay empty (see _bfs_batch_impl's live guard)
-    live = src != PAD_ROOT
+    with jax.named_scope("bfs.init"):
+        row_gids = _global_ids(grid, pr_, lr, n, "row")
+        col_gids = _global_ids(grid, pc_, lc, A.ncols, "col")
+        src = sources.astype(jnp.int32)[None, None, :]
+        # PAD_ROOT lanes stay empty (see _bfs_batch_impl's live guard)
+        live = src != PAD_ROOT
 
-    levels0 = jnp.where(
-        (row_gids[:, :, None] == src) & live, 0, -1
-    ).astype(jnp.int8)  # [pr, lr, W]
-    x0 = ((col_gids[:, :, None] == src) & live).astype(
-        jnp.int8
-    )  # [pc, lc, W]
+        levels0 = jnp.where(
+            (row_gids[:, :, None] == src) & live, 0, -1
+        ).astype(jnp.int8)  # [pr, lr, W]
+        x0 = ((col_gids[:, :, None] == src) & live).astype(
+            jnp.int8
+        )  # [pc, lc, W]
 
     def mk(b, align):
         return DistMultiVec(blocks=b, length=n, align=align, grid=grid)
@@ -1228,23 +1198,30 @@ def _bfs_batch_compact_impl(A, sources, max_iters: int | None = None,
             )
         else:
             reached = _ell_levels_step(A, x, undisc, ring=ring)
-        new = reached > 0
-        levels = jnp.where(new, (level + 1).astype(jnp.int8), levels)
+        with jax.named_scope("bfs.update"):
+            new = reached > 0
+            levels = jnp.where(
+                new, (level + 1).astype(jnp.int8), levels
+            )
         x_next = mk(reached, "row").realign("col").blocks
-        return levels, x_next, level + 1, jnp.any(new)
+        with jax.named_scope("bfs.active"):
+            active = jnp.any(new)
+        return levels, x_next, level + 1, active
 
-    levels, _, niter, _ = jax.lax.while_loop(
-        cond, step, (levels0, x0, jnp.int8(0), jnp.bool_(True))
-    )
+    with jax.named_scope("bfs.level"):
+        levels, _, niter, _ = jax.lax.while_loop(
+            cond, step, (levels0, x0, jnp.int8(0), jnp.bool_(True))
+        )
 
-    levels_col = mk(levels, "row").realign("col").blocks
-    parents = _ell_parents_from_levels(A, levels_col, levels)
-    # roots are their own parents; undiscovered stay -1
-    parents = jnp.where(
-        (row_gids[:, :, None] == src) & live, src, parents
-    )
-    parents = jnp.where(
-        (levels < 0) | (row_gids[:, :, None] < 0), -1, parents
-    )
+    with jax.named_scope("bfs.parents"):
+        levels_col = mk(levels, "row").realign("col").blocks
+        parents = _ell_parents_from_levels(A, levels_col, levels)
+        # roots are their own parents; undiscovered stay -1
+        parents = jnp.where(
+            (row_gids[:, :, None] == src) & live, src, parents
+        )
+        parents = jnp.where(
+            (levels < 0) | (row_gids[:, :, None] < 0), -1, parents
+        )
     # plain arrays out (see the eager wrapper above)
     return parents, levels, niter.astype(jnp.int32)

@@ -70,6 +70,7 @@ from .sinks import (
     write_jsonl,
 )
 from .spans import NULL_SPAN, SpanTracker
+from . import opnames
 from . import trace as _trace
 
 #: Master switch, checked at every instrumentation site BEFORE any work.
@@ -137,11 +138,12 @@ def enabled() -> bool:
 
 
 def reset() -> None:
-    """Clear every metric, span, event, and per-request trace (the
-    flag is untouched)."""
+    """Clear every metric, span, event, per-request trace and published
+    instruction table (the flag is untouched)."""
     registry.clear()
     _spans.clear()
     _trace.clear()
+    opnames.clear()
 
 
 def reset_spans() -> None:

@@ -587,6 +587,25 @@ name                                   kind       meaning
 ``serve.batch.padding_waste``          histogram  pad lanes per batch
 ``serve.batches``                      gauge      total batches
                                                   executed
+``serve.readback.bytes``               counter    bytes ``execute``
+                                                  copied device to
+                                                  host, counted at the
+                                                  ``np.asarray``
+                                                  (labels ``kind``,
+                                                  ``width``)
+``serve.scatter.copied_bytes``         counter    bytes the scatter
+                                                  pass copied to give
+                                                  each request its own
+                                                  lane, one add a
+                                                  batch (labels
+                                                  ``kind``)
+``serve.scatter.views``                counter    lanes handed out as
+                                                  VIEWS of the batch
+                                                  buffer (no copy;
+                                                  the request pins the
+                                                  whole ``[n, W]``
+                                                  result; labels
+                                                  ``kind``)
 ``obs.provider_errors``                counter    broken pull-provider
                                                   callbacks (caught)
 ``serve.bench.*``                      gauge      bench-scenario

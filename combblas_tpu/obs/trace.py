@@ -72,6 +72,16 @@ def sampled(rid, rate: float | None = None) -> bool:
     )
 
 
+def _accumulate(pairs: list, name: str, seconds: float) -> None:
+    """Add to the ``[name, seconds]`` pair of that name, first-seen
+    order kept."""
+    for pair in pairs:
+        if pair[0] == name:
+            pair[1] += seconds
+            return
+    pairs.append([name, seconds])
+
+
 class RequestTrace:
     """One request's stage clock.  ``mark(stage)`` charges the time
     since the previous mark (or creation) to ``stage``; repeated stage
@@ -80,7 +90,7 @@ class RequestTrace:
     the trace and commits it to the bounded log."""
 
     __slots__ = ("rid", "name", "labels", "ts", "t0", "_last",
-                 "stages", "_done", "_held", "_held_status")
+                 "stages", "parts", "_done", "_held", "_held_status")
 
     def __init__(self, rid, name: str, labels: dict):
         self.rid = rid
@@ -90,20 +100,26 @@ class RequestTrace:
         self.t0 = time.perf_counter()
         self._last = self.t0
         self.stages: list[list] = []  # [stage, seconds], ordered
+        self.parts: dict[str, list[list]] = {}  # stage -> [part, seconds]
         self._done = False
         self._held = False
         self._held_status = None
 
-    def mark(self, stage: str, now: float | None = None) -> float:
+    def mark(self, stage: str, now: float | None = None,
+             parts=None) -> float:
+        """``parts``: ``(part, seconds)`` pairs that split THIS interval
+        (the engine's launch / device / readback / to_global inside
+        ``execute``); the caller makes them sum to the interval, so a
+        stage's parts telescope to the stage as stages do to the wall.
+        They accumulate by name like the stages."""
         now = time.perf_counter() if now is None else now
         dt = now - self._last
         self._last = now
-        for st in self.stages:
-            if st[0] == stage:
-                st[1] += dt
-                break
-        else:
-            self.stages.append([stage, dt])
+        _accumulate(self.stages, stage, dt)
+        if parts:
+            mine = self.parts.setdefault(stage, [])
+            for part, seconds in parts:
+                _accumulate(mine, part, seconds)
         return dt
 
     def annotate(self, **labels) -> None:
@@ -157,15 +173,27 @@ class RequestTrace:
         _commit(self)
 
     def record(self) -> dict:
-        """The schema-``trace`` record body (sinks.py validates it)."""
+        """The schema-``trace`` record body (sinks.py validates it).
+        ``t0`` is ``perf_counter`` at admission: ``t0`` plus the stage
+        sums up to a mark is that mark's time, so every mark of every
+        request of a process lies on one monotonic clock (``ts`` is the
+        wall clock, for joining across processes)."""
+        stages = []
+        for s, v in self.stages:
+            st = {"stage": s, "s": round(v, 9)}
+            if s in self.parts:
+                st["parts"] = [
+                    {"stage": p, "s": round(pv, 9)}
+                    for p, pv in self.parts[s]
+                ]
+            stages.append(st)
         return {
             "name": self.name,
             "rid": self.rid,
             "ts": self.ts,
+            "t0": self.t0,
             "wall_s": round(self._last - self.t0, 9),
-            "stages": [
-                {"stage": s, "s": round(v, 9)} for s, v in self.stages
-            ],
+            "stages": stages,
             "labels": dict(self.labels),
         }
 

@@ -313,6 +313,13 @@ def _width_ladder(max_k: int, kind: str = "fine") -> "np.ndarray":
     return np.asarray(widths, np.int64)
 
 
+def _bucket_scope(i: int, phase: str):
+    """``ell.bucket<i>/<phase>``: degree class ``i`` of a tile's sweep,
+    phase ``gather`` / ``fold`` / ``scatter_rows`` (the documented scope
+    list is ``models.bfs.BFS_SCOPES``)."""
+    return jax.named_scope(f"ell.bucket{i}/{phase}")
+
+
 def _bucket_fold(sr: Semiring, prods: Array) -> Array:
     if sr.add_kind == "sum":
         return jnp.sum(prods, axis=1)
@@ -469,27 +476,31 @@ def _ell_local_spmm(
     zero = sr.zero(x2.dtype)
     xpad = jnp.concatenate([x2, jnp.full((1, F), zero, x2.dtype)])
     y = None
-    for bc, bv, br in buckets:
+    for i, (bc, bv, br) in enumerate(buckets):
         nb_, kb = bc.shape
         payload = F * max(jnp.dtype(x2.dtype).itemsize, 1)
         for s0, s1 in _bucket_row_slices(nb_, kb, payload):
-            g = xpad[jnp.minimum(bc[s0:s1], lc)]  # [rows, kb, F]
-            if backend == "mxu_gather":
-                # pad slots: bv holds 0 there (host_build zero-fills),
-                # so the plus_times contraction drops them exactly
-                out_dtype = jnp.result_type(bv.dtype, x2.dtype)
-                yb = lax.dot_general(
-                    bv[s0:s1][:, None, :].astype(out_dtype),
-                    g.astype(out_dtype),
-                    dimension_numbers=(((2,), (1,)), ((0,), (0,))),
-                    preferred_element_type=out_dtype,
-                )[:, 0, :]
-            else:
-                prods = sr.mul(bv[s0:s1][..., None], g)
-                yb = _bucket_fold(sr, prods)  # [rows, F]
-            if y is None:
-                y = jnp.full((lr, F), sr.zero(yb.dtype), yb.dtype)
-            y = _scatter_rows(sr, y, br[s0:s1], yb.astype(y.dtype))
+            with _bucket_scope(i, "gather"):
+                g = xpad[jnp.minimum(bc[s0:s1], lc)]  # [rows, kb, F]
+            with _bucket_scope(i, "fold"):
+                if backend == "mxu_gather":
+                    # pad slots: bv holds 0 there (host_build
+                    # zero-fills), so the plus_times contraction drops
+                    # them exactly
+                    out_dtype = jnp.result_type(bv.dtype, x2.dtype)
+                    yb = lax.dot_general(
+                        bv[s0:s1][:, None, :].astype(out_dtype),
+                        g.astype(out_dtype),
+                        dimension_numbers=(((2,), (1,)), ((0,), (0,))),
+                        preferred_element_type=out_dtype,
+                    )[:, 0, :]
+                else:
+                    prods = sr.mul(bv[s0:s1][..., None], g)
+                    yb = _bucket_fold(sr, prods)  # [rows, F]
+            with _bucket_scope(i, "scatter_rows"):
+                if y is None:
+                    y = jnp.full((lr, F), sr.zero(yb.dtype), yb.dtype)
+                y = _scatter_rows(sr, y, br[s0:s1], yb.astype(y.dtype))
     if y is None:
         y = jnp.full((lr, F), zero, x2.dtype)
     return y
@@ -561,8 +572,9 @@ def dist_spmv_ell_masked_multi(
             tuple(a[0, 0] for a in flat[3 * i : 3 * i + 3]) for i in range(nb)
         ]
         y = _ell_local_spmv_multi(sr, buckets, xblk[0], lr, lc)
-        y = jnp.where(actblk[0], y, sr.zero(y.dtype))
-        return axis_reduce(sr, y, COL_AXIS)[None]
+        with jax.named_scope("ell.reduce"):
+            y = jnp.where(actblk[0], y, sr.zero(y.dtype))
+            return axis_reduce(sr, y, COL_AXIS)[None]
 
     flat_args = [a for b in E.buckets for a in b]
     blocks = jax.shard_map(
@@ -616,22 +628,27 @@ def _ell_levels_step(E: EllParMat, x8, undiscovered8, ring: bool = False):
         W = x.shape[1]
         xpad = jnp.concatenate([x, jnp.zeros((1, W), jnp.int8)])
         y = jnp.zeros((lr, W), jnp.int8)
-        for bc, _bv, br in buckets:
+        for i, (bc, _bv, br) in enumerate(buckets):
             nb_, kb = bc.shape
             for s0, s1 in _bucket_row_slices(nb_, kb, W):
-                g = xpad[jnp.minimum(bc[s0:s1], lc)]  # [rows, kb, W] int8
-                yb = jnp.max(g, axis=1)  # [rows, W]
-                y = y.at[br[s0:s1]].max(yb, mode="drop")
-        y = jnp.minimum(y, ublk[0])  # only undiscovered rows fire
-        if ring:
-            # the carousel schedule: neighbor ppermute rotation over the
-            # 'c' mesh axis (COL_AXIS — same axis the pmax path reduces)
-            # instead of the fused all-reduce
-            from ..semiring import SELECT2ND_MAX
-            from .collectives import axis_ring_reduce
+                with _bucket_scope(i, "gather"):
+                    # [rows, kb, W] int8
+                    g = xpad[jnp.minimum(bc[s0:s1], lc)]
+                with _bucket_scope(i, "fold"):
+                    yb = jnp.max(g, axis=1)  # [rows, W]
+                with _bucket_scope(i, "scatter_rows"):
+                    y = y.at[br[s0:s1]].max(yb, mode="drop")
+        with jax.named_scope("ell.reduce"):
+            y = jnp.minimum(y, ublk[0])  # only undiscovered rows fire
+            if ring:
+                # the carousel schedule: neighbor ppermute rotation over
+                # the 'c' mesh axis (COL_AXIS — same axis the pmax path
+                # reduces) instead of the fused all-reduce
+                from ..semiring import SELECT2ND_MAX
+                from .collectives import axis_ring_reduce
 
-            return axis_ring_reduce(SELECT2ND_MAX, y, COL_AXIS)[None]
-        return lax.pmax(y, COL_AXIS)[None]
+                return axis_ring_reduce(SELECT2ND_MAX, y, COL_AXIS)[None]
+            return lax.pmax(y, COL_AXIS)[None]
 
     flat_args = [a for b in E.buckets for a in b]
     return jax.shard_map(
@@ -674,20 +691,24 @@ def _ell_parents_from_levels(E: EllParMat, levels_col, levels_row):
         want = jnp.where(
             lvl_r > 0, lvl_r - 1, jnp.int8(-2)
         )  # rows at level 0 (roots) or undiscovered never match
-        for bc, _bv, br in buckets:
+        for i, (bc, _bv, br) in enumerate(buckets):
             nb_, kb = bc.shape
             # int32 candidates: half the byte budget of the int8 step
             for s0, s1 in _bucket_row_slices(nb_, kb, W,
                                              budget_bytes=1 << 31):
-                safe = jnp.minimum(bc[s0:s1], lc)
-                g = cpad[safe]  # [rows, kb, W] int8 neighbor levels
-                brs = br[s0:s1]
-                wantb = want[jnp.minimum(brs, lr - 1)][:, None, :]
-                gid = (col_base + safe).astype(jnp.int32)[:, :, None]
-                cand = jnp.where(g == wantb, gid, -1)  # [rows, kb, W]
-                yb = jnp.max(cand, axis=1)  # [rows, W]
-                y = y.at[brs].max(yb, mode="drop")
-        return lax.pmax(y, COL_AXIS)[None]
+                with _bucket_scope(i, "gather"):
+                    safe = jnp.minimum(bc[s0:s1], lc)
+                    g = cpad[safe]  # [rows, kb, W] int8 neighbor levels
+                with _bucket_scope(i, "fold"):
+                    brs = br[s0:s1]
+                    wantb = want[jnp.minimum(brs, lr - 1)][:, None, :]
+                    gid = (col_base + safe).astype(jnp.int32)[:, :, None]
+                    cand = jnp.where(g == wantb, gid, -1)  # [rows, kb, W]
+                    yb = jnp.max(cand, axis=1)  # [rows, W]
+                with _bucket_scope(i, "scatter_rows"):
+                    y = y.at[brs].max(yb, mode="drop")
+        with jax.named_scope("ell.reduce"):
+            return lax.pmax(y, COL_AXIS)[None]
 
     flat_args = [a for b in E.buckets for a in b]
     return jax.shard_map(

@@ -276,6 +276,10 @@ class DistVec:
     def realign(self, align: str) -> "DistVec":
         if align == self.align:
             return self
+        with jax.named_scope("vec.realign"):
+            return self._realign(align)
+
+    def _realign(self, align: str) -> "DistVec":
         grid = self.grid
         src_axis = self.axis_name()
         dst_pa = grid.pr if align == "row" else grid.pc
@@ -473,6 +477,10 @@ class DistMultiVec:
         along (ppermute/all_gather are shape-agnostic past the block dim)."""
         if align == self.align:
             return self
+        with jax.named_scope("vec.realign"):
+            return self._realign(align)
+
+    def _realign(self, align: str) -> "DistMultiVec":
         grid = self.grid
         src_axis = self.axis_name()
         dst_axis = ROW_AXIS if align == "row" else COL_AXIS

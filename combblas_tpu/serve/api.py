@@ -39,10 +39,27 @@ from concurrent.futures import Future
 
 from .. import obs
 from ..obs.recorder import FlightRecorder
+from ..obs.spans import NULL_SPAN
 from . import batcher
 from .faults import FaultInjector, InjectedFault
 from .scheduler import BackpressureError, Scheduler, ServeConfig, _bump
 from .slo import ErrorBudget
+
+
+
+def _split_parts(parts, start: float, end: float):
+    """The engine's ``(part, perf_counter at its end)`` boundaries as
+    ``(part, seconds)`` pairs that sum to ``end - start`` exactly: the
+    first part starts at the previous stage mark, the last one runs to
+    this stage's own mark.  None when there are none."""
+    if not parts:
+        return None
+    out, prev = [], start
+    for part, t in parts:
+        out.append([part, t - prev])
+        prev = t
+    out[-1][1] += end - prev
+    return out
 
 
 class Server:
@@ -1043,12 +1060,17 @@ class Server:
             if r.trace is not None:
                 r.trace.mark(stage0, now=t_pop)
         t_asm = t_exec = None
+        # the engine's parts of ``execute`` and the profiler's host
+        # annotations: only for a batch with a traced member
+        traced = obs.ENABLED and any(r.trace is not None for r in live)
+        parts = [] if traced else None
         try:
             self.faults.check("batch.assemble", kind=kind,
                               width=len(live))
-            sources = batcher.assemble(
-                live, self.config.lane_widths, record=toplevel
-            )
+            with obs.span("serve.assemble") if traced else NULL_SPAN:
+                sources = batcher.assemble(
+                    live, self.config.lane_widths, record=toplevel
+                )
             if toplevel:
                 # occupancy/batch accounting measures COALESCING, so
                 # retry sub-batches stay out of it (they are visible
@@ -1066,26 +1088,28 @@ class Server:
                 roots=tuple(r.root for r in live),
             )
             pm = self.engine.plan_misses
-            result = self.engine.execute(kind, sources)
+            result = self.engine.execute(kind, sources, parts)
             t_exec = time.perf_counter()
             plan_src = "cold" if self.engine.plan_misses > pm else "warm"
+            split = _split_parts(parts, t_asm, t_exec)
             for r in live:
                 if r.trace is not None:
-                    r.trace.mark("execute", now=t_exec)
+                    r.trace.mark("execute", now=t_exec, parts=split)
                     r.trace.annotate(
                         width=len(sources), plan=plan_src,
                         version=self.engine.version_id,
                     )
             self.faults.check("batch.scatter", kind=kind)
-            self.completed += batcher.scatter(
-                live, result,
-                on_timeout=self._on_exec_timeout,
-                on_ok=self._slo_ok if self.slo is not None else None,
-                on_error=(
-                    self._on_lane_error
-                    if self.slo is not None else None
-                ),
-            )
+            with obs.span("serve.scatter") if traced else NULL_SPAN:
+                self.completed += batcher.scatter(
+                    live, result,
+                    on_timeout=self._on_exec_timeout,
+                    on_ok=self._slo_ok if self.slo is not None else None,
+                    on_error=(
+                        self._on_lane_error
+                        if self.slo is not None else None
+                    ),
+                )
             if rec is not None:
                 now = time.perf_counter()
                 rec.record(
@@ -1103,12 +1127,21 @@ class Server:
                 breaker.record_success(time.monotonic(), kind)
         except Exception as e:  # failure touches THIS batch only
             now = time.perf_counter()
+            # what the failed attempt spent past its last whole part is
+            # the part "failed", so parts still sum to the stage
+            split = None
+            if traced:
+                t_last = t_exec or t_asm or t_pop  # the last mark made
+                split = _split_parts(
+                    [p for p in parts if p[1] > t_last]
+                    + [("failed", now)], t_last, now,
+                )
             for r in live:
                 if r.trace is not None:
                     # however far the batch got, the elapsed time was
                     # execution-side work: charge it there so retry
                     # marks stay telescoping
-                    r.trace.mark("execute", now=now)
+                    r.trace.mark("execute", now=now, parts=split)
             if rec is not None:
                 rec.record(
                     "serve.batch", query=kind, requests=len(live),

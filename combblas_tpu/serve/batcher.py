@@ -141,6 +141,7 @@ def scatter(requests: list[Request], result: dict,
     completed."""
     now = time.monotonic() if now is None else now
     done = 0
+    built = 0  # lanes handed out (settled or not)
     for k, req in enumerate(requests):
         if req.future.done():
             continue
@@ -149,8 +150,11 @@ def scatter(requests: list[Request], result: dict,
                    on_timeout)
             continue
         try:
-            # lane COPIES, not views: a retained view would pin the
-            # whole [n, W] batch buffer for one request's lifetime
+            # a lane is COPIED where the result's lane axis is strided
+            # (row-major [n, W]); where the readback hands the result
+            # over lane-major, ``ascontiguousarray`` returns a VIEW,
+            # which pins the whole [n, W] buffer for the request's
+            # lifetime (``serve.scatter.views`` counts those)
             lane = {
                 key: (
                     np.ascontiguousarray(val[..., k])
@@ -158,6 +162,7 @@ def scatter(requests: list[Request], result: dict,
                 )
                 for key, val in result.items()
             }
+            built += 1
             if settle(req.future, result=lane):
                 done += 1
                 obs.count("serve.requests", kind=req.kind, status="ok")
@@ -178,6 +183,20 @@ def scatter(requests: list[Request], result: dict,
                 req.trace.finish(status="error", stage="scatter")
             if on_error is not None:
                 on_error(req)
+    if obs.ENABLED and built:
+        # once a batch: a result whose lane 0 is contiguous gave every
+        # request a view, any other a copy of one lane's bytes
+        copied = views = 0
+        for val in result.values():
+            if isinstance(val, np.ndarray):
+                lane0 = val[..., 0]
+                if lane0.flags.c_contiguous:
+                    views += built
+                else:
+                    copied += built * lane0.nbytes
+        kind = requests[0].kind
+        obs.count("serve.scatter.copied_bytes", copied, kind=kind)
+        obs.count("serve.scatter.views", views, kind=kind)
     return done
 
 

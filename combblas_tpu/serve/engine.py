@@ -41,6 +41,7 @@ import time
 import numpy as np
 
 from .. import obs
+from ..obs.spans import NULL_SPAN
 from ..models import PAD_ROOT
 
 #: Query kinds the engine can build plans for.  ``"propagate"`` (round
@@ -49,6 +50,40 @@ from ..models import PAD_ROOT
 #: (models/propagate.py) — it needs a feature table
 #: (``from_coo(features=...)``).
 KINDS = ("bfs", "sssp", "pagerank", "bc", "propagate")
+
+
+
+class _PartClock:
+    """``with clock(part):`` around one part of ``execute``: appends
+    ``(part, perf_counter at its end)`` to the caller's list and writes
+    a ``serve.execute.<part>`` annotation on the profiler's clock."""
+
+    __slots__ = ("parts", "_part", "_ann")
+
+    def __init__(self, parts: list):
+        self.parts = parts
+
+    def __call__(self, part: str):
+        self._part = part
+        return self
+
+    def __enter__(self):
+        import jax
+
+        self._ann = jax.profiler.TraceAnnotation(
+            "serve.execute." + self._part
+        )
+        self._ann.__enter__()
+
+    def __exit__(self, *exc):
+        self._ann.__exit__(*exc)
+        self.parts.append((self._part, time.perf_counter()))
+        return False
+
+
+def _no_clock(part: str):
+    """What ``execute`` enters when nobody asked for its parts."""
+    return NULL_SPAN
 
 
 @dataclasses.dataclass
@@ -60,6 +95,7 @@ class _Plan:
     fn: object  # jitted callable
     traces: int = 0  # incremented at TRACE time (retrace counter)
     executions: int = 0
+    lower: object = None  # sources -> the same program, lowered
 
 
 @dataclasses.dataclass
@@ -779,13 +815,29 @@ class GraphEngine:
         else:
             raise ValueError(f"unknown query kind {kind!r}")
 
+        # the program's name in the compiler's text, the profiler's
+        # ``XLA Modules`` line and the persistent-cache key: what it
+        # is, not ``impl`` for every plan
+        impl.__name__ = impl.__qualname__ = f"serve_{kind}_w{width}"
         jitted = jax.jit(impl)
         # operands resolved at CALL time from the current GraphVersion
         # (not closed over): this is what lets swap() replace the graph
         # under a surviving plan cache — same-shape operands hit the
         # jit signature cache, different shapes retrace exactly once
         plan.fn = lambda sources: jitted(*self._plan_args(kind), sources)
+        plan.lower = lambda sources: jitted.lower(
+            *self._plan_args(kind), sources
+        )
         return plan
+
+    def _publish_op_names(self, plan: "_Plan", sources) -> None:
+        """Telemetry on, at warm-up: the compiled program's
+        ``{instruction: op_name}`` table (``obs.opnames``), so a device
+        trace's operations can be laid under the named scopes.  The
+        program is already traced and compiled: lowering it again hits
+        JAX's trace cache (no retrace is counted) and compiling it
+        fetches it from the persistent cache where one is kept."""
+        obs.opnames.publish(plan.lower(sources).compile().as_text())
 
     def _resolve_spmm_backend(self) -> str:
         """The op="spmm" tuner resolution, ONCE per engine (the plan
@@ -888,10 +940,11 @@ class GraphEngine:
                 with self._exec_lock, obs.span(
                     "serve.warmup", kind=kind, width=int(w)
                 ):
-                    res = self.plan(kind, w).fn(
-                        np.full(int(w), PAD_ROOT, np.int32)
-                    )
-                    jax.block_until_ready(res)
+                    plan = self.plan(kind, w)
+                    pads = np.full(int(w), PAD_ROOT, np.int32)
+                    jax.block_until_ready(plan.fn(pads))
+                    if obs.ENABLED:
+                        self._publish_op_names(plan, pads)
                 out[(kind, int(w))] = time.perf_counter() - t0
         return out
 
@@ -906,60 +959,89 @@ class GraphEngine:
     # -- execution ---------------------------------------------------------
 
     def _lanes_to_global(self, blocks) -> np.ndarray:
-        """[pa, L, W] device blocks -> [n, W] host array (the engine's
-        device->host sync) — via ``DistMultiVec.to_global`` so the
-        block-layout knowledge stays in exactly one place."""
+        """[pa, L, W] blocks (already on the host) -> [n, W] — via
+        ``DistMultiVec.to_global`` so the block-layout knowledge stays
+        in exactly one place."""
         from ..parallel.vec import DistMultiVec
 
         return DistMultiVec(
             blocks=blocks, length=self.nrows, align="row", grid=self.grid
         ).to_global()
 
-    def execute(self, kind: str, sources) -> dict:
+    #: Result names of each kind's device blocks, in program order (a
+    #: trailing iteration count follows them for every kind but "bc").
+    _RESULT_KEYS = {
+        "bfs": ("parents", "levels"),
+        "sssp": ("dist",),
+        "pagerank": ("ranks",),
+        "bc": ("scores",),
+    }
+
+    def execute(self, kind: str, sources, parts: list | None = None
+                ) -> dict:
         """Run one batch: ``sources`` is the int32 lane vector (pad
         slots = ``PAD_ROOT``). Returns a dict of host arrays with the
         lane axis LAST (what ``batcher.scatter`` slices per request).
+
+        ``parts``: a list the caller owns (the worker passes one when a
+        member of the batch is traced; engines are shared, so nothing is
+        kept here).  With telemetry on, the boundaries of the work are
+        appended to it as ``(part, perf_counter at its end)`` for
+        ``launch`` (``jnp.asarray`` + dispatch until the plan returns),
+        ``device`` (``block_until_ready``: only a traced batch waits
+        apart from its readback), ``readback`` (``np.asarray`` of every
+        result block) and ``to_global`` (reshape, slice, ``int(niter)``),
+        each also a ``serve.execute.<part>`` annotation on the
+        profiler's clock.  Without ``parts``, or with telemetry off, no
+        clock is read and nothing waits before the readback.
         """
         import jax.numpy as jnp
 
         sources = np.asarray(sources, np.int32)
         W = sources.shape[0]
         plan = self.plan(kind, W)
+        traced = parts is not None and obs.ENABLED
+        mark = _PartClock(parts) if traced else _no_clock
         with self._exec_lock, obs.span("serve.batch", kind=kind, width=W):
-            res = plan.fn(jnp.asarray(sources))
-            plan.executions += 1
-            # "batch_niter" is BATCH metadata (the max iteration count
-            # over all lanes, pad included), not a per-request fact: a
-            # request's own value would vary with its batch-mates
-            if kind == "bfs":
-                p, l, niter = res
-                return {
-                    "parents": self._lanes_to_global(p),
-                    "levels": self._lanes_to_global(l),
-                    "batch_niter": int(niter),
-                }
-            if kind == "sssp":
-                d, niter = res
-                return {
-                    "dist": self._lanes_to_global(d),
-                    "batch_niter": int(niter),
-                }
-            if kind == "pagerank":
-                x, niter = res
-                return {
-                    "ranks": self._lanes_to_global(x),
-                    "batch_niter": int(niter),
-                }
+            with mark("launch"):
+                res = plan.fn(jnp.asarray(sources))
+                plan.executions += 1
+            if traced:
+                import jax
+
+                with mark("device"):
+                    jax.block_until_ready(res)
             if kind == "propagate":
                 # [Fp, W] replicated features — strip the pow2 pad
                 # lanes back to the true feature dim; lane axis stays
                 # LAST (the batcher's scatter contract)
                 from ..parallel.spgemm import host_value
 
-                feats = host_value(res)
-                return {"features": feats[: self._version.feat_dim]}
-            # bc: per-lane Brandes dependency vectors
-            return {"scores": self._lanes_to_global(res)}
+                with mark("readback"):
+                    feats = host_value(res)
+                with mark("to_global"):
+                    return {"features": feats[: self._version.feat_dim]}
+            keys = self._RESULT_KEYS[kind]
+            # "batch_niter" is BATCH metadata (the max iteration count
+            # over all lanes, pad included), not a per-request fact: a
+            # request's own value would vary with its batch-mates
+            blocks, niter = (
+                ((res,), None) if kind == "bc" else (res[:-1], res[-1])
+            )
+            with mark("readback"):
+                host = [np.asarray(b) for b in blocks]
+            if obs.ENABLED:
+                obs.count(
+                    "serve.readback.bytes",
+                    sum(h.nbytes for h in host), kind=kind, width=W,
+                )
+            with mark("to_global"):
+                out = {
+                    k: self._lanes_to_global(h) for k, h in zip(keys, host)
+                }
+                if niter is not None:
+                    out["batch_niter"] = int(niter)
+                return out
 
     def stats(self) -> dict:
         # _plans_lock only: polling stats during a long batch must not

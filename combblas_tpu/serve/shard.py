@@ -1696,12 +1696,15 @@ class ShardedEngine:
             obs.observe("serve.shard.wire_quant_err", err)
         return p
 
-    def execute(self, kind: str, sources) -> dict:
+    def execute(self, kind: str, sources, parts: list | None = None
+                ) -> dict:
         """One batch, bulk-synchronously across slices; on a slice
         failure mid-batch the whole batch replays after the heal
         (replay is idempotent: a fresh epoch re-seeds every slice's
         resident state — including the respawned one's, which is how
-        a StaleEpochError report is resolved)."""
+        a StaleEpochError report is resolved).  ``parts`` (the worker's
+        list for ``GraphEngine.execute``'s parts) stays empty: the
+        hops have their own ``serve.shard.*`` histograms."""
         last_exc = None
         for attempt in range(self.exec_retries + 1):
             if attempt:

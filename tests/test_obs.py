@@ -1,6 +1,6 @@
 """Telemetry subsystem (combblas_tpu/obs): registry, spans, JSONL
-round-trip, multihost merge, zero-cost-when-disabled, and the obs_smoke
-bench trace against the documented schema (docs/observability.md)."""
+round-trip, multihost merge and zero-cost-when-disabled
+(docs/observability.md)."""
 
 import json
 import os
@@ -13,13 +13,7 @@ import numpy as np
 import pytest
 
 from combblas_tpu import obs
-from combblas_tpu.models.bfs import (
-    _bfs_level_step,
-    _global_ids,
-    bfs,
-    bfs_levels_instrumented,
-    clear_bfs_caches,
-)
+from combblas_tpu.models.bfs import clear_bfs_caches
 from combblas_tpu.parallel.grid import Grid
 from combblas_tpu.parallel.spmat import SpParMat
 from combblas_tpu.semiring import SELECT2ND_MAX
@@ -98,61 +92,50 @@ def test_timers_shim_still_accumulates_when_obs_disabled():
 # --- zero-cost-when-disabled ------------------------------------------------
 
 
-def _bare_levels(A, source, iters):
-    """The instrumented BFS's exact step loop with NO obs calls — the
-    no-obs baseline for the overhead comparison."""
-    grid = A.grid
-    n = A.nrows
-    row_gids = _global_ids(grid, grid.pr, grid.local_rows(n), n, "row")
-    col_gids = _global_ids(
-        grid, grid.pc, grid.local_cols(A.ncols), A.ncols, "col"
-    )
-    parents = jnp.where(row_gids == source, jnp.int32(source), -1)
-    levels = jnp.where(row_gids == source, 0, -1).astype(jnp.int32)
-    x = jnp.where(col_gids == source, jnp.int32(source), -1)
-    for hop in range(iters):
-        parents, levels, x, nnew = _bfs_level_step(
-            SELECT2ND_MAX, A, parents, levels, x, row_gids, jnp.int32(hop)
-        )
-        if int(nnew) == 0:
-            break
-    return parents
-
-
 def test_disabled_instrumentation_is_free(rng):
-    A, d = _graph(rng, n=64)
-    assert not obs.ENABLED
-    # warm both paths (compile once, identical program underneath)
-    p1, l1, n1 = bfs_levels_instrumented(A, 0)
-    _bare_levels(A, 0, 64)
-    # 1) no bookkeeping: registry AND span log stay empty
-    assert obs.registry.empty()
-    assert obs._spans.empty()
-    # parity with the one-launch kernel
-    p2, l2, n2 = bfs(A, 0)
-    np.testing.assert_array_equal(
-        np.asarray(p1.to_global()), np.asarray(p2.to_global())
-    )
-    assert n1 == int(n2)
+    """The served batch path with telemetry off against the bare plan
+    call and readback it wraps: nothing recorded, and under 5% of wall
+    time between them (interleaved, min-filtered: a load spike cannot
+    land on one side only)."""
+    from combblas_tpu.serve import GraphEngine
 
-    # 2) <5% wall-time overhead vs the uninstrumented twin loop. Both
-    #    drive the same compiled step program, so the delta IS the guard
-    #    cost. Samples are INTERLEAVED (bare, instr, bare, instr, ...)
-    #    and min-filtered so a CPU load spike (parallel test runners)
-    #    cannot land on only one side of the comparison.
+    n = 64
+    r = rng.integers(0, n, 400)
+    c = rng.integers(0, n, 400)
+    engine = GraphEngine.from_coo(
+        Grid.make(2, 2), np.concatenate([r, c]), np.concatenate([c, r]),
+        n, kinds=("bfs",),
+    )
+    sources = np.arange(16, dtype=np.int32)
+    engine.warmup(kinds=("bfs",), widths=(16,))
+    plan = engine.plan("bfs", 16)
+
+    def bare():
+        p, l, niter = plan.fn(jnp.asarray(sources))
+        return (engine._lanes_to_global(np.asarray(p)),
+                engine._lanes_to_global(np.asarray(l)), int(niter))
+
+    def served():
+        return engine.execute("bfs", sources)
+
+    assert not obs.ENABLED
+    out = served()
+    np.testing.assert_array_equal(bare()[1], out["levels"])
+
     def sample(fn):
         t0 = time.perf_counter()
-        for _ in range(3):
+        for _ in range(5):
             fn()
         return time.perf_counter() - t0
 
-    bare_t, instr_t = [], []
+    bare_t, served_t = [], []
     for _ in range(9):
-        bare_t.append(sample(lambda: _bare_levels(A, 0, 64)))
-        instr_t.append(sample(lambda: bfs_levels_instrumented(A, 0)))
-    t_bare, t_instr = min(bare_t), min(instr_t)
-    assert t_instr <= t_bare * 1.05 + 0.005, (t_instr, t_bare)
-    assert obs.registry.empty()  # still nothing recorded
+        bare_t.append(sample(bare))
+        served_t.append(sample(served))
+    assert min(served_t) <= min(bare_t) * 1.05 + 0.005, (
+        min(served_t), min(bare_t))
+    assert obs.registry.empty() and obs._spans.empty()
+    assert not obs.trace.records()
 
 
 def test_windowed_dot_counters_gated(rng):
@@ -425,27 +408,6 @@ def test_psum_counters_device_aggregation(grid_shape):
 # --- instrumented hot paths -------------------------------------------------
 
 
-def test_instrumented_bfs_records_per_hop_frontier(rng, tmp_path):
-    A, d = _graph(rng, n=48)
-    path = str(tmp_path / "bfs.jsonl")
-    obs.enable(jsonl_path=path, install_hooks=False)
-    parents, levels, niter = bfs_levels_instrumented(A, 0)
-    obs.dump_jsonl()
-    recs = obs.parse_jsonl(path)
-    hops = [r for r in recs if r["kind"] == "span" and r["name"] == "bfs.hop"]
-    assert len(hops) == niter
-    curve = []
-    for h in hops:
-        ev = [e for e in h["events"] if e["name"] == "frontier"]
-        assert len(ev) == 1
-        curve.append(ev[0]["nnz"])
-    # the frontier curve sums to the discovered set minus the source
-    assert sum(curve) == int((np.asarray(parents.to_global()) >= 0).sum()) - 1
-    # dispatch counters rode along (trace-or-call counts, > 0 either way)
-    assert obs.registry.get_counter("spmv.dispatch",
-                                    kernel="dist_spmv_masked") > 0
-
-
 def test_spgemm_and_redistribute_metrics(rng):
     from combblas_tpu.parallel.spgemm import spgemm
     from combblas_tpu.semiring import PLUS_TIMES
@@ -510,63 +472,6 @@ def test_bfs_caches_bounded_cleared_and_exported():
     assert snap["cache.bfs.gid_blocks.maxsize"] == 16
     clear_bfs_caches()
     assert bfs_mod._iota_operand.cache_info().currsize == 0
-
-
-# --- the smallest bench entrypoint, parsed against the schema ---------------
-
-
-def test_obs_smoke_bench_trace_matches_schema(tmp_path):
-    sys.path.insert(
-        0,
-        os.path.join(os.path.dirname(os.path.abspath(__file__)), "..",
-                     "benchmarks"),
-    )
-    import obs_smoke
-
-    out = str(tmp_path / "smoke.jsonl")
-    try:
-        path = obs_smoke.run(
-            scale=6, edgefactor=8, out_path=out, grid_shape=(2, 2),
-            cache_dir=str(tmp_path / "cache"),
-        )
-    finally:
-        # undo the smoke run's global compile-cache redirection —
-        # including the idempotence guard's committed dir, or a later
-        # same-process enable_compile_cache() would refuse to run
-        from combblas_tpu.utils import compile_cache as _cc
-
-        _cc._reset_for_tests()
-        jax.config.update("jax_compilation_cache_dir", None)
-    recs = obs.parse_jsonl(path)  # schema-validates every line
-    agg = obs.aggregate(recs)
-    # per-hop BFS spans with frontier-nnz events
-    hops = [r for r in recs if r["kind"] == "span" and r["name"] == "bfs.hop"]
-    assert hops
-    assert all(
-        any(e["name"] == "frontier" and "nnz" in e for e in h["events"])
-        for h in hops
-    )
-    # SpGEMM fill-in counters (symbolic + realized under DEVICE_SYNC)
-    assert agg["counters"]["spgemm.symbolic_fill_slots"] > 0
-    assert agg["counters"]["spgemm.realized_nnz"] > 0
-    # redistribute drop accounting
-    assert "redistribute.dropped" in agg["counters"]
-    # compile-cache hit/miss counters (values platform-dependent; the
-    # counters themselves are part of the documented trace)
-    assert "compile_cache.hits" in agg["counters"]
-    assert "compile_cache.misses" in agg["counters"]
-    # BFS lru-cache gauges exported via the provider
-    assert any(k.startswith("cache.bfs.") for k in agg["gauges"])
-    # round 15: the serve-path request traces ride in the same dump —
-    # the smallest end-to-end latency-decomposition trace
-    traces = [r for r in recs if r["kind"] == "trace"]
-    assert traces and all(
-        r["name"] == "serve.request" for r in traces
-    )
-    for r in traces:
-        assert abs(
-            sum(st["s"] for st in r["stages"]) - r["wall_s"]
-        ) < 1e-6
 
 
 def test_round11_dynamic_counters_gated(rng):
