@@ -338,11 +338,11 @@ def bench_sssp_batch():
     srcs = jnp.asarray(
         rng.choice(np.flatnonzero(deg > 0), size=W, replace=False), jnp.int32
     )
-    dist, it = sssp_batch(E, srcs)
+    dist, _, it = sssp_batch(E, srcs)
     jax.block_until_ready(dist.blocks)
     time.sleep(3)
     t0 = time.perf_counter()
-    dist, it = sssp_batch(E, srcs)
+    dist, _, it = sssp_batch(E, srcs)
     _ = float(jax.device_get(dist.blocks[0, 0, 0]))
     dt = time.perf_counter() - t0
     niter = int(jax.device_get(it))

@@ -65,67 +65,107 @@ def _sssp_impl(A: SpParMat, source):
     return db, niter
 
 
+#: The ``jax.named_scope`` names of the served batch program
+#: (``_sssp_batch_impl``), outermost first; inside ``sssp.round`` and
+#: ``sssp.parents`` the sweep's own ``ell.bucket<i>`` / ``gather`` /
+#: ``fold`` / ``scatter_rows`` and ``vec.realign``.  Trace-time metadata
+#: only: the device trace's per-scope and per-round times are read by
+#: these names (docs/observability.md "Named scopes").
+SSSP_SCOPES = (
+    "sssp.init",
+    "sssp.round",  # the whole while loop; one iteration = one round
+    "sssp.parents",  # the one sweep after the fixed point
+)
+
+
 def sssp_batch(E, sources):
-    """Eager wrapper over ``_sssp_batch_impl`` (plain-outputs law)."""
+    """Eager wrapper over ``_sssp_batch_impl`` (plain-outputs law):
+    ``(dist, parents, rounds)``, the first two row-aligned
+    ``DistMultiVec``s."""
     from ..parallel.vec import DistMultiVec
 
-    blocks, niter = _sssp_batch_impl(E, sources)
-    return (
-        DistMultiVec(
+    dist, parents, niter = _sssp_batch_impl(E, sources)
+
+    def mk(blocks):
+        return DistMultiVec(
             blocks=blocks, length=E.nrows, align="row", grid=E.grid
-        ),
-        niter,
-    )
+        )
+
+    return mk(dist), mk(parents), niter
 
 
 @jax.jit
 def _sssp_batch_impl(E, sources):
-    """Multi-source Bellman-Ford: distances from W sources in ONE program.
+    """Multi-source Bellman-Ford: Graph500 kernel 3's answer for W
+    sources in ONE program.
 
     ``E``: weighted EllParMat (entry (i,j) = w(j->i), non-negative).
-    ``sources``: [W] int32. Returns (row-aligned PLAIN [pr, lr, W] blocks (wrapper rebuilds the DistMultiVec) of
-    distances — +inf where unreachable — and the iteration count).
+    ``sources``: [W] int32. Returns row-aligned PLAIN [pr, lr, W] blocks
+    (the wrapper rebuilds the DistMultiVecs) of distances (+inf where
+    unreachable) and of shortest-path parents (a root its own, -1 where
+    unreachable), and the round count, the round that changed nothing
+    included.
 
     The multi-root amortization of the batched BFS applied to SSSP: the
     chip's gather cost is per-INDEX with payload lanes nearly free, so W
     Bellman-Ford chains advance for ~the cost of one (compare the
     single-source loop above, which pays the full gather per source).
+    After the fixed point one more sweep picks each reached row's parent
+    (``_ell_minplus_parents``): a neighbour ``j`` with ``d[j] + w ==
+    d[row]`` closes a shortest path; the pick is the max id among the
+    strictly nearer of them, and for a row that has none, among those as
+    near that settled in an earlier round, which the loop records (zero
+    and absorbed weights cannot close a cycle).
     Reference: ``Applications/SSSP`` role; the reference has no batched
     variant — this is TPU-native surface.
     """
-    from ..parallel.ellmat import dist_spmv_ell_multi
+    from ..parallel.ellmat import _ell_minplus_parents, dist_spmv_ell_multi
     from ..parallel.vec import DistMultiVec
+    from . import PAD_ROOT
 
     grid = E.grid
     n = E.nrows
     dtype = E.dtype
     inf = MIN_PLUS.zero(dtype)
 
-    gids = DistVec.iota(grid, n, jnp.int32, align="row").blocks  # [pr, lr]
-    # models.PAD_ROOT lanes are inert padding (all-inf distances — the
-    # serve batcher's lane padding); same guard as _bfs_batch_impl
-    from . import PAD_ROOT
-
-    live = sources[None, None, :] != PAD_ROOT
-    d0 = jnp.where(
-        (gids[..., None] == sources[None, None, :]) & live,
-        jnp.zeros((), dtype), inf,
-    )
-
     def mk(blocks):
         return DistMultiVec(blocks=blocks, length=n, align="row", grid=grid)
 
+    with jax.named_scope("sssp.init"):
+        gids = DistVec.iota(grid, n, jnp.int32, align="row").blocks  # [pr, lr]
+        src = sources.astype(jnp.int32)[None, None, :]
+        # models.PAD_ROOT lanes are inert padding (all-inf distances,
+        # all -1 parents: the serve batcher's lane padding); same guard
+        # as _bfs_batch_impl
+        is_root = (gids[..., None] == src) & (src != PAD_ROOT)
+        d0 = jnp.where(is_root, jnp.zeros((), dtype), inf)
+
     def cond(state):
-        _, changed, it = state
+        _, _, changed, it = state
         return changed & (it < n)
 
     def step(state):
-        db, _, it = state
+        db, settled, _, it = state
         relaxed = dist_spmv_ell_multi(MIN_PLUS, E, mk(db))
         nb = jnp.minimum(db, relaxed.blocks)
-        return nb, jnp.any(nb != db), it + 1
+        lowered = nb != db
+        # the round that last lowered each distance: what the parents
+        # pass orders equal distances by
+        settled = jnp.where(lowered, it + 1, settled)
+        return nb, settled, jnp.any(lowered), it + 1
 
-    db, _, niter = jax.lax.while_loop(
-        cond, step, (d0, jnp.bool_(True), jnp.int32(0))
-    )
-    return db, niter
+    # the whole loop, condition included, is one scope: a round is one
+    # iteration of it in the device trace
+    with jax.named_scope("sssp.round"):
+        db, settled, _, niter = jax.lax.while_loop(
+            cond, step,
+            (d0, jnp.zeros(d0.shape, jnp.int32), jnp.bool_(True),
+             jnp.int32(0)),
+        )
+
+    with jax.named_scope("sssp.parents"):
+        parents = _ell_minplus_parents(E, db, settled)
+        # roots are their own parents; unreached rows stay -1
+        parents = jnp.where(is_root, src, parents)
+        parents = jnp.where(db < inf, parents, -1)
+    return db, parents, niter

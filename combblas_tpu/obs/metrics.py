@@ -612,6 +612,15 @@ name                                   kind       meaning
                                                   the device chose
                                                   (label ``mode`` =
                                                   dense / skipped)
+``serve.sssp.rounds``                  counter    Bellman-Ford rounds
+                                                  of served SSSP
+                                                  batches, the round
+                                                  that changed nothing
+                                                  included (label
+                                                  ``width``)
+``serve.sssp.batches``                 counter    served SSSP batches
+                                                  executed (label
+                                                  ``width``)
 ``obs.provider_errors``                counter    broken pull-provider
                                                   callbacks (caught)
 ``serve.bench.*``                      gauge      bench-scenario

@@ -832,6 +832,85 @@ def _ell_levels_step(E: EllParMat, x8, undiscovered8, ring: bool = False):
     )(x8, undiscovered8, *flat_args)
 
 
+def _ell_pick_parents(E: EllParMat, table_col, want_row, match, *, pad,
+                      width: int, budget_bytes: int = 1 << 32,
+                      row_active=None):
+    """The one parents sweep both traversals end with: for every (row,
+    lane) the max GLOBAL id of an in-neighbour whose gathered entry
+    ``match``es the row's own, -1 where none does.
+
+    table_col: [pc, lc, F] col-aligned, what a neighbour is known by
+    (``pad`` in the inert pad slot's row); want_row: [pr, lr, G]
+    row-aligned, what the row asks for.  ``match(g, w, want)`` takes the
+    gathered entries ``g`` [rows, kb, F], the slots' weights ``w`` [rows,
+    kb] and the rows' own ``want`` [rows, 1, G] and returns the hits
+    [rows, kb, width].  ONE gather pass over the matrix, ``budget_bytes``
+    of gathered payload a row slice; returns [pr, lr, width] int32.
+
+    row_active: [pr, lr, width] bool row-aligned, or None.  Given, only
+    its (row, lane)s want a pick: a degree class with no such row is
+    skipped on the device (``_active_rows``, ``_class_sweep``), and the
+    others may come back with a pick or without one.
+    """
+    lr, lc = E.local_rows, E.local_cols
+    nb = len(E.buckets)
+
+    def body(tcb, wrb, *rest):
+        mask, flat = (None, rest) if row_active is None else (
+            rest[0], rest[1:])
+        buckets = [
+            tuple(a[0, 0] for a in flat[3 * i : 3 * i + 3]) for i in range(nb)
+        ]
+        t_c = tcb[0]  # [lc, F]
+        F = t_c.shape[1]
+        cpad = jnp.concatenate([t_c, jnp.full((1, F), pad, t_c.dtype)])
+        want = wrb[0]  # [lr, G]
+        j = lax.axis_index(COL_AXIS)
+        col_base = j * lc
+        y = jnp.full((lr, width), -1, jnp.int32)
+        payload = F * jnp.dtype(t_c.dtype).itemsize
+
+        def sweep(i, bc, bv, br, y):
+            nb_, kb = bc.shape
+            for s0, s1 in _bucket_row_slices(nb_, kb, payload,
+                                             budget_bytes=budget_bytes):
+                with _bucket_scope(i, "gather"):
+                    safe = jnp.minimum(bc[s0:s1], lc)
+                    g = cpad[safe]  # [rows, kb, F] neighbours' entries
+                with _bucket_scope(i, "fold"):
+                    brs = br[s0:s1]
+                    wantb = want[jnp.minimum(brs, lr - 1)][:, None, :]
+                    gid = (col_base + safe).astype(jnp.int32)[:, :, None]
+                    cand = jnp.where(match(g, bv[s0:s1], wantb), gid, -1)
+                    yb = jnp.max(cand, axis=1)  # [rows, width]
+                with _bucket_scope(i, "scatter_rows"):
+                    y = y.at[brs].max(yb, mode="drop")
+            return y
+
+        idle = [None] * nb
+        active = None if mask is None else _active_rows(
+            mask[0], jnp.ones((width,), jnp.bool_)
+        )
+        if active is not None:
+            y = _tile_varying(y)
+            idle = [_class_idle(i, br, active)
+                    for i, (_, _, br) in enumerate(buckets)]
+        for i, bucket in enumerate(buckets):
+            y, _ = _class_sweep(i, bucket, idle[i], partial(sweep, i), y)
+        with jax.named_scope("ell.reduce"):
+            return lax.pmax(y, COL_AXIS)[None]
+
+    flat_args = [a for b in E.buckets for a in b]
+    masks = () if row_active is None else (row_active,)
+    return jax.shard_map(
+        body,
+        mesh=E.grid.mesh,
+        in_specs=(P(COL_AXIS), P(ROW_AXIS)) + (P(ROW_AXIS),) * len(masks)
+        + (TILE_SPEC,) * (3 * nb),
+        out_specs=P(ROW_AXIS),
+    )(table_col, want_row, *masks, *flat_args)
+
+
 @partial(jax.jit, static_argnames=())
 def _ell_parents_from_levels(E: EllParMat, levels_col, levels_row):
     """Parent reconstruction: for every (row, root) pick the max-id
@@ -842,49 +921,90 @@ def _ell_parents_from_levels(E: EllParMat, levels_col, levels_row):
     matrix — the whole-search parent information the compact BFS loop
     deliberately did not carry.
     """
-    lr, lc = E.local_rows, E.local_cols
-    nb = len(E.buckets)
+    want = jnp.where(
+        levels_row > 0, levels_row - 1, jnp.int8(-2)
+    )  # rows at level 0 (roots) or undiscovered never match
+    return _ell_pick_parents(
+        E, levels_col, want, lambda g, _w, wantb: g == wantb,
+        pad=-1, width=levels_col.shape[-1],
+        # int32 candidates: half the byte budget of the int8 step
+        budget_bytes=1 << 31,
+    )
 
-    def body(lcb, lrb, *flat):
-        buckets = [
-            tuple(a[0, 0] for a in flat[3 * i : 3 * i + 3]) for i in range(nb)
-        ]
-        lvl_c = lcb[0]  # [lc, W] int8
-        W = lvl_c.shape[1]
-        lvl_r = lrb[0]  # [lr, W] int8
-        cpad = jnp.concatenate([lvl_c, jnp.full((1, W), -1, jnp.int8)])
-        j = lax.axis_index(COL_AXIS)
-        col_base = j * lc
-        y = jnp.full((lr, W), -1, jnp.int32)
-        want = jnp.where(
-            lvl_r > 0, lvl_r - 1, jnp.int8(-2)
-        )  # rows at level 0 (roots) or undiscovered never match
-        for i, (bc, _bv, br) in enumerate(buckets):
-            nb_, kb = bc.shape
-            # int32 candidates: half the byte budget of the int8 step
-            for s0, s1 in _bucket_row_slices(nb_, kb, W,
-                                             budget_bytes=1 << 31):
-                with _bucket_scope(i, "gather"):
-                    safe = jnp.minimum(bc[s0:s1], lc)
-                    g = cpad[safe]  # [rows, kb, W] int8 neighbor levels
-                with _bucket_scope(i, "fold"):
-                    brs = br[s0:s1]
-                    wantb = want[jnp.minimum(brs, lr - 1)][:, None, :]
-                    gid = (col_base + safe).astype(jnp.int32)[:, :, None]
-                    cand = jnp.where(g == wantb, gid, -1)  # [rows, kb, W]
-                    yb = jnp.max(cand, axis=1)  # [rows, W]
-                with _bucket_scope(i, "scatter_rows"):
-                    y = y.at[brs].max(yb, mode="drop")
-        with jax.named_scope("ell.reduce"):
-            return lax.pmax(y, COL_AXIS)[None]
 
-    flat_args = [a for b in E.buckets for a in b]
-    return jax.shard_map(
-        body,
-        mesh=E.grid.mesh,
-        in_specs=(P(COL_AXIS), P(ROW_AXIS)) + (TILE_SPEC,) * (3 * nb),
-        out_specs=P(ROW_AXIS),
-    )(levels_col, levels_row, *flat_args)
+@jax.jit
+def _ell_minplus_parents(E: EllParMat, dist_row, settled_row):
+    """Shortest-path parents from a min-plus fixed point: for every (row,
+    lane) the max-id in-neighbour ``j`` that closes a shortest path,
+    ``d[j] + w(j, row) == d[row]``, and is strictly NEARER, ``d[j] <
+    d[row]``; for a row that has none, the max-id one that is as near and
+    settled in an earlier round.
+
+    dist_row: [pr, lr, W] float32 row-aligned distances (+inf
+    unreached); settled_row: int32, the round that last lowered each (0
+    where none did: a root).  ONE sweep of the weighted matrix that
+    gathers ``d`` (``_ell_pick_parents``), then one that gathers ``d``
+    and the round for the rows still without a pick, which skips every
+    degree class that holds none of them: with weights that the sums tell
+    apart, all.
+
+    Why not any closing neighbour: a zero-weight edge, or a weight the
+    float sum absorbs, lets two vertices at EQUAL distance close a path
+    for each other, and the max-id pick alone would have them choose each
+    other.  (distance, settling round) falls strictly along every pick
+    here, so following parents ends at the root.  And a pick always
+    exists: the neighbour whose relaxation last lowered ``d[row]``, in
+    round r, is strictly nearer, or held that same distance by round
+    r - 1 (it can only have fallen since, and the sum is monotone).  The
+    sum is the relaxation's own (``MIN_PLUS.mul`` of the same two
+    floats).  A row at +inf matches nothing here; the caller sets roots
+    and clears unreached rows.
+    """
+    from ..semiring import MIN_PLUS
+    from .vec import DistMultiVec
+
+    if dist_row.dtype != jnp.float32:
+        raise TypeError(
+            f"min-plus parents: distances are float32, not {dist_row.dtype}"
+        )
+    W = dist_row.shape[-1]
+    inf = MIN_PLUS.zero(jnp.float32)
+
+    def mk(blocks):
+        return DistMultiVec(
+            blocks=blocks, length=E.nrows, align="row", grid=E.grid
+        )
+
+    def closes(w, dj, dv):
+        # a row at +inf equals its pad slots and its unreached
+        # neighbours: it matches nothing
+        return (MIN_PLUS.mul(w[..., None], dj) == dv) & (dv < inf)
+
+    nearer = _ell_pick_parents(
+        E, mk(dist_row).realign("col").blocks, dist_row,
+        lambda dj, w, dv: closes(w, dj, dv) & (dj < dv),
+        pad=inf, width=W,
+    )
+    # reached, not a root, and only neighbours as near close its paths
+    tied = (dist_row < inf) & (settled_row > 0) & (nearer < 0)
+
+    def bits(d):  # one int32 table: a gather copies it, bit for bit
+        return lax.bitcast_convert_type(d, jnp.int32)
+
+    def dist(t):
+        return lax.bitcast_convert_type(t, jnp.float32)
+
+    def earlier(g, w, wantb):
+        dj, rj = dist(g[..., :W]), g[..., W:]
+        dv, rv = dist(wantb[..., :W]), wantb[..., W:]
+        return closes(w, dj, dv) & (dj == dv) & (rj < rv)
+
+    table = mk(jnp.concatenate([bits(dist_row), settled_row], axis=-1))
+    as_near = _ell_pick_parents(
+        E, table.realign("col").blocks, table.blocks, earlier,
+        pad=bits(inf), width=W, row_active=tied,
+    )
+    return jnp.where(tied, as_near, nearer)
 
 
 # --- budgeted union-frontier sparse step (direction optimization for the

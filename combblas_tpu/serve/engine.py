@@ -975,7 +975,7 @@ class GraphEngine:
     #: trailing iteration count follows them for every kind but "bc").
     _RESULT_KEYS = {
         "bfs": ("parents", "levels"),
-        "sssp": ("dist",),
+        "sssp": ("dist", "parents"),
         "pagerank": ("ranks",),
         "bc": ("scores",),
     }
@@ -1049,6 +1049,9 @@ class GraphEngine:
                     # byte counter above
                     for mode, taken in zip(SWEEP_MODES, np.asarray(sweeps)):
                         obs.count("serve.bfs.sweeps", int(taken), mode=mode)
+                if kind == "sssp":
+                    obs.count("serve.sssp.rounds", int(niter), width=W)
+                    obs.count("serve.sssp.batches", 1, width=W)
             with mark("to_global"):
                 out = {
                     k: self._lanes_to_global(h) for k, h in zip(keys, host)

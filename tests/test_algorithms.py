@@ -330,7 +330,7 @@ def test_sssp_batch_matches_single(rng):
         d[r, c].astype(np.float32), n, n,
     )
     srcs = [0, 5, 17]
-    db, _ = sssp_batch(E, jnp.asarray(srcs, jnp.int32))
+    db, _, _ = sssp_batch(E, jnp.asarray(srcs, jnp.int32))
     got = db.to_global()
     for w, s in enumerate(srcs):
         dist, _ = sssp(A, s)

@@ -183,13 +183,16 @@ def test_every_class_is_a_conditional_for_the_v5e(
     assert " conditional(" not in _plan_hlo("bfs", E, grid)
 
 
-#: sha256 of the stripped optimised HLO of the parent's (PR 23, commit
-#: 2ad2df9) width-16 SSSP and PageRank plans at scale 14 for a described
-#: v5e, under the jax / jaxlib these were taken with.  To repeat: run
-#: ``_plan_hlo`` in a checkout of that commit.
+#: sha256 of the stripped optimised HLO of the width-16 PageRank and SSSP
+#: plans at scale 14 for a described v5e, under the jax / jaxlib these
+#: were taken with.  ``pagerank`` is PR 23's (commit 2ad2df9): no PR since
+#: has moved the unmasked sweep.  ``sssp`` was re-pinned by PR 26, which
+#: gave the program its parents pass and the loop its record of the round
+#: that settled each distance (``0525dc40...`` before).  To repeat:
+#: run ``_plan_hlo`` in a checkout of the commit.
 PARENT_HLO = {
     "jax": "0.9.0",
-    "sssp": "0525dc409b6be8204633ab7a32fbef1eb235616d19d6e5456d6f84dab0f05b81",
+    "sssp": "8adb19281ab356408cc85a0d53accf618c035fbb52313b1217cf656b2ecc5c13",
     "pagerank":
         "7b0a71bdfe7b85447c85b6739d71ebc537dddfed3a7b659fca2fdab9d566705c",
 }
@@ -198,32 +201,41 @@ PARENT_HLO = {
 @pytest.mark.parametrize("kind", ["sssp", "pagerank"])
 def test_unmasked_plans_are_the_parents_programs(
         operands, all_dense_sweeps, kind):
-    """SSSP and PageRank pass no row mask: their plans do not depend on
-    the choice at all (same text with it and without), hold no branch,
-    and are the parent's programs, so the parent's compile cache serves
-    them."""
+    """PageRank and SSSP's rounds pass no row mask: PageRank's plan does
+    not depend on the choice at all (same text with it and without) and
+    holds no branch; SSSP's holds one ``conditional`` per degree class
+    and no more, the parents pass's second sweep (the rows that only a
+    neighbour as near closes a path for), none in a round.  Both are the
+    pinned programs: a change to the sweep they share with BFS
+    (``_ell_local_spmm(row_active=None)``) shows here."""
     import hashlib
 
     import jax
 
     E, grid = operands
     text = _plan_hlo(kind, E, grid)
-    assert " conditional(" not in text
+    branches = re.findall(r"= [^=\n]* conditional\(", text)
+    assert len(branches) == (len(E.buckets) if kind == "sssp" else 0)
     all_dense_sweeps(True)
-    assert _plan_hlo(kind, E, grid) == text
+    dense = _plan_hlo(kind, E, grid)
+    assert " conditional(" not in dense
+    assert (dense == text) == (kind == "pagerank")
     if jax.__version__ != PARENT_HLO["jax"]:
         pytest.skip(f"the parent's text was taken under jax "
                     f"{PARENT_HLO['jax']}, this is {jax.__version__}")
     assert hashlib.sha256(text.encode()).hexdigest() == PARENT_HLO[kind]
 
 
-def test_mesh_level_loop_keeps_its_name_for_the_v5e(topo):
+@pytest.mark.parametrize("kind,loop", [
+    ("bfs", "bfs.level"), ("sssp", "sssp.round")])
+def test_mesh_loop_keeps_its_name_for_the_v5e(topo, kind, loop):
     """On a 2x2 mesh the ``while`` of the served BFS plan still carries
-    ``bfs.level`` in its ``op_name``: the device trace finds the levels by
-    it (``chipbench/scopes.py``).  A collective accumulated inside the
-    loop (the sweep tally summed over tiles every level) made the compiler
-    move it out and rebuild the loop without metadata, so the tally is
-    carried per tile and summed once after."""
+    ``bfs.level`` in its ``op_name``, and the served SSSP plan's
+    ``sssp.round``: the device trace finds the levels and the rounds by
+    it (``chipbench/scopes.py``, ``chipbench/k3scopes.py``).  A collective
+    accumulated inside the loop (the sweep tally summed over tiles every
+    level) made the compiler move it out and rebuild the loop without
+    metadata, so the tally is carried per tile and summed once after."""
     import jax
     from jax.sharding import NamedSharding
 
@@ -254,6 +266,47 @@ def test_mesh_level_loop_keeps_its_name_for_the_v5e(topo):
         return bfs_mod._bfs_batch_tallied(
             E, sources, None, SELECT2ND_MAX, True)
 
-    names = opnames.parse(_optimised(serve_bfs_w16, E, 16, grid))[1]
+    def serve_sssp_w16(E, sources):
+        from combblas_tpu.models.sssp import _sssp_batch_impl
+
+        return _sssp_batch_impl(E, sources)
+
+    fn = serve_bfs_w16 if kind == "bfs" else serve_sssp_w16
+    names = opnames.parse(_optimised(fn, E, 16, grid))[1]
     loops = [nm for instr, nm in names.items() if instr.startswith("while")]
-    assert any(nm.endswith("bfs.level/while") for nm in loops), loops
+    assert any(nm.endswith(loop + "/while") for nm in loops), loops
+
+
+def test_sssp_round_names_the_loop_of_the_one_chip_program(operands):
+    """The served kernel-3 program for the described v5e: its ``while``
+    is ``sssp.round``, the sweeps inside it and the one after it carry
+    the class and leaf scopes under ``sssp.round`` and ``sssp.parents``,
+    and the answer is three arrays (distances, parents, rounds)."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from combblas_tpu.models.sssp import SSSP_SCOPES, _sssp_batch_impl
+    from combblas_tpu.obs import opnames
+
+    E, grid = operands
+
+    def serve_sssp_w16(E, sources):
+        return _sssp_batch_impl(E, sources)
+
+    text = _optimised(serve_sssp_w16, E, 16, grid)
+    names = opnames.parse(text)[1]
+    loops = [nm for i, nm in names.items() if i.startswith("while")]
+    assert any(nm.endswith("sssp.round/while") for nm in loops), loops
+    seen = set(names.values())
+    for scope in SSSP_SCOPES:
+        assert any(f"/{scope}/" in nm for nm in seen), scope
+    for phase in ("sssp.round", "sssp.parents"):
+        assert any(phase in nm and "ell.bucket0/gather" in nm
+                   for nm in seen), phase
+    sources = jax.ShapeDtypeStruct(
+        (16,), jnp.int32, sharding=NamedSharding(grid.mesh, P()))
+    dist, parents, rounds = jax.eval_shape(serve_sssp_w16, E, sources)
+    assert (dist.dtype, parents.dtype) == (jnp.float32, jnp.int32)
+    assert dist.shape == parents.shape == (1, E.nrows, 16)
+    assert rounds.shape == ()
