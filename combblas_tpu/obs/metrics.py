@@ -587,6 +587,11 @@ name                                   kind       meaning
 ``serve.batch.padding_waste``          histogram  pad lanes per batch
 ``serve.batches``                      gauge      total batches
                                                   executed
+``serve.batch.overlapped``             counter    batches read back and
+                                                  scattered while the
+                                                  worker's next batch
+                                                  was on the device
+                                                  (labels ``kind``)
 ``serve.readback.bytes``               counter    bytes ``execute``
                                                   copied device to
                                                   host, counted at the

@@ -62,6 +62,31 @@ def _split_parts(parts, start: float, end: float):
     return out
 
 
+class _Started:
+    """One batch between the worker's two halves (``_start_batch`` /
+    ``_finish_batch``): its live requests, the marks made so far and
+    the engine's handle of what is on the device."""
+
+    __slots__ = ("live", "kind", "toplevel", "t_pop", "wait_s", "t_asm",
+                 "t_exec", "traced", "parts", "sources", "handle",
+                 "misses", "plan_src", "version", "error")
+
+    def __init__(self, live, toplevel: bool, engine):
+        self.live, self.kind, self.toplevel = live, live[0].kind, toplevel
+        self.t_asm = self.t_exec = self.sources = self.handle = None
+        self.plan_src = self.version = self.error = None
+        self.misses = engine.plan_misses
+
+    def ran(self, engine) -> None:
+        """Right after the plan was called (before a successor's call
+        can miss too): did it miss the plan cache, and on which version
+        of the graph did it run."""
+        self.plan_src = (
+            "cold" if engine.plan_misses > self.misses else "warm"
+        )
+        self.version = engine.version_id
+
+
 class Server:
     """In-process query server over one ``GraphEngine``."""
 
@@ -1033,7 +1058,10 @@ class Server:
         requests, run, and on failure hand the survivors to the
         bisection retrier. Top-level outcomes (not bisection
         sub-batches) feed the kind's circuit breaker, so one poisoned
-        request cannot open it.
+        request cannot open it.  The two halves back to back: what
+        ``pump(force=True)``, retry sub-batches and the pool run; the
+        worker puts the next batch's start between them
+        (``_execute_batches``).
 
         Observability (round 15): sampled requests' traces MARK each
         stage transition here (queue wait / retry wait -> assemble ->
@@ -1041,16 +1069,26 @@ class Server:
         and the always-on flight recorder takes one per-batch event
         with the same stage decomposition — per batch, not per
         request, so it can afford to run unconditionally."""
+        st = self._start_batch(reqs, toplevel=toplevel)
+        if st is not None:
+            self._finish_batch(st)
+
+    def _start_batch(self, reqs, *, toplevel: bool = True
+                     ) -> "_Started | None":
+        """The first half of a batch: drop dead requests, mark, assemble
+        and hand the plan to the device (``engine.launch``).  Never
+        raises: a failure here is recorded on the batch, and its
+        recovery runs in ``_finish_batch`` (so a batch in hand is
+        delivered first).  None when nobody in ``reqs`` is alive."""
         live = self._drop_dead(reqs)
         if not live:
-            return
-        kind = live[0].kind
-        breaker = self.scheduler.breakers.get(kind)
-        rec = self._recorder
-        t_pop = time.perf_counter()
+            return None
+        st = _Started(live, toplevel, self.engine)
+        kind = st.kind
+        st.t_pop = time.perf_counter()
         # oldest request's wait at pop time (monotonic base, matching
         # Request.submitted_at) — the recorder's queue-wait fact
-        wait_s = time.monotonic() - live[0].submitted_at
+        st.wait_s = time.monotonic() - live[0].submitted_at
         # the wait a request pays BEFORE the worker picks it up:
         # queue/flush wait at top level (in a pool, this includes the
         # WFQ credit wait — one number, by design), sibling-bisection
@@ -1058,17 +1096,16 @@ class Server:
         stage0 = "queue_wait" if toplevel else "retry_wait"
         for r in live:
             if r.trace is not None:
-                r.trace.mark(stage0, now=t_pop)
-        t_asm = t_exec = None
+                r.trace.mark(stage0, now=st.t_pop)
         # the engine's parts of ``execute`` and the profiler's host
         # annotations: only for a batch with a traced member
-        traced = obs.ENABLED and any(r.trace is not None for r in live)
-        parts = [] if traced else None
+        st.traced = obs.ENABLED and any(r.trace is not None for r in live)
+        st.parts = [] if st.traced else None
         try:
             self.faults.check("batch.assemble", kind=kind,
                               width=len(live))
-            with obs.span("serve.assemble") if traced else NULL_SPAN:
-                sources = batcher.assemble(
+            with obs.span("serve.assemble") if st.traced else NULL_SPAN:
+                st.sources = batcher.assemble(
                     live, self.config.lane_widths, record=toplevel
                 )
             if toplevel:
@@ -1076,31 +1113,65 @@ class Server:
                 # retry sub-batches stay out of it (they are visible
                 # as retry_batches / per_kind retried instead)
                 self.batches += 1
-                self._occupancy_sum += len(live) / len(sources)
+                self._occupancy_sum += len(live) / len(st.sources)
             else:
                 self.retry_batches += 1
-            t_asm = time.perf_counter()
+            st.t_asm = time.perf_counter()
             for r in live:
                 if r.trace is not None:
-                    r.trace.mark("assemble", now=t_asm)
+                    r.trace.mark("assemble", now=st.t_asm)
             self.faults.check(
                 "engine.execute", kind=kind,
                 roots=tuple(r.root for r in live),
             )
-            pm = self.engine.plan_misses
-            result = self.engine.execute(kind, sources, parts)
-            t_exec = time.perf_counter()
-            plan_src = "cold" if self.engine.plan_misses > pm else "warm"
-            split = _split_parts(parts, t_asm, t_exec)
+            # an engine that runs a batch in one call (ShardedEngine)
+            # has nothing to put on the device ahead of the readback:
+            # its ``execute`` runs whole in the second half
+            launch = getattr(self.engine, "launch", None)
+            if launch is not None:
+                st.handle = launch(kind, st.sources, st.parts)
+                st.ran(self.engine)
+        except Exception as e:  # failure touches THIS batch only
+            self._batch_failed(st, e)
+            st.error = e
+        return st
+
+    def _finish_batch(self, st: "_Started", handoff=None
+                      ) -> "_Started | None":
+        """The second half: wait for the device, read back, scatter,
+        record; any failure of either half reaches ``_recover`` with
+        the survivors here.  ``handoff`` (the worker's) is called once
+        the batch's device work has ended and before its readback
+        begins: it starts the next due batch, which is returned (and is
+        on the device while this one is read back and scattered)."""
+        if st.error is not None:
+            self._recover(st.live, st.error)
+            return None
+        live, kind, rec, nxt = st.live, st.kind, self._recorder, None
+        try:
+            if st.handle is None:
+                result = self.engine.execute(kind, st.sources, st.parts)
+                st.ran(self.engine)
+            else:
+                self.engine.wait(st.handle)
+                nxt = handoff() if handoff is not None else None
+                if nxt is not None and obs.ENABLED:
+                    if st.traced:
+                        st.parts.append(("handoff", time.perf_counter()))
+                    if nxt.handle is not None:
+                        obs.count("serve.batch.overlapped", kind=kind)
+                result = self.engine.collect(st.handle)
+            st.t_exec = time.perf_counter()
+            split = _split_parts(st.parts, st.t_asm, st.t_exec)
             for r in live:
                 if r.trace is not None:
-                    r.trace.mark("execute", now=t_exec, parts=split)
+                    r.trace.mark("execute", now=st.t_exec, parts=split)
                     r.trace.annotate(
-                        width=len(sources), plan=plan_src,
-                        version=self.engine.version_id,
+                        width=len(st.sources), plan=st.plan_src,
+                        version=st.version,
                     )
             self.faults.check("batch.scatter", kind=kind)
-            with obs.span("serve.scatter") if traced else NULL_SPAN:
+            with obs.span("serve.scatter") if st.traced else NULL_SPAN:
                 self.completed += batcher.scatter(
                     live, result,
                     on_timeout=self._on_exec_timeout,
@@ -1113,49 +1184,58 @@ class Server:
             if rec is not None:
                 now = time.perf_counter()
                 rec.record(
-                    "serve.batch", query=kind, width=len(sources),
-                    requests=len(live), toplevel=toplevel,
-                    outcome="ok", plan=plan_src,
-                    version=self.engine.version_id,
-                    queue_wait_s=round(wait_s, 6),
-                    assemble_s=round(t_asm - t_pop, 6),
-                    execute_s=round(t_exec - t_asm, 6),
-                    scatter_s=round(now - t_exec, 6),
+                    "serve.batch", query=kind, width=len(st.sources),
+                    requests=len(live), toplevel=st.toplevel,
+                    outcome="ok", plan=st.plan_src,
+                    version=st.version,
+                    queue_wait_s=round(st.wait_s, 6),
+                    assemble_s=round(st.t_asm - st.t_pop, 6),
+                    execute_s=round(st.t_exec - st.t_asm, 6),
+                    scatter_s=round(now - st.t_exec, 6),
                     rids=[r.rid for r in live],
                 )
-            if breaker is not None and toplevel:
+            breaker = self.scheduler.breakers.get(kind)
+            if breaker is not None and st.toplevel:
                 breaker.record_success(time.monotonic(), kind)
         except Exception as e:  # failure touches THIS batch only
-            now = time.perf_counter()
-            # what the failed attempt spent past its last whole part is
-            # the part "failed", so parts still sum to the stage
-            split = None
-            if traced:
-                t_last = t_exec or t_asm or t_pop  # the last mark made
-                split = _split_parts(
-                    [p for p in parts if p[1] > t_last]
-                    + [("failed", now)], t_last, now,
-                )
-            for r in live:
-                if r.trace is not None:
-                    # however far the batch got, the elapsed time was
-                    # execution-side work: charge it there so retry
-                    # marks stay telescoping
-                    r.trace.mark("execute", now=now, parts=split)
-            if rec is not None:
-                rec.record(
-                    "serve.batch", query=kind, requests=len(live),
-                    toplevel=toplevel, outcome="error",
-                    error=repr(e),
-                    elapsed_s=round(now - t_pop, 6),
-                    rids=[r.rid for r in live],
-                )
-            if breaker is not None and toplevel:
-                if breaker.record_failure(time.monotonic(), kind):
-                    self._flight_dump(
-                        "breaker_open", query=kind, error=repr(e)
-                    )
+            self._batch_failed(st, e)
             self._recover(live, e)
+        return nxt
+
+    def _batch_failed(self, st: "_Started", e: Exception) -> None:
+        """Account a failed attempt (trace marks, recorder, breaker);
+        the caller hands the survivors to ``_recover``."""
+        live, kind = st.live, st.kind
+        now = time.perf_counter()
+        # what the failed attempt spent past its last whole part is
+        # the part "failed", so parts still sum to the stage
+        split = None
+        if st.traced:
+            t_last = st.t_exec or st.t_asm or st.t_pop  # the last mark
+            split = _split_parts(
+                [p for p in st.parts if p[1] > t_last]
+                + [("failed", now)], t_last, now,
+            )
+        for r in live:
+            if r.trace is not None:
+                # however far the batch got, the elapsed time was
+                # execution-side work: charge it there so retry
+                # marks stay telescoping
+                r.trace.mark("execute", now=now, parts=split)
+        if self._recorder is not None:
+            self._recorder.record(
+                "serve.batch", query=kind, requests=len(live),
+                toplevel=st.toplevel, outcome="error",
+                error=repr(e),
+                elapsed_s=round(now - st.t_pop, 6),
+                rids=[r.rid for r in live],
+            )
+        breaker = self.scheduler.breakers.get(kind)
+        if breaker is not None and st.toplevel:
+            if breaker.record_failure(time.monotonic(), kind):
+                self._flight_dump(
+                    "breaker_open", query=kind, error=repr(e)
+                )
 
     def _recover(self, reqs, exc: Exception) -> None:
         """Poisoned-batch isolation: a failed batch is bisected and
@@ -1204,20 +1284,55 @@ class Server:
         self._run_batch(retry[:mid], toplevel=False)
         self._run_batch(retry[mid:], toplevel=False)
 
-    def _execute_batches(self, ready) -> None:
-        for reqs in ready:
-            # whole-batch guard: these requests are already popped, so
-            # ANY failure (assemble, engine, scatter) must settle their
-            # futures (possibly after bisection retries) — a stranded
-            # future blocks its caller forever
-            self._run_batch(reqs)
+    def _execute_batches(self, ready) -> int:
+        """The worker's order of calls: at most ONE batch launched
+        ahead.  When the batch in hand is done on the device its
+        successor is started (from ``ready``, else the one batch the
+        scheduler has due at that instant), and only then is the batch
+        in hand read back and scattered: the host's part of a batch
+        runs under the device's part of the next.  A successor's
+        membership is decided no earlier than the serial order decided
+        it (the instant the readback would begin), never behind a
+        running program.  Returns batches popped here; nothing is in
+        hand on return.
+
+        Whole-batch guard: these requests are already popped, so ANY
+        failure (assemble, engine, scatter) must settle their futures
+        (possibly after bisection retries) — a stranded future blocks
+        its caller forever."""
+        ready = deque(ready)
+        popped = 0
+
+        def successor():
+            nonlocal popped
+            if not ready and not self._stop:
+                due = self.scheduler.pop_ready(max_batches=1)
+                popped += len(due)
+                ready.extend(due)
+            while ready:
+                st = self._start_batch(ready.popleft())
+                if st is not None:
+                    return st
+            return None
+
+        hand = successor()
+        while hand is not None:
+            hand = self._finish_batch(hand, successor) or successor()
+        return popped
 
     def pump(self, force: bool = False) -> int:
         """One synchronous scheduling step (the worker's body, callable
         directly for deterministic tests / worker-less embedding):
-        execute every batch currently due. Returns batches executed."""
-        ready = self.scheduler.pop_ready(force=force)
-        self._execute_batches(ready)
+        execute every batch due, one popped at a time and each started
+        before its predecessor is read back (``_execute_batches``);
+        under ``force`` (drain/close) whatever is queued, one whole
+        batch after the other. Returns batches executed; every popped
+        request's future is settled on return."""
+        if not force:
+            return self._execute_batches(())
+        ready = self.scheduler.pop_ready(force=True)
+        for reqs in ready:
+            self._run_batch(reqs)
         return len(ready)
 
     def _loop(self) -> None:

@@ -86,6 +86,19 @@ def _no_clock(part: str):
     return NULL_SPAN
 
 
+@dataclasses.dataclass(slots=True)
+class _Launched:
+    """A dispatched batch between ``launch`` and ``collect``: the
+    plan's un-read device results and what reading them back needs."""
+
+    kind: str
+    width: int
+    res: object  # the plan's outputs, still on the device
+    mark: object  # the part clock of a traced batch, else ``_no_clock``
+    feat_dim: int  # of the version it runs on: a swap may land first
+    waited: bool = False
+
+
 @dataclasses.dataclass
 class _Plan:
     """One warm executable: (kind, width) -> jitted program + metadata."""
@@ -985,6 +998,9 @@ class GraphEngine:
         """Run one batch: ``sources`` is the int32 lane vector (pad
         slots = ``PAD_ROOT``). Returns a dict of host arrays with the
         lane axis LAST (what ``batcher.scatter`` slices per request).
+        It is ``collect(launch(...))`` under one hold of the execution
+        lock; the serving worker calls the halves itself, with the next
+        batch's ``launch`` between ``wait`` and ``collect``.
 
         ``parts``: a list the caller owns (the worker passes one when a
         member of the batch is traced; engines are shared, so nothing is
@@ -998,6 +1014,45 @@ class GraphEngine:
         profiler's clock.  Without ``parts``, or with telemetry off, no
         clock is read and nothing waits before the readback.
         """
+        with self._exec_lock, obs.span(
+            "serve.batch", kind=kind, width=len(sources)
+        ):
+            return self._collect(self._launch(kind, sources, parts))
+
+    def launch(self, kind: str, sources, parts: list | None = None
+               ) -> "_Launched":
+        """The first half of ``execute``: dispatch the plan and return
+        at once with its un-read device results.  The lock is held for
+        the dispatch alone, so the order of programs is the same on
+        every chip of the mesh whoever launches next."""
+        with self._exec_lock, obs.span(
+            "serve.batch", kind=kind, width=len(sources)
+        ):
+            return self._launch(kind, sources, parts)
+
+    def wait(self, handle: "_Launched") -> None:
+        """Block until the device work of a launched batch has ended and
+        start its results on their way to the host (the part ``device``
+        where the batch is traced).  Holds no lock: a swap or another
+        launch may go ahead meanwhile."""
+        import jax
+
+        with handle.mark("device"):
+            jax.block_until_ready(handle.res)
+            # queued ahead of whatever is launched next; ``collect``'s
+            # ``np.asarray`` then finds the copy under way (20 ms of a
+            # mesh batch's 867 ms readback, 6 of 41 on one chip)
+            for out in jax.tree_util.tree_leaves(handle.res):
+                out.copy_to_host_async()
+        handle.waited = True
+
+    def collect(self, handle: "_Launched") -> dict:
+        """The second half of ``execute``: read a launched batch back
+        and lay it out ``[n, W]``; byte and sweep counters included."""
+        with self._exec_lock:
+            return self._collect(handle)
+
+    def _launch(self, kind: str, sources, parts) -> "_Launched":
         import jax.numpy as jnp
 
         sources = np.asarray(sources, np.int32)
@@ -1005,60 +1060,60 @@ class GraphEngine:
         plan = self.plan(kind, W)
         traced = parts is not None and obs.ENABLED
         mark = _PartClock(parts) if traced else _no_clock
-        with self._exec_lock, obs.span("serve.batch", kind=kind, width=W):
-            with mark("launch"):
-                res = plan.fn(jnp.asarray(sources))
-                plan.executions += 1
-            if traced:
-                import jax
+        with mark("launch"):
+            res = plan.fn(jnp.asarray(sources))
+            plan.executions += 1
+        return _Launched(kind, W, res, mark, self._version.feat_dim)
 
-                with mark("device"):
-                    jax.block_until_ready(res)
-            if kind == "propagate":
-                # [Fp, W] replicated features — strip the pow2 pad
-                # lanes back to the true feature dim; lane axis stays
-                # LAST (the batcher's scatter contract)
-                from ..parallel.spgemm import host_value
+    def _collect(self, handle: "_Launched") -> dict:
+        kind, W, res, mark = handle.kind, handle.width, handle.res, handle.mark
+        if mark is not _no_clock and not handle.waited:
+            self.wait(handle)  # a traced batch waits apart from its readback
+        if kind == "propagate":
+            # [Fp, W] replicated features — strip the pow2 pad
+            # lanes back to the true feature dim; lane axis stays
+            # LAST (the batcher's scatter contract)
+            from ..parallel.spgemm import host_value
 
-                with mark("readback"):
-                    feats = host_value(res)
-                with mark("to_global"):
-                    return {"features": feats[: self._version.feat_dim]}
-            keys = self._RESULT_KEYS[kind]
-            # "batch_niter" is BATCH metadata (the max iteration count
-            # over all lanes, pad included), not a per-request fact: a
-            # request's own value would vary with its batch-mates
-            sweeps = None
-            if kind == "bc":
-                blocks, niter = (res,), None
-            elif kind == "bfs":
-                *blocks, niter, sweeps = res
-            else:
-                blocks, niter = res[:-1], res[-1]
             with mark("readback"):
-                host = [np.asarray(b) for b in blocks]
-            if obs.ENABLED:
-                obs.count(
-                    "serve.readback.bytes",
-                    sum(h.nbytes for h in host), kind=kind, width=W,
-                )
-                if sweeps is not None:
-                    from ..parallel.ellmat import SWEEP_MODES
-
-                    # 8 bytes, not part of the result: kept out of the
-                    # byte counter above
-                    for mode, taken in zip(SWEEP_MODES, np.asarray(sweeps)):
-                        obs.count("serve.bfs.sweeps", int(taken), mode=mode)
-                if kind == "sssp":
-                    obs.count("serve.sssp.rounds", int(niter), width=W)
-                    obs.count("serve.sssp.batches", 1, width=W)
+                feats = host_value(res)
             with mark("to_global"):
-                out = {
-                    k: self._lanes_to_global(h) for k, h in zip(keys, host)
-                }
-                if niter is not None:
-                    out["batch_niter"] = int(niter)
-                return out
+                return {"features": feats[: handle.feat_dim]}
+        keys = self._RESULT_KEYS[kind]
+        # "batch_niter" is BATCH metadata (the max iteration count
+        # over all lanes, pad included), not a per-request fact: a
+        # request's own value would vary with its batch-mates
+        sweeps = None
+        if kind == "bc":
+            blocks, niter = (res,), None
+        elif kind == "bfs":
+            *blocks, niter, sweeps = res
+        else:
+            blocks, niter = res[:-1], res[-1]
+        with mark("readback"):
+            host = [np.asarray(b) for b in blocks]
+        if obs.ENABLED:
+            obs.count(
+                "serve.readback.bytes",
+                sum(h.nbytes for h in host), kind=kind, width=W,
+            )
+            if sweeps is not None:
+                from ..parallel.ellmat import SWEEP_MODES
+
+                # 8 bytes, not part of the result: kept out of the
+                # byte counter above
+                for mode, taken in zip(SWEEP_MODES, np.asarray(sweeps)):
+                    obs.count("serve.bfs.sweeps", int(taken), mode=mode)
+            if kind == "sssp":
+                obs.count("serve.sssp.rounds", int(niter), width=W)
+                obs.count("serve.sssp.batches", 1, width=W)
+        with mark("to_global"):
+            out = {
+                k: self._lanes_to_global(h) for k, h in zip(keys, host)
+            }
+            if niter is not None:
+                out["batch_niter"] = int(niter)
+            return out
 
     def stats(self) -> dict:
         # _plans_lock only: polling stats during a long batch must not

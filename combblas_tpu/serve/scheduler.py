@@ -704,7 +704,9 @@ class Scheduler:
         ``max_batches`` (round 14) bounds how many batches one call may
         pop — the weighted-fair-queueing pump pops ONE batch per
         deficit charge so a saturated tenant drains in weighted shares
-        instead of monopolizing the worker for its whole backlog; the
+        instead of monopolizing the worker for its whole backlog, and
+        the serving worker pops one at each hand-off
+        (``Server._execute_batches``); the kinds take turns, and the
         dead-request sweep still covers every kind regardless.
         """
         now = time.monotonic() if now is None else now
@@ -748,6 +750,13 @@ class Scheduler:
                         break
                     take = min(len(q), wmax)
                     out.append([q.popleft() for _ in range(take)])
+            if max_batches is not None:
+                # a bounded pop serves the kinds in turn: one that gave
+                # a batch goes to the back, or a kind that always has a
+                # full lane would starve the others of a worker that
+                # pops one batch at a time
+                for kind in [b[0].kind for b in out]:
+                    self._pending[kind] = self._pending.pop(kind)
             obs.gauge(
                 "serve.queue.depth",
                 sum(len(q) for q in self._pending.values()),

@@ -151,6 +151,54 @@ def test_execute_with_obs_off_reads_no_clock_and_waits_for_nothing(
     assert calls["block"] == before["block"]
 
 
+# --- the hand-off (PR 27) ---------------------------------------------------
+
+
+def _pump_full_lanes(engine, lanes):
+    """``lanes`` full 16-wide batches through a worker-less ``pump()``;
+    returns the trace records grouped by batch, in pop order."""
+    srv = engine.serve(ServeConfig(lane_widths=(1, 16), max_wait_s=60.0))
+    srv.warmup(kinds=("bfs",), widths=(16,))
+    futs = [srv.submit("bfs", i) for i in range(16 * lanes)]
+    assert srv.pump() == lanes
+    assert all(f.done() for f in futs)
+    srv.close()
+    groups = {}
+    for rec in obs.trace.records():
+        ex = next(s for s in rec["stages"] if s["stage"] == "execute")
+        wait = next(s for s in rec["stages"] if s["stage"] == "queue_wait")
+        groups.setdefault(ex["s"], (rec["t0"] + wait["s"], rec, ex))
+    return [g[1:] for g in sorted(groups.values(), key=lambda g: g[0])]
+
+
+@pytest.mark.parametrize("lanes", [1, 3])
+def test_handoff_part_and_overlapped_counter(engine, lanes):
+    """A batch that started its successor between ``device`` and
+    ``readback`` carries the part ``handoff``; one with nothing due
+    keeps the four parts; either way parts sum to the stage, and
+    ``serve.batch.overlapped`` counts the batches read back under a
+    successor: all but the last."""
+    obs.enable(install_hooks=False)
+    obs.trace.set_sample_rate(1.0)
+    batches = _pump_full_lanes(engine, lanes)
+    assert len(batches) == lanes
+    four = ["launch", "device", "readback", "to_global"]
+    for k, (rec, ex) in enumerate(batches):
+        names = [p["stage"] for p in ex["parts"]]
+        last = k == lanes - 1
+        assert names == (four if last else four[:2] + ["handoff"] + four[2:])
+        assert all(p["s"] >= 0 for p in ex["parts"])
+        assert abs(sum(p["s"] for p in ex["parts"]) - ex["s"]) < 1e-8
+        assert abs(sum(s["s"] for s in rec["stages"]) - rec["wall_s"]) < 1e-8
+    assert (_counter("serve.batch.overlapped", kind="bfs") or 0) == lanes - 1
+
+
+def test_handoff_leaves_nothing_with_telemetry_off(engine):
+    obs.trace.set_sample_rate(1.0)
+    assert _pump_full_lanes(engine, 2) == []
+    assert obs.registry.empty() and obs._spans.empty()
+
+
 # --- counters ---------------------------------------------------------------
 
 
