@@ -70,7 +70,7 @@ SCALE_1CHIP = 20
 SCALE_4CHIP = 22   # per-chip nonzeros equal the one-chip scale-20 run
 EDGEFACTOR = 16
 SEED = 1
-BATCH_W = 256      # bench.py's batch width
+BATCH_W = 256      # the k2-batch cell's batch width
 REF_ROOTS = 4      # roots checked against the reference
 LANE_WIDTHS = (1, 4, 16)
 RESULT_TIMEOUT_S = 600.0

@@ -33,7 +33,7 @@ the WRITE half (the capability bar is the reference's in-place
 mutations into the buffer, a dedicated mutation thread coalesces and
 merges them OFF the execution lock, and ``swap_graph`` flips the
 version atomically — reads stay hot while writes stream in
-(``BENCH_SERVE_MUTATE=1`` in serve_bench measures the mix).  See
+(``tests/test_serve_mutate.py`` holds the mix under load).  See
 docs/dynamic.md.
 """
 

@@ -40,8 +40,7 @@ SPILL POLICY — the incremental path falls back to a full rebuild
   ``keep_coo=True``).
 
 Counters (``dynamic.merge.*``, cataloged in ``obs/metrics.py``) make
-the incremental-vs-rebuild amortization measurable; the serve bench's
-``BENCH_SERVE_MUTATE=1`` scenario gates on them.
+the incremental-vs-rebuild amortization measurable.
 """
 
 from __future__ import annotations

@@ -203,8 +203,8 @@ def bfs_diropt(
     The per-level regime switch is a ``lax.cond`` on frontier statistics
     INSIDE the while_loop — both regimes compile once and zero
     device-to-host readbacks happen during the search (the round-1 host
-    switch permanently degraded the chip's launch path via its per-level
-    ``int(cnt)`` readbacks; see bench.py's D2H note). Top-down runs the
+    switch degraded the round-5 machine's launch path via its per-level
+    ``int(cnt)`` readbacks; ROADMAP D14). Top-down runs the
     budgeted sparse-frontier kernel (work ∝ the static budgets); bottom-up
     runs the dense masked SpMV (work ∝ tile nnz, the regime where the
     reference's carousel operates, ``DirOptBFS.cpp:374-424``).
@@ -449,7 +449,7 @@ def bfs_batch(
 ):
     """Eager wrapper over ``_bfs_batch_impl`` (plain-outputs law: a
     dataclass-wrapped jit output tripled the batch child's wall time in
-    the r5 A/B — 90.8 vs 281.7 MTEPS; see probe_seq_r5 wa/wc)."""
+    the round-5 A/B on a machine that is gone; ROADMAP D14)."""
     from ..parallel.vec import DistMultiVec
 
     p, l, niter = _bfs_batch_impl(
@@ -488,7 +488,7 @@ def _bfs_batch_impl(
     ``track_levels=False`` drops the level array from the loop carry,
     saving one [n, W] int32 buffer (it raised the feasible batch width
     from 256 toward 384 at scale 20 — W=512 still exceeds this chip's
-    16G HBM; see benchmarks/results/bench_sweep_r2c.txt). Levels are then
+    16G HBM; round-2 width sweep). Levels are then
     returned as a discovery indicator (0 discovered / -1 not).
     """
     return _bfs_batch_tallied(A, sources, max_iters, sr, track_levels)[:3]
@@ -576,11 +576,11 @@ def _gid_blocks(grid, nblocks: int, block_len: int, length: int,
     """Materialized global-id blocks (``_global_ids`` as a DEVICE BUFFER,
     built host-side and uploaded once per (grid, shape)).
 
-    BOUNDED cache (ADVICE r5): each entry pins an HBM buffer for its
+    BOUNDED cache: each entry pins an HBM buffer for its
     (grid, shape, align); unbounded, a long-lived process sweeping many
     shapes (the pytest session) would accumulate pinned device memory
-    forever. 16 entries cover any realistic working set (the bench
-    children are single-shape); eviction just re-uploads. Growth is
+    forever. 16 entries cover any realistic working set; eviction
+    just re-uploads. Growth is
     visible through the ``cache.bfs.*`` gauges (``obs`` registry) and
     ``clear_bfs_caches()`` is the explicit release hook.
 
@@ -589,7 +589,7 @@ def _gid_blocks(grid, nblocks: int, block_len: int, length: int,
     per-iteration rematerialization that executes SERIALLY — the
     otherwise-identical single-root BFS program measured 39.5 s with the
     in-program iota vs 1.7 s with the table passed as an operand
-    (benchmarks/probe_seq_r5.py, modes v9 vs v7)."""
+    (round-5 sequence probe, modes v9 vs v7; ROADMAP D14)."""
     import numpy as np
 
     g = np.arange(nblocks * block_len, dtype=np.int32).reshape(
@@ -599,7 +599,7 @@ def _gid_blocks(grid, nblocks: int, block_len: int, length: int,
     if grid.size == 1:
         # UNSHARDED on purpose: a NamedSharding'd vector operand makes
         # the whole compiled program execute ~25x slower on the target
-        # backend (probe_seq_r5 w3 47.3 s vs v7 1.7 s — same loop, only
+        # backend (round-5 probe: 47.3 s vs 1.7 s — same loop, only
         # the gid operands' sharding differs)
         return jax.device_put(jnp.asarray(g))
     sh = (
@@ -700,9 +700,9 @@ def bfs_single(E, source, csc, *, tiers, csr=None, coldeg=None,
 
 #: Default sequential-root tier ladder for Graph500-class graphs at
 #: scale ~20 (sized from the measured level anatomy in
-#: benchmarks/results/r5): a small top-down tier for the pre-peak
+#: round 5): a small top-down tier for the pre-peak
 #: levels, two bottom-up tiers for the post-peak levels, dense for the
-#: peak step. bench.py and the probes share this constant.
+#: peak step.
 DEFAULT_SEQ_TIERS = (
     "td:1024,1024,512,128,16,2"
     "|bu:524288,16384,1024,0,0,0"
@@ -739,7 +739,7 @@ def _bfs_single_program(grid, nrows, ncols, nbuckets, tiers,
     top-down property, ``BFSFriends.h:59-182``; the bottom-up regime is
     Beamer's, ``DirOptBFS.cpp:374-424``).
 
-    Measured scale-20 R-MAT level anatomy (benchmarks/results/r5, host
+    Measured scale-20 R-MAT level anatomy (round 5, host
     profile): one step is heavy (expanding L2: 6-26M frontier edges —
     the dense sweep's regime), the steps before it have TINY frontiers
     (≤350K edges), and from L3 on the UNDISCOVERED side collapses
@@ -764,7 +764,8 @@ def _bfs_single_program(grid, nrows, ncols, nbuckets, tiers,
     forces the next strategy. Conditions are 7 masked reductions per
     side per level, computed once.
 
-    TPU-pathology notes baked into this design (probe_seq_r5):
+    TPU-pathology notes baked into this design (round-5 sequence probe,
+    a machine that is gone; not re-measured, ROADMAP D14):
     in-program iota/cumsum/1-D megascatter serialize on this backend
     (1.6-1.9 s per 1M elements; 39.5 s-vs-1.7 s for the v9/v7 program
     pair), so compaction is top_k (sort, ~50 ms/M), iota and gid tables
@@ -782,7 +783,7 @@ def _bfs_single_program(grid, nrows, ncols, nbuckets, tiers,
     CLOSURE-CONSTANT LAW (this backend, measured): the gid/iota tables
     must be CLOSED OVER by the jitted program, not passed as arguments —
     the identical loop runs 1.65 s with them as closure constants and
-    27.3 s as parameters (probe_seq_r5 w4 vs w7; in-program jnp.arange
+    27.3 s as parameters (round-5 probe w4 vs w7; in-program jnp.arange
     is 39.5 s, v9). Hence this factory: one cached jitted program per
     (grid, shape, tiers), taking only the per-graph arrays as arguments.
 
@@ -1057,7 +1058,7 @@ def bfs_batch_compact(A, sources, max_iters: int | None = None,
                       edge_capacity: int | None = None):
     """Eager wrapper: the jitted program returns plain block arrays (the
     plain-outputs law — DistVec/DistMultiVec dataclass wrapping inside
-    jit measured 60x slower on the target backend, probe_seq_r5 wa/wc);
+    jit measured 60x slower on the round-5 machine);
     this wrapper rebuilds the DistMultiVecs outside."""
     from ..parallel.vec import DistMultiVec
 

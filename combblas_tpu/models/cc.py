@@ -39,7 +39,7 @@ from ..parallel.vec import DistVec
 
 def connected_components(A: SpParMat) -> tuple[DistVec, jax.Array]:
     """Eager wrapper over ``_connected_components_impl`` (plain-outputs
-    law, PERF_NOTES_r5 §1: dataclass-wrapped jit outputs ran the batched
+    law, round-5 notes: dataclass-wrapped jit outputs ran the batched
     BFS child 3x slower in the r5 A/B)."""
     blocks, niter = _connected_components_impl(A)
     return (

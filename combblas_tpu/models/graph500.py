@@ -25,7 +25,6 @@ drop-retry check stays on device.
 
 from __future__ import annotations
 
-import os
 import time
 
 import jax
@@ -158,17 +157,9 @@ def kernel1_device(
     """
     from ..utils.rmat import rmat_edges
 
-    import sys
-
-    def _klog(msg):
-        if os.environ.get("BENCH_K1_LOG"):
-            print(f"[kernel1] {time.strftime('%H:%M:%S')} {msg}",
-                  file=sys.stderr, flush=True)
-
     timings: dict[str, float] = {}
     n = 1 << scale
     ndev = grid.pr * grid.pc
-    _klog("generate...")
 
     t0 = time.perf_counter()
     with obs.span("k1.generate", scale=scale):
@@ -191,7 +182,6 @@ def kernel1_device(
         cols = jax.device_put(cols.reshape(shape), grid.tile_sharding())
         jax.block_until_ready((rows, cols))
     timings["generate_s"] = time.perf_counter() - t0
-    _klog(f"generate done {timings['generate_s']:.1f}s; route...")
 
     t0 = time.perf_counter()
     with obs.span("k1.route_dedup"):
@@ -207,7 +197,6 @@ def kernel1_device(
         jax.block_until_ready(A.vals)
     timings["route_dedup_s"] = time.perf_counter() - t0
     timings["dropped_dev"] = dropped
-    _klog(f"route done {timings['route_dedup_s']:.1f}s")
 
     if extra_relabel:
         t0 = time.perf_counter()

@@ -204,13 +204,12 @@ def mcl(
     no capacities, no overflow, no per-iteration readbacks; ``dense_mode``
     picks the matmul precision (see ``parallel.spgemm._mxu_dot``).  On
     the target chip this is >10x per iteration over the sparse path at
-    scale 12-14 (PERF_NOTES_r4).
+    scale 12-14 (round-4 notes).
 
     ``perturb_delta`` (dense path only) enables the plateau
     detect-and-perturb kicks — OFF by default: the escalating self-loop
     mass can move boundary vertices between clusters, so LIBRARY callers
-    opt in explicitly (ADVICE r5); the bench driver enables it and the
-    kick count is recorded as a span event + artifact field.
+    opt in explicitly; the kick count is recorded as a span event.
 
     ``chaos_every=K > 1`` runs K expansion iterations per host
     synchronization with the chaos residual carried ON DEVICE — zero
@@ -415,8 +414,7 @@ def dense_mcl_program(n, npad, inflation, eps, max_iters, *, hard, select,
     termination, and the artifact records the kick count
     ("perturbations") so that trade is visible. ``perturb_delta=0``
     (THE DEFAULT — because kicks can alter cluster assignments, library
-    callers must opt in; the bench driver passes 5e-5 explicitly,
-    ADVICE r5) disables. The two post-perturbation iterations are
+    callers must opt in) disables. The two post-perturbation iterations are
     excused from the detector (chaos history resets to inf)."""
     import jax
 
@@ -452,7 +450,7 @@ def dense_mcl_program(n, npad, inflation, eps, max_iters, *, hard, select,
         """Escalating self-loop damping + deterministic jitter, then row
         re-normalization. Flip-flop limit cycles are STABLE attractors of
         the MCL map (van Dongen §flip-flop; a 5e-5 jitter alone measured
-        21 ineffective kicks at chaos 0.24825, apps_bench r5) — the
+        21 ineffective kicks at chaos 0.24825, round 5) — the
         classical cure is MORE LOOP MASS (the role of the reference's
         AdjustLoops colmax loops, MCL.cpp:462-471), so each kick adds
         alpha = delta * 4^k to the diagonal (k = kicks so far, capped at
@@ -524,9 +522,9 @@ def _mcl_dense_loop(A, inflation, eps, max_iters, prune_kwargs,
 
     Why dense: on the target chip the sparse expansion pays the ~22 M/s
     per-element random-memory wall several times per iteration (measured
-    48 s/iter at scale 12, overflow-flagged — PERF_NOTES_r3), while the
+    48 s/iter at scale 12, overflow-flagged — round-3 notes), while the
     MXU squares a 16K dense matrix in ~0.7 s (13.3 TFLOP/s bf16,
-    probe_r4a/d).  Below ~32K vertices the dense formulation wins by >10x
+    round-4 probes).  Below ~32K vertices the dense formulation wins by >10x
     AND eliminates the whole frozen-capacity/reroll machinery: pruning is
     a thresholded mask (ties keep, like the reference's kselect), chaos
     rides in the loop carry, and the only readback is the final state.

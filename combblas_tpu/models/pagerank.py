@@ -124,7 +124,7 @@ def _pagerank_batch_impl(
     """Personalized PageRank for W sources in ONE program (the multi-root
     amortization of the batched BFS applied to PageRank: the measured chip
     gather is per-INDEX bound with payload lanes nearly free, so W rank
-    chains cost ~one — PERF_NOTES_r2.md 'batching many PageRank chains').
+    chains cost ~one — round-2 notes; PERF.md §5 has today's lane costs).
 
     ``P_ell``: the COLUMN-NORMALIZED transition matrix as an EllParMat
     (entry (i,j) = 1/outdeg(j) for edge j->i — normalize host-side while

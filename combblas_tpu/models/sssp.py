@@ -20,7 +20,7 @@ from ..parallel.vec import DistVec
 
 def sssp(A: SpParMat, source) -> tuple[DistVec, jax.Array]:
     """Eager wrapper over ``_sssp_impl`` (plain-outputs law,
-    PERF_NOTES_r5 §1)."""
+    round-5 notes; ROADMAP D14)."""
     blocks, niter = _sssp_impl(A, source)
     return (
         DistVec(blocks=blocks, length=A.nrows, align="row", grid=A.grid),

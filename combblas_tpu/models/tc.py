@@ -279,7 +279,7 @@ def triangle_count(A: SpParMat, kernel: str = "auto") -> int:
     ``kernel="dense"`` (or "auto" on a single shard with n <=
     ``DENSE_MAX_DIM``) runs the round-4 one-launch MXU path: on the
     target chip the sparse masked SpGEMM pays the ~22 M/s random-memory
-    wall (6.31 s at scale 14, PERF_NOTES_r3) while the dense product runs
+    wall (6.31 s at scale 14, round-3 notes) while the dense product runs
     at 13.3 TFLOP/s and the mask removes any need for sparse extraction.
     ``kernel="edgeharvest"`` (the bit-packed output-support tier) now
     works on MULTI-DEVICE square grids too (round 6,

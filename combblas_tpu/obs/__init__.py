@@ -34,7 +34,7 @@ host callbacks or extra syncs are ever inserted into jitted code;
 counters recorded inside jit-traced Python count traces (retraces), not
 executions, and device facts are only read back where a host sync
 already exists — or when ``DEVICE_SYNC`` is explicitly opted into (CPU
-debugging; never on the readback-poisoned chip, see bench.py).
+debugging; a readback is a host sync the timed path does not have).
 
 Usage::
 
@@ -79,8 +79,8 @@ from . import trace as _trace
 ENABLED: bool = os.environ.get("COMBBLAS_OBS", "0") not in ("", "0")
 
 #: Opt-in for instrumentation that READS DEVICE SCALARS (e.g. realized
-#: SpGEMM output nnz). Never enable in timed sections on hardware where
-#: a D2H readback degrades later launches (bench.py module docstring).
+#: SpGEMM output nnz): each is a host sync. Never enable in timed
+#: sections.
 DEVICE_SYNC: bool = os.environ.get("COMBBLAS_OBS_SYNC", "0") not in ("", "0")
 
 registry = MetricsRegistry()
@@ -111,26 +111,6 @@ def enable(jsonl_path: str | None = None, *, device_sync: bool | None = None,
 def disable() -> None:
     global ENABLED
     ENABLED = False
-
-
-def enable_sidecar(tag: str) -> str | None:
-    """The BENCH_OBS=1 convention shared by the bench drivers: enable
-    telemetry with a per-process JSONL sidecar under ``$BENCH_OBS_DIR``
-    (default ``<tmpdir>/combblas_obs``), named ``obs-<tag>-<pid>.jsonl``.
-    Returns the sidecar path, or None when ``BENCH_OBS`` is not ``1``.
-    ``DEVICE_SYNC`` stays off: a bench child must never gain a readback
-    from telemetry (bench.py module docstring)."""
-    if os.environ.get("BENCH_OBS") != "1":
-        return None
-    import tempfile
-
-    d = os.environ.get("BENCH_OBS_DIR") or os.path.join(
-        tempfile.gettempdir(), "combblas_obs"
-    )
-    os.makedirs(d, exist_ok=True)
-    path = os.path.join(d, f"obs-{tag}-{os.getpid()}.jsonl")
-    enable(jsonl_path=path, device_sync=False)
-    return path
 
 
 def enabled() -> bool:
@@ -349,13 +329,13 @@ def install_jax_hooks() -> bool:
 
 
 #: The per-request tracing module (``obs.trace`` — sampling knobs,
-#: ``stage_summary`` for bench decompositions).
+#: ``stage_summary`` for latency decompositions).
 trace = _trace
 
 __all__ = [
     "ENABLED", "DEVICE_SYNC", "SCHEMA", "SCHEMA_VERSION",
     "FLIGHTREC_SCHEMA", "FLEETLOG_SCHEMA",
-    "enable", "disable", "enabled", "enable_sidecar", "reset",
+    "enable", "disable", "enabled", "reset",
     "reset_spans",
     "count", "gauge", "observe", "span", "span_event",
     "request_trace", "update_trace", "trace_records", "prune_labels",

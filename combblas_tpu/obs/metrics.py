@@ -552,9 +552,6 @@ name                                   kind       meaning
 ``compile_cache.entries``              gauge      cache files on disk
                                                   (labels ``cache`` =
                                                   xla / plans)
-``compile_cache.disabled``             counter    enable_compile_cache
-                                                  refusals (cache dir
-                                                  conflicts)
 ``mcl.perturb_kicks``                  counter    MCL chaos-plateau
                                                   perturbation kicks
 ``mcl.block_rerolls``                  counter    MCL sparse-block
@@ -628,9 +625,6 @@ name                                   kind       meaning
                                                   ``width``)
 ``obs.provider_errors``                counter    broken pull-provider
                                                   callbacks (caught)
-``serve.bench.*``                      gauge      bench-scenario
-                                                  headline gauges
-                                                  (serve_bench.py)
 =====================================  =========  =====================
 
 Production-observability series (round 15 — per-request tracing, the
@@ -1134,8 +1128,8 @@ KIND_HISTOGRAM = "histogram"
 #: Per-histogram sample reservoir size (round 15): the last RESERVOIR
 #: observations ride along in snapshots so quantile summaries
 #: (p50/p95/p99) are computable ONCE (``sinks.quantile_summary``) for
-#: the Prometheus exporter, ``aggregate()`` and the bench sidecars —
-#: instead of every bench keeping its own latency list.  Overflow
+#: the Prometheus exporter and ``aggregate()`` — instead of every
+#: reader keeping its own latency list.  Overflow
 #: overwrites in arrival order (a sliding window of recent values).
 RESERVOIR = 512
 
@@ -1233,8 +1227,8 @@ class MetricsRegistry:
                     "labels": dict(lk), "count": h[0], "sum": h[1],
                     "min": h[2], "max": h[3],
                     # the bounded reservoir + its quantile summary:
-                    # computed HERE once, reused by the exporter,
-                    # aggregate() and the bench sidecars
+                    # computed HERE once, reused by the exporter
+                    # and aggregate()
                     "samples": [round(float(v), 9) for v in h[4]],
                     **quantile_summary(h[4]),
                 })

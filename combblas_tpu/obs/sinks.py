@@ -65,8 +65,8 @@ _KINDS = ("meta", "span", "event", "counter", "gauge", "histogram",
 _META_SCHEMAS = (SCHEMA, FLIGHTREC_SCHEMA, FLEETLOG_SCHEMA)
 
 #: Quantiles every histogram summary carries (round 15): computed ONCE
-#: here and reused by the Prometheus exporter and the bench sidecars —
-#: benches must not re-derive percentiles by hand.
+#: here and reused by the Prometheus exporter — readers must not
+#: re-derive percentiles by hand.
 QUANTILES = (0.5, 0.95, 0.99)
 
 

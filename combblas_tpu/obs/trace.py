@@ -248,8 +248,7 @@ def clear() -> None:
 
 
 def stage_summary(trace_records=None) -> dict:
-    """Fold trace records into the latency decomposition the bench
-    summaries report: ``{stage: {"mean_s", "total_s", "count"}}`` plus
+    """Fold trace records into a latency decomposition: ``{stage: {"mean_s", "total_s", "count"}}`` plus
     a ``"_wall"`` row for the end-to-end latency.  Accepts any iterable
     of schema-``trace`` records (default: the in-process log)."""
     trace_records = records() if trace_records is None else trace_records

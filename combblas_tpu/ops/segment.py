@@ -44,7 +44,7 @@ def segment_reduce(
         # segment_sum's natural fill (0) is the additive identity of any
         # '+'-monoid — no empty-segment patch needed on the hottest path.
         # The sorted-indices hint is worth ~15-20% scatter throughput on
-        # the target chip (benchmarks/results/scatter_probe_r3.txt).
+        # the round-3 machine (scatter probe; not re-measured).
         return jax.ops.segment_sum(
             vals, ids, num_segments=num_segments,
             indices_are_sorted=ids_sorted,
@@ -116,7 +116,7 @@ def expand_ranges(lens: jax.Array, capacity: int):
     searchsorted: scatter each source's index (and start) at its start
     position, then a streaming cummax fills the run. On the target chip a
     searchsorted here costs ~0.4 us per slot (measured 24.8 s of a 30.7 s
-    scale-14 SpGEMM, benchmarks/results/scatter_probe_r3.txt) while the
+    scale-14 SpGEMM, round-3 scatter probe) while the
     two scatters touch only ``len(lens)`` slots and the cummaxes stream.
     """
     lens = lens.astype(jnp.int32)

@@ -66,9 +66,9 @@ def flops(a: SpTuples, b_csr: CSR) -> Array:
 
 #: Contiguous-lane width of the chunked expansion. The target chip's gather
 #: unit is per-INDEX bound with payload lanes up to ~256 B nearly free
-#: (benchmarks/results/PERF_NOTES_r2.md gatherw), while per-element random
+#: (round-2 notes; PERF.md §5 on today's chip), while per-element random
 #: gathers run only ~22-27 M/s at every table size
-#: (scatter_probe_r3.txt) — so fetching B rows in W-wide contiguous
+#: (round-3 scatter probe) — so fetching B rows in W-wide contiguous
 #: windows divides the expansion's gather count by ~W. Slot padding from
 #: rounding each B-row walk up to W is 3-6% on R-MAT at W=32 (flops
 #: concentrate in wide rows); ``flops_padded`` sizes it exactly.
@@ -146,7 +146,7 @@ def expand(
     # contiguous W-window gathers of B's indices and values
     # [V, W] computed-index gather; vmap(dynamic_slice) was measured 5-10x
     # SLOWER on the target chip despite its explicit contiguity (the
-    # slice-gather lowering serializes; benchmarks/results/spgemm_r3a.txt)
+    # slice-gather lowering serializes; round-3 capture)
     win = b0[:, None] + jnp.arange(W, dtype=jnp.int32)[None, :]
     bcols = b_indices[win]
     bvals = b_vals[win]
@@ -211,8 +211,8 @@ def sparsify_windowed(
 
     The target chip prices every per-element RANDOM memory op at ~22 M/s
     but serves one-index CONTIGUOUS multi-lane windows at ~130 M/s
-    (PERF_NOTES_r3 cost model), and streams elementwise passes at only
-    ~1 G elem-op/s (probe_r4e) — so an extraction must (a) be output-
+    (round-3 cost model), and streams elementwise passes at only
+    ~1 G elem-op/s (round-4 probe) — so an extraction must (a) be output-
     driven (input-driven scatters pay per CELL, and the r2 binary-search
     sparsify paid ~14 random probes per slot), and (b) spend its few
     per-slot memory ops on windows, not point gathers.  Scheme:
@@ -230,14 +230,14 @@ def sparsify_windowed(
 
     Exact, sorted row-major, ~2 window ops + ~40 lanes of vector work per
     output slot.  (A Pallas butterfly-pack alternative measured 4-10x
-    slower at bench densities in round 4 — PERF_NOTES_r4 — and is gone.)
+    slower at R-MAT densities in round 4 and is gone.)
     """
     from .segment import expand_ranges
 
     R, C = dense.shape
     # fence: without it XLA rematerializes the PRODUCER of `dense` (e.g.
     # the whole MXU matmul) inside every lax.map step below — measured
-    # 39.8 s vs 1.4 s at scale 14 (probe_r4 densespgemm vs pwindowed)
+    # 39.8 s vs 1.4 s at scale 14 (round-4 probe)
     dense = lax.optimization_barrier(dense)
     flat = dense.reshape(-1)
     ncell = R * C
@@ -248,7 +248,7 @@ def sparsify_windowed(
         mask = mask & (jnp.arange(C, dtype=jnp.int32)[None, :] < ncols)
     if R != nrows:
         mask = mask & (jnp.arange(R, dtype=jnp.int32)[:, None] < nrows)
-    # LAYOUT NOTE (the 16x-padding trap, probe_r4f): XLA:TPU tiles the two
+    # LAYOUT NOTE (the 16x-padding trap, round-4 probe): XLA:TPU tiles the two
     # minor dims to (8, 128), so any [N, 16] / [N, 8] intermediate pads
     # 8-16x — a [nch, 16, 8] view of the mask alone would materialize
     # 4.3 GB at scale 14.  Group counts therefore come from ONE MXU
@@ -407,7 +407,7 @@ def accumulate_block_scatter(
     accumulator block-locally — on backends with cached scatter units
     (XLA:CPU) this runs ~7x the fully-random scatter rate, and the sort
     (the 87 s scale-16 ESC floor) disappears entirely.  On the target TPU
-    (no scatter unit, PERF_NOTES_r4) the caller uses the ``dot`` backend
+    (no scatter unit, round-4 notes) the caller uses the ``dot`` backend
     instead; this function is the general-backend twin.
 
     ``a`` must already be row-masked to the block (rows outside the block

@@ -137,7 +137,7 @@ class SpTuples:
     def sort_rowmajor(self) -> "SpTuples":
         # A fused single-uint32-key variant was tried and measured on the
         # target chip: no improvement over the two-key sort
-        # (benchmarks/results/microbench_r2f.txt, 28.6s vs 26.6s) — the
+        # (round-2 microbench, 28.6s vs 26.6s) — the
         # sort is bandwidth/pass-bound, not operand-count-bound.
         r, c, v = lax.sort((self.rows, self.cols, self.vals), num_keys=2)
         return dataclasses.replace(self, rows=r, cols=c, vals=v)
@@ -233,7 +233,7 @@ class SpTuples:
         # (instead of one input-sized scatter per index array): the output
         # is typically several-fold smaller than the expansion, and this
         # chip prices scatters/gathers per ELEMENT (~22-27 M/s,
-        # benchmarks/results/scatter_probe_r3.txt).
+        # round-3 scatter probe).
         # distinct OOB sentinels keep the unique_indices contract for the
         # dropped (non-representative) slots
         slot_ids = jnp.arange(t.capacity, dtype=jnp.int32)

@@ -123,8 +123,8 @@ class EllParMat:
     ) -> "EllParMat":
         """Upload pre-built host bucket arrays (``host_build`` output, or
         the same arrays round-tripped through an .npz): one device_put per
-        array — the bench protocol's cheap per-child path (the parent
-        builds once on host; children only upload)."""
+        array — a snapshot load's path (``utils/checkpoint.py``: built
+        once on the host, later boots only upload)."""
         sh = grid.tile_sharding()
         # host array -> its shards directly: going through jnp.asarray
         # first would stage the WHOLE array on device 0
@@ -144,8 +144,8 @@ class EllParMat:
     ):
         """HOST-ONLY bucket construction (no device touch): returns a list
         of (bc, bv, br) numpy arrays — the serializable half of
-        ``from_host_coo``, split out so a bench parent process can build
-        once and ship the arrays to timing children via .npz without ever
+        ``from_host_coo``, split out so a process can build once and ship
+        the arrays via .npz (``utils/checkpoint.py``) without ever
         attaching to the chip itself.  ``headroom`` reserves extra free
         padding rows per class (see ``from_host_coo``)."""
         from ..tuner import config as tuner_config
@@ -253,8 +253,8 @@ class EllParMat:
         independent of bucket layout, slot order, or which class a
         sticky incremental merge left a row in — so two EllParMats with
         equal content compare bit-exact (the dynamic-merge acceptance
-        check).  A D2H readback: test/tooling path only, never ahead of
-        timed launches on readback-poisoned chips (bench.py)."""
+        check).  A D2H readback: test/tooling path only, never inside a
+        timed section."""
         import jax
 
         lr, lc = self.local_rows, self.local_cols
@@ -1046,8 +1046,7 @@ def build_csr_companion_host(grid: Grid, rows, cols, nrows: int, ncols: int):
 
 
 def build_csc_companion_host(grid: Grid, rows, cols, nrows: int, ncols: int):
-    """Host-only half of ``build_csc_companion`` (numpy in, numpy out) —
-    serializable for the bench parent → timing-children .npz handoff."""
+    """Host-only half of ``build_csc_companion`` (numpy in, numpy out)."""
     return _companion_host(grid, rows, cols, nrows, ncols, major="col")
 
 

@@ -120,31 +120,3 @@ class Grid:
 
     def __eq__(self, other):
         return isinstance(other, Grid) and self.mesh == other.mesh
-
-
-@dataclasses.dataclass(frozen=True)
-class HostGrid:
-    """Device-free stand-in for ``Grid`` carrying only the owner math —
-    for host-only construction (``EllParMat.host_build``,
-    ``build_csc_companion_host``) in processes that must never attach to
-    a chip: the bench parent builds search structures while its timing
-    children own the device (a chip belongs to one process at a time)."""
-
-    pr: int
-    pc: int
-
-    @property
-    def size(self) -> int:
-        return self.pr * self.pc
-
-    def local_rows(self, nrows: int) -> int:
-        return -(-nrows // self.pr)
-
-    def local_cols(self, ncols: int) -> int:
-        return -(-ncols // self.pc)
-
-    def row_owner(self, nrows: int, gr):
-        return gr // self.local_rows(nrows)
-
-    def col_owner(self, ncols: int, gc):
-        return gc // self.local_cols(ncols)

@@ -541,8 +541,8 @@ def _merge_heuristic(sr: Semiring, L: int, expansion_ratio: float,
     distinct bound), where the open-addressing combine's O(nnz) beats
     both the pre-sorts and the one concat sort; ``sort`` otherwise
     (unsorted producers at low L — the r13 scale-12 sweep measured
-    the piece pre-sort + union LOSING to the one concat sort at L=2,
-    benchmarks/results/r13/).  CPU-mesh-measured thresholds; the
+    the piece pre-sort + union LOSING to the one concat sort at L=2).
+    CPU-mesh-measured thresholds; the
     plan store / probe override per key, and a TPU re-measure is an
     open ROADMAP item."""
     from ..ops.spgemm import scatter_combine_for

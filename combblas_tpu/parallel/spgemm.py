@@ -1656,8 +1656,7 @@ def spgemm(
 def _record_realized_nnz(C: SpParMat) -> None:
     """Realized output fill-in (the other half of symbolic-vs-realized).
     Reading ``C.nnz`` is a device readback, so this records ONLY under
-    the explicit ``obs.DEVICE_SYNC`` opt-in — never in a timed section
-    on readback-poisoned hardware (bench.py module docstring)."""
+    the explicit ``obs.DEVICE_SYNC`` opt-in — never in a timed section."""
     if obs.ENABLED and obs.DEVICE_SYNC:
         realized = int(np.asarray(host_value(C.nnz)).sum())
         obs.count("spgemm.realized_nnz", realized)
@@ -1821,7 +1820,7 @@ _PALLAS_KINDS = {
 def _mxu_dot(da, db, mode: str, out_dtype):
     """Dense plus_times stage product at the requested precision.
 
-    Measured on the target chip (benchmarks/results/probe_r4a/b):
+    Measured on the round-4 machine (not re-measured on today's chip):
       f32 native dot      ~0.11 TFLOP/s  (exact f32)
       bf16 inputs         ~13.3 TFLOP/s  (EXACT when inputs are bf16-
                           representable — e.g. 0/1 adjacency — and the
@@ -1865,7 +1864,7 @@ def summa_spgemm_mxu(
     """Dense-block SUMMA: stage products run on the MATRIX UNIT.
 
     On this TPU every sparse-side primitive is capped by the ~22 M/s
-    per-element random-memory wall (PERF_NOTES_r3) while the MXU delivers
+    per-element random-memory wall (round-3 notes) while the MXU delivers
     13.3 TFLOP/s on bf16 blocks — below ~32K tile dims, spending n³ dense
     FLOPs beats sorting the sparse expansion outright: stage tiles densify
     (sorted-scatter), multiply via ``_mxu_dot`` (plus_times; ``mode``
@@ -1942,7 +1941,7 @@ def summa_spgemm_mxu(
 #: matmul (13.3 TFLOP/s bf16 — scale-14 tiles square in 0.7 s) but to the
 #: sparse-output EXTRACTION, which is point-gather/padding-bound at ~3 s+
 #: per 20M entries on the target chip (the full nine-design floor
-#: analysis: benchmarks/results/PERF_NOTES_r4.md).  The sort-based
+#: analysis was round 4's).  The sort-based
 #: kernels take over beyond it.
 MXU_MAX_TILE_DIM = 8192
 
@@ -2159,7 +2158,7 @@ def local_spgemm_windowed(
     loop dispatching one small compiled program PER ROW BLOCK instead of
     the one fused shard_map graph.
 
-    Measured on XLA:CPU at scale 16 (benchmarks/spgemm_bench.py): the
+    Measured on XLA:CPU at scale 16 (round 6): the
     32-block fused program runs 340 s while the same work as separate
     per-block programs runs ~100 s — the giant graph defeats the
     scheduler (and shard_map adds another layer even on one device), so
@@ -2895,9 +2894,8 @@ def _choose_spgemm_tier_2d(
             k_dim=B.local_rows, allow_mxu=False, n_dim=B.local_cols,
         )
     # evaluate every STATIC windowed precondition before paying the
-    # symbolic pass: the device pass ends in a host readback, which on
-    # the target chip permanently degrades later launches (bench.py
-    # module docstring) — never spend it when windowed is structurally
+    # symbolic pass: the device pass ends in a host readback (a sync
+    # on the caller's path) — never spend it when windowed is structurally
     # ineligible (generic monoids, oversized tiles, infeasible panels)
     if (
         scatter_combine_for(sr) is None

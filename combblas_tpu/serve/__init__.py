@@ -36,7 +36,7 @@ batches". Four layers (docs/serving.md has the full architecture):
    one worker thread arbitrated by weighted deficit-round-robin
    (reads AND write merges charge the tenant's share).
 7. **fleet** (`fleet.py`, rounds 14/16) — ``FleetRouter``: N replica
-   servers behind one front door sharing ONE warm plan store —
+   servers of one graph behind one front door —
    least-loaded routing with spillover (dead/closed/draining replicas
    attract no traffic), writes routed to a home replica and fanned
    out through the atomic swap, warm starts from
@@ -49,7 +49,7 @@ batches". Four layers (docs/serving.md has the full architecture):
    substrate (``dynamic/wal.py`` WAL + ``Server``'s background
    checkpointer + ``from_recovery``) is docs/serving.md "Durability &
    self-healing".
-8. **procfleet** (`procfleet.py` + `_procworker.py` + `ipc.py` +
+8. **procfleet** (`procfleet.py` + `_procworker.py` + `frame.py` +
    `policy.py`, round 17) — ``ProcessFleet``: the same fleet with
    REAL crash domains — each replica is an OS subprocess hosting a
    ``Server`` on its own JAX runtime (no shared exec lock: honest
@@ -64,13 +64,13 @@ batches". Four layers (docs/serving.md has the full architecture):
    real SIGKILL/SIGSTOP chaos deterministically.
 9. **net** (`net/`, round 19) — ``NetFrontend``/``NetClient``: the
    TCP front door — a versioned request/reply protocol over the
-   shared frame codec (``frame.py``, factored out of ``ipc.py`` so
-   procfleet and net speak ONE codec over two transports), fronting
+   shared frame codec (``frame.py``: procfleet and net speak ONE
+   codec over two transports), fronting
    any backend above: tenant-header routing into the pool, wire
    deadlines propagating into the SLO budget, the whole error
    taxonomy mapped onto typed protocol status codes (a rejection is
    a wire reply, never a dropped connection), and the open-loop
-   Poisson load harness (``net/loadgen.py``, ``BENCH_SERVE_NET=1``)
+   Poisson load harness (``net/loadgen.py``)
    whose latencies are measured from scheduled arrival time — no
    coordinated omission.
 10. **shard** (`shard.py` + `_shardworker.py`, round 20) —
@@ -88,8 +88,8 @@ batches". Four layers (docs/serving.md has the full architecture):
 
 Everything is wired into ``combblas_tpu.obs`` (queue-depth gauge,
 occupancy/padding-waste/latency histograms, plan-cache and
-``trace.serve`` counters) and measured by ``benchmarks/serve_bench.py``
-against the one-call-per-query baseline.
+``trace.serve`` counters); the served path is measured on the chip by
+``python3 -m chipbench.run`` (``BENCHMARK.json``, ``PERF.md``).
 """
 
 from .batcher import Request, assemble, bucket_width, scatter

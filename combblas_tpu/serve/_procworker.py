@@ -71,7 +71,7 @@ class ProcWorker:
 
     def _reply(self, rid, result=None, exc: Exception | None = None,
                trace: dict | None = None):
-        from .ipc import ChannelClosed
+        from .frame import ChannelClosed
 
         try:
             if exc is None:
@@ -117,7 +117,7 @@ class ProcWorker:
     # -- heartbeat ---------------------------------------------------------
 
     def _hb_loop(self):
-        from .ipc import ChannelClosed
+        from .frame import ChannelClosed
 
         while not self._hb_stop.wait(self.hb_interval_s):
             srv = self.srv
@@ -211,9 +211,7 @@ class ProcWorker:
         self.metrics_interval_s = float(
             m.get("metrics_interval_s", self.metrics_interval_s)
         )
-        # warm BEFORE taking traffic: with the shared plan store
-        # (COMBBLAS_PLAN_STORE in the inherited env) populated, the
-        # remembered lanes replay — the parent asserts zero
+        # warm BEFORE taking traffic — the parent asserts zero
         # post-warmup retraces over IPC (trace_mark/retraces_since)
         warmed = {}
         if m.get("warmup", True):
@@ -381,7 +379,7 @@ def main(argv=None) -> int:
     ap.add_argument("--hb-interval-s", type=float, default=0.25)
     args = ap.parse_args(argv)
     sock = socket.socket(fileno=args.fd)
-    from .ipc import Channel
+    from .frame import Channel
 
     worker = ProcWorker(
         Channel(sock, peer="parent"), hb_interval_s=args.hb_interval_s

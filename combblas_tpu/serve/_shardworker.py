@@ -53,7 +53,7 @@ class ShardWorker:
         self._busy_lock = threading.Lock()
 
     def _reply(self, rid, result=None, exc: Exception | None = None):
-        from .ipc import ChannelClosed
+        from .frame import ChannelClosed
 
         try:
             if exc is None:
@@ -72,7 +72,7 @@ class ShardWorker:
     # -- heartbeat ---------------------------------------------------------
 
     def _hb_loop(self):
-        from .ipc import ChannelClosed
+        from .frame import ChannelClosed
 
         while not self._hb_stop.wait(self.hb_interval_s):
             rt = self.rt
@@ -226,7 +226,7 @@ def main(argv=None) -> int:
     ap.add_argument("--hb-interval-s", type=float, default=0.25)
     args = ap.parse_args(argv)
     sock = socket.socket(fileno=args.fd)
-    from .ipc import Channel
+    from .frame import Channel
 
     worker = ShardWorker(
         Channel(sock, peer="parent"),

@@ -661,9 +661,7 @@ class Server:
         (``dynamic.wal.recover_version`` — bit-exact with the engine
         that crashed, acknowledged writes included), with the WAL
         re-attached at the seqno frontier so the write lane resumes
-        the same lineage.  Run ``warmup()`` before serving — with the
-        shared plan store populated it replays the fleet's remembered
-        lanes: zero retraces, zero re-measurement."""
+        the same lineage.  Run ``warmup()`` before serving."""
         from ..dynamic import wal as dyn_wal
         from ..tuner import config as tuner_config
         from .engine import GraphEngine

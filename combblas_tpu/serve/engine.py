@@ -734,25 +734,7 @@ class GraphEngine:
             p = self._build_plan(kind, int(width))
             with self._plans_lock:
                 self._plans[key] = p
-            self._record_lane(kind, int(width))
             return p
-
-    def _record_lane(self, kind: str, width: int) -> None:
-        """Remember a traced (kind, width) lane in the persisted plan
-        store (round 10): a FRESH process's ``warmup()`` replays the
-        recorded lane set, reaching zero-retrace steady state without
-        re-discovering which lanes the traffic mix actually uses.
-        Best-effort — a store problem must never fail serving."""
-        try:
-            from ..tuner import store as plan_store
-
-            st = plan_store.get_store()
-            if st is not None:
-                st.add_serve_lane(
-                    plan_store.serve_plan_key(self), kind, width
-                )
-        except Exception:
-            pass
 
     def _build_plan(self, kind: str, width: int) -> _Plan:
         import jax
@@ -920,38 +902,18 @@ class GraphEngine:
         ``retraces_since(mark)`` or the ``trace.serve`` obs counter.
         Returns {(kind, width): seconds}.
 
-        ``widths=None`` (default) warms ``DEFAULT_WARMUP_WIDTHS`` PLUS
-        every lane the plan store remembers for this graph's shape
-        bucket (``tuner.store`` — lanes are recorded on each plan-cache
-        miss), so a fresh replica pre-traces exactly what the fleet's
-        traffic mix used, without re-measuring.  Explicit ``widths``
-        warms exactly those.
+        ``widths=None`` (default) warms ``DEFAULT_WARMUP_WIDTHS``;
+        explicit ``widths`` warms exactly those.  A ``Server`` always
+        passes its config's ``lane_widths`` (``Server.warmup``): the
+        widths its batcher can form.
         """
         import jax
 
         kinds = self.kinds() if kinds is None else kinds
-        per_kind = {
-            k: set(self.DEFAULT_WARMUP_WIDTHS if widths is None
-                   else widths)
-            for k in kinds
-        }
-        if widths is None:
-            try:
-                from ..tuner import store as plan_store
-
-                st = plan_store.get_store()
-                lanes = (
-                    st.serve_lanes(plan_store.serve_plan_key(self))
-                    if st is not None else ()
-                )
-            except Exception:
-                lanes = ()
-            for k, w in lanes:
-                if k in per_kind:
-                    per_kind[k].add(int(w))
+        widths = self.DEFAULT_WARMUP_WIDTHS if widths is None else widths
         out = {}
         for kind in kinds:
-            for w in sorted(per_kind[kind]):
+            for w in sorted(set(widths)):
                 t0 = time.perf_counter()
                 with self._exec_lock, obs.span(
                     "serve.warmup", kind=kind, width=int(w)

@@ -5,13 +5,11 @@ GRAPHS behind one device; the fleet multiplexes many REPLICAS of one
 graph behind one router, the shape a real service scales reads with.
 Properties that make it more than a load balancer:
 
-* **One warm plan store.** Every replica resolves routing and records
-  serve warmup lanes through the SAME ``tuner.store`` JSONL (already
-  multi-process-safe, append-only, torn-write tolerant) — the first
-  replica's traffic teaches the store which (kind, width) lanes the mix
-  uses, and every later replica's ``warmup()`` replays them to
-  zero-retrace steady state without re-discovering anything
-  (docs/autotuning.md "Shipping plans to a fleet", now code).
+* **One lane set.** Every replica warms its config's ``lane_widths``
+  (``Server.warmup``): the widths its batcher can form, so a warmed
+  replica serves with zero retraces.  Kernel routing resolves through
+  the one ``tuner.store`` JSONL (multi-process-safe, append-only,
+  torn-write tolerant).
 * **Warm starts from snapshots.** ``FleetRouter.from_checkpoint``
   boots every replica from one ``utils.checkpoint.save_version``
   GraphVersion snapshot: bucket arrays re-upload bit-identically
@@ -85,7 +83,7 @@ def _strip_wal(cfg: ServeConfig, keep: str | None) -> ServeConfig:
 
 
 class FleetRouter(ReplicaFleetBase):
-    """Front door over N replica ``Server``s sharing one plan store."""
+    """Front door over N replica ``Server``s of one graph."""
 
     def __init__(self, servers, home: int = 0,
                  build_kw: dict | None = None):
@@ -245,9 +243,8 @@ class FleetRouter(ReplicaFleetBase):
         replica's version = latest valid snapshot + WAL-suffix replay
         (``dynamic.wal.recover_version`` — bit-exact with the fleet
         that crashed, every acknowledged write included), the home
-        re-attached to the WAL at the seqno frontier.  With the shared
-        plan store populated, ``warmup()`` replays the remembered
-        lanes — warm plans, zero retraces, zero re-measurement."""
+        re-attached to the WAL at the seqno frontier.  Run
+        ``warmup()`` before serving."""
         from .api import Server
         from .engine import GraphEngine
         from ..dynamic import wal as dyn_wal
@@ -493,8 +490,8 @@ class FleetRouter(ReplicaFleetBase):
 
     def _spawn_replica(self, i: int, engine, started: bool) -> None:
         """Install a fresh ``Server`` shell around ``engine`` at slot
-        ``i`` (shared exec lock, same tenant label), warmed from the
-        shared plan store before it takes traffic."""
+        ``i`` (shared exec lock, same tenant label), warmed before it
+        takes traffic."""
         from .api import Server
 
         cfg = _strip_wal(
@@ -505,8 +502,7 @@ class FleetRouter(ReplicaFleetBase):
         new = Server(engine, cfg, tenant=f"replica{i}")
         if started:
             new.start()
-        # warm BEFORE admitting traffic: the shared store replays the
-        # fleet's remembered lanes, so the replacement reaches
+        # warm BEFORE admitting traffic: the replacement reaches
         # zero-retrace steady state off the routing path
         try:
             new.warmup()
@@ -627,9 +623,8 @@ class FleetRouter(ReplicaFleetBase):
     # -- lifecycle / introspection -----------------------------------------
 
     def warmup(self, **kw) -> dict:
-        """Warm every replica. With the shared plan store populated
-        (a prior replica's traffic), each replica pre-traces the
-        remembered lanes — the fleet-wide zero-retrace claim."""
+        """Warm every replica (each its config's ``lane_widths``): the
+        fleet-wide zero-retrace claim."""
         return {
             i: srv.warmup(**kw) for i, srv in enumerate(self.replicas)
         }

@@ -6,9 +6,8 @@ versioned wire protocol (``protocol.py``, spoken over the shared
 ``serve/frame.py`` codec — one codec, two transports) to any
 in-process backend (``Server``/``PoolServer``/``FleetRouter``/
 ``ProcessFleet``); ``client.py`` is the blocking client; and
-``loadgen.py`` is the OPEN-LOOP Poisson load harness
-(``BENCH_SERVE_NET=1``) — the coordinated-omission-free capstone
-serving bench.  docs/serving.md "Network front door" has the
+``loadgen.py`` is the OPEN-LOOP Poisson load harness (latency from
+the scheduled arrival: no coordinated omission).  docs/serving.md "Network front door" has the
 protocol frames, the status taxonomy table, and deadline semantics.
 """
 

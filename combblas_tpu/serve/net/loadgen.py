@@ -1,10 +1,10 @@
 """Open-loop load generator for the network front door (r19).
 
-The closed-loop ``serve_bench`` scenarios submit, wait, submit again —
-so when the server slows down, the BENCH slows its arrival rate with
-it and the recorded latencies silently exclude the queueing the real
-world would have seen (COORDINATED OMISSION).  This harness is the
-open-loop antidote, and the capstone serving bench later PRs cite:
+A closed-loop client submits, waits, submits again — so when the
+server slows down, the client slows its arrival rate with it and the
+recorded latencies silently exclude the queueing the real world would
+have seen (COORDINATED OMISSION).  This harness is the open-loop
+antidote:
 
 * arrivals are a SEEDED POISSON PROCESS at a target rate — the full
   schedule (exponential inter-arrival gaps, connection choice, root
@@ -18,8 +18,8 @@ open-loop antidote, and the capstone serving bench later PRs cite:
   user would experience it;
 * hundreds of concurrent connections against a 2+-replica
   ``ProcessFleet``, optionally under scripted ``ProcessFaultPlan``
-  chaos (``BENCH_NET_CHAOS=1`` SIGKILLs a non-home replica mid-run
-  with the supervisor healing around it).
+  chaos (``chaos=True`` SIGKILLs a non-home replica mid-run with the
+  supervisor healing around it).
 
 Reported per run: offered vs achieved rate, p50/p99 latency,
 availability, every rejection bucketed by its TYPED protocol status
@@ -29,20 +29,15 @@ the wire, and the stitched ``net -> router -> ipc -> child`` stage
 decomposition folded from the same schema-``trace`` records the rest
 of the observability plane uses.
 
-Knobs (tuner/config.py): ``BENCH_NET_RATE`` (req/s),
-``BENCH_NET_CONNS``, ``BENCH_NET_SECONDS``.  Entry:
-``BENCH_SERVE_NET=1 python benchmarks/serve_bench.py`` (or
-``python -m combblas_tpu.serve.net.loadgen``), emitting the standard
-``{summary, metric, value, median, warning, rc}`` headline contract —
-``warning`` is ``None`` here; the closed-loop scenarios are the ones
-stamped ``"closed-loop (coordinated omission)"``.
+Entry: ``run()``; ``tests/test_serve_net.py`` holds the properties
+(``test_open_loop_gate_small_fleet`` and its chaos twin, both ``slow``).
+It is not a measurement of the chip: the repo's benchmark is
+``python3 -m chipbench.run`` (``BENCHMARK.json``).
 """
 
 from __future__ import annotations
 
-import json
 import os
-import sys
 import tempfile
 import threading
 import time
@@ -51,7 +46,6 @@ import numpy as np
 
 from ... import obs
 from ...obs import trace as obs_trace
-from ...tuner import config as tuner_config
 from ..policy import ReplicaDeadError
 from ..scheduler import BackpressureError, CircuitBreakerOpen
 from .client import NetClient
@@ -118,20 +112,16 @@ def _decompose(records) -> dict:
     return out
 
 
-def run(rate: float | None = None, conns: int | None = None,
-        seconds: float | None = None, *, scale: int = 8,
+def run(rate: float = 200.0, conns: int = 128,
+        seconds: float = 8.0, *, scale: int = 8,
         edgefactor: int = 8, replicas: int = 2, chaos: bool = False,
         seed: int = 7, kind: str = "bfs",
         deadline_s: float | None = 2.0, trace_rate: float = 1.0,
         backend=None) -> dict:
-    """One open-loop run; returns the result dict (``main`` wraps it
-    in the headline contract).  ``backend=None`` builds (and owns) a
-    ``ProcessFleet``; passing a backend reuses it (tests)."""
+    """One open-loop run; returns the result dict.  ``backend=None``
+    builds (and owns) a ``ProcessFleet``; passing a backend reuses it
+    (tests)."""
     from ...utils.rmat import rmat_symmetric_coo_host
-
-    rate = tuner_config.bench_net_rate(rate)
-    conns = tuner_config.bench_net_conns(conns)
-    seconds = tuner_config.bench_net_seconds(seconds)
 
     was_enabled = obs.ENABLED
     if not was_enabled:
@@ -319,51 +309,3 @@ def run(rate: float | None = None, conns: int | None = None,
         if own_fleet:
             backend.close(drain=False)
         obs_trace.set_sample_rate(prev_rate)
-
-
-def main() -> int:
-    """The ``BENCH_SERVE_NET=1`` entry: run, print the detail dict,
-    then emit the headline ``{summary, metric, value, median,
-    warning, rc}`` line + BENCH_SUMMARY.json (suppressed under
-    bench.py's child runner via BENCH_EMIT_SUMMARY=0, where the
-    detail line must stay last)."""
-    chaos = os.environ.get("BENCH_NET_CHAOS", "0") not in ("", "0")
-    scale = int(os.environ.get("BENCH_SERVE_SCALE", "8") or 8)
-    replicas = int(os.environ.get("BENCH_NET_REPLICAS", "2") or 2)
-    from ...utils import device_fields
-
-    out = run(chaos=chaos, scale=scale, replicas=replicas)
-    # read after run(): the fleet is closed, so the router starting its
-    # own backend takes nothing from a replica
-    dev = device_fields()
-    out.update(dev)
-    print(json.dumps(out), flush=True)
-    if os.environ.get("BENCH_EMIT_SUMMARY", "1") == "0":
-        return 0
-    rc = 0 if out.get("ok") else 1
-    s = {
-        "summary": 1,
-        "metric": out.get("metric"),
-        "value": out.get("value", 0.0),
-        "median": out.get("p50_ms", 0.0),
-        "warning": out.get("warning"),
-        "rc": rc,
-        "offered_qps": out.get("offered_qps"),
-        "achieved_qps": out.get("achieved_qps"),
-        "availability": out.get("availability"),
-        "decomposition": out.get("decomposition"),
-        **dev,
-    }
-    path = os.environ.get("BENCH_SUMMARY_PATH", "BENCH_SUMMARY.json")
-    try:
-        with open(path, "w") as f:
-            json.dump(s, f)
-            f.write("\n")
-    except OSError as e:
-        s["summary_write_error"] = f"{path}: {e}"
-    print(json.dumps(s), flush=True)
-    return rc
-
-
-if __name__ == "__main__":
-    sys.exit(main())

@@ -18,12 +18,11 @@ chip per child on a TPU host), so
   only ITSELF: the router's per-request IPC deadlines fail its
   in-flight futures and the heartbeat timeout routes around it, and
 * replicas execute in PARALLEL — N processes, N meshes, no shared
-  lock: the first honest replica-parallelism measurement
-  (``BENCH_FLEET=process``).
+  lock (the thread fleet's replicas serialise on one exec lock).
 
 What is SHARED is exactly what PR 14 built process-safe: the plan
-store (children inherit ``COMBBLAS_PLAN_STORE`` and warm from it —
-zero post-warmup retraces, asserted over IPC), the WAL + checkpoint
+store (children inherit ``COMBBLAS_PLAN_STORE``; zero post-warmup
+retraces are asserted over IPC), the WAL + checkpoint
 durability dir (the HOME child owns the log; promotion and respawn
 recover from the files), and the spool dir graph versions travel
 through as ``save_version`` checkpoints (``swap_from_checkpoint`` —
@@ -61,7 +60,7 @@ from ..tuner import config as tuner_config
 from ..utils import inherited_platform
 from .batcher import settle
 from .faults import ProcessFaultPlan
-from .ipc import Channel, ChannelClosed
+from .frame import Channel, ChannelClosed
 from .policy import ReplicaDeadError, ReplicaFleetBase, StaleEpochError
 from .scheduler import BackpressureError, ServeConfig
 
@@ -731,8 +730,8 @@ class ProcessFleet(ReplicaFleetBase):
 
     def _spawn(self, i: int, recover: bool, home: bool) -> ReplicaProc:
         """Fork + synchronously boot one replica (the respawn path —
-        load checkpoint / recover, start server, warm from the shared
-        plan store): the replica is serving when this returns."""
+        load checkpoint / recover, start server, warm up): the replica
+        is serving when this returns."""
         rp = self._launch(i)
         try:
             boot = rp.call(
@@ -1105,8 +1104,8 @@ class ProcessFleet(ReplicaFleetBase):
     def _replace_replica(self, i: int) -> None:
         """Respawn a dead slot warm from checkpoint+WAL: quarantine
         (SIGKILL — also the answer to a SIGSTOPped zombie), then a
-        fresh subprocess boots via recovery and warms from the shared
-        plan store before re-admission."""
+        fresh subprocess boots via recovery and warms up before
+        re-admission."""
         old = self.replicas[i]
         if not old.quarantined:
             old.quarantine(ReplicaDeadError(

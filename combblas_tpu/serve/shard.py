@@ -76,8 +76,8 @@ keep serving throughout (reads heal-and-retry, bounded).  The network
 front door runs UNCHANGED on top — the proof this is one service.
 
 Obs series live under ``serve.shard.*`` (cataloged in
-``obs/metrics.py``); the acceptance gate is ``BENCH_SERVE_SHARD=1``
-(benchmarks/serve_bench.py, r20).
+``obs/metrics.py``); ``tests/test_serve_shard.py`` holds the
+properties (answers bit-exact with the unsharded engine, recovery).
 """
 
 from __future__ import annotations
@@ -102,8 +102,7 @@ from ..dynamic import wal as dyn_wal
 from ..dynamic.delta import DeltaBatch
 from ..tuner import config as tuner_config
 from ..utils import checkpoint as ckpt
-from .frame import SparseFrontier, pack_bf16, unpack_bf16
-from .ipc import Channel
+from .frame import Channel, SparseFrontier, pack_bf16, unpack_bf16
 from .policy import ReplicaDeadError, StaleEpochError
 from .procfleet import IpcTimeoutError, ReplicaProc, child_env
 
@@ -436,8 +435,9 @@ class SliceRuntime:
     def _slab_row_gids(self):
         """[1, ls] GLOBAL row ids of this slab as a materialized device
         operand (the ``_gid_blocks`` stance: in-program iota serializes
-        inside loop fusions; unsharded on a 1-device grid — the 25x
-        sharded-operand pathology, probe_seq_r5 w3)."""
+        inside loop fusions; unsharded on a 1-device grid — the
+        sharded-operand pathology of the round-5 machine, not
+        re-measured)."""
         if self._row_gids is None:
             import jax
             import jax.numpy as jnp
@@ -814,8 +814,7 @@ class SliceRuntime:
         """Pre-trace every (kind, width) hop program AND every pow2
         scatter-capacity bucket on inert all-pad steps (empty frontier
         / all-inf distances / zero indicator) — after this, serving
-        inside the warmed set performs ZERO traces under ANY encoding
-        (asserted over IPC by the bench)."""
+        inside the warmed set performs ZERO traces under ANY encoding."""
         kinds = self.kinds if kinds is None else tuple(kinds)
         widths = (1, 2, 4, 8, 16) if widths is None else tuple(widths)
         out = {}

@@ -14,9 +14,8 @@ Three pieces (see docs/autotuning.md):
   rungs on a bounded deterministic proxy and write the winner back.
 
 ``parallel.spgemm.spgemm_auto`` and ``parallel.mesh3d.spgemm3d``
-consult the store; ``serve.GraphEngine`` records/replays warmup lanes
-through it.  The probe module is imported lazily (it pulls in the
-kernels); config and store are dependency-light.
+consult the store.  The probe module is imported lazily (it pulls in
+the kernels); config and store are dependency-light.
 """
 
 from . import config  # noqa: F401
@@ -29,7 +28,6 @@ from .store import (  # noqa: F401
     density_band,
     get_store,
     plan_key_from_counts,
-    serve_plan_key,
     shape_bucket,
     spgemm3d_plan_key,
     spgemm_plan_key,
@@ -46,7 +44,6 @@ __all__ = [
     "density_band",
     "get_store",
     "plan_key_from_counts",
-    "serve_plan_key",
     "shape_bucket",
     "spgemm3d_plan_key",
     "spgemm_plan_key",

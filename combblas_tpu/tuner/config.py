@@ -26,8 +26,7 @@ Resolution precedence (documented ONCE, here):
   disabled or over budget.
 
 Env-var conventions shared by every knob: unset or empty means
-"default"; for the integer knobs ``"0"`` also means default (the bench
-convention since round 6).
+"default"; for the integer knobs ``"0"`` also means default.
 """
 
 from __future__ import annotations
@@ -123,18 +122,10 @@ ENV_OBS_HB_METRICS_S = "COMBBLAS_OBS_HB_METRICS_S"
 #: from ``NetFrontend.port``); ``COMBBLAS_NET_MAX_CONNS`` caps open
 #: connections (past it a hello gets a typed ``backpressure`` wire
 #: reply, never a silent close); ``COMBBLAS_NET_ACCEPT_BACKLOG`` is
-#: the kernel ``listen()`` queue depth.  The ``BENCH_NET_*`` knobs
-#: parameterize the open-loop load generator
-#: (``serve/net/loadgen.py``): target arrival rate (req/s),
-#: concurrent connections, and run length — parsed HERE (not inline
-#: in the bench) so the vetting and "0 means default" semantics match
-#: every other knob.
+#: the kernel ``listen()`` queue depth.
 ENV_NET_PORT = "COMBBLAS_NET_PORT"
 ENV_NET_MAX_CONNS = "COMBBLAS_NET_MAX_CONNS"
 ENV_NET_ACCEPT_BACKLOG = "COMBBLAS_NET_ACCEPT_BACKLOG"
-ENV_BENCH_NET_RATE = "BENCH_NET_RATE"
-ENV_BENCH_NET_CONNS = "BENCH_NET_CONNS"
-ENV_BENCH_NET_SECONDS = "BENCH_NET_SECONDS"
 
 #: Round-21 knobs: the sharded hop wire protocol (docs/serving.md
 #: "Sharded hop wire protocol").  ``COMBBLAS_SHARD_FRONTIER`` picks
@@ -206,13 +197,6 @@ DEFAULT_OBS_HB_METRICS_S = 1.0
 DEFAULT_NET_PORT = 0
 DEFAULT_NET_MAX_CONNS = 512
 DEFAULT_NET_ACCEPT_BACKLOG = 128
-#: Open-loop load-generator defaults (round 19): 200 req/s offered
-#: over 128 connections for 8 seconds — small enough for a laptop,
-#: large enough that coordinated omission would be visible if the
-#: harness had it.
-DEFAULT_BENCH_NET_RATE = 200.0
-DEFAULT_BENCH_NET_CONNS = 128
-DEFAULT_BENCH_NET_SECONDS = 8.0
 #: Sharded-wire defaults (round 21): adaptive frontier encoding with
 #: dense fallback once the live frontier fills a quarter of the
 #: ``[n, W]`` operand (past ~0.25 the per-entry triple overhead —
@@ -230,8 +214,7 @@ def _str_env(name: str) -> str | None:
 
 
 def _int_env(name: str) -> int | None:
-    """Unset, empty, and "0" all mean "use the default" (the bench
-    knob convention: BENCH_BLOCK_ROWS=0 falls through)."""
+    """Unset, empty, and "0" all mean "use the default"."""
     v = os.environ.get(name)
     if not v:
         return None
@@ -504,52 +487,6 @@ def net_accept_backlog(given: int | str | None = None) -> int:
         return DEFAULT_NET_ACCEPT_BACKLOG
     n = _vet_int(ENV_NET_ACCEPT_BACKLOG, v, "an integer backlog")
     return DEFAULT_NET_ACCEPT_BACKLOG if n == 0 else max(n, 1)
-
-
-def bench_net_rate(given: float | str | None = None) -> float:
-    """Open-loop offered arrival rate (req/s): explicit argument >
-    ``BENCH_NET_RATE`` > 200.  ``0``/unset = default; a bogus value
-    raises naming the knob."""
-    v = os.environ.get(ENV_BENCH_NET_RATE) if given is None else given
-    if v is None or v == "":
-        return DEFAULT_BENCH_NET_RATE
-    try:
-        r = float(v)
-    except (TypeError, ValueError):
-        raise ValueError(
-            f"{ENV_BENCH_NET_RATE} must be a request rate in req/s; "
-            f"got {v!r}"
-        ) from None
-    return DEFAULT_BENCH_NET_RATE if r == 0 else max(r, 0.1)
-
-
-def bench_net_conns(given: int | str | None = None) -> int:
-    """Open-loop concurrent connection count: explicit argument >
-    ``BENCH_NET_CONNS`` > 128.  ``0``/unset = default; clamped >= 1."""
-    v = os.environ.get(ENV_BENCH_NET_CONNS) if given is None else given
-    if v is None or v == "":
-        return DEFAULT_BENCH_NET_CONNS
-    n = _vet_int(ENV_BENCH_NET_CONNS, v, "an integer connection count")
-    return DEFAULT_BENCH_NET_CONNS if n == 0 else max(n, 1)
-
-
-def bench_net_seconds(given: float | str | None = None) -> float:
-    """Open-loop run length in seconds: explicit argument >
-    ``BENCH_NET_SECONDS`` > 8.  ``0``/unset = default."""
-    v = (
-        os.environ.get(ENV_BENCH_NET_SECONDS)
-        if given is None else given
-    )
-    if v is None or v == "":
-        return DEFAULT_BENCH_NET_SECONDS
-    try:
-        s = float(v)
-    except (TypeError, ValueError):
-        raise ValueError(
-            f"{ENV_BENCH_NET_SECONDS} must be a duration in seconds; "
-            f"got {v!r}"
-        ) from None
-    return DEFAULT_BENCH_NET_SECONDS if s == 0 else max(s, 0.1)
 
 
 def shard_frontier(given: str | None = None) -> str:

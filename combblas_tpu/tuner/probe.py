@@ -180,8 +180,6 @@ def probe_spgemm(
     budget_s: float | None = None,
     max_dim: int | None = None,
     seed: int = 0,
-    host_coo_a=None,
-    host_coo_b=None,
     measure=None,
     tier_order=None,
     geometry: bool = True,
@@ -192,10 +190,8 @@ def probe_spgemm(
     measurement was possible (empty proxy) — the caller then falls
     back to the heuristic.
 
-    ``host_coo_a``/``host_coo_b`` ((rows, cols, vals) host arrays) skip
-    the operand readback for callers that still hold the construction
-    COO (the benches: no device readback).  ``measure`` injects the cost
-    functional (tests use a deterministic fake; default wall time);
+    ``measure`` injects the cost functional (tests use a
+    deterministic fake; default wall time);
     ``tier_order`` overrides the admissibility-gated candidate list
     and ``geometry=False`` skips the windowed block-shape sweep (both
     for deterministic tests — production callers leave the defaults)."""
@@ -205,12 +201,7 @@ def probe_spgemm(
     max_dim = config.probe_max_dim() if max_dim is None else max_dim
     measure = _default_measure if measure is None else measure
 
-    def host_coo(M, given):
-        if given is not None:
-            return given
-        return M.to_global_coo()
-
-    ra, ca, va = host_coo(A, host_coo_a)
+    ra, ca, va = A.to_global_coo()
     pm = _proxy_dim(A.nrows, max_dim)
     pk = _proxy_dim(A.ncols, max_dim)
     pn = _proxy_dim(B.ncols, max_dim)
@@ -221,8 +212,7 @@ def probe_spgemm(
         ra, ca, (A.nrows, A.ncols), (pm, pk), seed=seed,
         modes=("restrict", "fold"),
     )
-    rb, cb, vb = (ra, ca, va) if (B is A and host_coo_b is None) \
-        else host_coo(B, host_coo_b)
+    rb, cb, vb = (ra, ca, va) if B is A else B.to_global_coo()
     pbr, pbc, keep_b = downsample_coo(
         rb, cb, (B.nrows, B.ncols), (pk, pn), seed=seed,
         modes=("fold", "restrict"),
@@ -289,10 +279,9 @@ def probe_spgemm(
     # probe measured the WINDOWED rung at its default block geometry;
     # when windowed won and budget remains, sweep a small block_rows /
     # block_cols grid on the same proxy and persist the winning
-    # geometry WITH the plan (before this, geometry was recordable only
-    # via BENCH_PLAN_RECORD=1).  Proxy-scale geometry transfers as a
-    # measured hint — a bench-recorded real-scale plan (source="bench")
-    # overwrites it on the next record.
+    # geometry WITH the plan.  Proxy-scale geometry transfers as a
+    # measured hint — a real-scale plan put() under the same key
+    # overwrites it.
     best_geo = (None, None)
     if geometry and winner == "windowed" and spent < budget_s:
         best_cost = costs[winner]

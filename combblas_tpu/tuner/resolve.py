@@ -1,19 +1,13 @@
 """Shared ``store > env > probe > heuristic`` tier resolution.
 
 ``spgemm_auto`` and ``mesh3d.spgemm3d`` resolve their tier through the
-precedence chain documented in :mod:`~combblas_tpu.tuner.config`; the
-bench drivers (which decide from HOST counts before touching the
-device — host-side sizing, no readback) used to re-implement that
-chain inline, and the copies skipped the library's record vetting: a hand-mangled or
-wrong-op store line would route a bench where the library would have
-rejected it.  :func:`resolve_tier` is the ONE walk of the chain both
-benches share.
-
-The library routers keep their own inlined resolution (they interleave
-record geometry / ring / dispatch fills the benches don't carry), but
-the VETTING semantics — unknown tier rejected with
+precedence chain documented in :mod:`~combblas_tpu.tuner.config` with
+their own inlined resolution (they interleave record geometry / ring /
+dispatch fills); :func:`resolve_tier` is the same walk for callers that
+need the tier alone (``parallel/spmm.py``'s backend), with the same
+VETTING semantics — unknown tier rejected with
 ``tuner.store.rejected{reason=tier}``, the winning source counted as
-``spgemm.auto.plan_source`` — are identical by construction here.
+``spgemm.auto.plan_source``.
 """
 
 from __future__ import annotations
@@ -53,8 +47,7 @@ def resolve_tier(
     * ``account`` — ``True`` uses ``store.lookup`` (hit/miss counters +
       ``spgemm.auto.plan_source``); ``False`` uses ``store.peek`` and
       emits NOTHING — the mirror mode for callers whose library call
-      does the accounted resolution itself (spgemm3d_bench's
-      provenance JSON).
+      does the accounted resolution itself.
     """
     if tier is not None:
         source, rec = "arg", None

@@ -21,11 +21,6 @@ library down.  Writes append a fully formed line (single ``write``
 call), so a torn write from a dying process truncates to an invalid
 LAST line, not a poisoned store.
 
-The store also remembers SERVE WARMUP LANES: the (kind, width) plan
-cache entries a serving process actually used, so a fresh replica's
-``GraphEngine.warmup()`` pre-traces exactly the lanes the fleet serves
-(zero steady-state retraces without re-measuring).
-
 Host-side counters (``stats()``) are plain ints and always live; the
 obs mirrors (``tuner.store.{hits,misses,entries}`` ...) cost nothing
 when telemetry is disabled.
@@ -50,7 +45,7 @@ from . import config
 SCHEMA = "combblas_tpu.plans/v1"
 
 _TIERS = (
-    "mxu", "windowed", "scan", "esc", "windowed3d", "serve",
+    "mxu", "windowed", "scan", "esc", "windowed3d",
     # op="spmm" backends (round 12): the MXU gather-contract lane and
     # its exact-everywhere scatter/fold fallback
     "mxu_gather", "scatter",
@@ -75,8 +70,8 @@ def density_band(nnz: int, dim: int) -> int:
 @dataclasses.dataclass(frozen=True)
 class PlanKey:
     """What a plan is keyed by.  ``op`` distinguishes the 2D router
-    ("spgemm"), the 3D entry ("spgemm3d"), and serve warmup lane sets
-    ("serve"); ``grid3`` is "" for 2D products."""
+    ("spgemm"), the 3D entry ("spgemm3d") and SpMM ("spmm");
+    ``grid3`` is "" for 2D products."""
 
     op: str
     shape: tuple[int, int, int]   # shape buckets of (m, k, n)
@@ -112,9 +107,7 @@ class PlanRecord:
     """One remembered decision: the winning tier plus the knobs it was
     measured with and the measured cost.  ``block_rows``/``block_cols``
     of ``None`` mean "the kernel default for this shape" (the probe
-    records what it actually ran).  ``lanes`` is the serve-warmup
-    variant's payload ((kind, width) pairs); spgemm records leave it
-    empty."""
+    records what it actually ran)."""
 
     tier: str
     block_rows: int | None = None
@@ -128,18 +121,15 @@ class PlanRecord:
     #: lines load as None, so the field is schema-additive.
     merge: str | None = None
     cost_s: float | None = None
-    source: str = "probe"          # probe | manual | bench
+    source: str = "probe"          # probe | manual
     probe_dim: int | None = None   # proxy dimension the cost came from
-    lanes: tuple = ()
     #: Measurement wall-clock (``time.time()``): the aging policy's
     #: eviction order — records without one age out first.  Excluded
     #: from equality (bookkeeping, not part of the decision).
     ts: float | None = dataclasses.field(default=None, compare=False)
 
     def to_json(self) -> dict:
-        d = dataclasses.asdict(self)
-        d["lanes"] = [list(x) for x in self.lanes]
-        return d
+        return dataclasses.asdict(self)
 
     @staticmethod
     def from_json(d: dict) -> "PlanRecord":
@@ -172,9 +162,6 @@ class PlanRecord:
             probe_dim=(
                 None if d.get("probe_dim") is None
                 else int(d["probe_dim"])
-            ),
-            lanes=tuple(
-                (str(k), int(w)) for k, w in d.get("lanes", ())
             ),
             ts=None if d.get("ts") is None else float(d["ts"]),
         )
@@ -436,31 +423,6 @@ class PlanStore:
             obs.gauge("tuner.store.entries", len(self._plans),
                       dir=self.path)
 
-    def add_serve_lane(self, key: PlanKey, kind: str,
-                       width: int) -> bool:
-        """Merge one (kind, width) into the serve-lane record for
-        ``key``; returns True (and persists) iff the lane is new."""
-        import time
-
-        lane = (str(kind), int(width))
-        with self._lock:
-            rec = self._plans.get(key)
-            if rec is None:
-                rec = PlanRecord(tier="serve", source="serve")
-                self._plans[key] = rec
-            if lane in rec.lanes:
-                return False
-            rec.lanes = tuple(sorted(set(rec.lanes) | {lane}))
-            rec.ts = time.time()  # an actively-serving graph's lane
-            # set stays young under the aging policy
-        self._append(key, rec)
-        return True
-
-    def serve_lanes(self, key: PlanKey) -> tuple:
-        with self._lock:
-            rec = self._plans.get(key)
-            return rec.lanes if rec is not None else ()
-
     # -- bookkeeping -------------------------------------------------------
 
     def record_probe(self, runs: int, seconds: float) -> None:
@@ -615,21 +577,4 @@ def spmm_plan_key(sr, E, feat_width: int,
         backend="",
         grid=f"{E.grid.pr}x{E.grid.pc}",
         platform=platform,
-    )
-
-
-def serve_plan_key(engine) -> PlanKey:
-    """Key for a serving engine's warmup-lane record: the graph's shape
-    bucket + density band + grid (version-independent — hot-swapped
-    same-shape versions keep the same lane set)."""
-    v = engine.version
-    nnz = max(int(getattr(v, "nnz", -1)), 1)
-    return PlanKey(
-        op="serve",
-        shape=(shape_bucket(int(v.nrows)),
-               shape_bucket(int(v.ncols)), 0),
-        band=(density_band(nnz, int(v.nrows)), 0),
-        sr="",
-        backend="",
-        grid=f"{engine.grid.pr}x{engine.grid.pc}",
     )

@@ -1,9 +1,8 @@
 """Persistent XLA compilation cache switch, shared by every driver.
 
-One definition so ``chip_smoke.py``, the benches and every probe run
-under identical cache behavior; ``BENCH_NOCACHE=1`` disables for
-diagnostics.  Library constructors never call it: a process entry point
-does, once, at start.
+One definition so ``chip_smoke.py`` and ``chipbench`` run under
+identical cache behavior.  Library constructors never call it: a process
+entry point does, once, at start.
 
 PLACEMENT: where ``JAX_COMPILATION_CACHE_DIR`` is set, the cache lives
 there and this module sets NO directory in code (JAX reads the variable
@@ -104,9 +103,6 @@ def enable_compile_cache(cache_dir: str | None = None) -> None:
 
     if obs.ENABLED:
         obs.install_jax_hooks()
-    if os.environ.get("BENCH_NOCACHE") == "1":
-        obs.count("compile_cache.disabled")
-        return
     env_dir = _env_dir()
     # abspath: "cache" and os.path.abspath("cache") are the same dir,
     # and the committed identity must not drift under a later chdir
@@ -125,8 +121,9 @@ def enable_compile_cache(cache_dir: str | None = None) -> None:
         )
     if _configured_dir is not None:
         # cache_dir=None means "ensure enabled", not "move to the
-        # default dir" — every argless caller (bench.py, probes) must
-        # keep working after someone committed a custom dir
+        # default dir" — every argless caller (chip_smoke.py,
+        # chipbench) must keep working after someone committed a
+        # custom dir
         return  # idempotent re-enable
     if not env_dir:
         jax.config.update("jax_compilation_cache_dir", resolved)

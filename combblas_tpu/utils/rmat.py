@@ -68,8 +68,8 @@ def rmat_symmetric_coo_host(
 ):
     """Pure-numpy R-MAT (same kernel as ``rmat_edges``) → symmetrized COO.
 
-    Numpy only, so a process that must not start a backend (the bench
-    parent, ``chip_smoke.py``'s references) can build the graph, and a
+    Numpy only, so a process that must not start a backend
+    (``chip_smoke.py``'s parent and references) can build the graph, and a
     chip process only uploads. Deterministic in ``seed``.
     """
     import numpy as np

@@ -66,17 +66,13 @@ os.environ["COMBBLAS_OBS_HB_METRICS_S"] = "0"
 # Hermetic net-frontend knobs (round 19): an ambient COMBBLAS_NET_PORT
 # would make every test NetFrontend bind a FIXED operator port (two
 # tests in one run would collide on EADDRINUSE), ambient conn/backlog
-# caps would change the backpressure tests' admission points, and
-# ambient BENCH_NET_* rates would re-scale the slow open-loop harness
-# test — pin the defaults ("0" = default per the tuner/config
-# convention: port 0 means ephemeral); tests that exercise the knobs
-# pass explicit arguments or monkeypatch instead.
+# caps would change the backpressure tests' admission points — pin
+# the defaults ("0" = default per the tuner/config convention: port 0
+# means ephemeral); tests that exercise the knobs pass explicit
+# arguments or monkeypatch instead.
 os.environ["COMBBLAS_NET_PORT"] = "0"
 os.environ["COMBBLAS_NET_MAX_CONNS"] = "0"
 os.environ["COMBBLAS_NET_ACCEPT_BACKLOG"] = "0"
-os.environ["BENCH_NET_RATE"] = "0"
-os.environ["BENCH_NET_CONNS"] = "0"
-os.environ["BENCH_NET_SECONDS"] = "0"
 
 # Hermetic sharded wire-protocol knobs (round 21): an ambient
 # COMBBLAS_SHARD_FRONTIER would force every sharded test's hop
@@ -107,10 +103,7 @@ import pytest
 def pytest_configure(config):
     # "slow" keeps stress/latency tests out of the tier-1 budget
     # (ROADMAP.md runs `-m 'not slow'`); registered here since the repo
-    # carries no pytest.ini.  Current slow set: the serve stress test
-    # (test_serve.py) and the end-to-end bench.py subprocess run
-    # (test_bench_summary.py) — the tier-1 guard for the summary-line
-    # contract is the FAST test in that same file.
+    # carries no pytest.ini.
     config.addinivalue_line(
         "markers", "slow: excluded from the tier-1 `-m 'not slow'` run"
     )

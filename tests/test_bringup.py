@@ -1,7 +1,7 @@
 """Bring-up guards (ISSUE 21): nothing in the product hides the device.
 
 * importing the package (and every launcher module) starts no backend —
-  a router or bench parent that imports it must not take the chip its
+  a router or launcher that imports it must not take the chip its
   children need;
 * the compile cache is placed from OUTSIDE: with
   ``JAX_COMPILATION_CACHE_DIR`` set the program sets no directory in

@@ -30,46 +30,77 @@ ALLOWED = {
 }
 
 _NAME = re.compile(r"COMBBLAS_[A-Z0-9_]+")
+_BENCH_NAME = re.compile(r"(?<![A-Z0-9_])BENCH_[A-Z0-9_]+")
 
 
-def _env_read_names(lines, idx, window=2):
-    """COMBBLAS_* names within ``window`` lines of an os.environ read
+def _env_read_names(lines, idx, name_re=_NAME, window=2):
+    """``name_re`` names within ``window`` lines of an os.environ read
     (catches the name sitting on the call line or a continuation)."""
     lo = max(0, idx - window)
     hi = min(len(lines), idx + window + 1)
     names = set()
     for ln in lines[lo:hi]:
-        names.update(_NAME.findall(ln))
+        names.update(name_re.findall(ln))
     return names
 
 
-def test_no_stray_combblas_env_reads():
+def _stray_env_reads(root, name_re, allowed=None, skip_dirs=()):
+    """``rel:line: [names]`` for every os.environ read under ``root``
+    that sits beside a ``name_re`` name the file is not allowed."""
+    allowed = allowed or {}
     violations = []
-    for dirpath, _dirnames, filenames in os.walk(PKG_ROOT):
-        if "__pycache__" in dirpath:
-            continue
+    for dirpath, dirnames, filenames in os.walk(root):
+        dirnames[:] = [
+            d for d in dirnames
+            if d != "__pycache__"
+            and os.path.join(dirpath, d) not in skip_dirs
+        ]
         for fn in filenames:
             if not fn.endswith(".py"):
                 continue
             path = os.path.join(dirpath, fn)
-            rel = os.path.relpath(path, PKG_ROOT).replace(os.sep, "/")
-            allowed = ALLOWED.get(rel, set())
-            if allowed == "*":
+            rel = os.path.relpath(path, root).replace(os.sep, "/")
+            ok = allowed.get(rel, set())
+            if ok == "*":
                 continue
             with open(path, encoding="utf-8") as f:
                 lines = f.readlines()
             for i, line in enumerate(lines):
                 if "os.environ" not in line and "environ[" not in line:
                     continue
-                stray = _env_read_names(lines, i) - set(allowed)
+                stray = _env_read_names(lines, i, name_re) - set(ok)
                 if stray:
                     violations.append(
                         f"{rel}:{i + 1}: {sorted(stray)}"
                     )
+    return violations
+
+
+def test_no_stray_combblas_env_reads():
+    violations = _stray_env_reads(PKG_ROOT, _NAME, ALLOWED)
     assert not violations, (
         "COMBBLAS_* env reads outside tuner/config.py (add an accessor "
         "there instead — precedence and '0 means default' semantics "
         "live in one place):\n" + "\n".join(violations)
+    )
+
+
+def test_no_bench_env_reads():
+    """The pre-chip bench stack and its ``BENCH_*`` switches are gone
+    (PR 28): neither the package nor the tests read one.  The
+    benchmark (``chipbench/``, ``tests/chipbench/``) takes arguments,
+    not environment switches."""
+    tests_root = os.path.dirname(os.path.abspath(__file__))
+    violations = _stray_env_reads(PKG_ROOT, _BENCH_NAME) + [
+        "tests/" + v for v in _stray_env_reads(
+            tests_root, _BENCH_NAME,
+            skip_dirs=(os.path.join(tests_root, "chipbench"),),
+        )
+    ]
+    assert not violations, (
+        "BENCH_* env reads (measure through `python3 -m chipbench.run`; "
+        "a library knob is a COMBBLAS_* name in tuner/config.py):\n"
+        + "\n".join(violations)
     )
 
 
@@ -153,8 +184,8 @@ def test_fleet_obs_knobs_centralized(monkeypatch, tmp_path):
 
 
 def test_net_knobs_centralized(monkeypatch):
-    """The round-19 net-frontend + open-loop-bench knobs parse through
-    tuner/config with the shared conventions: unset/"0" = default
+    """The round-19 net-frontend knobs parse through tuner/config
+    with the shared conventions: unset/"0" = default
     (port 0 = ephemeral bind), explicit argument beats the env, the
     count knobs clamp sane, and a bogus value raises NAMING the
     knob."""
@@ -167,38 +198,22 @@ def test_net_knobs_centralized(monkeypatch):
         config.ENV_NET_ACCEPT_BACKLOG,
     ):
         assert name.startswith("COMBBLAS_")
-    for name in (
-        config.ENV_BENCH_NET_RATE, config.ENV_BENCH_NET_CONNS,
-        config.ENV_BENCH_NET_SECONDS,
-    ):
-        assert name.startswith("BENCH_NET_")
     # conftest pins these to "0" => defaults: ephemeral port, default
-    # conn/backlog caps, default open-loop shape
+    # conn/backlog caps
     assert config.net_port() == config.DEFAULT_NET_PORT == 0
     assert config.net_max_conns() == config.DEFAULT_NET_MAX_CONNS
     assert config.net_accept_backlog() == config.DEFAULT_NET_ACCEPT_BACKLOG
-    assert config.bench_net_rate() == config.DEFAULT_BENCH_NET_RATE
-    assert config.bench_net_conns() == config.DEFAULT_BENCH_NET_CONNS
-    assert config.bench_net_seconds() == config.DEFAULT_BENCH_NET_SECONDS
     monkeypatch.setenv(config.ENV_NET_PORT, "19219")
     monkeypatch.setenv(config.ENV_NET_MAX_CONNS, "64")
     monkeypatch.setenv(config.ENV_NET_ACCEPT_BACKLOG, "16")
-    monkeypatch.setenv(config.ENV_BENCH_NET_RATE, "50.5")
-    monkeypatch.setenv(config.ENV_BENCH_NET_CONNS, "32")
-    monkeypatch.setenv(config.ENV_BENCH_NET_SECONDS, "2.5")
     assert config.net_port() == 19219
     assert config.net_max_conns() == 64
     assert config.net_accept_backlog() == 16
-    assert config.bench_net_rate() == 50.5
-    assert config.bench_net_conns() == 32
-    assert config.bench_net_seconds() == 2.5
     # argument > env, clamped sane
     assert config.net_port(0) == 0
     assert config.net_max_conns(1) == 1
     assert config.net_max_conns(-3) == 1  # clamp >= 1
     assert config.net_accept_backlog(-1) == 1
-    assert config.bench_net_conns(0) == config.DEFAULT_BENCH_NET_CONNS
-    assert config.bench_net_rate(0.01) == 0.1  # clamp >= 0.1
     # vetting raises NAMING the knob
     with pytest.raises(ValueError, match=config.ENV_NET_PORT):
         config.net_port(70000)
@@ -206,8 +221,6 @@ def test_net_knobs_centralized(monkeypatch):
         config.net_port("not-a-port")
     with pytest.raises(ValueError, match=config.ENV_NET_MAX_CONNS):
         config.net_max_conns("many")
-    with pytest.raises(ValueError, match=config.ENV_BENCH_NET_RATE):
-        config.bench_net_rate("fast")
 
 
 def test_shard_wire_knobs_centralized(monkeypatch):
@@ -237,7 +250,7 @@ def test_shard_wire_knobs_centralized(monkeypatch):
     assert config.shard_frontier("dense") == "dense"
     assert config.shard_density(0.1) == 0.1
     assert config.shard_wire("f32") == "f32"
-    # "0" falls through to the default (the bench-knob convention)
+    # "0" falls through to the default
     assert config.shard_density(0) == config.DEFAULT_SHARD_DENSITY
     # vetting raises NAMING the knob
     with pytest.raises(ValueError, match=config.ENV_SHARD_FRONTIER):

@@ -1,4 +1,4 @@
-"""Semantic graphs / filtered BFS+MIS, phase timers, checkpointing."""
+"""Semantic graphs / filtered BFS+MIS, forced spans, checkpointing."""
 
 import jax
 import jax.numpy as jnp
@@ -11,8 +11,8 @@ from combblas_tpu.parallel.grid import Grid
 from combblas_tpu.parallel.spmat import SpParMat
 from combblas_tpu.parallel.vec import DistVec
 from combblas_tpu.semantic import SemanticGraph, filtered_bfs, filtered_mis
+from combblas_tpu import obs
 from combblas_tpu.utils import checkpoint as ckpt
-from combblas_tpu.utils import timers
 from conftest import random_dense
 
 
@@ -80,11 +80,11 @@ def test_filtered_mis_independent(rng):
     assert sub.sum() == 0
 
 
-def test_timers_accumulate():
-    timers.reset_all()
-    with timers.phase("unit_test_phase"):
+def test_forced_spans_accumulate():
+    obs.reset_spans()
+    with obs.span("unit_test_phase", force=True):
         x = jnp.arange(8).sum()
-    rep = timers.report()
+    rep = obs.report()
     assert "unit_test_phase" in rep
     sec, n = rep["unit_test_phase"]
     assert n == 1 and sec >= 0

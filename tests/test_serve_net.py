@@ -30,7 +30,7 @@ from combblas_tpu.serve import (
     ReplicaDeadError,
     ServeConfig,
 )
-from combblas_tpu.serve import frame, ipc
+from combblas_tpu.serve import frame
 from combblas_tpu.serve.net import protocol as P
 from combblas_tpu.utils.rmat import rmat_symmetric_coo_host
 
@@ -144,18 +144,6 @@ def test_wire_status_taxonomy_round_trip():
 
 
 # --- the shared frame codec -------------------------------------------------
-
-
-def test_ipc_reexports_are_the_frame_codec():
-    """One codec, two transports: serve/ipc.py is a pure re-export of
-    serve/frame.py — the process fleet and the net front door cannot
-    drift apart."""
-    assert ipc.Channel is frame.Channel
-    assert ipc.ChannelClosed is frame.ChannelClosed
-    assert ipc.encode is frame.encode
-    assert ipc.decode is frame.decode
-    assert ipc.denumpy is frame.denumpy
-    assert ipc.MAX_FRAME == frame.MAX_FRAME
 
 
 def test_channel_ndarray_round_trip_and_byte_accounting():
@@ -552,8 +540,7 @@ def test_net_trace_telescopes_to_wall(served, live_roots):
 
 @pytest.mark.slow
 def test_open_loop_gate_small_fleet():
-    """Representative of the BENCH_SERVE_NET=1 acceptance gate, scaled
-    down: seeded Poisson arrivals over concurrent connections against
+    """The open-loop harness, scaled down: seeded Poisson arrivals over concurrent connections against
     a 2-replica process fleet — >=99% availability, zero stranded
     futures, zero post-warmup retraces, every failure typed."""
     from combblas_tpu.serve.net import loadgen

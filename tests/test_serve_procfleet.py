@@ -9,8 +9,7 @@ spawn-free unit tests of the IPC channel, the parent-side replica
 client (stub responder over a socketpair — no subprocess, no jax
 child), and the deterministic process fault plan.  The real-signal
 chaos scenarios (SIGKILL respawn, SIGSTOP heartbeat-timeout
-promotion) are ``slow``; ``BENCH_FLEET=process`` is their measured
-twin.
+promotion) are ``slow``.
 """
 
 import json
@@ -32,7 +31,7 @@ from combblas_tpu.serve import (
     ProcessFleet,
     ServeConfig,
 )
-from combblas_tpu.serve.ipc import Channel, ChannelClosed
+from combblas_tpu.serve.frame import Channel, ChannelClosed
 from combblas_tpu.serve.procfleet import (
     IpcTimeoutError,
     ReplicaDeadError,
@@ -179,11 +178,11 @@ def test_ipc_send_survives_reader_poll_timeout():
 
 
 def test_ipc_oversized_frame_refused():
-    from combblas_tpu.serve import ipc
+    from combblas_tpu.serve import frame
 
     a, b = socket.socketpair()
     ca = Channel(a)
-    big = "x" * (ipc.MAX_FRAME + 1)
+    big = "x" * (frame.MAX_FRAME + 1)
     with pytest.raises(ValueError, match="too large"):
         ca.send({"blob": big})
     ca.close()
@@ -506,7 +505,7 @@ def test_fleet_observability_plane_end_to_end(tmp_path):
     assert fr._scrape is None  # close() stops the scrape thread
 
 
-# --- real-signal chaos (slow; BENCH_FLEET=process is the measured twin) ------
+# --- real-signal chaos (slow) -------------------------------------------------
 
 
 @pytest.mark.slow

@@ -7,8 +7,7 @@ included), ``recover_version`` = latest valid snapshot + WAL-suffix
 replay must be ``to_host_coo()``-equal with a never-crashed engine
 that merged the same acknowledged ops — and no acknowledged write may
 be lost.  Tier-1 runs the boundary sweep on a 1x1 grid plus one 2x4
-representative; the threaded kill-storm soak is ``slow`` (the
-BENCH_SERVE_RECOVERY scenario is its measured twin).
+representative; the threaded kill-storm soak is ``slow``.
 """
 
 import json
@@ -758,7 +757,7 @@ def test_fleet_from_recovery_boots_whole_fleet(tmp_path):
         assert res["fanned_out"] == 1
 
 
-# --- threaded kill-storm soak (slow; the bench's deterministic twin) ---------
+# --- threaded kill-storm soak (slow) ------------------------------------------
 
 
 @pytest.mark.slow

@@ -16,7 +16,7 @@ The load-bearing properties:
 
 Tier-1 runs the local-mode (in-process slices) representatives; the
 full boundary sweep and the subprocess SIGKILL/respawn scenario are
-``slow`` (the BENCH_SERVE_SHARD gate is their measured twin).
+``slow``.
 """
 
 import os
@@ -310,7 +310,7 @@ def test_crash_recovery_bit_exact_at_every_boundary(tmp_path):
                         ckpt=ckpt, torn=torn)
 
 
-# --- subprocess fleet: SIGKILL + respawn (slow; the bench's twin) ------------
+# --- subprocess fleet: SIGKILL + respawn (slow) -------------------------------
 
 
 @pytest.mark.slow

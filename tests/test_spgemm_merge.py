@@ -340,7 +340,7 @@ def test_merge_resolution_chain(rng, tmp_path, monkeypatch):
     store = tuner_store.get_store()
     key = tuner_store.spgemm3d_plan_key(PLUS_TIMES, A3, B3, "")
     store.put(key, tuner_store.PlanRecord(
-        tier="windowed", merge="hash", source="bench", cost_s=1.0,
+        tier="windowed", merge="hash", source="manual", cost_s=1.0,
     ))
     obs.enable(install_hooks=False)
     try:

@@ -74,7 +74,7 @@ def _golden(name, r, c, v, X, n):
     ((2, 2), "plus_times"), ((2, 2), "min_plus"),
     # max_min on 2x2 rides the slow lane: the fold path is the same
     # scatter kernel min_plus already exercises distributed, and the
-    # 1x1 case plus the bench golden keep the semiring covered
+    # 1x1 case keeps the semiring covered
     pytest.param((2, 2), "max_min", marks=pytest.mark.slow),
 ])
 def test_ell_spmm_golden(rng, grid_shape, sr_name):

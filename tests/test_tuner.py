@@ -212,7 +212,7 @@ def test_key_buckets_and_bands():
     assert shape_bucket((1 << 14) + 1) == 15  # ceil, not floor
     assert density_band(16 * 1024, 1024) == 4  # avg degree 16
     assert density_band(0, 1024) == -8  # clamped floor
-    # the host-count key and the matrix key agree (the bench contract)
+    # the host-count key and the matrix key agree
     grid = Grid.make(1, 1)
     n, nnz = 256, 2048
     rng = np.random.default_rng(7)
@@ -337,14 +337,14 @@ def test_store_invalid_dispatch_line_ignored(tmp_path):
 def test_store_wrong_op_tier_record_falls_back(
     tmp_path, monkeypatch, rng
 ):
-    """A serve-lane tier under a spgemm key (hand-mangled store) is
+    """An SpMM tier under a spgemm key (hand-mangled store) is
     rejected at routing — heuristic fallback, no assert."""
     grid = Grid.make(1, 1)
     r, c, v = coo(rng, 64, 64, 300)
     A = SpParMat.from_global_coo(grid, r, c, v, 64, 64)
     st = _use_store(monkeypatch, tmp_path)
     key = spgemm_plan_key(PLUS_TIMES, A, A, "scatter")
-    st._plans[key] = PlanRecord(tier="serve")  # bypass put()'s surface
+    st._plans[key] = PlanRecord(tier="mxu_gather")  # bypass put()'s surface
     C = spgemm_auto(PLUS_TIMES, A, A)
     np.testing.assert_allclose(
         dense_of(C), dense_of(spgemm(PLUS_TIMES, A, A)),
@@ -589,37 +589,7 @@ def test_windowed_auto_dispatch_is_blocked_multidev(rng):
         obs.reset()
 
 
-# --- serve lane replay -----------------------------------------------------
-
-
-def test_serve_lanes_recorded_and_replayed(tmp_path, monkeypatch):
-    from combblas_tpu.serve.engine import GraphEngine
-
-    _use_store(monkeypatch, tmp_path)
-    rng = np.random.default_rng(5)
-    N = 64
-    rows = rng.integers(0, N, 300).astype(np.int64)
-    cols = rng.integers(0, N, 300).astype(np.int64)
-    rows_s = np.concatenate([rows, cols])
-    cols_s = np.concatenate([cols, rows])
-
-    def build():
-        return GraphEngine.from_coo(
-            Grid.make(1, 1), rows_s, cols_s, N, kinds=("bfs",)
-        )
-
-    eng1 = build()
-    eng1.plan("bfs", 32)  # a non-default lane the traffic mix used
-    # fresh "process": new engine + a reloaded store instance
-    tstore._reset_for_tests()
-    eng2 = build()
-    warmed = eng2.warmup()
-    assert ("bfs", 32) in warmed  # the remembered lane was pre-traced
-    for w in eng2.DEFAULT_WARMUP_WIDTHS:
-        assert ("bfs", w) in warmed
-    mark = eng2.trace_mark()
-    eng2.execute("bfs", np.full(32, -1, np.int32))
-    assert eng2.retraces_since(mark) == 0  # zero-retrace steady state
+# --- serve warmup widths -----------------------------------------------------
 
 
 def test_warmup_explicit_widths_unchanged(tmp_path, monkeypatch):
@@ -636,6 +606,30 @@ def test_warmup_explicit_widths_unchanged(tmp_path, monkeypatch):
     )
     warmed = eng.warmup(widths=(2, 4))
     assert set(warmed) == {("bfs", 2), ("bfs", 4)}
+
+
+def test_warmup_default_widths_and_store_untouched(tmp_path, monkeypatch):
+    """One place decides which lanes are warmed: no ``widths`` means
+    ``DEFAULT_WARMUP_WIDTHS`` and nothing else, and neither a warm-up
+    nor a plan-cache miss writes to the plan store."""
+    from combblas_tpu.serve.engine import GraphEngine
+
+    st = _use_store(monkeypatch, tmp_path)
+    rng = np.random.default_rng(7)
+    N = 32
+    rows = rng.integers(0, N, 100).astype(np.int64)
+    cols = rng.integers(0, N, 100).astype(np.int64)
+    eng = GraphEngine.from_coo(
+        Grid.make(1, 1), np.concatenate([rows, cols]),
+        np.concatenate([cols, rows]), N, kinds=("bfs",),
+    )
+    eng.plan("bfs", 32)  # a miss outside the default widths
+    warmed = eng.warmup()
+    assert set(warmed) == {
+        ("bfs", w) for w in GraphEngine.DEFAULT_WARMUP_WIDTHS
+    }
+    assert st.entries() == 0
+    assert not os.path.exists(st.file) or os.path.getsize(st.file) == 0
 
 
 # --- shared health surface -------------------------------------------------
@@ -827,7 +821,7 @@ def test_resolve_tier_precedence_and_vetting(tmp_path, monkeypatch):
 
 def test_resolve_tier_account_false_peeks_silently(tmp_path,
                                                    monkeypatch):
-    """account=False (the spgemm3d_bench mirror): peek — no hit/miss
+    """account=False (the mirror mode): peek — no hit/miss
     accounting, no plan_source counter."""
     from combblas_tpu.tuner.resolve import resolve_tier
 
@@ -859,8 +853,7 @@ def test_resolve_tier_account_false_peeks_silently(tmp_path,
 def test_probe_geometry_sweep_records_block_shape(tmp_path, rng):
     """When the tier sweep's winner is ``windowed`` and budget remains,
     the probe sweeps a bounded block-geometry grid and persists the
-    winning block_rows/block_cols WITH the plan (before round 12,
-    geometry reached the store only via BENCH_PLAN_RECORD=1)."""
+    winning block_rows/block_cols WITH the plan."""
     from combblas_tpu.tuner.probe import _geometry_candidates
 
     grid = Grid.make(1, 1)
