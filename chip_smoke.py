@@ -607,7 +607,7 @@ def phase_mesh4(scale: int, seed: int) -> dict:
     for cls, bucket in enumerate(engine.E.buckets):
         for arr in bucket:
             check(four(arr), f"E bucket class {cls}: shards on 4 devices")
-    p, l, _niter, _sweeps = engine.plan("bfs", 16).fn(np.asarray(roots, np.int32))
+    p, l, *_ = engine.plan("bfs", 16).fn(np.asarray(roots, np.int32))
     check(four(p) and four(l), "result blocks: shards on 4 devices")
     block_bytes = grid.local_rows(n) * 16 * 4
     whole = [

@@ -22,9 +22,10 @@ The CSC / transpose / normalized twins ride the same machinery: the
 weighted matrix and the PageRank transition matrix share the structural
 bucket layout (their values are derived per class from the merged
 weights / out-degrees), the transpose twin is patched through a second
-orientation of the same patcher, and the lazy CSC companion is reset to
-rebuild on demand from the carried host COO (it has no compiled-shape
-contract to preserve).
+orientation of the same patcher, and the CSC companion (an operand of
+the BFS plan) keeps its arrays for their shapes and is marked
+not-current on a structural change, to be rebuilt from the carried host
+COO off the query path (``GraphEngine.csc_companion``).
 
 SPILL POLICY — the incremental path falls back to a full rebuild
 (``dynamic.merge.applied{mode=rebuild}``, labeled reason) when:
@@ -518,10 +519,20 @@ def _full_build(grid, version, keys: np.ndarray,
             grid, (state.outdeg == 0).astype(np.float32), align="col"
         )
     ET = build(t_o, _vals_ones) if t_o is not None else None
+    csc = None
+    if version.csc is not None:
+        # a rebuild already sorts every edge: the BFS plan's companion
+        # with it, at the parent's length where the edges fit
+        from ..parallel.ellmat import build_csc_companion
+
+        csc = build_csc_companion(
+            grid, rows, cols, nrows, ncols, headroom=hr,
+            cap=int(version.csc[1].shape[-1]),
+        )
     new_version = GraphVersion(
         nrows=nrows, ncols=ncols, nnz=int(len(keys)), E=E,
         deg=state.deg, outdeg=state.outdeg, E_weighted=E_weighted,
-        P_ell=P_ell, dangling=dangling, ET=ET,
+        P_ell=P_ell, dangling=dangling, ET=ET, csc=csc,
         host_coo=(rows, cols, ncols),
         # the feature table is edge-independent: the rebuilt version
         # keeps serving the same device arrays (invdeg stays None —
@@ -825,14 +836,17 @@ def apply_delta(version, batch: DeltaBatch, *,
         deg=new_deg, outdeg=new_outdeg, E_weighted=E_weighted,
         P_ell=P_ell, dangling=dangling, ET=ET,
         host_coo=(rows, cols, ncols),
-        # BUGFIX (round 12): the lazy CSC companion is STRUCTURAL
-        # (indptr + row ids, no values) — a fold that touched no edges
-        # (no-op upsert batch, weight-only change) leaves it exactly
-        # valid, so carry it instead of resetting to a full
-        # rebuild-from-COO on next use.  Any structural change still
-        # resets (None -> lazily rebuilt).  coldeg rides the same
-        # argument: out-degrees are untouched when no edge moved.
-        csc=(version.csc if changed_struct == 0 else None),
+        # the CSC companion is STRUCTURAL (indptr + row ids, no
+        # values): a fold that touched no edges (no-op upsert batch,
+        # weight-only change) leaves it exactly valid.  It is an
+        # operand of the BFS plan, so a structural change keeps the
+        # parent's arrays too, for their SHAPES, and marks them
+        # not-current: the swap stays zero-retrace, level 0 of a batch
+        # runs in the loop, and ``GraphEngine.csc_companion`` rebuilds
+        # it off the query path.  coldeg is no plan's operand: reset,
+        # lazily rebuilt (out-degrees are untouched when no edge moved).
+        csc=version.csc,
+        csc_current=version.csc_current and changed_struct == 0,
         coldeg=(version.coldeg if changed_struct == 0 else None),
         X=getattr(version, "X", None),
         feat_dim=int(getattr(version, "feat_dim", 0)),

@@ -39,9 +39,14 @@ from ..parallel.vec import DistVec
 #: one scope per degree class.  Trace-time metadata only: the device
 #: trace's per-scope and per-level times are read by these names
 #: (docs/observability.md "Named scopes"), so a rename is a change of
-#: yardstick.  ``bfs.parents`` exists in the compact program only.
+#: yardstick.  ``bfs.parents`` exists in the compact program only,
+#: ``bfs.push`` (level 0 as a walk of the roots' columns, before the
+#: loop; ``ell.reduce``, ``bfs.update``, ``vec.realign`` and
+#: ``bfs.active`` recur under it) in a program handed the CSC companion
+#: only: the served plan.
 BFS_SCOPES = (
     "bfs.init",
+    "bfs.push",
     "bfs.level",  # the whole while loop; one iteration = one level
     # inside it: gather, fold, scatter_rows; the count that lets a masked
     # sweep skip the class is the scope's own
@@ -449,7 +454,10 @@ def bfs_batch(
 ):
     """Eager wrapper over ``_bfs_batch_impl`` (plain-outputs law: a
     dataclass-wrapped jit output tripled the batch child's wall time in
-    the round-5 A/B on a machine that is gone; ROADMAP D14)."""
+    the round-5 A/B on a machine that is gone; ROADMAP D14).  A library
+    caller holds no CSC companion, so this is the all-pull program;
+    the served plan takes level 0 as a push (``_bfs_batch_tallied``):
+    same answer."""
     from ..parallel.vec import DistMultiVec
 
     p, l, niter = _bfs_batch_impl(
@@ -494,13 +502,46 @@ def _bfs_batch_impl(
     return _bfs_batch_tallied(A, sources, max_iters, sr, track_levels)[:3]
 
 
-def _bfs_batch_tallied(A, sources, max_iters, sr, track_levels):
+#: Edge slots a tile has for the columns of a batch's roots: level 0 as
+#: a push (``_bfs_batch_tallied``).  Host count, Graph500 scale 20, a
+#: million drawn batches of 16 roots: the degrees sum to 442 at the
+#: median, 4,801 at the 99th percentile, past 2^16 in 5 draws and past
+#: 2^17 in none (ISSUE 29); a batch that does not fit runs level 0 in the
+#: loop, as every batch did.  Static: it sizes the walk.
+PUSH_EDGE_CAPACITY = 1 << 17
+
+#: What the device chose for level 0, the scalar a program handed the
+#: companion returns: ``serve.bfs.push{outcome}``.
+PUSH_OUTCOMES = ("taken", "over_budget", "stale")
+
+
+def _bfs_batch_tallied(A, sources, max_iters, sr, track_levels, csc=None):
     """``_bfs_batch_impl`` plus, as a fourth output, the ``int32[2]``
     tally over the whole search of degree-class sweeps run dense /
-    skipped (``ellmat.SWEEP_MODES``).  Not jitted: the served plan
-    (``engine._build_plan``) traces it into its own program."""
+    skipped (``ellmat.SWEEP_MODES``) and, as a fifth, what level 0 did
+    (an index into ``PUSH_OUTCOMES``; None for a program with no push in
+    it).  Not jitted: the served plan (``engine._build_plan``) traces it
+    into its own program.
+
+    Level 0 is the one level whose work is known before it starts: its
+    frontier is the batch's roots, W columns, which the pull sweep finds
+    by gathering every slot of the matrix.  Given ``csc`` (``(indptr,
+    rowidx, current)``: ``ellmat.build_csc_companion`` of ``A``'s edges
+    and a bool scalar, False once they have moved on) it is taken BEFORE
+    the loop as a walk of those columns (``ellmat.ell_roots_push``, scope
+    ``bfs.push``), and the loop starts at level 1.  The device decides,
+    from the input: the companion is current and the roots' columns fit
+    ``PUSH_EDGE_CAPACITY`` on every tile; otherwise the loop starts at
+    level 0, the state as it always was.  One ``cond`` whose other branch
+    is the identity: the loop body is the same text either way, and no
+    gather table crosses a branch (``ellmat._ell_local_spmm``).  Parents,
+    levels, ``niter`` and every tie are the all-pull program's, bit for
+    bit.  The walk is ``SELECT2ND_MAX``'s; any other semiring, and a
+    caller without a companion, gets the all-pull program."""
     from ..parallel.vec import DistMultiVec
-    from ..parallel.ellmat import SWEEP_MODES, ell_masked_multi_sweep
+    from ..parallel.ellmat import (
+        SWEEP_MODES, ell_masked_multi_sweep, ell_roots_fit, ell_roots_push,
+    )
 
     grid = A.grid
     n = A.nrows
@@ -536,38 +577,64 @@ def _bfs_batch_tallied(A, sources, max_iters, sr, track_levels):
         _, _, _, level, active, _ = state
         return active & (level < iters)
 
-    def step(state):
-        parents, levels, x, level, _, tally = state
-        unvisited = mk(parents < 0, "row")
-        y, sweeps = ell_masked_multi_sweep(sr, A, mk(x, "col"), unvisited)
+    def advance(parents, levels, level, y):
+        """What a level does with its candidates ``y`` [pr, lr, W]."""
         with jax.named_scope("bfs.update"):
             new = (
-                (y.blocks >= 0) & (parents < 0)
+                (y >= 0) & (parents < 0)
                 & (row_gids[:, :, None] >= 0)
             )
-            parents = jnp.where(new, y.blocks, parents)
+            parents = jnp.where(new, y, parents)
             if track_levels:
                 levels = jnp.where(new, level + 1, levels)
             frontier = jnp.where(new, row_gids[:, :, None], -1)
         x_next = mk(frontier, "row").realign("col").blocks
         with jax.named_scope("bfs.active"):
             active = jnp.any(new)
-        return parents, levels, x_next, level + 1, active, tally + sweeps
+        return parents, levels, x_next, level + 1, active
+
+    def step(state):
+        parents, levels, x, level, _, tally = state
+        unvisited = mk(parents < 0, "row")
+        y, sweeps = ell_masked_multi_sweep(sr, A, mk(x, "col"), unvisited)
+        return (*advance(parents, levels, level, y.blocks), tally + sweeps)
+
+    state = (
+        parents0, levels0, x0, jnp.int32(0), jnp.bool_(True),
+        jnp.zeros((pr_, pc_, len(SWEEP_MODES)), jnp.int32),
+    )
+    outcome = None
+    if csc is not None and sr is SELECT2ND_MAX and iters > 0:
+        indptr, rowidx, current = csc
+        current = jnp.asarray(current, jnp.bool_)
+        roots = src[0, 0]
+
+        def pushed(state):
+            parents, levels, _, level, _, tally = state
+            y = ell_roots_push(A, indptr, rowidx, roots, PUSH_EDGE_CAPACITY)
+            return (*advance(parents, levels, level, y), tally)
+
+        with jax.named_scope("bfs.push"):
+            fits = ell_roots_fit(A, indptr, roots, PUSH_EDGE_CAPACITY)
+            state = jax.lax.cond(
+                current & fits, pushed, lambda state: state, state
+            )
+            outcome = jnp.where(
+                current, jnp.where(fits, 0, 1), 2
+            ).astype(jnp.int32)
 
     # the whole loop, condition included, is one scope: a level is one
     # iteration of it in the device trace
     with jax.named_scope("bfs.level"):
         parents, levels, _, niter, _, tally = jax.lax.while_loop(
-            cond, step,
-            (parents0, levels0, x0, jnp.int32(0), jnp.bool_(True),
-             jnp.zeros((pr_, pc_, len(SWEEP_MODES)), jnp.int32)),
+            cond, step, state
         )
     tally = jnp.sum(tally, axis=(0, 1))  # over tiles, once, after the loop
     if not track_levels:
         # levels were not tracked: return discovery indicator (0 for the
         # sources / discovered? -1 undiscovered) — parents' sign carries it.
         levels = jnp.where(parents >= 0, 0, -1)
-    return parents, levels, niter, tally
+    return parents, levels, niter, tally, outcome
 
 
 @lru_cache(maxsize=16)

@@ -614,6 +614,29 @@ name                                   kind       meaning
                                                   the device chose
                                                   (label ``mode`` =
                                                   dense / skipped)
+``serve.bfs.push``                     counter    served BFS batches by
+                                                  what the device did
+                                                  with level 0 (label
+                                                  ``outcome`` = taken:
+                                                  walked the roots'
+                                                  columns / over_budget:
+                                                  they hold more edges
+                                                  than the walk's slots
+                                                  / stale: the CSC
+                                                  companion is not this
+                                                  version's; the last
+                                                  two ran level 0 in
+                                                  the loop)
+``serve.bfs.companion_rebuilds``       counter    rebuilds of a stale
+                                                  CSC companion by the
+                                                  write lane, once its
+                                                  buffer is empty
+                                                  (label ``outcome`` =
+                                                  ok / outgrown: the
+                                                  edges no longer fit
+                                                  the operand's length,
+                                                  the stand-in stays /
+                                                  error)
 ``serve.sssp.rounds``                  counter    Bellman-Ford rounds
                                                   of served SSSP
                                                   batches, the round

@@ -1012,22 +1012,29 @@ def _ell_minplus_parents(E: EllParMat, dist_row, settled_row):
 # roots at once) -------------------------------------------------------------
 
 
-def build_csc_companion(grid: Grid, rows, cols, nrows: int, ncols: int):
+def build_csc_companion(grid: Grid, rows, cols, nrows: int, ncols: int,
+                        headroom: float | None = None,
+                        cap: int | None = None):
     """Host build of per-tile CSC structure arrays for column walks:
     (indptr [pr, pc, lc+1], rowidx [pr, pc, cap]) int32, cap = max tile
     nnz. The EllParMat's row buckets cannot walk COLUMNS; sparse
-    union-frontier steps need exactly that (the reference's SpImpl CSC
-    kernels, SpImpl.cpp:345-600)."""
-    indptr, rowidx = build_csc_companion_host(grid, rows, cols, nrows, ncols)
+    union-frontier steps and the served BFS plan's first level
+    (``ell_roots_push``) need exactly that (the reference's SpImpl CSC
+    kernels, SpImpl.cpp:345-600).  ``headroom`` / ``cap``: see
+    ``build_csc_companion_host``."""
+    indptr, rowidx = build_csc_companion_host(
+        grid, rows, cols, nrows, ncols, headroom=headroom, cap=cap
+    )
     return upload_csc_companion(grid, indptr, rowidx)
 
 
 def upload_csc_companion(grid: Grid, indptr, rowidx):
     """Upload pre-built host CSC arrays (``build_csc_companion_host``)."""
     sh = grid.tile_sharding()
+    # host array -> its shards directly (``from_host_buckets``)
     return (
-        jax.device_put(jnp.asarray(indptr), sh),
-        jax.device_put(jnp.asarray(rowidx), sh),
+        jax.device_put(np.asarray(indptr), sh),
+        jax.device_put(np.asarray(rowidx), sh),
     )
 
 
@@ -1045,12 +1052,34 @@ def build_csr_companion_host(grid: Grid, rows, cols, nrows: int, ncols: int):
     return _companion_host(grid, rows, cols, nrows, ncols, major="row")
 
 
-def build_csc_companion_host(grid: Grid, rows, cols, nrows: int, ncols: int):
-    """Host-only half of ``build_csc_companion`` (numpy in, numpy out)."""
-    return _companion_host(grid, rows, cols, nrows, ncols, major="col")
+def build_csc_companion_host(grid: Grid, rows, cols, nrows: int, ncols: int,
+                             headroom: float | None = None,
+                             cap: int | None = None):
+    """Host-only half of ``build_csc_companion`` (numpy in, numpy out).
+
+    ``rowidx``'s length follows the ELL buckets' ``headroom`` policy
+    (``EllParMat.host_build``; the caller's resolved value, no
+    environment default here): the fullest tile's edges plus that
+    fraction of free slots, ``COMPANION_SLACK`` at least, so a graph
+    that grows inside its buckets' slack keeps this operand's shape
+    too.  ``headroom`` None (a library caller): the exact length.
+    ``cap`` asks for a length (the one a version already serves with);
+    it is kept when the edges fit and outgrown, by the policy, when they
+    do not."""
+    return _companion_host(grid, rows, cols, nrows, ncols, major="col",
+                           headroom=headroom, cap=cap)
 
 
-def _companion_host(grid, rows, cols, nrows, ncols, *, major):
+#: Least share of free slots a companion built for a graph version gets
+#: (``headroom`` given, 0 included).  An ELL row grows into the padding
+#: its class width leaves it (1.15x the edges on a Graph500 graph); a
+#: CSC has no such room, and without any the first inserted edge would
+#: outgrow the operand the BFS plans were traced with.
+COMPANION_SLACK = 1 / 16
+
+
+def _companion_host(grid, rows, cols, nrows, ncols, *, major,
+                    headroom=None, cap=None):
     """Shared per-tile walk-structure builder: sort each tile's tuples by
     the major axis, indptr over that axis, minor indices padded with the
     minor block size as the inert sentinel."""
@@ -1062,7 +1091,12 @@ def _companion_host(grid, rows, cols, nrows, ncols, *, major):
         grid, rows, cols, nrows, ncols, None
     )
     pr_, pc_ = grid.pr, grid.pc
-    cap = max(int(counts.max()), 1)
+    need = max(int(counts.max()), 1)
+    if cap is None or cap < need:
+        cap = need + (
+            0 if headroom is None
+            else int(np.ceil(need * max(headroom, COMPANION_SLACK)))
+        )
     lmaj, lmin = (lr, lc) if major == "row" else (lc, lr)
     indptr = np.zeros((pr_, pc_, lmaj + 1), np.int32)
     minidx = np.full((pr_, pc_, cap), lmin, np.int32)
@@ -1078,6 +1112,31 @@ def _companion_host(grid, rows, cols, nrows, ncols, *, major):
     return indptr, minidx
 
 
+def _walk_columns(indptr, rowid, fcols, capacity: int, lr: int, lc: int):
+    """The edges of a tile's local columns ``fcols`` ([F] int32; ``lc``
+    = no column) laid into ``capacity`` static slots (``expand_ranges``
+    over their degrees), one CSC walk shared by the union-frontier step
+    and the roots' push.  Returns per slot ``(owner, tgt_row, valid)``:
+    which entry of ``fcols`` the edge leaves from, the local row it
+    enters (``lr`` where ``valid`` is False: dropped by a scatter), and
+    whether the slot holds an edge at all.  Edges past ``capacity`` are
+    cut: the caller tests its budget first."""
+    from ..ops.segment import expand_ranges
+
+    start, deg = _column_ranges(indptr, fcols)
+    owner, offset, valid, _ = expand_ranges(deg, capacity)
+    slot = jnp.minimum(start[owner] + offset, rowid.shape[0] - 1)
+    return owner, jnp.where(valid, rowid[slot], lr), valid
+
+
+def _column_ranges(indptr, fcols):
+    """``(start, degree)`` in a tile's CSC companion of each local column
+    of ``fcols``; the column ``lc`` (none) has degree 0."""
+    ipt_pad = jnp.concatenate([indptr, indptr[-1:]])
+    start = ipt_pad[fcols]
+    return start, ipt_pad[fcols + 1] - start
+
+
 @partial(jax.jit, static_argnames=("frontier_capacity", "edge_capacity"))
 def _ell_union_sparse_step(
     E: EllParMat, csc_indptr, csc_rowidx, x8, undiscovered8,
@@ -1091,8 +1150,6 @@ def _ell_union_sparse_step(
     caller guarantees the budgets (on-device cond in bfs_batch_compact).
     Semantics identical to _ell_levels_step.
     """
-    from ..ops.segment import expand_ranges
-
     lr, lc = E.local_rows, E.local_cols
 
     def body(ipt, ridx, xblk, ublk):
@@ -1109,18 +1166,12 @@ def _ell_union_sparse_step(
             .at[scatter]
             .set(jnp.arange(lc, dtype=jnp.int32), mode="drop")
         )
-        ipt_pad = jnp.concatenate([indptr, indptr[-1:]])
-        deg = jnp.where(
-            fcols < lc, ipt_pad[fcols + 1] - ipt_pad[fcols], 0
+        owner, tgt_row, valid = _walk_columns(
+            indptr, rowid, fcols, edge_capacity, lr, lc
         )
-        owner, offset, valid, _ = expand_ranges(deg, edge_capacity)
-        src_col = fcols[owner]  # local col of this edge
-        slot = jnp.minimum(ipt_pad[jnp.minimum(src_col, lc)] + offset,
-                           rowid.shape[0] - 1)
-        tgt_row = jnp.where(valid, rowid[slot], lr)
         # per-root frontier value of the edge's source column: [Ecap, W]
         xpad = jnp.concatenate([x, jnp.zeros((1, W), jnp.int8)])
-        contrib = xpad[jnp.minimum(src_col, lc)]
+        contrib = xpad[jnp.minimum(fcols[owner], lc)]
         contrib = jnp.where(valid[:, None], contrib, 0)
         y = jnp.zeros((lr, W), jnp.int8).at[tgt_row].max(
             contrib, mode="drop"
@@ -1134,3 +1185,76 @@ def _ell_union_sparse_step(
         in_specs=(TILE_SPEC, TILE_SPEC, P(COL_AXIS), P(ROW_AXIS)),
         out_specs=P(ROW_AXIS),
     )(csc_indptr, csc_rowidx, x8, undiscovered8)
+
+
+# --- the roots' own columns: level 0 of a served BFS as a push -------------
+
+
+def _root_columns(src, lc: int, ncols: int):
+    """This tile's local column of each of the batch's ``[W]`` roots,
+    ``lc`` for a root another column block owns and for a lane that has
+    none (``PAD_ROOT``, any id outside the matrix)."""
+    j = lax.axis_index(COL_AXIS)
+    lcol = src - j * lc
+    mine = (src >= 0) & (src < ncols) & (lcol >= 0) & (lcol < lc)
+    return jnp.where(mine, lcol, lc)
+
+
+def ell_roots_fit(E: EllParMat, csc_indptr, sources, capacity: int):
+    """Scalar bool: on every tile, the columns of the batch's roots hold
+    at most ``capacity`` edges between them, so ``ell_roots_push`` walks
+    them all.  ``[W]`` reads of ``indptr`` a tile and one ``all``."""
+    lc = E.local_cols
+
+    def body(ipt, src):
+        _, deg = _column_ranges(ipt[0, 0], _root_columns(src, lc, E.ncols))
+        # clamped, so that the sum cannot wrap whatever a column holds
+        total = jnp.sum(jnp.minimum(deg, capacity + 1))
+        return (total <= capacity)[None, None]
+
+    fits = jax.shard_map(
+        body,
+        mesh=E.grid.mesh,
+        in_specs=(TILE_SPEC, P()),
+        out_specs=TILE_SPEC,
+    )(csc_indptr, sources)
+    return jnp.all(fits)
+
+
+def ell_roots_push(E: EllParMat, csc_indptr, csc_rowidx, sources,
+                   capacity: int):
+    """``E (x) X`` under ``SELECT2ND_MAX`` for the ``X`` a batched BFS
+    starts from, lane ``w`` holding root ``sources[w]`` at its own column
+    and nothing else: ``[pr, lr, W]`` int32 row-aligned blocks with the
+    root's id wherever the row has an edge from it, -1 elsewhere.  The
+    pull sweep finds those rows by gathering every slot of the matrix;
+    this walks the roots' columns in the CSC companion
+    (``build_csc_companion``) and scatters: ``capacity`` slots a tile,
+    which the caller has tested with ``ell_roots_fit``.
+
+    One frontier vertex a lane, so the max the scatter folds with has
+    nothing to choose between: the result is the sweep's, entry for
+    entry, unmasked (the caller's update keeps unvisited rows only).
+    Equal roots in two lanes are two lanes; a lane without a root
+    (``PAD_ROOT``) walks nothing; the companion comes from the COO, so a
+    directed matrix walks out-edges."""
+    lr, lc = E.local_rows, E.local_cols
+
+    def body(ipt, ridx, src):
+        W = src.shape[0]
+        fcols = _root_columns(src, lc, E.ncols)
+        owner, tgt_row, valid = _walk_columns(
+            ipt[0, 0], ridx[0, 0], fcols, capacity, lr, lc
+        )
+        y = jnp.full((lr, W), -1, jnp.int32).at[tgt_row, owner].max(
+            jnp.where(valid, src[owner], -1), mode="drop"
+        )
+        with jax.named_scope("ell.reduce"):
+            return lax.pmax(y, COL_AXIS)[None]
+
+    return jax.shard_map(
+        body,
+        mesh=E.grid.mesh,
+        in_specs=(TILE_SPEC, TILE_SPEC, P()),
+        out_specs=P(ROW_AXIS),
+    )(csc_indptr, csc_rowidx, sources)

@@ -959,6 +959,32 @@ class Server:
             # stopping with pending ops falls through: the final
             # merge(s) run before the thread exits (close() drains)
             self._merge_once()
+            self._refresh_companion()
+
+    def _refresh_companion(self) -> None:
+        """On the write lane's own thread, once the buffer is empty: a
+        structural merge left the BFS plan's CSC companion marked
+        not-current (level 0 of a batch runs in the loop meanwhile);
+        rebuild it (``GraphEngine.csc_companion``: a host sort and an
+        upload outside every lock).  A write stream that never pauses
+        never pays for it."""
+        v = getattr(self.engine, "version", None)
+        if (
+            self._upd_stop
+            or getattr(v, "csc", None) is None or v.csc_current
+            or v.host_coo is None
+            or (self._upd_buffer is not None and self._upd_buffer.depth())
+        ):
+            return
+        try:
+            # grow=False: a graph that outgrew the length the plans
+            # were traced with keeps the stand-in (a longer operand
+            # would retrace every width on the query path)
+            grown = self.engine.csc_companion(grow=False) is None
+            outcome = "outgrown" if grown else "ok"
+        except Exception:  # the stand-in keeps serving
+            outcome = "error"
+        obs.count("serve.bfs.companion_rebuilds", outcome=outcome)
 
     def _stop_mutator(self, drain: bool, timeout: float,
                       abort_exc: Exception | None = None) -> None:

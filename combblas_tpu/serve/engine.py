@@ -9,8 +9,8 @@ both halves for one graph:
   the column-normalized PageRank transition matrix + dangling vector,
   the transpose (BC on directed graphs) and the row/column degree
   vectors (``coldeg``) — built host-side once at load, uploaded once;
-  the CSC companion tiers (``csc_companion()``, the future
-  sparse-regime hook) build lazily on first use;
+  where BFS is served, the CSC companion (``csc_companion()``): the
+  column structure level 0 of the BFS plan walks from the roots;
 * a **plan cache** keyed by (query kind, lane width): each plan is one
   jitted program whose trace increments both a host-side counter and
   the ``trace.serve`` obs counter (trace-time side effects count
@@ -136,7 +136,14 @@ class GraphVersion:
     P_ell: object = None           # pagerank transition matrix
     dangling: object = None        # pagerank dangling DistVec
     ET: object = None              # None => symmetric (E is its own T)
-    csc: object = None             # lazy CSC companion cache
+    csc: object = None             # CSC companion (indptr, rowidx),
+    #                                an operand of the BFS plan
+    csc_current: bool = True       # False: ``csc`` has the shapes the
+    #                                plans were traced with but not this
+    #                                version's edges (a structural merge,
+    #                                a snapshot without one): level 0
+    #                                runs in the loop until
+    #                                ``csc_companion()`` rebuilds it
     coldeg: object = None          # lazy col-degree DistVec cache
     host_coo: tuple | None = None  # retained iff keep_coo=True
     host_weights: object = None    # deduped weights (the mutation lane)
@@ -187,13 +194,18 @@ class GraphVersion:
 def _build_version(grid, rows, cols, nrows: int, ncols: int,
                    weights, kinds: tuple[str, ...], symmetric: bool,
                    keep_coo: bool, features=None,
-                   headroom: float | None = None) -> GraphVersion:
+                   headroom: float | None = None,
+                   companion: bool = False,
+                   companion_cap: int | None = None) -> GraphVersion:
     """Host-side construction of every artifact ``kinds`` need (the
     body of the old ``from_coo``): dedup the COO, build the structural
     / weighted / normalized / transposed matrices and the degree
     tables. Runs WITHOUT any engine lock — this is the double-buffered
     half of hot-swap: build the next generation while the current one
-    keeps serving."""
+    keeps serving.  ``companion``: also the CSC companion of the deduped
+    edges (an engine that serves BFS; a shard's slab does not), at the
+    length ``companion_cap`` where they fit it (the one the engine's
+    plans were traced with)."""
     from ..parallel.ellmat import EllParMat
     from ..parallel.vec import DistVec
 
@@ -274,6 +286,14 @@ def _build_version(grid, rows, cols, nrows: int, ncols: int,
         if ("bc" in kinds or "propagate" in kinds) and not symmetric:
             ET = EllParMat.from_host_coo(grid, cols, rows, ones,
                                          ncols, n, headroom=headroom)
+        csc = None
+        if companion:
+            from ..parallel.ellmat import build_csc_companion
+
+            csc = build_csc_companion(
+                grid, rows, cols, n, ncols, headroom=headroom,
+                cap=companion_cap,
+            )
         X = None
         feat_dim = 0
         # like every other artifact here, the feature table is built
@@ -302,7 +322,7 @@ def _build_version(grid, rows, cols, nrows: int, ncols: int,
     return GraphVersion(
         nrows=n, ncols=ncols, nnz=int(len(rows)), E=E, deg=deg,
         outdeg=outdeg, E_weighted=E_weighted, P_ell=P_ell,
-        dangling=dangling, ET=ET,
+        dangling=dangling, ET=ET, csc=csc,
         host_coo=(rows, cols, ncols) if keep_coo else None,
         # the deduped (min-combined) weights ride along for the
         # mutation lane's merge-state bootstrap
@@ -387,6 +407,7 @@ class GraphEngine:
         self._plans_lock = threading.Lock()
         self.plan_hits = 0
         self.plan_misses = 0
+        self._flags = None  # ``_push_operand``'s device bools
 
     # -- version delegation ------------------------------------------------
     # The loaded matrices live on the CURRENT GraphVersion; these
@@ -510,6 +531,7 @@ class GraphEngine:
         version = _build_version(
             grid, rows, cols, n, ncols, weights, tuple(kinds),
             symmetric, keep_coo, features=features, headroom=headroom,
+            companion="bfs" in kinds,
         )
         return GraphEngine(
             grid, version=version, kinds=tuple(kinds),
@@ -544,6 +566,12 @@ class GraphEngine:
             # bucket shapes must round-trip the swap: reuse the
             # engine's configured headroom
             headroom=self._version.headroom,
+            companion="bfs" in self._kinds,
+            # like the buckets' shapes, the companion's rides the swap
+            companion_cap=(
+                None if self._version.csc is None
+                else int(self._version.csc[1].shape[-1])
+            ),
         )
         if v.X is None and self._version.X is not None:
             # features are edge-independent: a version rebuilt without
@@ -656,9 +684,9 @@ class GraphEngine:
 
     def coldeg_vec(self):
         """Col-aligned out-degree DistVec (the budget input of the
-        direction-optimized kernels) — built lazily like
-        ``csc_companion``: no current dense plan consumes it, so the
-        device upload is deferred to first use and cached."""
+        direction-optimized kernels) — built lazily: no served plan
+        consumes it, so the device upload is deferred to first use and
+        cached."""
         if self.coldeg is None:
             outdeg = getattr(self, "_outdeg", None)
             if outdeg is None:
@@ -673,30 +701,79 @@ class GraphEngine:
             )
         return self.coldeg
 
-    def csc_companion(self):
-        """The CSC companion tiers (``ellmat.build_csc_companion``) —
-        the direction-optimization hook for future sparse-regime serve
-        plans. Built LAZILY on first use (it is dead weight for the
-        dense batch kernels the current plans run) and cached; needs
-        the host COO, so it requires ``from_coo(..., keep_coo=True)``
-        (opt-in: retaining the edge list costs ~8 bytes/nnz of host RAM
-        for the engine's lifetime). The COO is released after the
-        build — the companion caches, the edge list does not linger.
-        """
-        if self.csc is None:
-            if self._host_coo is None:
-                raise ValueError(
-                    "csc_companion needs the host COO: build the "
-                    "engine with GraphEngine.from_coo(keep_coo=True)"
-                )
-            from ..parallel.ellmat import build_csc_companion
+    def csc_companion(self, grow: bool = True):
+        """The CSC companion of the served graph
+        (``ellmat.build_csc_companion``): the ``(indptr, rowidx)``
+        device pair whose columns level 0 of the BFS plan walks from
+        the batch's roots (``models.bfs._bfs_batch_tallied``).
+        ``from_coo`` builds it with the matrices when ``"bfs"`` is
+        served, snapshots carry it, and this returns it.
 
-            rows, cols, ncols = self._host_coo
-            self.csc = build_csc_companion(
-                self.grid, rows, cols, self.nrows, ncols
+        Where the version's is not current (a structural merge keeps
+        the parent's arrays as a stand-in of the same shapes; a
+        snapshot from before there was one has a placeholder) it is
+        rebuilt here from the version's host COO, off the query path:
+        the sort and the upload hold no lock, the flip is one
+        assignment under the execution lock.  The length the plans were
+        traced with is kept when the edges fit it (zero retraces), and
+        outgrown by the ``headroom`` policy when they do not (one
+        retrace a width, like any version of another shape), unless
+        ``grow`` is False: then the stand-in stays and None is
+        returned (the write lane's call: never a retrace on the query
+        path).  Needs the edge list: ``from_coo(..., keep_coo=True)``,
+        which every merged version has."""
+        v = self._version
+        if v.csc is not None and v.csc_current:
+            return v.csc
+        if v.host_coo is None:
+            raise ValueError(
+                "csc_companion needs the host COO to rebuild from: "
+                "build the engine with GraphEngine.from_coo("
+                "keep_coo=True)"
             )
-            self._host_coo = None  # companion built: drop the edge list
-        return self.csc
+        from ..parallel.ellmat import (
+            build_csc_companion_host, upload_csc_companion)
+
+        rows, cols, ncols = v.host_coo
+        cap = None if v.csc is None else int(v.csc[1].shape[-1])
+        if not grow and cap is not None:
+            # one count a tile, before the sort that would be thrown away
+            g = self.grid
+            tile = (np.asarray(rows) // g.local_rows(self.nrows)) * g.pc \
+                + np.asarray(cols) // g.local_cols(ncols)
+            if np.bincount(tile, minlength=g.size).max() > cap:
+                return None
+        indptr, rowidx = build_csc_companion_host(
+            self.grid, rows, cols, self.nrows, ncols,
+            headroom=v.headroom, cap=cap,
+        )
+        csc = upload_csc_companion(self.grid, indptr, rowidx)
+        with self._exec_lock:
+            v.csc, v.csc_current = csc, True
+        return csc
+
+    def _push_operand(self) -> tuple:
+        """``(indptr, rowidx, current)`` for the BFS plan.  A version
+        without a companion (built by hand, or restored from a snapshot
+        that predates it) gets the smallest stand-in, marked
+        not-current: its batches run level 0 in the loop."""
+        import jax.numpy as jnp
+
+        v = self._version
+        if v.csc is None:
+            from ..parallel.ellmat import upload_csc_companion
+
+            g, lc = self.grid, self.grid.local_cols(v.ncols)
+            v.csc = upload_csc_companion(
+                g, np.zeros((g.pr, g.pc, lc + 1), np.int32),
+                np.full((g.pr, g.pc, 1), g.local_rows(v.nrows), np.int32),
+            )
+            v.csc_current = False
+        if self._flags is None:
+            # two device scalars for the engine's life: no transfer a
+            # batch, and a swap between current and not is no retrace
+            self._flags = {b: jnp.asarray(b) for b in (False, True)}
+        return (*v.csc, self._flags[bool(v.csc_current)])
 
     def serve(self, config=None, tenant: str | None = None):
         from .api import Server
@@ -754,12 +831,13 @@ class GraphEngine:
 
         if kind == "bfs":
 
-            def impl(E, sources):
-                # (parents, levels, niter, sweep tally): the tally is
-                # read back only with telemetry on (``execute``)
+            def impl(E, csc, sources):
+                # (parents, levels, niter, sweep tally, what level 0
+                # did): the last two are read back only with telemetry
+                # on (``execute``)
                 trace_mark()
                 return _bfs_batch_tallied(
-                    E, sources, self.max_iters, SELECT2ND_MAX, True
+                    E, sources, self.max_iters, SELECT2ND_MAX, True, csc
                 )
 
         elif kind == "sssp":
@@ -876,7 +954,7 @@ class GraphEngine:
         """The current version's operands for one kind (the properties
         apply the unit-weight / symmetric-transpose fallbacks)."""
         if kind == "bfs":
-            return (self.E,)
+            return (self.E, self._push_operand())
         if kind == "sssp":
             return (self.E_weighted,)
         if kind == "pagerank":
@@ -911,6 +989,10 @@ class GraphEngine:
 
         kinds = self.kinds() if kinds is None else kinds
         widths = self.DEFAULT_WARMUP_WIDTHS if widths is None else widths
+        if "bfs" in kinds and self._version.host_coo is not None:
+            # a companion that is not current is rebuilt BEFORE the
+            # plans are traced with a stand-in's shapes
+            self.csc_companion()
         out = {}
         for kind in kinds:
             for w in sorted(set(widths)):
@@ -1045,11 +1127,11 @@ class GraphEngine:
         # "batch_niter" is BATCH metadata (the max iteration count
         # over all lanes, pad included), not a per-request fact: a
         # request's own value would vary with its batch-mates
-        sweeps = None
+        sweeps = push = None
         if kind == "bc":
             blocks, niter = (res,), None
         elif kind == "bfs":
-            *blocks, niter, sweeps = res
+            *blocks, niter, sweeps, push = res
         else:
             blocks, niter = res[:-1], res[-1]
         with mark("readback"):
@@ -1066,6 +1148,12 @@ class GraphEngine:
                 # byte counter above
                 for mode, taken in zip(SWEEP_MODES, np.asarray(sweeps)):
                     obs.count("serve.bfs.sweeps", int(taken), mode=mode)
+            if push is not None:
+                from ..models.bfs import PUSH_OUTCOMES
+
+                obs.count(
+                    "serve.bfs.push", outcome=PUSH_OUTCOMES[int(push)]
+                )
             if kind == "sssp":
                 obs.count("serve.sssp.rounds", int(niter), width=W)
                 obs.count("serve.sssp.batches", 1, width=W)

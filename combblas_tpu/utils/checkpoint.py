@@ -276,7 +276,11 @@ def save_version(path: str, version, *, extra_meta: dict | None = None) -> None:
     ``EllParMat.from_host_buckets`` (one ``device_put`` per array, no
     dedup sort, no host bucket pass) and a warmed plan cache keeps
     every compiled executable: ZERO retraces after ``swap()``, the
-    regression-tested guarantee.  The host COO/weights ride along when
+    regression-tested guarantee.  The BFS plan's CSC companion
+    (``version.csc`` and whether it is current) is an operand like the
+    buckets and is persisted the same way; a snapshot without one
+    (written before there was one) loads, and its engine serves BFS
+    with a stand-in marked not-current.  The host COO/weights ride along when
     the version retained them (``keep_coo=True``), so a restored
     replica can still serve the write lane.
 
@@ -338,6 +342,12 @@ def save_version(path: str, version, *, extra_meta: dict | None = None) -> None:
         )
     if version.X is not None:
         arrays["X"] = np.asarray(jax.device_get(version.X.blocks))
+    if version.csc is not None:
+        # the BFS plan's operand, as built: a boot only uploads it
+        indptr, rowidx = version.csc
+        arrays["csc.indptr"] = np.asarray(jax.device_get(indptr))
+        arrays["csc.rowidx"] = np.asarray(jax.device_get(rowidx))
+        meta["csc_current"] = bool(version.csc_current)
     if version.host_coo is not None:
         rows, cols, _nc = version.host_coo
         arrays["coo_rows"] = np.asarray(rows)
@@ -469,6 +479,13 @@ def _load_version(path: str, grid: Grid, writable: bool = True):
                 ),
                 length=meta["ncols"], align="row", grid=grid,
             )
+        csc = None
+        if "csc.indptr" in z:
+            from ..parallel.ellmat import upload_csc_companion
+
+            csc = upload_csc_companion(
+                grid, z["csc.indptr"], z["csc.rowidx"]
+            )
         host_coo = None
         host_weights = None
         if "coo_rows" in z:
@@ -489,6 +506,11 @@ def _load_version(path: str, grid: Grid, writable: bool = True):
             P_ell=mats["P_ell"],
             dangling=dangling,
             ET=mats["ET"],
+            # a snapshot from before the companion has none: the engine
+            # serves it with a stand-in marked not-current (level 0 in
+            # the loop) until ``csc_companion()`` can rebuild one
+            csc=csc,
+            csc_current=bool(meta.get("csc_current", csc is not None)),
             host_coo=host_coo,
             host_weights=host_weights,
             X=X,

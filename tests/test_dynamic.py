@@ -462,9 +462,10 @@ def test_headroom_env_default(monkeypatch):
 
 def test_csc_companion_survives_noop_merge(rng):
     """REGRESSION (round 12): a fold that touched no edges (upsert of
-    an already-present edge) must CARRY the lazy CSC companion and the
-    cached coldeg instead of resetting them to a rebuild-from-COO; any
-    structural change still resets."""
+    an already-present edge) must CARRY the CSC companion and the
+    cached coldeg instead of resetting them to a rebuild-from-COO.  A
+    structural change resets coldeg, and keeps the companion's arrays
+    for their shapes (the BFS plan's operand) marked not-current."""
     eng, rows, cols, _w = _weighted_engine(rng, Grid.make(2, 2))
     sentinel_csc = object()
     sentinel_coldeg = object()
@@ -478,9 +479,10 @@ def test_csc_companion_survives_noop_merge(rng):
     assert v1.dyn.last_stats.mode == "incremental"
     assert v1.dyn.last_stats.inserted == 0
     assert v1.dyn.last_stats.removed == 0
-    assert v1.csc is sentinel_csc
+    assert v1.csc is sentinel_csc and v1.csc_current
     assert v1.coldeg is sentinel_coldeg
-    # a real structural change still resets both (lazily rebuilt)
+    # a real structural change resets coldeg (lazily rebuilt) and marks
+    # the companion
     free = next(
         (a, b) for a in range(3) for b in range(3)
         if not np.any((rows == a) & (cols == b)) and a != b
@@ -490,7 +492,8 @@ def test_csc_companion_survives_noop_merge(rng):
         ("insert", free[1], free[0], 1.0),
     ])
     v2 = apply_delta(eng.version, real, kinds=eng.kinds())
-    assert v2.csc is None and v2.coldeg is None
+    assert v2.csc is sentinel_csc and not v2.csc_current
+    assert v2.coldeg is None
 
 
 def test_symmetry_guard_covers_propagate(rng):

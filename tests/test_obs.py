@@ -109,7 +109,7 @@ def test_disabled_instrumentation_is_free(rng):
     plan = engine.plan("bfs", 16)
 
     def bare():
-        p, l, niter, _sweeps = plan.fn(jnp.asarray(sources))
+        p, l, niter, *_ = plan.fn(jnp.asarray(sources))
         return (engine._lanes_to_global(np.asarray(p)),
                 engine._lanes_to_global(np.asarray(l)), int(niter))
 

@@ -76,8 +76,10 @@ def test_parts_telescope_to_execute_and_only_when_traced(
     assert bool(recs) == want
     for rec in recs:
         ex = next(s for s in rec["stages"] if s["stage"] == "execute")
-        assert [p["stage"] for p in ex["parts"]] == [
-            "launch", "device", "readback", "to_global"]
+        # a batch that started its successor at the hand-off (the four
+        # requests left over, once due) carries the fifth part
+        assert [p["stage"] for p in ex["parts"] if p["stage"] != "handoff"
+                ] == ["launch", "device", "readback", "to_global"]
         assert all(p["s"] >= 0 for p in ex["parts"])
         # parts sum to the stage as stages sum to the wall (each number
         # is rounded to a nanosecond on its own)
@@ -280,8 +282,9 @@ def test_every_documented_scope_is_in_the_lowered_program(shape, program):
     names = _op_names(lowered)
     found = _scope_components(names)
     want = set(bfs_mod.BFS_SCOPES) - {"ell.bucket<i>"}
-    if program == "served":
-        want -= {"bfs.parents"}
+    # each program has one scope the other lacks: level 0 as a push is
+    # the served plan's, the parents pass the compact program's
+    want -= {"bfs.parents"} if program == "served" else {"bfs.push"}
     assert want <= found, sorted(want - found)
     nb = len(eng.E.buckets)
     assert {f"ell.bucket{i}" for i in range(nb)} <= found

@@ -254,21 +254,38 @@ def test_submit_many_generator_keeps_future_per_root(engine, live_roots):
     srv.scheduler.fail_pending(RuntimeError("test teardown"))
 
 
-def test_csc_companion_opt_in_and_released(graph):
-    """CSC tiers build lazily from the retained COO (opt-in), which is
-    released after the build; without keep_coo the hook raises."""
+def test_csc_companion_lives_with_the_version(graph):
+    """An engine that serves BFS builds the CSC companion with its
+    matrices (no ``keep_coo`` needed: ``from_coo`` has the edges in
+    hand); an engine that does not serve BFS has none.  One that is not
+    current is rebuilt from the retained COO, which stays (the write
+    lane merges into it); without the COO there is nothing to rebuild
+    from."""
     rows, cols = graph
     eng = GraphEngine.from_coo(
         Grid.make(1, 1), rows, cols, N, kinds=("bfs",), keep_coo=True
     )
     csc = eng.csc_companion()
-    assert len(csc) == 2 and eng._host_coo is None  # edge list dropped
-    assert eng.csc_companion() is csc  # cached
+    assert len(csc) == 2 and csc is eng.version.csc
+    assert eng.version.csc_current and eng._host_coo is not None
+    eng.version.csc_current = False  # as a structural merge leaves it
+    rebuilt = eng.csc_companion()
+    assert rebuilt is not csc and eng.version.csc_current
+    for a, b in zip(csc, rebuilt):
+        assert a.shape == b.shape
+        assert np.array_equal(np.asarray(a), np.asarray(b))
     eng2 = GraphEngine.from_coo(
         Grid.make(1, 1), rows, cols, N, kinds=("bfs",)
     )
+    assert eng2.csc_companion() is eng2.version.csc
+    eng2.version.csc_current = False
     with pytest.raises(ValueError, match="keep_coo"):
         eng2.csc_companion()
+    eng3 = GraphEngine.from_coo(
+        Grid.make(1, 1), rows, cols, N,
+        weights=np.ones(len(rows), np.float32), kinds=("sssp",),
+    )
+    assert eng3.version.csc is None
 
 
 def test_scatter_returns_lane_copies(engine, live_roots):

@@ -62,6 +62,35 @@ def operands(topo):
     return EllParMat(buckets=buckets, nrows=n, ncols=n, grid=grid), grid
 
 
+def _companion(grid, rows, cols, n):
+    """``(indptr, rowidx, current)`` as the engine hands them to the BFS
+    plan (``GraphEngine._push_operand``), as shapes on ``grid``."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from combblas_tpu.parallel.ellmat import (
+        TILE_SPEC, build_csc_companion_host)
+    from combblas_tpu.parallel.grid import Grid
+
+    host = build_csc_companion_host(
+        Grid.make(grid.pr, grid.pc), rows, cols, n, n)
+    tile = NamedSharding(grid.mesh, TILE_SPEC)
+    return tuple(
+        jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=tile) for a in host
+    ) + (jax.ShapeDtypeStruct(
+        (), jnp.bool_, sharding=NamedSharding(grid.mesh, P())),)
+
+
+@pytest.fixture(scope="module")
+def companion(operands):
+    from chipbench import graph
+
+    E, grid = operands
+    n, rows, cols, _ = graph.rmat_graph(SCALE, 16, 1)
+    return _companion(grid, rows, cols, n)
+
+
 def _optimised(fn, E, width, grid):
     import jax
     import jax.numpy as jnp
@@ -127,9 +156,10 @@ def test_scopes_change_no_instruction_for_the_v5e(
 # that never pass a row mask are the programs they were ------------------
 
 
-def _plan_hlo(kind, E, grid):
+def _plan_hlo(kind, E, grid, csc=None, strip=True):
     """Stripped optimised HLO of the width-16 served plan of ``kind`` as
-    ``engine._build_plan`` composes it (same function names)."""
+    ``engine._build_plan`` composes it (same function names; ``csc``:
+    the BFS plan's second operand)."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
@@ -145,10 +175,10 @@ def _plan_hlo(kind, E, grid):
         (16,), jnp.int32, sharding=NamedSharding(grid.mesh, P())
     )
     if kind == "bfs":
-        def serve_bfs_w16(E, sources):
+        def serve_bfs_w16(E, csc, sources):
             return bfs_mod._bfs_batch_tallied(
-                E, sources, None, SELECT2ND_MAX, True)
-        args, fn = (E, sources), serve_bfs_w16
+                E, sources, None, SELECT2ND_MAX, True, csc)
+        args, fn = (E, csc, sources), serve_bfs_w16
     elif kind == "sssp":
         def serve_sssp_w16(E, sources):
             return _sssp_batch_impl(E, sources)
@@ -167,20 +197,103 @@ def _plan_hlo(kind, E, grid):
                 P_ell, sources, dangling, alpha=0.85, tol=1e-6,
                 max_iters=100)
         args, fn = (E, dangling, sources), serve_pagerank_w16
-    return _strip(jax.jit(fn).lower(*args).compile().as_text())
+    text = jax.jit(fn).lower(*args).compile().as_text()
+    return _strip(text) if strip else text
 
 
 def test_every_class_is_a_conditional_for_the_v5e(
-        operands, all_dense_sweeps):
+        operands, companion, all_dense_sweeps):
     """The chip's compiler keeps the choice a branch: one ``conditional``
     per degree class in the served BFS plan, not a ``select`` that runs
-    the sweep and throws it away."""
+    the sweep and throws it away; and exactly one more, level 0's (push
+    or leave the state as it is: PR 29).  With every sweep dense, the
+    push's alone is left."""
     E, grid = operands
-    text = _plan_hlo("bfs", E, grid)
+    text = _plan_hlo("bfs", E, grid, companion)
     conditionals = re.findall(r"= [^=\n]* conditional\(", text)
-    assert len(conditionals) == len(E.buckets)
+    assert len(conditionals) == len(E.buckets) + 1
     all_dense_sweeps(True)
+    dense = _plan_hlo("bfs", E, grid, companion)
+    assert len(re.findall(r"= [^=\n]* conditional\(", dense)) == 1
     assert " conditional(" not in _plan_hlo("bfs", E, grid)
+
+
+def _computations(text):
+    """``{computation: [instruction lines]}`` of an HLO module's text."""
+    comps, cur = {}, None
+    for ln in text.splitlines():
+        if ln and not ln[0].isspace() and ln.rstrip().endswith("{"):
+            toks = ln.split()
+            cur = (toks[1] if toks[0] == "ENTRY" else toks[0]).lstrip("%")
+            comps[cur] = []
+        elif cur is not None:
+            comps[cur].append(ln.strip())
+    return comps
+
+
+def _loop_gather_tables(text, width=16):
+    """``[(class, defining line of the table its sweep gathers from)]``
+    for every degree class's payload gather inside ``bfs.level``: the
+    gather sits in a fusion, the table is that fusion's operand."""
+    comps = _computations(text)
+    defs = {
+        c: {re.sub(r"^(ROOT )?%", "", ln.split(" = ")[0]): ln
+            for ln in lines if " = " in ln}
+        for c, lines in comps.items()
+    }
+    def produced(c, name):
+        """The line that computes ``name`` of computation ``c``: through
+        the fusions that only hand it down as a parameter."""
+        param = re.match(r"param_(\d+)", name)
+        if not param:
+            return defs[c].get(name, "")
+        for c2, lines2 in comps.items():
+            for l2 in lines2:
+                if re.search(r"calls=%%?%s(?![\w.])" % re.escape(c), l2):
+                    ops = re.search(
+                        r" fusion\(([^)]*)\)", l2).group(1).split(", ")
+                    return produced(
+                        c2, ops[int(param.group(1))].lstrip("%"))
+        return defs[c].get(name, "")
+
+    out = []
+    for c, lines in comps.items():
+        for ln in lines:
+            m = re.search(
+                r"= s32\[[\d,]+,%d\]\S* gather\(%%?([\w.\-]+), .*"
+                r"op_name=\"[^\"]*bfs\.level[^\"]*"
+                r"ell\.bucket(\d+)/gather/gather\"" % width, ln)
+            if m:
+                out.append((int(m.group(2)), produced(c, m.group(1))))
+    return sorted(out)
+
+
+def test_push_is_peeled_and_the_loop_keeps_its_fast_tables(
+        operands, companion):
+    """Level 0 runs BEFORE the loop under ``bfs.push``; the loop is still
+    ``bfs.level/while``, and inside it every class's gather table is
+    still built in the branch that gathers from it and placed in the
+    compiler's fast memory (``S(1)`` on its layout).  A table handed
+    into a ``conditional`` stays in HBM and gathers three times slower:
+    what made PR 24's first build 17% slower (PERF.md section 6)."""
+    from combblas_tpu.obs import opnames
+
+    E, grid = operands
+    text = _plan_hlo("bfs", E, grid, companion, strip=False)
+    names = opnames.parse(text)[1]
+    loops = [nm for i, nm in names.items() if i.startswith("while")]
+    assert any(nm.endswith("bfs.level/while") for nm in loops), loops
+    seen = set(names.values())
+    assert any("/bfs.push/" in nm and "bfs.level" not in nm for nm in seen)
+    assert not any("bfs.push" in nm and "bfs.level" in nm for nm in seen)
+    for scope in ("ell.reduce", "bfs.update", "vec.realign", "bfs.active"):
+        assert any(f"/bfs.push/" in nm and f"/{scope}/" in nm
+                   for nm in seen), scope
+    tables = _loop_gather_tables(text)
+    assert sorted({cls for cls, _ in tables}) == list(range(len(E.buckets)))
+    for cls, line in tables:
+        layout = line.split(" = ", 1)[1].split(" ", 1)[0]
+        assert "S(1)" in layout and "bfs.level" in line, (cls, line[:200])
 
 
 #: sha256 of the stripped optimised HLO of the width-16 PageRank and SSSP
@@ -259,19 +372,22 @@ def test_mesh_loop_keeps_its_name_for_the_v5e(topo, kind, loop):
         nrows=n, ncols=n, grid=grid,
     )
 
-    def serve_bfs_w16(E, sources):
+    def serve_bfs_w16(operands, sources):
         from combblas_tpu.models import bfs as bfs_mod
         from combblas_tpu.semiring import SELECT2ND_MAX
 
         return bfs_mod._bfs_batch_tallied(
-            E, sources, None, SELECT2ND_MAX, True)
+            operands[0], sources, None, SELECT2ND_MAX, True, operands[1])
 
     def serve_sssp_w16(E, sources):
         from combblas_tpu.models.sssp import _sssp_batch_impl
 
         return _sssp_batch_impl(E, sources)
 
-    fn = serve_bfs_w16 if kind == "bfs" else serve_sssp_w16
+    if kind == "bfs":  # the plan's operands: the matrix and its companion
+        fn, E = serve_bfs_w16, (E, _companion(grid, rows, cols, n))
+    else:
+        fn = serve_sssp_w16
     names = opnames.parse(_optimised(fn, E, 16, grid))[1]
     loops = [nm for instr, nm in names.items() if instr.startswith("while")]
     assert any(nm.endswith(loop + "/while") for nm in loops), loops
