@@ -143,6 +143,24 @@ def betweenness_centrality(
     return acc
 
 
+#: The ``jax.named_scope`` names of the dense batch program
+#: (``_bc_batch_lanes``), outermost first; inside ``bc.forward`` and
+#: ``bc.backward`` the sweep's own ``ell.bucket<i>`` / ``gather`` /
+#: ``fold`` / ``scatter_rows`` and ``vec.realign``.  Trace-time metadata
+#: only: the device trace's per-scope and per-sweep times are read by
+#: these names (docs/observability.md "Named scopes").
+BC_SCOPES = (
+    "bc.init",
+    "bc.forward",  # the whole while loop; one iteration = one BFS level
+    "bc.backward",  # the whole loop; one iteration = one level back
+    "bc.finish",
+)
+
+#: What ``_bc_batch_lanes``' ``int32[2]`` of whole ELL sweeps counts, in
+#: order: one forward sweep a BFS level, one backward sweep a level back.
+BC_PHASES = ("forward", "backward")
+
+
 def bc_batch_dense(E, ET, sources, max_depth: int | None = None):
     """Eager wrapper over ``_bc_batch_dense_impl`` (plain-outputs law)."""
     total = _bc_batch_dense_impl(E, ET, sources, max_depth=max_depth)
@@ -171,6 +189,17 @@ def bc_batch_dense_lanes(E, ET, sources, max_depth: int | None = None):
 @partial(jax.jit, static_argnames=("max_depth", "per_lane"))
 def _bc_batch_dense_impl(E, ET, sources, max_depth: int | None = None,
                          per_lane: bool = False):
+    """``_bc_batch_lanes`` without its counts: the [pr, lr, W] per-lane
+    dependencies (``per_lane=True``) or their sum over the lanes, the
+    row-aligned partial BC blocks of these W sources."""
+    delta, _, _ = _bc_batch_lanes(E, ET, sources, max_depth)
+    if per_lane:
+        return delta
+    with jax.named_scope("bc.finish"):
+        return jnp.sum(delta, axis=-1)
+
+
+def _bc_batch_lanes(E, ET, sources, max_depth: int | None):
     """Batched Brandes in ONE compiled program over dense [n, W] state.
 
     The host-loop ``bc_batch`` mirrors the reference's
@@ -183,33 +212,40 @@ def _bc_batch_dense_impl(E, ET, sources, max_depth: int | None = None,
 
     ``E``: adjacency with entry (i, j) = edge j→i (the BFS gather
     orientation); ``ET``: its transpose (pass the same EllParMat for
-    symmetric graphs). ``sources``: [W] int32. Returns the row-aligned
-    partial BC DistVec (dependency sums over these W sources, endpoints
-    excluded per Brandes).
+    symmetric graphs). ``sources``: [W] int32. Returns ``(delta,
+    depth, sweeps)``: the row-aligned PLAIN [pr, lr, W] per-lane
+    dependencies (lane k is Brandes' delta of ``sources[k]``, endpoints
+    excluded), the number of BFS levels that hold a vertex in the deepest
+    lane (the roots' own level counted), and the ``int32[2]`` count of
+    whole ELL sweeps the two loops ran, by ``BC_PHASES`` (forward: one a
+    level, the last of which finds nothing unless ``max_depth`` cut the
+    loop short; backward: one a level but the roots').  Not jitted
+    itself: the served plan (``engine._build_plan``) traces it inside its
+    own program and hands each lane back to its request, the depth as
+    ``batch_niter``.
     """
     from ..parallel.ellmat import dist_spmv_ell_multi
     from ..parallel.vec import DistMultiVec
+    from . import PAD_ROOT
 
     grid = E.grid
     n = E.nrows
-    W = sources.shape[0]
     D = max_depth if max_depth is not None else n
-
-    gids = DistVec.iota(grid, n, jnp.int32, align="row").blocks  # [pr, lr]
-    # models.PAD_ROOT lanes are inert (all-zero dependencies — the
-    # serve batcher's lane padding). The iota gid table pads with ids
-    # >= n so PAD_ROOT can never match, but the explicit guard keeps
-    # the contract independent of the gid-table padding convention
-    # (the -1-padded _global_ids tables WOULD match).
-    from . import PAD_ROOT
-
-    live = sources[None, None, :] != PAD_ROOT
-    is_src = (gids[..., None] == sources[None, None, :]) & live
-    lvl0 = jnp.where(is_src, 0, -1).astype(jnp.int32)
-    nsp0 = is_src.astype(E.dtype)
 
     def mk(blocks):
         return DistMultiVec(blocks=blocks, length=n, align="row", grid=grid)
+
+    with jax.named_scope("bc.init"):
+        gids = DistVec.iota(grid, n, jnp.int32, align="row").blocks  # [pr, lr]
+        # models.PAD_ROOT lanes are inert (all-zero dependencies — the
+        # serve batcher's lane padding). The iota gid table pads with ids
+        # >= n so PAD_ROOT can never match, but the explicit guard keeps
+        # the contract independent of the gid-table padding convention
+        # (the -1-padded _global_ids tables WOULD match).
+        live = sources[None, None, :] != PAD_ROOT
+        is_src = (gids[..., None] == sources[None, None, :]) & live
+        lvl0 = jnp.where(is_src, 0, -1).astype(jnp.int32)
+        nsp0 = is_src.astype(E.dtype)
 
     def fcond(st):
         d, _, _, active = st
@@ -224,9 +260,12 @@ def _bc_batch_dense_impl(E, ET, sources, max_depth: int | None = None,
         nsp = nsp + jnp.where(new, arriving, 0)
         return d + 1, lvl, nsp, jnp.any(new)
 
-    depth, lvl, nsp, still_active = jax.lax.while_loop(
-        fcond, fstep, (jnp.int32(0), lvl0, nsp0, jnp.bool_(True))
-    )
+    # the whole loop, condition included, is one scope: a forward sweep
+    # is one iteration of it in the device trace
+    with jax.named_scope("bc.forward"):
+        depth, lvl, nsp, still_active = jax.lax.while_loop(
+            fcond, fstep, (jnp.int32(0), lvl0, nsp0, jnp.bool_(True))
+        )
 
     # Backward dependency sweep: d = depth ... 1; every level-(d) vertex
     # w exports (1+delta[w])/nsp[w]; level-(d-1) predecessors v collect it
@@ -245,18 +284,20 @@ def _bc_batch_dense_impl(E, ET, sources, max_depth: int | None = None,
         upd = jnp.where(lvl == d - 1, collected * nsp, 0)
         return delta + upd
 
-    # on natural exit level `depth` is empty (the last step found
-    # nothing) — skip its guaranteed no-op SpMV; when the max_depth bound
-    # cut the sweep short (still_active), level `depth` is real
-    start = jnp.where(still_active, 0, 1)
-    delta = jax.lax.fori_loop(
-        start, depth, bstep, jnp.zeros_like(nsp0)
-    )
-    # endpoints excluded: zero each lane's own source slot, sum lanes
-    # (``per_lane=True`` skips the sum — the serve path hands each lane
-    # back to its own request)
-    delta = jnp.where(is_src, 0, delta)
-    if per_lane:
-        return delta
-    total = jnp.sum(delta, axis=-1)
-    return total
+    with jax.named_scope("bc.backward"):
+        # on natural exit level `depth` is empty (the last step found
+        # nothing) — skip its guaranteed no-op SpMV; when the max_depth
+        # bound cut the sweep short (still_active), level `depth` is real
+        start = jnp.where(still_active, 0, 1)
+        delta = jax.lax.fori_loop(
+            start, depth, bstep, jnp.zeros_like(nsp0)
+        )
+    with jax.named_scope("bc.finish"):
+        # endpoints excluded: zero each lane's own source slot
+        delta = jnp.where(is_src, 0, delta)
+        # levels that hold a vertex: 0 .. depth - 1, and level `depth`
+        # too where the bound stopped a sweep that was still finding
+        levels = depth + still_active.astype(jnp.int32)
+        # iterations of the two loops above, as they ran
+        sweeps = jnp.stack([depth, depth - start]).astype(jnp.int32)
+    return delta, levels, sweeps

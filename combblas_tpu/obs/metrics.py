@@ -646,6 +646,18 @@ name                                   kind       meaning
 ``serve.sssp.batches``                 counter    served SSSP batches
                                                   executed (label
                                                   ``width``)
+``serve.bc.sweeps``                    counter    whole ELL sweeps of
+                                                  served BC batches
+                                                  (labels ``phase`` =
+                                                  forward: one a BFS
+                                                  level, the last
+                                                  finding nothing /
+                                                  backward: one a level
+                                                  but the roots';
+                                                  ``width``)
+``serve.bc.batches``                   counter    served BC batches
+                                                  executed (label
+                                                  ``width``)
 ``obs.provider_errors``                counter    broken pull-provider
                                                   callbacks (caught)
 =====================================  =========  =====================

@@ -2,7 +2,10 @@
 fixed-width lanes the batch kernels want.
 
 The batch kernels (``models.bfs.bfs_batch``, ``models.sssp.sssp_batch``,
-``models.pagerank.pagerank_batch``, ``models.bc.bc_batch_dense_lanes``)
+``models.pagerank.pagerank_batch``, ``models.bc.bc_batch_dense_lanes``;
+each served plan returns its ``[n, W]`` result blocks and then the
+batch's iteration count, for ``bc`` ``(scores, depth, ...)``, which
+``scatter`` hands every request unchanged as ``batch_niter``)
 amortize the per-index gather cost across W payload lanes — but they are
 compiled per (kind, W, dtype), so serving arbitrary request counts
 directly would retrace constantly. The batcher therefore rounds every

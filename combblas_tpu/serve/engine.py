@@ -816,7 +816,7 @@ class GraphEngine:
     def _build_plan(self, kind: str, width: int) -> _Plan:
         import jax
 
-        from ..models.bc import _bc_batch_dense_impl
+        from ..models.bc import _bc_batch_lanes
         from ..models.bfs import _bfs_batch_tallied
         from ..semiring import SELECT2ND_MAX
         from ..models.pagerank import _pagerank_batch_impl
@@ -864,11 +864,10 @@ class GraphEngine:
         elif kind == "bc":
 
             def impl(E, ET, sources):
+                # (per-lane dependencies, levels of the deepest lane,
+                # sweeps by BC_PHASES)
                 trace_mark()
-                return _bc_batch_dense_impl(
-                    E, ET, sources, max_depth=self.max_iters,
-                    per_lane=True,
-                )
+                return _bc_batch_lanes(E, ET, sources, self.max_iters)
 
         elif kind == "propagate":
             from ..models.propagate import _propagate_batch_impl
@@ -1028,8 +1027,12 @@ class GraphEngine:
             blocks=blocks, length=self.nrows, align="row", grid=self.grid
         ).to_global()
 
-    #: Result names of each kind's device blocks, in program order (a
-    #: trailing iteration count follows them for every kind but "bc").
+    #: Result names of each kind's device blocks, in program order.  An
+    #: iteration count follows them (BFS levels, Bellman-Ford rounds,
+    #: PageRank iterations, and for "bc" the BFS levels of the batch's
+    #: deepest lane), then what the kind's program counted of itself,
+    #: read only with telemetry on ("bfs": sweeps by mode and the push's
+    #: outcome; "bc": sweeps by phase).
     _RESULT_KEYS = {
         "bfs": ("parents", "levels"),
         "sssp": ("dist", "parents"),
@@ -1127,13 +1130,8 @@ class GraphEngine:
         # "batch_niter" is BATCH metadata (the max iteration count
         # over all lanes, pad included), not a per-request fact: a
         # request's own value would vary with its batch-mates
-        sweeps = push = None
-        if kind == "bc":
-            blocks, niter = (res,), None
-        elif kind == "bfs":
-            *blocks, niter, sweeps, push = res
-        else:
-            blocks, niter = res[:-1], res[-1]
+        blocks, niter = res[:len(keys)], res[len(keys)]
+        counted = res[len(keys) + 1:]
         with mark("readback"):
             host = [np.asarray(b) for b in blocks]
         if obs.ENABLED:
@@ -1141,28 +1139,33 @@ class GraphEngine:
                 "serve.readback.bytes",
                 sum(h.nbytes for h in host), kind=kind, width=W,
             )
-            if sweeps is not None:
+            # (a few bytes a batch, not part of the result: kept out
+            # of the byte counter above)
+            if kind == "bfs":
+                from ..models.bfs import PUSH_OUTCOMES
                 from ..parallel.ellmat import SWEEP_MODES
 
-                # 8 bytes, not part of the result: kept out of the
-                # byte counter above
+                sweeps, push = counted
                 for mode, taken in zip(SWEEP_MODES, np.asarray(sweeps)):
                     obs.count("serve.bfs.sweeps", int(taken), mode=mode)
-            if push is not None:
-                from ..models.bfs import PUSH_OUTCOMES
-
                 obs.count(
                     "serve.bfs.push", outcome=PUSH_OUTCOMES[int(push)]
                 )
             if kind == "sssp":
                 obs.count("serve.sssp.rounds", int(niter), width=W)
                 obs.count("serve.sssp.batches", 1, width=W)
+            if kind == "bc":
+                from ..models.bc import BC_PHASES
+
+                for phase, ran in zip(BC_PHASES, np.asarray(counted[0])):
+                    obs.count("serve.bc.sweeps", int(ran),
+                              phase=phase, width=W)
+                obs.count("serve.bc.batches", 1, width=W)
         with mark("to_global"):
             out = {
                 k: self._lanes_to_global(h) for k, h in zip(keys, host)
             }
-            if niter is not None:
-                out["batch_niter"] = int(niter)
+            out["batch_niter"] = int(niter)
             return out
 
     def stats(self) -> dict:

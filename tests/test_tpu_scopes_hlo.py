@@ -426,3 +426,48 @@ def test_sssp_round_names_the_loop_of_the_one_chip_program(operands):
     assert (dist.dtype, parents.dtype) == (jnp.float32, jnp.int32)
     assert dist.shape == parents.shape == (1, E.nrows, 16)
     assert rounds.shape == ()
+
+
+def test_bc_scopes_name_both_loops_of_the_one_chip_program(operands):
+    """The served BC program for the described v5e: two ``while``s, one
+    under ``bc.forward`` and one under ``bc.backward``
+    (``chipbench/bcscopes.py`` reads the sweeps of each by them), every
+    one of ``BC_SCOPES`` on some instruction, the class and leaf scopes
+    under both loops, no ``conditional`` (no sweep is thinned), the
+    gather tables in the fast memory, and the answer three arrays: the
+    per-lane dependencies, the batch's depth and the sweeps each loop
+    ran."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from combblas_tpu.models.bc import BC_SCOPES, _bc_batch_lanes
+    from combblas_tpu.obs import opnames
+
+    E, grid = operands
+
+    def serve_bc_w16(E, ET, sources):
+        return _bc_batch_lanes(E, ET, sources, None)
+
+    sources = jax.ShapeDtypeStruct(
+        (16,), jnp.int32, sharding=NamedSharding(grid.mesh, P()))
+    text = jax.jit(serve_bc_w16).lower(E, E, sources).compile().as_text()
+    names = opnames.parse(text)[1]
+    loops = [nm for i, nm in names.items() if i.startswith("while")]
+    for loop in ("bc.forward", "bc.backward"):
+        assert any(nm.endswith(loop + "/while") for nm in loops), loops
+    seen = set(names.values())
+    for scope in BC_SCOPES:
+        assert any(f"/{scope}/" in nm for nm in seen), scope
+    for phase in ("bc.forward", "bc.backward"):
+        for leaf in ("gather", "fold"):
+            assert any(f"/{phase}/" in nm and f"ell.bucket0/{leaf}" in nm
+                       for nm in seen), (phase, leaf)
+    assert " conditional(" not in text
+    table = rf"f32\[{E.nrows + 1},16\]\{{[^}}]*\}}"
+    assert any("S(1)" in t for t in re.findall(table, text))
+    scores, depth, sweeps = jax.eval_shape(serve_bc_w16, E, E, sources)
+    assert scores.dtype == jnp.float32
+    assert scores.shape == (1, E.nrows, 16)
+    assert (depth.shape, depth.dtype) == ((), jnp.int32)
+    assert (sweeps.shape, sweeps.dtype) == ((2,), jnp.int32)
