@@ -156,8 +156,10 @@ BC_SCOPES = (
     "bc.finish",
 )
 
-#: What ``_bc_batch_lanes``' ``int32[2]`` of whole ELL sweeps counts, in
-#: order: one forward sweep a BFS level, one backward sweep a level back.
+#: What ``_bc_batch_lanes``' ``int32[2]`` of ELL sweeps counts, in order:
+#: one forward sweep a BFS level, one backward sweep a level back; and the
+#: rows of its ``int32[2, 2]`` tally of degree-class sweeps (the columns
+#: are ``ellmat.SWEEP_MODES``).
 BC_PHASES = ("forward", "backward")
 
 
@@ -192,7 +194,7 @@ def _bc_batch_dense_impl(E, ET, sources, max_depth: int | None = None,
     """``_bc_batch_lanes`` without its counts: the [pr, lr, W] per-lane
     dependencies (``per_lane=True``) or their sum over the lanes, the
     row-aligned partial BC blocks of these W sources."""
-    delta, _, _ = _bc_batch_lanes(E, ET, sources, max_depth)
+    delta = _bc_batch_lanes(E, ET, sources, max_depth)[0]
     if per_lane:
         return delta
     with jax.named_scope("bc.finish"):
@@ -210,21 +212,33 @@ def _bc_batch_lanes(E, ET, sources, max_depth: int | None):
     one multi-lane ELL SpMV, and both sweeps run under ``lax`` control
     flow — zero device→host readbacks.
 
+    Both loops hand the sweep the row mask they apply to its result
+    anyway (``ellmat.ell_masked_multi_sweep``, the served BFS plan's
+    sweep): forward, the rows no lane with a frontier has reached yet;
+    backward at ``d``, the rows AT level ``d - 1``.  Each tile then
+    skips, on the device, every degree class none of whose rows the
+    level can change, and every class's gather table is built in the
+    branch that gathers from it.  A skipped class could only have added
+    to rows the mask drops, so the answers are bit for bit those of the
+    same program with every class swept.
+
     ``E``: adjacency with entry (i, j) = edge j→i (the BFS gather
     orientation); ``ET``: its transpose (pass the same EllParMat for
     symmetric graphs). ``sources``: [W] int32. Returns ``(delta,
-    depth, sweeps)``: the row-aligned PLAIN [pr, lr, W] per-lane
-    dependencies (lane k is Brandes' delta of ``sources[k]``, endpoints
-    excluded), the number of BFS levels that hold a vertex in the deepest
-    lane (the roots' own level counted), and the ``int32[2]`` count of
-    whole ELL sweeps the two loops ran, by ``BC_PHASES`` (forward: one a
-    level, the last of which finds nothing unless ``max_depth`` cut the
-    loop short; backward: one a level but the roots').  Not jitted
-    itself: the served plan (``engine._build_plan``) traces it inside its
-    own program and hands each lane back to its request, the depth as
-    ``batch_niter``.
+    depth, sweeps, class_sweeps)``: the row-aligned PLAIN [pr, lr, W]
+    per-lane dependencies (lane k is Brandes' delta of ``sources[k]``,
+    endpoints excluded), the number of BFS levels that hold a vertex in
+    the deepest lane (the roots' own level counted), the ``int32[2]``
+    count of ELL sweeps the two loops ran, by ``BC_PHASES`` (forward: one
+    a level, the last of which finds nothing unless ``max_depth`` cut the
+    loop short; backward: one a level but the roots' and their
+    neighbours'), and the ``int32[2, 2]`` tally of what those sweeps did
+    class by class, all tiles: ``BC_PHASES`` by ``ellmat.SWEEP_MODES``.
+    Not jitted itself: the served plan (``engine._build_plan``) traces it
+    inside its own program and hands each lane back to its request, the
+    depth as ``batch_niter``.
     """
-    from ..parallel.ellmat import dist_spmv_ell_multi
+    from ..parallel.ellmat import SWEEP_MODES, ell_masked_multi_sweep
     from ..parallel.vec import DistMultiVec
     from . import PAD_ROOT
 
@@ -234,6 +248,12 @@ def _bc_batch_lanes(E, ET, sources, max_depth: int | None):
 
     def mk(blocks):
         return DistMultiVec(blocks=blocks, length=n, align="row", grid=grid)
+
+    def sweep(M, x, row_active):
+        """``M ⊗ x`` where ``row_active`` keeps it, and the tile tally."""
+        y, tally = ell_masked_multi_sweep(
+            PLUS_TIMES, M, mk(x), mk(row_active))
+        return y.blocks, tally
 
     with jax.named_scope("bc.init"):
         gids = DistVec.iota(grid, n, jnp.int32, align="row").blocks  # [pr, lr]
@@ -246,51 +266,58 @@ def _bc_batch_lanes(E, ET, sources, max_depth: int | None):
         is_src = (gids[..., None] == sources[None, None, :]) & live
         lvl0 = jnp.where(is_src, 0, -1).astype(jnp.int32)
         nsp0 = is_src.astype(E.dtype)
+        # per tile, summed once after the loops: a collective accumulated
+        # inside a loop costs the loop its op_name (_bfs_batch_tallied)
+        tally0 = jnp.zeros((grid.pr, grid.pc, len(SWEEP_MODES)), jnp.int32)
 
     def fcond(st):
-        d, _, _, active = st
+        d, _, _, active, _ = st
         return active & (d < D)
 
     def fstep(st):
-        d, lvl, nsp, _ = st
+        d, lvl, nsp, _, tally = st
         frontier = jnp.where(lvl == d, nsp, 0)
-        arriving = dist_spmv_ell_multi(PLUS_TIMES, E, mk(frontier)).blocks
+        arriving, swept = sweep(E, frontier, lvl < 0)
         new = (arriving > 0) & (lvl < 0)
         lvl = jnp.where(new, d + 1, lvl)
         nsp = nsp + jnp.where(new, arriving, 0)
-        return d + 1, lvl, nsp, jnp.any(new)
+        return d + 1, lvl, nsp, jnp.any(new), tally + swept
 
     # the whole loop, condition included, is one scope: a forward sweep
     # is one iteration of it in the device trace
     with jax.named_scope("bc.forward"):
-        depth, lvl, nsp, still_active = jax.lax.while_loop(
-            fcond, fstep, (jnp.int32(0), lvl0, nsp0, jnp.bool_(True))
+        depth, lvl, nsp, still_active, ftally = jax.lax.while_loop(
+            fcond, fstep, (jnp.int32(0), lvl0, nsp0, jnp.bool_(True), tally0)
         )
 
-    # Backward dependency sweep: d = depth ... 1; every level-(d) vertex
+    # Backward dependency sweep: d = depth ... 2; every level-(d) vertex
     # w exports (1+delta[w])/nsp[w]; level-(d-1) predecessors v collect it
     # along their out-edges and scale by nsp[v]. Starting at d = depth
     # (one past the last level on natural exit — a no-op there) keeps the
     # deepest level's exports when the max_depth bound cut the forward
     # sweep short; the loop bound is the TRACED depth, so only the real
     # levels run (fori_loop lowers a traced bound to a while_loop).
-    def bstep(k, delta):
+    def bstep(k, st):
+        delta, tally = st
         d = depth - k
         wmask = (lvl == d) & (nsp > 0)
         w = jnp.where(
             wmask, (1.0 + delta) / jnp.maximum(nsp, 1e-30), 0
         ).astype(E.dtype)
-        collected = dist_spmv_ell_multi(PLUS_TIMES, ET, mk(w)).blocks
+        collected, swept = sweep(ET, w, lvl == d - 1)
         upd = jnp.where(lvl == d - 1, collected * nsp, 0)
-        return delta + upd
+        return delta + upd, tally + swept
 
     with jax.named_scope("bc.backward"):
         # on natural exit level `depth` is empty (the last step found
         # nothing) — skip its guaranteed no-op SpMV; when the max_depth
         # bound cut the sweep short (still_active), level `depth` is real
         start = jnp.where(still_active, 0, 1)
-        delta = jax.lax.fori_loop(
-            start, depth, bstep, jnp.zeros_like(nsp0)
+        # d = 1 is not run: the one level it updates is the roots' own
+        # entries, which bc.finish zeroes (a root with no edge has
+        # depth 1: the bounds cross and nothing runs)
+        delta, btally = jax.lax.fori_loop(
+            start, depth - 1, bstep, (jnp.zeros_like(nsp0), tally0)
         )
     with jax.named_scope("bc.finish"):
         # endpoints excluded: zero each lane's own source slot
@@ -299,5 +326,7 @@ def _bc_batch_lanes(E, ET, sources, max_depth: int | None):
         # too where the bound stopped a sweep that was still finding
         levels = depth + still_active.astype(jnp.int32)
         # iterations of the two loops above, as they ran
-        sweeps = jnp.stack([depth, depth - start]).astype(jnp.int32)
-    return delta, levels, sweeps
+        sweeps = jnp.stack(
+            [depth, jnp.maximum(depth - 1 - start, 0)]).astype(jnp.int32)
+        class_sweeps = jnp.sum(jnp.stack([ftally, btally]), axis=(1, 2))
+    return delta, levels, sweeps, class_sweeps

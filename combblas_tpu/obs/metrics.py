@@ -646,15 +646,25 @@ name                                   kind       meaning
 ``serve.sssp.batches``                 counter    served SSSP batches
                                                   executed (label
                                                   ``width``)
-``serve.bc.sweeps``                    counter    whole ELL sweeps of
-                                                  served BC batches
-                                                  (labels ``phase`` =
-                                                  forward: one a BFS
-                                                  level, the last
-                                                  finding nothing /
-                                                  backward: one a level
-                                                  but the roots';
+``serve.bc.sweeps``                    counter    ELL sweeps of served
+                                                  BC batches (labels
+                                                  ``phase`` = forward:
+                                                  one a BFS level, the
+                                                  last finding nothing
+                                                  / backward: one a
+                                                  level but the roots'
+                                                  and their
+                                                  neighbours';
                                                   ``width``)
+``serve.bc.class_sweeps``              counter    degree-class sweeps
+                                                  of those sweeps, all
+                                                  tiles (labels
+                                                  ``phase`` = forward /
+                                                  backward; ``mode`` =
+                                                  dense / skipped: no
+                                                  row of the class
+                                                  could change at that
+                                                  level)
 ``serve.bc.batches``                   counter    served BC batches
                                                   executed (label
                                                   ``width``)

@@ -865,7 +865,7 @@ class GraphEngine:
 
             def impl(E, ET, sources):
                 # (per-lane dependencies, levels of the deepest lane,
-                # sweeps by BC_PHASES)
+                # sweeps by BC_PHASES, class sweeps by phase and mode)
                 trace_mark()
                 return _bc_batch_lanes(E, ET, sources, self.max_iters)
 
@@ -1032,7 +1032,7 @@ class GraphEngine:
     #: PageRank iterations, and for "bc" the BFS levels of the batch's
     #: deepest lane), then what the kind's program counted of itself,
     #: read only with telemetry on ("bfs": sweeps by mode and the push's
-    #: outcome; "bc": sweeps by phase).
+    #: outcome; "bc": sweeps by phase, and class sweeps by phase and mode).
     _RESULT_KEYS = {
         "bfs": ("parents", "levels"),
         "sssp": ("dist", "parents"),
@@ -1156,10 +1156,15 @@ class GraphEngine:
                 obs.count("serve.sssp.batches", 1, width=W)
             if kind == "bc":
                 from ..models.bc import BC_PHASES
+                from ..parallel.ellmat import SWEEP_MODES
 
-                for phase, ran in zip(BC_PHASES, np.asarray(counted[0])):
+                sweeps, by_class = (np.asarray(c) for c in counted)
+                for phase, ran, classes in zip(BC_PHASES, sweeps, by_class):
                     obs.count("serve.bc.sweeps", int(ran),
                               phase=phase, width=W)
+                    for mode, taken in zip(SWEEP_MODES, classes):
+                        obs.count("serve.bc.class_sweeps", int(taken),
+                                  phase=phase, mode=mode)
                 obs.count("serve.bc.batches", 1, width=W)
         with mark("to_global"):
             out = {

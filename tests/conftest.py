@@ -153,12 +153,10 @@ def _bounded_jit_cache():
     jax.clear_caches()
 
 
-@pytest.fixture
-def all_dense_sweeps(monkeypatch):
-    """``set(on)``: with ``on``, the masked batch sweeps traced after it
-    skip no degree class: the all-dense sweep the kernels ran before they
-    used their mask to avoid work (``ellmat._active_rows`` gives None).
-    Decided at trace time, so the traced programs go with it."""
+def _ellmat_switch(monkeypatch, attr, replacement):
+    """A fixture's ``set(on)``: with ``on``, ``ellmat.<attr>`` is
+    ``replacement`` in the programs traced after it.  Decided at trace
+    time, so the traced programs go with every change."""
     import jax
 
     from combblas_tpu.parallel import ellmat
@@ -166,12 +164,42 @@ def all_dense_sweeps(monkeypatch):
     def set_(on: bool):
         monkeypatch.undo()
         if on:
-            monkeypatch.setattr(ellmat, "_active_rows", lambda *a: None)
+            monkeypatch.setattr(ellmat, attr, replacement)
         jax.clear_caches()
 
     yield set_
     monkeypatch.undo()
     jax.clear_caches()
+
+
+@pytest.fixture
+def all_dense_sweeps(monkeypatch):
+    """``set(on)``: with ``on``, the masked batch sweeps traced after it
+    skip no degree class: the all-dense sweep the kernels ran before they
+    used their mask to avoid work (``ellmat._active_rows`` gives None)."""
+    yield from _ellmat_switch(monkeypatch, "_active_rows", lambda *a: None)
+
+
+@pytest.fixture
+def no_class_idle(monkeypatch):
+    """``set(on)``: with ``on``, the masked batch sweeps traced after it
+    keep every class's choice and find no class idle, whatever the mask
+    (``ellmat._class_idle`` gives a False the compiler cannot fold): the
+    SAME program as the masked one, branch for branch, with every branch
+    taken.  What a rounding semiring needs for a comparison bit for bit:
+    XLA:CPU orders an f32 fold inside a branch otherwise than the same
+    fold outside one (``all_dense_sweeps`` has no branch), in the last
+    bit, in some degree classes (PERF.md section 6, PR 31).  One
+    ``monkeypatch`` serves both fixtures: a ``set`` of either undoes the
+    other's."""
+    import jax
+    import jax.numpy as jnp
+
+    def never(i, br, active):
+        with jax.named_scope(f"ell.bucket{i}"):
+            return jnp.sum(active[br].astype(jnp.int32)) < 0
+
+    yield from _ellmat_switch(monkeypatch, "_class_idle", never)
 
 
 @pytest.fixture

@@ -3,8 +3,9 @@ answers one root's Brandes dependency vector and the batch's depth,
 held to the plain reference (``chipbench/bcref.py``: float64 Brandes,
 the sum rule) on a seeded R-MAT graph with a path, a star, a two-vertex
 component and a lone vertex added on vertices the generator left
-isolated; the plan's depth against the reference's level count; and the
-``serve.bc.*`` counters."""
+isolated; the plan's depth against the reference's level count; the
+``serve.bc.*`` counters; and the masked sweeps of both loops against the
+all-dense program, bit for bit."""
 
 import os
 import sys
@@ -165,7 +166,9 @@ def test_the_checks_catch_a_planted_error(served, shapes, planted):
 def test_the_plan_returns_its_depth_and_counts_its_sweeps(engine, shapes):
     """``engine.execute("bc", ...)``: ``batch_niter`` is the reference's
     level count of the deepest live lane, and with telemetry on one
-    batch adds that many forward sweeps, one fewer backward, one batch."""
+    batch adds that many forward sweeps, two fewer backward (the deepest
+    level exports to nothing, the roots' level is not updated), one
+    batch."""
     _, _, _, named, ref = shapes
     deep = [named["path"][0], _roots(shapes)["rmat"][0], PAD_ROOT,
             named["lone"]]
@@ -188,7 +191,7 @@ def test_the_plan_returns_its_depth_and_counts_its_sweeps(engine, shapes):
             res = engine.execute("bc", np.asarray(srcs, np.int32))
             assert res["batch_niter"] == levels
             assert [counted("forward"), counted("backward"), counted()] == [
-                before[0] + levels, before[1] + levels - 1, before[2] + 1]
+                before[0] + levels, before[1] + levels - 2, before[2] + 1]
     finally:
         obs.disable()
         obs.reset()
@@ -196,8 +199,10 @@ def test_the_plan_returns_its_depth_and_counts_its_sweeps(engine, shapes):
 
 def test_a_depth_bound_is_counted_as_what_ran(shapes):
     """``max_iters`` below the graph's depth: the forward loop stops at
-    the bound with level ``max_iters`` found, so that many forward AND
-    backward sweeps ran, and the depth says which levels hold a vertex."""
+    the bound with level ``max_iters`` found, so that many forward sweeps
+    ran and one fewer backward (level ``max_iters`` exports, the roots'
+    level is not updated), and the depth says which levels hold a
+    vertex."""
     n, r, c, named, _ = shapes
     bounded = GraphEngine.from_coo(
         Grid.make(1, 1), r, c, n, kinds=("bc",), max_iters=2)
@@ -212,6 +217,147 @@ def test_a_depth_bound_is_counted_as_what_ran(shapes):
     finally:
         obs.disable()
         obs.reset()
-    assert res["batch_niter"] == 3 and got == [2, 2]
+    assert res["batch_niter"] == 3 and got == [2, 1]
     # the bounded answer is Brandes of the first three levels of the path
     assert [res["scores"][v, 0] for v in named["path"]] == [0, 1, 0, 0, 0, 0]
+
+
+# --- PR 31: both loops sweep through the class-choosing masked sweep --------
+
+
+def _masked_case(shapes, case):
+    """``(sources, grid shape, max_depth)``: 16 lanes of the module's
+    graph."""
+    _, _, _, named, ref = shapes
+    live = [int(x) for x in graph.draw_roots(ref.bfs.deg, 11, 16)]
+    pad = [PAD_ROOT]
+    return {
+        "16 live roots": (live, (1, 1), None),
+        # the pair's lane ends after two levels, the star's after three
+        "unequal depths": (
+            [named["a"], named["leaves"][0], named["centre"]] + live[:13],
+            (1, 1), None),
+        # the path's end runs six levels: the R-MAT lanes have ended, and
+        # every row they reached is one its lane has not
+        "a long lane alone": (
+            [named["path"][0], named["leaves"][0], named["a"], live[0]]
+            + [named["path"][3], named["centre"]] * 6, (1, 1), None),
+        "pad lanes": (live[:5] + pad * 11, (1, 1), None),
+        "depth bound": (live[:8] + [named["path"][0]] + pad * 7, (1, 1), 2),
+        "no edge": ([named["lone"]] * 3 + pad * 13, (1, 1), None),
+        "2x2 grid": (
+            live[:6] + [named["path"][1], named["lone"]] + pad * 8,
+            (2, 2), None),
+        "2x2 grid, depth bound": (live[:12] + pad * 4, (2, 2), 3),
+    }[case]
+
+
+@pytest.mark.parametrize("case", [
+    "16 live roots", "unequal depths", "a long lane alone", "pad lanes",
+    "depth bound", "no edge", "2x2 grid", "2x2 grid, depth bound"])
+def test_masked_sweeps_answer_as_all_dense(
+        shapes, all_dense_sweeps, no_class_idle, case):
+    """Both loops of ``_bc_batch_lanes`` skip the degree classes none of
+    whose rows a level can change, and the dependencies and the depth
+    are BIT FOR BIT those of the same program with every class swept
+    (``no_class_idle``); ``sweeps`` counts what ran (forward one a level,
+    backward none for the deepest level on a natural exit and none for
+    the roots'), and every sweep's classes are all in the tally, on every
+    tile.  Against the program with no choice in it (``all_dense_sweeps``)
+    depth, sweeps and which entries are zero are equal, and the float32
+    sums to the last bits: XLA:CPU folds a class inside a branch in
+    another order than outside one."""
+    import jax
+    import jax.numpy as jnp
+
+    from combblas_tpu.models.bc import _bc_batch_lanes
+    from combblas_tpu.parallel.ellmat import EllParMat
+
+    n, r, c, _, ref = shapes
+    sources, shape, max_depth = _masked_case(shapes, case)
+    E = EllParMat.from_host_coo(
+        Grid.make(*shape), r, c, np.ones(len(r), np.float32), n, n)
+    srcs = jnp.asarray(sources, jnp.int32)
+
+    def run():
+        return [np.asarray(a) for a in jax.jit(
+            lambda E, s: _bc_batch_lanes(E, E, s, max_depth))(E, srcs)]
+
+    all_dense_sweeps(True)
+    unbranched = run()
+    all_dense_sweeps(False)
+    no_class_idle(True)
+    swept = run()
+    no_class_idle(False)
+    delta, depth, sweeps, by_class = run()
+
+    assert delta.dtype == np.float32
+    np.testing.assert_array_equal(
+        delta.view(np.uint32), swept[0].view(np.uint32))
+    np.testing.assert_array_equal(delta == 0, unbranched[0] == 0)
+    np.testing.assert_allclose(delta, unbranched[0], rtol=2e-6, atol=0)
+    for other in (swept, unbranched):
+        assert depth == other[1] and sweeps.tolist() == other[2].tolist()
+    assert not unbranched[3].any()  # no class chose, no tally
+    assert not swept[3][:, 1].any()  # every class chose, none skipped
+    np.testing.assert_array_equal(swept[3].sum(axis=1), by_class.sum(axis=1))
+
+    levels = ref.level_count([s for s in sources if s != PAD_ROOT])
+    cut = max_depth is not None and max_depth < levels - 1
+    assert depth == (max_depth + 1 if cut else levels)
+    forward = max_depth if cut else levels
+    assert sweeps.tolist() == [
+        forward, max(forward - (1 if cut else 2), 0)]
+    tiles = shape[0] * shape[1]
+    assert by_class.sum(axis=1).tolist() == [
+        len(E.buckets) * tiles * int(ran) for ran in sweeps]
+    # which loops thinned: (forward, backward)
+    thinned = (bool(by_class[0, 1]), bool(by_class[1, 1]))
+    assert thinned == {
+        # a lone root's lane is live for its one sweep: nothing is reached
+        "no edge": (False, False),
+        # levels 0-2 going out and level 2 going back: every class busy
+        "depth bound": (False, False),
+        # a live lane in a small component leaves every other row
+        # unreached: going out nothing thins, going back all but its rows
+        "a long lane alone": (False, True),
+    }.get(case, (True, True)), by_class
+    if case == "a long lane alone":
+        assert by_class[1, 1] >= by_class[1, 0]
+
+
+def test_class_sweeps_are_counted_with_telemetry_on(engine, shapes):
+    """A served batch adds classes x sweeps to
+    ``serve.bc.class_sweeps{phase, mode}``; with telemetry off nothing is
+    read back and nothing counted."""
+    from combblas_tpu.models.bc import BC_PHASES
+    from combblas_tpu.parallel.ellmat import SWEEP_MODES
+
+    _, _, _, named, _ = shapes
+    srcs = np.asarray([named["path"][0], _roots(shapes)["rmat"][0],
+                       PAD_ROOT, named["lone"]], np.int32)
+
+    def counted():
+        return {
+            (p, m): obs.registry.get_counter(
+                "serve.bc.class_sweeps", phase=p, mode=m)
+            for p in BC_PHASES for m in SWEEP_MODES}
+
+    obs.reset()
+    engine.execute("bc", srcs)  # telemetry off
+    assert not any(counted().values())
+    obs.enable(install_hooks=False)
+    try:
+        engine.execute("bc", srcs)
+        got = counted()
+    finally:
+        obs.disable()
+        obs.reset()
+    classes = len(engine.E.buckets)
+    for phase, ran in (("forward", PATH), ("backward", PATH - 2)):
+        assert got[phase, "dense"] + got[phase, "skipped"] == classes * ran
+    # the path's lane runs on alone: going out it keeps every class busy
+    # (no row of the R-MAT component is reached in it), going back it
+    # alone is swept
+    assert got["forward", "skipped"] == 0
+    assert got["backward", "skipped"] >= got["backward", "dense"] > 0

@@ -231,39 +231,46 @@ def _computations(text):
     return comps
 
 
-def _loop_gather_tables(text, width=16):
+def _loop_gather_tables(text, loop="bfs.level", dtype="s32", width=16):
     """``[(class, defining line of the table its sweep gathers from)]``
-    for every degree class's payload gather inside ``bfs.level``: the
-    gather sits in a fusion, the table is that fusion's operand."""
+    for every degree class's payload gather (``dtype[slots, width]``)
+    inside the loop scoped ``loop``: the gather sits in a fusion, the
+    table is that fusion's operand.  Where two loops call one jitted
+    sweep, an instruction inside a fusion is named from the sweep down
+    and the fusion that holds it carries the loop's name."""
     comps = _computations(text)
     defs = {
         c: {re.sub(r"^(ROOT )?%", "", ln.split(" = ")[0]): ln
             for ln in lines if " = " in ln}
         for c, lines in comps.items()
     }
+
+    # {computation: (computation, line) of the fusion that calls it}
+    callers = {}
+    for c2, lines2 in comps.items():
+        for l2 in lines2:
+            called = re.search(r" fusion\(.*calls=%?([\w.\-]+)", l2)
+            if called:
+                callers.setdefault(called.group(1), (c2, l2))
+
     def produced(c, name):
         """The line that computes ``name`` of computation ``c``: through
         the fusions that only hand it down as a parameter."""
         param = re.match(r"param_(\d+)", name)
-        if not param:
+        c2, l2 = callers.get(c, (None, "")) if param else (None, "")
+        if c2 is None:
             return defs[c].get(name, "")
-        for c2, lines2 in comps.items():
-            for l2 in lines2:
-                if re.search(r"calls=%%?%s(?![\w.])" % re.escape(c), l2):
-                    ops = re.search(
-                        r" fusion\(([^)]*)\)", l2).group(1).split(", ")
-                    return produced(
-                        c2, ops[int(param.group(1))].lstrip("%"))
-        return defs[c].get(name, "")
+        ops = re.search(r" fusion\(([^)]*)\)", l2).group(1).split(", ")
+        return produced(c2, ops[int(param.group(1))].lstrip("%"))
 
     out = []
     for c, lines in comps.items():
         for ln in lines:
             m = re.search(
-                r"= s32\[[\d,]+,%d\]\S* gather\(%%?([\w.\-]+), .*"
-                r"op_name=\"[^\"]*bfs\.level[^\"]*"
-                r"ell\.bucket(\d+)/gather/gather\"" % width, ln)
-            if m:
+                r"= %s\[[\d,]+,%d\]\S* gather\(%%?([\w.\-]+), .*"
+                r"op_name=\"[^\"]*ell\.bucket(\d+)/gather/gather\""
+                % (dtype, width), ln)
+            if m and loop in ln + callers.get(c, (None, ""))[1]:
                 out.append((int(m.group(2)), produced(c, m.group(1))))
     return sorted(out)
 
@@ -340,15 +347,18 @@ def test_unmasked_plans_are_the_parents_programs(
 
 
 @pytest.mark.parametrize("kind,loop", [
-    ("bfs", "bfs.level"), ("sssp", "sssp.round")])
+    ("bfs", "bfs.level"), ("sssp", "sssp.round"),
+    ("bc", "bc.forward"), ("bc", "bc.backward")])
 def test_mesh_loop_keeps_its_name_for_the_v5e(topo, kind, loop):
     """On a 2x2 mesh the ``while`` of the served BFS plan still carries
-    ``bfs.level`` in its ``op_name``, and the served SSSP plan's
-    ``sssp.round``: the device trace finds the levels and the rounds by
-    it (``chipbench/scopes.py``, ``chipbench/k3scopes.py``).  A collective
-    accumulated inside the loop (the sweep tally summed over tiles every
-    level) made the compiler move it out and rebuild the loop without
-    metadata, so the tally is carried per tile and summed once after."""
+    ``bfs.level`` in its ``op_name``, the served SSSP plan's
+    ``sssp.round``, and the BC plan's two ``bc.forward`` and
+    ``bc.backward``: the device trace finds the levels, the rounds and the
+    sweeps by it (``chipbench/scopes.py``, ``k3scopes.py``,
+    ``bcscopes.py``).  A collective accumulated inside the loop (the sweep
+    tally summed over tiles every level) made the compiler move it out
+    and rebuild the loop without metadata, so the tally is carried per
+    tile and summed once after."""
     import jax
     from jax.sharding import NamedSharding
 
@@ -384,10 +394,15 @@ def test_mesh_loop_keeps_its_name_for_the_v5e(topo, kind, loop):
 
         return _sssp_batch_impl(E, sources)
 
+    def serve_bc_w16(E, sources):
+        from combblas_tpu.models.bc import _bc_batch_lanes
+
+        return _bc_batch_lanes(E, E, sources, None)
+
     if kind == "bfs":  # the plan's operands: the matrix and its companion
         fn, E = serve_bfs_w16, (E, _companion(grid, rows, cols, n))
     else:
-        fn = serve_sssp_w16
+        fn = {"sssp": serve_sssp_w16, "bc": serve_bc_w16}[kind]
     names = opnames.parse(_optimised(fn, E, 16, grid))[1]
     loops = [nm for instr, nm in names.items() if instr.startswith("while")]
     assert any(nm.endswith(loop + "/while") for nm in loops), loops
@@ -428,15 +443,19 @@ def test_sssp_round_names_the_loop_of_the_one_chip_program(operands):
     assert rounds.shape == ()
 
 
-def test_bc_scopes_name_both_loops_of_the_one_chip_program(operands):
+def test_bc_scopes_name_both_loops_of_the_one_chip_program(
+        operands, all_dense_sweeps):
     """The served BC program for the described v5e: two ``while``s, one
     under ``bc.forward`` and one under ``bc.backward``
     (``chipbench/bcscopes.py`` reads the sweeps of each by them), every
     one of ``BC_SCOPES`` on some instruction, the class and leaf scopes
-    under both loops, no ``conditional`` (no sweep is thinned), the
-    gather tables in the fast memory, and the answer three arrays: the
-    per-lane dependencies, the batch's depth and the sweeps each loop
-    ran."""
+    under both loops; every degree class of BOTH loops a ``conditional``
+    (PR 31: a sweep skips the classes none of whose rows its level can
+    change) whose gather reads a table built in its own branch and placed
+    in the fast memory (``S(1)``: a table shared by the classes is what
+    the compiler evicted under one of them, PERF.md section 6); and the
+    answer four arrays: the per-lane dependencies, the batch's depth, the
+    sweeps each loop ran and what they did class by class."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
@@ -451,7 +470,11 @@ def test_bc_scopes_name_both_loops_of_the_one_chip_program(operands):
 
     sources = jax.ShapeDtypeStruct(
         (16,), jnp.int32, sharding=NamedSharding(grid.mesh, P()))
-    text = jax.jit(serve_bc_w16).lower(E, E, sources).compile().as_text()
+
+    def compiled():
+        return jax.jit(serve_bc_w16).lower(E, E, sources).compile().as_text()
+
+    text = compiled()
     names = opnames.parse(text)[1]
     loops = [nm for i, nm in names.items() if i.startswith("while")]
     for loop in ("bc.forward", "bc.backward"):
@@ -463,11 +486,22 @@ def test_bc_scopes_name_both_loops_of_the_one_chip_program(operands):
         for leaf in ("gather", "fold"):
             assert any(f"/{phase}/" in nm and f"ell.bucket0/{leaf}" in nm
                        for nm in seen), (phase, leaf)
-    assert " conditional(" not in text
-    table = rf"f32\[{E.nrows + 1},16\]\{{[^}}]*\}}"
-    assert any("S(1)" in t for t in re.findall(table, text))
-    scores, depth, sweeps = jax.eval_shape(serve_bc_w16, E, E, sources)
+    conditionals = re.findall(r"= [^=\n]* conditional\(", _strip(text))
+    assert len(conditionals) == 2 * len(E.buckets)
+    for loop in ("bc.forward", "bc.backward"):
+        tables = _loop_gather_tables(text, loop, "f32")
+        assert sorted({cls for cls, _ in tables}) == list(
+            range(len(E.buckets))), loop
+        for cls, line in tables:
+            layout = line.split(" = ", 1)[1].split(" ", 1)[0]
+            assert layout.startswith(f"f32[{E.nrows + 1},16]"), line[:200]
+            assert "S(1)" in layout and loop in line, (loop, cls, line[:200])
+    scores, depth, sweeps, by_class = jax.eval_shape(
+        serve_bc_w16, E, E, sources)
     assert scores.dtype == jnp.float32
     assert scores.shape == (1, E.nrows, 16)
     assert (depth.shape, depth.dtype) == ((), jnp.int32)
     assert (sweeps.shape, sweeps.dtype) == ((2,), jnp.int32)
+    assert (by_class.shape, by_class.dtype) == ((2, 2), jnp.int32)
+    all_dense_sweeps(True)
+    assert " conditional(" not in compiled()
