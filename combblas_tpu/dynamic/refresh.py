@@ -35,7 +35,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from .. import obs
-from ..semiring import MIN_PLUS, PLUS_TIMES, SELECT2ND_MIN
+from ..semiring import MIN_PLUS, PLUS_TIMES
 
 #: Kinds ``GraphEngine.refresh`` understands.
 REFRESH_KINDS = ("bfs", "cc", "pagerank")
@@ -100,67 +100,23 @@ def _bfs_refresh(engine, root: int, prev: np.ndarray | None):
 # -- connected-components repair ---------------------------------------------
 
 
-@jax.jit
-def _cc_ell_impl(E, f0_blocks):
-    """FastSV over an ``EllParMat`` with an explicit initial parent
-    vector (``models/cc.py:_connected_components_impl`` generalized:
-    iota is just the cold start).  Any initial vector whose entries
-    name SAME-COMPONENT vertices converges to the per-component minimum
-    — previous labels qualify after insert-only deltas."""
-    from ..parallel.ellmat import dist_spmv_ell
-    from ..parallel.vec import DistVec
-
-    grid, n = E.grid, E.nrows
-
-    def mk(blocks):
-        return DistVec(blocks=blocks, length=n, align="row", grid=grid)
-
-    def cond(state):
-        _, changed, it = state
-        return changed & (it < n)
-
-    def step(state):
-        fb, _, it = state
-        f = mk(fb)
-        gf = f.gather(f)
-        u = dist_spmv_ell(SELECT2ND_MIN, E, gf.realign("col"))
-        f1 = f.scatter_combine(SELECT2ND_MIN, idx=f, src=u)
-        nb = jnp.minimum(jnp.minimum(f1.blocks, u.blocks), gf.blocks)
-        return nb, jnp.any(nb != fb), it + 1
-
-    fb, _, niter = jax.lax.while_loop(
-        cond, step, (f0_blocks, jnp.bool_(True), jnp.int32(0))
-    )
-
-    def jcond(state):
-        _, changed = state
-        return changed
-
-    def jstep(state):
-        fb, _ = state
-        gf = mk(fb).gather(mk(fb))
-        return gf.blocks, jnp.any(gf.blocks != fb)
-
-    fb, _ = jax.lax.while_loop(jcond, jstep, (fb, jnp.bool_(True)))
-    return fb, niter
-
-
 def _cc_refresh(engine, prev: np.ndarray | None):
+    """FastSV over the loaded ``EllParMat`` (``models/cc.py:fastsv``),
+    cold from ``iota`` or warm from the previous labels: any start whose
+    entries name SAME-COMPONENT vertices converges to the per-component
+    minimum, and previous labels qualify after insert-only deltas."""
+    from ..models.cc import fastsv
     from ..parallel.vec import DistVec
 
-    n = engine.nrows
-    f0 = (
-        np.arange(n, dtype=np.int32) if prev is None
-        else np.asarray(prev, np.int32)
-    )
-    x0 = DistVec.from_global(engine.grid, f0, align="row")
-    # padding slots must carry self-ids out of range, like iota does
-    x0 = x0.mask_padding(np.int32(2**31 - 1))
-    blocks, niter = _cc_ell_impl(engine.E, x0.blocks)
-    labels = DistVec(
-        blocks=blocks, length=n, align="row", grid=engine.grid
-    ).to_global().astype(np.int32)
-    return labels, int(niter)
+    f0 = None
+    if prev is not None:
+        f0 = DistVec.from_global(
+            engine.grid, np.asarray(prev, np.int32), align="row"
+        )
+        # padding slots must carry self-ids out of range, like iota does
+        f0 = f0.mask_padding(np.int32(2**31 - 1))
+    labels, rounds, _ = fastsv(engine.E, f0)
+    return labels.to_global().astype(np.int32), int(rounds)
 
 
 # -- PageRank restart --------------------------------------------------------

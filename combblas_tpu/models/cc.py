@@ -16,7 +16,9 @@ with ``u[i] = min over neighbors j of gf[j]`` and ``gf = f[f]``.
 TPU-native expression: ``u`` is one semiring SpMV (SELECT2ND_MIN) over the
 mesh; hooking is ``DistVec.scatter_combine`` (segment-min); the whole loop is
 a ``lax.while_loop`` with a fixed-point convergence test — no host round
-trips, the entire CC run is one XLA program.
+trips, the entire CC run is one XLA program.  The matrix is an ``SpParMat``
+(COO tiles) or an ``EllParMat`` (what a loaded ``GraphEngine`` holds as
+``engine.E``): the argument's type picks the sweep and nothing else differs.
 
 ``lacc`` below is a real implementation of LACC (``Applications/CC.h``,
 Azad-Buluç IPDPS'19) — the star-hooking algorithm the reference's ctest
@@ -31,39 +33,83 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import obs
 from ..semiring import SELECT2ND_MIN
+from ..parallel.ellmat import EllParMat
 from ..parallel.spmat import SpParMat
 from ..parallel.spmv import dist_spmv
 from ..parallel.vec import DistVec
 
+#: The ``jax.named_scope`` names of the FastSV program (``_fastsv``),
+#: outermost first; inside ``cc.spmv`` an ``EllParMat``'s sweep sets its
+#: own ``ell.bucket<i>`` / ``gather`` / ``fold`` / ``scatter_rows``.
+#: Trace-time metadata only: the device trace's per-scope and per-round
+#: times are read by these names (docs/observability.md "Named scopes"),
+#: so a rename is a change of yardstick.
+CC_SCOPES = (
+    "cc.init",
+    "cc.iter",  # the whole while loop; one iteration = one FastSV round
+    "cc.gather",  # gf = f[f]
+    "cc.spmv",  # u = A (select2nd, min) gf: the one sweep of a round
+    "cc.hook",  # stochastic hooking: the scatter-min of u into f[f]
+    "cc.min",  # aggressive hooking, shortcutting, the fixed-point test
+    "cc.jump",  # the pointer-jumping loop after the rounds
+)
 
-def connected_components(A: SpParMat) -> tuple[DistVec, jax.Array]:
-    """Eager wrapper over ``_connected_components_impl`` (plain-outputs
-    law, round-5 notes: dataclass-wrapped jit outputs ran the batched
-    BFS child 3x slower in the r5 A/B)."""
-    blocks, niter = _connected_components_impl(A)
-    return (
-        DistVec(blocks=blocks, length=A.nrows, align="row", grid=A.grid),
-        niter,
-    )
+
+def fastsv(M, f0: DistVec | None = None):
+    """FastSV on ``M``, an ``SpParMat`` or an ``EllParMat`` (the type
+    picks the sweep, ``dist_spmv``'s seam): ``(labels, rounds, jumps)``,
+    ``labels[v]`` the smallest vertex id of ``v``'s component, ``rounds``
+    the iterations of the hooking loop and ``jumps`` those of the
+    pointer-jumping loop after it.  ``f0`` (row-aligned int32, padding
+    out of range) starts the loop from labels that name same-component
+    vertices (``dynamic/refresh.py``'s warm start) instead of ``iota``.
+
+    Eager wrapper: the jitted programs return plain block arrays (the
+    plain-outputs law) and this rebuilds the DistVec outside."""
+    program = cc_fastsv_ell if isinstance(M, EllParMat) else cc_fastsv
+    f0_blocks = None if f0 is None else f0.blocks
+    if obs.ENABLED:
+        # no warm-up of its own: the first traced call of a shape
+        # publishes the program's op names (obs/opnames.py)
+        obs.opnames.publish_once(
+            (program.__name__, M.grid, M.nrows, f0 is None,
+             tuple(a.shape for a in jax.tree_util.tree_leaves(M))),
+            lambda: program.lower(M, f0_blocks).compile().as_text(),
+        )
+    blocks, rounds, jumps = program(M, f0_blocks)
+    if obs.ENABLED:
+        obs.count("models.cc.jobs")
+        obs.count("models.cc.rounds", int(rounds))
+        obs.count("models.cc.jumps", int(jumps))
+    labels = DistVec(blocks=blocks, length=M.nrows, align="row", grid=M.grid)
+    return labels, rounds, jumps
 
 
-@jax.jit
-def _connected_components_impl(A: SpParMat):
-    """Component labels (min vertex id in each component) + iteration count.
+def connected_components(M) -> tuple[DistVec, jax.Array]:
+    """``fastsv(M)``'s labels and round count."""
+    return fastsv(M)[:2]
 
-    A is interpreted structurally (any nonzero = edge) and must be
+
+def _fastsv(M, f0_blocks):
+    """Component labels (min vertex id in each component), the hooking
+    loop's iteration count and the pointer-jumping loop's.
+
+    M is interpreted structurally (any nonzero = edge) and must be
     symmetric; returns PLAIN row-aligned int32 label BLOCKS (the eager
     wrapper above rebuilds the DistVec); padding slots carry their own
     (out-of-range) ids and never interact with real vertices.
     """
-    grid = A.grid
-    n = A.nrows
-
-    f0 = DistVec.iota(grid, n, jnp.int32, align="row")
+    grid = M.grid
+    n = M.nrows
 
     def mk(blocks):
         return DistVec(blocks=blocks, length=n, align="row", grid=grid)
+
+    with jax.named_scope("cc.init"):
+        if f0_blocks is None:
+            f0_blocks = DistVec.iota(grid, n, jnp.int32, align="row").blocks
 
     def cond(state):
         _, changed, it = state
@@ -72,33 +118,55 @@ def _connected_components_impl(A: SpParMat):
     def step(state):
         fb, _, it = state
         f = mk(fb)
-        gf = f.gather(f)  # grandparent labels f[f[i]]
-        # u[i] = min over neighbors j of gf[j]  (one semiring SpMV)
-        u = dist_spmv(SELECT2ND_MIN, A, gf.realign("col"))
-        # stochastic hooking: lower the parent's label
-        f1 = f.scatter_combine(SELECT2ND_MIN, idx=f, src=u)
-        # aggressive hooking + shortcutting (elementwise minimums)
-        nb = jnp.minimum(jnp.minimum(f1.blocks, u.blocks), gf.blocks)
-        changed = jnp.any(nb != fb)
+        with jax.named_scope("cc.gather"):
+            gf = f.gather(f)  # grandparent labels f[f[i]]
+        with jax.named_scope("cc.spmv"):
+            # u[i] = min over neighbors j of gf[j]  (one semiring SpMV)
+            u = dist_spmv(SELECT2ND_MIN, M, gf.realign("col"))
+        with jax.named_scope("cc.hook"):
+            # stochastic hooking: lower the parent's label
+            f1 = f.scatter_combine(SELECT2ND_MIN, idx=f, src=u)
+        with jax.named_scope("cc.min"):
+            # aggressive hooking + shortcutting (elementwise minimums)
+            nb = jnp.minimum(jnp.minimum(f1.blocks, u.blocks), gf.blocks)
+            changed = jnp.any(nb != fb)
         return nb, changed, it + 1
 
-    fb, _, niter = jax.lax.while_loop(
-        cond, step, (f0.blocks, jnp.bool_(True), jnp.int32(0))
-    )
+    with jax.named_scope("cc.iter"):
+        fb, _, rounds = jax.lax.while_loop(
+            cond, step, (f0_blocks, jnp.bool_(True), jnp.int32(0))
+        )
 
     # Final pointer-jumping: compress remaining parent chains to roots.
     def jcond(state):
-        fb, changed = state
+        _, changed, _ = state
         return changed
 
     def jstep(state):
-        fb, _ = state
+        fb, _, it = state
         f = mk(fb)
         gf = f.gather(f)
-        return gf.blocks, jnp.any(gf.blocks != fb)
+        return gf.blocks, jnp.any(gf.blocks != fb), it + 1
 
-    fb, _ = jax.lax.while_loop(jcond, jstep, (fb, jnp.bool_(True)))
-    return fb, niter
+    with jax.named_scope("cc.jump"):
+        fb, _, jumps = jax.lax.while_loop(
+            jcond, jstep, (fb, jnp.bool_(True), jnp.int32(0))
+        )
+    return fb, rounds, jumps
+
+
+@jax.jit
+def cc_fastsv(A: SpParMat, f0_blocks=None):
+    """``_fastsv`` over COO tiles (``parallel/spmv.py:dist_spmv``)."""
+    return _fastsv(A, f0_blocks)
+
+
+@jax.jit
+def cc_fastsv_ell(E: EllParMat, f0_blocks=None):
+    """``_fastsv`` over the ELL sweep (``ellmat.dist_spmv_ell``): the
+    same loop under a program name of its own, so a trace's ``XLA
+    Modules`` line and the compile cache tell the two apart."""
+    return _fastsv(E, f0_blocks)
 
 
 _STAR, _NONSTAR, _CONVERGED = np.int32(1), np.int32(0), np.int32(2)

@@ -668,6 +668,23 @@ name                                   kind       meaning
 ``serve.bc.batches``                   counter    served BC batches
                                                   executed (label
                                                   ``width``)
+``models.cc.jobs``                     counter    FastSV jobs run
+                                                  through the eager
+                                                  wrapper
+                                                  (``models/cc.py:
+                                                  fastsv``)
+``models.cc.rounds``                   counter    hooking rounds of
+                                                  those jobs (the
+                                                  program's own count,
+                                                  the round that
+                                                  changed nothing
+                                                  included)
+``models.cc.jumps``                    counter    iterations of their
+                                                  pointer-jumping loop
+                                                  (the program's own
+                                                  count, the one that
+                                                  changed nothing
+                                                  included)
 ``obs.provider_errors``                counter    broken pull-provider
                                                   callbacks (caught)
 =====================================  =========  =====================

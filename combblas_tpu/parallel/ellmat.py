@@ -452,14 +452,17 @@ def _ell_local_spmv(sr: Semiring, buckets, x: Array, lr: int, lc: int) -> Array:
     xpad = jnp.concatenate([x, zero[None]])
     y = None
     out_dtype = None
-    for bc, bv, br in buckets:
-        g = xpad[jnp.minimum(bc, lc)]  # [nb, kb]
-        prods = sr.mul(bv, g)
-        yb = _bucket_fold(sr, prods)
+    for i, (bc, bv, br) in enumerate(buckets):
+        with _bucket_scope(i, "gather"):
+            g = xpad[jnp.minimum(bc, lc)]  # [nb, kb]
+        with _bucket_scope(i, "fold"):
+            prods = sr.mul(bv, g)
+            yb = _bucket_fold(sr, prods)
         if y is None:
             out_dtype = yb.dtype
             y = jnp.full((lr,), sr.zero(out_dtype), out_dtype)
-        y = _scatter_rows(sr, y, br, yb.astype(out_dtype))
+        with _bucket_scope(i, "scatter_rows"):
+            y = _scatter_rows(sr, y, br, yb.astype(out_dtype))
     if y is None:
         y = jnp.full((lr,), zero, x.dtype)
     return y
