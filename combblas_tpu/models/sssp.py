@@ -68,9 +68,11 @@ def _sssp_impl(A: SpParMat, source):
 #: The ``jax.named_scope`` names of the served batch program
 #: (``_sssp_batch_impl``), outermost first; inside ``sssp.round`` and
 #: ``sssp.parents`` the sweep's own ``ell.bucket<i>`` / ``gather`` /
-#: ``fold`` / ``scatter_rows`` and ``vec.realign``.  Trace-time metadata
-#: only: the device trace's per-scope and per-round times are read by
-#: these names (docs/observability.md "Named scopes").
+#: ``fold`` / ``scatter_rows`` and ``vec.realign``, and inside
+#: ``sssp.round`` (a masked sweep since PR 33) each class's test for an
+#: active row too, under its bare ``ell.bucket<i>``, and ``ell.reduce``.
+#: Trace-time metadata only: the device trace's per-scope and per-round
+#: times are read by these names (docs/observability.md "Named scopes").
 SSSP_SCOPES = (
     "sssp.init",
     "sssp.round",  # the whole while loop; one iteration = one round
@@ -84,7 +86,7 @@ def sssp_batch(E, sources):
     ``DistMultiVec``s."""
     from ..parallel.vec import DistMultiVec
 
-    dist, parents, niter = _sssp_batch_impl(E, sources)
+    dist, parents, niter, _ = _sssp_batch_impl(E, sources)
 
     def mk(blocks):
         return DistMultiVec(
@@ -116,10 +118,23 @@ def _sssp_batch_impl(E, sources):
     strictly nearer of them, and for a row that has none, among those as
     near that settled in an earlier round, which the loop records (zero
     and absorbed weights cannot close a cycle).
+
+    A round hands the sweep the rows it can still lower
+    (``ellmat.ell_masked_multi_sweep``, the served BFS and BC plans'
+    sweep): with non-negative weights a distance falls in round k only
+    through a neighbour that fell in round k - 1 (round k - 1 relaxed
+    it over every other), so it ends above that neighbour's, and
+    entries at or under the lane's ``floor``, the smallest distance
+    round k - 1 lowered, are left alone.  Hubs hold a lane's smallest
+    distances, so the widest degree classes go idle first, and each
+    tile skips, on the device, a class with no such row.  ``min``
+    neither rounds nor orders: the answers are bit for bit those of
+    the same program with every class swept.
     Reference: ``Applications/SSSP`` role; the reference has no batched
     variant — this is TPU-native surface.
     """
-    from ..parallel.ellmat import _ell_minplus_parents, dist_spmv_ell_multi
+    from ..parallel.ellmat import (
+        SWEEP_MODES, _ell_minplus_parents, ell_masked_multi_sweep)
     from ..parallel.vec import DistMultiVec
     from . import PAD_ROOT
 
@@ -139,28 +154,39 @@ def _sssp_batch_impl(E, sources):
         # as _bfs_batch_impl
         is_root = (gids[..., None] == src) & (src != PAD_ROOT)
         d0 = jnp.where(is_root, jnp.zeros((), dtype), inf)
+        # per tile, summed once after the loop: a collective accumulated
+        # inside a loop costs the loop its op_name (_bfs_batch_tallied)
+        tally0 = jnp.zeros((grid.pr, grid.pc, len(SWEEP_MODES)), jnp.int32)
 
     def cond(state):
-        _, _, changed, it = state
+        _, _, changed, it, _ = state
         return changed & (it < n)
 
     def step(state):
-        db, settled, _, it = state
-        relaxed = dist_spmv_ell_multi(MIN_PLUS, E, mk(db))
+        db, settled, _, it, tally = state
+        # the smallest distance the round before lowered, by lane (+inf
+        # where it lowered none: a finished or PAD_ROOT lane; at round 0
+        # every ``settled`` is 0 and only the roots are finite: their
+        # 0).  Only an entry ABOVE it can fall now, and only because no
+        # weight is negative (the contract above): a negative edge
+        # would lower entries this mask leaves out.
+        floor = jnp.min(jnp.where(settled == it, db, inf), axis=(0, 1))
+        relaxed, swept = ell_masked_multi_sweep(
+            MIN_PLUS, E, mk(db), mk(db > floor))
         nb = jnp.minimum(db, relaxed.blocks)
         lowered = nb != db
         # the round that last lowered each distance: what the parents
         # pass orders equal distances by
         settled = jnp.where(lowered, it + 1, settled)
-        return nb, settled, jnp.any(lowered), it + 1
+        return nb, settled, jnp.any(lowered), it + 1, tally + swept
 
     # the whole loop, condition included, is one scope: a round is one
     # iteration of it in the device trace
     with jax.named_scope("sssp.round"):
-        db, settled, _, niter = jax.lax.while_loop(
+        db, settled, _, niter, tally = jax.lax.while_loop(
             cond, step,
             (d0, jnp.zeros(d0.shape, jnp.int32), jnp.bool_(True),
-             jnp.int32(0)),
+             jnp.int32(0), tally0),
         )
 
     with jax.named_scope("sssp.parents"):
@@ -168,4 +194,4 @@ def _sssp_batch_impl(E, sources):
         # roots are their own parents; unreached rows stay -1
         parents = jnp.where(is_root, src, parents)
         parents = jnp.where(db < inf, parents, -1)
-    return db, parents, niter
+    return db, parents, niter, jnp.sum(tally, axis=(0, 1))

@@ -646,6 +646,17 @@ name                                   kind       meaning
 ``serve.sssp.batches``                 counter    served SSSP batches
                                                   executed (label
                                                   ``width``)
+``serve.sssp.class_sweeps``            counter    degree-class sweeps
+                                                  of those batches'
+                                                  rounds, all tiles
+                                                  (label ``mode`` =
+                                                  dense / skipped: no
+                                                  row of the class
+                                                  lay above the
+                                                  smallest distance
+                                                  the round before
+                                                  lowered; the parents
+                                                  pass is not counted)
 ``serve.bc.sweeps``                    counter    ELL sweeps of served
                                                   BC batches (labels
                                                   ``phase`` = forward:

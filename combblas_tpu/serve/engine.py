@@ -843,6 +843,8 @@ class GraphEngine:
         elif kind == "sssp":
 
             def impl(E, sources):
+                # (dist, parents, rounds, the rounds' class sweeps by
+                # mode)
                 trace_mark()
                 return _sssp_batch_impl(E, sources)
 
@@ -1032,7 +1034,8 @@ class GraphEngine:
     #: PageRank iterations, and for "bc" the BFS levels of the batch's
     #: deepest lane), then what the kind's program counted of itself,
     #: read only with telemetry on ("bfs": sweeps by mode and the push's
-    #: outcome; "bc": sweeps by phase, and class sweeps by phase and mode).
+    #: outcome; "sssp": the rounds' class sweeps by mode; "bc": sweeps by
+    #: phase, and class sweeps by phase and mode).
     _RESULT_KEYS = {
         "bfs": ("parents", "levels"),
         "sssp": ("dist", "parents"),
@@ -1152,8 +1155,13 @@ class GraphEngine:
                     "serve.bfs.push", outcome=PUSH_OUTCOMES[int(push)]
                 )
             if kind == "sssp":
+                from ..parallel.ellmat import SWEEP_MODES
+
                 obs.count("serve.sssp.rounds", int(niter), width=W)
                 obs.count("serve.sssp.batches", 1, width=W)
+                for mode, taken in zip(SWEEP_MODES, np.asarray(counted[0])):
+                    obs.count("serve.sssp.class_sweeps", int(taken),
+                              mode=mode)
             if kind == "bc":
                 from ..models.bc import BC_PHASES
                 from ..parallel.ellmat import SWEEP_MODES

@@ -144,6 +144,17 @@ TIES = [(0, 1, 1.), (1, 2, .5), (1, 3, .5), (2, 3, 0.), (3, 4, 0.),
         (9, 6, 1.)]
 
 
+def ties_coo():
+    """``(n, rows, cols, weights)`` of ``TIES``, both directions, sorted
+    by (row, col)."""
+    n = 10
+    r = np.array([e[0] for e in TIES] + [e[1] for e in TIES], np.int32)
+    c = np.array([e[1] for e in TIES] + [e[0] for e in TIES], np.int32)
+    w = np.array([e[2] for e in TIES] * 2, np.float32)
+    order = np.argsort(r * n + c)
+    return n, r[order], c[order], w[order]
+
+
 @pytest.mark.parametrize("dense", [False, True], ids=["thin", "all-dense"])
 @pytest.mark.parametrize("grid", GRIDS, ids=lambda g: f"{g[0]}x{g[1]}")
 def test_parents_are_a_tree_where_distances_tie(grid, dense, all_dense_sweeps):
@@ -154,12 +165,7 @@ def test_parents_are_a_tree_where_distances_tie(grid, dense, all_dense_sweeps):
     settles such rows gives the same picks whether it skips the degree
     classes that hold none of them or sweeps them all."""
     all_dense_sweeps(dense)
-    n = 10
-    r = np.array([e[0] for e in TIES] + [e[1] for e in TIES], np.int32)
-    c = np.array([e[1] for e in TIES] + [e[0] for e in TIES], np.int32)
-    w = np.array([e[2] for e in TIES] * 2, np.float32)
-    order = np.argsort(r * n + c)
-    r, c, w = r[order], c[order], w[order]
+    n, r, c, w = ties_coo()
     eng = GraphEngine.from_coo(
         Grid.make(*grid), r, c, n, weights=w, kinds=("sssp",))
     roots = np.arange(n, dtype=np.int32)

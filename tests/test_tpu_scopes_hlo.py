@@ -275,6 +275,19 @@ def _loop_gather_tables(text, loop="bfs.level", dtype="s32", width=16):
     return sorted(out)
 
 
+def _assert_own_fast_tables(text, loop, E):
+    """Every degree class's f32 gather inside ``loop`` reads an
+    ``[n + 1, 16]`` table built under that loop, in its own branch, and
+    placed in the fast memory (``S(1)`` on its layout)."""
+    tables = _loop_gather_tables(text, loop, "f32")
+    assert sorted({cls for cls, _ in tables}) == list(
+        range(len(E.buckets))), loop
+    for cls, line in tables:
+        layout = line.split(" = ", 1)[1].split(" ", 1)[0]
+        assert layout.startswith(f"f32[{E.nrows + 1},16]"), line[:200]
+        assert "S(1)" in layout and loop in line, (loop, cls, line[:200])
+
+
 def test_push_is_peeled_and_the_loop_keeps_its_fast_tables(
         operands, companion):
     """Level 0 runs BEFORE the loop under ``bfs.push``; the loop is still
@@ -308,11 +321,13 @@ def test_push_is_peeled_and_the_loop_keeps_its_fast_tables(
 #: were taken with.  ``pagerank`` is PR 23's (commit 2ad2df9): no PR since
 #: has moved the unmasked sweep.  ``sssp`` was re-pinned by PR 26, which
 #: gave the program its parents pass and the loop its record of the round
-#: that settled each distance (``0525dc40...`` before).  To repeat:
+#: that settled each distance (``0525dc40...`` before, ``8adb1928...``
+#: after), and by PR 33, which sent the round through the masked sweep
+#: under the floor of what the round before lowered.  To repeat:
 #: run ``_plan_hlo`` in a checkout of the commit.
 PARENT_HLO = {
     "jax": "0.9.0",
-    "sssp": "8adb19281ab356408cc85a0d53accf618c035fbb52313b1217cf656b2ecc5c13",
+    "sssp": "08f4bab2ae05717077baff167c9a64db68edaceba21fab30ce6ba6ac34bf57d8",
     "pagerank":
         "7b0a71bdfe7b85447c85b6739d71ebc537dddfed3a7b659fca2fdab9d566705c",
 }
@@ -321,13 +336,16 @@ PARENT_HLO = {
 @pytest.mark.parametrize("kind", ["sssp", "pagerank"])
 def test_unmasked_plans_are_the_parents_programs(
         operands, all_dense_sweeps, kind):
-    """PageRank and SSSP's rounds pass no row mask: PageRank's plan does
-    not depend on the choice at all (same text with it and without) and
-    holds no branch; SSSP's holds one ``conditional`` per degree class
-    and no more, the parents pass's second sweep (the rows that only a
-    neighbour as near closes a path for), none in a round.  Both are the
-    pinned programs: a change to the sweep they share with BFS
-    (``_ell_local_spmm(row_active=None)``) shows here."""
+    """PageRank passes no row mask: its plan does not depend on the
+    choice at all (same text with it and without) and holds no branch.
+    SSSP's holds two ``conditional``s per degree class and no more: the
+    round's sweep (PR 33: the rows above the smallest distance the round
+    before lowered) and the parents pass's second sweep (the rows that
+    only a neighbour as near closes a path for); the parents pass's
+    first sweep stays unmasked.  Both are the pinned programs: a change
+    to the sweep PageRank shares with BFS
+    (``_ell_local_spmm(row_active=None)``) shows in its hash, which no
+    PR since PR 23 has moved."""
     import hashlib
 
     import jax
@@ -335,7 +353,7 @@ def test_unmasked_plans_are_the_parents_programs(
     E, grid = operands
     text = _plan_hlo(kind, E, grid)
     branches = re.findall(r"= [^=\n]* conditional\(", text)
-    assert len(branches) == (len(E.buckets) if kind == "sssp" else 0)
+    assert len(branches) == (2 * len(E.buckets) if kind == "sssp" else 0)
     all_dense_sweeps(True)
     dense = _plan_hlo(kind, E, grid)
     assert " conditional(" not in dense
@@ -412,7 +430,13 @@ def test_sssp_round_names_the_loop_of_the_one_chip_program(operands):
     """The served kernel-3 program for the described v5e: its ``while``
     is ``sssp.round``, the sweeps inside it and the one after it carry
     the class and leaf scopes under ``sssp.round`` and ``sssp.parents``,
-    and the answer is three arrays (distances, parents, rounds)."""
+    the round's sweep its class tests and ``ell.reduce`` too, and the
+    answer is four arrays (distances, parents, rounds, and what the
+    rounds' sweeps did class by class); every degree class's gather in
+    a round reads a table built in its own branch and placed in the fast
+    memory (``S(1)``: the one table the classes shared before PR 33 is
+    what the compiler evicted under the 4.67 M-slot class at scale 20,
+    PERF.md section 6)."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
@@ -435,12 +459,16 @@ def test_sssp_round_names_the_loop_of_the_one_chip_program(operands):
     for phase in ("sssp.round", "sssp.parents"):
         assert any(phase in nm and "ell.bucket0/gather" in nm
                    for nm in seen), phase
+    assert any("/sssp.round/" in nm and "/ell.reduce/" in nm for nm in seen)
+    _assert_own_fast_tables(text, "sssp.round", E)
     sources = jax.ShapeDtypeStruct(
         (16,), jnp.int32, sharding=NamedSharding(grid.mesh, P()))
-    dist, parents, rounds = jax.eval_shape(serve_sssp_w16, E, sources)
+    dist, parents, rounds, by_class = jax.eval_shape(
+        serve_sssp_w16, E, sources)
     assert (dist.dtype, parents.dtype) == (jnp.float32, jnp.int32)
     assert dist.shape == parents.shape == (1, E.nrows, 16)
     assert rounds.shape == ()
+    assert (by_class.shape, by_class.dtype) == ((2,), jnp.int32)
 
 
 def test_bc_scopes_name_both_loops_of_the_one_chip_program(
@@ -489,13 +517,7 @@ def test_bc_scopes_name_both_loops_of_the_one_chip_program(
     conditionals = re.findall(r"= [^=\n]* conditional\(", _strip(text))
     assert len(conditionals) == 2 * len(E.buckets)
     for loop in ("bc.forward", "bc.backward"):
-        tables = _loop_gather_tables(text, loop, "f32")
-        assert sorted({cls for cls, _ in tables}) == list(
-            range(len(E.buckets))), loop
-        for cls, line in tables:
-            layout = line.split(" = ", 1)[1].split(" ", 1)[0]
-            assert layout.startswith(f"f32[{E.nrows + 1},16]"), line[:200]
-            assert "S(1)" in layout and loop in line, (loop, cls, line[:200])
+        _assert_own_fast_tables(text, loop, E)
     scores, depth, sweeps, by_class = jax.eval_shape(
         serve_bc_w16, E, E, sources)
     assert scores.dtype == jnp.float32
