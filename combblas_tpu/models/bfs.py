@@ -1133,9 +1133,12 @@ def bfs_batch_compact(A, sources, max_iters: int | None = None,
         max_iters=max_iters, ring=ring, csc=csc,
         frontier_capacity=frontier_capacity, edge_capacity=edge_capacity,
     )
+    p, l, niter = _bfs_batch_compact_program(A, sources, **opts)
     if obs.ENABLED:
         # this entry has no warm-up of its own: the first traced call of
-        # a shape publishes the program's op names (obs/opnames.py)
+        # a shape publishes the program's op names (obs/opnames.py),
+        # AFTER the call, so the call pays for the program as an
+        # untraced one does and the publishing only for itself
         obs.opnames.publish_once(
             ("bfs_batch_compact", A.grid, A.nrows, A.ncols,
              len(A.buckets), sources.shape, max_iters, ring,
@@ -1144,7 +1147,6 @@ def bfs_batch_compact(A, sources, max_iters: int | None = None,
                 A, sources, **opts
             ).compile().as_text(),
         )
-    p, l, niter = _bfs_batch_compact_program(A, sources, **opts)
     mk = lambda b: DistMultiVec(
         blocks=b, length=A.nrows, align="row", grid=A.grid
     )

@@ -70,16 +70,17 @@ def fastsv(M, f0: DistVec | None = None):
     plain-outputs law) and this rebuilds the DistVec outside."""
     program = cc_fastsv_ell if isinstance(M, EllParMat) else cc_fastsv
     f0_blocks = None if f0 is None else f0.blocks
+    blocks, rounds, jumps = program(M, f0_blocks)
     if obs.ENABLED:
         # no warm-up of its own: the first traced call of a shape
-        # publishes the program's op names (obs/opnames.py)
+        # publishes the program's op names (obs/opnames.py), AFTER the
+        # call, so the call pays for the program as an untraced one does
+        # and the publishing only for itself
         obs.opnames.publish_once(
             (program.__name__, M.grid, M.nrows, f0 is None,
              tuple(a.shape for a in jax.tree_util.tree_leaves(M))),
             lambda: program.lower(M, f0_blocks).compile().as_text(),
         )
-    blocks, rounds, jumps = program(M, f0_blocks)
-    if obs.ENABLED:
         obs.count("models.cc.jobs")
         obs.count("models.cc.rounds", int(rounds))
         obs.count("models.cc.jumps", int(jumps))

@@ -51,6 +51,7 @@ See docs/observability.md for the event schema and worked examples.
 
 from __future__ import annotations
 
+import functools
 import os
 
 from .metrics import MetricsRegistry
@@ -169,6 +170,18 @@ def span(name: str, *, sync=None, force: bool = False, **attrs):
     return _spans.open(name, True, sync=sync, log=ENABLED, **attrs)
 
 
+def spanned(name: str):
+    """Decorator: the whole call under ``span(name)`` (a constructor, a
+    boot step).  One flag read a call with telemetry off."""
+    def deco(fn):
+        @functools.wraps(fn)
+        def wrapper(*args, **kw):
+            with span(name):
+                return fn(*args, **kw)
+        return wrapper
+    return deco
+
+
 def span_event(name: str, **fields) -> None:
     """Attach a per-iteration record (hop/round/stage) to the innermost
     open span — or log it top-level if no span is open."""
@@ -200,6 +213,22 @@ def update_trace(rid, tenant: str | None = None):
 def trace_records() -> list[dict]:
     """Completed per-request trace records (schema kind ``trace``)."""
     return _trace.records()
+
+
+def spans() -> list[dict]:
+    """Closed spans in closing order (a copy of the structured log):
+    ``name``, ``path`` (parents joined by ``/``), ``ts`` (``time.time``)
+    and ``t0`` (``perf_counter``) at the start, ``wall_s``, and where
+    present ``attrs``, ``parts``, ``events``, ``failed``.  (The name is
+    also the submodule's: ``from combblas_tpu.obs.spans import ...``
+    reaches that, the attribute ``obs.spans`` is this reader.)"""
+    return _spans.records()
+
+
+def events() -> list[dict]:
+    """Events recorded with no span open (a copy): ``name``, ``ts``,
+    ``t`` (``perf_counter``) and the event's own fields."""
+    return _spans.top_events()
 
 
 def prune_labels(**labels) -> int:
@@ -289,13 +318,26 @@ def dump_jsonl(path: str | None = None, *, process: int | None = None,
 _CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 _CACHE_MISS_EVENT = "/jax/compilation_cache/cache_misses"
 
+#: JAX's own seconds for what a first call of a program costs, by the
+#: name of the span event each becomes.  ``compile`` is JAX's whole
+#: backend-compile interval, so on a persistent-cache hit it CONTAINS
+#: the ``fetch`` fired inside it, as an outer function's ``trace`` does
+#: its inner jits': a reader sums them as intervals ``[t - s, t]``.
+JAX_DURATION_EVENTS = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "compile",
+    "/jax/compilation_cache/cache_retrieval_time_sec": "fetch",
+}
+
 
 def install_jax_hooks() -> bool:
-    """Bridge ``jax.monitoring`` into the registry (idempotent):
+    """Bridge ``jax.monitoring`` into telemetry (idempotent):
     persistent-compile-cache hits/misses become the ``compile_cache.*``
-    counters, every other ``/jax/...`` event is counted under its own
-    path, and duration events (tracing/backend-compile times) land in
-    histograms — the jit retrace/compile visibility layer."""
+    counters, and JAX's trace / lower / fetch / compile durations
+    (``JAX_DURATION_EVENTS``) become span events ``{name, s, t}`` on the
+    innermost open span of the thread they ran on, top-level where none
+    is open: what a boot paid for its programs, where it paid it."""
     global _hooks_installed
     if _hooks_installed:
         return True
@@ -311,13 +353,13 @@ def install_jax_hooks() -> bool:
             registry.count("compile_cache.hits")
         elif event == _CACHE_MISS_EVENT:
             registry.count("compile_cache.misses")
-        else:
-            registry.count(event)
 
     def _on_duration(event: str, duration_secs: float, **kw):
         if not ENABLED:
             return
-        registry.observe(event, duration_secs)
+        name = JAX_DURATION_EVENTS.get(event)
+        if name is not None:
+            _spans.event(name, s=float(duration_secs))
 
     monitoring.register_event_listener(_on_event)
     monitoring.register_event_duration_secs_listener(_on_duration)
@@ -337,8 +379,9 @@ __all__ = [
     "FLIGHTREC_SCHEMA", "FLEETLOG_SCHEMA",
     "enable", "disable", "enabled", "reset",
     "reset_spans",
-    "count", "gauge", "observe", "span", "span_event",
-    "request_trace", "update_trace", "trace_records", "prune_labels",
+    "count", "gauge", "observe", "span", "spanned", "span_event",
+    "request_trace", "update_trace", "trace_records", "spans", "events",
+    "prune_labels",
     "register_provider", "report", "print_report", "span_seconds",
     "metrics_snapshot", "dump_jsonl", "install_jax_hooks",
     "parse_jsonl", "merge_jsonl_files", "aggregate", "validate_record",

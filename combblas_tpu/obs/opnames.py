@@ -9,7 +9,10 @@ wants its scopes read back from a trace (the served plans at warm-up,
 telemetry is on; a trace reader joins events to scopes by instruction
 name, per module name (``jit_serve_bfs_w16``).  Publishing compiles
 nothing new on a warm persistent cache and is never done with telemetry
-off.  ``obs.reset()`` clears the tables.
+off.  What it does cost (lowering again, the cache fetch, the text of
+the whole program, the parse) runs under the span
+``obs.opnames.publish``, so a traced boot can say what it paid for being
+traced.  ``obs.reset()`` clears the tables.
 """
 
 from __future__ import annotations
@@ -38,15 +41,22 @@ def parse(hlo_text: str) -> tuple[str | None, dict[str, str]]:
     )
 
 
-def publish(hlo_text: str) -> str | None:
+def publish(hlo_text) -> str | None:
     """Keep the table of one compiled program under its module name
-    (a later program of the same name replaces it).  Returns the name."""
-    name, table = parse(hlo_text)
-    if name is None:
-        return None
-    with _lock:
-        _tables[name] = table
-    return name
+    (a later program of the same name replaces it).  Returns the name.
+    ``hlo_text``: the compiled text, or a zero-argument callable that
+    makes it (so that making it is inside the span too)."""
+    from .. import obs
+
+    with obs.span("obs.opnames.publish"):
+        if callable(hlo_text):
+            hlo_text = hlo_text()
+        name, table = parse(hlo_text)
+        if name is None:
+            return None
+        with _lock:
+            _tables[name] = table
+        return name
 
 
 def publish_once(key, make_text) -> None:
@@ -56,7 +66,7 @@ def publish_once(key, make_text) -> None:
         if key in _published:
             return
         _published.add(key)
-    publish(make_text())
+    publish(make_text)
 
 
 def tables() -> dict[str, dict[str, str]]:

@@ -11,9 +11,13 @@ kinds and their required fields:
     meta       schema (str, == SCHEMA), ts (float), process (int),
                nprocs (int)
     span       name (str), path (str), ts (float), wall_s (number >= 0);
-               optional attrs (obj), events (list of {"name", "t_s", ...}),
+               t0 (float: ``perf_counter`` at the start, the process's
+               monotonic clock); optional attrs (obj), parts (list of
+               {"stage", "s"} telescoping to wall_s), events (list of
+               {"name", "t_s", "t", ...}: ``t`` on ``perf_counter``),
                failed (bool)
-    event      name (str), ts (float)  — span-less, process-level
+    event      name (str), ts (float), t (float: ``perf_counter``)
+               — span-less, process-level
     counter    name (str), value (number), labels (obj)
     gauge      name (str), value (number), labels (obj)
     histogram  name (str), count (int), sum/min/max (number), labels (obj);

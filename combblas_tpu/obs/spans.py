@@ -9,6 +9,14 @@ JSONL exporter. Span EVENTS carry the per-iteration records — BFS hop +
 frontier nnz, MCL round + chaos, SUMMA stage — that the scalar timer
 table cannot express.
 
+Every closed span carries two clocks: ``ts`` (``time.time``, what the
+device-trace offset is taken on) and ``t0`` (``time.perf_counter``, the
+monotonic clock the per-request records and a benchmark's ``setup_s``
+are on), so spans of one process order and nest by ``t0`` /
+``t0 + wall_s``.  ``mark`` splits a span into PARTS that telescope like
+a request's stages (``obs/trace.py``): each is the time since the
+previous mark, so parts marked to the end sum to the wall.
+
 Disabled-path cost: ``SpanTracker.open`` returns a shared null context
 manager after one flag check — no allocation, no dict work — so
 instrumented hot paths are free when telemetry is off.
@@ -31,6 +39,15 @@ class _NullSpan:
     def __exit__(self, *exc):
         return False
 
+    def mark(self, part: str) -> None:
+        pass
+
+    def annotate(self, **attrs) -> None:
+        pass
+
+    def sync_on(self, value) -> None:
+        pass
+
 
 NULL_SPAN = _NullSpan()
 
@@ -40,8 +57,8 @@ MAX_LOG = 100_000
 
 
 class _ActiveSpan:
-    __slots__ = ("tracker", "name", "attrs", "sync", "events", "t0", "ts",
-                 "path", "log", "_ann")
+    __slots__ = ("tracker", "name", "attrs", "sync", "events", "parts",
+                 "t0", "ts", "path", "log", "_ann", "_last")
 
     def __init__(self, tracker, name, attrs, sync, log=True):
         self.tracker = tracker
@@ -50,6 +67,7 @@ class _ActiveSpan:
         self.sync = sync
         self.log = log
         self.events = []
+        self.parts = []
 
     def __enter__(self):
         stack = self.tracker._stack()
@@ -61,7 +79,7 @@ class _ActiveSpan:
         self._ann = jax.profiler.TraceAnnotation(self.name)
         self._ann.__enter__()
         self.ts = time.time()
-        self.t0 = time.perf_counter()
+        self.t0 = self._last = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb):
@@ -80,11 +98,30 @@ class _ActiveSpan:
         return False
 
     def event(self, name: str, **fields):
+        now = time.perf_counter()
         self.events.append({
             "name": name,
-            "t_s": round(time.perf_counter() - self.t0, 6),
+            "t_s": round(now - self.t0, 6),
+            "t": now,
             **fields,
         })
+
+    def mark(self, part: str) -> None:
+        """Close one PART of the span: the seconds since the span opened
+        or the previous mark."""
+        now = time.perf_counter()
+        self.parts.append({"stage": part, "s": round(now - self._last, 6)})
+        self._last = now
+
+    def annotate(self, **attrs) -> None:
+        """Attributes learned inside the span (bytes read, placed)."""
+        self.attrs.update(attrs)
+
+    def sync_on(self, value) -> None:
+        """``sync=`` for a value the span itself made: blocked on before
+        the timer closes.  Only a live span blocks, so a site that calls
+        this keeps its asynchronous dispatch with telemetry off."""
+        self.sync = value
 
 
 class SpanTracker:
@@ -125,7 +162,8 @@ class SpanTracker:
                 self.dropped += 1
                 return
             self.events.append({
-                "name": name, "ts": time.time(), **fields,
+                "name": name, "ts": time.time(),
+                "t": time.perf_counter(), **fields,
             })
 
     def _close(self, span: _ActiveSpan, wall: float, failed: bool):
@@ -148,15 +186,28 @@ class SpanTracker:
                 "name": span.name,
                 "path": span.path,
                 "ts": span.ts,
+                "t0": span.t0,
                 "wall_s": round(wall, 6),
             }
             if span.attrs:
                 rec["attrs"] = span.attrs
+            if span.parts:
+                rec["parts"] = span.parts
             if span.events:
                 rec["events"] = span.events
             if failed:
                 rec["failed"] = True
             self.log.append(rec)
+
+    def records(self) -> list[dict]:
+        """The closed spans, in closing order (a copy of the log)."""
+        with self._lock:
+            return list(self.log)
+
+    def top_events(self) -> list[dict]:
+        """The events recorded with no span open (a copy)."""
+        with self._lock:
+            return list(self.events)
 
     # -- the per-app timing table (utils/timers.py compat) -----------------
     def seconds(self, name: str) -> float:

@@ -90,6 +90,7 @@ class _Started:
 class Server:
     """In-process query server over one ``GraphEngine``."""
 
+    @obs.spanned("serve.server.init")
     def __init__(self, engine, config: ServeConfig | None = None,
                  tenant: str | None = None):
         self.engine = engine

@@ -252,16 +252,21 @@ def _build_version(grid, rows, cols, nrows: int, ncols: int,
                 "symmetric; pass symmetric=False (builds the "
                 "transpose for bc) or symmetrize the graph"
             )
+    def ell(r, c, v, nr, nc):
+        # ``from_host_coo`` in its two halves, a span each
+        with obs.span("bucket"):
+            host = EllParMat.host_build(grid, r, c, v, nr, nc,
+                                        headroom=headroom)
+        with obs.span("upload") as upload:
+            M = EllParMat.from_host_buckets(grid, host, nr, nc)
+            upload.sync_on(M)
+        return M
+
     with obs.span("serve.load", nrows=n, nnz=int(len(rows))):
         ones = np.ones(len(rows), np.float32)
-        E = EllParMat.from_host_coo(grid, rows, cols, ones, n, ncols,
-                                    headroom=headroom)
+        E = ell(rows, cols, ones, n, ncols)
         E_weighted = (
-            EllParMat.from_host_coo(
-                grid, rows, cols,
-                np.asarray(weights, np.float32), n, ncols,
-                headroom=headroom,
-            )
+            ell(rows, cols, np.asarray(weights, np.float32), n, ncols)
             if weights is not None else None
         )
         # degree artifacts: rowdeg = in-edges per row; outdeg feeds
@@ -276,24 +281,23 @@ def _build_version(grid, rows, cols, nrows: int, ncols: int,
             pvals = (
                 1.0 / np.maximum(outdeg[cols], 1)
             ).astype(np.float32)
-            P_ell = EllParMat.from_host_coo(
-                grid, rows, cols, pvals, n, ncols, headroom=headroom
-            )
+            P_ell = ell(rows, cols, pvals, n, ncols)
             dangling = DistVec.from_global(
                 grid, (outdeg == 0).astype(np.float32), align="col"
             )
         ET = None
         if ("bc" in kinds or "propagate" in kinds) and not symmetric:
-            ET = EllParMat.from_host_coo(grid, cols, rows, ones,
-                                         ncols, n, headroom=headroom)
+            ET = ell(cols, rows, ones, ncols, n)
         csc = None
         if companion:
             from ..parallel.ellmat import build_csc_companion
 
-            csc = build_csc_companion(
-                grid, rows, cols, n, ncols, headroom=headroom,
-                cap=companion_cap,
-            )
+            with obs.span("companion") as comp:
+                csc = build_csc_companion(
+                    grid, rows, cols, n, ncols, headroom=headroom,
+                    cap=companion_cap,
+                )
+                comp.sync_on(csc)
         X = None
         feat_dim = 0
         # like every other artifact here, the feature table is built
@@ -340,6 +344,7 @@ class GraphEngine:
     backpressured server (``combblas_tpu.serve.api.Server``).
     """
 
+    @obs.spanned("serve.engine.init")
     def __init__(self, grid, E=None, *, nrows: int | None = None,
                  deg: np.ndarray | None = None,
                  E_weighted=None, P_ell=None, dangling=None, ET=None,
@@ -914,7 +919,9 @@ class GraphEngine:
         program is already traced and compiled: lowering it again hits
         JAX's trace cache (no retrace is counted) and compiling it
         fetches it from the persistent cache where one is kept."""
-        obs.opnames.publish(plan.lower(sources).compile().as_text())
+        obs.opnames.publish(
+            lambda: plan.lower(sources).compile().as_text()
+        )
 
     def _resolve_spmm_backend(self) -> str:
         """The op="spmm" tuner resolution, ONCE per engine (the plan
@@ -993,19 +1000,27 @@ class GraphEngine:
         if "bfs" in kinds and self._version.host_coo is not None:
             # a companion that is not current is rebuilt BEFORE the
             # plans are traced with a stand-in's shapes
-            self.csc_companion()
+            with obs.span("serve.warmup.companion"):
+                self.csc_companion()
         out = {}
         for kind in kinds:
             for w in sorted(set(widths)):
                 t0 = time.perf_counter()
+                # parts: ``build`` (the plan object), ``execute`` (trace,
+                # lower, fetch or compile, and the first run: JAX's own
+                # seconds for the first four land here as span events),
+                # ``probe`` (telemetry's own cost, traced boots only)
                 with self._exec_lock, obs.span(
                     "serve.warmup", kind=kind, width=int(w)
-                ):
+                ) as sp:
                     plan = self.plan(kind, w)
+                    sp.mark("build")
                     pads = np.full(int(w), PAD_ROOT, np.int32)
                     jax.block_until_ready(plan.fn(pads))
+                    sp.mark("execute")
                     if obs.ENABLED:
                         self._publish_op_names(plan, pads)
+                        sp.mark("probe")
                 out[(kind, int(w))] = time.perf_counter() - t0
         return out
 

@@ -423,85 +423,88 @@ def _load_version(path: str, grid: Grid, writable: bool = True):
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from .. import obs
-    from ..parallel.ellmat import EllParMat
+    from ..parallel.ellmat import EllParMat, upload_csc_companion
     from ..parallel.grid import COL_AXIS, ROW_AXIS
     from ..parallel.vec import DistMultiVec
     from ..serve.engine import GraphVersion
 
     t0 = time.perf_counter()
-    with np.load(path) as z:
-        meta = json.loads(bytes(z["__meta__"]).decode())
-        if meta.get("v") != VERSION_SCHEMA:
-            raise SnapshotError(
-                f"{path!r} is not a GraphVersion snapshot (schema "
-                f"{meta.get('v')!r} != {VERSION_SCHEMA!r})"
-            )
-        pr, pc = meta["grid"]
-        if (pr, pc) != (grid.pr, grid.pc):
-            raise SnapshotError(
-                f"snapshot was taken on a {pr}x{pc} grid; load_version "
-                f"restores onto the SAME grid shape (got {grid.pr}x"
-                f"{grid.pc}) — rebuild from COO to re-shard"
-            )
-        mats = {}
+    # one span a boundary the restore crosses, on the clock a boot is
+    # timed on: ``read`` (the file to host arrays), ``upload`` (host to
+    # device), ``companion`` (the BFS plan's CSC operand)
+    with obs.span("serve.restore", path=path) as restore:
+        with obs.span("read"):
+            with np.load(path) as z:
+                meta = json.loads(bytes(z["__meta__"]).decode())
+                if meta.get("v") != VERSION_SCHEMA:
+                    raise SnapshotError(
+                        f"{path!r} is not a GraphVersion snapshot (schema "
+                        f"{meta.get('v')!r} != {VERSION_SCHEMA!r})"
+                    )
+                pr, pc = meta["grid"]
+                if (pr, pc) != (grid.pr, grid.pc):
+                    raise SnapshotError(
+                        f"snapshot was taken on a {pr}x{pc} grid; "
+                        f"load_version restores onto the SAME grid shape "
+                        f"(got {grid.pr}x{grid.pc}) — rebuild from COO to "
+                        "re-shard"
+                    )
+                # every member, decompressed once
+                host = {k: z[k] for k in z.files if k != "__meta__"}
         host_mats = {}  # host (bc, bv, br) triples: the merge-state
         #                 derivation below needs them pre-upload
         for nm in _VERSION_MATS:
             info = meta["mats"].get(nm)
-            if info is None:
-                mats[nm] = None
-                continue
-            host_buckets = [
-                (
-                    z[f"{nm}.{i}.c"], z[f"{nm}.{i}.v"], z[f"{nm}.{i}.r"],
+            if info is not None:
+                host_mats[nm] = [
+                    (host[f"{nm}.{i}.c"], host[f"{nm}.{i}.v"],
+                     host[f"{nm}.{i}.r"])
+                    for i in range(info["nbuckets"])
+                ]
+        with obs.span("upload") as upload:
+            mats = {
+                nm: EllParMat.from_host_buckets(
+                    grid, host_mats[nm], meta["mats"][nm]["nrows"],
+                    meta["mats"][nm]["ncols"],
+                ) if nm in host_mats else None
+                for nm in _VERSION_MATS
+            }
+            dangling = None
+            if "dangling" in host:
+                dangling = DistVec(
+                    blocks=jax.device_put(
+                        jnp.asarray(host["dangling"]),
+                        NamedSharding(grid.mesh, P(COL_AXIS)),
+                    ),
+                    length=meta["ncols"], align="col", grid=grid,
                 )
-                for i in range(info["nbuckets"])
-            ]
-            host_mats[nm] = host_buckets
-            mats[nm] = EllParMat.from_host_buckets(
-                grid, host_buckets, info["nrows"], info["ncols"]
-            )
-        dangling = None
-        if "dangling" in z:
-            dangling = DistVec(
-                blocks=jax.device_put(
-                    jnp.asarray(z["dangling"]),
-                    NamedSharding(grid.mesh, P(COL_AXIS)),
-                ),
-                length=meta["ncols"], align="col", grid=grid,
-            )
-        X = None
-        if "X" in z:
-            X = DistMultiVec(
-                blocks=jax.device_put(
-                    jnp.asarray(z["X"]),
-                    NamedSharding(grid.mesh, P(ROW_AXIS)),
-                ),
-                length=meta["ncols"], align="row", grid=grid,
-            )
+            X = None
+            if "X" in host:
+                X = DistMultiVec(
+                    blocks=jax.device_put(
+                        jnp.asarray(host["X"]),
+                        NamedSharding(grid.mesh, P(ROW_AXIS)),
+                    ),
+                    length=meta["ncols"], align="row", grid=grid,
+                )
+            upload.sync_on((mats, dangling, X))
         csc = None
-        if "csc.indptr" in z:
-            from ..parallel.ellmat import upload_csc_companion
-
-            csc = upload_csc_companion(
-                grid, z["csc.indptr"], z["csc.rowidx"]
-            )
+        if "csc.indptr" in host:
+            with obs.span("companion") as companion:
+                csc = upload_csc_companion(
+                    grid, host["csc.indptr"], host["csc.rowidx"]
+                )
+                companion.sync_on(csc)
         host_coo = None
         host_weights = None
-        if "coo_rows" in z:
-            host_coo = (
-                np.asarray(z["coo_rows"]), np.asarray(z["coo_cols"]),
-                meta["ncols"],
-            )
-            if "coo_weights" in z:
-                host_weights = np.asarray(z["coo_weights"])
+        if "coo_rows" in host:
+            host_coo = (host["coo_rows"], host["coo_cols"], meta["ncols"])
+            host_weights = host.get("coo_weights")
         version = GraphVersion(
             nrows=meta["nrows"], ncols=meta["ncols"], nnz=meta["nnz"],
             E=mats["E"],
-            deg=np.asarray(z["deg"]),
-            outdeg=(
-                np.asarray(z["outdeg"]) if "outdeg" in z else None
-            ),
+            deg=host["deg"],
+            outdeg=host.get("outdeg"),
             E_weighted=mats["E_weighted"],
             P_ell=mats["P_ell"],
             dangling=dangling,
@@ -534,10 +537,8 @@ def _load_version(path: str, grid: Grid, writable: bool = True):
             # and bucket copies — only the write-lane owner merges.
             e_buckets = host_mats["E"]
             t_buckets = host_mats.get("ET")
-            deg_host = np.asarray(z["deg"])
-            outdeg_host = (
-                np.asarray(z["outdeg"]) if "outdeg" in z else None
-            )
+            deg_host = host["deg"]
+            outdeg_host = host.get("outdeg")
 
             def _dyn_source():
                 from ..dynamic.merge import state_from_host_buckets
@@ -548,6 +549,12 @@ def _load_version(path: str, grid: Grid, writable: bool = True):
                 )
 
             version.dyn_source = _dyn_source
+        if obs.ENABLED:
+            restore.annotate(
+                file_bytes=os.path.getsize(path),
+                host_bytes=sum(int(a.nbytes) for a in host.values()),
+                device_bytes=version.device_bytes(),
+            )
     obs.observe("serve.checkpoint.load_s", time.perf_counter() - t0)
     return version
 

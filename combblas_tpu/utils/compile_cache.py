@@ -21,7 +21,8 @@ explicit test-only escape hatch.
 When telemetry is on (``combblas_tpu.obs``), enabling the cache also
 installs the jax.monitoring bridge so persistent-cache hits/misses
 surface as the ``compile_cache.hits`` / ``compile_cache.misses``
-counters, and registers a pull-provider publishing the
+counters (and each fetch's and compile's seconds as an event of the span
+it ran under: ``obs.JAX_DURATION_EVENTS``), and registers a pull-provider publishing the
 ``compile_cache.entries`` gauge (files currently in the cache dir) into
 every report/JSONL dump.
 """

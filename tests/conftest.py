@@ -127,13 +127,33 @@ def pytest_collection_modifyitems(items):
     # ``benchmark`` PR may edit (PERF.md section 7).  Until one does,
     # its other assertions are held, with the sign turned, by
     # ``test_serve_handoff.py::test_rehearsed_cell_reads_the_hidden_tail``.
+    #
+    # PR 34: six per-layer entries that every cell reports are appended
+    # to ``BENCHMARK.json``.  ``test_chipbench_scopes.py:221`` pins PR
+    # 23's eleven as the file's LAST (eleven cases), and
+    # ``test_chipbench_cc.py`` pins the CC cell's per-layer metrics to
+    # exactly three, in its entry (line 370) and in its traced line
+    # (line 406); both files are the benchmark's too.  Every other
+    # assertion of the first is held, the pin as an order check, for
+    # every entry in ``test_per_layer_contract.py``; of the two CC cases
+    # in ``test_boot_cells.py``, the sets turned into subsets.
+    known = {
+        "test_chipbench_parts_cell.py::test_traced_served_cell_prints":
+            "asserts the serial worker's batch_gap_ms >= 0",
+        "test_chipbench_scopes.py::"
+        "test_new_per_layer_entries_follow_the_contract":
+            "pins PR 23's entries as the last of per_layer",
+        "test_chipbench_cc.py::"
+        "test_the_cell_is_appended_and_its_readers_wait_for_a_benchmark_pr":
+            "pins the CC cell's per-layer metrics to three",
+        "test_chipbench_cc.py::test_the_cell_through_the_real_command":
+            "pins the CC cell's traced line to three metrics",
+    }
     for item in items:
-        if ("test_chipbench_parts_cell.py::"
-                "test_traced_served_cell_prints" in item.nodeid):
-            item.add_marker(pytest.mark.xfail(
-                reason="asserts the serial worker's batch_gap_ms >= 0",
-                strict=False,
-            ))
+        for case, reason in known.items():
+            if case in item.nodeid:
+                item.add_marker(pytest.mark.xfail(reason=reason,
+                                                  strict=False))
 
 
 @pytest.fixture(scope="session", autouse=True)

@@ -305,3 +305,35 @@ def test_rehearsed_cell_reads_the_hidden_tail(tmp_path, cell, devices):
     assert m["scatter_copied_mb"] == pytest.approx(
         2 * 512 * 4 * 16 / 1e6, rel=0.5)
     assert m["compiles_in_window"] == 0
+    # the boot under the same run (PR 34): all six set-up metrics, and
+    # what lies outside the program's top-level spans plus their union
+    # (recomputed from the logged timeline) is the run's ``setup_s``
+    boot = {"graph_ready_s", "upload_s", "boot_trace_s", "boot_fetch_s",
+            "boot_probe_s", "boot_unspanned_s"}
+    assert boot <= set(m) and all(m[k] >= 0 for k in boot)
+    spans = [ln.split() for ln in r.stderr.splitlines() if " boot span " in ln]
+    at = lambda f, key: float(f[f.index(key) + 1])
+    # (a fresh directory: this run built its graph; only an engine that
+    # kept its edge list has a companion to look after at warm-up)
+    others = [f[4] for f in spans if f[4] != "serve.warmup"]
+    assert others[:3] == [
+        "serve.load", "serve.engine.init", "serve.server.init"]
+    assert others[3:] in ([], ["serve.warmup.companion"])
+    cover, reach = 0.0, 0.0
+    for a, w in sorted((at(f, "at"), at(f, "wall")) for f in spans):
+        cover += max(a + w - max(a, reach), 0.0)
+        reach = max(reach, a + w)
+    setup_s = float(r.stderr.rsplit("] setup_s ", 1)[1].split()[0])
+    assert cover + m["boot_unspanned_s"] == pytest.approx(setup_s, abs=0.05)
+    assert m["upload_s"] < m["graph_ready_s"] <= m["load_s"]
+    plans = [ln for ln in r.stderr.splitlines() if " boot plan bfs w" in ln]
+    assert len(plans) == len(spans) - len(others)
+    # a plan's line adds up to its wall, and the plans to the warm-up
+    total = 0.0
+    for ln in plans:
+        nums = ln.split(": ", 1)[1].replace(",", "").replace("(", "").split()
+        parts = dict(zip(nums[0::2], map(float, nums[1::2])))
+        assert sum(v for k, v in parts.items() if k != "wall") == \
+            pytest.approx(parts["wall"], abs=0.01)
+        total += parts["wall"]
+    assert total == pytest.approx(m["warmup_s"], rel=0.05)
