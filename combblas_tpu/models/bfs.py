@@ -449,7 +449,6 @@ def bfs_batch(
     A,
     sources,
     max_iters: int | None = None,
-    sr: "Semiring" = SELECT2ND_MAX,
     track_levels: bool = True,
 ):
     """Eager wrapper over ``_bfs_batch_impl`` (plain-outputs law: a
@@ -461,8 +460,7 @@ def bfs_batch(
     from ..parallel.vec import DistMultiVec
 
     p, l, niter = _bfs_batch_impl(
-        A, sources, max_iters=max_iters, sr=sr,
-        track_levels=track_levels,
+        A, sources, max_iters=max_iters, track_levels=track_levels,
     )
     mk = lambda b: DistMultiVec(
         blocks=b, length=A.nrows, align="row", grid=A.grid
@@ -470,12 +468,11 @@ def bfs_batch(
     return mk(p), mk(l), niter
 
 
-@partial(jax.jit, static_argnames=("max_iters", "sr", "track_levels"))
+@partial(jax.jit, static_argnames=("max_iters", "track_levels"))
 def _bfs_batch_impl(
     A,
     sources,
     max_iters: int | None = None,
-    sr: "Semiring" = SELECT2ND_MAX,
     track_levels: bool = True,
 ):
     """Multi-source batched BFS: W independent BFS trees in ONE program.
@@ -483,10 +480,12 @@ def _bfs_batch_impl(
     Graph500 runs 64 search keys (the reference loops them host-side,
     ``TopDownBFS.cpp:437-444``); on TPU the whole batch advances together as
     a [n, W] frontier matrix — SURVEY §2.3 strategy 7 (BetwCent's
-    frontier-as-matrix) applied to BFS itself. Two wins, both measured on
-    v5e: (a) gathers are per-index bound, so W parent lanes ride one index
-    fetch ~free; (b) the whole batch is one launch — one fixed ~100ms
-    dispatch instead of W of them.
+    frontier-as-matrix) applied to BFS itself: the whole batch is one
+    launch, and one gathered index serves all W lanes (a level gathers
+    one membership word a column, ``_bfs_batch_tallied``).  A row's
+    parent is its largest in-frontier in-neighbour id: the reference's
+    ``SelectMaxSRing`` (``semiring.SELECT2ND_MAX``), the one semiring a
+    batched level has.
 
     ``sources``: int32 [W]. Returns (parents [pr, lr, W] int32 blocks,
     levels blocks, num_iters) — PLAIN ARRAYS (the eager wrapper above
@@ -499,7 +498,7 @@ def _bfs_batch_impl(
     16G HBM; round-2 width sweep). Levels are then
     returned as a discovery indicator (0 discovered / -1 not).
     """
-    return _bfs_batch_tallied(A, sources, max_iters, sr, track_levels)[:3]
+    return _bfs_batch_tallied(A, sources, max_iters, track_levels)[:3]
 
 
 #: Edge slots a tile has for the columns of a batch's roots: level 0 as
@@ -514,14 +513,36 @@ PUSH_EDGE_CAPACITY = 1 << 17
 #: companion returns: ``serve.bfs.push{outcome}``.
 PUSH_OUTCOMES = ("taken", "over_budget", "stale")
 
+#: What a served BFS level gathers from (``serve.warmup``'s ``payload``
+#: attribute): the frontier as membership bits, one int32 word a column
+#: for every 32 lanes (``ellmat.pack_lanes``).
+FRONTIER_PAYLOAD = "bits"
 
-def _bfs_batch_tallied(A, sources, max_iters, sr, track_levels, csc=None):
+
+def frontier_table_bytes(E, width: int) -> int:
+    """Bytes of the table one tile's degree class gathers from in a
+    width-``width`` level (``ellmat._ell_local_frontier``): a word a
+    local column and the pad slots' empty one, for every 32 lanes."""
+    from ..parallel.ellmat import WORD_LANES
+
+    return 4 * (E.local_cols + 1) * -(-int(width) // WORD_LANES)
+
+
+def _bfs_batch_tallied(A, sources, max_iters, track_levels, csc=None):
     """``_bfs_batch_impl`` plus, as a fourth output, the ``int32[2]``
     tally over the whole search of degree-class sweeps run dense /
     skipped (``ellmat.SWEEP_MODES``) and, as a fifth, what level 0 did
     (an index into ``PUSH_OUTCOMES``; None for a program with no push in
     it).  Not jitted: the served plan (``engine._build_plan``) traces it
     into its own program.
+
+    The loop carries the frontier as MEMBERSHIP, not as ids: ``member
+    [pc, lc, ceil(W / 32)]`` int32, col-aligned, bit ``l`` of word ``w``
+    set where the column is in the frontier of lane ``32 w + l``.  The id
+    a sweep's slot would fetch from a table of ids is its own column's,
+    so a level gathers the word and makes the candidate itself
+    (``ellmat.ell_frontier_sweep``): a sixteenth of the 16-lane table to
+    gather from and to realign, and one table at every width to 32.
 
     Level 0 is the one level whose work is known before it starts: its
     frontier is the batch's roots, W columns, which the pull sweep finds
@@ -534,20 +555,20 @@ def _bfs_batch_tallied(A, sources, max_iters, sr, track_levels, csc=None):
     ``PUSH_EDGE_CAPACITY`` on every tile; otherwise the loop starts at
     level 0, the state as it always was.  One ``cond`` whose other branch
     is the identity: the loop body is the same text either way, and no
-    gather table crosses a branch (``ellmat._ell_local_spmm``).  Parents,
-    levels, ``niter`` and every tie are the all-pull program's, bit for
-    bit.  The walk is ``SELECT2ND_MAX``'s; any other semiring, and a
-    caller without a companion, gets the all-pull program."""
+    gather table crosses a branch (``ellmat._ell_class_sweeps``).
+    Parents, levels, ``niter`` and every tie are the all-pull program's,
+    bit for bit; a caller without a companion gets the all-pull
+    program."""
     from ..parallel.vec import DistMultiVec
     from ..parallel.ellmat import (
-        SWEEP_MODES, ell_masked_multi_sweep, ell_roots_fit, ell_roots_push,
+        SWEEP_MODES, ell_frontier_sweep, ell_roots_fit, ell_roots_push,
+        pack_lanes,
     )
 
     grid = A.grid
     n = A.nrows
     pr_, lr = grid.pr, grid.local_rows(n)
     pc_, lc = grid.pc, grid.local_cols(A.ncols)
-    W = sources.shape[0]
     iters = max_iters if max_iters is not None else n
 
     with jax.named_scope("bfs.init"):
@@ -566,12 +587,7 @@ def _bfs_batch_tallied(A, sources, max_iters, sr, track_levels, csc=None):
             if track_levels
             else jnp.zeros((1, 1, 1), jnp.int32)  # placeholder carry
         )
-        x0 = jnp.where(
-            (col_gids[:, :, None] == src) & live, src, jnp.int32(-1)
-        )
-
-    def mk(b, align):
-        return DistMultiVec(blocks=b, length=n, align=align, grid=grid)
+        member0 = pack_lanes((col_gids[:, :, None] == src) & live)
 
     def cond(state):
         _, _, _, level, active, _ = state
@@ -587,24 +603,25 @@ def _bfs_batch_tallied(A, sources, max_iters, sr, track_levels, csc=None):
             parents = jnp.where(new, y, parents)
             if track_levels:
                 levels = jnp.where(new, level + 1, levels)
-            frontier = jnp.where(new, row_gids[:, :, None], -1)
-        x_next = mk(frontier, "row").realign("col").blocks
+            found = pack_lanes(new)  # [pr, lr, nw]
+        member = DistMultiVec(
+            blocks=found, length=n, align="row", grid=grid
+        ).realign("col").blocks
         with jax.named_scope("bfs.active"):
             active = jnp.any(new)
-        return parents, levels, x_next, level + 1, active
+        return parents, levels, member, level + 1, active
 
     def step(state):
-        parents, levels, x, level, _, tally = state
-        unvisited = mk(parents < 0, "row")
-        y, sweeps = ell_masked_multi_sweep(sr, A, mk(x, "col"), unvisited)
-        return (*advance(parents, levels, level, y.blocks), tally + sweeps)
+        parents, levels, member, level, _, tally = state
+        y, sweeps = ell_frontier_sweep(A, member, parents < 0)
+        return (*advance(parents, levels, level, y), tally + sweeps)
 
     state = (
-        parents0, levels0, x0, jnp.int32(0), jnp.bool_(True),
+        parents0, levels0, member0, jnp.int32(0), jnp.bool_(True),
         jnp.zeros((pr_, pc_, len(SWEEP_MODES)), jnp.int32),
     )
     outcome = None
-    if csc is not None and sr is SELECT2ND_MAX and iters > 0:
+    if csc is not None and iters > 0:
         indptr, rowidx, current = csc
         current = jnp.asarray(current, jnp.bool_)
         roots = src[0, 0]

@@ -29,7 +29,7 @@ SpMSpV path (PageRank's normalization, bfs_diropt) keep SpParMat.
 from __future__ import annotations
 
 import dataclasses
-from functools import partial
+from functools import partial, reduce
 
 import jax
 import jax.numpy as jnp
@@ -38,7 +38,7 @@ from jax import lax
 from jax.sharding import PartitionSpec as P
 
 from ..ops.segment import segment_reduce
-from ..semiring import Semiring
+from ..semiring import SELECT2ND_MAX, Semiring
 from .collectives import axis_reduce
 from .grid import COL_AXIS, ROW_AXIS, Grid
 from .spmat import SpParMat, TILE_SPEC
@@ -556,14 +556,88 @@ def _ell_reduce_rows_jit(E: EllParMat, sr: Semiring, map_fn) -> DistVec:
 # --- multi-root (batched) SpMV — frontier-as-matrix, SURVEY §2.3 #7 ---------
 
 
+def _ell_class_sweeps(
+    sr: Semiring, buckets, lr: int, lc: int, table, contract, *,
+    slot_bytes: int, dtype=None, row_active: Array | None = None,
+    lane_live: Array | None = None,
+):
+    """The ONE multi-lane class loop: per degree class gather, contract
+    the k axis, combine by row id into ``y [lr, lanes]``.  What a sweep
+    gathers FROM and what a gathered slot is worth are the caller's:
+
+    ``table()`` makes the gather table (first axis ``lc + 1``: the local
+    columns and the row pad slots fetch; an array or a tuple of arrays,
+    each indexed alike); ``contract(bc, bv, g)`` turns a row slice's
+    column ids, values and gathered entries into its ``[rows, lanes]``
+    partial result, ``sr.add`` over k done.  ``slot_bytes``: what one
+    slot gathers, for ``_bucket_row_slices``'s byte envelope.
+
+    ``row_active`` (``[lr, lanes]`` bool): the mask the CALLER applies
+    to ``y`` afterwards; ``lane_live [lanes]``: which lanes of the table
+    hold anything but the semiring zero; ``dtype``: ``y``'s, which the
+    choice carries before the first class exists.  Given them, a class
+    none of whose rows can still change is skipped (``_class_sweep``):
+    ``y`` is then right on every entry the mask keeps and may hold the
+    zero elsewhere, and ``tally`` is the ``int32[2]`` count of class
+    sweeps by ``SWEEP_MODES``.  Without a mask every class is swept
+    whole from one shared table, ``tally`` is None, ``y`` is None for a
+    matrix without buckets, and the program is what it was before there
+    was a choice.
+    """
+    # Unmasked, one table serves every class.  Masked, each sweep builds
+    # its own INSIDE its branch: the v5e compiler keeps a table in its
+    # fast memory space (``S(1)`` in the optimised HLO) only when it is
+    # produced in the computation that gathers from it, and only while
+    # it is small enough: ``s32[2^20 + 1, 16]`` (67 MB, one chip's
+    # 16-lane id table at scale 20) was placed, by the described-chip
+    # compile and on the chip, ``s32[2^21 + 1, 16]`` (134 MB, a 2x2 mesh
+    # tile's at scale 22) was not (PERF.md section 6, PR 35).  A gather
+    # from there is 3x a gather from HBM (48.6 against 167 ms for the
+    # 7.8 M slots of one class, PR 24).  A table handed into a branch
+    # stays in HBM; a copy costs 0.2 ms.
+    shared = table() if row_active is None else None
+
+    def sweep(i, bc, bv, br, y):
+        nb_, kb = bc.shape
+        xpad = shared if shared is not None else table()
+        for s0, s1 in _bucket_row_slices(nb_, kb, slot_bytes):
+            with _bucket_scope(i, "gather"):
+                safe = jnp.minimum(bc[s0:s1], lc)
+                # [rows, kb, ...] a table
+                g = jax.tree.map(lambda t: t[safe], xpad)
+            with _bucket_scope(i, "fold"):
+                yb = contract(bc[s0:s1], bv[s0:s1], g)  # [rows, lanes]
+            with _bucket_scope(i, "scatter_rows"):
+                if y is None:
+                    y = jnp.full(
+                        (lr, yb.shape[1]), sr.zero(yb.dtype), yb.dtype)
+                y = _scatter_rows(sr, y, br[s0:s1], yb.astype(y.dtype))
+        return y
+
+    y = tally = active = None
+    if row_active is not None:
+        active = _active_rows(row_active, lane_live)
+        tally = jnp.zeros((len(SWEEP_MODES),), jnp.int32)
+        # the choice carries y, so it exists before the first class
+        y = _tile_varying(
+            jnp.full((lr, row_active.shape[1]), sr.zero(dtype), dtype))
+    for i, bucket in enumerate(buckets):
+        idle = None if active is None else _class_idle(i, bucket[2], active)
+        y, mode = _class_sweep(i, bucket, idle, partial(sweep, i), y)
+        if mode is not None:
+            tally = tally.at[mode].add(1)
+    return y, tally
+
+
 def _ell_local_spmm(
     sr: Semiring, buckets, x2: Array, lr: int, lc: int, backend: str,
     row_active: Array | None = None,
 ):
     """[lr, F] semiring fold of one tile's buckets over a [lc, F]
-    dense block — the ONE local gather-contract kernel shared by the
+    dense block — the local gather-contract kernel shared by the
     batched SpMV lanes (W frontier columns) and the round-12 SpMM lane
-    (F feature columns).  Returns ``(y, tally)``.
+    (F feature columns): ``_ell_class_sweeps`` over the table ``x2``
+    itself.  Returns ``(y, tally)``.
 
     Per bucket, ONE gather fetches each neighbor's whole payload row
     (``[rows, kb, F]`` — per-index bound on the target chip, so the
@@ -577,73 +651,46 @@ def _ell_local_spmm(
     slot — F lanes × itemsize here where the int8 BFS step passed W).
 
     ``row_active`` (``[lr, F]`` bool): the mask the CALLER applies to
-    ``y`` afterwards.  Given it, a class none of whose rows can still
-    change is skipped (``_class_sweep``): ``y`` is then right on every
-    entry the mask keeps and may hold the zero elsewhere, and ``tally``
-    is the ``int32[2]`` count of class sweeps by ``SWEEP_MODES``.
-    Without it every class is swept whole, ``tally`` is None, and the
-    program is what it was before there was a choice.
+    ``y`` afterwards; with it classes that cannot change a kept entry
+    are skipped and ``tally`` counts the sweeps by ``SWEEP_MODES``
+    (``_ell_class_sweeps``); without it ``tally`` is None.
     """
     F = x2.shape[1]
     zero = sr.zero(x2.dtype)
+
     def table():
         """x2 plus the zero row that pad slots fetch."""
         return jnp.concatenate([x2, jnp.full((1, F), zero, x2.dtype)])
 
-    # Unmasked, one table serves every class.  Masked, each sweep builds
-    # its own INSIDE its branch: the v5e compiler keeps a table of up to
-    # ~100 MB in its fast memory space (``S(1)`` in the optimised HLO)
-    # only when it is produced in the computation that gathers from it,
-    # and a gather from there is 3x a gather from HBM (48.6 against 167 ms
-    # for the 7.8 M slots of one class, PERF.md section 6, PR 24).  A
-    # table handed into a branch stays in HBM; a copy costs 0.2 ms.
-    shared = table() if row_active is None else None
-    payload = F * max(jnp.dtype(x2.dtype).itemsize, 1)
+    def contract(_bc, bv, g):
+        if backend == "mxu_gather":
+            # pad slots: bv holds 0 there (host_build zero-fills), so
+            # the plus_times contraction drops them exactly
+            out_dtype = jnp.result_type(bv.dtype, x2.dtype)
+            return lax.dot_general(
+                bv[:, None, :].astype(out_dtype),
+                g.astype(out_dtype),
+                dimension_numbers=(((2,), (1,)), ((0,), (0,))),
+                preferred_element_type=out_dtype,
+            )[:, 0, :]
+        return _bucket_fold(sr, sr.mul(bv[..., None], g))
 
-    def sweep(i, bc, bv, br, y):
-        nb_, kb = bc.shape
-        xpad = shared if shared is not None else table()
-        for s0, s1 in _bucket_row_slices(nb_, kb, payload):
-            with _bucket_scope(i, "gather"):
-                g = xpad[jnp.minimum(bc[s0:s1], lc)]  # [rows, kb, F]
-            with _bucket_scope(i, "fold"):
-                if backend == "mxu_gather":
-                    # pad slots: bv holds 0 there (host_build
-                    # zero-fills), so the plus_times contraction drops
-                    # them exactly
-                    out_dtype = jnp.result_type(bv.dtype, x2.dtype)
-                    yb = lax.dot_general(
-                        bv[s0:s1][:, None, :].astype(out_dtype),
-                        g.astype(out_dtype),
-                        dimension_numbers=(((2,), (1,)), ((0,), (0,))),
-                        preferred_element_type=out_dtype,
-                    )[:, 0, :]
-                else:
-                    prods = sr.mul(bv[s0:s1][..., None], g)
-                    yb = _bucket_fold(sr, prods)  # [rows, F]
-            with _bucket_scope(i, "scatter_rows"):
-                if y is None:
-                    y = jnp.full((lr, F), sr.zero(yb.dtype), yb.dtype)
-                y = _scatter_rows(sr, y, br[s0:s1], yb.astype(y.dtype))
-        return y
-
-    y = tally = active = None
+    masked = {}
     if row_active is not None:
-        active = _active_rows(row_active, jnp.any(x2 != zero, axis=0))
-        tally = jnp.zeros((len(SWEEP_MODES),), jnp.int32)
-    if active is not None and buckets:
-        # the choice carries y, so it exists before the first class
-        dt = jax.eval_shape(
-            sr.mul, jax.ShapeDtypeStruct((), buckets[0][1].dtype),
-            jax.ShapeDtypeStruct((), x2.dtype),
-        ).dtype
-        y = _tile_varying(jnp.full((lr, F), sr.zero(dt), dt))
-    for i, bucket in enumerate(buckets):
-        idle = None if active is None else _class_idle(i, bucket[2], active)
-        y, mode = _class_sweep(i, bucket, idle, partial(sweep, i), y)
-        if mode is not None:
-            tally = tally.at[mode].add(1)
-    if y is None:
+        vals = buckets[0][1].dtype if buckets else x2.dtype
+        masked = dict(
+            row_active=row_active,
+            lane_live=jnp.any(x2 != zero, axis=0),
+            dtype=jax.eval_shape(
+                sr.mul, jax.ShapeDtypeStruct((), vals),
+                jax.ShapeDtypeStruct((), x2.dtype),
+            ).dtype,
+        )
+    y, tally = _ell_class_sweeps(
+        sr, buckets, lr, lc, table, contract,
+        slot_bytes=F * max(jnp.dtype(x2.dtype).itemsize, 1), **masked,
+    )
+    if y is None:  # unmasked, and a matrix with no bucket
         y = jnp.full((lr, F), zero, x2.dtype)
     return y, tally
 
@@ -703,6 +750,32 @@ def dist_spmv_ell_masked_multi(
     return ell_masked_multi_sweep(sr, E, X, row_active)[0]
 
 
+def _masked_tile_sweeps(sr: Semiring, E: EllParMat, xblocks, active, local):
+    """The mesh schedule of a masked sweep: every tile runs
+    ``local(buckets, xblk, actblk) -> (y, tally)`` on its column block of
+    ``xblocks`` (col-aligned) and its row block of the mask ``active``
+    (row-aligned bool), applies the mask and folds over the "c" axis.
+    Returns ``(blocks [pr, lr, W], tally int32[pr, pc, 2])``."""
+    nb = len(E.buckets)
+
+    def body(xblk, actblk, *flat):
+        buckets = [
+            tuple(a[0, 0] for a in flat[3 * i : 3 * i + 3]) for i in range(nb)
+        ]
+        y, tally = local(buckets, xblk[0], actblk[0])
+        with jax.named_scope("ell.reduce"):
+            y = jnp.where(actblk[0], y, sr.zero(y.dtype))
+            return axis_reduce(sr, y, COL_AXIS)[None], tally[None, None]
+
+    flat_args = [a for b in E.buckets for a in b]
+    return jax.shard_map(
+        body,
+        mesh=E.grid.mesh,
+        in_specs=(P(COL_AXIS), P(ROW_AXIS)) + (TILE_SPEC,) * (3 * nb),
+        out_specs=(P(ROW_AXIS), TILE_SPEC),
+    )(xblocks, active, *flat_args)
+
+
 @partial(jax.jit, static_argnames=("sr",))
 def ell_masked_multi_sweep(sr: Semiring, E: EllParMat, X, row_active):
     """``dist_spmv_ell_masked_multi`` and how it got there: ``(Y,
@@ -721,28 +794,124 @@ def ell_masked_multi_sweep(sr: Semiring, E: EllParMat, X, row_active):
     X = X.realign("col")
     row_active = row_active.realign("row")
     lr, lc = E.local_rows, E.local_cols
-    nb = len(E.buckets)
-
-    def body(xblk, actblk, *flat):
-        buckets = [
-            tuple(a[0, 0] for a in flat[3 * i : 3 * i + 3]) for i in range(nb)
-        ]
-        y, tally = _ell_local_spmm(
-            sr, buckets, xblk[0], lr, lc, "scatter", row_active=actblk[0]
-        )
-        with jax.named_scope("ell.reduce"):
-            y = jnp.where(actblk[0], y, sr.zero(y.dtype))
-            return axis_reduce(sr, y, COL_AXIS)[None], tally[None, None]
-
-    flat_args = [a for b in E.buckets for a in b]
-    blocks, tally = jax.shard_map(
-        body,
-        mesh=E.grid.mesh,
-        in_specs=(P(COL_AXIS), P(ROW_AXIS)) + (TILE_SPEC,) * (3 * nb),
-        out_specs=(P(ROW_AXIS), TILE_SPEC),
-    )(X.blocks, row_active.blocks, *flat_args)
+    blocks, tally = _masked_tile_sweeps(
+        sr, E, X.blocks, row_active.blocks,
+        lambda buckets, x2, act: _ell_local_spmm(
+            sr, buckets, x2, lr, lc, "scatter", row_active=act),
+    )
     Y = DistMultiVec(blocks=blocks, length=E.nrows, align="row", grid=E.grid)
     return Y, tally
+
+
+# --- a served BFS level: the frontier as membership bits --------------------
+
+#: lanes one int32 word of a packed frontier holds
+WORD_LANES = 32
+
+
+def pack_lanes(mask: Array) -> Array:
+    """``bool[..., W]`` -> ``int32[..., ceil(W / 32)]``: bit ``l`` of word
+    ``w`` is lane ``32 w + l``."""
+    W = mask.shape[-1]
+    # lane by lane and OR: a fold over the lane axis makes the compiler
+    # lay the loop's [n, W] state out lane-minor, W of a register's 128
+    # lanes in use
+    bits = mask.astype(jnp.uint32)
+    words = [
+        reduce(jnp.bitwise_or, (
+            bits[..., s0 + l] << np.uint32(l)
+            for l in range(min(WORD_LANES, W - s0))
+        ))
+        for s0 in range(0, W, WORD_LANES)
+    ]
+    return lax.bitcast_convert_type(jnp.stack(words, axis=-1), jnp.int32)
+
+
+def _word_lanes(word: Array, w: int, width: int, axis: int) -> Array:
+    """The lanes of packed word ``w`` of a ``width``-lane frontier, bit
+    by bit along a new axis ``axis``: bool, ``min(32, width - 32 w)``
+    long there."""
+    lanes = np.arange(min(WORD_LANES, width - w * WORD_LANES), dtype=np.int32)
+    word = jnp.expand_dims(word, axis)
+    along = [1] * word.ndim
+    along[axis] = -1
+    return (word >> lanes.reshape(along)) & 1 != 0
+
+
+def unpack_lanes(words: Array, width: int) -> Array:
+    """``pack_lanes`` undone: ``int32[..., nw]`` -> ``bool[..., width]``."""
+    return jnp.concatenate([
+        _word_lanes(words[..., w], w, width, -1)
+        for w in range(words.shape[-1])
+    ], axis=-1)
+
+
+def _ell_local_frontier(buckets, member: Array, lr: int, lc: int,
+                        row_active: Array):
+    """One tile of a served BFS level: ``[lr, W]`` int32, the largest
+    GLOBAL id of an in-neighbour of the row that is in the lane's
+    frontier, -1 where none is; ``(y, tally)`` as ``_ell_class_sweeps``
+    gives them under the mask ``row_active [lr, W]``.
+
+    ``member [lc, nw]`` int32 says WHO is in the frontier and nothing
+    else: bit ``l`` of word ``w`` of column ``j`` for lane ``32 w + l``
+    (``pack_lanes``).  That is ``SELECT2ND_MAX`` over a table of ids
+    (``x[j, lane] = j`` in the frontier, -1 outside), entry for entry:
+    the id a slot would fetch there is its own column's, which the sweep
+    holds already.  So the table a class gathers from is one word a
+    column, ``s32[lc + 1]`` (8 MB for a 2x2 mesh tile of a scale-22
+    graph, at every lane width to 32), where the id table is
+    ``s32[lc + 1, W]`` (134 MB at W = 16: past what the v5e compiler
+    places in its fast memory, ``_ell_class_sweeps``)."""
+    W = row_active.shape[1]
+    nw = member.shape[1]
+    base = lax.axis_index(COL_AXIS) * lc
+
+    def table():
+        """A word a column and lane word, plus the empty word that pad
+        slots fetch; one-dimensional each, so that the layout pads
+        nothing."""
+        return tuple(
+            jnp.concatenate([member[:, w], jnp.zeros((1,), jnp.int32)])
+            for w in range(nw)
+        )
+
+    def contract(bc, _bv, words):
+        ids = bc + base  # [rows, kb]
+        # lanes first, [lanes, rows, kb]: the fold runs along the slots,
+        # as the gathered words lie.  Pad slots: their word is empty.
+        best = [
+            jnp.max(jnp.where(_word_lanes(word, w, W, 0), ids, -1), axis=2)
+            for w, word in enumerate(words)
+        ]
+        return jnp.concatenate(best).T  # [rows, W]
+
+    return _ell_class_sweeps(
+        SELECT2ND_MAX, buckets, lr, lc, table, contract,
+        # the candidates a slot is worth, should they be materialised
+        slot_bytes=4 * W, dtype=jnp.int32, row_active=row_active,
+        lane_live=unpack_lanes(jnp.bitwise_or.reduce(member, axis=0), W),
+    )
+
+
+@jax.jit
+def ell_frontier_sweep(E: EllParMat, member: Array, row_active: Array):
+    """A served BFS level's sweep: ``(candidates, tally)``.
+
+    member: ``int32[pc, lc, nw]`` col-aligned blocks, the W lanes'
+    frontiers as membership bits (``pack_lanes``); row_active:
+    ``bool[pr, lr, W]`` row-aligned blocks, the rows a lane has not
+    visited.  candidates: ``int32[pr, lr, W]`` row-aligned blocks, for
+    every active (row, lane) the largest id among the row's in-neighbours
+    in the lane's frontier, -1 where there is none and on every other
+    entry: what ``ell_masked_multi_sweep(SELECT2ND_MAX, ...)`` gives for
+    the table of ids, bit for bit, through the same class loop, choice
+    and ``tally`` (``int32[pr, pc, 2]``)."""
+    lr, lc = E.local_rows, E.local_cols
+    return _masked_tile_sweeps(
+        SELECT2ND_MAX, E, member, row_active,
+        lambda buckets, m, act: _ell_local_frontier(buckets, m, lr, lc, act),
+    )
 
 
 def _bucket_row_slices(nb: int, kb: int, W: int,
@@ -815,7 +984,6 @@ def _ell_levels_step(E: EllParMat, x8, undiscovered8, ring: bool = False):
                 # the carousel schedule: neighbor ppermute rotation over
                 # the 'c' mesh axis (COL_AXIS — same axis the pmax path
                 # reduces) instead of the fused all-reduce
-                from ..semiring import SELECT2ND_MAX
                 from .collectives import axis_ring_reduce
 
                 return axis_ring_reduce(SELECT2ND_MAX, y, COL_AXIS)[None]

@@ -823,7 +823,6 @@ class GraphEngine:
 
         from ..models.bc import _bc_batch_lanes
         from ..models.bfs import _bfs_batch_tallied
-        from ..semiring import SELECT2ND_MAX
         from ..models.pagerank import _pagerank_batch_impl
         from ..models.sssp import _sssp_batch_impl
 
@@ -842,7 +841,7 @@ class GraphEngine:
                 # on (``execute``)
                 trace_mark()
                 return _bfs_batch_tallied(
-                    E, sources, self.max_iters, SELECT2ND_MAX, True, csc
+                    E, sources, self.max_iters, True, csc
                 )
 
         elif kind == "sssp":
@@ -995,6 +994,8 @@ class GraphEngine:
         """
         import jax
 
+        from ..models.bfs import FRONTIER_PAYLOAD, frontier_table_bytes
+
         kinds = self.kinds() if kinds is None else kinds
         widths = self.DEFAULT_WARMUP_WIDTHS if widths is None else widths
         if "bfs" in kinds and self._version.host_coo is not None:
@@ -1014,6 +1015,12 @@ class GraphEngine:
                     "serve.warmup", kind=kind, width=int(w)
                 ) as sp:
                     plan = self.plan(kind, w)
+                    if kind == "bfs":
+                        # what a level's sweep gathers from, a tile
+                        sp.annotate(
+                            payload=FRONTIER_PAYLOAD,
+                            table_bytes=frontier_table_bytes(self.E, w),
+                        )
                     sp.mark("build")
                     pads = np.full(int(w), PAD_ROOT, np.int32)
                     jax.block_until_ready(plan.fn(pads))

@@ -495,7 +495,7 @@ def test_thin_sweeps_match_all_dense(case, shape, all_dense_sweeps):
     def run():
         served = jax.jit(
             lambda E, r: _bfs_batch_tallied(
-                E, r, None, SELECT2ND_MAX, True)[:4]
+                E, r, None, True)[:4]
         )(E, roots)
         p, l, niter = bfs_batch_compact(E, roots)
         return [np.asarray(a) for a in (*served, p.blocks, l.blocks, niter)]
@@ -559,7 +559,7 @@ def test_sweep_tally_on_a_path():
         Grid.make(1, 1), rows, cols, np.ones(len(rows), np.float32), n, n
     )
     _, _, niter, tally, push = jax.jit(
-        lambda E, r: _bfs_batch_tallied(E, r, None, SELECT2ND_MAX, True)
+        lambda E, r: _bfs_batch_tallied(E, r, None, True)
     )(E, jnp.asarray(roots, jnp.int32))
     assert push is None  # no companion handed in: no push in the program
     dense, skipped = (int(t) for t in tally)

@@ -13,7 +13,6 @@ import jax.numpy as jnp
 from combblas_tpu.models import PAD_ROOT
 from combblas_tpu.models import bfs as bfs_mod
 from combblas_tpu.parallel.grid import Grid
-from combblas_tpu.semiring import SELECT2ND_MAX
 from combblas_tpu.serve import GraphEngine
 
 SCALE = 10
@@ -61,7 +60,7 @@ def engines():
 @jax.jit
 def _all_pull_program(E, sources):
     return bfs_mod._bfs_batch_tallied(
-        E, sources, None, SELECT2ND_MAX, True, None)
+        E, sources, None, True, None)
 
 
 def _all_pull(E, sources):

@@ -102,7 +102,9 @@ def test_a_fresh_plans_warmup_carries_jaxs_own_seconds():
     assert [s["name"] for s in spans if "/" not in s["path"]] == [
         "serve.server.init", "serve.warmup.companion", "serve.warmup"]
     warm, = [s for s in spans if s["path"] == "serve.warmup"]
-    assert warm["attrs"] == {"kind": "bfs", "width": 2}
+    # a bfs plan's span also says what its levels gather from (PR 35)
+    assert warm["attrs"] == {"kind": "bfs", "width": 2, "payload": "bits",
+                             "table_bytes": 4 * (n + 1)}
     parts = {p["stage"]: p["s"] for p in warm["parts"]}
     assert list(parts) == ["build", "execute", "probe"]
     assert sum(parts.values()) == pytest.approx(warm["wall_s"], rel=0.05)

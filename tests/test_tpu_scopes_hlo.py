@@ -168,7 +168,6 @@ def _plan_hlo(kind, E, grid, csc=None, strip=True):
     from combblas_tpu.models.pagerank import _pagerank_batch_impl
     from combblas_tpu.models.sssp import _sssp_batch_impl
     from combblas_tpu.parallel.vec import DistVec
-    from combblas_tpu.semiring import SELECT2ND_MAX
 
     n = E.nrows
     sources = jax.ShapeDtypeStruct(
@@ -177,7 +176,7 @@ def _plan_hlo(kind, E, grid, csc=None, strip=True):
     if kind == "bfs":
         def serve_bfs_w16(E, csc, sources):
             return bfs_mod._bfs_batch_tallied(
-                E, sources, None, SELECT2ND_MAX, True, csc)
+                E, sources, None, True, csc)
         args, fn = (E, csc, sources), serve_bfs_w16
     elif kind == "sssp":
         def serve_sssp_w16(E, sources):
@@ -231,13 +230,16 @@ def _computations(text):
     return comps
 
 
-def _loop_gather_tables(text, loop="bfs.level", dtype="s32", width=16):
+def _loop_gather_tables(text, loop="bfs.level", dtype="s32", lanes=None):
     """``[(class, defining line of the table its sweep gathers from)]``
-    for every degree class's payload gather (``dtype[slots, width]``)
-    inside the loop scoped ``loop``: the gather sits in a fusion, the
-    table is that fusion's operand.  Where two loops call one jitted
-    sweep, an instruction inside a fusion is named from the sweep down
-    and the fusion that holds it carries the loop's name."""
+    for every degree class's payload gather inside the loop scoped
+    ``loop``: the gathered block is ``dtype[slots, lanes]``, and with
+    ``lanes`` None one ``dtype`` word a slot (``dtype[rows, kb]``, or
+    ``dtype[rows]`` for the class of width 1: a served BFS level's
+    membership words).  The gather sits in a fusion, the table is that
+    fusion's operand.  Where two loops call one jitted sweep, an
+    instruction inside a fusion is named from the sweep down and the
+    fusion that holds it carries the loop's name."""
     comps = _computations(text)
     defs = {
         c: {re.sub(r"^(ROOT )?%", "", ln.split(" = ")[0]): ln
@@ -267,9 +269,9 @@ def _loop_gather_tables(text, loop="bfs.level", dtype="s32", width=16):
     for c, lines in comps.items():
         for ln in lines:
             m = re.search(
-                r"= %s\[[\d,]+,%d\]\S* gather\(%%?([\w.\-]+), .*"
+                r"= %s\[[\d,]+%s\]\S* gather\(%%?([\w.\-]+), .*"
                 r"op_name=\"[^\"]*ell\.bucket(\d+)/gather/gather\""
-                % (dtype, width), ln)
+                % (dtype, "" if lanes is None else ",%d" % lanes), ln)
             if m and loop in ln + callers.get(c, (None, ""))[1]:
                 out.append((int(m.group(2)), produced(c, m.group(1))))
     return sorted(out)
@@ -279,7 +281,7 @@ def _assert_own_fast_tables(text, loop, E):
     """Every degree class's f32 gather inside ``loop`` reads an
     ``[n + 1, 16]`` table built under that loop, in its own branch, and
     placed in the fast memory (``S(1)`` on its layout)."""
-    tables = _loop_gather_tables(text, loop, "f32")
+    tables = _loop_gather_tables(text, loop, "f32", 16)
     assert sorted({cls for cls, _ in tables}) == list(
         range(len(E.buckets))), loop
     for cls, line in tables:
@@ -309,11 +311,72 @@ def test_push_is_peeled_and_the_loop_keeps_its_fast_tables(
     for scope in ("ell.reduce", "bfs.update", "vec.realign", "bfs.active"):
         assert any(f"/bfs.push/" in nm and f"/{scope}/" in nm
                    for nm in seen), scope
+    _assert_fast_frontier_tables(text, E)
+
+
+def _assert_fast_frontier_tables(text, E):
+    """Every degree class of the served BFS loop gathers one int32 word
+    a slot from a table of one word a local column (the frontier as
+    membership bits, PR 35), built under ``bfs.level`` in the class's
+    own branch and placed in the fast memory (``S(1)``)."""
     tables = _loop_gather_tables(text)
     assert sorted({cls for cls, _ in tables}) == list(range(len(E.buckets)))
     for cls, line in tables:
         layout = line.split(" = ", 1)[1].split(" ", 1)[0]
+        assert layout.startswith(f"s32[{E.local_cols + 1}]"), line[:200]
         assert "S(1)" in layout and "bfs.level" in line, (cls, line[:200])
+    # the loop's [n, W] state stays a plane a lane (rows minor, as the
+    # sweep's tables and results are): packed by a fold over the lane
+    # axis it came out lane-minor, 16 of a register's 128 lanes in use,
+    # and a width-4 wave cost 812 ms where it costs 610 (PERF.md sec. 6)
+    updates = re.findall(
+        r"= s32\[1,\d+,\d+\](\{[\d,]+)\S* select\([^\n]*"
+        r"bfs\.level/while/body/bfs\.update/", text)
+    assert updates and set(updates) == {"{1,2,0"}, set(updates)
+
+
+def test_a_mesh_tiles_frontier_table_is_placed_for_the_v5e(topo):
+    """The width-16 served BFS plan of the mesh cell's size, compiled
+    over shapes alone for the described 2x2: ``n`` = 2^22, so a tile's
+    column block is 2^21 columns, and a handful of synthetic degree
+    classes (the placement follows the table's shape, not the matrix).
+    Every class's table in ``bfs.level`` is ``s32[2^21 + 1]`` with
+    ``S(1)``.  The table of ids the loop gathered from before PR 35,
+    ``s32[2^21 + 1, 16]`` (134 MB), compiles to ``{0,1:T(8,128)}`` with
+    no ``S(1)`` here, and a level on the mesh took 21 ns an index where
+    one chip's 67 MB table, placed, takes 6.2 (PERF.md section 6)."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from combblas_tpu.parallel.ellmat import TILE_SPEC, EllParMat
+    from combblas_tpu.parallel.grid import Grid
+
+    grid = Grid.make(2, 2, devices=list(topo.devices))
+    n, lc = 1 << 22, 1 << 21
+    tile = NamedSharding(grid.mesh, TILE_SPEC)
+
+    def tiles(shape, dtype):
+        return jax.ShapeDtypeStruct((2, 2) + shape, dtype, sharding=tile)
+
+    # (bucket rows, class width): narrow and many to wide and few
+    classes = [(1 << 20, 1), (1 << 19, 4), (1 << 18, 16), (1 << 14, 256),
+               (64, 1 << 15)]
+    E = EllParMat(
+        buckets=tuple(
+            (tiles((nb, kb), jnp.int32), tiles((nb, kb), jnp.float32),
+             tiles((nb,), jnp.int32))
+            for nb, kb in classes),
+        nrows=n, ncols=n, grid=grid,
+    )
+    assert E.local_cols == lc
+    companion = (
+        tiles((lc + 1,), jnp.int32), tiles((1 << 22,), jnp.int32),
+        jax.ShapeDtypeStruct(
+            (), jnp.bool_, sharding=NamedSharding(grid.mesh, P())),
+    )
+    _assert_fast_frontier_tables(
+        _plan_hlo("bfs", E, grid, companion, strip=False), E)
 
 
 #: sha256 of the stripped optimised HLO of the width-16 PageRank and SSSP
@@ -402,10 +465,9 @@ def test_mesh_loop_keeps_its_name_for_the_v5e(topo, kind, loop):
 
     def serve_bfs_w16(operands, sources):
         from combblas_tpu.models import bfs as bfs_mod
-        from combblas_tpu.semiring import SELECT2ND_MAX
 
         return bfs_mod._bfs_batch_tallied(
-            operands[0], sources, None, SELECT2ND_MAX, True, operands[1])
+            operands[0], sources, None, True, operands[1])
 
     def serve_sssp_w16(E, sources):
         from combblas_tpu.models.sssp import _sssp_batch_impl
