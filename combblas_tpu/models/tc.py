@@ -74,6 +74,22 @@ EDGE_HARVEST_BITS_MAX_DIM = 262144
 # _coo_sort_dedup now lives in ops/spgemm.py (coo_sort_dedup) — it is the
 # shared dedup front of every bit-packed kernel, imported above.
 
+#: The ``jax.named_scope`` names of the bit-packed harvest program
+#: (``tc_edgeharvest_bits``), outermost first; ``gather`` and
+#: ``popcount`` are set by ``ops/spgemm.py:popcount_pair_counts`` inside
+#: a step of the scan, so they read ``tc.harvest/gather`` and
+#: ``tc.harvest/popcount``.  Trace-time metadata only: the device
+#: trace's per-scope times are read by these names
+#: (docs/observability.md "Named scopes"), so a rename is a change of
+#: yardstick.
+TC_SCOPES = (
+    "tc.dedup",  # two stable sorts of every stored slot + the repeat mask
+    "tc.pack",  # zero fill + scatter-add of one bit a kept nonzero
+    "tc.harvest",  # the whole scan over chunks of row pairs
+    "gather",  # a step's two row gathers of [chunk, n/32] words
+    "popcount",  # a step's AND, population count and weighted sum
+)
+
 
 def _tc_edge_harvest(rows, cols, n: int, chunk: int = 4096) -> jax.Array:
     """One-launch TC past the dense-product ceiling (32K < n <= 64K):
@@ -147,7 +163,7 @@ def _tc_edge_harvest(rows, cols, n: int, chunk: int = 4096) -> jax.Array:
     return jnp.stack([hi, lo])
 
 
-def _tc_edge_harvest_bits(rows, cols, n: int, chunk: int = 8192) -> jax.Array:
+def _tc_edge_harvest_bits(rows, cols, n: int, chunk: int = 8192):
     """Bit-packed edge-harvest TC: the adjacency as a [n, n/32] uint32
     bitmask; each edge's common-neighbor count is popcount(row_i & row_j).
 
@@ -158,23 +174,38 @@ def _tc_edge_harvest_bits(rows, cols, n: int, chunk: int = 8192) -> jax.Array:
     is a scatter-ADD of 2^(c mod 32) at (r, c div 32): the input COO is
     dedup'd, so add ≡ bitwise-or (each bit lands exactly once).
 
-    Returns the (hi, lo) int32 split of 3·T like ``_tc_edge_harvest``.
+    Returns ``(hilo, pairs, edges)``: the (hi, lo) int32 split of 3·T
+    like ``_tc_edge_harvest``, the pair slots the scan walks (every
+    stored slot of the tile, kept or not, after chunk padding) and the
+    pairs of weight 1 (the undirected edges counted).
     """
     # ON-DEVICE DEDUP (duplicate COO entries would double-add a bit,
     # carrying into the NEXT bit and corrupting the adjacency — unlike
     # the idempotent .set of the bf16 variant): mask repeats, zero their
     # bit contribution AND their edge weight.
-    rows, cols, dup = _coo_sort_dedup(rows, cols)
-    loops = rows == cols
-    r_all = jnp.where(loops | dup, n, rows)  # dropped (mode="drop")
-    bits = pack_support_bits(r_all, cols, n, n, assume_unique=True)
-    keep = (rows > cols) & ~dup
-    nedge = rows.shape[0]
-    epad = -(-nedge // chunk) * chunk
-    er = jnp.pad(jnp.where(keep, rows, 0), (0, epad - nedge))
-    ec = jnp.pad(jnp.where(keep, cols, 0), (0, epad - nedge))
-    ew = jnp.pad(keep.astype(jnp.int32), (0, epad - nedge))
-    return popcount_pair_counts(bits, bits, er, ec, ew, chunk=chunk)
+    with jax.named_scope("tc.dedup"):
+        rows, cols, dup = _coo_sort_dedup(rows, cols)
+        loops = rows == cols
+        keep = (rows > cols) & ~dup
+    with jax.named_scope("tc.pack"):
+        r_all = jnp.where(loops | dup, n, rows)  # dropped (mode="drop")
+        bits = pack_support_bits(r_all, cols, n, n, assume_unique=True)
+    with jax.named_scope("tc.harvest"):
+        nedge = rows.shape[0]
+        epad = -(-nedge // chunk) * chunk
+        er = jnp.pad(jnp.where(keep, rows, 0), (0, epad - nedge))
+        ec = jnp.pad(jnp.where(keep, cols, 0), (0, epad - nedge))
+        ew = jnp.pad(keep.astype(jnp.int32), (0, epad - nedge))
+        hilo = popcount_pair_counts(bits, bits, er, ec, ew, chunk=chunk)
+    return hilo, jnp.int32(epad), jnp.sum(ew)
+
+
+@partial(jax.jit, static_argnames=("n",))
+def tc_edgeharvest_bits(rows, cols, n: int):
+    """The ONE program of a ``tc_job`` (``jit_tc_edgeharvest_bits`` in a
+    device trace): a one-tile ``SpParMat``'s ``[1, 1, cap]`` rows and
+    columns in, ``(hilo, pairs, edges)`` out."""
+    return _tc_edge_harvest_bits(rows[0, 0], cols[0, 0], n)
 
 
 #: Exact host-side total from a (hi, lo) split — shared with the other
@@ -272,6 +303,49 @@ def _tc_edge_harvest_dist(A: SpParMat, chunk: int = 8192) -> jax.Array:
     )(A.rows, A.cols)
 
 
+def tc_job(A: SpParMat) -> tuple[int, int, int]:
+    """One whole triangle count of the simple undirected graph ``A``
+    (symmetric nonzero structure; loops and repeated entries are masked
+    on the device) as GAP times a trial: from the stored edge list to
+    the exact count, nothing kept from job to job.  Runs the bit-packed
+    edge harvest, the kernel ``triangle_count``'s ``auto`` picks on one
+    device past the dense product's ceiling (``DENSE_MAX_DIM`` < n <=
+    ``EDGE_HARVEST_BITS_MAX_DIM``) and ``kernel="edgeharvest"`` names
+    at any n under that cap.
+
+    Returns ``(triangles, pairs, edges)``, Python ints, all three from
+    the program's own outputs (so they come back with telemetry off):
+    the exact count, the pair slots the harvest walked (every stored
+    slot of the tile after chunk padding, two row gathers each whether
+    kept or not) and the pairs of weight 1 (the undirected edges).
+
+    Eager wrapper: the readback of the three closes the job."""
+    n = max(A.nrows, A.ncols)
+    if A.grid.size != 1 or n > EDGE_HARVEST_BITS_MAX_DIM:
+        raise ValueError(
+            "edgeharvest needs the dense adjacency in one chip's HBM: "
+            f"one device and n <= {EDGE_HARVEST_BITS_MAX_DIM}, got "
+            f"{A.grid.size} devices and n = {n}"
+        )
+    hilo, pairs, edges = jax.device_get(
+        tc_edgeharvest_bits(A.rows, A.cols, n=A.nrows)
+    )
+    triangles, pairs, edges = combine_hilo(hilo) // 3, int(pairs), int(edges)
+    if obs.ENABLED:
+        # after the call, as models/cc.py:fastsv publishes: the first
+        # traced job pays for the program as an untraced one does
+        obs.opnames.publish_once(
+            ("tc_edgeharvest_bits", A.nrows, A.rows.shape),
+            lambda: tc_edgeharvest_bits.lower(
+                A.rows, A.cols, n=A.nrows).compile().as_text(),
+        )
+        obs.count("models.tc.jobs")
+        obs.count("models.tc.pairs", pairs)
+        obs.count("models.tc.edges", edges)
+        obs.count("models.tc.triangles", triangles)
+    return triangles, pairs, edges
+
+
 def triangle_count(A: SpParMat, kernel: str = "auto") -> int:
     """Number of triangles in the simple undirected graph A (symmetric,
     loop-free nonzero structure).
@@ -316,11 +390,7 @@ def triangle_count(A: SpParMat, kernel: str = "auto") -> int:
         return _tc_combine(
             jax.jit(_tc_dense, static_argnums=2)(t.rows, t.cols, A.nrows)
         )
-    harvest = {
-        "edgeharvest": _tc_edge_harvest_bits,
-        "edgeharvest_bf16": _tc_edge_harvest,
-    }
-    if kernel in harvest:
+    if kernel in ("edgeharvest", "edgeharvest_bf16"):
         if obs.ENABLED:
             obs.count("spgemm.auto.tier", tier=kernel, sr="plus_times")
         if A.grid.size > 1:
@@ -339,18 +409,16 @@ def triangle_count(A: SpParMat, kernel: str = "auto") -> int:
                     f"{p}x{p} grid, got {max(A.nrows, A.ncols)}"
                 )
             return combine_hilo(_tc_edge_harvest_dist(A)) // 3
-        cap = (
-            EDGE_HARVEST_BITS_MAX_DIM if kernel == "edgeharvest"
-            else EDGE_HARVEST_MAX_DIM
-        )
-        if max(A.nrows, A.ncols) > cap:
+        if kernel == "edgeharvest":
+            return tc_job(A)[0]
+        if max(A.nrows, A.ncols) > EDGE_HARVEST_MAX_DIM:
             raise ValueError(
                 f"{kernel} needs the dense adjacency in HBM: "
-                f"n <= {cap}, got {max(A.nrows, A.ncols)}"
+                f"n <= {EDGE_HARVEST_MAX_DIM}, got {max(A.nrows, A.ncols)}"
             )
         t = A.local_tile(A.rows, A.cols, A.vals, A.nnz)
         return _tc_combine(
-            jax.jit(harvest[kernel], static_argnums=2)(
+            jax.jit(_tc_edge_harvest, static_argnums=2)(
                 t.rows, t.cols, A.nrows
             )
         ) // 3

@@ -696,6 +696,24 @@ name                                   kind       meaning
                                                   count, the one that
                                                   changed nothing
                                                   included)
+``models.tc.jobs``                     counter    triangle-count jobs
+                                                  run through the eager
+                                                  wrapper
+                                                  (``models/tc.py:
+                                                  tc_job``)
+``models.tc.pairs``                    counter    row pairs their
+                                                  harvest walked (every
+                                                  stored slot after
+                                                  chunk padding, kept
+                                                  or not; the program's
+                                                  own count)
+``models.tc.edges``                    counter    of those, the pairs
+                                                  of weight 1: the
+                                                  undirected edges
+                                                  counted (the
+                                                  program's own count)
+``models.tc.triangles``                counter    triangles those jobs
+                                                  counted
 ``obs.provider_errors``                counter    broken pull-provider
                                                   callbacks (caught)
 =====================================  =========  =====================

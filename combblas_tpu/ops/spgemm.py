@@ -835,10 +835,13 @@ def popcount_pair_counts(
 
     def body(carry, eidx):
         hi, lo = carry
-        gi = bits_i[ii[eidx]]  # [chunk, nw] u32
-        gj = bits_j[jj[eidx]]
-        pc = lax.population_count(gi & gj)
-        cnt = jnp.sum(pc.astype(jnp.int32), axis=1) * weights[eidx]
+        # scopes (metadata only; models/tc.py:TC_SCOPES names them)
+        with jax.named_scope("gather"):
+            gi = bits_i[ii[eidx]]  # [chunk, nw] u32
+            gj = bits_j[jj[eidx]]
+        with jax.named_scope("popcount"):
+            pc = lax.population_count(gi & gj)
+            cnt = jnp.sum(pc.astype(jnp.int32), axis=1) * weights[eidx]
         # renormalize the split each step: an unbounded lo accumulation
         # would itself wrap past 2^31 (models/tc.py rationale)
         lo = lo + jnp.sum(cnt & 0x7FFF)

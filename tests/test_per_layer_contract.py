@@ -17,13 +17,16 @@ from chipbench.spec import CHECKOUT, Spec
 
 BENCH = os.path.join(CHECKOUT, "BENCHMARK.json")
 
-#: PR 23's eleven and PR 34's six, each run in the order its issue gave
+#: PR 23's eleven, PR 34's six and PR 37's six, each run in the order
+#: its issue gave
 RUNS = [
     ["launch_ms", "readback_ms", "to_global_ms", "readback_mb_per_query",
      "scatter_copied_mb", "batch_gap_ms", "bfs_gather_share",
      "bfs_level_ms", "k2_gather_share", "k2_level_ms", "k2_parents_ms"],
     ["graph_ready_s", "upload_s", "boot_trace_s", "boot_fetch_s",
      "boot_probe_s", "boot_unspanned_s"],
+    ["tc_device_ms", "tc_pack_ms", "tc_harvest_ms", "tc_pairs_per_edge",
+     "tc_hbm_share", "tc_hbm_peak_gb"],
 ]
 
 
@@ -50,22 +53,30 @@ def test_a_per_layer_entry_follows_the_contract(name):
     assert callable(spec.load_module("layers", name).read)
 
 
-@pytest.mark.parametrize("run", RUNS, ids=["pr23", "pr34"])
+@pytest.mark.parametrize("run", RUNS, ids=["pr23", "pr34", "pr37"])
 def test_appended_entries_keep_their_issues_order(run):
     names = _names()
     assert [n for n in names if n in run] == run
     assert len(set(names)) == len(names)
 
 
-def test_the_boot_metrics_are_the_last_and_in_every_cell():
+def test_each_run_was_appended_after_the_run_before_it():
+    """A run's first entry comes after the last of the run before: where
+    the driver takes new entries, at the end of what was there then."""
+    names = _names()
+    for before, after in zip(RUNS, RUNS[1:]):
+        assert names.index(before[-1]) < names.index(after[0])
+
+
+def test_the_boot_metrics_keep_their_order_and_are_in_every_cell():
     spec = Spec(BENCH)
-    last = spec.doc["per_layer"][-len(RUNS[1]):]
-    assert [m["name"] for m in last] == RUNS[1]
-    for m in last:
+    boot = [m for m in spec.doc["per_layer"] if m["name"] in RUNS[1]]
+    assert [m["name"] for m in boot] == RUNS[1]
+    for m in boot:
         assert "workloads" not in m
         assert (m["unit"], m["better"], m["source"], m["moves"]) == (
             "s", "lower", "program_span", "setup_s")
-    assert [m["layer"] for m in last] == [
+    assert [m["layer"] for m in boot] == [
         "set-up", "set-up", "compiler / cache", "compiler / cache",
         "set-up", "set-up"]
     for w in spec.doc["workloads"]:

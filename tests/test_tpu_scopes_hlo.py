@@ -589,3 +589,49 @@ def test_bc_scopes_name_both_loops_of_the_one_chip_program(
     assert (by_class.shape, by_class.dtype) == ((2, 2), jnp.int32)
     all_dense_sweeps(True)
     assert " conditional(" not in compiled()
+
+
+def test_the_triangle_count_job_fits_the_chip_at_the_cell_s_size(topo):
+    """``jit_tc_edgeharvest_bits`` at the size ``g500-s18tc.tc-batch``
+    runs it (n = 2^18, the configuration's 7,611,536 stored nonzeros)
+    for the described v5e: the compiler takes it with ``chunk=8192``;
+    the ``uint32[n, n/32]`` table (8.59 GB) exists ONCE (the scatter-add
+    lands in the fresh ``zeros``: a copy would be 17.2 GB and not fit a
+    16 GB chip), so the program's temporaries stay under the table plus
+    2 GB; the scan's two row gathers are ``u32[8192, 8192]`` blocks
+    under ``tc.harvest/.../gather``; and every one of ``TC_SCOPES`` is
+    on some instruction (``chipbench/tcscopes.py`` reads the device
+    trace by them)."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from combblas_tpu.models import tc
+    from combblas_tpu.obs import opnames
+
+    n, stored = 1 << 18, 7_611_536
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    tile = jax.ShapeDtypeStruct((1, 1, stored), jnp.int32, sharding=one_chip)
+    compiled = tc.tc_edgeharvest_bits.lower(tile, tile, n=n).compile()
+    table = n * n // 8
+    mem = compiled.memory_analysis()
+    assert table < mem.temp_size_in_bytes < table + 2 * 2**30
+    assert mem.temp_size_in_bytes + mem.argument_size_in_bytes < 12 * 2**30
+    text = compiled.as_text()
+    assert text.startswith("HloModule jit_tc_edgeharvest_bits")
+    names = opnames.parse(text)[1]
+    seen = set(names.values())
+    for scope in tc.TC_SCOPES:
+        assert any(f"/{scope}/" in nm for nm in seen), scope
+    loops = [nm for i, nm in names.items() if i.startswith("while")]
+    assert any(nm.endswith("tc.harvest/while") for nm in loops), loops
+    gathers = re.findall(
+        r"= u32\[8192,8192\]\S* gather\(.*op_name=\"[^\"]*"
+        r"tc\.harvest/[^\"]*/gather/gather\"", text)
+    assert len(gathers) == 2
+    assert re.search(
+        r"= u32\[262144,8192\]\S* scatter\(.*op_name=\"[^\"]*tc\.pack/", text)
+    hilo, pairs, edges = jax.eval_shape(
+        tc.tc_edgeharvest_bits, tile, tile, n=n)
+    assert (hilo.shape, hilo.dtype) == ((2,), jnp.int32)
+    assert pairs.shape == edges.shape == () and pairs.dtype == jnp.int32
