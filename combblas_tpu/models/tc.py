@@ -19,6 +19,7 @@ from .. import obs
 from ..ops.spgemm import (
     combine_hilo,
     coo_sort_dedup as _coo_sort_dedup,
+    front_pack_pairs,
     pack_support_bits,
     popcount_pair_counts,
 )
@@ -78,14 +79,16 @@ EDGE_HARVEST_BITS_MAX_DIM = 262144
 #: (``tc_edgeharvest_bits``), outermost first; ``gather`` and
 #: ``popcount`` are set by ``ops/spgemm.py:popcount_pair_counts`` inside
 #: a step of the scan, so they read ``tc.harvest/gather`` and
-#: ``tc.harvest/popcount``.  Trace-time metadata only: the device
+#: ``tc.harvest/popcount``.  The scan walks the kept pairs, chunk-padded
+#: (``front_pack_pairs`` brings them to the front under ``tc.dedup``),
+#: one step a chunk.  Trace-time metadata only: the device
 #: trace's per-scope times are read by these names
 #: (docs/observability.md "Named scopes"), so a rename is a change of
 #: yardstick.
 TC_SCOPES = (
-    "tc.dedup",  # two stable sorts of every stored slot + the repeat mask
+    "tc.dedup",  # the sorts of every stored slot, the repeat mask, kept first
     "tc.pack",  # zero fill + scatter-add of one bit a kept nonzero
-    "tc.harvest",  # the whole scan over chunks of row pairs
+    "tc.harvest",  # the whole scan over chunks of the kept row pairs
     "gather",  # a step's two row gathers of [chunk, n/32] words
     "popcount",  # a step's AND, population count and weighted sum
 )
@@ -174,10 +177,15 @@ def _tc_edge_harvest_bits(rows, cols, n: int, chunk: int = 8192):
     is a scatter-ADD of 2^(c mod 32) at (r, c div 32): the input COO is
     dedup'd, so add ≡ bitwise-or (each bit lands exactly once).
 
+    The scan walks the pairs it counts: the kept slots (strict lower
+    triangle, first of a run of repeats) are brought to the front of
+    the pair list in their row-sorted order, and the scan runs the
+    ``ceil(edges / chunk)`` steps that hold one.
+
     Returns ``(hilo, pairs, edges)``: the (hi, lo) int32 split of 3·T
-    like ``_tc_edge_harvest``, the pair slots the scan walks (every
-    stored slot of the tile, kept or not, after chunk padding) and the
-    pairs of weight 1 (the undirected edges counted).
+    like ``_tc_edge_harvest``, the pair slots the scan walks (the kept
+    pairs, chunk-padded: ``steps * chunk``; 0 where nothing is kept)
+    and the pairs of weight 1 (the undirected edges counted).
     """
     # ON-DEVICE DEDUP (duplicate COO entries would double-add a bit,
     # carrying into the NEXT bit and corrupting the adjacency — unlike
@@ -187,17 +195,14 @@ def _tc_edge_harvest_bits(rows, cols, n: int, chunk: int = 8192):
         rows, cols, dup = _coo_sort_dedup(rows, cols)
         loops = rows == cols
         keep = (rows > cols) & ~dup
+        er, ec, ew, edges = front_pack_pairs(keep, rows, cols, chunk=chunk)
     with jax.named_scope("tc.pack"):
         r_all = jnp.where(loops | dup, n, rows)  # dropped (mode="drop")
         bits = pack_support_bits(r_all, cols, n, n, assume_unique=True)
     with jax.named_scope("tc.harvest"):
-        nedge = rows.shape[0]
-        epad = -(-nedge // chunk) * chunk
-        er = jnp.pad(jnp.where(keep, rows, 0), (0, epad - nedge))
-        ec = jnp.pad(jnp.where(keep, cols, 0), (0, epad - nedge))
-        ew = jnp.pad(keep.astype(jnp.int32), (0, epad - nedge))
-        hilo = popcount_pair_counts(bits, bits, er, ec, ew, chunk=chunk)
-    return hilo, jnp.int32(epad), jnp.sum(ew)
+        hilo = popcount_pair_counts(
+            bits, bits, er, ec, ew, chunk=chunk, count=edges)
+    return hilo, -(-edges // chunk) * chunk, edges
 
 
 @partial(jax.jit, static_argnames=("n",))
@@ -228,7 +233,10 @@ def _tc_edge_harvest_dist(A: SpParMat, chunk: int = 8192) -> jax.Array:
     SpParMat.transpose's route).  Every device then harvests ONLY ITS
     OWN tile's strict-lower edges — the edge mask is already
     distributed — with ``popcount_pair_counts`` over the two local
-    tables, and the (hi, lo) partial sums ``psum`` into the global
+    tables, fed as the one-device kernel feeds it (``front_pack_pairs``,
+    then as many steps as the tile's own kept pairs fill: each device
+    loops its own count, no collective is inside the loop), and the
+    (hi, lo) partial sums ``psum`` into the global
     3·T count.  Local-column packing keeps the gather transient at the
     table's own n²/(8p) bytes (packing full-width [lr, n/32] tiles and
     OR-folding would transiently materialize p copies = n²/8 — the
@@ -250,8 +258,6 @@ def _tc_edge_harvest_dist(A: SpParMat, chunk: int = 8192) -> jax.Array:
         "kernel='sparse'"
     )
     nw_loc = -(-lc // 32)
-    cap = A.capacity
-    epad = -(-cap // chunk) * chunk
 
     def body(ar, ac):
         rows, cols = ar[0, 0], ac[0, 0]
@@ -285,13 +291,10 @@ def _tc_edge_harvest_dist(A: SpParMat, chunk: int = 8192) -> jax.Array:
             rowbits, (ROW_AXIS, COL_AXIS), grid.transpose_perm()
         )
         keep = (~dup) & (grows < n) & (grows > gcols)
-        er = jnp.where(keep, grows - ri * lr, 0)
-        ec = jnp.where(keep, gcols - ci * lc, 0)
-        ew = keep.astype(jnp.int32)
-        er = jnp.pad(er, (0, epad - cap))
-        ec = jnp.pad(ec, (0, epad - cap))
-        ew = jnp.pad(ew, (0, epad - cap))
-        hilo = popcount_pair_counts(rowbits, colbits, er, ec, ew, chunk=chunk)
+        er, ec, ew, kept = front_pack_pairs(
+            keep, grows - ri * lr, gcols - ci * lc, chunk=chunk)
+        hilo = popcount_pair_counts(
+            rowbits, colbits, er, ec, ew, chunk=chunk, count=kept)
         return lax.psum(lax.psum(hilo, ROW_AXIS), COL_AXIS)
 
     return jax.shard_map(
@@ -315,9 +318,9 @@ def tc_job(A: SpParMat) -> tuple[int, int, int]:
 
     Returns ``(triangles, pairs, edges)``, Python ints, all three from
     the program's own outputs (so they come back with telemetry off):
-    the exact count, the pair slots the harvest walked (every stored
-    slot of the tile after chunk padding, two row gathers each whether
-    kept or not) and the pairs of weight 1 (the undirected edges).
+    the exact count, the pair slots the harvest walked (the kept pairs,
+    chunk-padded: two row gathers each) and the pairs of weight 1 (the
+    undirected edges).
 
     Eager wrapper: the readback of the three closes the job."""
     n = max(A.nrows, A.ncols)

@@ -702,11 +702,10 @@ name                                   kind       meaning
                                                   (``models/tc.py:
                                                   tc_job``)
 ``models.tc.pairs``                    counter    row pairs their
-                                                  harvest walked (every
-                                                  stored slot after
-                                                  chunk padding, kept
-                                                  or not; the program's
-                                                  own count)
+                                                  harvest walked (the
+                                                  kept pairs,
+                                                  chunk-padded; the
+                                                  program's own count)
 ``models.tc.edges``                    counter    of those, the pairs
                                                   of weight 1: the
                                                   undirected edges

@@ -805,6 +805,38 @@ def pack_support_bits(
     )
 
 
+def front_pack_pairs(
+    keep: Array, ii: Array, jj: Array, *, chunk: int = 8192
+) -> tuple[Array, Array, Array, Array]:
+    """The pair list ``popcount_pair_counts`` walks, kept pairs FIRST.
+
+    One stable sort on ``~keep`` carrying both index lists: the kept
+    pairs come out as a prefix in the order they had (row-major after
+    ``coo_sort_dedup``), the rest behind them clamped to pair (0, 0)
+    under weight 0, and the whole is padded to a multiple of ``chunk``
+    the same way.  A sort and not the cumsum + scatter of
+    ``SpTuples._select``: the chip scatters some 11.5 M elements a
+    second (PERF.md section 6, PR 37), a sort carries its operands
+    along.
+
+    Returns ``(ii, jj, weights, count)``; ``count`` (traced int32) is
+    the length of the kept prefix, the ``count`` to hand the scan so it
+    runs the steps that hold a kept pair and no more.
+    """
+    drop, ii, jj = lax.sort(
+        (
+            (~keep).astype(jnp.int32),
+            jnp.where(keep, ii, 0),
+            jnp.where(keep, jj, 0),
+        ),
+        num_keys=1,
+        is_stable=True,
+    )
+    pad = (0, -ii.shape[0] % chunk)
+    weights = jnp.pad(1 - drop, pad)
+    return jnp.pad(ii, pad), jnp.pad(jj, pad), weights, jnp.sum(weights)
+
+
 def popcount_pair_counts(
     bits_i: Array,
     bits_j: Array,
@@ -813,6 +845,7 @@ def popcount_pair_counts(
     weights: Array,
     *,
     chunk: int = 8192,
+    count: Array | None = None,
 ) -> Array:
     """Σ_pairs weights · popcount(bits_i[ii] ∩ bits_j[jj]) as an int32
     (hi, lo) 15-bit split (totals can exceed 2^31; int64 is unavailable
@@ -820,37 +853,49 @@ def popcount_pair_counts(
 
     The masked-SpGEMM numeric pass for 0/1-valued plus_times products:
     each (i, j) pair's count is the exact C[i,j] = Σ_k A[i,k]·B[k,j]
-    restricted to the pair list (the output-support mask).  A lax.scan
-    walks static ``chunk``-sized pair blocks; per step two row gathers of
-    the packed tables + a streaming popcount — the bit-packed
-    edge-harvest inner loop (models/tc.py) generalized to two distinct
-    bit tables, which is what the DISTRIBUTED tier needs (row-block and
-    col-block masks live on different devices).
+    restricted to the pair list (the output-support mask).  A loop walks
+    ``chunk``-sized pair blocks, each a slice of the list at the loop's
+    counter; per step two row gathers of the packed tables + a streaming
+    popcount — the bit-packed edge-harvest inner loop (models/tc.py)
+    generalized to two distinct bit tables, which is what the
+    DISTRIBUTED tier needs (row-block and col-block masks live on
+    different devices).
 
     ``ii``/``jj``/``weights`` must be padded to a multiple of ``chunk``
     with weight-0 slots (indices clamped in-range by the caller).
+    ``count`` (a traced int32; default: every pair) is how many LEADING
+    pairs to walk: the loop runs ``ceil(count / chunk)`` steps, so a
+    list with its weight-1 pairs in front (``front_pack_pairs``) costs
+    the steps that hold one.
     """
     npairs = ii.shape[0]
     assert npairs % chunk == 0, (npairs, chunk)
+    if count is None:
+        steps = npairs // chunk
+    else:
+        steps = -(-jnp.minimum(count, npairs) // chunk)
 
-    def body(carry, eidx):
+    def body(k, carry):
         hi, lo = carry
+
+        def cut(a):  # step k's chunk of the pair list: a slice, no gather
+            return lax.dynamic_slice(a, (k * chunk,), (chunk,))
+
         # scopes (metadata only; models/tc.py:TC_SCOPES names them)
         with jax.named_scope("gather"):
-            gi = bits_i[ii[eidx]]  # [chunk, nw] u32
-            gj = bits_j[jj[eidx]]
+            gi = bits_i[cut(ii)]  # [chunk, nw] u32
+            gj = bits_j[cut(jj)]
         with jax.named_scope("popcount"):
             pc = lax.population_count(gi & gj)
-            cnt = jnp.sum(pc.astype(jnp.int32), axis=1) * weights[eidx]
+            cnt = jnp.sum(pc.astype(jnp.int32), axis=1) * cut(weights)
         # renormalize the split each step: an unbounded lo accumulation
         # would itself wrap past 2^31 (models/tc.py rationale)
         lo = lo + jnp.sum(cnt & 0x7FFF)
         hi = hi + jnp.sum(cnt >> 15) + (lo >> 15)
         lo = lo & 0x7FFF
-        return (hi, lo), None
+        return hi, lo
 
-    idx = jnp.arange(npairs, dtype=jnp.int32).reshape(-1, chunk)
-    (hi, lo), _ = lax.scan(body, (jnp.int32(0), jnp.int32(0)), idx)
+    hi, lo = lax.fori_loop(0, steps, body, (jnp.int32(0), jnp.int32(0)))
     return jnp.stack([hi, lo])
 
 

@@ -360,6 +360,35 @@ def test_distributed_edge_harvest_tc_matches_masked(rng):
     assert want == ref
 
 
+def test_distributed_edge_harvest_tiles_loop_their_own_steps(rng):
+    """Each tile's scan runs the steps its OWN kept pairs fill, inside
+    one ``shard_map``: on a 2x2 mesh the tile above the diagonal keeps
+    nothing (0 steps), a diagonal tile about half its slots (k) and the
+    tile below every one (2k); a small ``chunk`` makes k several steps.
+    The count is the masked SpGEMM's and the definition's."""
+    from combblas_tpu.models.tc import _tc_edge_harvest_dist, triangle_count
+
+    n, chunk = 128, 32
+    m = rng.random((n, n)) < 0.12
+    m = np.triu(m, 1)
+    m = m | m.T
+    r, c = np.nonzero(m)
+    grid = Grid.make(2, 2)
+    A = SpParMat.from_global_coo(
+        grid, r, c, np.ones(len(r), np.float32), n, n
+    )
+    h = n // 2
+    kept = {(i, j): int(np.tril(m, -1)[i * h:(i + 1) * h,
+                                      j * h:(j + 1) * h].sum())
+            for i in (0, 1) for j in (0, 1)}
+    steps = {t: -(-k // chunk) for t, k in kept.items()}
+    assert steps[0, 1] == 0 < steps[0, 0] < steps[1, 0]
+    assert steps[1, 0] >= 2 * min(steps[0, 0], steps[1, 1]) - 1 > 4
+    ref = int(np.trace(np.linalg.matrix_power(m.astype(np.int64), 3)) // 6)
+    assert combine_hilo(_tc_edge_harvest_dist(A, chunk=chunk)) == 3 * ref > 0
+    assert triangle_count(A, kernel="sparse") == ref
+
+
 def test_distributed_edge_harvest_tc_ceil_blocked(rng):
     """n % local_rows != 0 (ceil-blocking over-cover): the n-sentinel
     minus the last block's offset lands INSIDE the local range — the

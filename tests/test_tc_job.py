@@ -58,11 +58,73 @@ def test_job_counts_what_the_definition_counts(case):
     assert all(type(v) is int for v in (triangles, pairs, edges))
     assert triangles == tcref.brute_force(n, rows, cols) > 0
     assert triangles == tcref.TCReference(n, rows, cols).triangles
-    # the two counts are the host's: every stored slot, chunk-padded, and
-    # the undirected edges once the loops and repeats are gone
+    # the two counts are the host's: the undirected edges once the loops
+    # and repeats are gone, and the same chunk-padded: the scan walks the
+    # kept pairs alone, not the stored slots
     assert edges == len(rows) // 2 == len(tcref.undirected_edges(
         n, rows, cols)[0])
-    assert pairs == -(-stored // CHUNK) * CHUNK >= stored > 2 * edges
+    assert pairs == -(-edges // CHUNK) * CHUNK
+    assert stored > 2 * edges
+
+
+def _path_plus(n, m):
+    """``m`` undirected edges on ``n`` vertices, triangles among them:
+    the path 0-1-...-(n-1), then the chords (i, i+2), (i, i+3), ... in
+    turn; one direction an edge, as (larger, smaller)."""
+    out = [(i + step, i) for step in range(1, n) for i in range(n - step)]
+    assert len(out) >= m
+    e = np.array(out[:m], np.int32)
+    return e[:, 0], e[:, 1]
+
+
+def _stored(case, c):
+    """``(n, rows, cols)`` as stored, and the clean symmetric list the
+    reference takes."""
+    rng = np.random.default_rng(c)
+    if case == "loops-and-repeats-only":
+        loops = np.arange(0, 60, 3, dtype=np.int32)
+        r = np.concatenate([loops, loops, loops[:7]])
+        none = np.zeros(0, np.int32)
+        return 64, r, r, none, none
+    if case == "upper-first-shuffled":
+        n, rows, cols, _ = graph.rmat_graph(8, 16, 3)
+        up = rows < cols
+        order = np.concatenate([
+            rng.permutation(np.flatnonzero(up)),
+            rng.permutation(np.flatnonzero(~up))])
+        return n, rows[order], cols[order], rows, cols
+    m = {"exact-multiple": 3 * c, "one-past-a-chunk": c + 1}[case]
+    hi, lo = _path_plus(40, m)
+    rows, cols = np.concatenate([hi, lo]), np.concatenate([lo, hi])
+    # loops and repeats in the stored list, and a shuffle over all of it
+    again = rng.choice(len(rows), len(rows) // 5, replace=False)
+    loops = np.arange(5, dtype=np.int32)
+    r = np.concatenate([rows, rows[again], loops])
+    k = np.concatenate([cols, cols[again], loops])
+    order = rng.permutation(len(r))
+    return 40, r[order], k[order], rows, cols
+
+
+@pytest.mark.parametrize("case,c", [
+    ("exact-multiple", 64), ("one-past-a-chunk", 64),
+    ("loops-and-repeats-only", 64), ("upper-first-shuffled", 128)])
+def test_the_scan_runs_the_steps_that_hold_a_kept_pair(case, c):
+    """``_tc_edge_harvest_bits`` under a small ``chunk``, so the loop
+    runs many steps here: the count is the definition's, ``edges`` the
+    kept pairs, ``pairs`` those chunk-padded (a whole number of chunks
+    adds no step, one pair more adds one, nothing kept runs none)."""
+    n, r, k, rows, cols = _stored(case, c)
+    hilo, pairs, edges = jax.jit(
+        tc._tc_edge_harvest_bits, static_argnames=("n", "chunk"))(
+            jnp.asarray(r), jnp.asarray(k), n=n, chunk=c)
+    kept, want = len(rows) // 2, tcref.brute_force(n, rows, cols)
+    got = (ops.combine_hilo(hilo), int(pairs), int(edges))
+    assert got == (3 * want, -(-kept // c) * c, kept)
+    if case == "loops-and-repeats-only":
+        assert got == (0, 0, 0)
+    else:
+        assert want > 0 and pairs // c > 1
+        assert (kept % c == 0) == (case == "exact-multiple")
 
 
 @pytest.mark.parametrize("kernel", KERNELS)

@@ -629,6 +629,14 @@ def test_the_triangle_count_job_fits_the_chip_at_the_cell_s_size(topo):
         r"= u32\[8192,8192\]\S* gather\(.*op_name=\"[^\"]*"
         r"tc\.harvest/[^\"]*/gather/gather\"", text)
     assert len(gathers) == 2
+    # a step takes its chunk of the pair list by slice at the loop's
+    # counter: no element gather of 8,192 indices from the list is left
+    # (PERF.md section 6, PR 39), and what the front-packing sort adds
+    # fits with the rest under the table + 2 GB (asserted above)
+    assert re.search(
+        r"= s32\[8192\]\S* dynamic-slice\(.*op_name=\"[^\"]*tc\.harvest/", text)
+    assert not re.search(
+        r"= s32\[8192\]\S* gather\(.*op_name=\"[^\"]*tc\.harvest/", text)
     assert re.search(
         r"= u32\[262144,8192\]\S* scatter\(.*op_name=\"[^\"]*tc\.pack/", text)
     hilo, pairs, edges = jax.eval_shape(
