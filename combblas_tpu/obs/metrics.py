@@ -713,6 +713,40 @@ name                                   kind       meaning
                                                   program's own count)
 ``models.tc.triangles``                counter    triangles those jobs
                                                   counted
+``spgemm.job.jobs``                    counter    products run as one
+                                                  job (``parallel/
+                                                  spgemm.py:
+                                                  spgemm_job``); every
+                                                  ``spgemm.job.*``
+                                                  series is labelled
+                                                  ``tier``, ``backend``
+``spgemm.job.products``                counter    scalar multiplies of
+                                                  those jobs (the
+                                                  symbolic pass's true
+                                                  flops, summed in f32
+                                                  on the device)
+``spgemm.job.nnz_out``                 counter    stored entries of
+                                                  their results (the
+                                                  digest's own count)
+``spgemm.job.windows``                 counter    windows their numeric
+                                                  phases launched (row
+                                                  blocks under
+                                                  ``scatter``, (row
+                                                  block, col window)
+                                                  pairs under ``dot``;
+                                                  0 off the windowed
+                                                  tier)
+``spgemm.job.windows_skipped``         counter    windows the symbolic
+                                                  pass found empty and
+                                                  the job never
+                                                  launched
+``spgemm.job.dense_flops``             counter    flop their dense
+                                                  stage products issued
+                                                  (two a cell of every
+                                                  launched window's
+                                                  padded contraction; 0
+                                                  for a tier that never
+                                                  densifies)
 ``obs.provider_errors``                counter    broken pull-provider
                                                   callbacks (caught)
 =====================================  =========  =====================

@@ -41,11 +41,15 @@ def parse(hlo_text: str) -> tuple[str | None, dict[str, str]]:
     )
 
 
-def publish(hlo_text) -> str | None:
+def publish(hlo_text, nth: int | None = None) -> str | None:
     """Keep the table of one compiled program under its module name
     (a later program of the same name replaces it).  Returns the name.
     ``hlo_text``: the compiled text, or a zero-argument callable that
-    makes it (so that making it is inside the span too)."""
+    makes it (so that making it is inside the span too).  ``nth``: for
+    a function one job launches under several static signatures, each
+    its own program of the one module name: this launch's place among
+    them, kept as ``<module>#<nth>`` (a trace reader takes a job's
+    executions of the module in order)."""
     from .. import obs
 
     with obs.span("obs.opnames.publish"):
@@ -54,19 +58,21 @@ def publish(hlo_text) -> str | None:
         name, table = parse(hlo_text)
         if name is None:
             return None
+        if nth is not None:
+            name = f"{name}#{nth}"
         with _lock:
             _tables[name] = table
         return name
 
 
-def publish_once(key, make_text) -> None:
+def publish_once(key, make_text, nth: int | None = None) -> None:
     """``publish(make_text())`` the first time ``key`` is seen: for a
     program with no warm-up of its own, whose every call asks."""
     with _lock:
         if key in _published:
             return
         _published.add(key)
-    publish(make_text)
+    publish(make_text, nth)
 
 
 def tables() -> dict[str, dict[str, str]]:

@@ -206,123 +206,54 @@ def densify(t: SpTuples, pad_rows: int, pad_cols: int, zero) -> Array:
 def sparsify_windowed(
     dense: Array, zero, nrows: int, ncols: int, capacity: int
 ) -> tuple[SpTuples, Array]:
-    """Dense [R, C] → compacted row-major SpTuples, output-driven with
-    CONTIGUOUS-WINDOW narrowing (round 4).
+    """Dense [R, C] → compacted row-major SpTuples by ONE sort: every
+    cell's key is its own row-major index where it holds a value and
+    R*C where it does not, and the values ride along.
 
-    The target chip prices every per-element RANDOM memory op at ~22 M/s
-    but serves one-index CONTIGUOUS multi-lane windows at ~130 M/s
-    (round-3 cost model), and streams elementwise passes at only
-    ~1 G elem-op/s (round-4 probe) — so an extraction must (a) be output-
-    driven (input-driven scatters pay per CELL, and the r2 binary-search
-    sparsify paid ~14 random probes per slot), and (b) spend its few
-    per-slot memory ops on windows, not point gathers.  Scheme:
+    What an extraction costs on the v5e is what it moves one element at
+    a time (my chip runs, PR 40, one [4096, 8192] window, 15% of its
+    cells set, capacity = its cells): a gather of 33.5 M f32 by
+    ascending indices 935 ms (28 ns each), a scatter of as many int32 to
+    ascending slots 237 ms (7 ns each), whereas a sort of 33.5 M int32
+    keys is 81 ms and a running sum over them 8 ms.  The whole
+    extraction measured 116 ms a window this way, 485 ms as a running
+    count and two scatters (indices, values) and 877 ms as one scatter
+    and the gather of the values; the output-driven scheme this
+    replaces (round 4: group-count tables and two "contiguous window"
+    gathers, 16 + 8 lanes, a SLOT of capacity; its cost model came from
+    a machine that is gone) was 81.1 s a product job at scale 14; with
+    this one the same job is 1.1 s, 0.6 s of it here.
 
-      counts:  8-cell group counts + 128-cell chunk prefix tables (MXU /
-               streaming passes over the dense input — no random ops)
-      slots:   ``expand_ranges`` over the 2M chunk counts → each output
-               slot learns its (chunk, rank-within-chunk) for one
-               chunk-sized scatter + one output-sized cummax
-      narrow:  TWO window gathers per slot — the chunk's 16-entry group-
-               prefix window (locates the 8-cell group) and the group's 8
-               values (locates the lane IN REGISTER: the winning lane is
-               selected by comparing the group's running nonzero count to
-               the residual rank — no take_along_axis anywhere)
-
-    Exact, sorted row-major, ~2 window ops + ~40 lanes of vector work per
-    output slot.  (A Pallas butterfly-pack alternative measured 4-10x
-    slower at R-MAT densities in round 4 and is gone.)
+    Exact, sorted row-major, valid entries a prefix; ``total`` is the
+    nonzero count before any truncation to ``capacity``.
     """
-    from .segment import expand_ranges
-
     R, C = dense.shape
+    cells = R * C
     # fence: without it XLA rematerializes the PRODUCER of `dense` (e.g.
-    # the whole MXU matmul) inside every lax.map step below — measured
-    # 39.8 s vs 1.4 s at scale 14 (round-4 probe)
+    # the whole MXU matmul) inside the passes below
     dense = lax.optimization_barrier(dense)
-    flat = dense.reshape(-1)
-    ncell = R * C
-    assert ncell % 128 == 0, (R, C)
-    nch = ncell // 128
     mask = dense != zero
     if C != ncols:
         mask = mask & (jnp.arange(C, dtype=jnp.int32)[None, :] < ncols)
     if R != nrows:
         mask = mask & (jnp.arange(R, dtype=jnp.int32)[:, None] < nrows)
-    # LAYOUT NOTE (the 16x-padding trap, round-4 probe): XLA:TPU tiles the two
-    # minor dims to (8, 128), so any [N, 16] / [N, 8] intermediate pads
-    # 8-16x — a [nch, 16, 8] view of the mask alone would materialize
-    # 4.3 GB at scale 14.  Group counts therefore come from ONE MXU
-    # matmul on the un-padded [nch, 128] layout, and the only [nch, 16]
-    # arrays are two transients immediately flattened to 1-D tables.
-    # On non-TPU backends the (8, 128) tiling does not exist and the
-    # matmul is the EXPENSIVE op (XLA:CPU has no MXU; an emulated-bf16
-    # dot dominated the windowed-tier extraction profile) — a plain
-    # reshape-sum computes the same [nch, 16] counts as one streaming
-    # pass there.
-    if jax.default_backend() == "tpu":
-        mrow = mask.reshape(nch, 128).astype(jnp.bfloat16)
-        gsel = (
-            lax.broadcasted_iota(jnp.int32, (128, 16), 0) // 8
-            == lax.broadcasted_iota(jnp.int32, (128, 16), 1)
-        ).astype(jnp.bfloat16)
-        t8 = jnp.dot(mrow, gsel, preferred_element_type=jnp.float32)
-        t8 = t8.astype(jnp.int32)  # [nch, 16] group counts (exact: <= 8)
+    mask = mask.reshape(-1)
+    total = jnp.sum(mask, dtype=jnp.int32)
+    key = jnp.where(mask, jnp.arange(cells, dtype=jnp.int32), cells)
+    # the kept cells' keys are distinct: no tie-break operand to carry
+    key, vals = lax.sort(
+        (key, dense.reshape(-1)), num_keys=1, is_stable=False)
+    if capacity <= cells:
+        key, vals = key[:capacity], vals[:capacity]
     else:
-        t8 = jnp.sum(
-            mask.reshape(nch, 16, 8).astype(jnp.int32), axis=-1
-        )  # [nch, 16] group counts (exact: <= 8)
-    g8 = jnp.cumsum(t8, axis=1) - t8  # exclusive group prefix within chunk
-    g8f = g8.reshape(-1)  # flat 1-D table: no lane padding
-    tch = jnp.sum(t8, axis=1)  # [nch] chunk counts
-    g8f, tch = lax.optimization_barrier((g8f, tch))  # same remat fence
-    # output-slot arrays are cap-sized int32 (fine); the [slot, 16]/[slot,
-    # 8] narrowing intermediates are NOT (they pad to [slot, 128]) — so
-    # the narrowing runs as a lax.map over bounded slot chunks.
-    cs = min(1 << 18, max(capacity, 1 << 10))
-    cap_pad = -(-capacity // cs) * cs
-    owner, t, valid, total = expand_ranges(tch, cap_pad)
-    owner = jnp.minimum(owner, nch - 1)
-
-    def narrow(args):
-        owner, t, valid = args
-        # level 1: 16-lane window of the chunk's group prefix
-        w16 = g8f[owner[:, None] * 16
-                  + jnp.arange(16, dtype=jnp.int32)[None, :]]
-        le = w16 <= t[:, None]
-        b = jnp.sum(le, axis=1).astype(jnp.int32) - 1  # group index
-        r8 = t - jnp.max(jnp.where(le, w16, 0), axis=1)  # rank within group
-        # level 2: the group's 8 cells (values + mask) in one window each
-        gbase = (owner * 16 + b) * 8
-        cell = gbase[:, None] + jnp.arange(8, dtype=jnp.int32)[None, :]
-        w8 = flat[cell]
-        m8 = w8 != zero
-        if C != ncols:
-            m8 = m8 & (cell % C < ncols)
-        if R != nrows:
-            m8 = m8 & (cell // C < nrows)
-        m8i = m8.astype(jnp.int32)
-        excl8 = jnp.cumsum(m8i, axis=1) - m8i
-        sel = m8 & (excl8 == r8[:, None])  # exactly one lane per valid slot
-        lane = jnp.sum(
-            jnp.where(sel, jnp.arange(8, dtype=jnp.int32)[None, :], 0), axis=1
-        )
-        vals = jnp.sum(jnp.where(sel, w8, 0), axis=1)
-        fi = gbase + lane
-        rows = jnp.where(valid, fi // C, nrows).astype(jnp.int32)
-        cols = jnp.where(valid, fi % C, ncols).astype(jnp.int32)
-        return rows, cols, jnp.where(valid, vals, 0)
-
-    ncb = cap_pad // cs
-    rows, cols, vals = lax.map(
-        narrow,
-        (owner.reshape(ncb, cs), t.reshape(ncb, cs), valid.reshape(ncb, cs)),
-    )
-    rows = rows.reshape(-1)[:capacity]
-    cols = cols.reshape(-1)[:capacity]
-    vals = vals.reshape(-1)[:capacity]
+        key = jnp.pad(key, (0, capacity - cells), constant_values=cells)
+        vals = jnp.pad(vals, (0, capacity - cells))
+    valid = key < cells
     return (
         SpTuples(
-            rows=rows, cols=cols, vals=vals,
+            rows=jnp.where(valid, key // C, nrows).astype(jnp.int32),
+            cols=jnp.where(valid, key % C, ncols).astype(jnp.int32),
+            vals=jnp.where(valid, vals, 0),
             nnz=jnp.minimum(total, capacity).astype(jnp.int32),
             nrows=nrows, ncols=ncols,
         ),

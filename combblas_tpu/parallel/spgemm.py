@@ -57,6 +57,25 @@ def host_value(x) -> np.ndarray:
     return np.asarray(x)
 
 
+def _publish_opnames(fn, *args, nth: int | None = None, **kw) -> None:
+    """With telemetry on, and once a program (a jitted function, its
+    operands' shapes and its static arguments): publish the compiled
+    program's ``{instruction: op_name}`` table (``obs.opnames``), which
+    is what lets a device trace's operations be read by ``SQ_SCOPES``.
+    Called AFTER the launch, as ``models/tc.py:tc_job`` publishes: the
+    first traced call pays for the program as an untraced one does, and
+    this lowers it again and fetches it from the compile cache.
+    ``nth``: see ``obs.opnames.publish``."""
+    if not obs.ENABLED:
+        return
+    key = (fn.__name__, nth) + tuple(
+        (leaf.shape, str(leaf.dtype)) if hasattr(leaf, "shape") else leaf
+        for leaf in jax.tree.leaves((args, kw))
+    )
+    obs.opnames.publish_once(
+        key, lambda: fn.lower(*args, **kw).compile().as_text(), nth)
+
+
 def _check_compat(A: SpParMat, B: SpParMat):
     """≈ CheckSpGEMMCompliance + ProductGrid (ParFriends.h:161,
     CommGrid.cpp:164)."""
@@ -243,8 +262,10 @@ def summa_spgemm(
         b_mine = B.local_tile(br, bc, bv, bn)
 
         def stage_output(a_stage: SpTuples, b_stage: SpTuples) -> SpTuples:
-            b_csr = CSR.from_tuples(b_stage)
-            return esc_expand(sr, a_stage, b_csr, flop_capacity)
+            with jax.named_scope("sq.densify"):
+                b_csr = CSR.from_tuples(b_stage)
+            with jax.named_scope("sq.dot"):
+                return esc_expand(sr, a_stage, b_csr, flop_capacity)
 
         chunks = []
         if not ring:
@@ -263,18 +284,20 @@ def summa_spgemm(
             for s, a_cur, b_cur in _carousel_stages(a_mine, b_mine, p):
                 chunks.append(stage_output(a_cur, b_cur))
 
-        if merge == "runs":
-            # per-stage sorts + rank-space union: the stage chunks ARE
-            # the sorted runs, so the compact skips its global sort
-            merged = merge_sorted_runs(
-                [ch.sort_rowmajor() for ch in chunks]
-            )
-            out = merged.compact(
-                sr, capacity=out_capacity, assume_sorted=True
-            )
-        else:
-            merged = SpTuples.concat(chunks)
-            out = merged.compact(sr, capacity=out_capacity)
+        with jax.named_scope("sq.extract"):
+            if merge == "runs":
+                # per-stage sorts + rank-space union: the stage chunks
+                # ARE the sorted runs, so the compact skips its global
+                # sort
+                merged = merge_sorted_runs(
+                    [ch.sort_rowmajor() for ch in chunks]
+                )
+                out = merged.compact(
+                    sr, capacity=out_capacity, assume_sorted=True
+                )
+            else:
+                merged = SpTuples.concat(chunks)
+                out = merged.compact(sr, capacity=out_capacity)
         return SpParMat._pack_tile(out)
 
     r, c, v, n = jax.shard_map(
@@ -317,17 +340,19 @@ def summa_stage_flops(A: SpParMat, B: SpParMat, padded: bool = True) -> jax.Arra
         ag_cols = lax.all_gather(a_cols, COL_AXIS)
         bg_rows = lax.all_gather(b_rows, ROW_AXIS)
         per_stage = []
-        for s in range(p):
-            b_valid = bg_rows[s] < lrB
-            blens = jax.ops.segment_sum(
-                b_valid.astype(jnp.int32), bg_rows[s], num_segments=lrB + 1
-            )
-            if padded:
-                blens = -(-blens // CHUNK_W) * CHUNK_W
-            a_valid = ag_rows[s] < A.local_rows
-            k = jnp.minimum(ag_cols[s], lrB)
-            per_entry = jnp.where(a_valid, blens[k], 0)
-            per_stage.append(jnp.sum(per_entry.astype(jnp.float32)))
+        with jax.named_scope("sq.symbolic"):
+            for s in range(p):
+                b_valid = bg_rows[s] < lrB
+                blens = jax.ops.segment_sum(
+                    b_valid.astype(jnp.int32), bg_rows[s],
+                    num_segments=lrB + 1,
+                )
+                if padded:
+                    blens = -(-blens // CHUNK_W) * CHUNK_W
+                a_valid = ag_rows[s] < A.local_rows
+                k = jnp.minimum(ag_cols[s], lrB)
+                per_entry = jnp.where(a_valid, blens[k], 0)
+                per_stage.append(jnp.sum(per_entry.astype(jnp.float32)))
         mine = jnp.stack(per_stage)  # [p]
         # Replicate the (tiny) result so every PROCESS can read it whole —
         # a mesh-sharded output is not host-addressable under multi-host
@@ -495,24 +520,27 @@ def summa_rowblock_flops_pair(
         ag_cols = lax.all_gather(a_cols, COL_AXIS)
         bg_rows = lax.all_gather(b_rows, ROW_AXIS)
         per_stage = []
-        for s in range(p):
-            b_valid = bg_rows[s] < lrB
-            blens = jax.ops.segment_sum(
-                b_valid.astype(jnp.int32), bg_rows[s], num_segments=lrB + 1
-            )
-            blens_pad = -(-blens // chunk_w) * chunk_w
-            a_valid = ag_rows[s] < lrA
-            k = jnp.minimum(ag_cols[s], lrB)
-            g = jnp.where(a_valid, ag_rows[s] // block_rows, nblocks)
-            both = []
-            for bl in (blens_pad, blens):
-                per_entry = jnp.where(a_valid, bl[k], 0).astype(jnp.float32)
-                both.append(
-                    jax.ops.segment_sum(
-                        per_entry, g, num_segments=nblocks + 1
-                    )[:nblocks]
+        with jax.named_scope("sq.symbolic"):
+            for s in range(p):
+                b_valid = bg_rows[s] < lrB
+                blens = jax.ops.segment_sum(
+                    b_valid.astype(jnp.int32), bg_rows[s],
+                    num_segments=lrB + 1,
                 )
-            per_stage.append(jnp.stack(both))  # [2, nblocks]
+                blens_pad = -(-blens // chunk_w) * chunk_w
+                a_valid = ag_rows[s] < lrA
+                k = jnp.minimum(ag_cols[s], lrB)
+                g = jnp.where(a_valid, ag_rows[s] // block_rows, nblocks)
+                both = []
+                for bl in (blens_pad, blens):
+                    per_entry = jnp.where(
+                        a_valid, bl[k], 0).astype(jnp.float32)
+                    both.append(
+                        jax.ops.segment_sum(
+                            per_entry, g, num_segments=nblocks + 1
+                        )[:nblocks]
+                    )
+                per_stage.append(jnp.stack(both))  # [2, nblocks]
         mine = jnp.stack(per_stage)  # [p, 2, nblocks]
         g2 = lax.all_gather(lax.all_gather(mine, COL_AXIS), ROW_AXIS)
         return jnp.transpose(g2, (3, 4, 2, 0, 1))  # [2, nblocks, p, pr, pc]
@@ -649,13 +677,15 @@ def summa_window_flops_pair(
         ag_cols = lax.all_gather(a_cols, COL_AXIS)
         bg_rows = lax.all_gather(b_rows, ROW_AXIS)
         bg_cols = lax.all_gather(b_cols, ROW_AXIS)
-        per_stage = [
-            _window_stage_symbolic(
-                ag_rows[s], ag_cols[s], bg_rows[s], bg_cols[s],
-                lrA, lrB, block_rows, block_cols, nblocks, ncw, chunk_w,
-            )
-            for s in range(p)
-        ]
+        with jax.named_scope("sq.symbolic"):
+            per_stage = [
+                _window_stage_symbolic(
+                    ag_rows[s], ag_cols[s], bg_rows[s], bg_cols[s],
+                    lrA, lrB, block_rows, block_cols, nblocks, ncw,
+                    chunk_w,
+                )
+                for s in range(p)
+            ]
         mine = jnp.stack(per_stage)  # [p, 2, nblocks, ncw]
         g2 = lax.all_gather(lax.all_gather(mine, COL_AXIS), ROW_AXIS)
         # [pr, pc, p, 2, nblocks, ncw] -> [2, nblocks, ncw, p, pr, pc]
@@ -724,11 +754,13 @@ def summa_window_bnnz(B: SpParMat, block_cols: int) -> jax.Array:
 
     def body(br, bc):
         b_rows, b_cols = br[0, 0], bc[0, 0]
-        valid = b_rows < lrB
-        h = jnp.where(valid, b_cols // block_cols, ncw).astype(jnp.int32)
-        mine = jax.ops.segment_sum(
-            valid.astype(jnp.int32), h, num_segments=ncw + 1
-        )[:ncw]
+        with jax.named_scope("sq.symbolic"):
+            valid = b_rows < lrB
+            h = jnp.where(
+                valid, b_cols // block_cols, ncw).astype(jnp.int32)
+            mine = jax.ops.segment_sum(
+                valid.astype(jnp.int32), h, num_segments=ncw + 1
+            )[:ncw]
         g2 = lax.all_gather(lax.all_gather(mine, COL_AXIS), ROW_AXIS)
         return g2  # [pr, pc, ncw]
 
@@ -1713,11 +1745,14 @@ def summa_spgemm_scan(
         worst = jnp.int32(0)
 
         def merge(acc, worst, a_stage, b_stage):
-            chunk = esc_expand(
-                sr, a_stage, CSR.from_tuples(b_stage), flop_capacity
-            )
-            merged = SpTuples.concat([acc, chunk])
-            acc, distinct = merged.compact_counted(sr, capacity=out_capacity)
+            with jax.named_scope("sq.densify"):
+                b_csr = CSR.from_tuples(b_stage)
+            with jax.named_scope("sq.dot"):
+                chunk = esc_expand(sr, a_stage, b_csr, flop_capacity)
+            with jax.named_scope("sq.extract"):
+                merged = SpTuples.concat([acc, chunk])
+                acc, distinct = merged.compact_counted(
+                    sr, capacity=out_capacity)
             return acc, jnp.maximum(worst, distinct - out_capacity)
 
         if not ring:
@@ -1820,13 +1855,21 @@ _PALLAS_KINDS = {
 def _mxu_dot(da, db, mode: str, out_dtype):
     """Dense plus_times stage product at the requested precision.
 
-    Measured on the round-4 machine (not re-measured on today's chip):
-      f32 native dot      ~0.11 TFLOP/s  (exact f32)
-      bf16 inputs         ~13.3 TFLOP/s  (EXACT when inputs are bf16-
-                          representable — e.g. 0/1 adjacency — and the
-                          f32-accumulated counts stay < 2^24)
-      bf16x3 split-float  ~2-4 TFLOP/s   (hi/lo decomposition, error
-                          ~2^-16 per operand — f32-grade for graph work)
+    Measured on the v5e (one [4096, 16384] x [16384, 8192] product of
+    f32 operands, 1.1e12 flop, best of three; my chip run, PR 40):
+      f32 native dot      7.00 ms, 157 TFLOP/s: the DEFAULT precision,
+                          which on this chip is one bf16 pass with an
+                          f32 accumulator, so NOT exact f32 (the same
+                          time as the next line, to the microsecond);
+                          ``Precision.HIGHEST`` measured 36.8 ms, 29.9
+                          TFLOP/s, and no mode here asks for it
+      bf16 inputs         7.02 ms, 157 TFLOP/s (EXACT when inputs are
+                          bf16-representable — e.g. 0/1 adjacency — and
+                          the f32-accumulated counts stay < 2^24); 6.61
+                          ms, 166 TFLOP/s on operands already bf16
+      bf16x3 split-float  24.3 ms, 45 TFLOP/s (hi/lo decomposition,
+                          error ~2^-16 per operand — f32-grade for graph
+                          work)
     """
     if mode == "f32":
         return jnp.dot(da, db, preferred_element_type=out_dtype)
@@ -1863,9 +1906,10 @@ def summa_spgemm_mxu(
 ) -> tuple[SpParMat, jax.Array]:
     """Dense-block SUMMA: stage products run on the MATRIX UNIT.
 
-    On this TPU every sparse-side primitive is capped by the ~22 M/s
-    per-element random-memory wall (round-3 notes) while the MXU delivers
-    13.3 TFLOP/s on bf16 blocks — below ~32K tile dims, spending n³ dense
+    On this TPU every sparse-side primitive pays per element (a
+    scatter-add of 40.7 M int32 into 16,384 bins measured 273 ms, 149 M
+    adds/s; my chip run, PR 40) while the MXU delivers 157 TFLOP/s on
+    bf16 blocks (``_mxu_dot``) — below ~32K tile dims, spending n³ dense
     FLOPs beats sorting the sparse expansion outright: stage tiles densify
     (sorted-scatter), multiply via ``_mxu_dot`` (plus_times; ``mode``
     picks the precision/speed point) or the Pallas semiring matmul
@@ -1906,19 +1950,24 @@ def summa_spgemm_mxu(
         b_stages = _gather_stage_tiles(b_mine, ROW_AXIS, p)
         acc = jnp.full((pm, pn), zero, A.vals.dtype)
         for s in range(p):
-            da = densify(a_stages[s], pm, pk, zero)
-            db = densify(b_stages[s], pk, pn, zero)
-            if kind == "plus_times":
-                prod = _mxu_dot(da, db, mode, acc.dtype)
-            else:
-                # XLA has no MXU/VPU lowering for tropical rings — this is
-                # where the Pallas dense kernel earns its keep
-                prod = semiring_matmul(
-                    kind, da, db, bm=256, bk=512, bn=256,
-                    interpret=interpret,
-                )
-            acc = sr.add(acc, prod)
-        out, total = sparsify_windowed(acc, zero, lrA, lcB, out_capacity)
+            with jax.named_scope("sq.densify"):
+                da = densify(a_stages[s], pm, pk, zero)
+                db = densify(b_stages[s], pk, pn, zero)
+            with jax.named_scope("sq.dot"):
+                if kind == "plus_times":
+                    prod = _mxu_dot(da, db, mode, acc.dtype)
+                else:
+                    # XLA has no MXU/VPU lowering for tropical rings —
+                    # this is where the Pallas dense kernel earns its
+                    # keep
+                    prod = semiring_matmul(
+                        kind, da, db, bm=256, bk=512, bn=256,
+                        interpret=interpret,
+                    )
+                acc = sr.add(acc, prod)
+        with jax.named_scope("sq.extract"):
+            out, total = sparsify_windowed(
+                acc, zero, lrA, lcB, out_capacity)
         worst = jnp.maximum(total - out_capacity, 0)
         worst = lax.pmax(lax.pmax(worst, ROW_AXIS), COL_AXIS)
         return SpParMat._pack_tile(out) + (worst[None, None],)
@@ -1937,12 +1986,15 @@ def summa_spgemm_mxu(
     return mat, overflow[0, 0]
 
 
-#: Above this local tile dimension the dense path loses: not to the
-#: matmul (13.3 TFLOP/s bf16 — scale-14 tiles square in 0.7 s) but to the
-#: sparse-output EXTRACTION, which is point-gather/padding-bound at ~3 s+
-#: per 20M entries on the target chip (the full nine-design floor
-#: analysis was round 4's).  The sort-based
-#: kernels take over beyond it.
+#: Above this local tile dimension the router leaves the whole-tile
+#: dense path.  The threshold was set on a machine that is gone, where
+#: the sparse-output EXTRACTION was said to cost "~3 s+ per 20M
+#: entries"; on the v5e the matmul runs at 157 TFLOP/s (``_mxu_dot``:
+#: a scale-14 tile squares in 56 ms) and the extraction, one sort of a
+#: window's cells since PR 40, at 116 ms a [4096, 8192] window
+#: (``ops/spgemm.py:sparsify_windowed``; my chip runs, PR 40).  Not
+#: re-derived here: moving it is a ``perf_opt`` issue's, with the cell
+#: to show it.
 MXU_MAX_TILE_DIM = 8192
 
 
@@ -2046,22 +2098,26 @@ def _windowed_block_local(
     lrA, lcB = a.nrows, b_csr.ncols
     pcols = -(-lcB // 128) * 128
     zero = sr.zero(a.vals.dtype)
-    am = mask_rows(a, lo, lo + rb)
-    acc = jnp.full((rb, pcols), zero, a.vals.dtype)
-    acc = accumulate_block_scatter(
-        sr, acc, am, b_csr, row_lo=lo, flop_capacity=flop_cap,
-        chunk_w=chunk_w,
-    )
-    t, total = sparsify_windowed(
-        acc, float(np.asarray(sr.zero_fn(a.vals.dtype))), rb, lcB, out_cap
-    )
-    rows = jnp.where(t.valid_mask(), t.rows + lo, lrA)
+    with jax.named_scope("sq.dot"):
+        am = mask_rows(a, lo, lo + rb)
+        acc = jnp.full((rb, pcols), zero, a.vals.dtype)
+        acc = accumulate_block_scatter(
+            sr, acc, am, b_csr, row_lo=lo, flop_capacity=flop_cap,
+            chunk_w=chunk_w,
+        )
+    with jax.named_scope("sq.extract"):
+        t, total = sparsify_windowed(
+            acc, float(np.asarray(sr.zero_fn(a.vals.dtype))), rb, lcB,
+            out_cap,
+        )
+        rows = jnp.where(t.valid_mask(), t.rows + lo, lrA)
     return rows, t.cols, t.vals, t.nnz, total
 
 
 @jax.jit
 def _local_csr(t: SpTuples) -> CSR:
-    return CSR.from_tuples(t)
+    with jax.named_scope("sq.densify"):
+        return CSR.from_tuples(t)
 
 
 @partial(jax.jit, static_argnames=("block_cols",))
@@ -2069,14 +2125,15 @@ def _colmajor_with_starts(t: SpTuples, block_cols: int):
     """Col-major-sorted tile + per-window CSC slot starts (the panel
     slicing preamble of the 2D dot backend, hoisted out of the per-block
     programs on the local fast path)."""
-    ts = t.sort_colmajor()
-    ncw = -(-t.ncols // block_cols)
-    bounds = jnp.minimum(
-        jnp.arange(ncw + 1, dtype=jnp.int32) * block_cols, t.ncols
-    )
-    starts = jnp.searchsorted(ts.cols, bounds, side="left").astype(
-        jnp.int32
-    )
+    with jax.named_scope("sq.densify"):
+        ts = t.sort_colmajor()
+        ncw = -(-t.ncols // block_cols)
+        bounds = jnp.minimum(
+            jnp.arange(ncw + 1, dtype=jnp.int32) * block_cols, t.ncols
+        )
+        starts = jnp.searchsorted(ts.cols, bounds, side="left").astype(
+            jnp.int32
+        )
     return ts, starts
 
 
@@ -2114,24 +2171,31 @@ def _windowed_block_local_dot(
     kind = _PALLAS_KINDS[sr.name]
     arows = _pad128(rb)
     zero = float(np.asarray(sr.zero_fn(a.vals.dtype)))
-    am = mask_rows(a, lo, lo + rb)
-    da = densify_combine(sr, _shift_rowblock(am, lo, arows), arows, pk)
+    with jax.named_scope("sq.densify"):
+        am = mask_rows(a, lo, lo + rb)
+        da = densify_combine(
+            sr, _shift_rowblock(am, lo, arows), arows, pk)
     rows_l, cols_l, vals_l = [], [], []
     nnz = jnp.int32(0)
     worst = jnp.int32(0)
     for h in packed_windows(skip_row):  # packed launch list
-        panel = _dense_col_panel(
-            sr, bs, b_starts, h, block_cols, pk, pwin, panel_cap
-        )
-        prod = _window_stage_product(sr, kind, da, panel, mode, interpret)
+        with jax.named_scope("sq.densify"):
+            panel = _dense_col_panel(
+                sr, bs, b_starts, h, block_cols, pk, pwin, panel_cap
+            )
+        with jax.named_scope("sq.dot"):
+            prod = _window_stage_product(
+                sr, kind, da, panel, mode, interpret)
         wc = min(block_cols, lcB - h * block_cols)
-        t, total = sparsify_windowed(prod, zero, rb, wc, out_caps_row[h])
-        worst = jnp.maximum(worst, total - out_caps_row[h])
-        vm = t.valid_mask()
-        rows_l.append(jnp.where(vm, t.rows + lo, lrA))
-        cols_l.append(jnp.where(vm, t.cols + h * block_cols, lcB))
-        vals_l.append(t.vals)
-        nnz = nnz + t.nnz
+        with jax.named_scope("sq.extract"):
+            t, total = sparsify_windowed(
+                prod, zero, rb, wc, out_caps_row[h])
+            worst = jnp.maximum(worst, total - out_caps_row[h])
+            vm = t.valid_mask()
+            rows_l.append(jnp.where(vm, t.rows + lo, lrA))
+            cols_l.append(jnp.where(vm, t.cols + h * block_cols, lcB))
+            vals_l.append(t.vals)
+            nnz = nnz + t.nnz
     return (
         jnp.concatenate(rows_l), jnp.concatenate(cols_l),
         jnp.concatenate(vals_l), nnz, worst,
@@ -2179,11 +2243,13 @@ def local_spgemm_windowed(
     if backend == "dot":
         assert block_cols is not None and panel_cap is not None
         bs, b_starts = _colmajor_with_starts(bt, block_cols)
+        _publish_opnames(_colmajor_with_starts, bt, block_cols)
         pk = _pad128(B.local_rows)
         pwin = _pad128(block_cols)
     else:
         assert backend == "scatter", backend
         b_csr = _local_csr(bt)
+        _publish_opnames(_local_csr, bt)
     rows_l, cols_l, vals_l = [], [], []
     nnz = None
     worst = jnp.int32(0)
@@ -2193,22 +2259,29 @@ def local_spgemm_windowed(
         lo = g * block_rows
         rb = min(block_rows, lrA - lo)
         if backend == "dot":
-            r, c, v, nz, over = _windowed_block_local_dot(
-                sr, a, bs, b_starts, jnp.int32(lo), rb=rb,
-                out_caps_row=oc, skip_row=sk, block_cols=block_cols,
+            kw = dict(
+                rb=rb, out_caps_row=oc, skip_row=sk, block_cols=block_cols,
                 pk=pk, pwin=pwin, panel_cap=panel_cap, mode=mode,
                 interpret=interpret,
             )
+            r, c, v, nz, over = _windowed_block_local_dot(
+                sr, a, bs, b_starts, jnp.int32(lo), **kw)
+            _publish_opnames(
+                _windowed_block_local_dot, sr, a, bs, b_starts,
+                jnp.int32(lo), nth=len(rows_l), **kw)
             rows_l.append(r)
             cols_l.append(c)
             vals_l.append(v)
             nnz = nz if nnz is None else nnz + nz
             worst = jnp.maximum(worst, over)
             continue
+        kw = dict(rb=rb, flop_cap=max(fc, chunk_w), out_cap=oc,
+                  chunk_w=chunk_w)
         r, c, v, nz, total = _windowed_block_local(
-            sr, a, b_csr, jnp.int32(lo), rb=rb,
-            flop_cap=max(fc, chunk_w), out_cap=oc, chunk_w=chunk_w,
-        )
+            sr, a, b_csr, jnp.int32(lo), **kw)
+        _publish_opnames(
+            _windowed_block_local, sr, a, b_csr, jnp.int32(lo),
+            nth=len(rows_l), **kw)
         rows_l.append(r)
         cols_l.append(c)
         vals_l.append(v)
@@ -2443,64 +2516,62 @@ def _oracle_out_caps_2d(
     return tuple(new_caps), tuple(new_skip)
 
 
-def spgemm_windowed(
+@dataclasses.dataclass(frozen=True)
+class WindowedPlan:
+    """What one symbolic pass decided for the windowed tier: the window
+    geometry, the per-window static capacities and the skip list
+    (``windowed_plan`` for ``scatter``: 1D tuples; ``windowed_plan_2d``
+    for ``dot``: a tuple of per-block tuples, plus the B panel's slice
+    capacity).  ``per_true`` keeps the true symbolic counts the plan
+    was made from (the density gauges read them).  The same operands
+    give the same plan, so a repeated product compiles nothing."""
+
+    backend: str
+    block_rows: int
+    block_cols: int | None
+    flop_caps: tuple
+    out_caps: tuple
+    skip: tuple
+    panel_cap: int | None
+    per_true: np.ndarray
+
+    def chunk_caps(self) -> tuple[int, ...]:
+        """The output capacities of the launched windows, in the order
+        the kernels lay their chunks in the result: row blocks
+        (``scatter``), or (row block, col window) pairs block-major
+        (``dot``)."""
+        if self.backend == "dot":
+            return tuple(
+                self.out_caps[g][h] for g, h in packed_windows_2d(self.skip))
+        return tuple(self.out_caps[g] for g in packed_windows(self.skip))
+
+    def windows(self) -> tuple[int, int]:
+        """``(launched, skipped)`` windows."""
+        launched = len(self.chunk_caps())
+        total = sum(
+            len(row) if self.backend == "dot" else 1 for row in self.skip)
+        return launched, total - launched
+
+
+def plan_windowed(
     sr: Semiring,
     A: SpParMat,
     B: SpParMat,
     *,
+    backend: str,
     block_rows: int | None = None,
     block_cols: int | None = None,
-    backend: str | None = None,
-    mode: str = "f32",
     slack: float = 1.02,
-    interpret: bool = False,
     oracle: bool = False,
-    ring: bool = False,
-    pipeline: bool = True,
-    dispatch: str | None = None,
-) -> SpParMat:
-    """Sized entry for the windowed tier: device symbolic pass →
-    ``windowed_plan`` (scatter, 1D) or ``windowed_plan_2d`` (dot, 2D) →
-    the matching kernel (one host readback for sizing; benchmarks on
-    readback-poisoned hardware size on host via
-    ``summa_rowblock_flops_host`` / ``summa_window_flops_host`` +
-    ``summa_window_bnnz_host`` instead).
-
-    ``dispatch`` (argument > env ``COMBBLAS_SPGEMM_DISPATCH`` >
-    ``"auto"``) picks the multi-device program decomposition for the
-    scatter backend: ``"auto"`` (default) routes any product with more
-    than one occupied row block through the BLOCKED building-block
-    dispatch (``summa_spgemm_windowed_blocked`` — one small fixed-shape
-    program per occupied block, caps pow2-bucketed so blocks share
-    compiles), which bounds both first-touch compile time and the live
-    set: no single XLA compile scales with the whole product (the
-    scale-17 54-minute fused-compile wall cannot recur).  ``"fused"``
-    forces the one-graph kernel (required by — and implied for — the
-    ``ring`` carousel schedules); ``"blocked"`` forces per-block
-    programs.  Single-device products already run per-block programs
-    (``local_spgemm_windowed``); the dot backend's multi-device path
-    has no blocked kernel yet and stays fused.
-
-    ``oracle=True`` (dot, single device, inside the support-oracle
-    envelope) replaces the clamped-flops out caps with the EXACT
-    per-window output counts from the bit-packed support oracle — which
-    also SHRINKS the packed launch list: flops-positive but
-    output-empty windows become skips, so the kernel pays one MXU
-    launch per genuinely occupied window
-    (``spgemm.windowed.windows_packed`` / ``.pack_ratio``).
-
-    ``ring=True`` (multi-device only) runs the stage-pipelined carousel
-    schedule instead of the gathered one; ``pipeline=False`` pins the
-    serial-chain control (see ``summa_spgemm_windowed``).
-    """
-    from ..tuner import config as tuner_config
-
-    backend = resolve_spgemm_backend(backend)
-    dispatch = tuner_config.resolve_dispatch(dispatch)
-    bucket = tuner_config.bucket_caps_enabled()
+    bucket_caps: bool = True,
+) -> WindowedPlan:
+    """The windowed tier's symbolic pass: device counts, read back to
+    the host and turned into a ``WindowedPlan`` (every host readback of
+    the tier's sizing is in here; ``run_windowed`` then launches only).
+    Reads no environment variable: ``spgemm_windowed`` resolves those
+    and passes them on."""
     if block_rows is None:
         block_rows = default_block_rows(A.local_rows, B.local_cols)
-    chunk_w = WINDOWED_CHUNK_W
     if backend == "dot":
         if block_cols is None:
             block_cols = default_block_cols(B.local_rows, B.local_cols)
@@ -2512,6 +2583,9 @@ def spgemm_windowed(
                 A, B, block_rows, block_cols, chunk_w=1
             )
         )
+        _publish_opnames(
+            summa_window_flops_pair, A, B, block_rows, block_cols,
+            chunk_w=1)
         pt = pair[1]
         flop_caps, out_caps, skip = windowed_plan_2d(
             None, pt, block_rows, block_cols,
@@ -2535,7 +2609,7 @@ def spgemm_windowed(
                 # clamped-flops caps, observably (never silently)
                 if obs.ENABLED:
                     obs.count("spgemm.windowed.oracle_skipped")
-        if bucket:
+        if bucket_caps:
             # pow2 caps AFTER oracle tightening: the bucket keeps the
             # compile-sharing property, the oracle keeps the skips;
             # then re-impose the dense-window bound the round may have
@@ -2559,6 +2633,67 @@ def spgemm_windowed(
             host_value(summa_window_bnnz(B, block_cols)),
             int(B.capacity),
         )
+        _publish_opnames(summa_window_bnnz, B, block_cols)
+        return WindowedPlan(
+            "dot", block_rows, block_cols, flop_caps, out_caps, skip,
+            panel_cap, np.asarray(pt),
+        )
+    assert backend == "scatter", backend
+    # one symbolic pass yields both the padded (expansion-capacity) and
+    # true (output-bound) counts
+    pair = host_value(
+        summa_rowblock_flops_pair(
+            A, B, block_rows, chunk_w=WINDOWED_CHUNK_W
+        )
+    )
+    _publish_opnames(
+        summa_rowblock_flops_pair, A, B, block_rows,
+        chunk_w=WINDOWED_CHUNK_W)
+    pb, pt = pair[0], pair[1]
+    flop_caps, out_caps, skip = windowed_plan(
+        pb, pt, block_rows, A.local_rows, B.local_cols, slack=slack
+    )
+    if bucket_caps:
+        flop_caps, out_caps = bucket_plan_caps(flop_caps, out_caps)
+        # dense-block bound re-imposed after the pow2 round (tail
+        # blocks: rb * lcB may not be a power of two)
+        out_caps = tuple(
+            min(
+                oc,
+                max(min(block_rows, A.local_rows - g * block_rows), 1)
+                * B.local_cols,
+            )
+            for g, oc in enumerate(out_caps)
+        )
+    return WindowedPlan(
+        "scatter", block_rows, None, flop_caps, out_caps, skip, None,
+        np.asarray(pt),
+    )
+
+
+def run_windowed(
+    sr: Semiring,
+    A: SpParMat,
+    B: SpParMat,
+    plan: WindowedPlan,
+    *,
+    mode: str = "f32",
+    interpret: bool = False,
+    ring: bool = False,
+    pipeline: bool = True,
+    dispatch: str = "auto",
+) -> SpParMat:
+    """The windowed tier's numeric phase under a ``WindowedPlan``: the
+    kernel the backend, the grid and ``dispatch`` select, closed by the
+    host's read of the overflow flag (the plan's caps are symbolic
+    UPPER bounds, so an overflow is a bug, never a retry)."""
+    backend, block_rows, block_cols = (
+        plan.backend, plan.block_rows, plan.block_cols
+    )
+    flop_caps, out_caps, skip = plan.flop_caps, plan.out_caps, plan.skip
+    pt = plan.per_true
+    chunk_w = WINDOWED_CHUNK_W
+    if backend == "dot":
         if obs.ENABLED:
             obs.count(
                 "spgemm.windowed.dispatch",
@@ -2608,15 +2743,15 @@ def spgemm_windowed(
             C, overflow = local_spgemm_windowed(
                 sr, A, B, block_rows=block_rows, flop_caps=flop_caps,
                 out_caps=out_caps, skip=skip, backend="dot",
-                block_cols=block_cols, panel_cap=panel_cap, mode=mode,
-                interpret=interpret,
+                block_cols=block_cols, panel_cap=plan.panel_cap,
+                mode=mode, interpret=interpret,
             )
         else:
             C, overflow = summa_spgemm_windowed(
                 sr, A, B, block_rows=block_rows, flop_caps=flop_caps,
                 out_caps=out_caps, skip=skip, backend="dot", mode=mode,
                 chunk_w=chunk_w, interpret=interpret,
-                block_cols=block_cols, panel_cap=panel_cap,
+                block_cols=block_cols, panel_cap=plan.panel_cap,
                 ring=ring, pipeline=pipeline,
             )
         over = int(overflow)
@@ -2625,27 +2760,6 @@ def spgemm_windowed(
         )
         _record_realized_nnz(C)
         return C
-    # one symbolic pass yields both the padded (expansion-capacity) and
-    # true (output-bound) counts
-    pair = host_value(
-        summa_rowblock_flops_pair(A, B, block_rows, chunk_w=chunk_w)
-    )
-    pb, pt = pair[0], pair[1]
-    flop_caps, out_caps, skip = windowed_plan(
-        pb, pt, block_rows, A.local_rows, B.local_cols, slack=slack
-    )
-    if bucket:
-        flop_caps, out_caps = bucket_plan_caps(flop_caps, out_caps)
-        # dense-block bound re-imposed after the pow2 round (tail
-        # blocks: rb * lcB may not be a power of two)
-        out_caps = tuple(
-            min(
-                oc,
-                max(min(block_rows, A.local_rows - g * block_rows), 1)
-                * B.local_cols,
-            )
-            for g, oc in enumerate(out_caps)
-        )
     # the building-block decomposition rule (round 10): any distributed
     # scatter product with >1 occupied block defaults to per-block
     # programs — the ring carousel is a fused-only schedule, so a ring
@@ -2720,6 +2834,70 @@ def spgemm_windowed(
     assert over <= 0, f"windowed tier overflowed its symbolic bound by {over}"
     _record_realized_nnz(C)
     return C
+
+
+def spgemm_windowed(
+    sr: Semiring,
+    A: SpParMat,
+    B: SpParMat,
+    *,
+    block_rows: int | None = None,
+    block_cols: int | None = None,
+    backend: str | None = None,
+    mode: str = "f32",
+    slack: float = 1.02,
+    interpret: bool = False,
+    oracle: bool = False,
+    ring: bool = False,
+    pipeline: bool = True,
+    dispatch: str | None = None,
+) -> SpParMat:
+    """Sized entry for the windowed tier: device symbolic pass →
+    ``windowed_plan`` (scatter, 1D) or ``windowed_plan_2d`` (dot, 2D) →
+    the matching kernel (one host readback for sizing; benchmarks on
+    readback-poisoned hardware size on host via
+    ``summa_rowblock_flops_host`` / ``summa_window_flops_host`` +
+    ``summa_window_bnnz_host`` instead).
+
+    ``dispatch`` (argument > env ``COMBBLAS_SPGEMM_DISPATCH`` >
+    ``"auto"``) picks the multi-device program decomposition for the
+    scatter backend: ``"auto"`` (default) routes any product with more
+    than one occupied row block through the BLOCKED building-block
+    dispatch (``summa_spgemm_windowed_blocked`` — one small fixed-shape
+    program per occupied block, caps pow2-bucketed so blocks share
+    compiles), which bounds both first-touch compile time and the live
+    set: no single XLA compile scales with the whole product (the
+    scale-17 54-minute fused-compile wall cannot recur).  ``"fused"``
+    forces the one-graph kernel (required by — and implied for — the
+    ``ring`` carousel schedules); ``"blocked"`` forces per-block
+    programs.  Single-device products already run per-block programs
+    (``local_spgemm_windowed``); the dot backend's multi-device path
+    has no blocked kernel yet and stays fused.
+
+    ``oracle=True`` (dot, single device, inside the support-oracle
+    envelope) replaces the clamped-flops out caps with the EXACT
+    per-window output counts from the bit-packed support oracle — which
+    also SHRINKS the packed launch list: flops-positive but
+    output-empty windows become skips, so the kernel pays one MXU
+    launch per genuinely occupied window
+    (``spgemm.windowed.windows_packed`` / ``.pack_ratio``).
+
+    ``ring=True`` (multi-device only) runs the stage-pipelined carousel
+    schedule instead of the gathered one; ``pipeline=False`` pins the
+    serial-chain control (see ``summa_spgemm_windowed``).
+    """
+    from ..tuner import config as tuner_config
+
+    plan = plan_windowed(
+        sr, A, B, backend=resolve_spgemm_backend(backend),
+        block_rows=block_rows, block_cols=block_cols, slack=slack,
+        oracle=oracle, bucket_caps=tuner_config.bucket_caps_enabled(),
+    )
+    return run_windowed(
+        sr, A, B, plan, mode=mode, interpret=interpret, ring=ring,
+        pipeline=pipeline,
+        dispatch=tuner_config.resolve_dispatch(dispatch),
+    )
 
 
 def coo_has_duplicates(M: SpParMat) -> bool:
@@ -3173,3 +3351,299 @@ def spgemm_auto(
             f"spgemm_auto still overflowing by {over} after {max_retries} "
             "retries; pass an explicit out_capacity"
         )
+
+
+# ---------------------------------------------------------------------------
+# One product as one job
+# ---------------------------------------------------------------------------
+
+#: The ``jax.named_scope`` names of a product job's programs, in the
+#: order a job meets them.  ``sq.symbolic`` is the counting pass whose
+#: result the host reads to size the numeric phase; ``sq.densify`` the
+#: scatter of a row block of A and of a column panel of B into dense
+#: operands; ``sq.dot`` the stage product on the matrix unit;
+#: ``sq.extract`` the walk from a dense product back to tuples
+#: (``ops/spgemm.py:sparsify_windowed``) and the sort-and-fold of the
+#: tiers that never densify; ``sq.digest`` the job's last program.
+#: Trace-time metadata only: the device trace's per-scope times are read
+#: by these names (docs/observability.md "Named scopes"), so a rename is
+#: a change of yardstick.
+SQ_SCOPES = (
+    "sq.symbolic",
+    "sq.densify",
+    "sq.dot",
+    "sq.extract",
+    "sq.digest",
+)
+
+#: The tiers a job can name, and the accumulate backend a job runs under
+#: when none is given: the chip's (``resolve_spgemm_backend``'s platform
+#: default on a TPU), on every platform, so a CPU rehearsal runs the
+#: chip's program.
+JOB_TIERS = ("mxu", "windowed", "scan", "esc")
+JOB_BACKEND = "dot"
+
+#: ``h(j) = (j + 1) * DIGEST_MULTIPLIER mod 2^32``: the odd multiplier
+#: of the digest's column hash (the golden-ratio constant of Knuth's
+#: multiplicative hashing).
+DIGEST_MULTIPLIER = 0x9E3779B1
+
+
+@jax.jit
+def spgemm_digest(C: SpParMat):
+    """What closes a product job, from the stored tuples of ``C`` and on
+    the device: ``(nnz, hilo, counts, sums, prints)``.
+
+    ``counts[i]`` is the number of stored entries of row ``i``,
+    ``sums[i]`` their sum and ``prints[i]`` the fingerprint
+    ``sum_j C[i, j] * h(j)`` in wrapping uint32 arithmetic (carried as
+    int32 bits), ``h`` the fixed odd-multiplier hash of the GLOBAL
+    column (``DIGEST_MULTIPLIER``); all three are ``int32[nrows]``,
+    replicated.  Values enter as ``int32(C[i, j])``: exact for the
+    integer-valued products a digest is for.  ``nnz`` is the stored
+    entries in all and ``hilo`` the ``int32[2]`` 15-bit (hi, lo) split
+    of the sum of C (``ops/spgemm.py:combine_hilo``), exact while every
+    row's sum is below 2^31 and the whole below 2^46.  An entry
+    changed moves a sum and a fingerprint; one dropped a count; one
+    moved along its row the fingerprint alone, since ``h`` is
+    injective on columns."""
+    grid = C.grid
+    lr, lc = C.local_rows, C.local_cols
+
+    def body(r, c, v):
+        rows, cols, vals = r[0, 0], c[0, 0], v[0, 0]
+        with jax.named_scope("sq.digest"):
+            valid = rows < lr
+            seg = jnp.where(valid, rows, lr)
+            vi = jnp.where(valid, vals.astype(jnp.int32), 0)
+            gcol = cols + lax.axis_index(COL_AXIS) * lc
+            h = (gcol.astype(jnp.uint32) + jnp.uint32(1)) * jnp.uint32(
+                DIGEST_MULTIPLIER)
+            fp = lax.bitcast_convert_type(
+                vi.astype(jnp.uint32) * h, jnp.int32)
+            # by rows with one sort, two running sums and a search of
+            # the sorted rows for every row's end: on the v5e a sort of
+            # 33.5 M keys is 81 ms and a running sum 8 ms, where one
+            # scatter-add of 40.7 M entries into their rows is 273 ms
+            # (the digest as three of those was 1.09 s of a 2.29 s job;
+            # my chip runs, PR 40)
+            seg, vi, fp = lax.sort(  # sums: any order inside a row
+                (seg, vi, fp), num_keys=1, is_stable=False)
+            ends = jnp.searchsorted(
+                seg, jnp.arange(lr, dtype=jnp.int32), side="right"
+            ).astype(jnp.int32)
+            starts = jnp.concatenate([jnp.zeros((1,), jnp.int32), ends[:-1]])
+
+            def by_row(w):  # int32 adds wrap, in the running sum too
+                run = jnp.concatenate(
+                    [jnp.zeros((1,), jnp.int32), jnp.cumsum(w)])
+                return run[ends] - run[starts]
+
+            counts, sums, prints = (
+                lax.all_gather(
+                    lax.psum(w, COL_AXIS), ROW_AXIS
+                ).reshape(-1)[:C.nrows]
+                for w in (ends - starts, by_row(vi), by_row(fp))
+            )
+            # the sum of C from the row sums, in 15-bit halves folded
+            # 2^15 rows at a time so no partial sum passes 2^31
+            pad = -C.nrows % (1 << 15)
+            s2 = jnp.pad(sums, (0, pad)).reshape(-1, 1 << 15)
+            lo = jnp.sum(s2 & 0x7FFF, axis=1)
+            hi = jnp.sum(s2 >> 15) + jnp.sum(lo >> 15)
+            hilo = jnp.stack([hi, jnp.sum(lo & 0x7FFF)])
+            return jnp.sum(counts), hilo, counts, sums, prints
+
+    return jax.shard_map(
+        body,
+        mesh=grid.mesh,
+        in_specs=(TILE_SPEC,) * 3,
+        out_specs=P(),
+        check_vma=False,
+    )(C.rows, C.cols, C.vals)
+
+
+@partial(jax.jit, static_argnames=("caps",))
+def _chunk_counts(C: SpParMat, caps: tuple):
+    """Stored entries of each chunk of a one-tile result, and whether
+    every chunk holds them as a prefix (``caps``: the chunks' static
+    capacities, in the order the tier laid them)."""
+    rows, lr = C.rows[0, 0], C.local_rows
+    counts, ok, off = [], jnp.bool_(True), 0
+    with jax.named_scope("sq.extract"):
+        for cap in caps:
+            valid = rows[off:off + cap] < lr
+            n = jnp.sum(valid, dtype=jnp.int32)
+            counts.append(n)
+            ok &= ~jnp.any(
+                valid & (jnp.arange(cap, dtype=jnp.int32) >= n))
+            off += cap
+    return jnp.stack(counts), ok
+
+
+@partial(jax.jit, static_argnames=("caps", "counts"))
+def _pack_chunks(C: SpParMat, caps: tuple, counts: tuple) -> SpParMat:
+    """A one-tile result whose chunks hold their stored entries as
+    prefixes, with the padding between them taken out: static slices
+    and one concatenation, capacity = the stored entries."""
+    offs = np.concatenate([[0], np.cumsum(caps)[:-1]])
+    with jax.named_scope("sq.extract"):
+        rows, cols, vals = (
+            jnp.concatenate([
+                x[0, 0, o:o + n] for o, n in zip(offs, counts) if n
+            ])[None, None]
+            for x in (C.rows, C.cols, C.vals)
+        )
+    return dataclasses.replace(C, rows=rows, cols=cols, vals=vals)
+
+
+def _packed(C: SpParMat, caps: tuple) -> SpParMat:
+    """A tier's capacity-padded one-tile result cut to what it stores.
+    Every tier sizes its output by a symbolic UPPER bound (the windowed
+    tier a window at a time: on a squared R-MAT the bounds add up to the
+    dense matrix), and whatever reads the result next pays for its
+    capacity, not for its entries.  One small readback (the chunks'
+    counts), then slices at what the host now knows."""
+    counts, ok = jax.device_get(_chunk_counts(C, caps))
+    _publish_opnames(_chunk_counts, C, caps)
+    assert ok, "a tier's chunk does not hold its entries as a prefix"
+    counts = tuple(int(n) for n in counts)
+    if not sum(counts):
+        return C
+    out = _pack_chunks(C, caps, counts)
+    _publish_opnames(_pack_chunks, C, caps, counts)
+    return out
+
+
+def spgemm_job(
+    sr: Semiring,
+    A: SpParMat,
+    B: SpParMat,
+    *,
+    tier: str | None = None,
+    backend: str = JOB_BACKEND,
+    mode: str = "f32",
+    block_rows: int | None = None,
+    block_cols: int | None = None,
+) -> tuple[SpParMat, dict]:
+    """One whole product ``C = A·B`` as an analyst's call times it: from
+    the stored operands to C on the device and its digest on the host,
+    nothing known beforehand and nothing kept from job to job.  The
+    symbolic pass is inside the job; so is the routing where ``tier``
+    is None: ``choose_tier_from_counts``'s rule under ``backend``,
+    which defaults to the chip's (``JOB_BACKEND``) on every platform.
+    Nothing else decides: no environment variable, no plan store, no
+    probe; no retry either: every capacity is a symbolic upper bound,
+    so a job runs its numeric phase once (an overflow is an
+    ``AssertionError``, not a doubling).  The same operands give the
+    same static shapes, so a repeated job compiles nothing.
+
+    Returns ``(C, digest)``.  C stays on the device; on one device it
+    is cut to what it stores (``_packed``: the tiers return tiles padded
+    to their symbolic bounds, the windowed tier's add up to the dense
+    matrix on a squared R-MAT, and the digest, like any next step, pays
+    for capacity).  ``digest`` is
+    ``spgemm_digest(C)`` read by the host, which closes the job:
+    ``nnz`` and ``sum`` (Python ints), ``counts`` / ``sums`` /
+    ``prints`` (``int32[nrows]`` numpy), with the ``tier`` and
+    ``backend`` the job ran.  It comes back with telemetry off.
+
+    ``mode`` is the dense stage product's input pass (``_mxu_dot``);
+    ``block_rows`` / ``block_cols`` override the windowed tier's
+    geometry (tests run several windows on a small matrix)."""
+    from ..ops.spgemm import combine_hilo
+
+    assert backend in ("dot", "scatter"), backend
+    with obs.span("spgemm.job", sr=sr.name, backend=backend) as job:
+        with obs.span("symbolic"):
+            per_stage = host_value(
+                summa_stage_flops(A, B, padded=False)
+            ).astype(np.float64)
+            _publish_opnames(summa_stage_flops, A, B, padded=False)
+            products = float(per_stage.sum())
+            if tier is None:
+                tier = choose_tier_from_counts(
+                    sr, max(A.local_rows, A.local_cols, B.local_cols),
+                    A.local_rows * B.local_cols, A.grid.pr, products,
+                    backend, k_dim=B.local_rows, n_dim=B.local_cols,
+                    allow_mxu=not (
+                        coo_has_duplicates(A)
+                        or (B is not A and coo_has_duplicates(B))
+                    ),
+                )
+            assert tier in JOB_TIERS, tier
+            dense_tile = A.local_rows * B.local_cols
+            plan, windows, skipped, dense_flops = None, 0, 0, 0
+            if tier == "windowed":
+                plan = plan_windowed(
+                    sr, A, B, backend=backend, block_rows=block_rows,
+                    block_cols=block_cols,
+                )
+                windows, skipped = plan.windows()
+                if backend == "dot":
+                    # two flop a cell of every launched window's padded
+                    # row block x contraction x col window
+                    dense_flops = 2 * _pad128(B.local_rows) * _pad128(
+                        plan.block_cols) * sum(
+                        _pad128(min(plan.block_rows,
+                                    A.local_rows - g * plan.block_rows))
+                        for g, _ in packed_windows_2d(plan.skip))
+            elif tier in ("scan", "esc"):
+                flop_cap, out_cap = summa_capacities(A, B)
+                _publish_opnames(summa_stage_flops, A, B)
+            else:
+                # one output a flop at most, and no more than the tile
+                out_cap = _caps_from_stage_flops(
+                    per_stage, dense_tile, 1.02)[1]
+                dense_flops = 2 * A.grid.pr * _pad128(
+                    A.local_rows) * _pad128(A.local_cols) * _pad128(
+                    B.local_cols)
+            job.annotate(tier=tier)
+        with obs.span("numeric"):
+            if tier == "windowed":
+                C = run_windowed(sr, A, B, plan, mode=mode)
+                caps = plan.chunk_caps()
+            else:
+                if tier == "esc":
+                    # pow2 as ``spgemm`` rounds them
+                    flop_cap = 1 << (flop_cap - 1).bit_length()
+                    out_cap = min(
+                        1 << (out_cap - 1).bit_length(),
+                        max(dense_tile, 1))
+                    fn, kw = summa_spgemm, dict(
+                        flop_capacity=flop_cap, out_capacity=out_cap,
+                        merge="sort")
+                elif tier == "scan":
+                    fn, kw = summa_spgemm_scan, dict(
+                        flop_capacity=flop_cap, out_capacity=out_cap)
+                else:
+                    fn, kw = summa_spgemm_mxu, dict(
+                        out_capacity=out_cap, mode=mode)
+                out = fn(sr, A, B, **kw)
+                _publish_opnames(fn, sr, A, B, **kw)
+                C, over = out if tier != "esc" else (out, 0)
+                over = int(over)
+                assert over <= 0, (
+                    f"{tier} tier overflowed its symbolic bound by {over}"
+                )
+                caps = (C.capacity,)
+            if A.grid.size == 1 and sum(caps) == C.capacity:
+                C = _packed(C, caps)
+        with obs.span("digest"):
+            nnz, hilo, counts, sums, prints = jax.device_get(
+                spgemm_digest(C))
+            _publish_opnames(spgemm_digest, C)
+    digest = {
+        "nnz": int(nnz), "sum": combine_hilo(hilo),
+        "counts": counts, "sums": sums, "prints": prints,
+        "tier": tier, "backend": backend,
+    }
+    if obs.ENABLED:
+        labels = {"tier": tier, "backend": backend}
+        obs.count("spgemm.job.jobs", **labels)
+        obs.count("spgemm.job.products", int(products), **labels)
+        obs.count("spgemm.job.nnz_out", digest["nnz"], **labels)
+        obs.count("spgemm.job.windows", windows, **labels)
+        obs.count("spgemm.job.windows_skipped", skipped, **labels)
+        obs.count("spgemm.job.dense_flops", dense_flops, **labels)
+    return C, digest

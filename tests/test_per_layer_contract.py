@@ -17,8 +17,8 @@ from chipbench.spec import CHECKOUT, Spec
 
 BENCH = os.path.join(CHECKOUT, "BENCHMARK.json")
 
-#: PR 23's eleven, PR 34's six and PR 37's six, each run in the order
-#: its issue gave
+#: PR 23's eleven, PR 34's six, PR 37's six and PR 40's seven, each run
+#: in the order its issue gave
 RUNS = [
     ["launch_ms", "readback_ms", "to_global_ms", "readback_mb_per_query",
      "scatter_copied_mb", "batch_gap_ms", "bfs_gather_share",
@@ -27,6 +27,8 @@ RUNS = [
      "boot_probe_s", "boot_unspanned_s"],
     ["tc_device_ms", "tc_pack_ms", "tc_harvest_ms", "tc_pairs_per_edge",
      "tc_hbm_share", "tc_hbm_peak_gb"],
+    ["sq_device_ms", "sq_dot_ms", "sq_extract_ms", "sq_host_gap_ms",
+     "sq_mnnz_out_per_s", "sq_hbm_share", "sq_hbm_peak_gb"],
 ]
 
 
@@ -53,7 +55,7 @@ def test_a_per_layer_entry_follows_the_contract(name):
     assert callable(spec.load_module("layers", name).read)
 
 
-@pytest.mark.parametrize("run", RUNS, ids=["pr23", "pr34", "pr37"])
+@pytest.mark.parametrize("run", RUNS, ids=["pr23", "pr34", "pr37", "pr40"])
 def test_appended_entries_keep_their_issues_order(run):
     names = _names()
     assert [n for n in names if n in run] == run

@@ -643,3 +643,56 @@ def test_the_triangle_count_job_fits_the_chip_at_the_cell_s_size(topo):
         tc.tc_edgeharvest_bits, tile, tile, n=n)
     assert (hilo.shape, hilo.dtype) == ((2,), jnp.int32)
     assert pairs.shape == edges.shape == () and pairs.dtype == jnp.int32
+
+
+def test_a_product_job_s_row_block_fits_the_chip_at_the_cell_s_size(topo):
+    """``jit__windowed_block_local_dot`` at the size ``g500-sq.spgemm-
+    batch`` runs it (n = 2^14, the configuration's 426,544 stored
+    nonzeros, the default geometry: four row blocks of 4,096 by two
+    column windows of 8,192, every window's output slots clamped to its
+    cells) for the described v5e: the compiler takes it, its temporaries
+    stay under 3 GB, its output is the 2 x 2^25 slots of three arrays,
+    the stage product is ONE bf16 x bf16 -> f32 dot a window on the
+    matrix unit, and ``sq.densify`` / ``sq.dot`` / ``sq.extract`` are on
+    its instructions (``chipbench/sqscopes.py`` reads the device trace
+    by them)."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from combblas_tpu.obs import opnames
+    from combblas_tpu.ops.tuples import SpTuples
+    from combblas_tpu.parallel import spgemm as S
+    from combblas_tpu.semiring import PLUS_TIMES
+
+    n, stored = 1 << 14, 426_544
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def tile():
+        return SpTuples(
+            rows=sds((stored,), jnp.int32), cols=sds((stored,), jnp.int32),
+            vals=sds((stored,), jnp.float32), nnz=sds((), jnp.int32),
+            nrows=n, ncols=n)
+
+    rb, bc = S.default_block_rows(n, n), S.default_block_cols(n, n)
+    assert (rb, bc) == (4096, 8192)
+    compiled = S._windowed_block_local_dot.lower(
+        PLUS_TIMES, tile(), tile(), sds((3,), jnp.int32), sds((), jnp.int32),
+        rb=rb, out_caps_row=(rb * bc,) * 2, skip_row=(False, False),
+        block_cols=bc, pk=n, pwin=bc, panel_cap=1 << 18, mode="bf16",
+        interpret=False).compile()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < 3 * 2**30
+    assert 0 <= mem.output_size_in_bytes - 2 * rb * bc * 12 < 4096
+    text = compiled.as_text()
+    assert text.startswith("HloModule jit__windowed_block_local_dot")
+    seen = set(opnames.parse(text)[1].values())
+    for scope in ("sq.densify", "sq.dot", "sq.extract"):
+        assert any(f"/{scope}/" in nm for nm in seen), scope
+    dots = re.findall(
+        r"= f32\[4096,8192\]\S* (?:convolution|dot)\(.*op_name=\"[^\"]*"
+        r"sq\.dot/", text)
+    assert len(dots) == 2, len(dots)
