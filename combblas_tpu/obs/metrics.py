@@ -747,6 +747,17 @@ name                                   kind       meaning
                                                   padded contraction; 0
                                                   for a tier that never
                                                   densifies)
+``spgemm.job.extract_groups``          counter    row groups their
+                                                  launched windows were
+                                                  sorted in (``ops/
+                                                  spgemm.py:
+                                                  sparsify_groups`` of
+                                                  every window's dense
+                                                  shape; equals
+                                                  ``spgemm.job.
+                                                  windows`` when every
+                                                  window took the flat
+                                                  sort)
 ``obs.provider_errors``                counter    broken pull-provider
                                                   callbacks (caught)
 =====================================  =========  =====================

@@ -22,6 +22,8 @@ reference's exact preallocation).
 
 from __future__ import annotations
 
+import math
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -203,12 +205,67 @@ def densify(t: SpTuples, pad_rows: int, pad_cols: int, zero) -> Array:
     return dense.reshape(pad_rows, pad_cols)
 
 
+#: The longest list ``sparsify_windowed`` sorts at once, in cells (a
+#: power of two).  A window of more cells is sorted a group of
+#: consecutive rows at a time (``sparsify_groups``); the ladder that
+#: chose it is in ``sparsify_windowed``'s docstring.
+SPARSIFY_GROUP_CELLS = 1 << 14
+
+
+def sparsify_groups(R: int, C: int) -> int:
+    """How many groups of consecutive rows ``sparsify_windowed`` sorts
+    an [R, C] window in, read from the shape alone: 1 where the window
+    has at most ``SPARSIFY_GROUP_CELLS`` cells (one flat sort), else R
+    over the largest power of two that divides R and whose rows hold no
+    more than that many cells (1 row where a single row is longer)."""
+    if R * C <= SPARSIFY_GROUP_CELLS:
+        return 1
+    g = max(1, SPARSIFY_GROUP_CELLS // C)
+    return R // math.gcd(1 << (g.bit_length() - 1), R)
+
+
+def _lay_prefixes(key: Array, vals: Array) -> tuple[Array, Array]:
+    """Sorted groups ``[G, L]``, each holding its kept cells as a prefix
+    and the sentinel ``G * L`` after it, → the prefixes end to end in
+    ``[G * L]``, sentinels (and zeros) after them.
+
+    An ascending overlapped copy: group i writes ALL its slots at the
+    sum of the counts before it, its tail of sentinels lands where
+    group i + 1 starts, and group i + 1 overwrites it.  The buffers
+    carry one group's slots of slack because ``dynamic_update_slice``
+    clamps a start that would run past the end.  A trip slices its
+    group from the FLAT sorted arrays: a row of the tiled ``[G, L]``
+    is an operation more a trip, and a trip is what the loop costs
+    (4-5 us on the v5e; ``sparsify_windowed`` has the ladder)."""
+    G, L = key.shape
+    cells = G * L
+    cnt = jnp.sum(key < cells, axis=1, dtype=jnp.int32)
+    off = jnp.cumsum(cnt) - cnt
+    flat = key.reshape(-1), vals.reshape(-1)
+
+    def lay(i, out):
+        return tuple(
+            lax.dynamic_update_slice(
+                o, lax.dynamic_slice(x, (i * L,), (L,)), (off[i],))
+            for o, x in zip(out, flat)
+        )
+
+    out_key, out_vals = lax.fori_loop(0, G, lay, (
+        jnp.full((cells + L,), cells, key.dtype),
+        jnp.zeros((cells + L,), vals.dtype),
+    ))
+    return out_key[:cells], out_vals[:cells]
+
+
 def sparsify_windowed(
     dense: Array, zero, nrows: int, ncols: int, capacity: int
 ) -> tuple[SpTuples, Array]:
-    """Dense [R, C] → compacted row-major SpTuples by ONE sort: every
+    """Dense [R, C] → compacted row-major SpTuples by sorting: every
     cell's key is its own row-major index where it holds a value and
-    R*C where it does not, and the values ride along.
+    R*C where it does not, and the values ride along.  A window of at
+    most ``SPARSIFY_GROUP_CELLS`` cells is ONE flat sort; a larger one
+    is sorted a group of rows at a time and the groups' prefixes are
+    laid end to end (``_lay_prefixes``).
 
     What an extraction costs on the v5e is what it moves one element at
     a time (my chip runs, PR 40, one [4096, 8192] window, 15% of its
@@ -223,6 +280,33 @@ def sparsify_windowed(
     gathers, 16 + 8 lanes, a SLOT of capacity; its cost model came from
     a machine that is gone) was 81.1 s a product job at scale 14; with
     this one the same job is 1.1 s, 0.6 s of it here.
+
+    A sort's cost follows the length of the axis it sorts, not the
+    bytes it moves, so the grain pays (my chip runs, PR 41, the same
+    window, best of three, ms: the ``[G, L]`` sort alone, the copy that
+    lays the prefixes end to end, the whole extraction;
+    ``scripts/sparsify_ladder.py``):
+
+    ======================  =====  ====  ====  ==========
+    cells a group (rows)    G      sort  copy  extraction
+    ======================  =====  ====  ====  ==========
+    2^25 (4,096): flat      1      74.2  -     76.3
+    2^22 (512)              8      77.3  5.3   75.2
+    2^20 (128)              32     59.5  5.5   57.8
+    2^19 (64)               64     44.6  5.7   50.1
+    2^18 (32)               128    59.6  6.9   65.3
+    2^17 (16)               256    49.5  7.0   56.4
+    2^16 (8)                512    41.9  7.0   48.3
+    2^15 (4)                1,024  35.6  7.7   41.2
+    2^14 (2): shipped       2,048  27.3  10.6  38.0
+    2^13 (1)                4,096  21.9  16.7  38.5
+    ======================  =====  ====  ====  ==========
+
+    The copy is the bytes (5-7 ms for two arrays of 134 MB at
+    unaligned offsets) up to some 500 trips and 4-5 us a trip after;
+    the sorts between the ends are no smooth curve (2^18 is slower
+    than 2^19).  With the grain at 2^14 the job above is 0.81 s,
+    0.30 s of it here.
 
     Exact, sorted row-major, valid entries a prefix; ``total`` is the
     nonzero count before any truncation to ``capacity``.
@@ -240,9 +324,19 @@ def sparsify_windowed(
     mask = mask.reshape(-1)
     total = jnp.sum(mask, dtype=jnp.int32)
     key = jnp.where(mask, jnp.arange(cells, dtype=jnp.int32), cells)
-    # the kept cells' keys are distinct: no tie-break operand to carry
-    key, vals = lax.sort(
-        (key, dense.reshape(-1)), num_keys=1, is_stable=False)
+    groups = sparsify_groups(R, C)
+    # the kept cells' keys are distinct: no tie-break operand to carry;
+    # one group stays a FLAT sort ([1, cells] along axis 1 is another
+    # program on the chip: 426 ms where the flat one is 74)
+    if groups == 1:
+        key, vals = lax.sort(
+            (key, dense.reshape(-1)), num_keys=1, is_stable=False)
+    else:
+        # a group's keys all lie under the next group's: the flat sort's
+        # result is the sorted groups' prefixes laid end to end
+        key, vals = _lay_prefixes(*lax.sort(
+            (key.reshape(groups, -1), dense.reshape(groups, -1)),
+            dimension=1, num_keys=1, is_stable=False))
     if capacity <= cells:
         key, vals = key[:capacity], vals[:capacity]
     else:

@@ -3551,7 +3551,7 @@ def spgemm_job(
     ``mode`` is the dense stage product's input pass (``_mxu_dot``);
     ``block_rows`` / ``block_cols`` override the windowed tier's
     geometry (tests run several windows on a small matrix)."""
-    from ..ops.spgemm import combine_hilo
+    from ..ops.spgemm import combine_hilo, sparsify_groups
 
     assert backend in ("dot", "scatter"), backend
     with obs.span("spgemm.job", sr=sr.name, backend=backend) as job:
@@ -3574,20 +3574,33 @@ def spgemm_job(
             assert tier in JOB_TIERS, tier
             dense_tile = A.local_rows * B.local_cols
             plan, windows, skipped, dense_flops = None, 0, 0, 0
+            extract_groups = 0
             if tier == "windowed":
                 plan = plan_windowed(
                     sr, A, B, backend=backend, block_rows=block_rows,
                     block_cols=block_cols,
                 )
                 windows, skipped = plan.windows()
+
+                def rb(g):
+                    return min(
+                        plan.block_rows, A.local_rows - g * plan.block_rows)
+
+                # the dense [rows, cols] every launched window extracts
                 if backend == "dot":
+                    shapes = [
+                        (_pad128(rb(g)), _pad128(plan.block_cols))
+                        for g, _ in packed_windows_2d(plan.skip)]
                     # two flop a cell of every launched window's padded
                     # row block x contraction x col window
-                    dense_flops = 2 * _pad128(B.local_rows) * _pad128(
-                        plan.block_cols) * sum(
-                        _pad128(min(plan.block_rows,
-                                    A.local_rows - g * plan.block_rows))
-                        for g, _ in packed_windows_2d(plan.skip))
+                    dense_flops = 2 * _pad128(B.local_rows) * sum(
+                        r * c for r, c in shapes)
+                else:
+                    pcols = _windowed_dims(
+                        backend, None, B.local_rows, B.local_cols)[1]
+                    shapes = [(rb(g), pcols) for g in packed_windows(plan.skip)]
+                extract_groups = sum(
+                    sparsify_groups(r, c) for r, c in shapes)
             elif tier in ("scan", "esc"):
                 flop_cap, out_cap = summa_capacities(A, B)
                 _publish_opnames(summa_stage_flops, A, B)
@@ -3646,4 +3659,5 @@ def spgemm_job(
         obs.count("spgemm.job.windows", windows, **labels)
         obs.count("spgemm.job.windows_skipped", skipped, **labels)
         obs.count("spgemm.job.dense_flops", dense_flops, **labels)
+        obs.count("spgemm.job.extract_groups", extract_groups, **labels)
     return C, digest

@@ -337,3 +337,87 @@ def test_sparsify_windowed_direct(rng, density, truncate, pad, zero):
     rr, cc = np.nonzero(m)
     flat_ref = np.sort(rr.astype(np.int64) * C + cc)
     np.testing.assert_array_equal(flat_got, flat_ref[: len(flat_got)])
+
+
+def _window(name):
+    """A [16, 32] window by name: (dense, nrows, ncols)."""
+    R, C = 16, 32
+    x = np.zeros((R, C), np.float32)
+    vals = (np.arange(R * C, dtype=np.float32).reshape(R, C) % 37) + 1
+    sparse = (np.arange(R * C).reshape(R, C) * 7) % 11 == 0
+    nrows, ncols = R, C
+    if name == "full":
+        x[:] = vals
+    elif name == "one_full_row":
+        x[sparse] = vals[sparse]
+        x[5] = vals[5]
+    elif name == "empty_group_between":
+        # rows 4..11 hold nothing: under 4 rows a group, two empty
+        # groups between two that hold some
+        x[sparse] = vals[sparse]
+        x[4:12] = 0
+    elif name == "short":
+        # set cells past nrows / ncols must not surface
+        x[:] = vals
+        nrows, ncols = 13, 27
+    else:
+        assert name == "empty", name
+    return x, nrows, ncols
+
+
+@pytest.mark.parametrize("window", [
+    "empty", "full", "one_full_row", "empty_group_between", "short"])
+@pytest.mark.parametrize("capacity", ["below", "exact", "above_cells"])
+@pytest.mark.parametrize("group_cells", [1, 128, 16 * 32 - 1, 16 * 32])
+def test_sparsify_windowed_by_row_groups(
+        monkeypatch, window, capacity, group_cells):
+    """The grouped extraction (a window of more than
+    ``SPARSIFY_GROUP_CELLS`` cells: one sort a group of rows, prefixes
+    laid end to end) held to ``numpy.nonzero`` on coordinates, values,
+    ``nnz``, ``total`` and the padding, from one row a group (``g = 1``)
+    to the whole window (``g = R``, today's flat sort)."""
+    from combblas_tpu.ops import spgemm as ops
+
+    monkeypatch.setattr(ops, "SPARSIFY_GROUP_CELLS", group_cells)
+    x, nrows, ncols = _window(window)
+    R, C = x.shape
+    assert ops.sparsify_groups(R, C) == {
+        1: 16, 128: 4, R * C - 1: 2, R * C: 1}[group_cells]
+    kept = x.copy()
+    kept[nrows:] = 0
+    kept[:, ncols:] = 0
+    r, c = np.nonzero(kept)
+    cap = {"below": max(len(r) // 2, 1), "exact": max(len(r), 1),
+           "above_cells": R * C + 9}[capacity]
+    # jitted afresh: the grain is read at trace time
+    t, total = jax.jit(
+        lambda d: ops.sparsify_windowed(d, 0.0, nrows, ncols, cap))(
+        jnp.asarray(x))
+    k = min(len(r), cap)
+    assert int(total) == len(r) and int(t.nnz) == k
+    assert t.rows.shape == t.cols.shape == t.vals.shape == (cap,)
+    np.testing.assert_array_equal(np.asarray(t.rows[:k]), r[:k])
+    np.testing.assert_array_equal(np.asarray(t.cols[:k]), c[:k])
+    np.testing.assert_array_equal(np.asarray(t.vals[:k]), kept[r[:k], c[:k]])
+    assert (np.asarray(t.rows[k:]) == nrows).all()
+    assert (np.asarray(t.cols[k:]) == ncols).all()
+    assert (np.asarray(t.vals[k:]) == 0).all()
+
+
+@pytest.mark.parametrize("shape,group_cells,groups", [
+    ((4096, 8192), 1 << 25, 1), ((4096, 8192), 1 << 14, 2048),
+    ((4096, 8192), 1 << 13, 4096), ((4096, 8192), 1 << 12, 4096),
+    ((24, 16), 256, 3), ((24, 16), 64, 6), ((7, 16), 32, 7),
+    ((0, 16), 1, 1), ((5, 0), 1, 1),
+])
+def test_the_extraction_s_grain_is_read_from_the_shape(
+        monkeypatch, shape, group_cells, groups):
+    """A power of two of rows that divides R, no more cells than
+    ``SPARSIFY_GROUP_CELLS`` (itself a power of two) where a row allows
+    it."""
+    from combblas_tpu.ops import spgemm as ops
+
+    shipped = ops.SPARSIFY_GROUP_CELLS
+    assert shipped & (shipped - 1) == 0
+    monkeypatch.setattr(ops, "SPARSIFY_GROUP_CELLS", group_cells)
+    assert ops.sparsify_groups(*shape) == groups

@@ -318,3 +318,71 @@ def test_a_job_never_retries(s8, monkeypatch):
     with pytest.raises(AssertionError, match="overflowed its symbolic"):
         S.spgemm_job(PLUS_TIMES, A, A, tier="scan")
     assert len(calls) == 2
+
+
+@pytest.fixture
+def grain(monkeypatch):
+    """``grain(cells)`` sets ``SPARSIFY_GROUP_CELLS`` and returns the
+    list of ``[G, L]`` shapes whose prefixes were laid end to end since.
+    The grain is read when a program is traced: nothing traced before
+    may serve these jobs, and nothing traced here a later test."""
+    from combblas_tpu.ops import spgemm as ops
+
+    laid = []
+    real = ops._lay_prefixes
+
+    def counted(key, vals):
+        laid.append(key.shape)
+        return real(key, vals)
+
+    def set_grain(cells):
+        monkeypatch.setattr(ops, "SPARSIFY_GROUP_CELLS", cells)
+        monkeypatch.setattr(ops, "_lay_prefixes", counted)
+        jax.clear_caches()
+        return laid
+
+    yield set_grain
+    jax.clear_caches()
+
+
+def test_a_job_whose_windows_are_sorted_by_row_groups(s8, grain):
+    """The cell's tier and backend with several groups a window (a
+    [512, 512] window in 16 groups of 32 rows): the digest and every
+    entry equal the reference's."""
+    n, rows, cols, A, ref = s8
+    laid = grain(1 << 14)
+    C, digest = S.spgemm_job(
+        PLUS_TIMES, A, A, **CHIP, block_rows=128, block_cols=128)
+    _held(ref, C, digest)
+    # one trace serves both row blocks: two windows, 16 groups each
+    assert laid == [(16, 1 << 14)] * 2
+
+
+@pytest.mark.parametrize("backend,cells,windows,groups", [
+    ("dot", 1 << 18, 4, 4), ("dot", 1 << 14, 4, 4 * 16),
+    ("scatter", 1 << 14, 2, 2 * 2),
+])
+def test_a_job_counts_the_row_groups_it_sorted(
+        s8, grain, backend, cells, windows, groups):
+    """``spgemm.job.extract_groups``: the launched windows' groups, from
+    the plan's static shapes ([512, 512] under ``dot``, [128, 256] under
+    ``scatter``); ``windows`` where every window took the flat sort."""
+    n, rows, cols, A, ref = s8
+    laid = grain(cells)
+    obs.reset()
+    obs.enable(install_hooks=False)
+    try:
+        C, digest = S.spgemm_job(
+            PLUS_TIMES, A, A, tier="windowed", backend=backend,
+            mode="bf16", block_rows=128, block_cols=128)
+        counters = {
+            r["name"]: r["value"] for r in obs.registry.snapshot()
+            if r["kind"] == "counter" and r["labels"] == {
+                "tier": "windowed", "backend": backend}}
+    finally:
+        obs.disable()
+        obs.reset()
+    _held(ref, C, digest)
+    assert counters["spgemm.job.windows"] == windows
+    assert counters["spgemm.job.extract_groups"] == groups
+    assert bool(laid) == (groups > windows)
