@@ -50,7 +50,7 @@ CC_SCOPES = (
     "cc.init",
     "cc.iter",  # the whole while loop; one iteration = one FastSV round
     "cc.gather",  # gf = f[f]
-    "cc.spmv",  # u = A (select2nd, min) gf: the one sweep of a round
+    "cc.spmv",  # the one sweep of a round, where gf changed: u = A (min) gf
     "cc.hook",  # stochastic hooking: the scatter-min of u into f[f]
     "cc.min",  # aggressive hooking, shortcutting, the fixed-point test
     "cc.jump",  # the pointer-jumping loop after the rounds
@@ -66,11 +66,23 @@ def fastsv(M, f0: DistVec | None = None):
     out of range) starts the loop from labels that name same-component
     vertices (``dynamic/refresh.py``'s warm start) instead of ``iota``.
 
+    A round sweeps the matrix only where its grandparents ``f[f]`` are
+    not the ones the last sweep read (``_fastsv``): FastSV usually ends
+    with a round that only shortcuts and one that confirms, and the
+    confirming round then costs its two vector subscripts and no sweep
+    (four sweeps of five rounds on the Graph500 scale-20 graph).  The
+    answer, ``rounds`` and ``jumps`` are what sweeping every round gives.
+    A graph whose last changing round still hooks reuses nothing and
+    pays one ``[n]`` compare a round; a deep one (a road network: tens
+    of rounds) saves one sweep of many.
+
     Eager wrapper: the jitted programs return plain block arrays (the
-    plain-outputs law) and this rebuilds the DistVec outside."""
+    plain-outputs law) and, fourth, the rounds that swept; this rebuilds
+    the DistVec outside and, with telemetry on, adds the three counts to
+    ``models.cc.rounds`` / ``.jumps`` / ``.sweeps``."""
     program = cc_fastsv_ell if isinstance(M, EllParMat) else cc_fastsv
     f0_blocks = None if f0 is None else f0.blocks
-    blocks, rounds, jumps = program(M, f0_blocks)
+    blocks, rounds, jumps, sweeps = program(M, f0_blocks)
     if obs.ENABLED:
         # no warm-up of its own: the first traced call of a shape
         # publishes the program's op names (obs/opnames.py), AFTER the
@@ -84,6 +96,7 @@ def fastsv(M, f0: DistVec | None = None):
         obs.count("models.cc.jobs")
         obs.count("models.cc.rounds", int(rounds))
         obs.count("models.cc.jumps", int(jumps))
+        obs.count("models.cc.sweeps", int(sweeps))
     labels = DistVec(blocks=blocks, length=M.nrows, align="row", grid=M.grid)
     return labels, rounds, jumps
 
@@ -95,12 +108,24 @@ def connected_components(M) -> tuple[DistVec, jax.Array]:
 
 def _fastsv(M, f0_blocks):
     """Component labels (min vertex id in each component), the hooking
-    loop's iteration count and the pointer-jumping loop's.
+    loop's iteration count, the pointer-jumping loop's, and how many of
+    the hooking rounds swept the matrix.
 
     M is interpreted structurally (any nonzero = edge) and must be
     symmetric; returns PLAIN row-aligned int32 label BLOCKS (the eager
     wrapper above rebuilds the DistVec); padding slots carry their own
     (out-of-range) ids and never interact with real vertices.
+
+    A round's sweep reads ``gf = f[f]`` and a matrix that does not
+    change, so the loop carries the ``gf`` its last sweep read and the
+    ``u`` that sweep gave, and a round whose ``gf`` is that one takes
+    the carried ``u`` (``lax.cond`` on a global ``any``: every tile of a
+    mesh takes the same branch; the sweep, its realign and its gather
+    table are all inside the branch).  Round 1 always sweeps.  Labels,
+    rounds and jumps are bit for bit those of sweeping every round.
+    Not done here: ``gf`` only ever falls, so a round that changes few
+    entries of it could lower ``u`` by a push over those columns alone
+    (upstream's ``SpMSpV``); that needs a column companion.
     """
     grid = M.grid
     n = M.nrows
@@ -113,29 +138,41 @@ def _fastsv(M, f0_blocks):
             f0_blocks = DistVec.iota(grid, n, jnp.int32, align="row").blocks
 
     def cond(state):
-        _, changed, it = state
+        _, changed, it, *_ = state
         return changed & (it < n)
 
     def step(state):
-        fb, _, it = state
+        fb, _, it, gf_swept, u_swept, sweeps = state
         f = mk(fb)
         with jax.named_scope("cc.gather"):
             gf = f.gather(f)  # grandparent labels f[f[i]]
         with jax.named_scope("cc.spmv"):
-            # u[i] = min over neighbors j of gf[j]  (one semiring SpMV)
-            u = dist_spmv(SELECT2ND_MIN, M, gf.realign("col"))
+            # u[i] = min over neighbors j of gf[j]  (one semiring SpMV).
+            # The sweep reads gf and nothing else that changes: where gf
+            # is what the last sweep read, that sweep's u is this round's
+            stale = (it == 0) | jnp.any(gf.blocks != gf_swept)
+            ub = jax.lax.cond(
+                stale,
+                lambda: dist_spmv(
+                    SELECT2ND_MIN, M, gf.realign("col")).blocks,
+                lambda: u_swept,
+            )
         with jax.named_scope("cc.hook"):
             # stochastic hooking: lower the parent's label
-            f1 = f.scatter_combine(SELECT2ND_MIN, idx=f, src=u)
+            f1 = f.scatter_combine(SELECT2ND_MIN, idx=f, src=mk(ub))
         with jax.named_scope("cc.min"):
             # aggressive hooking + shortcutting (elementwise minimums)
-            nb = jnp.minimum(jnp.minimum(f1.blocks, u.blocks), gf.blocks)
+            nb = jnp.minimum(jnp.minimum(f1.blocks, ub), gf.blocks)
             changed = jnp.any(nb != fb)
-        return nb, changed, it + 1
+        return (nb, changed, it + 1, gf.blocks, ub,
+                sweeps + stale.astype(jnp.int32))
 
     with jax.named_scope("cc.iter"):
-        fb, _, rounds = jax.lax.while_loop(
-            cond, step, (f0_blocks, jnp.bool_(True), jnp.int32(0))
+        # round 1 sweeps whatever the carried gf and u start as
+        fb, _, rounds, _, _, sweeps = jax.lax.while_loop(
+            cond, step,
+            (f0_blocks, jnp.bool_(True), jnp.int32(0), f0_blocks,
+             jnp.zeros_like(f0_blocks), jnp.int32(0)),
         )
 
     # Final pointer-jumping: compress remaining parent chains to roots.
@@ -153,7 +190,7 @@ def _fastsv(M, f0_blocks):
         fb, _, jumps = jax.lax.while_loop(
             jcond, jstep, (fb, jnp.bool_(True), jnp.int32(0))
         )
-    return fb, rounds, jumps
+    return fb, rounds, jumps, sweeps
 
 
 @jax.jit

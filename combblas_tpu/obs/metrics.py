@@ -696,6 +696,15 @@ name                                   kind       meaning
                                                   count, the one that
                                                   changed nothing
                                                   included)
+``models.cc.sweeps``                   counter    rounds of those jobs
+                                                  that swept the matrix
+                                                  (the program's own
+                                                  count): a round whose
+                                                  ``f[f]`` is the one
+                                                  the last sweep read
+                                                  keeps that sweep's
+                                                  result; reused share
+                                                  = 1 - sweeps / rounds
 ``models.tc.jobs``                     counter    triangle-count jobs
                                                   run through the eager
                                                   wrapper

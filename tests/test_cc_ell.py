@@ -131,12 +131,16 @@ def test_counters_add_a_job_s_own_counts_once_and_nothing_when_off():
     obs.reset()
     _, rounds, jumps = cc.fastsv(E)
     assert _counters() == {}
+    # the sweeps a job ran come from the program (its fourth output):
+    # the rounds whose grandparents were not the round before's
+    sweeps = int(cc.cc_fastsv_ell(E, None)[3])
+    assert 1 <= sweeps <= int(rounds)
     obs.enable(install_hooks=False)
     try:
         cc.fastsv(E)
         assert _counters() == {
             "models.cc.jobs": 1, "models.cc.rounds": int(rounds),
-            "models.cc.jumps": int(jumps)}
+            "models.cc.jumps": int(jumps), "models.cc.sweeps": sweeps}
         cc.connected_components(E)
         assert _counters()["models.cc.jobs"] == 2
         assert _counters()["models.cc.rounds"] == 2 * int(rounds)

@@ -62,6 +62,31 @@ def operands(topo):
     return EllParMat(buckets=buckets, nrows=n, ncols=n, grid=grid), grid
 
 
+def _ell_of_shapes(grid, classes, n):
+    """An ``EllParMat`` of ``n`` rows and columns as shapes alone on
+    ``grid``: one degree class a ``(bucket rows, class width)`` of
+    ``classes``, every tile alike."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding
+
+    from combblas_tpu.parallel.ellmat import TILE_SPEC, EllParMat
+
+    tile = NamedSharding(grid.mesh, TILE_SPEC)
+
+    def tiles(shape, dtype):
+        return jax.ShapeDtypeStruct(
+            (grid.pr, grid.pc) + shape, dtype, sharding=tile)
+
+    return EllParMat(
+        buckets=tuple(
+            (tiles((nb, kb), jnp.int32), tiles((nb, kb), jnp.float32),
+             tiles((nb,), jnp.int32))
+            for nb, kb in classes),
+        nrows=n, ncols=n, grid=grid,
+    )
+
+
 def _companion(grid, rows, cols, n):
     """``(indptr, rowidx, current)`` as the engine hands them to the BFS
     plan (``GraphEngine._push_operand``), as shapes on ``grid``."""
@@ -349,7 +374,7 @@ def test_a_mesh_tiles_frontier_table_is_placed_for_the_v5e(topo):
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from combblas_tpu.parallel.ellmat import TILE_SPEC, EllParMat
+    from combblas_tpu.parallel.ellmat import TILE_SPEC
     from combblas_tpu.parallel.grid import Grid
 
     grid = Grid.make(2, 2, devices=list(topo.devices))
@@ -362,13 +387,7 @@ def test_a_mesh_tiles_frontier_table_is_placed_for_the_v5e(topo):
     # (bucket rows, class width): narrow and many to wide and few
     classes = [(1 << 20, 1), (1 << 19, 4), (1 << 18, 16), (1 << 14, 256),
                (64, 1 << 15)]
-    E = EllParMat(
-        buckets=tuple(
-            (tiles((nb, kb), jnp.int32), tiles((nb, kb), jnp.float32),
-             tiles((nb,), jnp.int32))
-            for nb, kb in classes),
-        nrows=n, ncols=n, grid=grid,
-    )
+    E = _ell_of_shapes(grid, classes, n)
     assert E.local_cols == lc
     companion = (
         tiles((lc + 1,), jnp.int32), tiles((1 << 22,), jnp.int32),
@@ -643,6 +662,61 @@ def test_the_triangle_count_job_fits_the_chip_at_the_cell_s_size(topo):
         tc.tc_edgeharvest_bits, tile, tile, n=n)
     assert (hilo.shape, hilo.dtype) == ((2,), jnp.int32)
     assert pairs.shape == edges.shape == () and pairs.dtype == jnp.int32
+
+
+#: ``(rows, width)`` of the 23 degree classes of ``g500-s20-cc-1x1``'s
+#: graph (``chipbench/graph.py:rmat_graph(20, 16, 1)`` through
+#: ``EllParMat.host_build`` on one tile): 36,953,104 slots
+CC_CELL_CLASSES = (
+    (140345, 1), (76312, 2), (51559, 3), (39823, 4), (51955, 6), (26271, 8),
+    (45637, 12), (49776, 16), (26708, 24), (3933, 32), (57479, 48),
+    (16429, 64), (116, 96), (9658, 128), (29093, 192), (280, 384),
+    (15222, 512), (2, 768), (4845, 1536), (1140, 4096), (190, 12288),
+    (20, 32768), (1, 65536),
+)
+
+
+def test_a_fastsv_round_s_sweep_keeps_its_fast_table_inside_its_branch(topo):
+    """``jit_cc_fastsv_ell`` at the size ``g500-s20cc.cc-batch`` runs it
+    (n = 2^20, the configuration's 23 classes as shapes alone) for the
+    described v5e.  The round's sweep sits in the ONE ``conditional`` of
+    the program, under ``cc.spmv`` inside the ``cc.iter`` loop (a round
+    whose ``f[f]`` is the last swept one runs the other branch: PERF.md
+    section 6, PR 42), and every class's gather in that branch reads the
+    one ``s32[n + 1]`` table, BUILT in the branch and placed in the
+    fast memory (``S(1)`` on its layout): a table handed into a
+    ``conditional`` stays in HBM and a sweep then costs three times its
+    287 ms (PR 24), which would cost more than the reused round saves."""
+    import jax
+
+    from combblas_tpu.models import cc
+    from combblas_tpu.obs import opnames
+    from combblas_tpu.parallel.grid import Grid
+
+    n = 1 << 20
+    assert sum(nb * kb for nb, kb in CC_CELL_CLASSES) == 36_953_104
+    grid = Grid.make(1, 1, devices=[topo.devices[0]])
+    E = _ell_of_shapes(grid, CC_CELL_CLASSES, n)
+    text = cc.cc_fastsv_ell.lower(E, None).compile().as_text()
+    assert text.startswith("HloModule jit_cc_fastsv_ell")
+    names = opnames.parse(text)[1]
+    loops = [nm for i, nm in names.items() if i.startswith("while")]
+    assert any(nm.endswith("cc.iter/while") for nm in loops), loops
+    branch = "cc.iter/while/body/cc.spmv/cond"
+    conds = re.findall(
+        r"= [^=\n]* conditional\(.*op_name=\"([^\"]*)\"", text)
+    assert len(conds) == 1 and conds[0].endswith(branch), conds
+    tables = _loop_gather_tables(text, loop=branch + "/branch_")
+    assert sorted(cls for cls, _ in tables) == list(
+        range(len(CC_CELL_CLASSES)))
+    for cls, line in tables:
+        layout = line.split(" = ", 1)[1].split(" ", 1)[0]
+        assert layout.startswith(f"s32[{n + 1}]"), line[:200]
+        assert "S(1)" in layout and branch + "/branch_" in line, (
+            cls, line[:300])
+    # what comes back beside the labels: three counts
+    out = jax.eval_shape(cc.cc_fastsv_ell, E, None)
+    assert [o.shape for o in out] == [(1, n), (), (), ()]
 
 
 @pytest.fixture(scope="module")
