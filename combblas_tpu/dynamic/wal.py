@@ -2,10 +2,9 @@
 
 The mutation lane's ``DeltaBuffer`` and the merged ``GraphVersion``s
 are memory-only — before this module, a crash lost every acknowledged
-write since boot.  The WAL closes that hole with the same append-only
-JSONL conventions as the plan store (``tuner/store.py``): one fully
-formed line per acknowledged ``submit_update`` batch, written with a
-single ``write`` call so a torn write from a dying process truncates
+write since boot.  The WAL closes that hole with an append-only
+JSONL: one fully formed line per acknowledged ``submit_update``
+batch, written with a single ``write`` call so a torn write from a dying process truncates
 to an invalid FINAL line (tolerated at replay), never a poisoned log.
 
 Line format (schema ``combblas_tpu.wal/v1``)::
@@ -17,7 +16,7 @@ Line format (schema ``combblas_tpu.wal/v1``)::
 batch was admitted under — replay is ordered and deduplicated by them
 (records whose range a snapshot already covers are skipped; a record
 re-appended after a failover whose range is not past the frontier is
-superseded — later lines win, the plan-store stance).  ``ops`` are the
+superseded — later lines win).  ``ops`` are the
 ``delta.OP_INSERT/OP_DELETE/OP_UPSERT`` codes.  Two auxiliary record
 shapes share the schema line: ``{"v": ..., "drop": [a, z]}`` tombstones
 a range whose merge FAILED on the live engine (replay must not
@@ -56,7 +55,7 @@ from .delta import DeltaBatch, OP_NAMES
 
 #: JSONL schema tag — bump on any incompatible record layout change;
 #: records carrying another tag are skipped at replay (never guessed
-#: at — the plan-store convention).
+#: at).
 SCHEMA = "combblas_tpu.wal/v1"
 
 #: File name inside the durability directory (``COMBBLAS_WAL``); the

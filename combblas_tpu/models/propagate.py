@@ -10,8 +10,7 @@ Two entries:
 
 * :func:`propagate_features` — the whole-graph model API: host
   ``[n, F]`` features in, propagated ``[n, F]`` out (one fused
-  ``spmm_khop`` launch; backend resolves through the op="spmm" tuner
-  chain).
+  ``spmm_khop`` launch; the backend is ``resolve_spmm_backend``'s).
 
 * :func:`_propagate_batch_impl` — the SERVE plan body (kind
   ``"propagate"``): a W-lane batch of root queries answered WITHOUT

@@ -65,10 +65,9 @@ def _tc_dense(rows, cols, n: int) -> jax.Array:
     return jnp.stack([hi, lo])
 
 
-#: Edge-harvest ceilings: the symmetric adjacency must fit HBM — bf16
-#: n^2 bytes*2 (8.6 GB at n = 65536), bit-packed n^2/8 bytes (8.6 GB at
-#: n = 262144, i.e. scale 18 on the 16 GB chip).
-EDGE_HARVEST_MAX_DIM = 65536
+#: Edge-harvest ceiling: the symmetric adjacency must fit HBM,
+#: bit-packed n^2/8 bytes (8.6 GB at n = 262144, i.e. scale 18 on the
+#: 16 GB chip).
 EDGE_HARVEST_BITS_MAX_DIM = 262144
 
 
@@ -94,88 +93,19 @@ TC_SCOPES = (
 )
 
 
-def _tc_edge_harvest(rows, cols, n: int, chunk: int = 4096) -> jax.Array:
-    """One-launch TC past the dense-product ceiling (32K < n <= 64K):
-    per-EDGE common-neighbor harvest against the dense adjacency.
-
-    The full dense wedge product is 2n^3 FLOPs (~560 TFLOP at n = 64K —
-    ~42 s even at MXU peak) and its f32 output doesn't fit HBM next to
-    the operand. But TC only needs (A·A)[i,j] ON the edges: for each
-    undirected edge (i>j), |N(i) ∩ N(j)| = <D[i,:], D[j,:]> = number of
-    triangles through that edge, so
-
-        3·T = Σ_{edges i>j} <D[i,:], D[j,:]>
-
-    which is 2·nnz/2·n ≈ 1.3e11 multiply-adds (4000x fewer than dense)
-    and is HBM-BOUND: ~2 full-row loads per edge ≈ nnz·n·2 B of traffic.
-    A lax.scan walks static edge chunks; each step gathers [chunk, n]
-    bf16 row pairs and dots them on the VPU (0/1 bf16 products are
-    exact; per-edge counts < n < 2^24 are f32-exact).
-
-    Returns the (hi, lo) int32 split of 3·T (``_tc_combine`` // 3 gives
-    T; 3·T can exceed 2^31 — same split rationale as ``_tc_dense``).
-
-    Reference role: the masked Mult_AnXBn of TC.cpp:104-116, redesigned
-    output-driven for a chip with no scatter unit (the ESC sparse path
-    pays the 22 M/s random-memory wall — 87 s at scale 16).
-    """
-    npad = -(-n // 128) * 128
-    # ON-DEVICE DEDUP: the adjacency ``.set`` is idempotent, but the
-    # EDGE WALK below is not — a duplicated COO entry would harvest its
-    # common neighbors twice and double-count 3T; repeats are masked out
-    # of the edge list.
-    rows, cols, dup = _coo_sort_dedup(rows, cols)
-    loops = rows == cols
-    # dense SYMMETRIC adjacency (input is symmetrized; drop loops; padded
-    # sentinel slots land in the dump row npad-? -> use drop mode)
-    r_all = jnp.where(loops, npad, rows)
-    d = jnp.zeros((npad, npad), jnp.bfloat16)
-    d = d.at[r_all, cols].set(jnp.bfloat16(1.0), mode="drop")
-    # strict-lower edge list, padded slots -> row 0 x col 0 with weight 0
-    keep = (rows > cols) & ~dup
-    nedge = rows.shape[0]
-    epad = -(-nedge // chunk) * chunk
-    er = jnp.where(keep, rows, 0)
-    ec = jnp.where(keep, cols, 0)
-    ew = keep.astype(jnp.float32)
-    er = jnp.pad(er, (0, epad - nedge))
-    ec = jnp.pad(ec, (0, epad - nedge))
-    ew = jnp.pad(ew, (0, epad - nedge))
-
-    def body(carry, eidx):
-        hi, lo = carry
-        ri = er[eidx]  # [chunk]
-        ci = ec[eidx]
-        wi = ew[eidx]
-        gi = d[ri]  # [chunk, npad] bf16
-        gj = d[ci]
-        w = jnp.einsum(
-            "bn,bn->b", gi, gj, preferred_element_type=jnp.float32
-        )
-        cnt = (w * wi).astype(jnp.int32)  # per-edge: exact (< n < 2^24)
-        # renormalize the split each step: an unbounded lo accumulation
-        # would itself wrap past 2^31 on triangle-rich graphs (the exact
-        # regime this kernel exists for)
-        lo = lo + jnp.sum(cnt & 0x7FFF)
-        hi = hi + jnp.sum(cnt >> 15) + (lo >> 15)
-        lo = lo & 0x7FFF
-        return (hi, lo), None
-
-    idx = jnp.arange(epad, dtype=jnp.int32).reshape(-1, chunk)
-    (hi, lo), _ = jax.lax.scan(body, (jnp.int32(0), jnp.int32(0)), idx)
-    return jnp.stack([hi, lo])
-
-
 def _tc_edge_harvest_bits(rows, cols, n: int, chunk: int = 8192):
     """Bit-packed edge-harvest TC: the adjacency as a [n, n/32] uint32
     bitmask; each edge's common-neighbor count is popcount(row_i & row_j).
 
-    Same mathematics as ``_tc_edge_harvest`` with 16x less gather
-    traffic (8 KB/row at n = 64K instead of 131 KB of bf16) — the
-    bf16 variant measured only ~12 GB/s of effective row-gather
-    bandwidth on the chip, so traffic is the knob that matters. Packing
-    is a scatter-ADD of 2^(c mod 32) at (r, c div 32): the input COO is
-    dedup'd, so add ≡ bitwise-or (each bit lands exactly once).
+    TC only needs (A·A)[i,j] ON the edges: for each undirected edge
+    (i>j), |N(i) ∩ N(j)| is the number of triangles through that edge,
+    so 3·T = Σ_{edges i>j} |N(i) ∩ N(j)| — two row loads an edge, bound
+    by row-gather traffic (8 KB/row at n = 64K), against the dense
+    wedge product's 2n^3 FLOPs.  Reference role: the masked Mult_AnXBn
+    of TC.cpp:104-116, redesigned output-driven for a chip with no
+    scatter unit.  Packing is a scatter-ADD of 2^(c mod 32) at
+    (r, c div 32): the input COO is dedup'd, so add ≡ bitwise-or (each
+    bit lands exactly once).
 
     The scan walks the pairs it counts: the kept slots (strict lower
     triangle, first of a run of repeats) are brought to the front of
@@ -183,14 +113,15 @@ def _tc_edge_harvest_bits(rows, cols, n: int, chunk: int = 8192):
     ``ceil(edges / chunk)`` steps that hold one.
 
     Returns ``(hilo, pairs, edges)``: the (hi, lo) int32 split of 3·T
-    like ``_tc_edge_harvest``, the pair slots the scan walks (the kept
+    (``combine_hilo`` // 3 gives T; 3·T can exceed 2^31 — same split
+    rationale as ``_tc_dense``), the pair slots the scan walks (the kept
     pairs, chunk-padded: ``steps * chunk``; 0 where nothing is kept)
     and the pairs of weight 1 (the undirected edges counted).
     """
     # ON-DEVICE DEDUP (duplicate COO entries would double-add a bit,
-    # carrying into the NEXT bit and corrupting the adjacency — unlike
-    # the idempotent .set of the bf16 variant): mask repeats, zero their
-    # bit contribution AND their edge weight.
+    # carrying into the NEXT bit and corrupting the adjacency, and the
+    # edge walk would harvest their common neighbors twice): mask
+    # repeats, zero their bit contribution AND their edge weight.
     with jax.named_scope("tc.dedup"):
         rows, cols, dup = _coo_sort_dedup(rows, cols)
         loops = rows == cols
@@ -349,6 +280,10 @@ def tc_job(A: SpParMat) -> tuple[int, int, int]:
     return triangles, pairs, edges
 
 
+#: The kernels ``triangle_count`` can be told to run.
+TC_KERNELS = ("auto", "dense", "edgeharvest", "sparse")
+
+
 def triangle_count(A: SpParMat, kernel: str = "auto") -> int:
     """Number of triangles in the simple undirected graph A (symmetric,
     loop-free nonzero structure).
@@ -367,8 +302,13 @@ def triangle_count(A: SpParMat, kernel: str = "auto") -> int:
     masked-SpGEMM path (TC.cpp:104-116 flow), the fallback beyond the
     mask budget and on non-square grids; NOTE it expects a deduplicated
     edge list (values are wedge counts), while the harvest kernels
-    dedup on device.
+    dedup on device.  Any other ``kernel`` is a ``ValueError``.
     """
+    if kernel not in TC_KERNELS:
+        raise ValueError(
+            f"kernel must be one of {', '.join(TC_KERNELS)}; "
+            f"got {kernel!r}"
+        )
     p = A.grid.pr
     # distributed bitmask budget: two n²/(8p)-byte tables per device must
     # fit the single-shard kernel's one-table HBM envelope
@@ -393,18 +333,10 @@ def triangle_count(A: SpParMat, kernel: str = "auto") -> int:
         return _tc_combine(
             jax.jit(_tc_dense, static_argnums=2)(t.rows, t.cols, A.nrows)
         )
-    if kernel in ("edgeharvest", "edgeharvest_bf16"):
+    if kernel == "edgeharvest":
         if obs.ENABLED:
             obs.count("spgemm.auto.tier", tier=kernel, sr="plus_times")
         if A.grid.size > 1:
-            # the DISTRIBUTED oracle tier: only the bit-packed variant
-            # (the bf16 one has no distributed formulation — its gather
-            # traffic is the reason the bitmask exists)
-            if kernel != "edgeharvest":
-                raise ValueError(
-                    "distributed edge-harvest supports kernel="
-                    f"'edgeharvest' only, got {kernel}"
-                )
             if max(A.nrows, A.ncols) > dist_bits_cap:
                 raise ValueError(
                     "distributed edgeharvest needs two n^2/(8p)-byte "
@@ -412,19 +344,7 @@ def triangle_count(A: SpParMat, kernel: str = "auto") -> int:
                     f"{p}x{p} grid, got {max(A.nrows, A.ncols)}"
                 )
             return combine_hilo(_tc_edge_harvest_dist(A)) // 3
-        if kernel == "edgeharvest":
-            return tc_job(A)[0]
-        if max(A.nrows, A.ncols) > EDGE_HARVEST_MAX_DIM:
-            raise ValueError(
-                f"{kernel} needs the dense adjacency in HBM: "
-                f"n <= {EDGE_HARVEST_MAX_DIM}, got {max(A.nrows, A.ncols)}"
-            )
-        t = A.local_tile(A.rows, A.cols, A.vals, A.nnz)
-        return _tc_combine(
-            jax.jit(_tc_edge_harvest, static_argnums=2)(
-                t.rows, t.cols, A.nrows
-            )
-        ) // 3
+        return tc_job(A)[0]
     L = A.remove_loops().tril(strict=True).apply(ones_f32)
     B = spgemm(PLUS_TIMES, L, L)  # B[i,j] = # wedges i->k->j with i>k>j
     C = B.ewise_mult(L)  # keep wedge counts only where edge (i,j) closes

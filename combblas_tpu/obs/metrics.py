@@ -168,73 +168,22 @@ event per stage at trace time (fields ``stage``,
 trace export shows the planned comm/compute overlap structure of the
 compiled schedule.
 
-Autotuner / plan-store series (round 10 — the measured-cost plan store
-and micro-probe pass, docs/autotuning.md):
+Windowed-tier dispatch series (round 10):
 
 ===================================  =======  =========================
 name                                 kind     meaning
 ===================================  =======  =========================
-``tuner.store.hits``                 counter  routing decisions served
-                                              from a remembered plan
-                                              (labels: ``op`` =
-                                              spgemm / spgemm3d)
-``tuner.store.misses``               counter  lookups with no matching
-                                              plan (probe or fallback
-                                              follows)
-``tuner.store.entries``              gauge    plans currently loaded
-                                              (labels: ``dir``); also
-                                              published by the
-                                              compile-cache provider
-                                              as ``compile_cache.
-                                              entries{cache=plans}`` —
-                                              one health surface for
-                                              both caches
-``tuner.store.invalid``              counter  corrupted / truncated /
-                                              schema-mismatched JSONL
-                                              lines skipped at load
-``tuner.store.write_errors``         counter  failed store appends
-                                              (read-only replica; the
-                                              in-memory plan still
-                                              routes)
-``tuner.probe.runs``                 counter  candidate rungs measured
-                                              by the micro-probe pass
-                                              (labels: ``tier``)
-``tuner.probe.seconds``              counter  cumulative timed probe
-                                              seconds (the obs-visible
-                                              probe cost)
-``tuner.probe.winner``               counter  probe passes won per
-                                              tier (labels: ``tier``)
-``tuner.probe.errors``               counter  candidate rungs that
-                                              faulted on the proxy
-                                              (dropped, not fatal)
-``tuner.probe.budget_exhausted``     counter  probe passes cut short
-                                              by the probe budget
-``tuner.store.rejected``             counter  key-matched records
-                                              DISCARDED at routing
-                                              (labels: ``reason`` =
-                                              tier / no_grid3 / dup) —
-                                              pair with ``hits`` to
-                                              read the true hit rate
 ``spgemm.windowed.dispatch_conflict``  counter  ring requests that
                                               overrode an explicit
                                               blocked dispatch (ring
                                               is fused-only; the more
                                               specific ask wins)
-``spgemm.auto.plan_source``          counter  WHERE each routing came
-                                              from: labels ``source``
-                                              (arg / store / env /
-                                              probe / heuristic),
-                                              ``tier``, ``op``
 ``spgemm.windowed.dispatch``         counter  windowed-tier program
                                               decomposition per call:
                                               labels ``mode`` (local /
                                               fused / blocked — the
                                               building-block default)
 ===================================  =======  =========================
-
-The ``tuner.probe`` span wraps each probe pass (attrs ``sr``, proxy
-``dim``), so trace exports show probe cost inline with the product that
-paid it.
 
 Dynamic-graph mutation series (round 11 — delta buffers, incremental
 version builds, warm-restart recompute, the serve write lane;
@@ -309,18 +258,11 @@ name                                  kind       meaning
                                                  ``exc_type``
 ``serve.update.coalesced``            histogram  ops per merged batch
                                                  (write coalescing)
-``tuner.store.compacted``             counter    superseded/evicted
-                                                 JSONL lines removed by
-                                                 the load-time
-                                                 compaction rewrite
-``tuner.store.evicted``               counter    plans dropped by the
-                                                 max-entries
-                                                 oldest-cost eviction
 ====================================  =========  =======================
 
 Batched-SpMM / propagate-lane series (round 12 — the MXU-resident
-SpMM kernel family, the ``"propagate"`` serve kind, headroom-aware
-bucket sizing and window-geometry probing; docs/spmm.md):
+SpMM kernel family, the ``"propagate"`` serve kind and
+headroom-aware bucket sizing; docs/spmm.md):
 
 ====================================  =======  =========================
 name                                  kind     meaning
@@ -355,11 +297,6 @@ name                                  kind     meaning
                                                stripped; the pow2 pad
                                                width is the compiled
                                                shape)
-``spgemm.auto.plan_source``           counter  gains ``op="spmm"``
-                                               rows: where each SpMM
-                                               backend resolution came
-                                               from (arg / store / env
-                                               / probe / heuristic)
 ``dynamic.merge.headroom_used``       counter  free padding slots
                                                claimed by re-bucketing
                                                rows (the
@@ -367,14 +304,6 @@ name                                  kind     meaning
                                                reserve paying off
                                                instead of a
                                                ``bucket_full`` spill)
-``tuner.probe.geometry_runs``         counter  windowed block-geometry
-                                               candidates measured by
-                                               the probe's
-                                               window-geometry sweep
-                                               (the winner persists
-                                               with ``block_rows`` /
-                                               ``block_cols`` in its
-                                               plan record)
 ====================================  =======  =========================
 
 Merge-tier / 3D-carousel series (round 13 — the sort-free fiber
@@ -389,14 +318,12 @@ name                                  kind     meaning
                                                resolved (labels
                                                ``tier`` = sort / runs
                                                / hash, ``source`` =
-                                               arg / store / env /
-                                               probe / heuristic /
+                                               arg / heuristic /
                                                hash_fallback, with a
                                                ``_degraded`` suffix
                                                when a forced hash on a
                                                generic monoid degraded
-                                               to runs at the knob,
-                                               and ``op``)
+                                               to runs, and ``op``)
 ``spgemm.merge.hash_overflow``        counter  entries the hash tier's
                                                bounded table failed to
                                                place (the product
@@ -1035,24 +962,6 @@ name                                      kind       meaning
                                                      replica lags the
                                                      home (labels
                                                      ``replica``)
-``tuner.store.compact_skipped``           counter    plan-store
-                                                     compactions
-                                                     skipped on
-                                                     advisory-lock
-                                                     contention (a
-                                                     sibling process
-                                                     is compacting) —
-                                                     the next loader
-                                                     compacts instead
-``tuner.store.append_unfenced``           counter    plan appends that
-                                                     proceeded without
-                                                     the shared fence
-                                                     after the bounded
-                                                     non-blocking
-                                                     retries (a wedged
-                                                     lock holder must
-                                                     never hang the
-                                                     write path)
 ========================================  =========  ==================
 
 Fleet observability plane (round 18, the serve/procfleet.py +

@@ -476,9 +476,8 @@ def _fiber_exchange(partial_c: SpTuples, L: int, w_out: int,
     return runs, worst
 
 
-#: Valid fiber-reduce combine tiers (docs/spgemm.md "merge tiers") —
-#: the ONE definition lives with the env vetting in tuner/config.py.
-from ..tuner.config import MERGE_TIER_NAMES as MERGE_TIERS  # noqa: E402
+#: Valid fiber-reduce combine tiers (docs/spgemm.md "merge tiers").
+MERGE_TIERS = ("sort", "runs", "hash")
 
 #: Probe rounds of the hash merge tier before the counted overflow
 #: fallback kicks in (load factor <= 0.25 via ``hash_table_capacity``
@@ -532,8 +531,8 @@ def _fiber_merge(
 
 def _merge_heuristic(sr: Semiring, L: int, expansion_ratio: float,
                      pieces_sorted: bool) -> str:
-    """The merge-tier heuristic rung (arg > store > env > THIS):
-    ``runs`` when the pieces arrive already sorted — the windowed
+    """The merge tier where no argument names one: ``runs`` when the
+    pieces arrive already sorted — the windowed
     tiers' structural freebie (no sort anywhere in the reduce; the
     r13 capture's 1.87x) always beats speculating on the hash table;
     ``hash`` for UNSORTED producers at high layer counts with heavy
@@ -542,9 +541,8 @@ def _merge_heuristic(sr: Semiring, L: int, expansion_ratio: float,
     both the pre-sorts and the one concat sort; ``sort`` otherwise
     (unsorted producers at low L — the r13 scale-12 sweep measured
     the piece pre-sort + union LOSING to the one concat sort at L=2).
-    CPU-mesh-measured thresholds; the
-    plan store / probe override per key, and a TPU re-measure is an
-    open ROADMAP item."""
+    CPU-mesh-measured thresholds; a TPU re-measure is an open
+    ROADMAP item."""
     from ..ops.spgemm import scatter_combine_for
 
     if pieces_sorted:
@@ -1111,28 +1109,19 @@ def spgemm3d_windowed(
     merge: str | None = None,
     ring: bool = False,
     pipeline: bool = True,
-    merge_source: str | None = None,
 ) -> SpParMat3D:
     """Sized entry for the windowed 3D tier: 3D symbolic pass →
     ``windowed_plan3d`` (caps maxed over layers) → the compiled
-    ``summa3d_spgemm_windowed``.  Both accumulate backends; benchmarks
-    on readback-poisoned hardware size on host via
-    ``summa3d_window_flops_host`` + ``summa3d_window_bnnz_host`` and
-    call the kernel directly.
+    ``summa3d_spgemm_windowed``.  Both accumulate backends.
 
     ``merge`` picks the fiber-reduce combine tier (``MERGE_TIERS``;
-    ``None`` resolves env ``COMBBLAS_SPGEMM_MERGE`` > the L/collision
-    heuristic — callers with a plan record pass its merge explicitly,
-    holding the arg > store > env > heuristic chain).  ``ring``/
-    ``pipeline`` pick the per-layer SUMMA schedule (the r9 carousel).
-    A hash-tier placement overflow is COUNTED
+    ``None`` is the L/collision heuristic, ``_merge_heuristic``).
+    ``ring``/``pipeline`` pick the per-layer SUMMA schedule (the r9
+    carousel).  A hash-tier placement overflow is COUNTED
     (``spgemm.merge.hash_overflow``) and the product transparently
     reruns through the sorted-runs tier — never wrong, only slower.
     A fiber piece overflow raises a diagnostic naming the ``slack``
-    knob instead of truncating downstream.  ``merge_source`` labels
-    the ``spgemm.merge.tier`` counter when a ROUTER (``spgemm3d``)
-    already resolved ``merge`` from its store/env rung — direct
-    callers leave it None ("arg")."""
+    knob instead of truncating downstream."""
     from .spgemm import (
         WINDOWED_CHUNK_W,
         default_block_cols,
@@ -1143,7 +1132,6 @@ def spgemm3d_windowed(
         panel_cap_from_bnnz,
         resolve_spgemm_backend,
     )
-    from ..tuner import config as tuner_config
 
     backend = resolve_spgemm_backend(backend)
     grid = A3.grid
@@ -1196,11 +1184,7 @@ def spgemm3d_windowed(
     rnd = lambda x: 1 << (max(int(x), 1) - 1).bit_length()
     piece_cap = rnd(min(sum(per_block_bound), lr * lcB))
     out_cap = min(rnd(piece_cap * L), max(lr * (lcB // L), 1))
-    if merge is not None and merge_source is None:
-        merge_source = "arg"
-    if merge is None:
-        merge = tuner_config.env_merge()
-        merge_source = "env" if merge is not None else None
+    merge_source = "heuristic" if merge is None else "arg"
     if merge is None:
         # collision estimate: total merge-input slots over the
         # distinct-key bound — ≈ how many partial entries fold into
@@ -1208,7 +1192,6 @@ def spgemm3d_windowed(
         merge = _merge_heuristic(
             sr, L, piece_cap * L / max(out_cap, 1), pieces_sorted
         )
-        merge_source = "heuristic"
     assert merge in MERGE_TIERS, merge
     if obs.ENABLED:
         obs.gauge("spgemm.summa3d.layers", L)
@@ -1220,32 +1203,34 @@ def spgemm3d_windowed(
             "spgemm.merge.tier", tier=merge, source=merge_source,
             op="spgemm3d",
         )
-    C, overflow = summa3d_spgemm_windowed(
-        sr, A3, B3, block_rows=block_rows, flop_caps=flop_caps,
-        out_caps=out_caps, skip=skip, backend=backend, mode=mode,
-        chunk_w=chunk_w, interpret=interpret, block_cols=block_cols,
-        panel_cap=panel_cap, piece_capacity=piece_cap,
-        out_capacity=out_cap, ring=ring, pipeline=pipeline,
-        merge=merge,
-    )
-    extract_over, piece_over, merge_over, hash_over = (
-        int(x) for x in np.asarray(host_value(overflow))
-    )
-    _check_fiber_overflow(piece_over, piece_cap, "spgemm3d_windowed",
-                          slack)
+
+    def run_kernel(mg):
+        C, overflow = summa3d_spgemm_windowed(
+            sr, A3, B3, block_rows=block_rows, flop_caps=flop_caps,
+            out_caps=out_caps, skip=skip, backend=backend, mode=mode,
+            chunk_w=chunk_w, interpret=interpret, block_cols=block_cols,
+            panel_cap=panel_cap, piece_capacity=piece_cap,
+            out_capacity=out_cap, ring=ring, pipeline=pipeline,
+            merge=mg,
+        )
+        over = tuple(int(x) for x in np.asarray(host_value(overflow)))
+        _check_fiber_overflow(over[1], piece_cap, "spgemm3d_windowed",
+                              slack)
+        return (C,) + over
+
+    C, extract_over, _, merge_over, hash_over = run_kernel(merge)
     if hash_over > 0:
         # counted fallback: the hash table failed to place hash_over
-        # entries — rerun through the sorted-runs tier (never wrong,
-        # only slower); the counter is how operators notice a
-        # mis-sized table / mis-routed plan
+        # entries — rerun the ALREADY-SIZED kernel through the
+        # sorted-runs tier (never wrong, only slower); the counter is
+        # how operators notice a mis-sized table
         if obs.ENABLED:
             obs.count("spgemm.merge.hash_overflow", hash_over)
-        return spgemm3d_windowed(
-            sr, A3, B3, block_rows=block_rows, block_cols=block_cols,
-            backend=backend, mode=mode, slack=slack,
-            interpret=interpret, merge="runs", ring=ring,
-            pipeline=pipeline, merge_source="hash_fallback",
-        )
+            obs.count(
+                "spgemm.merge.tier", tier="runs",
+                source="hash_fallback", op="spgemm3d",
+            )
+        C, extract_over, _, merge_over, _ = run_kernel("runs")
     assert extract_over <= 0 and merge_over <= 0, (
         f"windowed 3D tier overflowed its symbolic bound "
         f"(extraction {extract_over}, merge {merge_over})"
@@ -1278,117 +1263,37 @@ def spgemm3d(
     *, tier: str | None = None, backend: str | None = None,
     mode: str = "f32", block_rows: int | None = None,
     block_cols: int | None = None, interpret: bool = False,
-    merge: str | None = None, ring: bool | None = None,
-    pipeline: bool | None = None, merge_source: str | None = None,
+    merge: str | None = None, ring: bool = False,
+    pipeline: bool = True,
 ) -> SpParMat3D:
     """Unjitted entry: distributed symbolic sizing → compiled 3D SUMMA.
 
-    ``tier`` picks the per-layer local kernel: ``"esc"`` (default — the
-    classic expand/sort/compress stage kernel, exact for every
-    semiring) or ``"windowed"`` (the sort-free dense-window tier,
-    ``spgemm3d_windowed``).  Resolution follows the tuner precedence
-    (tuner/config.py): argument > plan store (``op="spgemm3d"``
-    records, written by benches/operators or the opt-in real-operand
-    probe) > env ``COMBBLAS_SPGEMM3D_TIER`` > probe
-    (``COMBBLAS_TUNER_PROBE=1``: ``tuner.probe.probe_spgemm3d``
-    measures admissible (tier, merge) pairs on the REAL operands and
-    persists the winner) > ``"esc"``.  The ESC sizing pass mirrors
+    ``tier`` picks the per-layer local kernel: ``"esc"`` (``None``'s
+    default — the classic expand/sort/compress stage kernel, exact for
+    every semiring) or ``"windowed"`` (the sort-free dense-window tier,
+    ``spgemm3d_windowed``).  Nothing else decides: no environment
+    variable, no file, no timed guess.  The ESC sizing pass mirrors
     ``EstPerProcessNnzSUMMA``'s role (ParFriends.h:1243); capacities
     round to powers of two (clamped to the dense-tile bound) for
     compile-cache reuse.
 
     ``merge`` picks the fiber-reduce combine tier (``MERGE_TIERS``:
-    sort | runs | hash), resolved arg > store record > env
-    ``COMBBLAS_SPGEMM_MERGE`` > heuristic on L and the collision
-    estimate.  ``ring``/``pipeline`` are tri-state (None = defer to
-    the record, then the kernel defaults): the per-layer SUMMA's
-    carousel schedule.
+    sort | runs | hash); ``None`` is the heuristic on L and the
+    collision estimate (``_merge_heuristic``).  ``ring``/``pipeline``:
+    the per-layer SUMMA's carousel schedule.
     """
     from .. import obs
     from ..ops.spgemm import scatter_combine_for
-    from ..tuner import config as tuner_config
-    from ..tuner import store as tuner_store
-    from ..tuner.resolve import resolve_merge
 
-    plan_source = "arg" if tier is not None else None
-    st = rec = None
-    if tier is None:
-        st = tuner_store.get_store()
-        # key construction costs host nnz readbacks (D2H syncs) — only
-        # pay it when the store holds plans OR the opt-in probe would
-        # persist one under the key (no device readback for nothing)
-        if st is not None and (
-            st.entries() > 0 or tuner_config.probe_enabled()
-        ):
-            key = tuner_store.spgemm3d_plan_key(
-                sr, A, B,
-                backend or tuner_config.env_backend() or "",
-            )
-            rec = st.lookup(key) if st.entries() > 0 else None
-            if rec is not None and rec.tier not in ("esc", "windowed"):
-                # a key-matched record with a non-3D tier is discarded
-                # — made visible, like the 2D router, so hits-vs-
-                # plan_source can't silently contradict
-                if obs.ENABLED:
-                    obs.count("tuner.store.rejected", reason="tier")
-                rec = None
-            if rec is not None:
-                tier = rec.tier
-                plan_source = "store"
-                if block_rows is None:
-                    block_rows = rec.block_rows
-                if block_cols is None:
-                    block_cols = rec.block_cols
-                # tri-state schedule flags: an explicit arg beats the
-                # record, None defers to it (the spgemm_auto contract)
-                if ring is None:
-                    ring = rec.ring
-                if pipeline is None:
-                    pipeline = rec.pipeline
-    if tier is None:
-        tier = tuner_config.env_tier3d()
-        if tier is not None:
-            plan_source = "env"
-    if tier is None and st is not None and tuner_config.probe_enabled():
-        from ..tuner.probe import probe_spgemm3d
-
-        prec = probe_spgemm3d(sr, A, B, store=st, key=key)
-        if prec is not None:
-            tier = prec.tier
-            plan_source = "probe"
-            rec = prec
-            if ring is None:
-                ring = prec.ring
-            if pipeline is None:
-                pipeline = prec.pipeline
     if tier is None:
         tier = "esc"
-        plan_source = "heuristic"
-    # merge tier: arg > store record > env (heuristic resolves inside
-    # the sized entries, where the collision estimate exists).
-    # ``merge_source`` overrides the label when a CALLER already
-    # resolved merge (the hash-overflow rerun below).
-    caller_source = merge_source
-    merge, merge_source = resolve_merge(merge, rec)
-    if caller_source is not None:
-        merge_source = caller_source
-    elif merge_source == "store" and plan_source == "probe":
-        # the record came from this call's probe pass, not the store
-        merge_source = "probe"
-    if obs.ENABLED:
-        obs.count(
-            "spgemm.auto.plan_source", source=plan_source, tier=tier,
-            op="spgemm3d",
-        )
     assert tier in ("esc", "windowed"), tier
-    ring = False if ring is None else bool(ring)
-    pipeline = True if pipeline is None else bool(pipeline)
     if tier == "windowed":
         return spgemm3d_windowed(
             sr, A, B, block_rows=block_rows, block_cols=block_cols,
             backend=backend, mode=mode, slack=max(slack - 0.03, 1.02),
             interpret=interpret, merge=merge, ring=ring,
-            pipeline=pipeline, merge_source=merge_source,
+            pipeline=pipeline,
         )
     if ring and not pipeline:
         # the ESC ring rides _carousel_stages, which is ALWAYS
@@ -1413,6 +1318,7 @@ def spgemm3d(
     rnd = lambda x: 1 << (x - 1).bit_length()
     piece_cap = rnd(piece_cap)
     out_cap = min(rnd(out_cap), max(dense_tile, 1))
+    merge_source = "heuristic" if merge is None else "arg"
     if merge is None:
         # ESC stage chunks are UNSORTED (pieces_sorted=False): "runs"
         # would pay L piece-local pre-sorts, so the heuristic keeps the
@@ -1421,12 +1327,10 @@ def spgemm3d(
         merge = _merge_heuristic(
             sr, L, piece_cap * L / max(out_cap, 1), pieces_sorted=False
         )
-        merge_source = "heuristic"
     if merge == "hash" and scatter_combine_for(sr) is None:
-        # a forced hash (env/record/arg) on a generic monoid must
-        # DEGRADE at the knob, not assert mid-trace inside the
-        # shard_map body — the 2D spgemm entry's convention; runs is
-        # exact for every semiring
+        # a forced hash on a generic monoid must DEGRADE here, not
+        # assert mid-trace inside the shard_map body — the 2D spgemm
+        # entry's convention; runs is exact for every semiring
         merge = "runs"
         merge_source = f"{merge_source}_degraded"
     assert merge in MERGE_TIERS, merge
@@ -1451,8 +1355,7 @@ def spgemm3d(
     _check_fiber_overflow(piece_over, piece_cap, "spgemm3d", slack)
     if hash_over > 0:
         # counted fallback: rerun the ALREADY-SIZED kernel through the
-        # sorted-runs tier (no re-entry into the routing entry — one
-        # logical call counts one plan_source resolution)
+        # sorted-runs tier (no re-entry into the routing entry)
         if obs.ENABLED:
             obs.count("spgemm.merge.hash_overflow", hash_over)
             obs.count(

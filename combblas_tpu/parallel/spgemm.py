@@ -385,9 +385,7 @@ def summa_capacities(A: SpParMat, B: SpParMat, slack: float = 1.05):
     clamped to the dense tile size. ``slack`` covers the float32 rounding of
     the counts plus headroom for reusing compiled code across inputs.
 
-    NOTE: reads the device symbolic pass back to host (one sync); a
-    caller that still holds the host COO can size with
-    ``summa_capacities_host`` and no device readback.
+    NOTE: reads the device symbolic pass back to host (one sync).
     """
     per_stage = host_value(summa_stage_flops(A, B)).astype(np.float64)
     if obs.ENABLED:
@@ -409,71 +407,6 @@ def _record_symbolic_metrics(per_stage: np.ndarray) -> None:
         "spgemm.load_imbalance",
         float(per_tile.max() / mean) if mean > 0 else 1.0,
     )
-
-
-def summa_stage_flops_host(
-    grid, rows_a, cols_a, rows_b, cols_b,
-    nrows_a: int, ncols_a: int, ncols_b: int,
-    padded: bool = True,
-) -> np.ndarray:
-    """Host-numpy twin of ``summa_stage_flops``: [p, pr, pc] flop counts
-    computed from global COO arrays, with zero device interaction.
-
-    For benchmarking on hardware where any D2H readback degrades later
-    launches, the symbolic sizing must happen before upload; this computes
-    the identical per-stage per-tile counts from the same owner math.
-    """
-    pr_, pc_ = grid.pr, grid.pc
-    assert pr_ == pc_, "SUMMA requires a square grid"
-    p = pr_
-    lrA = grid.local_rows(nrows_a)
-    lcA = grid.local_cols(ncols_a)
-    lrB = grid.local_rows(ncols_a)
-    lcB = grid.local_cols(ncols_b)
-    assert lcA == lrB, "A col-blocking must equal B row-blocking"
-    from ..ops.spgemm import CHUNK_W
-
-    rows_a = np.asarray(rows_a, np.int64)
-    cols_a = np.asarray(cols_a, np.int64)
-    rows_b = np.asarray(rows_b, np.int64)
-    cols_b = np.asarray(cols_b, np.int64)
-    # countA[i, s, k] = nnz of A-tile (i,s) in local column k
-    ia, sa, ka = rows_a // lrA, cols_a // lcA, cols_a % lcA
-    countA = np.bincount(
-        (ia * p + sa) * lcA + ka, minlength=p * p * lcA
-    ).reshape(p, p, lcA)
-    # countB[s, j, k] = nnz of B-tile (s,j) in local row k
-    sb, jb, kb = rows_b // lrB, cols_b // lcB, rows_b % lrB
-    countB = np.bincount(
-        (sb * p + jb) * lrB + kb, minlength=p * p * lrB
-    ).reshape(p, p, lrB)
-    if padded:  # chunked-expansion slots (see summa_stage_flops)
-        countB = -(-countB // CHUNK_W) * CHUNK_W
-    # flops[s, i, j] = sum_k countA[i,s,k] * countB[s,j,k]
-    return np.einsum(
-        "isk,sjk->sij", countA.astype(np.float64), countB.astype(np.float64)
-    )
-
-
-def summa_capacities_host(
-    grid, rows_a, cols_a, rows_b, cols_b,
-    nrows_a: int, ncols_a: int, ncols_b: int, slack: float = 1.05,
-    per_stage: np.ndarray | None = None,
-):
-    """Host-only twin of ``summa_capacities`` (flop_capacity, out_capacity)
-    from global COO arrays — host-side sizing, no device readback (the
-    benchmarks size capacities this way before any upload).
-
-    Pass a precomputed ``per_stage`` (from ``summa_stage_flops_host``) to
-    avoid recomputing the O(nnz) symbolic pass."""
-    if per_stage is None:
-        per_stage = summa_stage_flops_host(
-            grid, rows_a, cols_a, rows_b, cols_b, nrows_a, ncols_a, ncols_b
-        )
-    if obs.ENABLED:
-        _record_symbolic_metrics(np.asarray(per_stage, np.float64))
-    dense_tile = grid.local_rows(nrows_a) * grid.local_cols(ncols_b)
-    return _caps_from_stage_flops(per_stage, dense_tile, slack)
 
 
 def summa_rowblock_flops(
@@ -552,47 +485,6 @@ def summa_rowblock_flops_pair(
         out_specs=P(),
         check_vma=False,
     )(A.rows, A.cols, B.rows)
-
-
-def summa_rowblock_flops_host(
-    grid, rows_a, cols_a, rows_b, cols_b,
-    nrows_a: int, ncols_a: int, ncols_b: int,
-    block_rows: int, chunk_w: int = 0,
-) -> np.ndarray:
-    """Host-numpy twin of ``summa_rowblock_flops`` from global COO arrays
-    (host-side sizing, no device interaction, like
-    ``summa_stage_flops_host``)."""
-    pr_, pc_ = grid.pr, grid.pc
-    assert pr_ == pc_, "SUMMA requires a square grid"
-    p = pr_
-    lrA = grid.local_rows(nrows_a)
-    lcA = grid.local_cols(ncols_a)
-    lrB = grid.local_rows(ncols_a)
-    assert lcA == lrB, "A col-blocking must equal B row-blocking"
-    nblocks = -(-lrA // block_rows)
-    rows_a = np.asarray(rows_a, np.int64)
-    cols_a = np.asarray(cols_a, np.int64)
-    rows_b = np.asarray(rows_b, np.int64)
-    cols_b = np.asarray(cols_b, np.int64)
-    ia, sa, ka = rows_a // lrA, cols_a // lcA, cols_a % lcA
-    g = (rows_a % lrA) // block_rows
-    countA = np.bincount(
-        (((ia * p + sa) * nblocks) + g) * lcA + ka,
-        minlength=p * p * nblocks * lcA,
-    ).reshape(p, p, nblocks, lcA)
-    sb, kb = rows_b // lrB, rows_b % lrB
-    lcB = grid.local_cols(ncols_b)
-    jb = cols_b // lcB
-    countB = np.bincount(
-        (sb * p + jb) * lrB + kb, minlength=p * p * lrB
-    ).reshape(p, p, lrB)
-    if chunk_w:
-        countB = -(-countB // chunk_w) * chunk_w
-    # flops[g, s, i, j] = sum_k countA[i, s, g, k] * countB[s, j, k]
-    return np.einsum(
-        "isgk,sjk->gsij",
-        countA.astype(np.float64), countB.astype(np.float64),
-    )
 
 
 def _window_stage_symbolic(
@@ -700,50 +592,6 @@ def summa_window_flops_pair(
     )(A.rows, A.cols, B.rows, B.cols)
 
 
-def summa_window_flops_host(
-    grid, rows_a, cols_a, rows_b, cols_b,
-    nrows_a: int, ncols_a: int, ncols_b: int,
-    block_rows: int, block_cols: int, chunk_w: int = 0,
-) -> np.ndarray:
-    """Host-numpy twin of ``summa_window_flops_pair`` (one chunk_w at a
-    time): [nblocks, ncolwin, p, pr, pc] float64 from global COO arrays,
-    zero device interaction — the host-side 2D sizing path."""
-    pr_, pc_ = grid.pr, grid.pc
-    assert pr_ == pc_, "SUMMA requires a square grid"
-    p = pr_
-    lrA = grid.local_rows(nrows_a)
-    lcA = grid.local_cols(ncols_a)
-    lrB = grid.local_rows(ncols_a)
-    lcB = grid.local_cols(ncols_b)
-    assert lcA == lrB, "A col-blocking must equal B row-blocking"
-    nblocks = -(-lrA // block_rows)
-    ncw = -(-lcB // block_cols)
-    rows_a = np.asarray(rows_a, np.int64)
-    cols_a = np.asarray(cols_a, np.int64)
-    rows_b = np.asarray(rows_b, np.int64)
-    cols_b = np.asarray(cols_b, np.int64)
-    ia, sa, ka = rows_a // lrA, cols_a // lcA, cols_a % lcA
-    g = (rows_a % lrA) // block_rows
-    countA = np.bincount(
-        (((ia * p + sa) * nblocks) + g) * lcA + ka,
-        minlength=p * p * nblocks * lcA,
-    ).reshape(p, p, nblocks, lcA)
-    sb, kb = rows_b // lrB, rows_b % lrB
-    jb = cols_b // lcB
-    hb = (cols_b % lcB) // block_cols
-    countB = np.bincount(
-        (((sb * p + jb) * ncw) + hb) * lrB + kb,
-        minlength=p * p * ncw * lrB,
-    ).reshape(p, p, ncw, lrB)
-    if chunk_w:
-        countB = -(-countB // chunk_w) * chunk_w
-    # flops[g, h, s, i, j] = sum_k countA[i,s,g,k] * countB[s,j,h,k]
-    return np.einsum(
-        "isgk,sjhk->ghsij",
-        countA.astype(np.float64), countB.astype(np.float64),
-    )
-
-
 @partial(jax.jit, static_argnames=("block_cols",))
 def summa_window_bnnz(B: SpParMat, block_cols: int) -> jax.Array:
     """[pr, pc, ncolwin] int32, replicated: B-tile nnz per col window —
@@ -771,23 +619,6 @@ def summa_window_bnnz(B: SpParMat, block_cols: int) -> jax.Array:
         out_specs=P(),
         check_vma=False,
     )(B.rows, B.cols)
-
-
-def summa_window_bnnz_host(
-    grid, rows_b, cols_b, ncols_a: int, ncols_b: int, block_cols: int
-) -> np.ndarray:
-    """Host twin of ``summa_window_bnnz``: [pr, pc, ncolwin]."""
-    lrB = grid.local_rows(ncols_a)
-    lcB = grid.local_cols(ncols_b)
-    ncw = -(-lcB // block_cols)
-    rows_b = np.asarray(rows_b, np.int64)
-    cols_b = np.asarray(cols_b, np.int64)
-    sb, jb = rows_b // lrB, cols_b // lcB
-    hb = (cols_b % lcB) // block_cols
-    return np.bincount(
-        ((sb * grid.pc + jb) * ncw) + hb,
-        minlength=grid.pr * grid.pc * ncw,
-    ).reshape(grid.pr, grid.pc, ncw)
 
 
 def windowed_plan_2d(
@@ -1631,7 +1462,6 @@ def spgemm(
     *,
     pow2_caps: bool = True,
     merge: str | None = None,
-    merge_source: str | None = None,
 ) -> SpParMat:
     """Convenience: symbolic pass → sized numeric SUMMA (unjitted entry).
 
@@ -1642,26 +1472,16 @@ def spgemm(
     slack) so iterative callers (MCL's expand loop, BC's per-level products)
     hit the XLA compilation cache instead of recompiling for every new nnz.
 
-    ``merge``: the ESC stage-chunk combine (sort | runs) — ``None``
-    resolves env ``COMBBLAS_SPGEMM_MERGE`` > ``"sort"`` (the classic
-    path; ``spgemm_auto`` threads a plan record's remembered merge
-    through with ``merge_source="store"`` so the provenance counter
-    stays honest).  ``"hash"`` is a 3D-fiber tier; here it degrades
-    to ``"runs"`` (the expansion-sized chunks would swamp an
-    out-capacity table).
+    ``merge``: the ESC stage-chunk combine (sort | runs) — ``None`` is
+    ``"sort"`` (the classic path).  ``"hash"`` is a 3D-fiber tier; here
+    it degrades to ``"runs"`` (the expansion-sized chunks would swamp
+    an out-capacity table).
     """
-    from ..tuner import config as tuner_config
-
-    if merge is not None and merge_source is None:
-        merge_source = "arg"
-    if merge is None:
-        merge = tuner_config.env_merge()
-        merge_source = "env" if merge is not None else None
-    if merge == "hash":
-        merge = "runs"
+    merge_source = "heuristic" if merge is None else "arg"
     if merge is None:
         merge = "sort"
-        merge_source = "heuristic"
+    elif merge == "hash":
+        merge = "runs"
     with obs.span("spgemm", sr=sr.name):
         if obs.ENABLED:
             obs.count(
@@ -2439,13 +2259,8 @@ def summa_spgemm_windowed_blocked(
 
 def resolve_spgemm_backend(backend: str | None = None) -> str:
     """Accumulate-backend resolution, shared by the router and the sized
-    entries: explicit argument > ``COMBBLAS_SPGEMM_BACKEND`` env (parsed
-    by ``tuner.config``, the one knob parser) > the platform default
-    (``dot`` on TPU — no scatter unit — ``scatter`` elsewhere)."""
-    from ..tuner import config as tuner_config
-
-    if backend is None:
-        backend = tuner_config.env_backend()
+    entries: the argument, else the platform default (``dot`` on TPU —
+    no scatter unit — ``scatter`` elsewhere)."""
     if backend is None:
         backend = "dot" if jax.default_backend() == "tpu" else "scatter"
     assert backend in ("dot", "scatter"), backend
@@ -2462,7 +2277,7 @@ def bucket_plan_caps(flop_caps, out_caps):
     block geometry re-impose the cells clamp afterwards (the pow2 round
     can exceed a tail block's dense bound — see ``spgemm_windowed``).
     This is the r7/r9 per-block-program lesson generalized to the
-    default path (disable with ``COMBBLAS_SPGEMM_BUCKET_CAPS=0``)."""
+    default path."""
     rnd = lambda x: 1 << (max(int(x), 1) - 1).bit_length()
 
     def walk(t):
@@ -2563,13 +2378,12 @@ def plan_windowed(
     block_cols: int | None = None,
     slack: float = 1.02,
     oracle: bool = False,
-    bucket_caps: bool = True,
 ) -> WindowedPlan:
     """The windowed tier's symbolic pass: device counts, read back to
     the host and turned into a ``WindowedPlan`` (every host readback of
     the tier's sizing is in here; ``run_windowed`` then launches only).
-    Reads no environment variable: ``spgemm_windowed`` resolves those
-    and passes them on."""
+    The capacities are rounded up to powers of two
+    (``bucket_plan_caps``) and clamped to their window's cells."""
     if block_rows is None:
         block_rows = default_block_rows(A.local_rows, B.local_cols)
     if backend == "dot":
@@ -2609,26 +2423,25 @@ def plan_windowed(
                 # clamped-flops caps, observably (never silently)
                 if obs.ENABLED:
                     obs.count("spgemm.windowed.oracle_skipped")
-        if bucket_caps:
-            # pow2 caps AFTER oracle tightening: the bucket keeps the
-            # compile-sharing property, the oracle keeps the skips;
-            # then re-impose the dense-window bound the round may have
-            # exceeded on tail blocks/windows (no slot can outnumber
-            # the window's cells)
-            flop_caps, out_caps = bucket_plan_caps(flop_caps, out_caps)
-            out_caps = tuple(
-                tuple(
-                    min(
-                        oc,
-                        max(min(block_rows,
-                                A.local_rows - g * block_rows), 1)
-                        * max(min(block_cols,
-                                  B.local_cols - h * block_cols), 1),
-                    )
-                    for h, oc in enumerate(row)
+        # pow2 caps AFTER oracle tightening: the bucket keeps the
+        # compile-sharing property, the oracle keeps the skips; then
+        # re-impose the dense-window bound the round may have exceeded
+        # on tail blocks/windows (no slot can outnumber the window's
+        # cells)
+        flop_caps, out_caps = bucket_plan_caps(flop_caps, out_caps)
+        out_caps = tuple(
+            tuple(
+                min(
+                    oc,
+                    max(min(block_rows,
+                            A.local_rows - g * block_rows), 1)
+                    * max(min(block_cols,
+                              B.local_cols - h * block_cols), 1),
                 )
-                for g, row in enumerate(out_caps)
+                for h, oc in enumerate(row)
             )
+            for g, row in enumerate(out_caps)
+        )
         panel_cap = panel_cap_from_bnnz(
             host_value(summa_window_bnnz(B, block_cols)),
             int(B.capacity),
@@ -2653,18 +2466,17 @@ def plan_windowed(
     flop_caps, out_caps, skip = windowed_plan(
         pb, pt, block_rows, A.local_rows, B.local_cols, slack=slack
     )
-    if bucket_caps:
-        flop_caps, out_caps = bucket_plan_caps(flop_caps, out_caps)
-        # dense-block bound re-imposed after the pow2 round (tail
-        # blocks: rb * lcB may not be a power of two)
-        out_caps = tuple(
-            min(
-                oc,
-                max(min(block_rows, A.local_rows - g * block_rows), 1)
-                * B.local_cols,
-            )
-            for g, oc in enumerate(out_caps)
+    flop_caps, out_caps = bucket_plan_caps(flop_caps, out_caps)
+    # dense-block bound re-imposed after the pow2 round (tail blocks:
+    # rb * lcB may not be a power of two)
+    out_caps = tuple(
+        min(
+            oc,
+            max(min(block_rows, A.local_rows - g * block_rows), 1)
+            * B.local_cols,
         )
+        for g, oc in enumerate(out_caps)
+    )
     return WindowedPlan(
         "scatter", block_rows, None, flop_caps, out_caps, skip, None,
         np.asarray(pt),
@@ -2850,17 +2662,14 @@ def spgemm_windowed(
     oracle: bool = False,
     ring: bool = False,
     pipeline: bool = True,
-    dispatch: str | None = None,
+    dispatch: str = "auto",
 ) -> SpParMat:
     """Sized entry for the windowed tier: device symbolic pass →
     ``windowed_plan`` (scatter, 1D) or ``windowed_plan_2d`` (dot, 2D) →
-    the matching kernel (one host readback for sizing; benchmarks on
-    readback-poisoned hardware size on host via
-    ``summa_rowblock_flops_host`` / ``summa_window_flops_host`` +
-    ``summa_window_bnnz_host`` instead).
+    the matching kernel (one host readback for sizing).  Reads no
+    environment variable.
 
-    ``dispatch`` (argument > env ``COMBBLAS_SPGEMM_DISPATCH`` >
-    ``"auto"``) picks the multi-device program decomposition for the
+    ``dispatch`` picks the multi-device program decomposition for the
     scatter backend: ``"auto"`` (default) routes any product with more
     than one occupied row block through the BLOCKED building-block
     dispatch (``summa_spgemm_windowed_blocked`` — one small fixed-shape
@@ -2886,17 +2695,15 @@ def spgemm_windowed(
     schedule instead of the gathered one; ``pipeline=False`` pins the
     serial-chain control (see ``summa_spgemm_windowed``).
     """
-    from ..tuner import config as tuner_config
-
+    assert dispatch in ("auto", "fused", "blocked"), dispatch
     plan = plan_windowed(
         sr, A, B, backend=resolve_spgemm_backend(backend),
         block_rows=block_rows, block_cols=block_cols, slack=slack,
-        oracle=oracle, bucket_caps=tuner_config.bucket_caps_enabled(),
+        oracle=oracle,
     )
     return run_windowed(
         sr, A, B, plan, mode=mode, interpret=interpret, ring=ring,
-        pipeline=pipeline,
-        dispatch=tuner_config.resolve_dispatch(dispatch),
+        pipeline=pipeline, dispatch=dispatch,
     )
 
 
@@ -2949,8 +2756,7 @@ def choose_tier_from_counts(
     n_dim: int | None = None,
 ) -> str:
     """Pure tier gate over pre-computed counts — shared by the device
-    router (``choose_spgemm_tier``) and host-sizing benchmark drivers
-    (which must not touch the device to decide).  See
+    router (``choose_spgemm_tier``) and ``spgemm_job``.  See
     ``choose_spgemm_tier`` for the rule.  ``k_dim`` is B's local row
     count and ``n_dim`` B's local col count (the dot backend's
     panel-feasibility check — ``dot_panel_feasible``; ``k_dim``
@@ -3021,9 +2827,9 @@ def choose_spgemm_tier(
     keep their 2D tier (small tiles don't pay conversion; scan-sparse
     outputs would multiply the extraction scans by L).
 
-    Forced override: ``spgemm_auto(tier=...)`` or env
-    ``COMBBLAS_SPGEMM_TIER``; backend via argument, env
-    ``COMBBLAS_SPGEMM_BACKEND``, or the platform default.
+    Forced override: ``spgemm_auto(tier=...)``; backend by argument,
+    else the platform default.  Nothing else decides: no environment
+    variable, no file, no timed guess.
     """
     tier = _choose_spgemm_tier_2d(
         sr, A, B, backend=backend, assume_unique=assume_unique
@@ -3120,24 +2926,19 @@ def spgemm_auto(
     oracle: bool = False,
     assume_unique: bool = False,
     grid3=None,
-    ring: bool | None = None,
-    pipeline: bool | None = None,
-    dispatch: str | None = None,
+    ring: bool = False,
+    pipeline: bool = True,
+    dispatch: str = "auto",
     merge: str | None = None,
 ) -> SpParMat:
     """Auto-tiered sparse-output SpGEMM: route (shape, density, semiring)
     through the fastest applicable kernel instead of defaulting to ESC.
 
-    ``ring``/``pipeline`` are tri-state here (None = "let the resolved
-    plan decide"): an EXPLICIT True/False always beats a remembered
-    record's schedule flags — the arg > store precedence holds for
-    every knob, not just the tier.  ``merge`` (sort | runs | hash) is
-    the combine-merge tier of the merge-consuming tiers (the esc
-    stage-chunk combine, the windowed3d fiber reduce), resolved the
-    same way: arg > record > env ``COMBBLAS_SPGEMM_MERGE`` >
-    per-entry heuristic.
-
-    The ladder (see docs/spgemm.md and ``choose_spgemm_tier``):
+    Routing: the ``tier`` argument, else ``choose_spgemm_tier`` (which
+    ends in ``choose_tier_from_counts``, the rule ``spgemm_job`` is
+    measured under).  Nothing else decides: no environment variable, no
+    file, no timed guess.  The ladder (see docs/spgemm.md and
+    ``choose_spgemm_tier``):
 
       "mxu"      full-dense MXU stage products + one windowed extraction
                  (small tiles, dense-kernel semirings);
@@ -3148,23 +2949,16 @@ def spgemm_auto(
                  removes the ESC sort, on every backend;
       "scan"/"esc"  output-bounded / classic ESC (general fallback).
 
-    Routing resolution (the precedence documented in
-    ``tuner/config.py``): explicit ``tier`` argument > **plan store**
-    (a measured plan remembered for this (shape bucket, density band,
-    semiring, backend, grid) — ``combblas_tpu.tuner.store``, disabled
-    via ``COMBBLAS_PLAN_STORE=0``) > env ``COMBBLAS_SPGEMM_TIER`` >
-    the micro-probe pass (opt-in ``COMBBLAS_TUNER_PROBE=1``: measures
-    the admissible rungs on a bounded proxy and persists the winner) >
-    ``choose_spgemm_tier``'s heuristic ladder.  The winning source is
-    the labeled ``spgemm.auto.plan_source`` counter.
-
-    ``backend`` (or env ``COMBBLAS_SPGEMM_BACKEND``) forces the
-    windowed accumulate backend; ``block_rows``/``block_cols`` (or envs
-    ``COMBBLAS_SPGEMM_BLOCK_ROWS`` / ``COMBBLAS_SPGEMM_BLOCK_COLS``)
-    override the window geometry; ``dispatch`` threads through to the
-    windowed tier's program decomposition (see ``spgemm_windowed``).
-    The chosen tier is recorded as the
-    labeled ``spgemm.auto.tier`` counter, with
+    ``backend`` forces the windowed accumulate backend (else the
+    platform default, ``resolve_spgemm_backend``); ``block_rows`` /
+    ``block_cols`` override the window geometry (else
+    ``default_block_rows`` / ``default_block_cols``); ``ring`` /
+    ``pipeline`` / ``dispatch`` thread through to the windowed tier's
+    schedule and program decomposition (see ``spgemm_windowed``);
+    ``merge`` (sort | runs | hash) is the combine-merge tier of the
+    merge-consuming tiers (the esc stage-chunk combine, the windowed3d
+    fiber reduce), resolved where it is used.  The chosen tier is
+    recorded as the labeled ``spgemm.auto.tier`` counter, with
     ``spgemm.windowed.windows_skipped`` /
     ``spgemm.windowed.col_windows_skipped`` /
     ``spgemm.windowed.window_density`` / ``spgemm.auto.mask_density``
@@ -3186,117 +2980,17 @@ def spgemm_auto(
     backend, which densifies with the combining scatter
     (``densify_combine``) — absorbs duplicate COO entries exactly.
     """
-    from ..tuner import config as tuner_config
-    from ..tuner import store as tuner_store
-
-    plan_source = "arg" if tier is not None else None
-    merge_source = "arg" if merge is not None else None
-    store = key = rec = None
-    if tier is None:
-        # resolution precedence (documented once in tuner/config.py):
-        #   arg > plan store > env > probe-on-miss > heuristic
-        store = tuner_store.get_store()
-        # the key costs one memoized host-nnz readback per operand —
-        # never pay it when the store has nothing to offer AND no probe
-        # would persist a plan under it (no device readback for nothing)
-        if store is not None and (
-            store.entries() > 0 or tuner_config.probe_enabled()
-        ):
-            key = tuner_store.spgemm_plan_key(
-                sr, A, B, resolve_spgemm_backend(backend), grid3=grid3
-            )
-            rec = store.lookup(key)
-        # vet the remembered plan before trusting it — a rejected
-        # record degrades down the precedence chain (obs: the raw
-        # ``tuner.store.hits`` already counted the key match, so the
-        # discard is made visible as ``tuner.store.rejected``)
-        if rec is not None and rec.tier not in (
-            "mxu", "windowed", "scan", "esc", "windowed3d"
-        ):
-            # e.g. a serve-lane record under a hand-mangled spgemm key
-            if obs.ENABLED:
-                obs.count("tuner.store.rejected", reason="tier")
-            rec = None
-        if rec is not None and rec.tier == "windowed3d" and grid3 is None:
-            # a 3D plan is unusable without a layered mesh
-            if obs.ENABLED:
-                obs.count("tuner.store.rejected", reason="no_grid3")
-            rec = None
-        if rec is not None and rec.tier == "mxu" and not assume_unique:
-            # a remembered plan never bypasses the mxu unique-entries
-            # precondition: the record was measured on SOME input in
-            # this bucket, not necessarily a duplicate-free one
-            if coo_has_duplicates(A) or (
-                B is not A and coo_has_duplicates(B)
-            ):
-                if obs.ENABLED:
-                    obs.count("spgemm.auto.dedup_fallback", sr=sr.name)
-                    obs.count("tuner.store.rejected", reason="dup")
-                rec = None
-        if rec is not None:
-            tier = rec.tier
-            plan_source = "store"
-            if block_rows is None:
-                block_rows = rec.block_rows
-            if block_cols is None:
-                block_cols = rec.block_cols
-            if dispatch is None:
-                dispatch = rec.dispatch
-            # explicit args beat the record (tri-state: None = defer)
-            if ring is None:
-                ring = rec.ring
-            if pipeline is None:
-                pipeline = rec.pipeline
-            if merge is None and rec.merge is not None:
-                # provenance stays honest downstream: spgemm() /
-                # spgemm3d_windowed label the counter with THIS source
-                merge = rec.merge
-                merge_source = "store"
-    # env geometry fills in AFTER the store record (precedence: a
-    # measured plan's block shape beats a fleet-wide env default)
-    if block_rows is None:
-        block_rows = tuner_config.env_block_rows()
-    if block_cols is None:
-        block_cols = tuner_config.env_block_cols()
-    if tier is None:
-        tier = tuner_config.env_tier()
-        if tier is not None:
-            plan_source = "env"
-    if (
-        tier is None
-        and store is not None
-        and grid3 is None  # probing covers the 2D ladder
-        and tuner_config.probe_enabled()
-    ):
-        from ..tuner.probe import probe_spgemm
-
-        rec = probe_spgemm(
-            sr, A, B, backend=resolve_spgemm_backend(backend),
-            store=store, key=key,
-        )
-        if rec is not None:
-            tier = rec.tier
-            plan_source = "probe"
     if tier is None:
         tier = choose_spgemm_tier(
             sr, A, B, backend=backend, assume_unique=assume_unique,
             grid3=grid3,
         )
-        plan_source = "heuristic"
-    # tri-state schedule flags -> concrete (the kernel defaults)
-    ring = False if ring is None else bool(ring)
-    pipeline = True if pipeline is None else bool(pipeline)
     assert tier in ("mxu", "windowed", "scan", "esc", "windowed3d"), tier
     if obs.ENABLED:
         obs.count("spgemm.auto.tier", tier=tier, sr=sr.name)
-        obs.count(
-            "spgemm.auto.plan_source", source=plan_source, tier=tier,
-            op="spgemm",
-        )
     with obs.span("spgemm.auto", sr=sr.name, tier=tier):
         if tier == "esc":
-            return spgemm(sr, A, B, slack, merge=merge,
-                          merge_source=merge_source)
+            return spgemm(sr, A, B, slack, merge=merge)
         if tier == "scan":
             return spgemm_scan(
                 sr, A, B, out_capacity=out_capacity, slack=slack,
@@ -3327,7 +3021,6 @@ def spgemm_auto(
                 block_cols=block_cols, backend=backend, mode=mode,
                 slack=slack, interpret=interpret, merge=merge,
                 ring=ring, pipeline=pipeline,
-                merge_source=merge_source,
             )
             return C3.to_spmat(A.grid)
         # tier == "mxu": the round-4 whole-tile dense path
@@ -3532,7 +3225,7 @@ def spgemm_job(
     symbolic pass is inside the job; so is the routing where ``tier``
     is None: ``choose_tier_from_counts``'s rule under ``backend``,
     which defaults to the chip's (``JOB_BACKEND``) on every platform.
-    Nothing else decides: no environment variable, no plan store, no
+    Nothing else decides: no environment variable, no file, no timed
     probe; no retry either: every capacity is a symbolic upper bound,
     so a job runs its numeric phase once (an overflow is an
     ``AssertionError``, not a doubling).  The same operands give the

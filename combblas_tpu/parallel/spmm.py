@@ -37,10 +37,8 @@ smoothing) the ROADMAP names:
   the row-normalized twin the PageRank lane builds, derived here from
   the row degrees instead of materializing a second matrix).
 
-Backend routing rides the round-10 tuner: ``dist_spmm`` resolves
-``arg > plan store (op="spmm", feature-width bucket in the key) >
-env COMBBLAS_SPMM_BACKEND > probe > heuristic`` through
-``tuner.resolve.resolve_tier`` — see ``resolve_spmm_backend``.
+Backend routing: a vetted ``backend`` argument, else
+``spmm_backend_heuristic(sr)`` — see ``resolve_spmm_backend``.
 """
 
 from __future__ import annotations
@@ -105,9 +103,8 @@ def spmm_backend_heuristic(sr: Semiring) -> str:
 
 
 def admissible_spmm_backends(sr: Semiring) -> tuple[str, ...]:
-    """Backends that produce exact results for ``sr`` — the probe's
-    candidate gate (mirrors ``tuner.probe.admissible_tiers``'s role
-    for SpGEMM)."""
+    """Backends that produce exact results for ``sr``: what
+    ``resolve_spmm_backend`` vets an argument against."""
     if sr.name == "plus_times":
         return ("mxu_gather", "scatter")
     return ("scatter",)
@@ -159,12 +156,11 @@ def dist_spmm(
     sr: Semiring, E: EllParMat, X: DistMultiVec,
     backend: str | None = None,
 ) -> DistMultiVec:
-    """The ROUTED entry: resolve the backend through the tuner chain
-    (arg > store > env > probe > heuristic), then run
-    ``dist_spmm_ell``.  Callers that already know their backend (serve
-    plans, which resolve once at engine build) call the jitted kernel
-    directly."""
-    backend = resolve_spmm_backend(sr, E, X.width, backend=backend, X=X)
+    """The ROUTED entry: resolve the backend (``resolve_spmm_backend``),
+    then run ``dist_spmm_ell``.  Callers that already know their
+    backend (serve plans, which resolve once at engine build) call the
+    jitted kernel directly."""
+    backend = resolve_spmm_backend(sr, backend)
     return dist_spmm_ell(sr, E, X, backend=backend)
 
 
@@ -218,7 +214,7 @@ def spmm_khop(
 
     ``X``: a DistMultiVec or a host ``[n, F]`` array (padded to the
     pow2 feature width and uploaded).  Hops chain device-resident; the
-    backend resolves once through the tuner chain.  ``normalize`` is
+    backend resolves once (``resolve_spmm_backend``).  ``normalize`` is
     plus_times-only (a normalized min_plus has no meaning) and applies
     the row-degree reciprocal AFTER each hop.
     """
@@ -230,7 +226,7 @@ def spmm_khop(
         X = DistMultiVec.from_global(
             E.grid, pad_features(X), align="col"
         )
-    backend = resolve_spmm_backend(sr, E, X.width, backend=backend, X=X)
+    backend = resolve_spmm_backend(sr, backend)
     invdeg = row_invdeg(E) if normalize else None
     return _spmm_khop_impl(
         sr, E, X, invdeg, int(k), backend, bool(normalize)
@@ -372,69 +368,20 @@ def summa_spmm(
     )
 
 
-# -- tuner routing -----------------------------------------------------------
+# -- backend routing --------------------------------------------------------
 
 
-def resolve_spmm_backend(
-    sr: Semiring,
-    E,
-    feat_width: int,
-    backend: str | None = None,
-    X: DistMultiVec | None = None,
-) -> str:
-    """Resolve the SpMM backend through the round-10 chain: explicit
-    ``backend`` arg > plan store (``op="spmm"``, FEATURE-WIDTH bucket
-    riding the key's third shape slot) > env ``COMBBLAS_SPMM_BACKEND``
-    > micro-probe (both admissible backends measured ON THE REAL
-    OPERANDS when ``X`` is given — SpMM probes are one warm run per
-    candidate, bounded by the probe budget) > heuristic (plus_times →
-    mxu_gather, else scatter).  Non-plus_times semirings short-circuit:
-    scatter is the only exact backend, nothing to resolve."""
+def resolve_spmm_backend(sr: Semiring, backend: str | None = None) -> str:
+    """The SpMM backend: a ``backend`` argument vetted against
+    ``admissible_spmm_backends(sr)``, else ``spmm_backend_heuristic(sr)``
+    (plus_times → mxu_gather, else scatter).  Reads no file and no
+    environment variable."""
+    if backend is None:
+        return spmm_backend_heuristic(sr)
     allowed = admissible_spmm_backends(sr)
-    if backend is not None:
-        if backend not in allowed:
-            raise ValueError(
-                f"backend {backend!r} is not exact for {sr.name} "
-                f"(admissible: {allowed})"
-            )
-        return backend
-    if len(allowed) == 1:
-        return allowed[0]
-    from ..tuner import config as tuner_config
-    from ..tuner import store as tuner_store
-    from ..tuner.resolve import resolve_tier
-
-    store = tuner_store.get_store()
-    key = None
-    if store is not None and (
-        store.entries() > 0 or tuner_config.probe_enabled()
-    ):
-        key = tuner_store.spmm_plan_key(sr, E, feat_width)
-
-    probe = None
-    if X is not None:
-
-        def probe():
-            from ..tuner.probe import probe_spmm
-
-            return probe_spmm(sr, E, X, store=store, key=key)
-
-    tier, source, _rec = resolve_tier(
-        key,
-        allowed=allowed,
-        heuristic=lambda: spmm_backend_heuristic(sr),
-        op="spmm",
-        store=store,
-        probe=probe,
-    )
-    if tier not in allowed:
-        # the env rung returns its value unvetted (resolve_tier only
-        # vets STORE records); fail loudly naming the knob instead of
-        # asserting deep inside the kernel — or, under python -O,
-        # silently running the fallback branch
+    if backend not in allowed:
         raise ValueError(
-            f"resolved SpMM backend {tier!r} (source: {source}) is "
-            f"not admissible for {sr.name} — COMBBLAS_SPMM_BACKEND "
-            f"takes one of {allowed}"
+            f"backend {backend!r} is not exact for {sr.name} "
+            f"(admissible: {allowed})"
         )
-    return tier
+    return backend

@@ -391,10 +391,6 @@ class GraphEngine:
         self.pagerank_opts = pagerank_opts
         self.propagate_opts = propagate_opts
         self.max_iters = max_iters
-        # the SpMM backend resolves ONCE per engine through the tuner
-        # chain (op="spmm"; lazily on first propagate plan build) and
-        # stays static inside every compiled propagate plan
-        self._spmm_backend: str | None = None
         self._plans: dict[tuple[str, int], _Plan] = {}
         # whole-graph analytics cache for refresh(): (kind, root) ->
         # {vid, result, niter} — the warm-restart recompute's memory
@@ -923,25 +919,12 @@ class GraphEngine:
         )
 
     def _resolve_spmm_backend(self) -> str:
-        """The op="spmm" tuner resolution, ONCE per engine (the plan
-        store remembers it across processes; the result is a static
-        closure constant of every propagate plan).
+        """The propagate plans' SpMM backend: a static closure constant
+        of every such plan."""
+        from ..parallel.spmm import resolve_spmm_backend
+        from ..semiring import PLUS_TIMES
 
-        Keyed at the WIDEST warmup LANE width, not the feature-table
-        width: the plan's hot kernels are the k indicator hops over
-        the [n, W] batch block (the table enters once, in a
-        backend-independent dense dot), so a measurement cached under
-        the whole-graph F-width key would describe a different kernel
-        shape — and the two resolutions must not pollute each other's
-        store records."""
-        if self._spmm_backend is None:
-            from ..parallel.spmm import resolve_spmm_backend
-            from ..semiring import PLUS_TIMES
-
-            self._spmm_backend = resolve_spmm_backend(
-                PLUS_TIMES, self.ET, max(self.DEFAULT_WARMUP_WIDTHS),
-            )
-        return self._spmm_backend
+        return resolve_spmm_backend(PLUS_TIMES)
 
     def _propagate_invdeg(self):
         """Col-aligned 1/deg DistVec for normalized propagation — lazy

@@ -7,9 +7,10 @@ Properties that make it more than a load balancer:
 
 * **One lane set.** Every replica warms its config's ``lane_widths``
   (``Server.warmup``): the widths its batcher can form, so a warmed
-  replica serves with zero retraces.  Kernel routing resolves through
-  the one ``tuner.store`` JSONL (multi-process-safe, append-only,
-  torn-write tolerant).
+  replica serves with zero retraces.  Every replica routes a product
+  the same way, from an argument or from the operands' counts
+  (``parallel/spgemm.py:choose_spgemm_tier``): there is nothing to
+  share.
 * **Warm starts from snapshots.** ``FleetRouter.from_checkpoint``
   boots every replica from one ``utils.checkpoint.save_version``
   GraphVersion snapshot: bucket arrays re-upload bit-identically

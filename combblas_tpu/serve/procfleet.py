@@ -20,9 +20,8 @@ chip per child on a TPU host), so
 * replicas execute in PARALLEL — N processes, N meshes, no shared
   lock (the thread fleet's replicas serialise on one exec lock).
 
-What is SHARED is exactly what PR 14 built process-safe: the plan
-store (children inherit ``COMBBLAS_PLAN_STORE``; zero post-warmup
-retraces are asserted over IPC), the WAL + checkpoint
+What is SHARED is exactly what PR 14 built process-safe (zero
+post-warmup retraces are asserted over IPC): the WAL + checkpoint
 durability dir (the HOME child owns the log; promotion and respawn
 recover from the files), and the spool dir graph versions travel
 through as ``save_version`` checkpoints (``swap_from_checkpoint`` —

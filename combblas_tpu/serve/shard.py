@@ -106,8 +106,8 @@ from .frame import Channel, SparseFrontier, pack_bf16, unpack_bf16
 from .policy import ReplicaDeadError, StaleEpochError
 from .procfleet import IpcTimeoutError, ReplicaProc, child_env
 
-#: Manifest schema tag (refused at recovery when mismatched — the
-#: plan-store convention: never guess at an incompatible layout).
+#: Manifest schema tag (refused at recovery when mismatched: never
+#: guess at an incompatible layout).
 MANIFEST_SCHEMA = "combblas_tpu.shard_manifest/v1"
 MANIFEST_NAME = "shard_manifest.json"
 

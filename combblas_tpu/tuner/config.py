@@ -1,29 +1,8 @@
-"""The ONE place the ``COMBBLAS_SPGEMM_*`` / tuner knobs are parsed.
-
-Before round 10 the env parsing was scattered: ``spgemm_auto`` read
-``COMBBLAS_SPGEMM_TIER`` / ``_BLOCK_ROWS`` / ``_BLOCK_COLS`` inline,
-``resolve_spgemm_backend`` read ``COMBBLAS_SPGEMM_BACKEND``,
-``mesh3d.spgemm3d`` read ``COMBBLAS_SPGEMM3D_TIER``, and every bench
-re-implemented the same ``or None`` / ``"0" means default`` conventions.
-This module centralizes the parsing so the tuner, the router, and the
-benches all read identical semantics.
-
-Resolution precedence (documented ONCE, here):
-
-    explicit argument  >  plan store  >  env var  >  heuristic
-
-* **argument** — a caller passing ``tier=`` / ``backend=`` /
-  ``block_rows=`` etc. always wins (tests and forced benches).
-* **plan store** — a measured plan persisted by the micro-probe pass
-  (``combblas_tpu.tuner.store``); this is what makes tier choice
-  reproducible across processes.  Disable with ``COMBBLAS_PLAN_STORE=0``.
-* **env var** — the classic fleet-wide override knobs below.
-* **heuristic** — ``choose_spgemm_tier``'s hand-tuned ladder, the
-  fallback when nothing above decided.  The opt-in micro-probe pass
-  (``COMBBLAS_TUNER_PROBE=1``) runs at this point — on a store miss
-  with no arg/env override it MEASURES the admissible rungs and writes
-  the winner back, so the heuristic is consulted only when probing is
-  disabled or over budget.
+"""The ONE place the package's ``COMBBLAS_*`` knobs are parsed: the
+serve, dynamic, obs and shard settings below.  Each accessor resolves
+explicit argument > environment variable > default.  No knob chooses a
+product's kernel (``parallel/spgemm.py:choose_spgemm_tier`` does, from
+the operands' counts).
 
 Env-var conventions shared by every knob: unset or empty means
 "default"; for the integer knobs ``"0"`` also means default.
@@ -33,39 +12,13 @@ from __future__ import annotations
 
 import os
 
-#: SpGEMM routing / geometry knobs (round-6/7/9 compatible names).
-ENV_TIER = "COMBBLAS_SPGEMM_TIER"
-ENV_BACKEND = "COMBBLAS_SPGEMM_BACKEND"
-ENV_BLOCK_ROWS = "COMBBLAS_SPGEMM_BLOCK_ROWS"
-ENV_BLOCK_COLS = "COMBBLAS_SPGEMM_BLOCK_COLS"
-ENV_TIER3D = "COMBBLAS_SPGEMM3D_TIER"
-#: Windowed multi-device dispatch: fused | blocked | auto (default).
-ENV_DISPATCH = "COMBBLAS_SPGEMM_DISPATCH"
-#: Pow2-bucket the per-block plan capacities ("0" disables).
-ENV_BUCKET_CAPS = "COMBBLAS_SPGEMM_BUCKET_CAPS"
-
-#: Plan-store knobs (round 10).
-ENV_PLAN_STORE = "COMBBLAS_PLAN_STORE"      # dir | "0"/"off" disables
-ENV_PROBE = "COMBBLAS_TUNER_PROBE"          # "1" enables the probe pass
-ENV_PROBE_BUDGET = "COMBBLAS_TUNER_PROBE_BUDGET_S"
-ENV_PROBE_MAX_DIM = "COMBBLAS_TUNER_PROBE_MAX_DIM"
-
-#: Plan-store aging knobs (round 11): long-lived fleet stores grow one
-#: appended line per superseded plan and one per new serve lane; these
-#: bound the file and the loaded set.
-ENV_STORE_MAX = "COMBBLAS_PLAN_STORE_MAX"             # entries cap
-ENV_STORE_COMPACT = "COMBBLAS_PLAN_STORE_COMPACT_MIN"  # superseded-line
-#                                                     # rewrite trigger
-
 #: Dynamic-graph mutation knobs (round 11, docs/dynamic.md).
 ENV_DYNAMIC_SPILL = "COMBBLAS_DYNAMIC_SPILL_FRAC"
 
-#: Round-12 knobs: the batched-SpMM backend override (the op="spmm"
-#: analog of COMBBLAS_SPGEMM_TIER) and headroom-aware bucket sizing —
-#: the slack fraction of padding slots every ELL bucket class reserves
-#: at build so high-churn dynamic graphs re-bucket instead of spilling
-#: (docs/dynamic.md; counter ``dynamic.merge.headroom_used``).
-ENV_SPMM_BACKEND = "COMBBLAS_SPMM_BACKEND"
+#: Round-12 knob: headroom-aware bucket sizing — the slack fraction of
+#: padding slots every ELL bucket class reserves at build so high-churn
+#: dynamic graphs re-bucket instead of spilling (docs/dynamic.md;
+#: counter ``dynamic.merge.headroom_used``).
 ENV_DYNAMIC_HEADROOM = "COMBBLAS_DYNAMIC_HEADROOM"
 
 #: Round-14 knobs: the multi-tenant engine pool and the replicated
@@ -102,7 +55,7 @@ ENV_WAL_FSYNC = "COMBBLAS_WAL_FSYNC"
 ENV_CHECKPOINT_EVERY = "COMBBLAS_CHECKPOINT_EVERY"
 ENV_CHECKPOINT_RETAIN = "COMBBLAS_CHECKPOINT_RETAIN"
 
-#: Valid WAL fsync policies (vetted at the knob, the MERGE precedent).
+#: Valid WAL fsync policies (vetted at the knob).
 WAL_FSYNC_POLICIES = ("always", "off")
 
 #: Round-18 knobs: the fleet observability plane (docs/observability.md
@@ -144,31 +97,10 @@ ENV_SHARD_DENSITY = "COMBBLAS_SHARD_DENSITY"
 ENV_SHARD_WIRE = "COMBBLAS_SHARD_WIRE"
 
 #: Valid sharded frontier encodings / wire dtypes (vetted at the knob,
-#: the MERGE/WAL_FSYNC precedent).
+#: the WAL_FSYNC precedent).
 SHARD_FRONTIER_MODES = ("auto", "sparse", "dense")
 SHARD_WIRE_MODES = ("f32", "bf16")
 
-#: Round-13 knob: the SpGEMM combine-merge tier (sort | runs | hash) —
-#: how partial-product pieces (3D fiber pieces, 2D ESC stage chunks)
-#: fold into one compacted tile.  Resolution: arg > plan-store record
-#: > this env > the L/collision heuristic (docs/spgemm.md "merge
-#: tiers").
-ENV_MERGE = "COMBBLAS_SPGEMM_MERGE"
-
-#: Valid merge-tier names (parallel/mesh3d re-exports this as
-#: MERGE_TIERS — one definition, vetting and kernel asserts agree).
-MERGE_TIER_NAMES = ("sort", "runs", "hash")
-
-#: Default probe budget: total measured seconds across all candidate
-#: rungs for ONE store miss (compiles excluded from the budget check
-#: only insofar as the first candidate always completes).
-DEFAULT_PROBE_BUDGET_S = 30.0
-#: Proxy dimension cap for the downsampled probe operands.
-DEFAULT_PROBE_MAX_DIM = 2048
-#: Plan-store entry cap (oldest-cost eviction past it) and the
-#: superseded-line count that triggers a load-time compaction rewrite.
-DEFAULT_STORE_MAX_ENTRIES = 4096
-DEFAULT_STORE_COMPACT_MIN = 32
 #: Structural-change fraction above which ``dynamic.apply_delta``
 #: spills to a full rebuild (the incremental path's amortization bound).
 DEFAULT_DYNAMIC_SPILL_FRAC = 0.10
@@ -219,121 +151,6 @@ def _int_env(name: str) -> int | None:
     if not v:
         return None
     return int(v) or None
-
-
-def env_tier() -> str | None:
-    return _str_env(ENV_TIER)
-
-
-def env_backend() -> str | None:
-    return _str_env(ENV_BACKEND)
-
-
-def env_block_rows() -> int | None:
-    return _int_env(ENV_BLOCK_ROWS)
-
-
-def env_block_cols() -> int | None:
-    return _int_env(ENV_BLOCK_COLS)
-
-
-def env_tier3d() -> str | None:
-    return _str_env(ENV_TIER3D)
-
-
-def env_dispatch() -> str | None:
-    return _str_env(ENV_DISPATCH)
-
-
-def bucket_caps_enabled() -> bool:
-    """Pow2 cap bucketing is ON by default: it is what lets per-block
-    building-block programs share compiles across blocks and across
-    products inside one shape bucket (the bounded first-touch-compile
-    half of round 10)."""
-    return os.environ.get(ENV_BUCKET_CAPS, "1") not in ("", "0")
-
-
-def resolve_dispatch(dispatch: str | None = None) -> str:
-    """Windowed-tier dispatch: argument > env > ``"auto"``.
-
-    ``auto`` routes multi-device scatter products with more than one
-    occupied row block through the BLOCKED building-block dispatch
-    (``summa_spgemm_windowed_blocked``) so no single XLA compile scales
-    with the whole product; ``fused`` forces the one-graph kernel (the
-    carousel/ring schedules live there); ``blocked`` forces per-block
-    programs."""
-    if dispatch is None:
-        dispatch = env_dispatch()
-    if dispatch is None:
-        dispatch = "auto"
-    assert dispatch in ("auto", "fused", "blocked"), dispatch
-    return dispatch
-
-
-def store_dir() -> str | None:
-    """The plan-store directory, or ``None`` when the store is disabled.
-
-    ``COMBBLAS_PLAN_STORE``: a path uses that dir; ``0``/``off``
-    disables the store entirely.  Unset: the sibling of the XLA compile
-    cache dir (``utils/compile_cache.py`` — ``.plan_store`` next to
-    ``.jax_cache``), so a fleet that ships its compile cache ships its
-    plans with the same rsync."""
-    v = os.environ.get(ENV_PLAN_STORE)
-    if v is not None:
-        if v.strip().lower() in ("", "0", "off", "none"):
-            return None
-        return os.path.abspath(v)
-    from ..utils import compile_cache
-
-    return compile_cache.plan_store_dir()
-
-
-def probe_enabled() -> bool:
-    return os.environ.get(ENV_PROBE, "0") not in ("", "0")
-
-
-def probe_budget_s() -> float:
-    v = os.environ.get(ENV_PROBE_BUDGET)
-    return float(v) if v else DEFAULT_PROBE_BUDGET_S
-
-
-def probe_max_dim() -> int:
-    v = os.environ.get(ENV_PROBE_MAX_DIM)
-    return int(v) if v else DEFAULT_PROBE_MAX_DIM
-
-
-def store_max_entries() -> int:
-    """Plan-store entry cap: past it the loader evicts oldest-cost
-    entries (``tuner.store.evicted``).  ``0``/unset = the default."""
-    v = _int_env(ENV_STORE_MAX)
-    return DEFAULT_STORE_MAX_ENTRIES if v is None else v
-
-
-def store_compact_min() -> int:
-    """Superseded (last-wins-shadowed) line count that triggers the
-    load-time compaction rewrite (``tuner.store.compacted``)."""
-    v = _int_env(ENV_STORE_COMPACT)
-    return DEFAULT_STORE_COMPACT_MIN if v is None else v
-
-
-def env_merge() -> str | None:
-    """Fleet-wide SpGEMM merge-tier override (round 13).  A bogus
-    value raises here — naming the knob — instead of surfacing as a
-    bare kernel assert deep in a shard_map body (the round-12
-    SPMM_BACKEND vetting precedent)."""
-    v = _str_env(ENV_MERGE)
-    if v is not None and v not in MERGE_TIER_NAMES:
-        raise ValueError(
-            f"{ENV_MERGE} must be one of {'|'.join(MERGE_TIER_NAMES)}; "
-            f"got {v!r}"
-        )
-    return v
-
-
-def env_spmm_backend() -> str | None:
-    """Fleet-wide SpMM backend override (``mxu_gather``/``scatter``) —
-    the op="spmm" rung ``tuner.resolve.resolve_tier`` walks."""
-    return _str_env(ENV_SPMM_BACKEND)
 
 
 def dynamic_headroom(given: float | None = None) -> float:
@@ -387,7 +204,7 @@ def wal_dir(given: str | None = None) -> str | None:
     """The serve durability directory (WAL + checkpoints), or ``None``
     when durability is disabled: explicit argument >
     ``COMBBLAS_WAL`` > off.  ``0``/``off``/``none`` (argument or env)
-    disable explicitly — the plan-store convention."""
+    disable explicitly."""
     v = os.environ.get(ENV_WAL) if given is None else given
     if v is None or v.strip().lower() in ("", "0", "off", "none"):
         return None
@@ -397,7 +214,7 @@ def wal_dir(given: str | None = None) -> str | None:
 def wal_fsync(given: str | None = None) -> str:
     """WAL append fsync policy: explicit argument >
     ``COMBBLAS_WAL_FSYNC`` > ``always``.  A bogus value raises naming
-    the knob (the MERGE/SPMM_BACKEND vetting precedent) instead of
+    the knob instead of
     surfacing as a silent durability downgrade."""
     v = _str_env(ENV_WAL_FSYNC) if given is None else given
     if v is None:
@@ -438,7 +255,7 @@ def obs_hb_metrics_interval(given: float | None = None) -> float:
 
 def _vet_int(name: str, v, what: str) -> int:
     """Integer-knob vetting shared by the round-19 net knobs: a bogus
-    value raises NAMING the knob (the WAL_FSYNC/MERGE precedent)
+    value raises NAMING the knob (the WAL_FSYNC precedent)
     instead of surfacing as a bare ``int()`` traceback from deep
     inside socket setup."""
     try:
@@ -492,7 +309,7 @@ def net_accept_backlog(given: int | str | None = None) -> int:
 def shard_frontier(given: str | None = None) -> str:
     """Sharded hop frontier encoding: explicit argument >
     ``COMBBLAS_SHARD_FRONTIER`` > ``auto``.  A bogus value raises
-    naming the knob (the WAL_FSYNC/MERGE vetting precedent) instead of
+    naming the knob (the WAL_FSYNC vetting precedent) instead of
     surfacing as a silently-dense wire."""
     v = _str_env(ENV_SHARD_FRONTIER) if given is None else given
     if v is None:

@@ -165,7 +165,7 @@ def _npz_to_tuples(z, meta):
 # --- GraphVersion snapshots (round 14 — the serving fleet's warm start) ----
 
 #: Schema tag of ``save_version`` snapshots; a mismatched tag is
-#: refused at load (never guessed at — the plan-store convention).
+#: refused at load (never guessed at).
 VERSION_SCHEMA = "combblas_tpu.graph_version/v1"
 
 #: The EllParMat fields of a GraphVersion, in a fixed serialization

@@ -57,25 +57,9 @@ def configured_dir() -> str | None:
     return _configured_dir
 
 
-def plan_store_dir() -> str:
-    """Default MEASURED-PLAN store dir (round 10): the ``.plan_store``
-    SIBLING of the compile cache dir, so a fleet that ships its warm
-    compile cache to new replicas ships the measured tier plans with
-    the same rsync.  ``COMBBLAS_PLAN_STORE`` overrides (parsed by
-    ``tuner.config.store_dir``, which calls this for the default)."""
-    base = _configured_dir or _env_dir() or CACHE_DIR
-    return os.path.join(
-        os.path.dirname(os.path.abspath(base)), ".plan_store"
-    )
-
-
 def _record_cache_entries() -> None:
     """obs provider: persistent-cache entry count, polled at export time
-    (a push on every compile would race the async cache writer).  ONE
-    health surface covers both caches: the sibling plan store's entry
-    count is published by the same provider (``cache="plans"`` labeled
-    series + the ``tuner.store.entries`` gauge), so a fleet dashboard
-    watching compile-cache health sees plan-store health for free."""
+    (a push on every compile would race the async cache writer)."""
     if _configured_dir is not None:
         try:
             entries = sum(
@@ -84,18 +68,6 @@ def _record_cache_entries() -> None:
         except OSError:
             entries = 0
         obs.gauge("compile_cache.entries", entries, dir=_configured_dir)
-    try:
-        from ..tuner import store as plan_store
-
-        st = plan_store.get_store()
-    except Exception:
-        st = None
-    if st is not None:
-        obs.gauge(
-            "compile_cache.entries", st.entries(),
-            cache="plans", dir=st.path,
-        )
-        obs.gauge("tuner.store.entries", st.entries(), dir=st.path)
 
 
 def enable_compile_cache(cache_dir: str | None = None) -> None:

@@ -13,23 +13,10 @@ one themselves, so this is what keeps test children on the CPU.
 """
 
 import os
-import tempfile
 
 os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (
     os.environ.get("XLA_FLAGS", "") + " --xla_force_host_platform_device_count=8"
-)
-
-# Hermetic plan store (round 10): the measured-plan store defaults to the
-# repo's .plan_store dir, and a store populated by an earlier bench run —
-# or an ambient COMBBLAS_PLAN_STORE pointing at a fleet store — would
-# silently change spgemm_auto's routing under test (tier choices must
-# come from the code under test, not leftover measurements), so the env
-# var is OVERRIDDEN unconditionally.  Tests that exercise the store
-# itself monkeypatch COMBBLAS_PLAN_STORE to their own tmp_path and reset
-# the singleton (tuner.store._reset_for_tests).
-os.environ["COMBBLAS_PLAN_STORE"] = tempfile.mkdtemp(
-    prefix="combblas-plans-"
 )
 
 # Hermetic pool/fleet knobs (round 14): an ambient byte budget would

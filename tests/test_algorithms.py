@@ -353,9 +353,9 @@ def test_triangle_count_dense_kernel(rng):
 
 
 def test_triangle_count_edge_harvest_kernel(rng):
-    """Round-5 edge-harvest TC (dense-row gathers per edge, the
-    32K < n <= 64K regime) must match the sparse and dense paths,
-    including when the edge count doesn't divide the scan chunk."""
+    """The edge-harvest TC (two row gathers an edge) must match the
+    sparse and dense paths, including when the edge count doesn't
+    divide the scan chunk."""
     from combblas_tpu.models.tc import triangle_count
 
     grid = Grid.make(1, 1)
@@ -366,17 +366,14 @@ def test_triangle_count_edge_harvest_kernel(rng):
     A = SpParMat.from_dense(grid, d)
     want = triangle_count(A, kernel="sparse")
     assert triangle_count(A, kernel="edgeharvest") == want
-    assert triangle_count(A, kernel="edgeharvest_bf16") == want
     assert triangle_count(A, kernel="dense") == want
 
 
-@pytest.mark.parametrize("kernel", ["edgeharvest", "edgeharvest_bf16"])
-def test_triangle_count_edge_harvest_duplicates(rng, kernel):
-    """Both edge-harvest variants must survive duplicate COO entries: in
-    the bits variant a double-added bit would carry into the next bit
-    and corrupt the adjacency; in the bf16 variant a duplicated edge
-    would walk its common neighbors twice and double-count 3T (ADVICE
-    r5) — dedup happens on device in both."""
+def test_triangle_count_edge_harvest_duplicates(rng):
+    """The edge harvest must survive duplicate COO entries: a
+    double-added bit would carry into the next bit and corrupt the
+    adjacency, and a duplicated edge would walk its common neighbors
+    twice and double-count 3T (ADVICE r5) — dedup happens on device."""
     from combblas_tpu.models.tc import triangle_count
 
     grid = Grid.make(1, 1)
@@ -398,4 +395,21 @@ def test_triangle_count_edge_harvest_duplicates(rng, kernel):
         ),
         kernel="sparse",
     )
-    assert triangle_count(A, kernel=kernel) == want
+    assert triangle_count(A, kernel="edgeharvest") == want
+
+
+@pytest.mark.parametrize("kernel", ["edgeharvest_bf16", "bits", ""])
+def test_triangle_count_refuses_a_kernel_it_does_not_have(kernel):
+    """An unknown ``kernel`` names the four there are (at PR 42 it fell
+    through to the masked product, and ``edgeharvest_bf16`` ran a
+    kernel no rule picked)."""
+    from combblas_tpu.models.tc import triangle_count
+
+    A = SpParMat.from_global_coo(
+        Grid.make(1, 1), np.array([1, 0]), np.array([0, 1]),
+        np.ones(2, np.float32), 4, 4,
+    )
+    with pytest.raises(
+        ValueError, match="auto, dense, edgeharvest, sparse"
+    ):
+        triangle_count(A, kernel=kernel)

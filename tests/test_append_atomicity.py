@@ -1,17 +1,15 @@
 """Multi-process file-substrate safety (round 17, ISSUE 15
-satellites): the O_APPEND single-``write()`` contract both JSONL logs
-(WAL, plan store) rest on, the plan-store compaction flock, and
-checkpoint listing/loading under a concurrently-checkpointing sibling.
+satellites): the O_APPEND single-``write()`` contract the WAL's JSONL
+rests on, and checkpoint listing/loading under a
+concurrently-checkpointing sibling.
 
 The writer children are plain interpreters (stdlib only — no jax
-import) hammering the SAME files the product code reads back, so the
+import) hammering the SAME file the product code reads back, so the
 property is cheap enough for tier-1: two processes' interleaved
-appends must produce only whole, parseable lines, with the loaders'
-invalid-line counters at ZERO.
+appends must produce only whole, parseable lines, with the loader's
+invalid-line counter at ZERO.
 """
 
-import fcntl
-import json
 import os
 import subprocess
 import sys
@@ -23,33 +21,22 @@ import pytest
 from combblas_tpu.dynamic import WriteAheadLog
 from combblas_tpu.parallel.grid import Grid
 from combblas_tpu.serve import GraphEngine
-from combblas_tpu.tuner import store as tstore
 from combblas_tpu.utils import checkpoint
 
 N = 64
 
-#: Child writer: appends ``count`` fully formed lines produced by
-#: ``make_line(worker, k)`` to one shared file — each line down as ONE
-#: os.write to an O_APPEND fd, exactly the product appenders' contract.
+#: Child writer: appends ``count`` fully formed lines to one shared
+#: file — each line down as ONE os.write to an O_APPEND fd, exactly the
+#: product appender's contract.
 _WRITER = textwrap.dedent("""
     import json, os, sys
-    path, worker, count, kind = (
-        sys.argv[1], int(sys.argv[2]), int(sys.argv[3]), sys.argv[4]
-    )
+    path, worker, count = sys.argv[1], int(sys.argv[2]), int(sys.argv[3])
     fd = os.open(path, os.O_APPEND | os.O_CREAT | os.O_WRONLY, 0o644)
     for k in range(count):
-        if kind == "wal":
-            seq = worker * 100000 + k
-            rec = {"v": "combblas_tpu.wal/v1", "first_seq": seq,
-                   "last_seq": seq, "rows": [worker], "cols": [k % 64],
-                   "vals": [1.0], "ops": [0]}
-        else:
-            rec = {"v": "combblas_tpu.plans/v1",
-                   "key": {"op": "spgemm", "shape": [worker, k, 0],
-                           "band": [0, 0], "sr": "plusmul",
-                           "backend": "cpu", "grid": "1x1"},
-                   "plan": {"tier": "esc", "cost_s": 0.5,
-                            "ts": 1000.0 + worker}}
+        seq = worker * 100000 + k
+        rec = {"v": "combblas_tpu.wal/v1", "first_seq": seq,
+               "last_seq": seq, "rows": [worker], "cols": [k % 64],
+               "vals": [1.0], "ops": [0]}
         line = (json.dumps(rec, separators=(",", ":")) + "\\n").encode()
         n = os.write(fd, line)
         assert n == len(line)
@@ -57,11 +44,11 @@ _WRITER = textwrap.dedent("""
 """)
 
 
-def _run_writers(path, kind, nworkers=2, count=400):
+def _run_writers(path, nworkers=2, count=400):
     procs = [
         subprocess.Popen(
             [sys.executable, "-c", _WRITER, str(path), str(w),
-             str(count), kind],
+             str(count)],
         )
         for w in range(nworkers)
     ]
@@ -74,7 +61,7 @@ def test_wal_concurrent_appends_only_whole_lines(tmp_path):
     (the kernel's O_APPEND atomic seek+write), the loader's invalid
     counter is zero, and replay sees every record."""
     path = tmp_path / "wal.jsonl"
-    _run_writers(path, "wal")
+    _run_writers(path)
     wal = WriteAheadLog(str(path))
     batches = wal.replay()
     assert wal.invalid_lines == 0
@@ -83,114 +70,6 @@ def test_wal_concurrent_appends_only_whole_lines(tmp_path):
     wal.append(500000, [1], [2], [1.0], [0])
     assert wal.position() == 500000
     wal.close()
-
-
-def test_plan_store_concurrent_appends_only_whole_lines(tmp_path):
-    path = tmp_path / "plans"
-    path.mkdir()
-    _run_writers(path / "plans.jsonl", "plans")
-    st = tstore.PlanStore(str(path))
-    s = st.stats()
-    assert s["invalid_lines"] == 0
-    # 2 workers x 400 distinct (worker, k) keys, every one parsed
-    # whole — eviction (the max-entries cap) is the only reducer
-    assert s["entries"] + s["evicted"] == 800
-    # interop: a product append through the locked O_APPEND path
-    from combblas_tpu.tuner.store import PlanKey, PlanRecord
-
-    key = PlanKey(op="spgemm", shape=(9, 9, 9), band=(0, 0),
-                  sr="plusmul", backend="cpu", grid="1x1")
-    st.put(key, PlanRecord(tier="esc", cost_s=0.1))
-    st2 = tstore.PlanStore(str(path))
-    assert st2.lookup(key) is not None
-    assert st2.stats()["invalid_lines"] == 0
-
-
-# --- compaction flock (satellite: the PR 9 stat->replace window) -------------
-
-
-def _fill_superseded(store_dir, n=30):
-    """A plans.jsonl whose first n lines are shadowed by later ones —
-    exactly what load-time compaction rewrites."""
-    os.makedirs(store_dir, exist_ok=True)
-    f = os.path.join(store_dir, "plans.jsonl")
-    with open(f, "w") as fh:
-        for i in range(n + 1):  # same key n+1 times: n superseded
-            rec = {"v": tstore.SCHEMA,
-                   "key": {"op": "spgemm", "shape": [1, 1, 1],
-                           "band": [0, 0], "sr": "plusmul",
-                           "backend": "cpu", "grid": "1x1"},
-                   "plan": {"tier": "esc", "cost_s": float(i),
-                            "ts": float(i)}}
-            fh.write(json.dumps(rec) + "\n")
-    return f
-
-
-def test_compaction_skipped_under_contention(tmp_path, monkeypatch):
-    """A sibling holding the advisory lock (mid-compaction) makes OUR
-    compaction a SKIP — never a blocked load, never two rewrites
-    racing os.replace."""
-    monkeypatch.setenv("COMBBLAS_PLAN_STORE_COMPACT_MIN", "5")
-    d = str(tmp_path / "store")
-    f = _fill_superseded(d)
-    lf = os.open(f + ".lock", os.O_CREAT | os.O_RDWR, 0o644)
-    try:
-        fcntl.flock(lf, fcntl.LOCK_EX)  # the "sibling compactor"
-        st = tstore.PlanStore(d)
-        assert st.stats()["compacted_lines"] == 0  # skipped
-        assert sum(1 for _ in open(f)) == 31  # file untouched
-    finally:
-        fcntl.flock(lf, fcntl.LOCK_UN)
-        os.close(lf)
-    # lock released: the next loader compacts to one surviving line
-    st2 = tstore.PlanStore(d)
-    assert st2.stats()["compacted_lines"] == 30
-    assert sum(1 for _ in open(f)) == 1
-
-
-def test_compaction_leaves_sibling_append_intact(tmp_path, monkeypatch):
-    """The PR 9 window, closed: an append landing after the loader
-    read the file (but before its compaction) SURVIVES — the rewrite
-    detects the grown file under the exclusive lock and backs off."""
-    monkeypatch.setenv("COMBBLAS_PLAN_STORE_COMPACT_MIN", "5")
-    d = str(tmp_path / "store")
-    f = _fill_superseded(d)
-
-    sibling = {"v": tstore.SCHEMA,
-               "key": {"op": "spgemm", "shape": [7, 7, 7],
-                       "band": [0, 0], "sr": "plusmul",
-                       "backend": "cpu", "grid": "2x2"},
-               "plan": {"tier": "esc", "cost_s": 9.0, "ts": 9.0}}
-    line = (json.dumps(sibling) + "\n").encode()
-
-    orig_getsize = os.path.getsize
-    appended = {}
-
-    def race_append(path):
-        # the sibling's append lands exactly inside the old
-        # stat->replace window: just before the compactor's size check
-        if path == f and not appended:
-            fd = os.open(f, os.O_APPEND | os.O_WRONLY)
-            os.write(fd, line)
-            os.close(fd)
-            appended["done"] = True
-        return orig_getsize(path)
-
-    monkeypatch.setattr(os.path, "getsize", race_append)
-    st = tstore.PlanStore(d)
-    monkeypatch.setattr(os.path, "getsize", orig_getsize)
-    assert appended  # the race actually ran
-    assert st.stats()["compacted_lines"] == 0  # rewrite backed off
-    # the sibling's measurement is still on disk and loads
-    st2 = tstore.PlanStore(d)
-    from combblas_tpu.tuner.store import PlanKey
-
-    key = PlanKey(op="spgemm", shape=(7, 7, 7), band=(0, 0),
-                  sr="plusmul", backend="cpu", grid="2x2")
-    assert st2.lookup(key) is not None
-
-
-# --- checkpoint dir under a concurrently-checkpointing sibling ---------------
 
 
 @pytest.fixture(scope="module")

@@ -61,12 +61,11 @@ def test_compile_cache_is_placed_from_outside(tmp_path, monkeypatch):
     cc._reset_for_tests()
     try:
         # placed by the environment: no directory is set in code, the
-        # committed dir (entries gauge, plan-store sibling) follows it
+        # committed dir (entries gauge) follows it
         monkeypatch.setenv(cc.ENV_CACHE_DIR, str(tmp_path / "outside"))
         cc.enable_compile_cache()
         assert "jax_compilation_cache_dir" not in updates
         assert cc.configured_dir() == str(tmp_path / "outside")
-        assert cc.plan_store_dir() == str(tmp_path / ".plan_store")
         with pytest.raises(ValueError, match="already enabled"):
             cc.enable_compile_cache(str(tmp_path / "elsewhere"))
         # unset: the fixed <checkout>/.jax_cache, never a temporary name

@@ -1,12 +1,12 @@
 """Tier-1 guard: ``COMBBLAS_*`` env knobs are parsed in ONE place.
 
-Round 10 centralized every ``COMBBLAS_SPGEMM_*`` / tuner knob into
+Every knob (serve, dynamic, obs, shard) is parsed in
 ``tuner/config.py`` (precedence documented once, identical "0 means
-default" semantics everywhere); round 11 added the dynamic-lane and
-store-aging knobs THROUGH that module.  This test locks the invariant
-in: any new ``os.environ`` read of a ``COMBBLAS_`` name outside the
-allowlist below fails tier-1, so scattered knob parsing cannot creep
-back.
+default" semantics everywhere).  This test locks the invariant in: any
+new ``os.environ`` read of a ``COMBBLAS_`` name outside the allowlist
+below fails tier-1, so scattered knob parsing cannot creep back.  No
+knob routes a product: the fifteen that did (PR 43 took them out) stay
+out.
 
 Allowed:
 
@@ -110,8 +110,45 @@ def test_dynamic_knobs_centralized():
 
     assert config.ENV_DYNAMIC_SPILL.startswith("COMBBLAS_")
     assert 0 < config.dynamic_spill_frac() <= 1.0
-    assert config.store_max_entries() >= 1
-    assert config.store_compact_min() >= 1
+
+
+#: The names that chose, sized or remembered a product's kernel until
+#: PR 43: a product is routed by an argument or by its operands' counts.
+ROUTING_NAMES = (
+    "COMBBLAS_SPGEMM_TIER", "COMBBLAS_SPGEMM_BACKEND",
+    "COMBBLAS_SPGEMM_BLOCK_ROWS", "COMBBLAS_SPGEMM_BLOCK_COLS",
+    "COMBBLAS_SPGEMM_DISPATCH", "COMBBLAS_SPGEMM_BUCKET_CAPS",
+    "COMBBLAS_SPGEMM_MERGE", "COMBBLAS_SPGEMM3D_TIER",
+    "COMBBLAS_SPMM_BACKEND", "COMBBLAS_PLAN_STORE",
+    "COMBBLAS_PLAN_STORE_MAX", "COMBBLAS_PLAN_STORE_COMPACT_MIN",
+    "COMBBLAS_TUNER_PROBE", "COMBBLAS_TUNER_PROBE_BUDGET_S",
+    "COMBBLAS_TUNER_PROBE_MAX_DIM",
+)
+
+
+def test_no_routing_knob_is_named_in_the_package_or_its_documents():
+    """None of the fifteen names occurs in ``combblas_tpu/``, ``docs/``
+    or ``README.md``, as code, comment or prose."""
+    repo = os.path.dirname(PKG_ROOT)
+    assert len(set(ROUTING_NAMES)) == 15
+    paths = [os.path.join(repo, "README.md")]
+    for top in (PKG_ROOT, os.path.join(repo, "docs")):
+        for dirpath, dirnames, filenames in os.walk(top):
+            dirnames[:] = [d for d in dirnames if d != "__pycache__"]
+            paths += [
+                os.path.join(dirpath, fn) for fn in filenames
+                if fn.endswith((".py", ".md"))
+            ]
+    assert len(paths) > 80  # the sweep swept the package
+    found = []
+    for path in paths:
+        with open(path, encoding="utf-8") as f:
+            text = f.read()
+        found += [
+            f"{os.path.relpath(path, repo)}: {name}"
+            for name in ROUTING_NAMES if name in text
+        ]
+    assert not found, "\n".join(found)
 
 
 def test_durability_knobs_centralized(monkeypatch, tmp_path):

@@ -244,54 +244,6 @@ def test_round9_pipeline_pack_3d_counters_gated(rng):
         obs.reset()
 
 
-def test_round10_tuner_counters_gated(rng, tmp_path, monkeypatch):
-    """ISSUE 8 satellite: the round-10 tuner series — store hit/miss,
-    plan-source, entries — are emitted under obs and cost NOTHING when
-    disabled (the zero-cost gate extended to the plan store)."""
-    from combblas_tpu import PLUS_TIMES
-    from combblas_tpu.parallel.grid import Grid
-    from combblas_tpu.parallel.spgemm import spgemm_auto
-    from combblas_tpu.parallel.spmat import SpParMat
-    from combblas_tpu.tuner import PlanRecord, config, spgemm_plan_key
-    from combblas_tpu.tuner import store as tstore
-
-    monkeypatch.setenv(config.ENV_PLAN_STORE, str(tmp_path))
-    tstore._reset_for_tests()
-    try:
-        grid = Grid.make(1, 1)
-        m = 64
-        r = rng.integers(0, m, 300).astype(np.int64)
-        c = rng.integers(0, m, 300).astype(np.int64)
-        A = SpParMat.from_global_coo(
-            grid, r, c, np.ones(300, np.float32), m, m
-        )
-        assert not obs.ENABLED
-        spgemm_auto(PLUS_TIMES, A, A)  # store miss -> heuristic route
-        assert obs.registry.empty()  # disabled: zero bookkeeping
-        obs.enable(install_hooks=False)
-        obs.reset()
-        st = tstore.get_store()
-        st.put(
-            spgemm_plan_key(PLUS_TIMES, A, A, "scatter"),
-            PlanRecord(tier="scan", cost_s=0.2),
-        )
-        spgemm_auto(PLUS_TIMES, A, A)
-        assert obs.registry.get_counter(
-            "tuner.store.hits", op="spgemm"
-        ) == 1
-        assert obs.registry.get_counter(
-            "spgemm.auto.plan_source", source="store", tier="scan",
-            op="spgemm",
-        ) == 1
-        assert obs.registry.get_gauge(
-            "tuner.store.entries", dir=st.path
-        ) == 1
-    finally:
-        obs.disable()
-        obs.reset()
-        tstore._reset_for_tests()
-
-
 def test_round13_merge_counters_gated(rng):
     """ISSUE 11 satellite: the round-13 merge-tier series —
     ``spgemm.merge.tier`` and the ``merge``-labeled trace counter —

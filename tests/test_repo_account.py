@@ -29,11 +29,9 @@ BASES = ("", "combblas_tpu", "tests")
 _TOKEN = re.compile(r"`([^`\n]+)`")
 _FILE = re.compile(r"^[^\s()=]+\.(?:py|md|jsonl|json)$")
 
-#: Generated at run time (the plan store's file), outside the checkout,
-#: or a placeholder (``<workdir>/...``, ``$COMBBLAS_PLAN_STORE/...``).
-#: Upstream CombBLAS sources cited from SURVEY.md end in .cpp / .h and
-#: are not swept.
-ALLOWED = {"plans.jsonl"}
+#: Outside the checkout, or a placeholder (``<workdir>/...``,
+#: ``$COMBBLAS_WAL/...``).  Upstream CombBLAS sources cited from
+#: SURVEY.md end in .cpp / .h and are not swept.
 _ALLOWED_PREFIXES = ("/root/", "$")
 
 
@@ -78,7 +76,7 @@ def cited_files(text: str) -> list[str]:
         tok = _strip_anchor(tok.strip())
         if not _FILE.match(tok):
             continue
-        if tok in ALLOWED or tok.startswith(_ALLOWED_PREFIXES) or "<" in tok:
+        if tok.startswith(_ALLOWED_PREFIXES) or "<" in tok:
             continue
         out.extend(_expand_braces(tok))
     return out
@@ -107,7 +105,7 @@ def test_the_sweep_sees_a_missing_file():
     """The checker itself: anchors stripped, braces expanded, a file
     that is gone reported."""
     text = ("see `serve/engine.py:12`, `tests/test_obs.py::test_x`, "
-            "`serve/{api,nonesuch}.py`, `bench.py` and `plans.jsonl`")
+            "`serve/{api,nonesuch}.py`, `bench.py` and `$COMBBLAS_WAL/wal.jsonl`")
     cited = cited_files(text)
     assert cited == ["serve/engine.py", "tests/test_obs.py",
                      "serve/api.py", "serve/nonesuch.py", "bench.py"]

@@ -531,3 +531,34 @@ def test_compile_cache_idempotent(tmp_path):
                 "jax_persistent_cache_min_entry_size_bytes", 0
             )
             cc._configured_dir = None
+
+
+# --- serve warmup widths -----------------------------------------------------
+
+
+def _small_bfs_engine(seed):
+    rng = np.random.default_rng(seed)
+    n = 32
+    rows = rng.integers(0, n, 100).astype(np.int64)
+    cols = rng.integers(0, n, 100).astype(np.int64)
+    return GraphEngine.from_coo(
+        Grid.make(1, 1), np.concatenate([rows, cols]),
+        np.concatenate([cols, rows]), n, kinds=("bfs",),
+    )
+
+
+def test_warmup_explicit_widths_unchanged():
+    warmed = _small_bfs_engine(6).warmup(widths=(2, 4))
+    assert set(warmed) == {("bfs", 2), ("bfs", 4)}
+
+
+def test_warmup_default_widths():
+    """One place decides which lanes are warmed: no ``widths`` means
+    ``DEFAULT_WARMUP_WIDTHS`` and nothing else, whatever plan-cache
+    misses came before."""
+    eng = _small_bfs_engine(7)
+    eng.plan("bfs", 32)  # a miss outside the default widths
+    warmed = eng.warmup()
+    assert set(warmed) == {
+        ("bfs", w) for w in GraphEngine.DEFAULT_WARMUP_WIDTHS
+    }

@@ -1,6 +1,6 @@
 """Replicated serving fleet + GraphVersion checkpoints (round 14):
 least-loaded routing with spillover, home-replica writes fanned out
-through the atomic swap, one shared warm plan store, and the
+through the atomic swap, a cold against a warmed replica, and the
 ``save_version``/``load_version`` zero-retrace warm start.
 
 Tier-1 tests are small and pump/worker-deterministic; the threaded
@@ -19,8 +19,6 @@ from combblas_tpu.serve import (
     GraphEngine,
     ServeConfig,
 )
-from combblas_tpu.tuner import config as tuner_config
-from combblas_tpu.tuner import store as tstore
 from combblas_tpu.utils import checkpoint
 
 N = 64
@@ -38,13 +36,6 @@ def _coo(seed, n=N, m=300):
 @pytest.fixture(scope="module")
 def grid():
     return Grid.make(2, 4)
-
-
-@pytest.fixture(autouse=True)
-def _fresh_store_singleton():
-    tstore._reset_for_tests()
-    yield
-    tstore._reset_for_tests()
 
 
 # --- checkpoint round-trip ---------------------------------------------------
@@ -189,23 +180,20 @@ def test_fleet_write_home_and_fanout(grid):
     assert fr.fanouts == 1
 
 
-# --- shared warm plan store --------------------------------------------------
+# --- cold against warm replica -----------------------------------------------
 
 
-def test_fleet_cold_vs_warm_replica_ab(grid, tmp_path, monkeypatch):
-    """The fleet A/B: replica 1's traffic records its lanes in the
-    SHARED plan store; a cold replica serving the same lane retraces,
-    while a warm-started replica (fresh store load + ``warmup()``)
-    reaches zero-retrace steady state before its first request."""
-    monkeypatch.setenv(tuner_config.ENV_PLAN_STORE, str(tmp_path))
-    tstore._reset_for_tests()
+def test_fleet_cold_vs_warm_replica_ab(grid):
+    """The fleet A/B: a cold replica serving a lane retraces, while a
+    warm-started replica (``warmup()``) reaches zero-retrace steady
+    state before its first request."""
     rows, cols = _coo(7)
 
     def build():
         return GraphEngine.from_coo(grid, rows, cols, N, kinds=("bfs",))
 
     donor = build()
-    donor.plan("bfs", 4)  # the traffic mix's lane, recorded
+    donor.plan("bfs", 4)  # the traffic mix's lane
 
     # COLD replica: no warmup — first width-4 batch must trace
     cold = build()
@@ -213,9 +201,8 @@ def test_fleet_cold_vs_warm_replica_ab(grid, tmp_path, monkeypatch):
     cold.execute("bfs", np.full(4, -1, np.int32))
     assert cold.retraces_since(mark) > 0
 
-    # WARM replica: a fresh process (new store instance) replays the
-    # remembered lane during warmup -> zero retraces at steady state
-    tstore._reset_for_tests()
+    # WARM replica: the lane is among the warmed widths -> zero
+    # retraces at steady state
     warm = build()
     warmed = warm.warmup()
     assert ("bfs", 4) in warmed

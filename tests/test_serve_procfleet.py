@@ -351,7 +351,7 @@ def test_single_process_replica_end_to_end(tmp_path):
             np.asarray(lev), np.asarray(ref)[:, 0]  # lane 0 = root 3
         )
         # zero post-warmup retraces IN THE CHILD, asserted over IPC
-        # (the shared plan store + boot warmup claim)
+        # (the boot warmup claim)
         assert fr.retraces_since(marks) == 0
         # a write is WAL-durable before its future resolves; headroom
         # keeps the merge incremental so plans survive

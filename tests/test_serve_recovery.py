@@ -33,7 +33,6 @@ from combblas_tpu.serve import (
     Server,
 )
 from combblas_tpu.serve.fleet import ReplicaDeadError
-from combblas_tpu.tuner import store as tstore
 from combblas_tpu.utils import checkpoint
 
 N = 64
@@ -72,13 +71,6 @@ def _assert_bit_exact(va, vb):
 @pytest.fixture(scope="module")
 def grid():
     return Grid.make(1, 1)
-
-
-@pytest.fixture(autouse=True)
-def _fresh_store_singleton():
-    tstore._reset_for_tests()
-    yield
-    tstore._reset_for_tests()
 
 
 # --- WAL unit behavior -------------------------------------------------------
@@ -575,7 +567,7 @@ def test_fanout_failure_lags_visibly_and_heals(tmp_path):
 def test_supervisor_replaces_dead_replica_bit_exact(tmp_path):
     """A dead (non-home) replica is quarantined (pending futures fail
     honestly), rebuilt from checkpoint+WAL and re-admitted serving the
-    acknowledged writes — warm from the shared plan store.
+    acknowledged writes, warm.
 
     ``slow``: the tier-1 representative of the supervise->quarantine->
     rebuild path is ``test_home_death_promotes_at_wal_frontier``

@@ -28,16 +28,8 @@ from combblas_tpu.dynamic import DeltaBatch
 from combblas_tpu.parallel.grid import Grid
 from combblas_tpu.serve import GraphEngine, ShardedEngine
 from combblas_tpu.serve.shard import ShardSpec, plan_partition, shard_coo
-from combblas_tpu.tuner import store as tstore
 
 N = 40
-
-
-@pytest.fixture(autouse=True)
-def _fresh_store_singleton():
-    tstore._reset_for_tests()
-    yield
-    tstore._reset_for_tests()
 
 
 def _coo(seed, n=N, m=170):

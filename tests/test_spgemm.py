@@ -123,27 +123,6 @@ def test_summa_rect_matrices_nonuniform(rng):
     np.testing.assert_allclose(C.to_dense(), da @ db, rtol=1e-5, atol=1e-6)
 
 
-def test_summa_stage_flops_host_matches_device(rng):
-    """The host symbolic twin must track the device pass exactly — the
-    benchmarks size capacities from it with no device cross-check."""
-    from combblas_tpu.parallel.spgemm import (
-        summa_capacities,
-        summa_capacities_host,
-        summa_stage_flops,
-        summa_stage_flops_host,
-    )
-
-    grid = Grid.make(2, 2)
-    n = 37  # non-divisible dims exercise the padded owner math
-    d = (rng.random((n, n)) < 0.2).astype(np.float32)
-    r, c = np.nonzero(d)
-    A = SpParMat.from_global_coo(grid, r, c, d[r, c], n, n)
-    dev = np.asarray(summa_stage_flops(A, A), np.float64)
-    host = summa_stage_flops_host(grid, r, c, r, c, n, n, n)
-    np.testing.assert_array_equal(dev, host)
-    assert summa_capacities_host(grid, r, c, r, c, n, n, n) == summa_capacities(A, A)
-
-
 def test_spgemm_scan_matches_summa(rng):
     """Output-bounded scanned SUMMA == the unphased product."""
     from combblas_tpu.parallel.spgemm import spgemm_scan

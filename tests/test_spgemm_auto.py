@@ -30,6 +30,7 @@ from combblas_tpu.parallel.spgemm import (
     WINDOWED_MAX_COL_WINDOWS,
     WINDOWED_MAX_PANEL_CELLS,
     _pad128,
+    bucket_plan_caps,
     choose_spgemm_tier,
     choose_tier_from_counts,
     default_block_cols,
@@ -40,11 +41,8 @@ from combblas_tpu.parallel.spgemm import (
     spgemm_auto,
     spgemm_windowed,
     summa_rowblock_flops,
-    summa_rowblock_flops_host,
     summa_spgemm_windowed,
     summa_window_bnnz,
-    summa_window_bnnz_host,
-    summa_window_flops_host,
     summa_window_flops_pair,
     windowed_plan,
     windowed_plan_2d,
@@ -154,7 +152,7 @@ def test_empty_output_blocks_are_skipped(rng):
     )
 
 
-def test_forced_tier_overrides_agree(rng, monkeypatch):
+def test_forced_tier_overrides_agree(rng):
     grid = Grid.make(2, 2)
     m = 48
     ra, ca, va = coo(rng, m, m, 300)
@@ -170,10 +168,6 @@ def test_forced_tier_overrides_agree(rng, monkeypatch):
         np.testing.assert_allclose(
             dense_of(C), ref, rtol=1e-4, atol=1e-5
         )
-    # env override is honored
-    monkeypatch.setenv("COMBBLAS_SPGEMM_TIER", "windowed")
-    C = spgemm_auto(PLUS_TIMES, A, A)
-    np.testing.assert_allclose(dense_of(C), ref, rtol=1e-4, atol=1e-5)
 
 
 def test_tier_gate_rules():
@@ -248,24 +242,6 @@ def test_router_records_obs_counters(rng):
     finally:
         obs.disable()
         obs.reset()
-
-
-def test_rowblock_flops_host_matches_device(rng):
-    grid = Grid.make(2, 2)
-    m, k, n = 64, 48, 80
-    ra, ca, va = coo(rng, m, k, 400)
-    rb, cb, vb = coo(rng, k, n, 500)
-    A = SpParMat.from_global_coo(grid, ra, ca, va, m, k)
-    B = SpParMat.from_global_coo(grid, rb, cb, vb, k, n)
-    for w in (0, 8):
-        dev = np.asarray(
-            jax.device_get(summa_rowblock_flops(A, B, 8, chunk_w=w))
-        )
-        host = summa_rowblock_flops_host(
-            grid, ra, ca, rb, cb, m, k, n, 8, chunk_w=w
-        )
-        np.testing.assert_array_equal(dev.astype(np.int64),
-                                      host.astype(np.int64))
 
 
 def test_support_oracle_exact(rng):
@@ -501,37 +477,6 @@ def test_windowed_dot_2d_empty_windows_skipped(rng):
     C_esc = spgemm(PLUS_TIMES, A, B)
     np.testing.assert_allclose(
         dense_of(C_win), dense_of(C_esc), rtol=1e-5, atol=1e-6
-    )
-
-
-def test_window_flops_host_matches_device_2d(rng):
-    """Host==device agreement of the 2D symbolic plan inputs: the
-    per-(row block, col window) flop pair and the per-window B nnz."""
-    grid = Grid.make(2, 2)
-    m, k, n = 64, 48, 80
-    ra, ca, va = coo(rng, m, k, 400, dup_frac=0.1)
-    rb, cb, vb = coo(rng, k, n, 500, dup_frac=0.1)
-    A = SpParMat.from_global_coo(grid, ra, ca, va, m, k)
-    B = SpParMat.from_global_coo(grid, rb, cb, vb, k, n)
-    dev = np.asarray(
-        jax.device_get(summa_window_flops_pair(A, B, 8, 16, chunk_w=8))
-    )
-    host_pad = summa_window_flops_host(
-        grid, ra, ca, rb, cb, m, k, n, 8, 16, chunk_w=8
-    )
-    host_true = summa_window_flops_host(
-        grid, ra, ca, rb, cb, m, k, n, 8, 16, chunk_w=0
-    )
-    np.testing.assert_array_equal(
-        dev[0].astype(np.int64), host_pad.astype(np.int64)
-    )
-    np.testing.assert_array_equal(
-        dev[1].astype(np.int64), host_true.astype(np.int64)
-    )
-    bnnz_dev = np.asarray(jax.device_get(summa_window_bnnz(B, 16)))
-    bnnz_host = summa_window_bnnz_host(grid, rb, cb, k, n, 16)
-    np.testing.assert_array_equal(
-        bnnz_dev.astype(np.int64), bnnz_host.astype(np.int64)
     )
 
 
@@ -808,7 +753,6 @@ def test_blocked_dispatch_matches_fused(rng):
     the fused kernel and the ESC golden, duplicate entries included."""
     from combblas_tpu.parallel.spgemm import (
         WINDOWED_CHUNK_W,
-        summa_rowblock_flops_host,
         summa_spgemm_windowed_blocked,
     )
 
@@ -819,11 +763,10 @@ def test_blocked_dispatch_matches_fused(rng):
     # EVERY grid row, so the packed host loop's skip path is exercised
     ra = ra % 32
     A = SpParMat.from_global_coo(grid, ra, ca, va, m, m)
-    pb = summa_rowblock_flops_host(
-        grid, ra, ca, ra, ca, m, m, m, 16, chunk_w=WINDOWED_CHUNK_W
-    )
-    pt = summa_rowblock_flops_host(
-        grid, ra, ca, ra, ca, m, m, m, 16, chunk_w=0
+    pb, pt = (
+        np.asarray(jax.device_get(
+            summa_rowblock_flops(A, A, 16, chunk_w=w)))
+        for w in (WINDOWED_CHUNK_W, 0)
     )
     fc, oc, skip = windowed_plan(pb, pt, 16, A.local_rows, A.local_cols)
     assert any(skip)
@@ -937,3 +880,90 @@ def test_support_oracle_window_counts_and_seeding(rng):
         dense_of(C), dense_of(ref), rtol=1e-5, atol=1e-6
     )
     assert host_nnz(C) == host_nnz(ref)
+
+
+# --- building-block dispatch / bucketed caps -------------------------------
+
+
+def test_ring_wins_over_explicit_blocked(rng):
+    """ring is a fused-only schedule: an explicit dispatch='blocked'
+    yields to it (obs-counted), instead of silently dropping the
+    carousel request."""
+    grid = Grid.make(2, 2)
+    m = 64
+    r, c, v = coo(rng, m, m, 400, dup_frac=0.2)
+    A = SpParMat.from_global_coo(grid, r, c, v, m, m)
+    obs.enable(install_hooks=False)
+    try:
+        obs.reset()
+        spgemm_windowed(
+            PLUS_TIMES, A, A, block_rows=8, backend="scatter",
+            ring=True, dispatch="blocked",
+        )
+        assert obs.registry.get_counter(
+            "spgemm.windowed.dispatch_conflict"
+        ) == 1
+        assert obs.registry.get_counter(
+            "spgemm.windowed.dispatch", mode="fused"
+        ) == 1
+    finally:
+        obs.disable()
+        obs.reset()
+
+
+def test_bucket_plan_caps_shapes():
+    fc, oc = bucket_plan_caps((3, 17, 1), (1000, 5, 64))
+    assert fc == (4, 32, 1) and oc == (1024, 8, 64)
+    fc2, oc2 = bucket_plan_caps(
+        ((3, 5), (9, 1)), ((33, 2), (7, 128))
+    )
+    assert fc2 == ((4, 8), (16, 1)) and oc2 == ((64, 2), (8, 128))
+
+
+@pytest.mark.parametrize("dispatch", [
+    "auto", "blocked",
+    # "fused" is slow-lane (round 12, tier-1 budget): the fused
+    # one-graph kernel keeps tier-1 coverage via the ring tests and
+    # test_blocked_dispatch_matches_fused
+    pytest.param("fused", marks=pytest.mark.slow),
+])
+def test_windowed_dispatch_agreement(rng, dispatch):
+    """The blocked building-block dispatch (the round-10 multi-device
+    default) emits the same product as the fused graph."""
+    grid = Grid.make(2, 2)
+    m = 96
+    r, c, v = coo(rng, m, m, 800, dup_frac=0.1)
+    A = SpParMat.from_global_coo(grid, r, c, v, m, m)
+    C = spgemm_windowed(
+        PLUS_TIMES, A, A, block_rows=8, backend="scatter",
+        dispatch=dispatch,
+    )
+    C_ref = spgemm(PLUS_TIMES, A, A)
+    np.testing.assert_allclose(
+        dense_of(C), dense_of(C_ref), rtol=1e-5, atol=1e-6
+    )
+
+
+def test_windowed_auto_dispatch_is_blocked_multidev(rng):
+    grid = Grid.make(2, 2)
+    m = 96
+    r, c, v = coo(rng, m, m, 800, dup_frac=0.2)
+    A = SpParMat.from_global_coo(grid, r, c, v, m, m)
+    obs.enable(install_hooks=False)
+    try:
+        obs.reset()
+        spgemm_windowed(PLUS_TIMES, A, A, block_rows=8,
+                        backend="scatter")
+        assert obs.registry.get_counter(
+            "spgemm.windowed.dispatch", mode="blocked"
+        ) == 1
+        # ring keeps the fused carousel (the pipelined schedule)
+        obs.reset()
+        spgemm_windowed(PLUS_TIMES, A, A, block_rows=8,
+                        backend="scatter", ring=True)
+        assert obs.registry.get_counter(
+            "spgemm.windowed.dispatch", mode="fused"
+        ) == 1
+    finally:
+        obs.disable()
+        obs.reset()

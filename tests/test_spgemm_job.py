@@ -241,19 +241,14 @@ def test_a_job_with_no_tier_routes_by_the_rule_inside_it(s8):
     assert S.spgemm_job(PLUS_TIMES, dup, dup)[1]["tier"] == "windowed"
 
 
-def test_neither_the_platform_nor_the_environment_decides(s8, monkeypatch):
+def test_neither_the_platform_nor_the_environment_decides(s8):
     """The tier-1 tests run on a CPU, whose platform default is the
-    scatter backend, and under knobs that steer ``spgemm_auto``: a job
-    runs what its arguments say, shown by its span's labels."""
+    scatter backend: a job runs what its arguments say, shown by its
+    span's labels (no environment variable routes a product any more:
+    ``tests/test_spgemm_routing.py`` holds that, a name a case)."""
     n, rows, cols, A, ref = s8
     assert jax.default_backend() == "cpu"
     assert S.resolve_spgemm_backend() == "scatter"
-    for name, value in (("COMBBLAS_SPGEMM_TIER", "esc"),
-                        ("COMBBLAS_SPGEMM_BACKEND", "scatter"),
-                        ("COMBBLAS_SPGEMM_BUCKET_CAPS", "0"),
-                        ("COMBBLAS_SPGEMM_DISPATCH", "fused"),
-                        ("COMBBLAS_TUNER_PROBE", "1")):
-        monkeypatch.setenv(name, value)
     obs.reset()
     obs.enable(install_hooks=False)
     try:

@@ -329,66 +329,11 @@ def test_piece_overflow_detected_and_diagnosed(rng):
         obs.reset()
 
 
-def test_merge_resolution_chain(rng, tmp_path, monkeypatch):
-    """merge= resolves arg > store > env > heuristic (the tuner
-    precedence, extended to the round-13 knob)."""
-    from combblas_tpu.tuner import store as tuner_store
-
-    monkeypatch.setenv("COMBBLAS_PLAN_STORE", str(tmp_path))
-    tuner_store._reset_for_tests()
-    A3, B3 = _mats3d(rng)
-    store = tuner_store.get_store()
-    key = tuner_store.spgemm3d_plan_key(PLUS_TIMES, A3, B3, "")
-    store.put(key, tuner_store.PlanRecord(
-        tier="windowed", merge="hash", source="manual", cost_s=1.0,
-    ))
-    obs.enable(install_hooks=False)
-    try:
-        # arg beats the store record AND the env
-        monkeypatch.setenv("COMBBLAS_SPGEMM_MERGE", "sort")
-        obs.reset()
-        spgemm3d(PLUS_TIMES, A3, B3, merge="runs")
-        assert obs.registry.get_counter(
-            "spgemm.merge.tier", tier="runs", source="arg",
-            op="spgemm3d",
-        ) == 1
-        # store beats the env
-        obs.reset()
-        spgemm3d(PLUS_TIMES, A3, B3)
-        assert obs.registry.get_counter(
-            "spgemm.merge.tier", tier="hash", source="store",
-            op="spgemm3d",
-        ) == 1
-        # env beats the heuristic (tier forced so the record is
-        # bypassed — arg > store holds for the tier, so merge falls
-        # through to the env rung)
-        obs.reset()
-        spgemm3d(PLUS_TIMES, A3, B3, tier="esc")
-        assert obs.registry.get_counter(
-            "spgemm.merge.tier", tier="sort", source="env",
-            op="spgemm3d",
-        ) == 1
-        # heuristic when nothing else decided: windowed scatter pieces
-        # arrive presorted -> "runs"
-        monkeypatch.delenv("COMBBLAS_SPGEMM_MERGE")
-        obs.reset()
-        spgemm3d(PLUS_TIMES, A3, B3, tier="windowed")
-        assert obs.registry.get_counter(
-            "spgemm.merge.tier", tier="runs", source="heuristic",
-            op="spgemm3d",
-        ) == 1
-    finally:
-        obs.disable()
-        obs.reset()
-        tuner_store._reset_for_tests()
-
-
-def test_forced_hash_on_generic_monoid_degrades(rng, monkeypatch):
-    """Review finding (r13): a fleet-wide ``COMBBLAS_SPGEMM_MERGE=hash``
-    (or a hash plan record) on a semiring WITHOUT a native scatter
-    combiner must degrade to ``runs`` at the knob — counted with a
-    ``_degraded`` source — never assert mid-trace inside the shard_map
-    body (the round-12 env-vetting precedent)."""
+def test_forced_hash_on_generic_monoid_degrades(rng):
+    """Review finding (r13): a forced ``merge="hash"`` on a semiring
+    WITHOUT a native scatter combiner must degrade to ``runs`` where it
+    is resolved — counted with a ``_degraded`` source — never assert
+    mid-trace inside the shard_map body."""
     from combblas_tpu.semiring import Semiring
 
     sr = Semiring(
@@ -396,49 +341,16 @@ def test_forced_hash_on_generic_monoid_degrades(rng, monkeypatch):
         mul=lambda a, x: a * x, zero_fn=lambda dt: 0,
         one_fn=lambda dt: 1, add_kind="generic",
     )
-    monkeypatch.setenv("COMBBLAS_SPGEMM_MERGE", "hash")
     A3, B3 = _mats3d(rng)
     obs.enable(install_hooks=False)
     try:
         obs.reset()
-        spgemm3d(sr, A3, B3, tier="esc")
+        spgemm3d(sr, A3, B3, tier="esc", merge="hash")
         assert obs.registry.get_counter(
-            "spgemm.merge.tier", tier="runs", source="env_degraded",
+            "spgemm.merge.tier", tier="runs", source="arg_degraded",
             op="spgemm3d",
         ) == 1
     finally:
         obs.disable()
         obs.reset()
 
-
-def test_plan_record_merge_roundtrip(tmp_path, monkeypatch):
-    """PlanRecord.merge persists through the JSONL store (additive
-    field: pre-r13 lines load as None) and a mangled value is an
-    invalid LINE, not a crash."""
-    import json
-
-    from combblas_tpu.tuner import store as tuner_store
-
-    monkeypatch.setenv("COMBBLAS_PLAN_STORE", str(tmp_path))
-    tuner_store._reset_for_tests()
-    store = tuner_store.get_store()
-    key = tuner_store.plan_key_from_counts(
-        "plus_times", 64, 64, 64, 500, 500, "", "2x2",
-        grid3="2x2x2", op="spgemm3d",
-    )
-    store.put(key, tuner_store.PlanRecord(tier="esc", merge="runs"))
-    tuner_store._reset_for_tests()
-    got = tuner_store.get_store().peek(key)
-    assert got.merge == "runs"
-    # hand-mangled merge value: the line is skipped as invalid
-    with open(tuner_store.get_store().file, "a") as f:
-        line = {
-            "v": tuner_store.SCHEMA, "key": key.to_json(),
-            "plan": {"tier": "esc", "merge": "bogus"},
-        }
-        f.write(json.dumps(line) + "\n")
-    tuner_store._reset_for_tests()
-    st = tuner_store.get_store()
-    assert st.stats()["invalid_lines"] == 1
-    assert st.peek(key).merge == "runs"  # the valid line still routes
-    tuner_store._reset_for_tests()

@@ -1,8 +1,7 @@
 """Round-12 batched SpMM lane: kernel golden agreement across
 semirings / grids / backends with duplicate-entry COO, the SUMMA
 carousel schedules, fused k-hop propagation, the serve ``"propagate"``
-kind (pad-lane leak + zero-retrace), tuner op="spmm" store round-trip,
-and the round-12 obs series gate.  docs/spmm.md."""
+kind (pad-lane leak + zero-retrace), and the round-12 obs series gate.  docs/spmm.md."""
 
 import numpy as np
 import pytest
@@ -17,12 +16,9 @@ from combblas_tpu.parallel.spmat import SpParMat
 from combblas_tpu.parallel.spmm import (
     SPMM_BACKENDS,
     admissible_spmm_backends,
-    dist_spmm,
     dist_spmm_ell,
     pad_feature_width,
     pad_features,
-    resolve_spmm_backend,
-    spmm_backend_heuristic,
     spmm_khop,
     summa_spmm,
 )
@@ -173,107 +169,6 @@ def test_pad_feature_width():
         [1, 2, 4, 64, 128]
     out = pad_features(np.ones((3, 5), np.float32))
     assert out.shape == (3, 8) and np.all(out[:, 5:] == 0)
-
-
-# -- tuner routing (op="spmm") -----------------------------------------------
-
-
-def test_spmm_backend_resolution_chain(rng, tmp_path, monkeypatch):
-    """arg > store > env > heuristic for the SpMM backend; a store
-    record with a tier outside the SpMM set is rejected down the
-    chain; non-plus_times semirings short-circuit to scatter."""
-    from combblas_tpu.tuner import (
-        PlanRecord, spmm_plan_key,
-    )
-    from combblas_tpu.tuner import store as tstore
-
-    monkeypatch.setenv("COMBBLAS_PLAN_STORE", str(tmp_path))
-    tstore._reset_for_tests()
-    n, F = 48, 8
-    r, c, v = _coo(rng, n, 200, dup=0)
-    grid = Grid.make(1, 1)
-    E = EllParMat.from_host_coo(grid, r, c, v, n, n)
-
-    # heuristic rung (empty store, no env)
-    assert resolve_spmm_backend(PLUS_TIMES, E, F) == "mxu_gather"
-    assert resolve_spmm_backend(MIN_PLUS, E, F) == "scatter"
-    assert spmm_backend_heuristic(MAX_MIN) == "scatter"
-
-    # store rung: a remembered scatter plan beats the heuristic
-    store = tstore.get_store()
-    key = spmm_plan_key(PLUS_TIMES, E, F)
-    store.put(key, PlanRecord(tier="scatter", cost_s=0.01))
-    assert resolve_spmm_backend(PLUS_TIMES, E, F) == "scatter"
-    # the record round-trips the JSONL (fresh load, same resolution)
-    tstore._reset_for_tests()
-    st2 = tstore.get_store()
-    rec = st2.peek(key)
-    assert rec is not None and rec.tier == "scatter"
-    assert resolve_spmm_backend(PLUS_TIMES, E, F) == "scatter"
-    # feature-width bucket is part of the key: F=32 misses
-    assert spmm_plan_key(PLUS_TIMES, E, 32) != key
-    assert resolve_spmm_backend(PLUS_TIMES, E, 32) == "mxu_gather"
-
-    # a vetted-out record (spgemm tier under an spmm key) degrades to
-    # the next rung instead of routing
-    store2 = tstore.get_store()
-    store2.put(key, PlanRecord(tier="windowed"))
-    assert resolve_spmm_backend(PLUS_TIMES, E, F) == "mxu_gather"
-
-    # env rung (wins over heuristic when the store was vetted out)
-    monkeypatch.setenv("COMBBLAS_SPMM_BACKEND", "scatter")
-    assert resolve_spmm_backend(PLUS_TIMES, E, F) == "scatter"
-    monkeypatch.delenv("COMBBLAS_SPMM_BACKEND")
-
-    # arg rung beats everything; an inexact arg raises
-    assert resolve_spmm_backend(
-        PLUS_TIMES, E, F, backend="mxu_gather"
-    ) == "mxu_gather"
-    with pytest.raises(ValueError, match="not exact"):
-        resolve_spmm_backend(MIN_PLUS, E, F, backend="mxu_gather")
-
-    # a bogus env value fails loudly naming the knob, never a bare
-    # kernel assert (or a silent fallback under python -O)
-    monkeypatch.setenv("COMBBLAS_SPMM_BACKEND", "mxu")
-    with pytest.raises(ValueError, match="COMBBLAS_SPMM_BACKEND"):
-        resolve_spmm_backend(PLUS_TIMES, E, F)
-    monkeypatch.delenv("COMBBLAS_SPMM_BACKEND")
-
-
-def test_probe_spmm_records_winner(rng, tmp_path, monkeypatch):
-    """The SpMM micro-probe measures both backends with an injected
-    cost functional and persists the winner under the spmm key; the
-    routed entry then serves it from the store."""
-    from combblas_tpu.tuner import spmm_plan_key
-    from combblas_tpu.tuner import store as tstore
-    from combblas_tpu.tuner.probe import probe_spmm
-
-    monkeypatch.setenv("COMBBLAS_PLAN_STORE", str(tmp_path))
-    tstore._reset_for_tests()
-    n, F = 40, 4
-    r, c, v = _coo(rng, n, 150, dup=0)
-    grid = Grid.make(1, 1)
-    E = EllParMat.from_host_coo(grid, r, c, v, n, n)
-    X = DistMultiVec.from_global(
-        grid, rng.random((n, F)).astype(np.float32), align="col"
-    )
-    store = tstore.get_store()
-    key = spmm_plan_key(PLUS_TIMES, E, F)
-    fake_costs = iter([0.5, 0.1])  # heuristic first -> scatter wins
-
-    rec = probe_spmm(
-        PLUS_TIMES, E, X, store=store, key=key,
-        measure=lambda fn: next(fake_costs),
-    )
-    assert rec is not None and rec.tier == "scatter"
-    assert store.peek(key).tier == "scatter"
-    assert resolve_spmm_backend(PLUS_TIMES, E, F) == "scatter"
-    # nothing to probe for a single-backend semiring
-    assert probe_spmm(MIN_PLUS, E, X, store=store, key=None) is None
-    # the routed wrapper agrees with the forced-backend kernel
-    got = dist_spmm(PLUS_TIMES, E, X).to_global()
-    want = dist_spmm_ell(PLUS_TIMES, E, X, backend="scatter").to_global()
-    np.testing.assert_array_equal(got, want)
 
 
 # -- serve "propagate" kind --------------------------------------------------

@@ -24,7 +24,7 @@ from combblas_tpu.ops import spgemm as ops  # noqa: E402
 from combblas_tpu.parallel.grid import Grid  # noqa: E402
 from combblas_tpu.parallel.spmat import SpParMat  # noqa: E402
 
-KERNELS = ("auto", "dense", "edgeharvest", "edgeharvest_bf16", "sparse")
+KERNELS = ("auto", "dense", "edgeharvest", "sparse")
 CHUNK = 8192  # the harvest's pair chunk
 
 
