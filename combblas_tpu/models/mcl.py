@@ -28,9 +28,11 @@ iteration is one jitted SPMD program.
 from __future__ import annotations
 
 import dataclasses
-from functools import lru_cache
+from functools import lru_cache, partial
 
+import jax
 import jax.numpy as jnp
+from jax import lax
 
 from .. import obs
 from ..semiring import MAX_MIN, PLUS_TIMES
@@ -199,12 +201,19 @@ def mcl(
     vertex id of its cluster (the component labeling of the converged
     attractor structure).
 
-    ``expansion="dense"`` (round 4; single shard, n ≲ 32K) runs the whole
+    ``expansion="dense"`` (single shard; run at n = 16,384, not measured
+    above) runs the whole
     clustering as ONE jitted ``lax.while_loop`` with dense MXU squaring —
     no capacities, no overflow, no per-iteration readbacks; ``dense_mode``
-    picks the matmul precision (see ``parallel.spgemm._mxu_dot``).  On
-    the target chip this is >10x per iteration over the sparse path at
-    scale 12-14 (round-4 notes).
+    picks the matmul precision (see ``parallel.spgemm._mxu_dot``).
+    On one v5e, HipMCL's published parameters on a 1.4 M-edge planted-
+    family graph of 16,384 vertices (my chip run, PR 44): a warm
+    clustering 8.06 s for 18 iterations, where the sparse loops do not
+    fit the chip at all (the first expansion's program needs 28.1 GB,
+    16.9 GB with ``scan=True``; ``chaos_every``'s frozen capacity passes
+    int32) and ``mcl_job`` takes 5.48 s.  The measured path is
+    ``mcl_job`` below, which holds the same dense state a row block at a
+    time and selects without ``top_k``.
 
     ``perturb_delta`` (dense path only) enables the plateau
     detect-and-perturb kicks — OFF by default: the escalating self-loop
@@ -300,6 +309,454 @@ def mcl(
     sym = A.ewise_add(A.transpose(), PLUS_TIMES)
     labels, _ = connected_components(sym)
     return labels, it, ch
+
+
+# --- one whole clustering as one job ----------------------------------------
+
+#: The ``jax.named_scope`` names of a clustering job's programs, in the
+#: order a job meets them.  ``mcl.symbolic`` is what counts an
+#: expansion's multiplies before it runs (the loops and the first
+#: normalisation ride in its first program); ``mcl.expand`` the product
+#: (a dense row block on the matrix unit, or the sort-based ``scan``
+#: product, whose own ``sq.*`` scopes stay inside it); ``mcl.select``
+#: the hard prune, the select, the recovery and the re-normalisation;
+#: ``mcl.chaos`` the stopping residual; ``mcl.inflate`` the Hadamard
+#: power and its re-normalisation (and the walk between the dense state
+#: and tuples); ``mcl.interpret`` the residue's prune, the symmetrised
+#: matrix, its components and the digest.  Trace-time metadata only: a
+#: device trace's per-scope times are read by these names, so a rename
+#: is a change of yardstick.
+MCL_SCOPES = (
+    "mcl.symbolic",
+    "mcl.expand",
+    "mcl.select",
+    "mcl.chaos",
+    "mcl.inflate",
+    "mcl.interpret",
+)
+
+#: The tiers of ``choose_tier_from_counts`` under which an iteration's
+#: expansion is a dense product that selects before it stores.
+DENSE_TIERS = ("mxu", "windowed")
+
+
+def _pow2(x) -> int:
+    return 1 << max(int(x) - 1, 1).bit_length()
+
+
+def _products(A: SpParMat):
+    """``float32[2]``: the scalar multiplies of ``A @ A`` and the slots
+    its chunked expansion allocates (``summa_stage_flops``)."""
+    from ..parallel.spgemm import summa_stage_flops
+
+    with jax.named_scope("mcl.symbolic"):
+        return jnp.stack([
+            jnp.sum(summa_stage_flops(A, A, padded=padded))
+            for padded in (False, True)
+        ])
+
+
+@jax.jit
+def _mcl_start(A: SpParMat):
+    """Loops added, columns scaled to sum 1, and the first expansion's
+    counts."""
+    with jax.named_scope("mcl.symbolic"):
+        A = make_col_stochastic(A.add_loops(jnp.asarray(1, A.dtype)))
+    return A, _products(A)
+
+
+@partial(jax.jit, static_argnames=("npad",))
+def _mcl_densify(A: SpParMat, *, npad: int):
+    """The stored tuples as the dense TRANSPOSED state ``M[j, i] = A[i,
+    j]``: a column of A is a row of M, the axis a select reduces."""
+    from ..ops.spgemm import densify_combine
+
+    with jax.named_scope("mcl.inflate"):
+        t = A.local_tile(A.rows, A.cols, A.vals, A.nnz).transpose()
+        return densify_combine(PLUS_TIMES, t, npad, npad)
+
+
+@partial(
+    jax.jit,
+    static_argnames=(
+        "block_rows", "hard", "select", "recover", "rpct", "inflation",
+        "mode",
+    ),
+)
+def _mcl_dense_iter(m, *, block_rows, hard, select, recover, rpct,
+                    inflation, mode):
+    """One iteration on the dense transposed state, a row block at a
+    time: the block's product on the matrix unit, ``mcl_select_rows`` on
+    it where it lies (the unpruned product never leaves its window and
+    is never tuples), re-normalise, chaos, inflate.  Returns ``(next
+    state, chaos, int32[7 + blocks])``: the select's candidates, the
+    rows it cut, the rows that recovered, the NEXT expansion's
+    multiplies and its chunked expansion's slots (``_products``' two
+    counts), each a 15-bit (hi, lo) pair, and the stored cells of every
+    row block."""
+    from ..ops.spgemm import CHUNK_W, mcl_select_rows
+    from ..parallel.spgemm import _mxu_dot
+
+    npad = m.shape[0]
+    blocks, counts, ch = [], jnp.zeros((3,), jnp.int32), jnp.float32(0)
+    for lo in range(0, npad, block_rows):
+        with jax.named_scope("mcl.expand"):
+            c = _mxu_dot(m[lo:lo + block_rows], m, mode, jnp.float32)
+        with jax.named_scope("mcl.select"):
+            c, cnt = mcl_select_rows(c, hard, select, recover, rpct)
+            counts = counts + cnt
+            rs = jnp.sum(c, axis=1, keepdims=True)
+            c = c / jnp.where(rs > 0, rs, 1.0)
+        with jax.named_scope("mcl.chaos"):
+            nnzr = jnp.sum(c > 0, axis=1)
+            dev = jnp.max(c, axis=1) - jnp.sum(c * c, axis=1)
+            ch = jnp.maximum(ch, jnp.max(
+                jnp.where(nnzr > 0, dev * nnzr.astype(jnp.float32), 0.0)))
+        with jax.named_scope("mcl.inflate"):
+            c = c ** inflation
+            rs = jnp.sum(c, axis=1, keepdims=True)
+            blocks.append(c / jnp.where(rs > 0, rs, 1.0))
+    with jax.named_scope("mcl.inflate"):
+        m = jnp.concatenate(blocks) if len(blocks) > 1 else blocks[0]
+    with jax.named_scope("mcl.symbolic"):
+        nz = m > 0
+        rowcnt = jnp.sum(nz, axis=1, dtype=jnp.int32)
+        # A's column k meets A's row k: M's row count times its column
+        # count (rounded up to the expansion's chunk for the slots),
+        # under 2^31 each; summed in 15-bit halves so no sum passes it
+        # either
+        colcnt = jnp.sum(nz, axis=0, dtype=jnp.int32)
+        hilo = jnp.stack([
+            half
+            for walk in (colcnt, -(-colcnt // CHUNK_W) * CHUNK_W)
+            for half in (jnp.sum((rowcnt * walk) >> 15),
+                         jnp.sum((rowcnt * walk) & 0x7FFF))
+        ])
+        stored = jnp.stack([
+            jnp.sum(rowcnt[lo:lo + block_rows])
+            for lo in range(0, npad, block_rows)
+        ])
+    return m, ch, jnp.concatenate([counts, hilo, stored])
+
+
+@partial(jax.jit, static_argnames=("grid", "n", "block_rows", "caps"))
+def _mcl_to_tuples(m, grid, *, n: int, block_rows: int, caps: tuple):
+    """The dense transposed state back to A's tuples, a row block at a
+    time (``sparsify_windowed``, each block sized by its own stored
+    count), the blocks' padding sorted behind the entries."""
+    from ..ops.spgemm import sparsify_windowed
+
+    rows_l, cols_l, vals_l = [], [], []
+    with jax.named_scope("mcl.inflate"):
+        for b, lo in enumerate(range(0, n, block_rows)):
+            rb = min(block_rows, n - lo)
+            t, _ = sparsify_windowed(
+                m[lo:lo + rb], 0.0, rb, n, caps[b])
+            # M's row is A's column
+            cols_l.append(jnp.where(t.valid_mask(), t.rows + lo, n))
+            rows_l.append(jnp.where(t.valid_mask(), t.cols, n))
+            vals_l.append(t.vals)
+        cols, rows, vals = lax.sort(
+            tuple(jnp.concatenate(x) for x in (cols_l, rows_l, vals_l)),
+            num_keys=2, is_stable=False)
+        nnz = jnp.sum(rows < n, dtype=jnp.int32)
+    return SpParMat(
+        rows=rows[None, None], cols=cols[None, None], vals=vals[None, None],
+        nnz=nnz[None, None], nrows=n, ncols=n, grid=grid,
+    )
+
+
+@partial(
+    jax.jit,
+    static_argnames=("in_cap", "flop_cap", "out_cap", "hard"),
+)
+def _mcl_scan_expand(A: SpParMat, *, in_cap, flop_cap, out_cap, hard):
+    """A sparse iteration's first program: the sort-based ``scan``
+    product of the stored tuples and the hard prune.  Returns ``(C,
+    int32[3])``: what the product overflowed its symbolic bound by (0 or
+    less: exact), the entries above the prune limit and the largest
+    column's."""
+    from ..parallel.spgemm import summa_spgemm_scan
+
+    with jax.named_scope("mcl.expand"):
+        A = A.with_capacity(in_cap)
+        C, over = summa_spgemm_scan(
+            PLUS_TIMES, A, A, flop_capacity=flop_cap, out_capacity=out_cap)
+    with jax.named_scope("mcl.select"):
+        if hard > 0:
+            C = C.prune(_lt_pred(hard))
+        widest = jnp.max(C.nnz_per_column().blocks)
+    return C, jnp.stack([over, C.getnnz(), widest]).astype(jnp.int32)
+
+
+@partial(
+    jax.jit,
+    static_argnames=(
+        "cap", "cut", "select", "recover", "rpct", "inflation"),
+)
+def _mcl_scan_select(C: SpParMat, *, cap, cut, select, recover, rpct,
+                     inflation):
+    """A sparse iteration's second program, on the candidates cut to
+    ``cap`` slots: select and recovery (``cut``: some column holds more
+    than ``select``; else nothing can be cut and both thresholds are
+    skipped), re-normalise, chaos, inflate, and the next expansion's
+    counts.  Returns ``(A, chaos, int32[3], float32[2])``: stored
+    entries, columns cut, columns recovered; ``_products``."""
+    with jax.named_scope("mcl.select"):
+        C = C.with_capacity(cap)
+        bound = recovered = jnp.int32(0)
+        if cut:
+            s_th = C.kselect(select)
+            kept = C.prune_column(s_th, keep=_keep_ge).reduce(
+                PLUS_TIMES, "rows")
+            need = kept.blocks < rpct * C.reduce(PLUS_TIMES, "rows").blocks
+            th = jnp.where(
+                need, jnp.minimum(C.kselect(recover).blocks, s_th.blocks),
+                s_th.blocks)
+            bound = jnp.sum(C.nnz_per_column().blocks > select)
+            recovered = jnp.sum(need)
+            C = C.prune_column(
+                dataclasses.replace(s_th, blocks=th), keep=_keep_ge)
+        C = make_col_stochastic(C)
+    with jax.named_scope("mcl.chaos"):
+        ch = chaos(C)
+    with jax.named_scope("mcl.inflate"):
+        A = inflate(C, inflation)
+    counts = jnp.stack([A.getnnz(), bound, recovered]).astype(jnp.int32)
+    return A, ch, counts, _products(A)
+
+
+@partial(jax.jit, static_argnames=("hard",))
+def _mcl_attractors(A: SpParMat, *, hard):
+    """The converged matrix without its residue, symmetrised: what the
+    components are read off."""
+    with jax.named_scope("mcl.interpret"):
+        if hard > 0:
+            A = A.prune(_lt_pred(hard))
+        return A.ewise_add(A.transpose(), PLUS_TIMES)
+
+
+@partial(jax.jit, static_argnames=("n",))
+def _mcl_labels_digest(blocks, *, n: int):
+    """``(clusters, fingerprint)`` of row-aligned labels: a cluster's
+    label is its smallest vertex, so a cluster is a vertex that labels
+    itself; the fingerprint is ``sum_v labels[v] * h(v)`` in wrapping
+    uint32 arithmetic, ``h`` ``spgemm_digest``'s hash."""
+    from ..parallel.spgemm import DIGEST_MULTIPLIER
+
+    with jax.named_scope("mcl.interpret"):
+        labels = blocks.reshape(-1)[:n]
+        v = jnp.arange(n, dtype=jnp.int32)
+        h = (v.astype(jnp.uint32) + jnp.uint32(1)) * jnp.uint32(
+            DIGEST_MULTIPLIER)
+        return (
+            jnp.sum(labels == v, dtype=jnp.int32),
+            jnp.sum(labels.astype(jnp.uint32) * h, dtype=jnp.uint32),
+        )
+
+
+def mcl_job(
+    A: SpParMat,
+    *,
+    inflation: float = 2.0,
+    select: int = 1100,
+    recover: int = 1400,
+    recover_pct: float = 0.9,
+    prune: float = 1e-4,
+    eps: float = 1e-3,
+    max_iters: int = 64,
+    mode: str = "bf16x3",
+    hook=None,
+) -> tuple[DistVec, dict]:
+    """One whole clustering as an analyst's call times it: from the
+    stored ``SpParMat`` to the labels on the device and a digest on the
+    host, nothing known beforehand and nothing kept from job to job.
+    Upstream's order (``MCL.cpp:564-627``): loops added, columns scaled
+    to sum 1; then expand, prune / select / recover
+    (``MCLPruneRecoverySelect``, ParFriends.h:186-350), re-normalise,
+    chaos on the expanded matrix, inflate, until chaos is under ``eps``
+    or ``max_iters``; then the residue under ``prune`` goes and the
+    components of the symmetrised matrix are the clusters.
+
+    An iteration's expansion is chosen from its multiply count by
+    ``choose_tier_from_counts``'s rule evaluated FOR THE CHIP
+    (``JOB_BACKEND``), on every platform, and by nothing else: no
+    argument names a loop, a tier, a phase count or a backend, and no
+    environment variable or file is read inside a job.  Under a dense
+    tier (``DENSE_TIERS``) the state is the dense transposed matrix and
+    an iteration is ONE program that selects before it stores
+    (``_mcl_dense_iter``): the next expansion's count comes out of it,
+    so a dense iteration needs no capacity and one host read.  Under
+    ``scan`` the state is tuples, sized by the symbolic pass inside the
+    job as ``spgemm_job``'s are (powers of two, so iterations share
+    programs); an overflow is an ``AssertionError``, not a retry.  One
+    chip: the 3D expansion of a mesh is ``mcl(layers=...)``'s.
+
+    ``mode`` is the dense product's input pass (``_mxu_dot``): the
+    default ``bf16x3`` carries 2^-16 an operand; the chip's ``f32`` and
+    ``bf16`` are ONE bfloat16 pass (2^-8).
+
+    Returns ``(labels, digest)``.  ``labels`` is a row-aligned int32
+    ``DistVec``, every vertex the smallest vertex id of its cluster.
+    ``digest``, read by the host, closes the job and comes back with
+    telemetry off: ``iters``; ``chaos`` (float32, one an iteration);
+    ``stored`` (the entries after every iteration's select); ``tiers``;
+    ``clusters``; ``fingerprint`` (``sum_v labels[v] * h(v)`` mod 2^32,
+    ``spgemm_digest``'s hash).
+
+    ``hook(it, tier, fetch)``, where given, is called after every
+    iteration; ``fetch()`` reads the column-stochastic matrix after
+    iteration ``it`` back as host ``(rows, cols, vals)``.  A benchmark's
+    checked job uses it; a timed one passes none."""
+    import numpy as np
+
+    from ..parallel.spgemm import (
+        JOB_BACKEND,
+        _pad128,
+        _publish_opnames,
+        choose_tier_from_counts,
+        default_block_rows,
+    )
+    from ..ops.spgemm import combine_hilo
+    from .cc import fastsv
+
+    assert A.grid.size == 1 and A.nrows == A.ncols, (
+        "mcl_job holds the job whole on one chip and clusters a square "
+        "matrix"
+    )
+    n, grid = A.nrows, A.grid
+    npad = _pad128(n)
+    block_rows = default_block_rows(npad, npad)
+    hard = float(prune)
+    sel, rec = min(int(select), n), min(int(recover), n)
+    dense_kw = dict(
+        block_rows=block_rows, hard=hard, select=sel, recover=rec,
+        rpct=float(recover_pct), inflation=float(inflation), mode=mode,
+    )
+    chaos_l, stored_l, tiers = [], [], []
+    totals = dict(products=0.0, candidates=0, bound=0, recovered=0)
+
+    def to_tuples(m, by_block):
+        # a capacity a row block that holds rows of the matrix
+        caps = tuple(_pow2(max(c, 128))
+                     for c in by_block[:-(-n // block_rows)])
+        kw = dict(n=n, block_rows=block_rows, caps=caps)
+        out = _mcl_to_tuples(m, grid, **kw)
+        _publish_opnames(_mcl_to_tuples, m, grid, **kw)
+        return out
+
+    with obs.span("mcl.job", n=n, mode=mode) as job:
+        S, counts = _mcl_start(A)
+        _publish_opnames(_mcl_start, A)
+        products, slots = (float(x) for x in jax.device_get(counts))
+        M, by_block = None, None
+        scans = 0
+        for it in range(1, int(max_iters) + 1):
+            tier = choose_tier_from_counts(
+                PLUS_TIMES, n, n * n, 1, products, JOB_BACKEND,
+                k_dim=n, n_dim=n,
+            )
+            with obs.span("mcl.iter", iter=it, tier=tier) as sp:
+                if tier in DENSE_TIERS:
+                    if M is None:
+                        M = _mcl_densify(S, npad=npad)
+                        _publish_opnames(_mcl_densify, S, npad=npad)
+                        S = None
+                    out = _mcl_dense_iter(M, **dense_kw)
+                    _publish_opnames(_mcl_dense_iter, M, **dense_kw)
+                    M, (ch, c) = out[0], jax.device_get(out[1:])
+                    cand, bound, recovered = (int(x) for x in c[:3])
+                    nxt = float(combine_hilo(c[3:5]))
+                    slots = float(combine_hilo(c[5:7]))
+                    by_block = [int(x) for x in c[7:]]
+                    stored = sum(by_block)
+                else:
+                    if S is None:
+                        S, M = to_tuples(M, by_block), None
+                    kw = dict(
+                        in_cap=min(_pow2(stored_l[-1]), S.capacity)
+                        if stored_l else S.capacity,
+                        flop_cap=_pow2(slots * 1.05 + 1),
+                        out_cap=_pow2(min(products * 1.05 + 1, n * n)),
+                        hard=hard,
+                    )
+                    C, c = _mcl_scan_expand(S, **kw)
+                    _publish_opnames(_mcl_scan_expand, S, nth=scans, **kw)
+                    over, cand, widest = (int(x) for x in jax.device_get(c))
+                    assert over <= 0, (
+                        f"iteration {it}: the scan product overflowed its "
+                        f"symbolic bound by {over}"
+                    )
+                    kw = dict(
+                        cap=min(_pow2(cand), C.capacity), cut=widest > sel,
+                        select=sel, recover=rec, rpct=float(recover_pct),
+                        inflation=float(inflation),
+                    )
+                    out = _mcl_scan_select(C, **kw)
+                    _publish_opnames(_mcl_scan_select, C, nth=scans, **kw)
+                    scans += 1
+                    S, (ch, c, nxt2) = out[0], jax.device_get(out[1:])
+                    stored, bound, recovered = (int(x) for x in c)
+                    nxt, slots = float(nxt2[0]), float(nxt2[1])
+                ch = np.float32(ch)
+                sp.annotate(products=products, stored=stored,
+                            chaos=float(ch))
+            chaos_l.append(ch)
+            stored_l.append(stored)
+            tiers.append(tier)
+            totals["products"] += products
+            totals["candidates"] += cand
+            totals["bound"] += bound
+            totals["recovered"] += recovered
+            products = nxt
+            if hook is not None:
+                state = (M, by_block) if M is not None else S
+
+                def fetch(state=state):
+                    mat = (to_tuples(*state) if isinstance(state, tuple)
+                           else state)
+                    r, c, v = (np.asarray(x)[0, 0]
+                               for x in (mat.rows, mat.cols, mat.vals))
+                    keep = r < n
+                    return r[keep], c[keep], v[keep]
+
+                hook(it, tier, fetch)
+            if ch < eps:
+                break
+        with obs.span("mcl.interpret"):
+            if S is None:
+                S, M = to_tuples(M, by_block), None
+            sym = _mcl_attractors(S, hard=hard)
+            _publish_opnames(_mcl_attractors, S, hard=hard)
+            labels = fastsv(sym)[0]
+            clusters, fp = jax.device_get(
+                _mcl_labels_digest(labels.blocks, n=n))
+            _publish_opnames(_mcl_labels_digest, labels.blocks, n=n)
+        job.annotate(iters=len(tiers))
+    digest = {
+        "iters": len(tiers),
+        "chaos": np.asarray(chaos_l, np.float32),
+        "stored": np.asarray(stored_l, np.int64),
+        "tiers": tuple(tiers),
+        "clusters": int(clusters),
+        "fingerprint": int(fp),
+    }
+    if obs.ENABLED:
+        obs.count("mcl.job.jobs")
+        obs.count("mcl.job.products", totals["products"])
+        obs.count("mcl.job.candidates", totals["candidates"])
+        obs.count("mcl.job.stored", int(sum(stored_l)))
+        obs.count("mcl.job.select_bound_cols", totals["bound"])
+        obs.count("mcl.job.recovered_cols", totals["recovered"])
+        for t in sorted(set(tiers)):
+            obs.count("mcl.job.iters", tiers.count(t), tier=t)
+        # two flop a cell of the padded state's contraction, a pass of
+        # the input mode
+        obs.count(
+            "mcl.job.dense_flops",
+            sum(t in DENSE_TIERS for t in tiers) * 2 * npad ** 3
+            * (3 if mode == "bf16x3" else 1))
+    return labels, digest
 
 
 # --- K-iterations-per-sync block loop (zero D2H inside a block) ------------
@@ -520,12 +977,15 @@ def _mcl_dense_loop(A, inflation, eps, max_iters, prune_kwargs,
     ``lax.while_loop`` on the MXU — zero device→host readbacks, zero
     capacity estimation, overflow structurally impossible.
 
-    Why dense: on the target chip the sparse expansion pays the ~22 M/s
-    per-element random-memory wall several times per iteration (measured
-    48 s/iter at scale 12, overflow-flagged — round-3 notes), while the
-    MXU squares a 16K dense matrix in ~0.7 s (13.3 TFLOP/s bf16,
-    round-4 probes).  Below ~32K vertices the dense formulation wins by >10x
-    AND eliminates the whole frozen-capacity/reroll machinery: pruning is
+    Why dense: the sparse expansion pays per element for every sort,
+    gather and scatter of its expansion (on one v5e a ``scan`` product of
+    1.0e7 multiplies is 3.5 s, 340 ns a multiply; my chip run, PR 44),
+    while the matrix unit squares a 16K dense matrix in 166 ms under
+    ``bf16x3`` (three passes at 157 TFLOP/s each; ``mcl_job``'s dense
+    iteration with its select, same run).  This loop's own iteration at
+    that size is 448 ms (8.06 s for 18, same run: ``lax.top_k`` over n^2
+    every iteration).  It also
+    eliminates the whole frozen-capacity/reroll machinery: pruning is
     a thresholded mask (ties keep, like the reference's kselect), chaos
     rides in the loop carry, and the only readback is the final state.
 

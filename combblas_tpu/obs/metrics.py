@@ -694,6 +694,43 @@ name                                   kind       meaning
                                                   windows`` when every
                                                   window took the flat
                                                   sort)
+``mcl.job.jobs``                       counter    clusterings run as
+                                                  one job (``models/
+                                                  mcl.py:mcl_job``)
+``mcl.job.iters``                      counter    iterations of those
+                                                  jobs, labelled
+                                                  ``tier`` (what
+                                                  ``choose_tier_from_
+                                                  counts`` picked for
+                                                  the chip); the sum
+                                                  over tiers is a
+                                                  job's iterations
+``mcl.job.products``                   counter    scalar multiplies of
+                                                  their expansions (the
+                                                  symbolic counts that
+                                                  routed them)
+``mcl.job.candidates``                 counter    entries of their
+                                                  expansions above the
+                                                  prune limit, before
+                                                  the select
+``mcl.job.stored``                     counter    entries kept after
+                                                  their selects, summed
+                                                  over iterations
+``mcl.job.select_bound_cols``          counter    columns that held
+                                                  more than ``select``
+                                                  candidates (the
+                                                  select cut them)
+``mcl.job.recovered_cols``             counter    columns of those that
+                                                  kept under
+                                                  ``recover_pct`` of
+                                                  their mass and
+                                                  recovered
+``mcl.job.dense_flops``                counter    flop their dense
+                                                  iterations' products
+                                                  issued (two a cell of
+                                                  the padded state's
+                                                  contraction, a pass
+                                                  of the input mode)
 ``obs.provider_errors``                counter    broken pull-provider
                                                   callbacks (caught)
 =====================================  =========  =====================

@@ -1007,3 +1007,73 @@ def dense_support_nnz(dense: Array, zero, nrows: int, ncols: int) -> Array:
     if R != nrows:
         mask = mask & (jnp.arange(R, dtype=jnp.int32)[:, None] < nrows)
     return jnp.sum(mask).astype(jnp.int32)
+
+
+# --- the MCL column select on a dense window (select before it stores) ------
+
+
+def rows_kth_largest(dense: Array, ks: tuple) -> tuple:
+    """Per row of a non-negative float32 ``[R, C]`` window, the
+    ``k``-th largest value for every ``k`` of ``ks`` (0.0 where the row
+    holds fewer than ``k`` positive cells): the thresholds of a top-k
+    prune, EXACT, without a sort.
+
+    A non-negative float's bits order as the float does, so the k-th
+    largest is the largest ``t`` with ``count(row >= t) >= k``, built a
+    bit at a time from the top: 31 passes, each one fused compare and
+    row count over the window for all of ``ks`` at once.  A pass reads
+    the window and writes ``[R]``; nothing per entry (no gather, no
+    scatter, no sorted copy of the window) is on the path, which is what
+    ``SpParMat.kselect``'s radix select pays 32 segment sums for on
+    tuples."""
+    bits = lax.bitcast_convert_type(dense, jnp.int32)
+
+    def one_bit(i, ts):
+        bit = jnp.left_shift(jnp.int32(1), 30 - i)
+        out = []
+        for t, k in zip(ts, ks):
+            cand = t | bit
+            cnt = jnp.sum(bits >= cand[:, None], axis=1, dtype=jnp.int32)
+            out.append(jnp.where(cnt >= k, cand, t))
+        return tuple(out)
+
+    zeros = jnp.zeros((dense.shape[0],), jnp.int32)
+    ts = lax.fori_loop(0, 31, one_bit, tuple(zeros for _ in ks))
+    return tuple(lax.bitcast_convert_type(t, jnp.float32) for t in ts)
+
+
+def mcl_select_rows(
+    c: Array, hard: float, select: int, recover: int, rpct: float
+) -> tuple[Array, Array]:
+    """``MCLPruneRecoverySelect`` (ParFriends.h:186-350) on a dense
+    window whose ROWS are the matrix's columns (the transposed state of
+    ``models/mcl.py``), before anything is stored as tuples:
+
+      1. cells under ``hard`` become 0 (the hard prune);
+      2. a row keeps what is at least its ``select``-th largest value,
+         ties kept (``SpParMat.kselect``'s threshold semantics);
+      3. a row whose kept mass is under ``rpct`` of its mass after (1)
+         keeps what is at least its ``recover``-th largest instead.
+
+    Exactly ``models.mcl.mcl_prune_recovery_select``'s result on the
+    same values; the thresholds come from ``rows_kth_largest`` and are
+    skipped where no row holds more than ``select`` cells (then nothing
+    can be cut).  Returns ``(kept window, int32[3])``: the cells above
+    the prune limit (the select's candidates), the rows ``select`` cuts
+    and the rows that recover."""
+    c = jnp.where(c < hard, 0.0, c)
+    cnt = jnp.sum(c > 0, axis=1, dtype=jnp.int32)
+
+    def cut(c):
+        s_th, r_th = rows_kth_largest(c, (select, recover))
+        kept = jnp.sum(jnp.where(c >= s_th[:, None], c, 0.0), axis=1)
+        need = kept < rpct * jnp.sum(c, axis=1)
+        th = jnp.where(need, jnp.minimum(r_th, s_th), s_th)
+        return jnp.where(c >= th[:, None], c, 0.0), jnp.sum(
+            need, dtype=jnp.int32)
+
+    c, recovered = lax.cond(
+        jnp.max(cnt) > select, cut, lambda c: (c, jnp.int32(0)), c)
+    counts = jnp.stack([
+        jnp.sum(cnt), jnp.sum(cnt > select, dtype=jnp.int32), recovered])
+    return c, counts

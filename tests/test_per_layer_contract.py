@@ -17,8 +17,8 @@ from chipbench.spec import CHECKOUT, Spec
 
 BENCH = os.path.join(CHECKOUT, "BENCHMARK.json")
 
-#: PR 23's eleven, PR 34's six, PR 37's six and PR 40's seven, each run
-#: in the order its issue gave
+#: PR 23's eleven, PR 34's six, PR 37's six, PR 40's seven and PR 44's
+#: seven, each run in the order its issue gave
 RUNS = [
     ["launch_ms", "readback_ms", "to_global_ms", "readback_mb_per_query",
      "scatter_copied_mb", "batch_gap_ms", "bfs_gather_share",
@@ -29,6 +29,8 @@ RUNS = [
      "tc_hbm_share", "tc_hbm_peak_gb"],
     ["sq_device_ms", "sq_dot_ms", "sq_extract_ms", "sq_host_gap_ms",
      "sq_mnnz_out_per_s", "sq_hbm_share", "sq_hbm_peak_gb"],
+    ["mcl_device_ms", "mcl_expand_ms", "mcl_select_ms", "mcl_host_gap_ms",
+     "mcl_iters", "mcl_hbm_share", "mcl_hbm_peak_gb"],
 ]
 
 
@@ -55,7 +57,7 @@ def test_a_per_layer_entry_follows_the_contract(name):
     assert callable(spec.load_module("layers", name).read)
 
 
-@pytest.mark.parametrize("run", RUNS, ids=["pr23", "pr34", "pr37", "pr40"])
+@pytest.mark.parametrize("run", RUNS, ids=["pr23", "pr34", "pr37", "pr40", "pr44"])
 def test_appended_entries_keep_their_issues_order(run):
     names = _names()
     assert [n for n in names if n in run] == run

@@ -814,3 +814,52 @@ def test_a_product_window_is_sorted_a_row_group_at_a_time(product_row_block):
         rf"= s32\[{rb * bc + L}\]\S* dynamic-update-slice\(", text)) == 2
     mem = compiled.memory_analysis()
     assert mem.temp_size_in_bytes < 1.25 * 2**30
+
+
+def test_a_clustering_job_s_dense_iteration_fits_the_chip_at_the_cell_s_size(
+        topo):
+    """PR 44: ``_mcl_dense_iter`` compiled for the described v5e at the
+    size ``hipmcl-fam.mcl-batch`` runs it (n = 2^14, four row blocks of
+    4,096, the published select 1100 / recover 1400, ``bf16x3``): the
+    state in and out is n^2 float32 each, the program's temporaries stay
+    under 2.5 GB (a row block's unpruned product, the operand's two
+    bfloat16 halves), a row block's product is three bf16 dots on the
+    matrix unit whose halves are cut by a reduce-precision, nothing in the
+    program sorts (the select is a bisection
+    of the values' bits, 31 trips of one loop a row block that selects),
+    and the device trace's scopes are on its instructions."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from combblas_tpu.models import mcl as M
+    from combblas_tpu.obs import opnames
+    from combblas_tpu.parallel import spgemm as S
+
+    n = 1 << 14
+    rb = S.default_block_rows(n, n)
+    assert rb == 4096
+    m = jax.ShapeDtypeStruct(
+        (n, n), jnp.float32, sharding=SingleDeviceSharding(topo.devices[0]))
+    compiled = M._mcl_dense_iter.lower(
+        m, block_rows=rb, hard=1e-4, select=1100, recover=1400, rpct=0.9,
+        inflation=2.0, mode="bf16x3").compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes == 4 * n * n
+    assert 0 <= mem.output_size_in_bytes - 4 * n * n < 4096
+    assert mem.temp_size_in_bytes < 2.5e9
+    text = compiled.as_text()
+    assert text.startswith("HloModule jit__mcl_dense_iter")
+    seen = set(opnames.parse(text)[1].values())
+    for scope in ("mcl.expand", "mcl.select", "mcl.chaos", "mcl.inflate",
+                  "mcl.symbolic"):
+        assert any(f"/{scope}/" in nm for nm in seen), scope
+    dots = re.findall(
+        r"= f32\[4096,16384\]\S* (?:convolution|dot)\(.*op_name=\"[^\"]*"
+        r"mcl\.expand/", text)
+    assert len(dots) == 3 * (n // rb), len(dots)
+    # the split's hi half is a reduce-precision, which the compiler
+    # may not keep in float32 as it keeps a cast's round trip
+    assert re.findall(r" reduce-precision\(.*exponent_bits=8, "
+                      r"mantissa_bits=7", text)
+    assert not re.findall(r" sort\(", text)
