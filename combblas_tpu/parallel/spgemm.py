@@ -1836,12 +1836,26 @@ def summa_spgemm_mxu(
 MXU_MAX_TILE_DIM = 8192
 
 
-#: Windowed-tier envelope. The tier scans every dense cell of each
-#: non-skipped row block once during extraction, so it loses to the
-#: ESC/scan sort once the output is EXTREMELY sparse relative to the
-#: dense tile: the gate requires at most this many scanned cells per
-#: symbolic flop (R-MAT A-squared at scale 16 sits near 11).
-WINDOWED_MAX_CELLS_PER_FLOP = 16.0
+#: Windowed-tier envelope. A dense product costs by the CELL whatever
+#: its count (the matrix unit's passes, and the extraction's one scan of
+#: every launched window) and the ESC/scan sort by the MULTIPLY, so the
+#: dense tier loses once the output is sparse enough: the gate requires
+#: at most this many cells per symbolic flop.  Where the two cross on
+#: one TPU v5 lite at n = 2^14, 2^28 cells (my chip runs, PR 46,
+#: 2026-10-01, host clock, warm, every reading three times within
+#: 0.3%).  A clustering iteration (``mcl_job``, ``scripts/mcl_loops.py
+#: --cells-per-flop 16 64 256 512``): dense 171 ms whatever its count
+#: (three bf16 passes, no extraction); ``scan`` 47 ms at 1.9e5
+#: multiplies (1,377 cells each), 160 at 9.1e5 (293), 3,689 at 1.03e7
+#: (26): they cross near 270 cells a multiply, and a job is 5.64 s with
+#: the line at 16, 2.415 at 64 and at 256, 2.420 at 512.  A whole
+#: product (``spgemm_job`` under ``bf16``, ``scripts/tier_line.py``):
+#: ``windowed`` 547 / 444 / 462 ms against ``scan`` 1,455 / 692 / 433
+#: at 9.7e6 / 2.2e6 / 1.0e6 multiplies (28 / 120 / 258 cells each):
+#: they cross near 240.  One power of two serves both callers; the
+#: ``scatter`` backend shares the line and has no chip reading of its
+#: own.
+WINDOWED_MAX_CELLS_PER_FLOP = 256.0
 #: Per-device dense-tile ceiling for the windowed tier (cells, not
 #: bytes): one row-block accumulator plus the extraction pass must stay
 #: cheap; 2^33 cells ≈ scale-17 square tiles on one device.

@@ -15,13 +15,17 @@ and is killed at ``--limit`` seconds: the line then says what it had
 finished.  ``job`` (``mcl_job``) also times every iteration of its warm
 job.  ``mcl(layers=...)``'s 3D loop needs a mesh and is not here.  One
 JSON line a loop on stdout and in ``chiprun_out/mcl_loops.jsonl``.
-``--cells-per-flop 300 --loops job`` runs ``mcl_job`` with the rule's
-line moved for that run alone (ROADMAP S10).
+``--loops job --cells-per-flop 16 64 256 512`` is the ladder that set
+``parallel/spgemm.py:WINDOWED_MAX_CELLS_PER_FLOP`` (PR 46): ``mcl_job``
+with the rule's line moved for that child alone, one line a value with
+the job's ``tiers``, ``iter_s`` and ``seconds``; 0 keeps the library's
+value and prints it.
 """
 
 from __future__ import annotations
 
 import argparse
+import itertools
 import json
 import os
 import re
@@ -55,15 +59,16 @@ def child(args) -> int:
 
     if jax.default_backend() == "tpu":
         compile_cache.enable_compile_cache()
-    if args.cells_per_flop:
-        from combblas_tpu.parallel import spgemm
+    from combblas_tpu.parallel import spgemm
 
-        spgemm.WINDOWED_MAX_CELLS_PER_FLOP = args.cells_per_flop
+    if args.cells_per_flop[0]:
+        spgemm.WINDOWED_MAX_CELLS_PER_FLOP = args.cells_per_flop[0]
 
     def say(**kw):
-        if args.cells_per_flop:
-            kw["cells_per_flop"] = args.cells_per_flop
-        print(json.dumps(dict(loop=args.child, **kw)), flush=True)
+        print(json.dumps(dict(
+            loop=args.child,
+            cells_per_flop=spgemm.WINDOWED_MAX_CELLS_PER_FLOP, **kw)),
+            flush=True)
 
     n, rows, cols, vals, _ = famgraph.family_graph(
         args.scale, args.graph_seed, degree=args.degree, smax=args.smax)
@@ -83,7 +88,8 @@ def child(args) -> int:
             max_iters=64, **kw)
         return labels, it, ch, None
 
-    for stage in ("cold", "warm"):
+    # the job's second warm run says how far one reading can be trusted
+    for stage in ("cold", "warm") + ("warm",) * (kw is None):
         marks.clear()
         hook = None
         if kw is None and stage == "warm":
@@ -118,20 +124,21 @@ def main() -> int:
     ap.add_argument("--select", type=int, default=1100)
     ap.add_argument("--recover", type=int, default=1400)
     ap.add_argument("--limit", type=float, default=360.0)
-    ap.add_argument("--cells-per-flop", type=float, default=0.0,
-                    help="the rule's line (WINDOWED_MAX_CELLS_PER_FLOP) "
-                    "for this run alone: where mcl_job leaves the dense "
-                    "tier (ROADMAP S10); 0 keeps the library's")
+    ap.add_argument("--cells-per-flop", type=float, nargs="+", default=[0.0],
+                    help="the rule's line (WINDOWED_MAX_CELLS_PER_FLOP), "
+                    "every loop once a value, for that child alone: where "
+                    "mcl_job leaves the dense tier; 0 keeps the library's")
     ap.add_argument("--loops", nargs="*", default=list(LOOPS))
     ap.add_argument("--child", default=None, help=argparse.SUPPRESS)
     args = ap.parse_args()
     if args.child:
         return child(args)
     os.makedirs(os.path.dirname(OUT), exist_ok=True)
-    for loop in args.loops:
-        cmd = [sys.executable, os.path.abspath(__file__), "--child", loop] + [
+    for line, loop in itertools.product(args.cells_per_flop, args.loops):
+        cmd = [sys.executable, os.path.abspath(__file__), "--child", loop,
+               "--cells-per-flop", str(line)] + [
             a for k in ("scale", "graph_seed", "degree", "smax", "select",
-                        "recover", "cells_per_flop")
+                        "recover")
             for a in (f"--{k.replace('_', '-')}", str(getattr(args, k)))]
         t0 = time.perf_counter()
         try:
@@ -153,7 +160,7 @@ def main() -> int:
             how = f"killed at {args.limit:g} s"
         lines = [ln for ln in out.splitlines() if ln.startswith("{")]
         lines.append(json.dumps(dict(
-            loop=loop, stage="end", how=how,
+            loop=loop, cells_per_flop=line, stage="end", how=how,
             wall=round(time.perf_counter() - t0, 1))))
         with open(OUT, "a") as f:
             for ln in lines:

@@ -66,8 +66,12 @@ def s9():
 def small_dense_envelope(monkeypatch):
     """At a test's size every product is inside the whole-tile ``mxu``
     rung; with the rung cut to 128 the rule reads the multiply count, as
-    it does at the cell's n = 16,384."""
+    it does at the cell's n = 16,384.  A converged job's product is n
+    multiplies, n cells each, so under the chip's line a job of a few
+    hundred vertices leaves the dense tier late or never: at 16 cells a
+    multiply it crosses mid-job, as the cell's does."""
     monkeypatch.setattr(S, "MXU_MAX_TILE_DIM", 128)
+    monkeypatch.setattr(S, "WINDOWED_MAX_CELLS_PER_FLOP", 16.0)
 
 
 class _Compiles:
@@ -80,6 +84,14 @@ class _Compiles:
     def _on(self, event, duration, **kw):
         if event == "/jax/core/compile/backend_compile_duration":
             self.count += 1
+
+
+def _crossing(tiers) -> int:
+    """How many iterations ran dense: the rule leaves the dense tier
+    once and for good."""
+    k = sum(t == "windowed" for t in tiers)
+    assert tiers == ("windowed",) * k + ("scan",) * (len(tiers) - k)
+    return k
 
 
 def _held(n, ref, labels, digest, states=None):
@@ -178,10 +190,9 @@ def test_one_job_runs_a_dense_and_a_scan_iteration(
     labels, digest = M.mcl_job(A, hook=hook, **KW)
     tiers = digest["tiers"]
     assert set(tiers) == {"windowed", "scan"}
-    # the rule leaves the dense tier once and for good: 16 cells a
-    # multiply (``WINDOWED_MAX_CELLS_PER_FLOP``)
-    k = tiers.index("scan")
-    assert set(tiers[:k]) == {"windowed"} and set(tiers[k:]) == {"scan"}
+    # the rule leaves the dense tier once and for good, at the line
+    # (``WINDOWED_MAX_CELLS_PER_FLOP``)
+    k = _crossing(tiers)
     line = n * n / S.WINDOWED_MAX_CELLS_PER_FLOP
     assert ref["counts"][k - 1]["products"] >= line > ref["counts"][k][
         "products"]
@@ -201,6 +212,31 @@ def test_one_job_runs_a_dense_and_a_scan_iteration(
     # and the same job under the whole-tile rung is the same clustering
     # (the state after iteration k is the same matrix, whichever tier
     # hands it over)
+
+
+@pytest.mark.parametrize("line", [1.0, 16.0, None, 4096.0], ids=[
+    "1", "16", "shipped", "4096"])
+def test_the_line_moves_the_crossing_and_not_the_clustering(
+        s9, monkeypatch, line):
+    """Wherever the line stands (``None``: where the library has it), a
+    job is the reference's clustering; the line decides only how many
+    iterations run dense."""
+    n, A, ref = s9
+    monkeypatch.setattr(S, "MXU_MAX_TILE_DIM", 128)
+    if line is not None:
+        monkeypatch.setattr(S, "WINDOWED_MAX_CELLS_PER_FLOP", line)
+    labels, digest = M.mcl_job(A, **KW)
+    tiers = digest["tiers"]
+    k = _crossing(tiers)
+    counts = [c["products"] for c in ref["counts"][:len(tiers)]]
+    at = n * n / S.WINDOWED_MAX_CELLS_PER_FLOP
+    assert k == sum(p >= at for p in counts)
+    assert digest["iters"] == ref["iters"]
+    assert digest["clusters"] == len(np.unique(ref["labels"]))
+    assert digest["fingerprint"] == mclref.fingerprint(ref["labels"])
+    np.testing.assert_allclose(
+        digest["stored"], ref["stored"][:len(tiers)],
+        rtol=_limits()["stored_rel"])
 
 
 def test_the_tiers_agree_on_every_iteration(s9, monkeypatch):
@@ -303,9 +339,13 @@ def test_the_rule_for_the_chip_crosses_the_ladder_at_the_cell_s_size():
     tiers = [S.choose_tier_from_counts(
         PLUS_TIMES, n, n * n, 1, p, S.JOB_BACKEND, k_dim=n, n_dim=n)
         for p in products]
-    k = tiers.index("scan")
-    assert k >= 3 and set(tiers[:k]) == {"windowed"}
-    assert set(tiers[k:]) == {"scan"} and len(tiers) - k >= 3
+    # nine dense products, then nine sorts (PR 46: the line re-derived
+    # on the chip), and no count within a tenth of the line, where a
+    # rounding of the program's count against the reference's could
+    # flip a tier
+    assert len(products) == 18 and _crossing(tuple(tiers)) == 9
+    line = n * n / S.WINDOWED_MAX_CELLS_PER_FLOP
+    assert all(abs(p / line - 1) > 0.1 for p in products)
 
 
 def test_the_split_product_s_halves_add_up_to_the_operand(rng):

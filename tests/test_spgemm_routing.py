@@ -50,11 +50,18 @@ SRS = {
 
 # --- the rule as a table ------------------------------------------------------
 
+#: the rule's line, cells a multiply: a power of two, so that a count AT
+#: it is a whole number
+LINE = int(S.WINDOWED_MAX_CELLS_PER_FLOP)
+#: the multiplies that put 2^30 cells at the line
+AT = float((1 << 30) // LINE)
+
 #: (semiring, max tile dim, tile cells, grid rows, multiplies, backend,
 #: k_dim, n_dim, allow_mxu) -> tier.  The limits the rows stand on both
 #: sides of: the whole-tile rung's 8,192; the windowed tier's 2^33 cells
-#: a tile and 16 cells a multiply; a dot panel of 2^27 cells (padded k x
-#: a window of 512 columns, or of n / 32 where that is wider).
+#: a tile and ``LINE`` cells a multiply; a dot panel of 2^27 cells
+#: (padded k x a window of 512 columns, or of n / 32 where that is
+#: wider).
 RULE = [
     # the whole-tile rung, on both sides of 8,192
     ("plus_times", 8192, 8192 * 8192, 1, 1e9, "dot", 8192, 8192, True, "mxu"),
@@ -72,7 +79,7 @@ RULE = [
      "windowed"),
     ("plus_times", 8192, 8192 * 8192, 1, 1e9, "scatter", 8192, 8192, False,
      "windowed"),
-    ("plus_times", 64, 64 * 64, 1, 10.0, "dot", 64, 64, False, "scan"),
+    ("plus_times", 64, 64 * 64, 1, 2.0, "dot", 64, 64, False, "scan"),
     # 2^33 cells a tile: at it and one past it
     ("plus_times", 131072, 1 << 33, 1, 2.0 ** 29, "dot", 16384, 65536, True,
      "windowed"),
@@ -82,23 +89,31 @@ RULE = [
      True, "scan"),
     ("plus_times", 131072, (1 << 33) + 1, 1, 2.0 ** 30, "scatter", 16384,
      65536, True, "scan"),
-    # 16 cells a multiply: at it and one multiply short of it
-    ("plus_times", 32768, 1 << 30, 1, 2.0 ** 26, "dot", 32768, 32768, True,
+    # the line: at it and one multiply short of it
+    ("plus_times", 32768, 1 << 30, 1, AT, "dot", 32768, 32768, True,
      "windowed"),
-    ("plus_times", 32768, 1 << 30, 1, 2.0 ** 26 - 1, "dot", 32768, 32768,
-     True, "scan"),
-    ("plus_times", 32768, 1 << 30, 1, 2.0 ** 26, "scatter", 32768, 32768,
-     True, "windowed"),
-    ("plus_times", 32768, 1 << 30, 1, 2.0 ** 26 - 1, "scatter", 32768, 32768,
-     True, "scan"),
+    ("plus_times", 32768, 1 << 30, 1, AT - 1, "dot", 32768, 32768, True,
+     "scan"),
+    ("plus_times", 32768, 1 << 30, 1, AT, "scatter", 32768, 32768, True,
+     "windowed"),
+    ("plus_times", 32768, 1 << 30, 1, AT - 1, "scatter", 32768, 32768, True,
+     "scan"),
     # ... counted over the whole grid: a tile's cells times pr^2
-    ("plus_times", 16384, 1 << 28, 2, 2.0 ** 26, "scatter", 16384, 16384,
-     True, "windowed"),
-    ("plus_times", 16384, 1 << 28, 4, 2.0 ** 26, "scatter", 16384, 16384,
-     True, "scan"),
+    ("plus_times", 16384, 1 << 28, 2, AT, "scatter", 16384, 16384, True,
+     "windowed"),
+    ("plus_times", 16384, 1 << 28, 4, AT, "scatter", 16384, 16384, True,
+     "scan"),
     # no multiplies at all count as one
-    ("plus_times", 16, 16, 1, 0.0, "scatter", 16, 16, False, "windowed"),
-    ("plus_times", 17, 17, 1, 0.0, "scatter", 17, 17, False, "scan"),
+    ("plus_times", LINE, LINE, 1, 0.0, "scatter", LINE, LINE, False,
+     "windowed"),
+    ("plus_times", LINE + 1, LINE + 1, 1, 0.0, "scatter", LINE + 1, LINE + 1,
+     False, "scan"),
+    # the clustering cell's neighbours of the line at n = 2^14: its ninth
+    # iteration's multiplies (26 cells each) and its tenth's (293)
+    ("plus_times", 16384, 1 << 28, 1, 10341856.0, "dot", 16384, 16384, True,
+     "windowed"),
+    ("plus_times", 16384, 1 << 28, 1, 914625.0, "dot", 16384, 16384, True,
+     "scan"),
     # a dot panel: 2^18 padded rows under a 512-wide window, and past it
     ("plus_times", 262144, 1 << 30, 1, 2.0 ** 28, "dot", 262144, None, True,
      "windowed"),
@@ -121,8 +136,8 @@ RULE = [
      "windowed"),
     ("select2nd_max", 131072, (1 << 33) + 1, 1, 2.0 ** 30, "scatter", 16384,
      65536, True, "scan"),
-    ("select2nd_max", 32768, 1 << 30, 1, 2.0 ** 26 - 1, "scatter", 32768,
-     32768, True, "scan"),
+    ("select2nd_max", 32768, 1 << 30, 1, AT - 1, "scatter", 32768, 32768,
+     True, "scan"),
     # neither: the sorts
     ("generic", 4096, 4096 * 4096, 1, 1e8, "dot", 4096, 4096, True, "scan"),
     ("generic", 4096, 4096 * 4096, 1, 1e8, "scatter", 4096, 4096, True,
