@@ -20,6 +20,7 @@ from ..ops.spgemm import (
     combine_hilo,
     coo_sort_dedup as _coo_sort_dedup,
     front_pack_pairs,
+    harvest_path,
     pack_support_bits,
     popcount_pair_counts,
 )
@@ -75,25 +76,33 @@ EDGE_HARVEST_BITS_MAX_DIM = 262144
 # shared dedup front of every bit-packed kernel, imported above.
 
 #: The ``jax.named_scope`` names of the bit-packed harvest program
-#: (``tc_edgeharvest_bits``), outermost first; ``gather`` and
-#: ``popcount`` are set by ``ops/spgemm.py:popcount_pair_counts`` inside
-#: a step of the scan, so they read ``tc.harvest/gather`` and
-#: ``tc.harvest/popcount``.  The scan walks the kept pairs, chunk-padded
-#: (``front_pack_pairs`` brings them to the front under ``tc.dedup``),
-#: one step a chunk.  Trace-time metadata only: the device
-#: trace's per-scope times are read by these names
+#: (``tc_edgeharvest_bits``), outermost first.  The scan walks the kept
+#: pairs, chunk-padded (``front_pack_pairs`` brings them to the front
+#: under ``tc.dedup``), one step a chunk.  ``gather`` and ``popcount``
+#: are set by ``ops/spgemm.py:popcount_pair_counts`` inside a step of
+#: its ``jnp`` loop, where they read ``tc.harvest/gather`` and
+#: ``tc.harvest/popcount``; the fused step (``harvest_path``: a TPU and
+#: whole-tile rows) is one kernel, ``pair_popcount``, straight under
+#: ``tc.harvest``, and carries neither.  Trace-time metadata only: the
+#: device trace's per-scope times are read by these names
 #: (docs/observability.md "Named scopes"), so a rename is a change of
 #: yardstick.
 TC_SCOPES = (
     "tc.dedup",  # the sorts of every stored slot, the repeat mask, kept first
     "tc.pack",  # zero fill + scatter-add of one bit a kept nonzero
     "tc.harvest",  # the whole scan over chunks of the kept row pairs
-    "gather",  # a step's two row gathers of [chunk, n/32] words
-    "popcount",  # a step's AND, population count and weighted sum
+    "gather",  # jnp step: two row gathers of [chunk, n/32] words
+    "popcount",  # jnp step: the AND, population count and weighted sum
 )
 
 
-def _tc_edge_harvest_bits(rows, cols, n: int, chunk: int = 8192):
+#: Pairs a step of the one-device scan walks; the pair list is padded to
+#: it, so ``pairs`` is a multiple (what a step FETCHES at once is the
+#: kernel's own, ``ops/spgemm.py:HARVEST_GROUP``).
+HARVEST_CHUNK = 8192
+
+
+def _tc_edge_harvest_bits(rows, cols, n: int, chunk: int = HARVEST_CHUNK):
     """Bit-packed edge-harvest TC: the adjacency as a [n, n/32] uint32
     bitmask; each edge's common-neighbor count is popcount(row_i & row_j).
 
@@ -110,7 +119,10 @@ def _tc_edge_harvest_bits(rows, cols, n: int, chunk: int = 8192):
     The scan walks the pairs it counts: the kept slots (strict lower
     triangle, first of a run of repeats) are brought to the front of
     the pair list in their row-sorted order, and the scan runs the
-    ``ceil(edges / chunk)`` steps that hold one.
+    ``ceil(edges / chunk)`` steps that hold one.  The table's shape and
+    the step follow ``ops/spgemm.py:harvest_path``: on a TPU with n a
+    multiple of 32,768 a row is whole tiles and one kernel fetches and
+    counts a pair's rows; elsewhere plain rows and the ``jnp`` step.
 
     Returns ``(hilo, pairs, edges)``: the (hi, lo) int32 split of 3·T
     (``combine_hilo`` // 3 gives T; 3·T can exceed 2^31 — same split
@@ -129,7 +141,9 @@ def _tc_edge_harvest_bits(rows, cols, n: int, chunk: int = 8192):
         er, ec, ew, edges = front_pack_pairs(keep, rows, cols, chunk=chunk)
     with jax.named_scope("tc.pack"):
         r_all = jnp.where(loops | dup, n, rows)  # dropped (mode="drop")
-        bits = pack_support_bits(r_all, cols, n, n, assume_unique=True)
+        bits = pack_support_bits(
+            r_all, cols, n, n, assume_unique=True,
+            row_tiles=harvest_path(-(-n // 32)) == "fused")
     with jax.named_scope("tc.harvest"):
         hilo = popcount_pair_counts(
             bits, bits, er, ec, ew, chunk=chunk, count=edges)
@@ -250,7 +264,7 @@ def tc_job(A: SpParMat) -> tuple[int, int, int]:
     Returns ``(triangles, pairs, edges)``, Python ints, all three from
     the program's own outputs (so they come back with telemetry off):
     the exact count, the pair slots the harvest walked (the kept pairs,
-    chunk-padded: two row gathers each) and the pairs of weight 1 (the
+    chunk-padded: two rows fetched each) and the pairs of weight 1 (the
     undirected edges).
 
     Eager wrapper: the readback of the three closes the job."""
@@ -277,6 +291,8 @@ def tc_job(A: SpParMat) -> tuple[int, int, int]:
         obs.count("models.tc.pairs", pairs)
         obs.count("models.tc.edges", edges)
         obs.count("models.tc.triangles", triangles)
+        obs.count("models.tc.harvest_steps", pairs // HARVEST_CHUNK,
+                  path=harvest_path(-(-A.nrows // 32)))
     return triangles, pairs, edges
 
 

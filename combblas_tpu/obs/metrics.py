@@ -649,6 +649,18 @@ name                                   kind       meaning
                                                   program's own count)
 ``models.tc.triangles``                counter    triangles those jobs
                                                   counted
+``models.tc.harvest_steps``            counter    steps of their scans
+                                                  (pairs / 8,192),
+                                                  labelled ``path`` =
+                                                  ``fused`` (one kernel
+                                                  fetches and counts a
+                                                  pair's rows: a TPU,
+                                                  whole-tile rows) or
+                                                  ``jnp`` (two row
+                                                  gathers, then the
+                                                  count): ``ops/
+                                                  spgemm.py:
+                                                  harvest_path``
 ``spgemm.job.jobs``                    counter    products run as one
                                                   job (``parallel/
                                                   spgemm.py:

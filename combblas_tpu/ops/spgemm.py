@@ -797,6 +797,20 @@ def coo_sort_dedup(rows: Array, cols: Array) -> tuple[Array, Array, Array]:
     return rows, cols, dup
 
 
+#: Lanes of an (8, 128) tile (``pallas_kernels.LANES``, not imported
+#: here: Pallas is imported by the call that runs a kernel, as
+#: parallel/spgemm.py does), and the words of ONE whole tile: a packed
+#: row of a multiple of ``TILE_WORDS`` words (n a multiple of 32,768)
+#: can be held as whole tiles, ``[n, nw / 128, 128]`` with nothing
+#: padded.  A row of fewer (128 words at n = 4,096) would be one
+#: sublane of a tile: the chip pads the table to 8 rows of tiles and
+#: COPIES it into that layout, which a table of most of the chip's
+#: memory cannot afford (the described-v5e compile at n = 36,864 shows
+#: the copy; PERF.md section 6, PR 47).
+LANES = 128
+TILE_WORDS = 8 * LANES
+
+
 def pack_support_bits(
     rows: Array,
     cols: Array,
@@ -804,6 +818,7 @@ def pack_support_bits(
     ncols: int,
     *,
     assume_unique: bool = False,
+    row_tiles: bool = False,
 ) -> Array:
     """COO support → packed [nrows, ceil(ncols/32)] uint32 bitmask.
 
@@ -817,6 +832,13 @@ def pack_support_bits(
     This is the storage format of the output-support oracle: 32x less
     memory and gather traffic than a bool matrix, and intersection
     queries are ``popcount(a & b)`` (see ``popcount_pair_counts``).
+
+    ``row_tiles`` (the word axis must divide by ``TILE_WORDS``) returns
+    the same words as ``[nrows, nw / 128, 128]``: a row is then whole
+    (8, 128) tiles, 32 KB in one piece at nw = 8192, which is what the
+    fused harvest's row copies need.  The scatter writes those bytes
+    itself: the table cannot be COPIED into another layout where it is
+    most of the chip's memory (8.59 GB at n = 2^18).
     """
     nw = -(-ncols // 32)
     if not assume_unique:
@@ -824,10 +846,21 @@ def pack_support_bits(
         rows = jnp.where(dup, nrows, rows)
     oob = (rows >= nrows) | (cols >= ncols)
     r = jnp.where(oob, nrows, rows)
+    word = cols >> 5
+    bit = jnp.uint32(1) << (cols.astype(jnp.uint32) & 31)
+    if row_tiles:
+        assert nw % TILE_WORDS == 0, (ncols, nw)
+        # scattered as [nrows * tiles, 128] under two indices, which is
+        # [nrows, tiles, 128] byte for byte under the chip's (8, 128)
+        # tiling (the reshape is free); three indices cost the scatter
+        # 42 ms more at n = 2^18 (HARVEST_GROUP)
+        tiles = nw // LANES
+        bits = jnp.zeros((nrows * tiles, LANES), jnp.uint32)
+        tile = word >> (LANES.bit_length() - 1)
+        return bits.at[r * tiles + tile, word & (LANES - 1)].add(
+            bit, mode="drop").reshape(nrows, tiles, LANES)
     bits = jnp.zeros((nrows, nw), jnp.uint32)
-    return bits.at[r, cols >> 5].add(
-        jnp.uint32(1) << (cols.astype(jnp.uint32) & 31), mode="drop"
-    )
+    return bits.at[r, word].add(bit, mode="drop")
 
 
 def front_pack_pairs(
@@ -862,6 +895,45 @@ def front_pack_pairs(
     return jnp.pad(ii, pad), jnp.pad(jj, pad), weights, jnp.sum(weights)
 
 
+#: Pairs whose rows the fused harvest fetches at once (a group: its 2 x
+#: ``HARVEST_GROUP`` row copies fly while the group before is counted;
+#: 2 MB of on-chip buffers at 32 KB a row).  ``chiprun -- python
+#: scripts/tc_harvest_ladder.py`` at the cell's shapes (n = 2^18,
+#: 3,809,280 pairs, 249.6 GB of rows; my chip runs, PR 47, one v5e, best
+#: of three, three runs within 0.1 ms of each other): the fused kernel
+#: 360.64 / 350.58 / 350.66 / 351.11 ms a harvest at 8 / 16 / 32 / 64
+#: pairs a group (712 GB/s, 87% of 819: from 16 up the copies' bandwidth
+#: bounds it, not their issue rate; 16 is the least code to trace, lower
+#: and compile at every boot: the kernel's copies are unrolled); the
+#: ``jnp`` loop 1,217.5 ms at a step of 8,192 pairs (the loop before
+#: PR 47) and 1,025.9 / 855.9 / 702.7 / 779.4 at 2,048 / 1,024 / 512 /
+#: 256, where the compiler keeps both gathered blocks in its fast
+#: memory.  The pack (zero fill + scatter-add) 669.9 ms into ``[n, nw]``,
+#: 685.9 into ``[n * nw / 128, 128]`` (the whole-tile table, two
+#: indices), 728.2 into ``[n, nw / 128, 128]`` under three.  Re-run
+#: before moving the constant.
+HARVEST_GROUP = 16
+
+
+def _kernel_mode() -> str | None:
+    """How this process runs a Pallas TPU kernel: ``"compiled"`` on a
+    TPU, not at all (None) elsewhere.  Read from the backend, set by
+    nobody: the tests alone put ``"interpret"`` (a CPU) or
+    ``"compiled"`` (a described chip) here."""
+    return "compiled" if jax.default_backend() == "tpu" else None
+
+
+def harvest_path(nw: int) -> str:
+    """Which loop ``popcount_pair_counts`` runs over tables of ``nw``
+    words a row: ``"fused"`` (``pallas_kernels.pair_popcount_partials``:
+    a pair's rows are fetched and counted in one kernel) where the
+    backend is a TPU and a row is whole tiles (``nw % TILE_WORDS ==
+    0``, n a multiple of 32,768), ``"jnp"`` (two row gathers, then the
+    count) otherwise.  The caller packs its tables to match
+    (``pack_support_bits(row_tiles=...)``)."""
+    return "fused" if nw % TILE_WORDS == 0 and _kernel_mode() else "jnp"
+
+
 def popcount_pair_counts(
     bits_i: Array,
     bits_j: Array,
@@ -880,11 +952,21 @@ def popcount_pair_counts(
     each (i, j) pair's count is the exact C[i,j] = Σ_k A[i,k]·B[k,j]
     restricted to the pair list (the output-support mask).  A loop walks
     ``chunk``-sized pair blocks, each a slice of the list at the loop's
-    counter; per step two row gathers of the packed tables + a streaming
-    popcount — the bit-packed edge-harvest inner loop (models/tc.py)
+    counter — the bit-packed edge-harvest inner loop (models/tc.py)
     generalized to two distinct bit tables, which is what the
     DISTRIBUTED tier needs (row-block and col-block masks live on
     different devices).
+
+    What a step does with its block is read from the tables
+    (``harvest_path``).  Tables of whole-tile rows
+    (``pack_support_bits(row_tiles=True)``: ``[n, nw / 128, 128]``)
+    where a Pallas TPU kernel runs take the FUSED step: one kernel
+    fetches a pair's two rows into on-chip memory, ``HARVEST_GROUP``
+    pairs at a time, and counts them there, so a row crosses HBM once
+    and a step writes 512 B a pair.  Any other tables take the ``jnp``
+    step: two row gathers of ``[chunk, nw]`` words, written to HBM and
+    read back by a streaming popcount (three crossings; PERF.md section
+    6, PR 47).  Same pairs, same count.
 
     ``ii``/``jj``/``weights`` must be padded to a multiple of ``chunk``
     with weight-0 slots (indices clamped in-range by the caller).
@@ -899,6 +981,7 @@ def popcount_pair_counts(
         steps = npairs // chunk
     else:
         steps = -(-jnp.minimum(count, npairs) // chunk)
+    fused = bits_i.ndim == bits_j.ndim == 3 and _kernel_mode() is not None
 
     def body(k, carry):
         hi, lo = carry
@@ -906,13 +989,28 @@ def popcount_pair_counts(
         def cut(a):  # step k's chunk of the pair list: a slice, no gather
             return lax.dynamic_slice(a, (k * chunk,), (chunk,))
 
-        # scopes (metadata only; models/tc.py:TC_SCOPES names them)
-        with jax.named_scope("gather"):
-            gi = bits_i[cut(ii)]  # [chunk, nw] u32
-            gj = bits_j[cut(jj)]
-        with jax.named_scope("popcount"):
-            pc = lax.population_count(gi & gj)
-            cnt = jnp.sum(pc.astype(jnp.int32), axis=1) * cut(weights)
+        if fused:
+            from .pallas_kernels import pair_popcount_partials
+
+            # a row copy has no bounds check: clamp as a gather would
+            part = pair_popcount_partials(
+                bits_i, bits_j,
+                jnp.clip(cut(ii), 0, bits_i.shape[0] - 1),
+                jnp.clip(cut(jj), 0, bits_j.shape[0] - 1),
+                group=min(HARVEST_GROUP, chunk),
+                interpret=_kernel_mode() == "interpret",
+            )
+            cnt = jnp.sum(part, axis=1) * cut(weights)
+        else:
+            # scopes (metadata only; models/tc.py:TC_SCOPES names them)
+            with jax.named_scope("gather"):
+                gi = bits_i[cut(ii)]  # [chunk, nw] u32
+                gj = bits_j[cut(jj)]
+            with jax.named_scope("popcount"):
+                pc = lax.population_count(gi & gj)
+                cnt = jnp.sum(
+                    pc.astype(jnp.int32), axis=tuple(range(1, pc.ndim))
+                ) * cut(weights)
         # renormalize the split each step: an unbounded lo accumulation
         # would itself wrap past 2^31 (models/tc.py rationale)
         lo = lo + jnp.sum(cnt & 0x7FFF)

@@ -243,7 +243,10 @@ def test_counters_add_once_a_job_and_telemetry_off_returns_the_same(case):
     triangles, pairs, edges = off
     assert counted == {
         "models.tc.jobs": 3, "models.tc.pairs": 3 * pairs,
-        "models.tc.edges": 3 * edges, "models.tc.triangles": 3 * triangles}
+        "models.tc.edges": 3 * edges, "models.tc.triangles": 3 * triangles,
+        # the steps of the scan, under the loop that ran them (a CPU
+        # runs the jnp loop: tests/test_tc_fused_harvest.py)
+        "models.tc.harvest_steps": 3 * pairs // CHUNK}
     # the first traced job published the program's op names, once
     names = set(tables["jit_tc_edgeharvest_bits"].values())
     assert any("/tc.harvest/" in nm and nm.endswith("/gather/gather")

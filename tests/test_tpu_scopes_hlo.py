@@ -610,28 +610,43 @@ def test_bc_scopes_name_both_loops_of_the_one_chip_program(
     assert " conditional(" not in compiled()
 
 
-def test_the_triangle_count_job_fits_the_chip_at_the_cell_s_size(topo):
+def test_the_triangle_count_job_fits_the_chip_at_the_cell_s_size(
+        topo, monkeypatch):
     """``jit_tc_edgeharvest_bits`` at the size ``g500-s18tc.tc-batch``
     runs it (n = 2^18, the configuration's 7,611,536 stored nonzeros)
-    for the described v5e: the compiler takes it with ``chunk=8192``;
-    the ``uint32[n, n/32]`` table (8.59 GB) exists ONCE (the scatter-add
-    lands in the fresh ``zeros``: a copy would be 17.2 GB and not fit a
-    16 GB chip), so the program's temporaries stay under the table plus
-    2 GB; the scan's two row gathers are ``u32[8192, 8192]`` blocks
-    under ``tc.harvest/.../gather``; and every one of ``TC_SCOPES`` is
-    on some instruction (``chipbench/tcscopes.py`` reads the device
-    trace by them)."""
+    for the described v5e, on the path a TPU takes (this process's
+    backend is a CPU: the test says ``compiled`` where the program reads
+    its backend).  The table is ``uint32[n, 64, 128]``, a row eight
+    whole tiles, WRITTEN in those bytes by the scatter-add and existing
+    ONCE (8.59 GB: a copy, or a change of layout, would be 17.2 GB and
+    not fit a 16 GB chip), so the program's temporaries stay under the
+    table plus 2 GB; a step of the scan is ONE Mosaic kernel under
+    ``tc.harvest`` that reads the table where it lies and writes
+    ``s32[8192, 128]`` partial sums, and no ``u32[8192, ...]`` block of
+    gathered rows is among the program's values (PERF.md section 6,
+    PR 47: two of 268 MB each were written and read back a step); the
+    three outer scopes are on some instruction (``chipbench/tcscopes.py``
+    reads the device trace by them)."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import SingleDeviceSharding
 
     from combblas_tpu.models import tc
     from combblas_tpu.obs import opnames
+    from combblas_tpu.ops import spgemm as ops
 
+    monkeypatch.setattr(ops, "_kernel_mode", lambda: "compiled")
+    jax.clear_caches()
     n, stored = 1 << 18, 7_611_536
+    assert ops.harvest_path(n // 32) == "fused"
     one_chip = SingleDeviceSharding(topo.devices[0])
     tile = jax.ShapeDtypeStruct((1, 1, stored), jnp.int32, sharding=one_chip)
-    compiled = tc.tc_edgeharvest_bits.lower(tile, tile, n=n).compile()
+    try:
+        compiled = tc.tc_edgeharvest_bits.lower(tile, tile, n=n).compile()
+        hilo, pairs, edges = jax.eval_shape(
+            tc.tc_edgeharvest_bits, tile, tile, n=n)
+    finally:
+        jax.clear_caches()
     table = n * n // 8
     mem = compiled.memory_analysis()
     assert table < mem.temp_size_in_bytes < table + 2 * 2**30
@@ -640,26 +655,36 @@ def test_the_triangle_count_job_fits_the_chip_at_the_cell_s_size(topo):
     assert text.startswith("HloModule jit_tc_edgeharvest_bits")
     names = opnames.parse(text)[1]
     seen = set(names.values())
-    for scope in tc.TC_SCOPES:
+    for scope in tc.TC_SCOPES[:3]:
         assert any(f"/{scope}/" in nm for nm in seen), scope
     loops = [nm for i, nm in names.items() if i.startswith("while")]
     assert any(nm.endswith("tc.harvest/while") for nm in loops), loops
-    gathers = re.findall(
-        r"= u32\[8192,8192\]\S* gather\(.*op_name=\"[^\"]*"
-        r"tc\.harvest/[^\"]*/gather/gather\"", text)
-    assert len(gathers) == 2
+    kernels = re.findall(
+        r"= s32\[8192,128\]\S* custom-call\(.*"
+        r"custom_call_target=\"tpu_custom_call\".*"
+        r"op_name=\"[^\"]*tc\.harvest/while/body/pair_popcount/pallas_call\"",
+        text)
+    assert len(kernels) == 1
+    # its tables are the scatter's own output, twice: no copy between
+    assert re.search(
+        r"custom-call\(\S+, \S+, (\S+), \1\), "
+        r"custom_call_target=\"tpu_custom_call\"", kernels[0])
+    assert not re.search(r"= u32\[8192,", text)
+    assert not re.search(r"u32\[262144,8192\]", text)
     # a step takes its chunk of the pair list by slice at the loop's
     # counter: no element gather of 8,192 indices from the list is left
     # (PERF.md section 6, PR 39), and what the front-packing sort adds
     # fits with the rest under the table + 2 GB (asserted above)
     assert re.search(
-        r"= s32\[8192\]\S* dynamic-slice\(.*op_name=\"[^\"]*tc\.harvest/", text)
+        r"= s32\[8192\]\S* (dynamic-slice|fusion)\(.*"
+        r"op_name=\"[^\"]*tc\.harvest/", text)
     assert not re.search(
         r"= s32\[8192\]\S* gather\(.*op_name=\"[^\"]*tc\.harvest/", text)
+    # the scatter-add writes the table as [n * 64, 128] under two
+    # indices: the same bytes, so the kernel's [n, 64, 128] is a bitcast
     assert re.search(
-        r"= u32\[262144,8192\]\S* scatter\(.*op_name=\"[^\"]*tc\.pack/", text)
-    hilo, pairs, edges = jax.eval_shape(
-        tc.tc_edgeharvest_bits, tile, tile, n=n)
+        r"= u32\[16777216,128\]\S* scatter\(.*op_name=\"[^\"]*tc\.pack/",
+        text)
     assert (hilo.shape, hilo.dtype) == ((2,), jnp.int32)
     assert pairs.shape == edges.shape == () and pairs.dtype == jnp.int32
 
