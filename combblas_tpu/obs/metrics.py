@@ -706,6 +706,32 @@ name                                   kind       meaning
                                                   windows`` when every
                                                   window took the flat
                                                   sort)
+``spgemm.job.stages``                  counter    SUMMA stages of those
+                                                  jobs (the grid's
+                                                  ``pr``; 1 a job on one
+                                                  tile)
+``spgemm.job.exchange_bytes``          counter    operand bytes ONE chip
+                                                  received in their
+                                                  numeric phases' stage
+                                                  exchanges (``pr`` - 1
+                                                  tiles of each operand
+                                                  gathered, ``pr`` under
+                                                  the carousel, at the
+                                                  tiles' capacity; 0 on
+                                                  one tile)
+``spgemm.job.tile_nnz_max``            counter    stored entries of the
+                                                  fullest tile of their
+                                                  results (over
+                                                  ``spgemm.job.jobs``:
+                                                  one job's)
+``spgemm.job.tile_nnz_min``            counter    ... of the emptiest
+``spgemm.job.pack_capacity``           counter    the one capacity their
+                                                  results' tiles were
+                                                  cut under
+                                                  (``_packed``: the
+                                                  fullest tile's count
+                                                  on a mesh, the stored
+                                                  entries on one tile)
 ``mcl.job.jobs``                       counter    clusterings run as
                                                   one job (``models/
                                                   mcl.py:mcl_job``)

@@ -93,7 +93,11 @@ def _gather_stage_tiles(t: SpTuples, axis_name, p: int) -> list[SpTuples]:
     The fused-collective replacement for the reference's per-stage
     ``SpParHelper::BCastMatrix`` loop.
     """
-    g = [lax.all_gather(x, axis_name) for x in (t.rows, t.cols, t.vals, t.nnz)]
+    with jax.named_scope("sq.exchange"):
+        g = [
+            lax.all_gather(x, axis_name)
+            for x in (t.rows, t.cols, t.vals, t.nnz)
+        ]
     return [
         SpTuples(
             rows=g[0][s], cols=g[1][s], vals=g[2][s], nnz=g[3][s],
@@ -134,13 +138,14 @@ def _rotate_tiles(t: SpTuples, perm) -> SpTuples:
     (row, col) mesh axis.  Shared by the ESC, scan, and windowed carousel
     paths (this used to be duplicated as a local ``joint_permute`` in
     each ring kernel)."""
-    return SpTuples(
-        rows=lax.ppermute(t.rows, (ROW_AXIS, COL_AXIS), perm),
-        cols=lax.ppermute(t.cols, (ROW_AXIS, COL_AXIS), perm),
-        vals=lax.ppermute(t.vals, (ROW_AXIS, COL_AXIS), perm),
-        nnz=lax.ppermute(t.nnz, (ROW_AXIS, COL_AXIS), perm),
-        nrows=t.nrows, ncols=t.ncols,
-    )
+    with jax.named_scope("sq.exchange"):
+        return SpTuples(
+            rows=lax.ppermute(t.rows, (ROW_AXIS, COL_AXIS), perm),
+            cols=lax.ppermute(t.cols, (ROW_AXIS, COL_AXIS), perm),
+            vals=lax.ppermute(t.vals, (ROW_AXIS, COL_AXIS), perm),
+            nnz=lax.ppermute(t.nnz, (ROW_AXIS, COL_AXIS), perm),
+            nrows=t.nrows, ncols=t.ncols,
+        )
 
 
 def _chain_tiles(t: SpTuples, dep) -> SpTuples:
@@ -849,6 +854,51 @@ def _windowed_stage_b_side(sr, b_stage, backend, two_d, pk, pcols,
     return _colmajor_with_starts(b_stage, block_cols)
 
 
+def _dot_block_windows(
+    sr: Semiring, a_stages, b_sides, lo, *, rb, hs, out_caps_row,
+    block_cols, pk, pwin, panel_cap, mode, interpret, lrA, lcB, zero,
+):
+    """One ROW BLOCK of the 2D ``dot`` tier over gathered stage tiles:
+    every stage's dense product added into each live col window's
+    accumulator, each window extracted once.  Returns (the windows'
+    chunks in ``hs`` order, worst overflow)."""
+    from ..ops.spgemm import densify_combine, mask_rows
+
+    kind = _PALLAS_KINDS.get(sr.name)
+    arows = _pad128(rb)
+    accs = {}
+    for a_stage, (bs_sorted, b_starts) in zip(a_stages, b_sides):
+        with jax.named_scope("sq.densify"):
+            am = mask_rows(a_stage, lo, lo + rb)
+            da = densify_combine(
+                sr, _shift_rowblock(am, lo, arows), arows, pk
+            )
+        for h in hs:
+            with jax.named_scope("sq.densify"):
+                panel = _dense_col_panel(
+                    sr, bs_sorted, b_starts, h, block_cols, pk, pwin,
+                    panel_cap,
+                )
+            with jax.named_scope("sq.dot"):
+                prod = _window_stage_product(
+                    sr, kind, da, panel, mode, interpret
+                )
+                # the first stage's product IS the accumulator: no
+                # window-sized fill and add of the semiring's zero
+                accs[h] = prod if h not in accs else sr.add(accs[h], prod)
+    chunks = []
+    worst = jnp.int32(0)
+    for h in hs:
+        with jax.named_scope("sq.extract"):
+            chunk, over = _extract_window_2d(
+                accs[h], zero, lo, h, rb, block_cols, lrA, lcB,
+                out_caps_row[h],
+            )
+        worst = jnp.maximum(worst, over)
+        chunks.append(chunk)
+    return chunks, worst
+
+
 def _windowed_gathered_compute(
     sr: Semiring, a_stages, b_stages, *, lrA, lrB, lcB, block_rows,
     flop_caps, out_caps, skip, backend, mode, chunk_w, interpret,
@@ -880,32 +930,14 @@ def _windowed_gathered_compute(
         for g, hs in _live_windows_by_block(skip):
             lo = g * block_rows
             rb = min(block_rows, lrA - lo)
-            arows = _pad128(rb)
-            accs = {h: jnp.full((arows, pwin), zero, dtype) for h in hs}
-            for s in range(p):
-                am = mask_rows(a_stages[s], lo, lo + rb)
-                da = densify_combine(
-                    sr, _shift_rowblock(am, lo, arows), arows, pk
-                )
-                bs_sorted, b_starts = b_sides[s]
-                for h in hs:
-                    panel = _dense_col_panel(
-                        sr, bs_sorted, b_starts, h, block_cols, pk,
-                        pwin, panel_cap,
-                    )
-                    accs[h] = sr.add(
-                        accs[h],
-                        _window_stage_product(
-                            sr, kind, da, panel, mode, interpret
-                        ),
-                    )
-            for h in hs:
-                chunk, over = _extract_window_2d(
-                    accs[h], zero, lo, h, rb, block_cols, lrA, lcB,
-                    out_caps[g][h],
-                )
-                worst = jnp.maximum(worst, over)
-                chunks.append(chunk)
+            chunks_g, over = _dot_block_windows(
+                sr, a_stages, b_sides, lo, rb=rb, hs=hs,
+                out_caps_row=out_caps[g], block_cols=block_cols, pk=pk,
+                pwin=pwin, panel_cap=panel_cap, mode=mode,
+                interpret=interpret, lrA=lrA, lcB=lcB, zero=zero,
+            )
+            worst = jnp.maximum(worst, over)
+            chunks += chunks_g
         return chunks, worst
     for g in packed_windows(skip):
         lo = g * block_rows
@@ -1007,21 +1039,24 @@ def _windowed_carousel_compute(
             bs_sorted, b_starts = b_side
             for g, hs in live:
                 lo, rb, arows = block_geom(g)
-                am = mask_rows(a_cur, lo, lo + rb)
-                da = densify_combine(
-                    sr, _shift_rowblock(am, lo, arows), arows, pk
-                )
+                with jax.named_scope("sq.densify"):
+                    am = mask_rows(a_cur, lo, lo + rb)
+                    da = densify_combine(
+                        sr, _shift_rowblock(am, lo, arows), arows, pk
+                    )
                 for h in hs:
-                    panel = _dense_col_panel(
-                        sr, bs_sorted, b_starts, h, block_cols, pk,
-                        pwin, panel_cap,
-                    )
-                    accs[(g, h)] = sr.add(
-                        accs[(g, h)],
-                        _window_stage_product(
-                            sr, kind, da, panel, mode, interpret
-                        ),
-                    )
+                    with jax.named_scope("sq.densify"):
+                        panel = _dense_col_panel(
+                            sr, bs_sorted, b_starts, h, block_cols, pk,
+                            pwin, panel_cap,
+                        )
+                    with jax.named_scope("sq.dot"):
+                        accs[(g, h)] = sr.add(
+                            accs[(g, h)],
+                            _window_stage_product(
+                                sr, kind, da, panel, mode, interpret
+                            ),
+                        )
         else:
             for g in live:
                 lo, rb, arows = block_geom(g)
@@ -1062,10 +1097,11 @@ def _windowed_carousel_compute(
         for g, hs in live:
             lo, rb, _ = block_geom(g)
             for h in hs:
-                chunk, over = _extract_window_2d(
-                    accs[(g, h)], zero, lo, h, rb, block_cols, lrA,
-                    lcB, out_caps[g][h],
-                )
+                with jax.named_scope("sq.extract"):
+                    chunk, over = _extract_window_2d(
+                        accs[(g, h)], zero, lo, h, rb, block_cols, lrA,
+                        lcB, out_caps[g][h],
+                    )
                 worst = jnp.maximum(worst, over)
                 chunks.append(chunk)
         return chunks, worst
@@ -2591,13 +2627,15 @@ def run_windowed(
                 mode=mode, interpret=interpret,
             )
         else:
-            C, overflow = summa_spgemm_windowed(
-                sr, A, B, block_rows=block_rows, flop_caps=flop_caps,
+            kw = dict(
+                block_rows=block_rows, flop_caps=flop_caps,
                 out_caps=out_caps, skip=skip, backend="dot", mode=mode,
                 chunk_w=chunk_w, interpret=interpret,
                 block_cols=block_cols, panel_cap=plan.panel_cap,
                 ring=ring, pipeline=pipeline,
             )
+            C, overflow = summa_spgemm_windowed(sr, A, B, **kw)
+            _publish_opnames(summa_spgemm_windowed, sr, A, B, **kw)
         over = int(overflow)
         assert over <= 0, (
             f"windowed tier overflowed its symbolic bound by {over}"
@@ -2713,7 +2751,10 @@ def spgemm_windowed(
     ``ring`` carousel schedules); ``"blocked"`` forces per-block
     programs.  Single-device products already run per-block programs
     (``local_spgemm_windowed``); the dot backend's multi-device path
-    has no blocked kernel yet and stays fused.
+    is the fused kernel whatever ``dispatch`` says: at the mesh cell's
+    size (a ``[16384, 16384]`` tile, four row blocks by two windows by
+    two stages) the v5e compiler takes it in a minute and a job peaks
+    at 4.4 GB a chip (PR 48), so it has needed no blocked form.
 
     ``oracle=True`` (dot, single device, inside the support-oracle
     envelope) replaces the clamped-flops out caps with the EXACT
@@ -3101,6 +3142,13 @@ SQ_SCOPES = (
     "sq.digest",
 )
 
+#: What the job's mesh path adds to ``SQ_SCOPES``: ``sq.exchange`` the
+#: stage exchange of operand tiles (``_gather_stage_tiles``'s
+#: ``all_gather``, the carousel's ``ppermute``) and ``sq.pack`` the cut
+#: of every tile to what it stores (``_tile_chunk_counts``,
+#: ``_pack_tiles``).  A one-tile job carries neither.
+SQ_MESH_SCOPES = ("sq.exchange", "sq.pack")
+
 #: The tiers a job can name, and the accumulate backend a job runs under
 #: when none is given: the chip's (``resolve_spgemm_backend``'s platform
 #: default on a TPU), on every platform, so a CPU rehearsal runs the
@@ -3188,22 +3236,26 @@ def spgemm_digest(C: SpParMat):
     )(C.rows, C.cols, C.vals)
 
 
+def _prefix_counts(rows, lr: int, caps: tuple):
+    """Stored entries (``rows < lr``) of each chunk of one tile's slots,
+    and whether every chunk holds them as a prefix."""
+    counts, ok, off = [], jnp.bool_(True), 0
+    for cap in caps:
+        valid = rows[off:off + cap] < lr
+        n = jnp.sum(valid, dtype=jnp.int32)
+        counts.append(n)
+        ok &= ~jnp.any(valid & (jnp.arange(cap, dtype=jnp.int32) >= n))
+        off += cap
+    return jnp.stack(counts), ok
+
+
 @partial(jax.jit, static_argnames=("caps",))
 def _chunk_counts(C: SpParMat, caps: tuple):
     """Stored entries of each chunk of a one-tile result, and whether
     every chunk holds them as a prefix (``caps``: the chunks' static
     capacities, in the order the tier laid them)."""
-    rows, lr = C.rows[0, 0], C.local_rows
-    counts, ok, off = [], jnp.bool_(True), 0
     with jax.named_scope("sq.extract"):
-        for cap in caps:
-            valid = rows[off:off + cap] < lr
-            n = jnp.sum(valid, dtype=jnp.int32)
-            counts.append(n)
-            ok &= ~jnp.any(
-                valid & (jnp.arange(cap, dtype=jnp.int32) >= n))
-            off += cap
-    return jnp.stack(counts), ok
+        return _prefix_counts(C.rows[0, 0], C.local_rows, caps)
 
 
 @partial(jax.jit, static_argnames=("caps", "counts"))
@@ -3222,13 +3274,79 @@ def _pack_chunks(C: SpParMat, caps: tuple, counts: tuple) -> SpParMat:
     return dataclasses.replace(C, rows=rows, cols=cols, vals=vals)
 
 
+@partial(jax.jit, static_argnames=("caps",))
+def _tile_chunk_counts(C: SpParMat, caps: tuple):
+    """``_chunk_counts`` of every tile of a result on a mesh:
+    ``int32[pr, pc, len(caps)]`` (each tile's own, left where the tile
+    is), the tiles' totals ``int32[pr, pc]`` (replicated: the host reads
+    them) and whether every chunk of every tile holds its entries as a
+    prefix."""
+    lr = C.local_rows
+
+    def body(r):
+        with jax.named_scope("sq.pack"):
+            counts, ok = _prefix_counts(r[0, 0], lr, caps)
+            ok = lax.pmin(ok.astype(jnp.int32), (ROW_AXIS, COL_AXIS))
+            totals = lax.all_gather(
+                lax.all_gather(jnp.sum(counts), COL_AXIS), ROW_AXIS)
+        return counts[None, None], totals, ok
+
+    return jax.shard_map(
+        body, mesh=C.grid.mesh, in_specs=(TILE_SPEC,),
+        out_specs=(TILE_SPEC, P(), P()), check_vma=False,
+    )(C.rows)
+
+
+@partial(jax.jit, static_argnames=("caps", "capacity"))
+def _pack_tiles(C: SpParMat, counts, caps: tuple, capacity: int) -> SpParMat:
+    """Every tile of a result on a mesh with the padding between its
+    chunks taken out, all under ONE static ``capacity`` (a program is
+    one shape on every device): the chunks, whole, laid one after the
+    other at the running sum of the tile's own ``counts``, so each
+    covers the padding of the one before; a ``capacity`` below a tile's
+    entries would drop some (``_packed`` passes the fullest tile's)."""
+    lr, lc = C.local_rows, C.local_cols
+    offs = np.concatenate([[0], np.cumsum(caps)[:-1]])
+    room = capacity + max(caps)
+
+    def body(r, c, v, cnt):
+        cnt = cnt[0, 0]
+        starts = jnp.cumsum(cnt) - cnt
+        with jax.named_scope("sq.pack"):
+            out = []
+            for x, fill in ((r[0, 0], lr), (c[0, 0], lc), (v[0, 0], 0)):
+                buf = jnp.full((room,), fill, x.dtype)
+                for k, (o, cap) in enumerate(zip(offs, caps)):
+                    buf = lax.dynamic_update_slice(
+                        buf, x[o:o + cap], (starts[k],))
+                out.append(buf[:capacity][None, None])
+        return (*out, jnp.sum(cnt)[None, None])
+
+    rows, cols, vals, nnz = jax.shard_map(
+        body, mesh=C.grid.mesh, in_specs=(TILE_SPEC,) * 4,
+        out_specs=(TILE_SPEC,) * 4, check_vma=False,
+    )(C.rows, C.cols, C.vals, counts)
+    return dataclasses.replace(C, rows=rows, cols=cols, vals=vals, nnz=nnz)
+
+
 def _packed(C: SpParMat, caps: tuple) -> SpParMat:
-    """A tier's capacity-padded one-tile result cut to what it stores.
+    """A tier's capacity-padded result cut to what it stores.
     Every tier sizes its output by a symbolic UPPER bound (the windowed
     tier a window at a time: on a squared R-MAT the bounds add up to the
     dense matrix), and whatever reads the result next pays for its
     capacity, not for its entries.  One small readback (the chunks'
-    counts), then slices at what the host now knows."""
+    counts), then on one tile slices at what the host now knows; on a
+    mesh every tile is cut under the FULLEST tile's count
+    (``_pack_tiles``), which the same operands give again."""
+    if C.grid.size > 1:
+        counts, totals, ok = _tile_chunk_counts(C, caps)
+        totals, ok = host_value(totals), host_value(ok)
+        _publish_opnames(_tile_chunk_counts, C, caps)
+        assert ok, "a tier's chunk does not hold its entries as a prefix"
+        capacity = max(int(totals.max()), 1)
+        out = _pack_tiles(C, counts, caps, capacity)
+        _publish_opnames(_pack_tiles, C, counts, caps, capacity)
+        return out
     counts, ok = jax.device_get(_chunk_counts(C, caps))
     _publish_opnames(_chunk_counts, C, caps)
     assert ok, "a tier's chunk does not hold its entries as a prefix"
@@ -3263,11 +3381,14 @@ def spgemm_job(
     ``AssertionError``, not a doubling).  The same operands give the
     same static shapes, so a repeated job compiles nothing.
 
-    Returns ``(C, digest)``.  C stays on the device; on one device it
-    is cut to what it stores (``_packed``: the tiers return tiles padded
-    to their symbolic bounds, the windowed tier's add up to the dense
-    matrix on a squared R-MAT, and the digest, like any next step, pays
-    for capacity).  ``digest`` is
+    Returns ``(C, digest)``.  C stays on the device, 2D-distributed as
+    the operands are, and is cut to what it stores (``_packed``: the
+    tiers return tiles padded to their symbolic bounds, the windowed
+    tier's add up to the dense matrix on a squared R-MAT, and the
+    digest, like any next step, pays for capacity); on a mesh every tile
+    is cut under one capacity, the fullest tile's count, and the
+    windowed tier runs ``run_windowed``'s own schedule, the gathered
+    one (the chip ran the carousel no faster: ROADMAP D4).  ``digest`` is
     ``spgemm_digest(C)`` read by the host, which closes the job:
     ``nnz`` and ``sum`` (Python ints), ``counts`` / ``sums`` /
     ``prints`` (``int32[nrows]`` numpy), with the ``tier`` and
@@ -3317,9 +3438,10 @@ def spgemm_job(
                         (_pad128(rb(g)), _pad128(plan.block_cols))
                         for g, _ in packed_windows_2d(plan.skip)]
                     # two flop a cell of every launched window's padded
-                    # row block x contraction x col window
-                    dense_flops = 2 * _pad128(B.local_rows) * sum(
-                        r * c for r, c in shapes)
+                    # row block x contraction x col window, a stage (a
+                    # tile's own count: what ONE chip issues)
+                    dense_flops = 2 * A.grid.pr * _pad128(
+                        B.local_rows) * sum(r * c for r, c in shapes)
                 else:
                     pcols = _windowed_dims(
                         backend, None, B.local_rows, B.local_cols)[1]
@@ -3365,7 +3487,7 @@ def spgemm_job(
                     f"{tier} tier overflowed its symbolic bound by {over}"
                 )
                 caps = (C.capacity,)
-            if A.grid.size == 1 and sum(caps) == C.capacity:
+            if sum(caps) == C.capacity:
                 C = _packed(C, caps)
         with obs.span("digest"):
             nnz, hilo, counts, sums, prints = jax.device_get(
@@ -3385,4 +3507,19 @@ def spgemm_job(
         obs.count("spgemm.job.windows_skipped", skipped, **labels)
         obs.count("spgemm.job.dense_flops", dense_flops, **labels)
         obs.count("spgemm.job.extract_groups", extract_groups, **labels)
+        p = A.grid.pr
+        obs.count("spgemm.job.stages", p, **labels)
+        # the numeric phase's stage exchange, as the fullest tile pays:
+        # p - 1 tiles of each operand gathered
+        obs.count(
+            "spgemm.job.exchange_bytes",
+            (p - 1) * sum(
+                4 + M.capacity * (8 + M.vals.dtype.itemsize)
+                for M in (A, B)),
+            **labels)
+        tile_nnz = np.concatenate([
+            np.asarray(t.data).ravel() for t in C.nnz.addressable_shards])
+        obs.count("spgemm.job.tile_nnz_max", int(tile_nnz.max()), **labels)
+        obs.count("spgemm.job.tile_nnz_min", int(tile_nnz.min()), **labels)
+        obs.count("spgemm.job.pack_capacity", C.capacity, **labels)
     return C, digest

@@ -34,12 +34,16 @@ def _upload(n, rows, cols, vals=None, grid=None):
 
 
 def _held(ref, C, digest):
-    """Both of the cell's checks on one job; and a one-tile result is
-    cut to what it stores, whatever upper bound its tier sized it by."""
+    """Both of the cell's checks on one job; and a result is cut to
+    what it stores, whatever upper bound its tier sized it by: one tile
+    to its entries, the tiles of a mesh to the fullest tile's."""
     assert ref.check_digest(digest) is None
     assert ref.check_entries(*C.to_global_coo()) is None
-    if C.grid.size == 1:
-        assert C.capacity == digest["nnz"] == int(C.nnz[0, 0])
+    tiles = np.asarray(C.nnz)
+    assert C.capacity == tiles.max() and tiles.sum() == digest["nnz"]
+    # every tile holds its entries as a prefix, the rest padding
+    stored = (np.asarray(C.rows) < C.local_rows).sum(axis=-1)
+    assert np.array_equal(stored, tiles)
 
 
 @pytest.fixture(scope="module")
@@ -49,15 +53,24 @@ def s8():
         n, rows, cols)
 
 
-@pytest.mark.parametrize("scale,blocks", [
-    (8, {}), (8, dict(block_rows=64, block_cols=128)),
-    (9, dict(block_rows=128, block_cols=512)),
-    (10, dict(block_rows=512, block_cols=512)),
+MESH = (2, 2)
+
+
+@pytest.mark.parametrize("scale,blocks,grid", [
+    (8, {}, (1, 1)), (8, dict(block_rows=64, block_cols=128), (1, 1)),
+    (9, dict(block_rows=128, block_cols=512), (1, 1)),
+    (10, dict(block_rows=512, block_cols=512), (1, 1)),
+    # the mesh cell's shape in small: several row blocks and windows a
+    # tile, two stages into every window's accumulator
+    (8, dict(block_rows=32, block_cols=64), MESH),
+    (9, dict(block_rows=64, block_cols=128), MESH),
+    (9, {}, MESH),
 ])
-def test_the_chip_s_tier_equals_the_reference_entry_for_entry(scale, blocks):
+def test_the_chip_s_tier_equals_the_reference_entry_for_entry(
+        scale, blocks, grid):
     n, rows, cols = _graph(scale)
     ref = sqref.SQReference(n, rows, cols)
-    A = _upload(n, rows, cols)
+    A = _upload(n, rows, cols, grid=Grid.make(*grid))
     C, digest = S.spgemm_job(PLUS_TIMES, A, A, **CHIP, **blocks)
     assert (digest["tier"], digest["backend"]) == ("windowed", "dot")
     _held(ref, C, digest)
@@ -101,6 +114,134 @@ def test_a_job_on_a_mesh_and_of_two_operands(s8):
     d = sqref.digest_of(want)
     assert all(np.array_equal(digest[k], d[k]) for k in (
         "nnz", "sum", "counts", "sums", "prints"))
+
+
+@pytest.fixture(scope="module")
+def s9_mesh():
+    """The mesh cell's job in small: scale 9 on 2 x 2, [256, 256] tiles
+    of four row blocks by two windows, under the chip's arguments."""
+    n, rows, cols = _graph(9)
+    A = _upload(n, rows, cols, grid=Grid.make(*MESH))
+    blocks = dict(block_rows=64, block_cols=128)
+    C, digest = S.spgemm_job(PLUS_TIMES, A, A, **CHIP, **blocks)
+    return n, rows, cols, A, blocks, C, digest
+
+
+def test_a_mesh_job_s_digest_is_the_one_tile_job_s(s9_mesh):
+    n, rows, cols, _, blocks, _, digest = s9_mesh
+    one = S.spgemm_job(
+        PLUS_TIMES, _upload(n, rows, cols), _upload(n, rows, cols),
+        **CHIP, **blocks)[1]
+    assert all(np.array_equal(digest[k], one[k]) for k in (
+        "nnz", "sum", "counts", "sums", "prints", "tier", "backend"))
+
+
+def test_a_mesh_job_s_tiles_hold_the_product_once_under_one_capacity(
+        s9_mesh):
+    """Every tile is cut to the FULLEST tile's count (one static shape a
+    program, and not the windows' slots: a tile's eight windows are
+    sized 2^14 cells each here), the tiles' entries add up to nnz(C),
+    and read in GLOBAL coordinates none repeats."""
+    n, rows, cols, A, blocks, C, digest = s9_mesh
+    ref = sqref.SQReference(n, rows, cols)
+    plan = S.plan_windowed(PLUS_TIMES, A, A, backend="dot", **blocks)
+    slots = sum(plan.chunk_caps())
+    assert len(plan.chunk_caps()) == 8
+    tiles = np.asarray(C.nnz)
+    assert tiles.shape == MESH and tiles.sum() == ref.C.nnz == digest["nnz"]
+    assert C.capacity == tiles.max() < slots
+    assert tiles.min() < tiles.max()  # the emptiest tile keeps padding
+    r, c, v = C.to_global_coo()
+    assert len(r) == ref.C.nnz == len(np.unique(r.astype(np.int64) * n + c))
+    # a tile's block of the reference, tile by tile
+    lr = n // 2
+    for i in range(2):
+        for j in range(2):
+            blk = ref.C[i * lr:(i + 1) * lr, j * lr:(j + 1) * lr]
+            assert tiles[i, j] == blk.nnz
+            keep = np.asarray(C.rows)[i, j] < lr
+            assert np.asarray(C.vals)[i, j][keep].sum() == blk.sum()
+
+
+def test_a_mesh_job_of_unequal_tiles_still_packs_and_checks():
+    """An R-MAT WITHOUT the relabelling (vertices in degree order: the
+    first tile holds most of the product): capacities are the heaviest
+    tile's on every device, the pack cuts all four under the fullest
+    tile's count, and both checks hold."""
+    n, rows, cols = _graph(8)
+    rank = np.empty(n, np.int32)
+    rank[np.argsort(-graph.degrees(rows, n), kind="stable")] = np.arange(
+        n, dtype=np.int32)
+    rows, cols = rank[rows], rank[cols]
+    ref = sqref.SQReference(n, rows, cols)
+    A = _upload(n, rows, cols, grid=Grid.make(*MESH))
+    a_tiles = np.asarray(A.nnz)
+    assert a_tiles.max() > 4 * a_tiles.min()
+    C, digest = S.spgemm_job(
+        PLUS_TIMES, A, A, **CHIP, block_rows=32, block_cols=64)
+    _held(ref, C, digest)
+    tiles = np.asarray(C.nnz)
+    assert tiles[0, 0] == tiles.max() == C.capacity > 1.5 * tiles[1, 1]
+
+
+@pytest.mark.parametrize("schedule", [
+    dict(ring=True, pipeline=True), dict(ring=True, pipeline=False),
+], ids=["carousel", "carousel-serial"])
+def test_the_schedule_a_mesh_job_does_not_run_gives_the_same_product(
+        s9_mesh, schedule):
+    """A job runs ``run_windowed``'s own schedule, the gathered one; the
+    carousel, which ``scripts/sq_mesh_ladder.py`` times by the same
+    steps, lays the same chunks: equal tile for tile, slot for slot."""
+    n, rows, cols, A, blocks, C, digest = s9_mesh
+    plan = S.plan_windowed(PLUS_TIMES, A, A, backend=CHIP["backend"], **blocks)
+    C2 = S._packed(
+        S.run_windowed(PLUS_TIMES, A, A, plan, mode=CHIP["mode"], **schedule),
+        plan.chunk_caps())
+    for a, b in ((C.rows, C2.rows), (C.cols, C2.cols), (C.vals, C2.vals),
+                 (C.nnz, C2.nnz)):
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+
+
+def test_a_mesh_job_counts_its_stages_and_names_its_exchange_and_pack(
+        s9_mesh):
+    n, rows, cols, A, blocks, C, digest = s9_mesh
+    obs.reset()
+    obs.enable(install_hooks=False)
+    try:
+        C2, d2 = S.spgemm_job(PLUS_TIMES, A, A, **CHIP, **blocks)
+        counters = {
+            r["name"]: r["value"] for r in obs.registry.snapshot()
+            if r["kind"] == "counter" and r["labels"] == {
+                "tier": "windowed", "backend": "dot"}}
+        tables = obs.opnames.tables()
+    finally:
+        obs.disable()
+        obs.reset()
+    assert d2["nnz"] == digest["nnz"]
+    tiles = np.asarray(C.nnz)
+    assert counters["spgemm.job.jobs"] == 1
+    assert counters["spgemm.job.stages"] == 2
+    assert counters["spgemm.job.windows"] == 8
+    # one chip's stage products: two stages of eight windows, a
+    # [64, 256] x [256, 128] product padded to 512 on every side
+    assert counters["spgemm.job.dense_flops"] == 2 * 2 * 8 * 512 ** 3
+    # a tile of each operand from the one other device of its row / column
+    assert counters["spgemm.job.exchange_bytes"] == 2 * (
+        4 + 12 * A.capacity)
+    assert counters["spgemm.job.tile_nnz_max"] == tiles.max()
+    assert counters["spgemm.job.tile_nnz_min"] == tiles.min()
+    assert counters["spgemm.job.pack_capacity"] == C.capacity == tiles.max()
+    found = {c for t in tables.values() for op in t.values()
+             for c in op.split("/") if c.startswith("sq.")}
+    assert found == set(S.SQ_SCOPES) | set(S.SQ_MESH_SCOPES)
+    assert {"jit_summa_spgemm_windowed", "jit__tile_chunk_counts",
+            "jit__pack_tiles", "jit_spgemm_digest"} <= set(tables)
+    by_module = {
+        mod: {c for op in t.values() for c in op.split("/")
+              if c.startswith("sq.")} for mod, t in tables.items()}
+    assert by_module["jit_summa_spgemm_windowed"] == {
+        "sq.exchange", "sq.densify", "sq.dot", "sq.extract"}
+    assert by_module["jit__pack_tiles"] == {"sq.pack"}
 
 
 def _digest_of(n, r, c, v):
@@ -196,16 +337,16 @@ class _Compiles:
         self.count += event == self.EVENT
 
 
-def test_a_second_job_compiles_nothing():
+@pytest.mark.parametrize("grid", [(1, 1), MESH])
+def test_a_second_job_compiles_nothing(grid):
     n, rows, cols = _graph(8, seed=3)
-    A = _upload(n, rows, cols)
+    A = _upload(n, rows, cols, grid=Grid.make(*grid))
+    blocks = dict(block_rows=64 // grid[0], block_cols=128 // grid[1])
     watch = _Compiles()
-    first = S.spgemm_job(
-        PLUS_TIMES, A, A, **CHIP, block_rows=64, block_cols=128)[1]
+    first = S.spgemm_job(PLUS_TIMES, A, A, **CHIP, **blocks)[1]
     assert watch.count > 0
     before = watch.count
-    again = S.spgemm_job(
-        PLUS_TIMES, A, A, **CHIP, block_rows=64, block_cols=128)[1]
+    again = S.spgemm_job(PLUS_TIMES, A, A, **CHIP, **blocks)[1]
     assert watch.count == before
     assert again["nnz"] == first["nnz"] and np.array_equal(
         again["prints"], first["prints"])

@@ -888,3 +888,64 @@ def test_a_clustering_job_s_dense_iteration_fits_the_chip_at_the_cell_s_size(
     assert re.findall(r" reduce-precision\(.*exponent_bits=8, "
                       r"mantissa_bits=7", text)
     assert not re.findall(r" sort\(", text)
+
+
+def test_a_mesh_product_job_s_fused_program_fits_four_chips_at_the_cell_s_size(
+        topo):
+    """``g500-sq15x4.spgemm-mesh``'s numeric phase, the fused gathered
+    ``summa_spgemm_windowed`` under the ``dot`` backend, compiled for the
+    described 2 x 2 at the cell's shapes (a ``[16384, 16384]`` tile a
+    chip of 233,724 slots, four row blocks by two column windows of 2^24
+    output slots each, two stages: the host's plan of the scale-15
+    graph): the compiler takes it (a minute), a chip holds 1.61 GB of
+    output slots and under 3 GB of temporaries, the stage exchange is
+    all-gathers under ``sq.exchange`` and nothing else crosses chips but
+    the overflow flag, every window's accumulator takes TWO stage
+    products on the matrix unit, and the scopes the mesh readers
+    (``chipbench/sqmscopes.py``) read the trace by are on its
+    instructions."""
+    import jax
+    import jax.numpy as jnp
+
+    from combblas_tpu.obs import opnames
+    from combblas_tpu.parallel import spgemm as S
+    from combblas_tpu.parallel.grid import Grid
+    from combblas_tpu.parallel.spmat import SpParMat
+    from combblas_tpu.semiring import PLUS_TIMES
+
+    n, stored, slots = 1 << 15, 233_724, 1 << 24
+    grid = Grid.make(2, 2, devices=topo.devices)
+    tile = grid.tile_sharding()
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=tile)
+
+    A = SpParMat(
+        rows=sds((2, 2, stored), jnp.int32),
+        cols=sds((2, 2, stored), jnp.int32),
+        vals=sds((2, 2, stored), jnp.float32), nnz=sds((2, 2), jnp.int32),
+        nrows=n, ncols=n, grid=grid)
+    rb, bc = S.default_block_rows(n // 2, n // 2), S.default_block_cols(
+        n // 2, n // 2)
+    assert (rb, bc) == (4096, 8192)
+    compiled = S.summa_spgemm_windowed.lower(
+        PLUS_TIMES, A, A, block_rows=rb, flop_caps=((slots,) * 2,) * 4,
+        out_caps=((slots,) * 2,) * 4, skip=((False,) * 2,) * 4,
+        backend="dot", mode="bf16", chunk_w=S.WINDOWED_CHUNK_W,
+        interpret=False, block_cols=bc, panel_cap=1 << 17).compile()
+    mem = compiled.memory_analysis()
+    assert 0 <= mem.output_size_in_bytes - 8 * slots * 12 < 4096
+    assert mem.temp_size_in_bytes < 3 * 2**30
+    text = compiled.as_text()
+    assert text.startswith("HloModule jit_summa_spgemm_windowed")
+    seen = set(opnames.parse(text)[1].values())
+    for scope in ("sq.exchange", "sq.densify", "sq.dot", "sq.extract"):
+        assert any(f"/{scope}/" in nm for nm in seen), scope
+    gathers = re.findall(
+        r" all-gather(?:-start)?\(.*op_name=\"([^\"]*)\"", text)
+    assert gathers and all("/sq.exchange/" in nm for nm in gathers)
+    assert not re.findall(r" collective-permute(?:-start)?\(", text)
+    dots = re.findall(
+        r"= f32\[4096,8192\]\S* (?:convolution|dot)\(.*op_name=\"[^\"]*"
+        r"sq\.dot/", text)
+    assert len(dots) == 4 * 2 * 2, len(dots)
