@@ -89,7 +89,11 @@ EDGE_HARVEST_BITS_MAX_DIM = 262144
 #: yardstick.
 TC_SCOPES = (
     "tc.dedup",  # the sorts of every stored slot, the repeat mask, kept first
-    "tc.pack",  # zero fill + scatter-add of one bit a kept nonzero
+    # the packed table written: on the fused path one kernel (pack_rows)
+    # that assembles every row on the chip and stores it once, behind the
+    # fill and the binary searches that find a group's slots; elsewhere
+    # the zero fill + scatter-add of one bit a kept nonzero
+    "tc.pack",
     "tc.harvest",  # the whole scan over chunks of the kept row pairs
     "gather",  # jnp step: two row gathers of [chunk, n/32] words
     "popcount",  # jnp step: the AND, population count and weighted sum
@@ -112,9 +116,10 @@ def _tc_edge_harvest_bits(rows, cols, n: int, chunk: int = HARVEST_CHUNK):
     by row-gather traffic (8 KB/row at n = 64K), against the dense
     wedge product's 2n^3 FLOPs.  Reference role: the masked Mult_AnXBn
     of TC.cpp:104-116, redesigned output-driven for a chip with no
-    scatter unit.  Packing is a scatter-ADD of 2^(c mod 32) at
-    (r, c div 32): the input COO is dedup'd, so add ≡ bitwise-or (each
-    bit lands exactly once).
+    scatter unit.  Packing sets bit c mod 32 of word (r, c div 32) once
+    a kept slot (``pack_support_bits``: rows assembled on the chip and
+    written once on the fused path, a scatter-ADD elsewhere: the input
+    COO is dedup'd, so add ≡ bitwise-or, each bit lands exactly once).
 
     The scan walks the pairs it counts: the kept slots (strict lower
     triangle, first of a run of repeats) are brought to the front of
@@ -291,8 +296,12 @@ def tc_job(A: SpParMat) -> tuple[int, int, int]:
         obs.count("models.tc.pairs", pairs)
         obs.count("models.tc.edges", edges)
         obs.count("models.tc.triangles", triangles)
-        obs.count("models.tc.harvest_steps", pairs // HARVEST_CHUNK,
-                  path=harvest_path(-(-A.nrows // 32)))
+        # which step walked the pairs and which pack wrote the table:
+        # one predicate (whole-tile rows where a kernel runs)
+        path = harvest_path(-(-A.nrows // 32))
+        obs.count("models.tc.harvest_steps", pairs // HARVEST_CHUNK, path=path)
+        obs.count("models.tc.pack",
+                  path="rows" if path == "fused" else "scatter")
     return triangles, pairs, edges
 
 

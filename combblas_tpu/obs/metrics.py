@@ -661,6 +661,16 @@ name                                   kind       meaning
                                                   count): ``ops/
                                                   spgemm.py:
                                                   harvest_path``
+``models.tc.pack``                     counter    their packed tables
+                                                  (one a job), labelled
+                                                  ``path`` = ``rows``
+                                                  (every row assembled
+                                                  on the chip and
+                                                  written once, where
+                                                  the harvest is
+                                                  ``fused``) or
+                                                  ``scatter`` (zero
+                                                  fill + scatter-add)
 ``spgemm.job.jobs``                    counter    products run as one
                                                   job (``parallel/
                                                   spgemm.py:

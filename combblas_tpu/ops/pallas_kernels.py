@@ -17,6 +17,11 @@ The second kernel fuses what XLA keeps apart: ``pair_popcount_partials``
 fetches the packed rows a list of pairs names and counts ``a & b`` where
 they land, so the bit-packed harvest (``ops/spgemm.py:
 popcount_pair_counts``) moves a row across HBM once, not three times.
+
+The third builds the table those rows live in: ``pack_rows`` assembles
+a packed row on the chip from the row-sorted edge list and writes it to
+HBM once, where a scatter-add pays a trip to HBM a bit
+(``ops/spgemm.py:pack_support_bits``).
 """
 
 from __future__ import annotations
@@ -216,3 +221,150 @@ def pair_popcount_partials(
         name="pair_popcount",
         interpret=interpret,
     )(ii, jj, bits_i, bits_j)
+
+
+# --- packed rows assembled on the chip, written once (the bit table) -------
+
+
+def _pack_rows_kernel(
+    off_ref, at_hbm, pos_hbm, out_ref, at_s, pos_s, landed, sem,
+    *, span: int, piece: int, unroll: int, pieces: int,
+):
+    """One group of table rows (the grid's step; ``span`` sublanes of
+    128 words): the output block is zeroed, the group's slots of the
+    sorted list (``off_ref[g]`` up to ``off_ref[g + 1]``) are read from
+    scalar memory, and a run of slots on one sublane (consecutive in a
+    list sorted by row, then column) is OR-ed together in a register
+    that is stored at every slot, so nothing is read back and the last
+    store of a run holds the whole run.
+
+    The list comes a ``piece`` at a time, pieces in order and each
+    once: ``landed[0]`` pieces are in scalar memory, the next is in
+    flight into the other half of the buffers (the grid's steps run in
+    order and the scratch outlives a step)."""
+    g = pl.program_id(0)
+
+    def copies(b):
+        at = pl.ds(pl.multiple_of(b * piece, piece), piece)
+        slot = b % 2
+        to = pl.ds(pl.multiple_of(slot * piece, piece), piece)
+        return (
+            pltpu.make_async_copy(
+                at_hbm.at[at], at_s.at[to], sem.at[0, slot]),
+            pltpu.make_async_copy(
+                pos_hbm.at[at], pos_s.at[to], sem.at[1, slot]),
+        )
+
+    @pl.when(g == 0)
+    def _():
+        landed[0] = 0
+        for c in copies(0):
+            c.start()
+
+    out_ref[...] = jnp.zeros_like(out_ref)
+    lo, hi = off_ref[g], off_ref[g + 1]
+    base = g * span
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, LANES), 1)
+
+    def one_slot(k, carry):
+        at, acc = carry
+        to = at_s[k] - base
+        pos = pos_s[k]  # word (pos >> 5) of the sublane, bit pos & 31
+        acc = jnp.where(to == at, acc, 0) | jnp.where(
+            lane == (pos >> 5), jnp.int32(1) << (pos & 31), 0)
+        out_ref[pl.ds(to, 1), :] = jax.lax.bitcast_convert_type(
+            acc, out_ref.dtype)
+        return to, acc
+
+    def one_piece(b, carry):
+        @pl.when(b == landed[0])
+        def _():
+            for c in copies(b):
+                c.wait()
+
+            @pl.when(b + 1 < pieces)
+            def _():
+                for c in copies(b + 1):
+                    c.start()
+
+            landed[0] = b + 1
+
+        # the group's slots inside this piece: a ragged head, whole
+        # unrolled chunks, a ragged tail
+        held = (b % 2) * piece
+        first = jnp.maximum(lo - b * piece, 0)
+        last = jnp.minimum(hi - b * piece, piece)
+        body = jnp.minimum(-(-first // unroll) * unroll, last)
+        tail = jnp.maximum(last // unroll * unroll, body)
+
+        def chunk(j, carry):
+            for u in range(unroll):
+                carry = one_slot(held + j * unroll + u, carry)
+            return carry
+
+        carry = jax.lax.fori_loop(held + first, held + body, one_slot, carry)
+        carry = jax.lax.fori_loop(body // unroll, tail // unroll, chunk, carry)
+        return jax.lax.fori_loop(held + tail, held + last, one_slot, carry)
+
+    jax.lax.fori_loop(
+        lo // piece, -(-hi // piece), one_piece,
+        (jnp.int32(0), jnp.zeros((1, LANES), jnp.int32)))
+
+
+def pack_rows(
+    at: jax.Array,
+    pos: jax.Array,
+    offsets: jax.Array,
+    nrows: int,
+    nw: int,
+    *,
+    group: int,
+    piece: int,
+    unroll: int = 16,
+    interpret: bool = False,
+) -> jax.Array:
+    """The packed table ``uint32[nrows * nw / LANES, LANES]`` (``[nrows,
+    nw / LANES, LANES]`` byte for byte: a row is whole tiles), every row
+    written ONCE, empty ones as zeros: slot ``k`` sets bit ``pos[k] &
+    31`` of word ``pos[k] >> 5`` of the table's sublane ``at[k]`` (row
+    ``at[k] // (nw / LANES)``), and sets nothing where ``pos[k] >> 5``
+    is no lane (128 or more).
+
+    ``at`` must not descend (a list sorted by row, then column; a slot
+    that sets nothing carries its left neighbour's sublane).
+    ``offsets`` (``int32[nrows / group + 1]``, to scalar memory whole)
+    cuts the list by group of ``group`` rows: ``offsets[g]`` is the
+    first slot on a sublane of row ``g * group`` or later.  The list's
+    length must divide by ``piece``, ``piece`` by ``unroll``.
+    """
+    (slots,) = at.shape
+    tiles = nw // LANES
+    assert nw % (8 * LANES) == 0 and nrows % group == 0, (nrows, nw, group)
+    assert slots and slots % piece == 0 and piece % unroll == 0, (
+        slots, piece, unroll)
+    assert offsets.shape == (nrows // group + 1,), offsets.shape
+    span = group * tiles
+    kernel = functools.partial(
+        _pack_rows_kernel, span=span, piece=piece, unroll=unroll,
+        pieces=slots // piece)
+    return pl.pallas_call(
+        kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(nrows // group,),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * 2,
+            out_specs=pl.BlockSpec((span, LANES), lambda g, off: (g, 0)),
+            scratch_shapes=[
+                pltpu.SMEM((2 * piece,), jnp.int32),
+                pltpu.SMEM((2 * piece,), jnp.int32),
+                pltpu.SMEM((1,), jnp.int32),
+                pltpu.SemaphoreType.DMA((2, 2)),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((nrows * tiles, LANES), jnp.uint32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",),
+            vmem_limit_bytes=2 * span * LANES * 4 + (8 << 20)),
+        name="pack_rows",
+        interpret=interpret,
+    )(offsets, at, pos)

@@ -836,9 +836,20 @@ def pack_support_bits(
     ``row_tiles`` (the word axis must divide by ``TILE_WORDS``) returns
     the same words as ``[nrows, nw / 128, 128]``: a row is then whole
     (8, 128) tiles, 32 KB in one piece at nw = 8192, which is what the
-    fused harvest's row copies need.  The scatter writes those bytes
+    fused harvest's row copies need.  Its writer writes those bytes
     itself: the table cannot be COPIED into another layout where it is
-    most of the chip's memory (8.59 GB at n = 2^18).
+    most of the chip's memory (8.59 GB at n = 2^18).  Where a Pallas
+    TPU kernel runs (``harvest_path(nw) == "fused"``) that writer is
+    ``pallas_kernels.pack_rows``: every row is assembled in on-chip
+    memory from the sorted list and stored once, empty rows as zeros,
+    with no zero fill and no scatter into HBM (a scatter-add there is
+    one dependent read-modify-write of the table a slot: 88 ns against
+    5; ``PACK_GROUP``).  It walks the list in order, so with
+    ``assume_unique`` the caller guarantees more there: ``rows`` ascend
+    over the slots that count and ``cols`` within a row (what
+    ``coo_sort_dedup`` returns); slots at ``rows >= nrows``, the
+    dropped ones, may lie anywhere among them.  Everywhere else
+    (``row_tiles=False``, a CPU) the scatter-add takes any order.
     """
     nw = -(-ncols // 32)
     if not assume_unique:
@@ -850,17 +861,86 @@ def pack_support_bits(
     bit = jnp.uint32(1) << (cols.astype(jnp.uint32) & 31)
     if row_tiles:
         assert nw % TILE_WORDS == 0, (ncols, nw)
+        tiles = nw // LANES
+        if harvest_path(nw) == "fused":
+            # every row assembled in on-chip memory and written to HBM
+            # once: no zero fill, no scatter (PERF.md section 6, PR 49)
+            from .pallas_kernels import pack_rows
+
+            group = math.gcd(nrows, PACK_GROUP)
+            return pack_rows(
+                *pack_rows_operands(
+                    r, cols, nrows, nw, group=group, piece=PACK_PIECE),
+                nrows, nw, group=group, piece=PACK_PIECE,
+                interpret=_kernel_mode() == "interpret",
+            ).reshape(nrows, tiles, LANES)
         # scattered as [nrows * tiles, 128] under two indices, which is
         # [nrows, tiles, 128] byte for byte under the chip's (8, 128)
         # tiling (the reshape is free); three indices cost the scatter
         # 42 ms more at n = 2^18 (HARVEST_GROUP)
-        tiles = nw // LANES
         bits = jnp.zeros((nrows * tiles, LANES), jnp.uint32)
         tile = word >> (LANES.bit_length() - 1)
         return bits.at[r * tiles + tile, word & (LANES - 1)].add(
             bit, mode="drop").reshape(nrows, tiles, LANES)
     bits = jnp.zeros((nrows, nw), jnp.uint32)
     return bits.at[r, word].add(bit, mode="drop")
+
+
+#: Table rows a step of the on-chip pack assembles and writes (its
+#: output block: ``PACK_GROUP`` x 32 KB at nw = 8192, twice, pipelined:
+#: 2 MB of on-chip memory) and the slots of the sorted list one copy
+#: brings to scalar memory (two lists, two buffers each: 32 KB).
+#: ``chiprun -- python scripts/tc_pack_ladder.py`` at the cell's shapes
+#: (n = 2^18, 7,611,536 slots, the table 8.59 GB; my chip run, PR 49, one
+#: v5e, the committed files, best of three, the three within 0.2 ms):
+#: the zero fill and the scatter-add into HBM 673.3 ms, the same with
+#: ``indices_are_sorted`` and ``unique_indices`` promised 673.5 (88.5 ns
+#: a slot either way); a loop over slabs of 2,048 rows, each zeroed,
+#: scatter-added and laid into the table with no kernel, 123.0 (512
+#: rows: 277.8); the kernel with its operands 51.8 / 45.1 / 41.9 / 40.2
+#: at 8 / 16 / 32 / 64 rows a group (the operands alone, a fill from the
+#: left and the binary searches, are 9.5 / 7.0 / 5.8 / 5.1 of that),
+#: 42.2 / 41.9 / 41.4 at 1,024 / 2,048 / 8,192 slots a piece, and 99.1 /
+#: 51.5 / 45.4 / 41.9 / 41.2 at 1 / 4 / 8 / 16 / 32 slots an unrolled
+#: chunk (``pallas_kernels.pack_rows``'s ``unroll``): 4.7 ns, 4-5
+#: cycles, a slot, the table's 8.59 GB written under it (10.5 ms at the
+#: roofline).  A first form of the kernel that took rows and columns and
+#: tested every slot against its group read 75.2 at 8 slots a chunk and
+#: 68.4 at 16 (``pack_rows_operands`` says what it is handed instead).
+#: Every rung's table has the first's digest.  Re-run before moving
+#: either.
+PACK_GROUP = 32
+PACK_PIECE = 2048
+
+
+def pack_rows_operands(
+    r: Array, cols: Array, nrows: int, nw: int, *, group: int, piece: int
+) -> tuple[Array, Array, Array]:
+    """What ``pallas_kernels.pack_rows`` takes, from the list
+    ``pack_support_bits`` scatters: ``(at, pos, offsets)``.  ``r``
+    ascends over the slots under ``nrows`` and ``cols`` within a row
+    (the dropped slots, at ``nrows``, lie anywhere among them).
+
+    The kernel is handed what would cost it a scalar operation a slot
+    to find: the table's sublane a slot falls on (row x tiles + the
+    column's tile of 4,096; a dropped slot takes its left neighbour's,
+    so the list still ascends and the slot ORs nothing into the run it
+    lies in), the column's place inside it, and where each group of
+    ``group`` rows starts in the list: ``nrows / group + 1`` binary
+    searches (an index a GROUP, not a row: a gather costs this chip
+    28 ns an element).  The list is padded to whole ``piece``s with
+    dropped slots."""
+    tiles = nw // LANES
+    pad = -r.shape[0] % piece if r.shape[0] else piece
+    r = jnp.pad(r, (0, pad), constant_values=nrows)
+    cols = jnp.pad(cols, (0, pad))
+    kept = r < nrows
+    sublane = 32 * LANES  # columns a sublane holds: 128 words of 32
+    at = lax.cummax(jnp.where(kept, r * tiles + cols // sublane, 0))
+    pos = jnp.where(kept, cols % sublane, sublane)
+    offsets = jnp.searchsorted(
+        at, jnp.arange(nrows // group + 1, dtype=jnp.int32) * (group * tiles))
+    return at, pos, offsets.astype(jnp.int32)
 
 
 def front_pack_pairs(

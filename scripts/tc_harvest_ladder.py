@@ -12,8 +12,10 @@ shape the pack's ms and the harvest's by route:
   ``pallas_kernels.pair_popcount_partials`` on a TPU): ``K`` pairs' row
   copies in flight, ``K`` from ``--groups``;
 - ``row_tiles_3idx``: the pack alone, the same table scattered under
-  three indices (``pack_support_bits`` scatters it as ``[n * nw / 128,
-  128]`` under two and reshapes for nothing).
+  three indices (``pack_support_bits``'s scatter-add writes it as ``[n
+  * nw / 128, 128]`` under two and reshapes for nothing; since PR 49 a
+  TPU packs ``row_tiles`` on the chip, no scatter: the pack's own
+  ladder is ``scripts/tc_pack_ladder.py``).
 
     chiprun -- python scripts/tc_harvest_ladder.py
     JAX_PLATFORMS=cpu python scripts/tc_harvest_ladder.py --scale 15 --steps 8192 256

@@ -246,7 +246,10 @@ def test_counters_add_once_a_job_and_telemetry_off_returns_the_same(case):
         "models.tc.edges": 3 * edges, "models.tc.triangles": 3 * triangles,
         # the steps of the scan, under the loop that ran them (a CPU
         # runs the jnp loop: tests/test_tc_fused_harvest.py)
-        "models.tc.harvest_steps": 3 * pairs // CHUNK}
+        "models.tc.harvest_steps": 3 * pairs // CHUNK,
+        # and the pack that wrote the table (a CPU scatters:
+        # tests/test_tc_pack_rows.py)
+        "models.tc.pack": 3}
     # the first traced job published the program's op names, once
     names = set(tables["jit_tc_edgeharvest_bits"].values())
     assert any("/tc.harvest/" in nm and nm.endswith("/gather/gather")

@@ -617,7 +617,8 @@ def test_the_triangle_count_job_fits_the_chip_at_the_cell_s_size(
     for the described v5e, on the path a TPU takes (this process's
     backend is a CPU: the test says ``compiled`` where the program reads
     its backend).  The table is ``uint32[n, 64, 128]``, a row eight
-    whole tiles, WRITTEN in those bytes by the scatter-add and existing
+    whole tiles, WRITTEN in those bytes by the pack's kernel (every row
+    assembled on the chip and stored once: no scatter into HBM) and existing
     ONCE (8.59 GB: a copy, or a change of layout, would be 17.2 GB and
     not fit a 16 GB chip), so the program's temporaries stay under the
     table plus 2 GB; a step of the scan is ONE Mosaic kernel under
@@ -665,7 +666,7 @@ def test_the_triangle_count_job_fits_the_chip_at_the_cell_s_size(
         r"op_name=\"[^\"]*tc\.harvest/while/body/pair_popcount/pallas_call\"",
         text)
     assert len(kernels) == 1
-    # its tables are the scatter's own output, twice: no copy between
+    # its tables are the pack's own output, twice: no copy between
     assert re.search(
         r"custom-call\(\S+, \S+, (\S+), \1\), "
         r"custom_call_target=\"tpu_custom_call\"", kernels[0])
@@ -680,11 +681,18 @@ def test_the_triangle_count_job_fits_the_chip_at_the_cell_s_size(
         r"op_name=\"[^\"]*tc\.harvest/", text)
     assert not re.search(
         r"= s32\[8192\]\S* gather\(.*op_name=\"[^\"]*tc\.harvest/", text)
-    # the scatter-add writes the table as [n * 64, 128] under two
-    # indices: the same bytes, so the kernel's [n, 64, 128] is a bitcast
-    assert re.search(
-        r"= u32\[16777216,128\]\S* scatter\(.*op_name=\"[^\"]*tc\.pack/",
-        text)
+    # the table's writer is ONE Mosaic kernel under tc.pack, its output
+    # [n * 64, 128] (the same bytes: the harvest's [n, 64, 128] is a
+    # bitcast, asserted above as "no copy between"), and no scatter of
+    # that shape, nor a zero fill for one, is left in the program
+    # (PERF.md section 6, PR 49: 7.6 M read-modify-writes in HBM)
+    writers = re.findall(
+        r"= u32\[16777216,128\]\S* custom-call\(.*"
+        r"custom_call_target=\"tpu_custom_call\".*"
+        r"op_name=\"[^\"]*tc\.pack/pack_rows/pallas_call\"", text)
+    assert len(writers) == 1
+    assert not re.search(r"= u32\[16777216,128\]\S* scatter\(", text)
+    assert not re.search(r"= u32\[16777216,128\]\S* broadcast\(", text)
     assert (hilo.shape, hilo.dtype) == ((2,), jnp.int32)
     assert pairs.shape == edges.shape == () and pairs.dtype == jnp.int32
 
