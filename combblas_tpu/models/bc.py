@@ -158,8 +158,8 @@ BC_SCOPES = (
 
 #: What ``_bc_batch_lanes``' ``int32[2]`` of ELL sweeps counts, in order:
 #: one forward sweep a BFS level, one backward sweep a level back; and the
-#: rows of its ``int32[2, 2]`` tally of degree-class sweeps (the columns
-#: are ``ellmat.SWEEP_MODES``).
+#: first axis of its ``int32[2, pr, pc, classes, 2]`` tally of degree-class
+#: sweeps (the last is ``ellmat.SWEEP_MODES``).
 BC_PHASES = ("forward", "backward")
 
 
@@ -225,15 +225,18 @@ def _bc_batch_lanes(E, ET, sources, max_depth: int | None):
     ``E``: adjacency with entry (i, j) = edge j→i (the BFS gather
     orientation); ``ET``: its transpose (pass the same EllParMat for
     symmetric graphs). ``sources``: [W] int32. Returns ``(delta,
-    depth, sweeps, class_sweeps)``: the row-aligned PLAIN [pr, lr, W]
+    depth, sweeps, tally)``: the row-aligned PLAIN [pr, lr, W]
     per-lane dependencies (lane k is Brandes' delta of ``sources[k]``,
     endpoints excluded), the number of BFS levels that hold a vertex in
     the deepest lane (the roots' own level counted), the ``int32[2]``
     count of ELL sweeps the two loops ran, by ``BC_PHASES`` (forward: one
     a level, the last of which finds nothing unless ``max_depth`` cut the
     loop short; backward: one a level but the roots' and their
-    neighbours'), and the ``int32[2, 2]`` tally of what those sweeps did
-    class by class, all tiles: ``BC_PHASES`` by ``ellmat.SWEEP_MODES``.
+    neighbours'), and the ``int32[2, pr, pc, classes, 2]`` tally of what
+    those sweeps did in every tile, class by class: ``BC_PHASES`` first,
+    ``ellmat.SWEEP_MODES`` last (the forward loop sweeps ``E``, the
+    backward one ``ET``; where the two hold different numbers of degree
+    classes the shorter tally is padded with classes never swept).
     Not jitted itself: the served plan (``engine._build_plan``) traces it
     inside its own program and hands each lane back to its request, the
     depth as ``batch_niter``.
@@ -266,9 +269,13 @@ def _bc_batch_lanes(E, ET, sources, max_depth: int | None):
         is_src = (gids[..., None] == sources[None, None, :]) & live
         lvl0 = jnp.where(is_src, 0, -1).astype(jnp.int32)
         nsp0 = is_src.astype(E.dtype)
-        # per tile, summed once after the loops: a collective accumulated
-        # inside a loop costs the loop its op_name (_bfs_batch_tallied)
-        tally0 = jnp.zeros((grid.pr, grid.pc, len(SWEEP_MODES)), jnp.int32)
+        # per tile and class, as the sweep hands it up: a collective
+        # accumulated inside a loop costs the loop its op_name
+
+        def tally0(M):
+            return jnp.zeros(
+                (grid.pr, grid.pc, len(M.buckets), len(SWEEP_MODES)),
+                jnp.int32)
 
     def fcond(st):
         d, _, _, active, _ = st
@@ -287,7 +294,7 @@ def _bc_batch_lanes(E, ET, sources, max_depth: int | None):
     # is one iteration of it in the device trace
     with jax.named_scope("bc.forward"):
         depth, lvl, nsp, still_active, ftally = jax.lax.while_loop(
-            fcond, fstep, (jnp.int32(0), lvl0, nsp0, jnp.bool_(True), tally0)
+            fcond, fstep, (jnp.int32(0), lvl0, nsp0, jnp.bool_(True), tally0(E))
         )
 
     # Backward dependency sweep: d = depth ... 2; every level-(d) vertex
@@ -317,7 +324,7 @@ def _bc_batch_lanes(E, ET, sources, max_depth: int | None):
         # entries, which bc.finish zeroes (a root with no edge has
         # depth 1: the bounds cross and nothing runs)
         delta, btally = jax.lax.fori_loop(
-            start, depth - 1, bstep, (jnp.zeros_like(nsp0), tally0)
+            start, depth - 1, bstep, (jnp.zeros_like(nsp0), tally0(ET))
         )
     with jax.named_scope("bc.finish"):
         # endpoints excluded: zero each lane's own source slot
@@ -328,5 +335,9 @@ def _bc_batch_lanes(E, ET, sources, max_depth: int | None):
         # iterations of the two loops above, as they ran
         sweeps = jnp.stack(
             [depth, jnp.maximum(depth - 1 - start, 0)]).astype(jnp.int32)
-        class_sweeps = jnp.sum(jnp.stack([ftally, btally]), axis=(1, 2))
-    return delta, levels, sweeps, class_sweeps
+        classes = max(len(E.buckets), len(ET.buckets))
+        tally = jnp.stack([
+            jnp.pad(t, ((0, 0), (0, 0), (0, classes - t.shape[2]), (0, 0)))
+            for t in (ftally, btally)
+        ])
+    return delta, levels, sweeps, tally

@@ -529,9 +529,11 @@ def frontier_table_bytes(E, width: int) -> int:
 
 
 def _bfs_batch_tallied(A, sources, max_iters, track_levels, csc=None):
-    """``_bfs_batch_impl`` plus, as a fourth output, the ``int32[2]``
-    tally over the whole search of degree-class sweeps run dense /
-    skipped (``ellmat.SWEEP_MODES``) and, as a fifth, what level 0 did
+    """``_bfs_batch_impl`` plus, as a fourth output, the ``int32[pr, pc,
+    classes, 2]`` tally over the whole search of each tile's and degree
+    class's sweeps run dense / skipped (``ellmat.SWEEP_MODES``; left per
+    tile: a level waits for its busiest one, and the host weighs the
+    counts by ``ellmat.class_slots``) and, as a fifth, what level 0 did
     (an index into ``PUSH_OUTCOMES``; None for a program with no push in
     it).  Not jitted: the served plan (``engine._build_plan``) traces it
     into its own program.
@@ -618,7 +620,7 @@ def _bfs_batch_tallied(A, sources, max_iters, track_levels, csc=None):
 
     state = (
         parents0, levels0, member0, jnp.int32(0), jnp.bool_(True),
-        jnp.zeros((pr_, pc_, len(SWEEP_MODES)), jnp.int32),
+        jnp.zeros((pr_, pc_, len(A.buckets), len(SWEEP_MODES)), jnp.int32),
     )
     outcome = None
     if csc is not None and iters > 0:
@@ -646,7 +648,6 @@ def _bfs_batch_tallied(A, sources, max_iters, track_levels, csc=None):
         parents, levels, _, niter, _, tally = jax.lax.while_loop(
             cond, step, state
         )
-    tally = jnp.sum(tally, axis=(0, 1))  # over tiles, once, after the loop
     if not track_levels:
         # levels were not tracked: return discovery indicator (0 for the
         # sources / discovered? -1 undiscovered) — parents' sign carries it.

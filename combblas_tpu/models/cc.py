@@ -35,7 +35,9 @@ import numpy as np
 
 from .. import obs
 from ..semiring import SELECT2ND_MIN
-from ..parallel.ellmat import EllParMat
+from ..parallel.ellmat import (
+    SWEEP_MODES, EllParMat, class_slots, count_sweep_work,
+)
 from ..parallel.spmat import SpParMat
 from ..parallel.spmv import dist_spmv
 from ..parallel.vec import DistVec
@@ -78,8 +80,9 @@ def fastsv(M, f0: DistVec | None = None):
 
     Eager wrapper: the jitted programs return plain block arrays (the
     plain-outputs law) and, fourth, the rounds that swept; this rebuilds
-    the DistVec outside and, with telemetry on, adds the three counts to
-    ``models.cc.rounds`` / ``.jumps`` / ``.sweeps``."""
+    the DistVec outside and, with telemetry on, adds rounds and jumps to
+    ``models.cc.rounds`` / ``.jumps`` and, for an ``EllParMat``, the
+    sweeps to the ELL family (``_count_sweeps``)."""
     program = cc_fastsv_ell if isinstance(M, EllParMat) else cc_fastsv
     f0_blocks = None if f0 is None else f0.blocks
     blocks, rounds, jumps, sweeps = program(M, f0_blocks)
@@ -96,9 +99,25 @@ def fastsv(M, f0: DistVec | None = None):
         obs.count("models.cc.jobs")
         obs.count("models.cc.rounds", int(rounds))
         obs.count("models.cc.jumps", int(jumps))
-        obs.count("models.cc.sweeps", int(sweeps))
+        if isinstance(M, EllParMat):
+            _count_sweeps(M, int(sweeps))
     labels = DistVec(blocks=blocks, length=M.nrows, align="row", grid=M.grid)
     return labels, rounds, jumps
+
+
+def _count_sweeps(E: EllParMat, sweeps: int) -> None:
+    """A job's ``sweeps`` into the ELL family, ``kind="cc"``, ``width=1``
+    (``ellmat.count_sweep_work``, as a served batch's are): the one-lane
+    sweep has no choice inside it, so the tally is made here, ``sweeps``
+    dense sweeps of every class in every tile; the job is one of
+    ``ell.batches``.  (An ``SpParMat``'s sweep has no classes and counts
+    nothing.)"""
+    slots = class_slots(E)
+    tally = np.zeros(
+        (E.grid.pr, E.grid.pc, len(slots), len(SWEEP_MODES)), np.int64)
+    tally[..., 0] = sweeps
+    count_sweep_work("cc", 1, tally, slots)
+    obs.count("ell.batches", 1, kind="cc", width=1)
 
 
 def connected_components(M) -> tuple[DistVec, jax.Array]:

@@ -105,8 +105,9 @@ def _sssp_batch_impl(E, sources):
     ``sources``: [W] int32. Returns row-aligned PLAIN [pr, lr, W] blocks
     (the wrapper rebuilds the DistMultiVecs) of distances (+inf where
     unreachable) and of shortest-path parents (a root its own, -1 where
-    unreachable), and the round count, the round that changed nothing
-    included.
+    unreachable), the round count, the round that changed nothing
+    included, and the rounds' ``int32[pr, pc, classes, 2]`` tally of each
+    tile's and degree class's sweeps by ``ellmat.SWEEP_MODES``.
 
     The multi-root amortization of the batched BFS applied to SSSP: the
     chip's gather cost is per-INDEX with payload lanes nearly free, so W
@@ -154,9 +155,10 @@ def _sssp_batch_impl(E, sources):
         # as _bfs_batch_impl
         is_root = (gids[..., None] == src) & (src != PAD_ROOT)
         d0 = jnp.where(is_root, jnp.zeros((), dtype), inf)
-        # per tile, summed once after the loop: a collective accumulated
-        # inside a loop costs the loop its op_name (_bfs_batch_tallied)
-        tally0 = jnp.zeros((grid.pr, grid.pc, len(SWEEP_MODES)), jnp.int32)
+        # per tile and class, as the sweep hands it up: a collective
+        # accumulated inside a loop costs the loop its op_name
+        tally0 = jnp.zeros(
+            (grid.pr, grid.pc, len(E.buckets), len(SWEEP_MODES)), jnp.int32)
 
     def cond(state):
         _, _, changed, it, _ = state
@@ -194,4 +196,4 @@ def _sssp_batch_impl(E, sources):
         # roots are their own parents; unreached rows stay -1
         parents = jnp.where(is_root, src, parents)
         parents = jnp.where(db < inf, parents, -1)
-    return db, parents, niter, jnp.sum(tally, axis=(0, 1))
+    return db, parents, niter, tally

@@ -535,12 +535,6 @@ name                                   kind       meaning
                                                   whole ``[n, W]``
                                                   result; labels
                                                   ``kind``)
-``serve.bfs.sweeps``                   counter    degree-class sweeps
-                                                  of served BFS levels,
-                                                  all tiles, by what
-                                                  the device chose
-                                                  (label ``mode`` =
-                                                  dense / skipped)
 ``serve.bfs.push``                     counter    served BFS batches by
                                                   what the device did
                                                   with level 0 (label
@@ -573,17 +567,6 @@ name                                   kind       meaning
 ``serve.sssp.batches``                 counter    served SSSP batches
                                                   executed (label
                                                   ``width``)
-``serve.sssp.class_sweeps``            counter    degree-class sweeps
-                                                  of those batches'
-                                                  rounds, all tiles
-                                                  (label ``mode`` =
-                                                  dense / skipped: no
-                                                  row of the class
-                                                  lay above the
-                                                  smallest distance
-                                                  the round before
-                                                  lowered; the parents
-                                                  pass is not counted)
 ``serve.bc.sweeps``                    counter    ELL sweeps of served
                                                   BC batches (labels
                                                   ``phase`` = forward:
@@ -594,15 +577,6 @@ name                                   kind       meaning
                                                   and their
                                                   neighbours';
                                                   ``width``)
-``serve.bc.class_sweeps``              counter    degree-class sweeps
-                                                  of those sweeps, all
-                                                  tiles (labels
-                                                  ``phase`` = forward /
-                                                  backward; ``mode`` =
-                                                  dense / skipped: no
-                                                  row of the class
-                                                  could change at that
-                                                  level)
 ``serve.bc.batches``                   counter    served BC batches
                                                   executed (label
                                                   ``width``)
@@ -623,15 +597,45 @@ name                                   kind       meaning
                                                   count, the one that
                                                   changed nothing
                                                   included)
-``models.cc.sweeps``                   counter    rounds of those jobs
-                                                  that swept the matrix
-                                                  (the program's own
-                                                  count): a round whose
-                                                  ``f[f]`` is the one
-                                                  the last sweep read
-                                                  keeps that sweep's
-                                                  result; reused share
-                                                  = 1 - sweeps / rounds
+``ell.class_sweeps``                   counter    ELL sweep work, one
+                                                  family for every loop
+                                                  that runs the class
+                                                  loop (``ellmat.
+                                                  _ell_class_sweeps``;
+                                                  ``count_sweep_work``):
+                                                  degree-class sweeps,
+                                                  all tiles, by what
+                                                  the device chose
+                                                  (labels ``kind`` =
+                                                  bfs / sssp / bc: a
+                                                  served batch's
+                                                  levels, rounds or
+                                                  both loops; cc: a
+                                                  FastSV job's swept
+                                                  rounds, which never
+                                                  skip; ``width``;
+                                                  ``cls``: the degree
+                                                  class; ``mode`` =
+                                                  dense / skipped: no
+                                                  row of the class
+                                                  could change;
+                                                  ``phase`` = forward /
+                                                  backward for bc).
+                                                  Kernel 3's parents
+                                                  pass and the W = 256
+                                                  batch BFS are not
+                                                  tallied
+``ell.slots``                          counter    the same counts times
+                                                  the class's slots
+                                                  (bucket rows x width,
+                                                  padding included), of
+                                                  the BUSIEST tile: a
+                                                  wave waits for it
+                                                  (same labels)
+``ell.batches``                        counter    batches (cc: jobs)
+                                                  those two counted
+                                                  (labels ``kind``,
+                                                  ``width``)
 ``models.tc.jobs``                     counter    triangle-count jobs
                                                   run through the eager
                                                   wrapper

@@ -37,6 +37,7 @@ import numpy as np
 from jax import lax
 from jax.sharding import PartitionSpec as P
 
+from .. import obs
 from ..ops.segment import segment_reduce
 from ..semiring import SELECT2ND_MAX, Semiring
 from .collectives import axis_reduce
@@ -345,8 +346,45 @@ def _scatter_rows(sr: Semiring, y: Array, rowids: Array, yb: Array) -> Array:
     return sr.add(y, contrib)
 
 
-#: index of each sweep in the ``int32[2]`` tally a masked sweep returns
+#: last axis of the ``int32[classes, 2]`` tally a masked sweep returns:
+#: entry ``[i, mode]`` counts the times degree class ``i`` was swept
+#: dense / skipped
 SWEEP_MODES = ("dense", "skipped")
+
+
+def class_slots(E) -> tuple[int, ...]:
+    """Slots one tile gathers in a dense sweep of each degree class of
+    ``E`` (an ``EllParMat``), narrowest class first: bucket rows x width,
+    padded slots included (the device gathers them).  Python ints from
+    the buckets' shapes: what weighs a tally of class sweeps on the
+    host, where no width overflows."""
+    return tuple(
+        int(bc.shape[-2]) * int(bc.shape[-1]) for bc, _, _ in E.buckets)
+
+
+def count_sweep_work(kind: str, width: int, tally, slots, **labels):
+    """One tally of class sweeps, on the host (``int[pr, pc, classes,
+    2]``), added to the registry's ELL family under ``kind`` / ``width``
+    (and ``labels``), one series a degree class ``cls`` and mode:
+    ``ell.class_sweeps`` by number, all tiles, and ``ell.slots`` weighed
+    by ``slots`` (``class_slots`` of the swept matrix; a tally padded to
+    more classes holds nothing there), of the BUSIEST tile (the one that
+    gathered most: a wave waits for it, and one chip has one tile).
+    Returns the slots of that tile, ``int64[2]`` by ``SWEEP_MODES``.
+    Every loop that runs ``_ell_class_sweeps`` counts through here; the
+    caller adds ``ell.batches``."""
+    classes = len(slots)
+    counts = np.asarray(tally, np.int64)[:, :, :classes]
+    sweeps = counts.sum(axis=(0, 1))  # [classes, 2]
+    weighed = (counts * np.asarray(slots, np.int64)[:, None]).reshape(
+        -1, classes, len(SWEEP_MODES))
+    busiest = weighed[np.argmax(weighed[..., 0].sum(axis=1))]
+    for i in range(classes):
+        for m, mode in enumerate(SWEEP_MODES):
+            by = dict(labels, kind=kind, width=width, cls=i, mode=mode)
+            obs.count("ell.class_sweeps", int(sweeps[i, m]), **by)
+            obs.count("ell.slots", int(busiest[i, m]), **by)
+    return busiest.sum(axis=0)
 
 
 def _active_rows(row_active: Array, lane_live: Array) -> Array:
@@ -578,8 +616,10 @@ def _ell_class_sweeps(
     choice carries before the first class exists.  Given them, a class
     none of whose rows can still change is skipped (``_class_sweep``):
     ``y`` is then right on every entry the mask keeps and may hold the
-    zero elsewhere, and ``tally`` is the ``int32[2]`` count of class
-    sweeps by ``SWEEP_MODES``.  Without a mask every class is swept
+    zero elsewhere, and ``tally`` is the ``int32[classes, 2]`` count of
+    each class's sweeps by ``SWEEP_MODES`` (a one and a zero a class: a
+    loop adds them up, and the host weighs them by ``class_slots``).
+    Without a mask every class is swept
     whole from one shared table, ``tally`` is None, ``y`` is None for a
     matrix without buckets, and the program is what it was before there
     was a choice.
@@ -617,15 +657,19 @@ def _ell_class_sweeps(
     y = tally = active = None
     if row_active is not None:
         active = _active_rows(row_active, lane_live)
-        tally = jnp.zeros((len(SWEEP_MODES),), jnp.int32)
+        tally = jnp.zeros((len(buckets), len(SWEEP_MODES)), jnp.int32)
         # the choice carries y, so it exists before the first class
         y = _tile_varying(
             jnp.full((lr, row_active.shape[1]), sr.zero(dtype), dtype))
+    skipped = []
     for i, bucket in enumerate(buckets):
         idle = None if active is None else _class_idle(i, bucket[2], active)
         y, mode = _class_sweep(i, bucket, idle, partial(sweep, i), y)
         if mode is not None:
-            tally = tally.at[mode].add(1)
+            skipped.append(mode)
+    if skipped:  # every class chose: one row a class, a one and a zero
+        skipped = jnp.stack(skipped)
+        tally = jnp.stack([1 - skipped, skipped], axis=1)
     return y, tally
 
 
@@ -652,8 +696,9 @@ def _ell_local_spmm(
 
     ``row_active`` (``[lr, F]`` bool): the mask the CALLER applies to
     ``y`` afterwards; with it classes that cannot change a kept entry
-    are skipped and ``tally`` counts the sweeps by ``SWEEP_MODES``
-    (``_ell_class_sweeps``); without it ``tally`` is None.
+    are skipped and ``tally`` counts each class's sweeps by
+    ``SWEEP_MODES`` (``_ell_class_sweeps``); without it ``tally`` is
+    None.
     """
     F = x2.shape[1]
     zero = sr.zero(x2.dtype)
@@ -755,7 +800,7 @@ def _masked_tile_sweeps(sr: Semiring, E: EllParMat, xblocks, active, local):
     ``local(buckets, xblk, actblk) -> (y, tally)`` on its column block of
     ``xblocks`` (col-aligned) and its row block of the mask ``active``
     (row-aligned bool), applies the mask and folds over the "c" axis.
-    Returns ``(blocks [pr, lr, W], tally int32[pr, pc, 2])``."""
+    Returns ``(blocks [pr, lr, W], tally int32[pr, pc, classes, 2])``."""
     nb = len(E.buckets)
 
     def body(xblk, actblk, *flat):
@@ -779,8 +824,9 @@ def _masked_tile_sweeps(sr: Semiring, E: EllParMat, xblocks, active, local):
 @partial(jax.jit, static_argnames=("sr",))
 def ell_masked_multi_sweep(sr: Semiring, E: EllParMat, X, row_active):
     """``dist_spmv_ell_masked_multi`` and how it got there: ``(Y,
-    tally)``, ``tally`` the ``int32[pr, pc, 2]`` count per tile of class
-    sweeps run dense / skipped (``SWEEP_MODES``; per tile so that a caller
+    tally)``, ``tally`` the ``int32[pr, pc, classes, 2]`` count per tile
+    and degree class of sweeps run dense / skipped (``SWEEP_MODES``; per
+    tile because a wave waits for its busiest one, and so that a caller
     accumulating it in a loop puts no collective there: the compiler moves
     an accumulated all-reduce out of a ``while`` and rebuilds the loop
     without its ``op_name``, which is how the trace finds the BFS levels
@@ -906,7 +952,7 @@ def ell_frontier_sweep(E: EllParMat, member: Array, row_active: Array):
     in the lane's frontier, -1 where there is none and on every other
     entry: what ``ell_masked_multi_sweep(SELECT2ND_MAX, ...)`` gives for
     the table of ids, bit for bit, through the same class loop, choice
-    and ``tally`` (``int32[pr, pc, 2]``)."""
+    and ``tally`` (``int32[pr, pc, classes, 2]``)."""
     lr, lc = E.local_rows, E.local_cols
     return _masked_tile_sweeps(
         SELECT2ND_MAX, E, member, row_active,

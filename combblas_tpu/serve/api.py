@@ -1188,12 +1188,16 @@ class Server:
                 result = self.engine.collect(st.handle)
             st.t_exec = time.perf_counter()
             split = _split_parts(st.parts, st.t_asm, st.t_exec)
+            # what the batch's sweeps gathered and skipped (``slots``,
+            # ``slots_skipped``), where its plan tallies them and the
+            # engine read the tally
+            work = getattr(st.handle, "work", None) or {}
             for r in live:
                 if r.trace is not None:
                     r.trace.mark("execute", now=st.t_exec, parts=split)
                     r.trace.annotate(
                         width=len(st.sources), plan=st.plan_src,
-                        version=st.version,
+                        version=st.version, **work,
                     )
             self.faults.check("batch.scatter", kind=kind)
             with obs.span("serve.scatter") if st.traced else NULL_SPAN:

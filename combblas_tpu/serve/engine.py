@@ -86,6 +86,14 @@ def _no_clock(part: str):
     return NULL_SPAN
 
 
+#: The kinds whose program tallies its class sweeps
+#: (``ellmat._ell_class_sweeps``), and the output it returns the tally
+#: in: after the two result blocks and the iteration count of "bfs" and
+#: "sssp"; after "bc"'s one block, its depth and its sweeps by phase.
+_TALLIED = frozenset({"bfs", "sssp", "bc"})
+_TALLY_AT = 3
+
+
 @dataclasses.dataclass(slots=True)
 class _Launched:
     """A dispatched batch between ``launch`` and ``collect``: the
@@ -96,7 +104,13 @@ class _Launched:
     res: object  # the plan's outputs, still on the device
     mark: object  # the part clock of a traced batch, else ``_no_clock``
     feat_dim: int  # of the version it runs on: a swap may land first
+    #: telemetry on, a kind in ``_TALLIED``: what weighs its tally, of
+    #: the version it runs on too (``GraphEngine._swept``)
+    swept: tuple = ()
     waited: bool = False
+    #: what ``collect`` made of the tally (``_count_sweeps``), for the
+    #: batch's ``execute`` stage record
+    work: dict | None = None
 
 
 @dataclasses.dataclass
@@ -844,7 +858,7 @@ class GraphEngine:
 
             def impl(E, sources):
                 # (dist, parents, rounds, the rounds' class sweeps by
-                # mode)
+                # tile, class and mode)
                 trace_mark()
                 return _sssp_batch_impl(E, sources)
 
@@ -867,7 +881,8 @@ class GraphEngine:
 
             def impl(E, ET, sources):
                 # (per-lane dependencies, levels of the deepest lane,
-                # sweeps by BC_PHASES, class sweeps by phase and mode)
+                # sweeps by BC_PHASES, class sweeps by phase, tile,
+                # class and mode)
                 trace_mark()
                 return _bc_batch_lanes(E, ET, sources, self.max_iters)
 
@@ -1038,9 +1053,10 @@ class GraphEngine:
     #: iteration count follows them (BFS levels, Bellman-Ford rounds,
     #: PageRank iterations, and for "bc" the BFS levels of the batch's
     #: deepest lane), then what the kind's program counted of itself,
-    #: read only with telemetry on ("bfs": sweeps by mode and the push's
-    #: outcome; "sssp": the rounds' class sweeps by mode; "bc": sweeps by
-    #: phase, and class sweeps by phase and mode).
+    #: read only with telemetry on ("bfs": the levels' class sweeps and
+    #: the push's outcome; "sssp": the rounds' class sweeps; "bc": sweeps
+    #: by phase, and both loops' class sweeps; the tally of class sweeps
+    #: is output ``_TALLY_AT`` of all three).
     _RESULT_KEYS = {
         "bfs": ("parents", "levels"),
         "sssp": ("dist", "parents"),
@@ -1118,7 +1134,25 @@ class GraphEngine:
         with mark("launch"):
             res = plan.fn(jnp.asarray(sources))
             plan.executions += 1
-        return _Launched(kind, W, res, mark, self._version.feat_dim)
+        swept = self._swept(kind) if obs.ENABLED and kind in _TALLIED else ()
+        return _Launched(
+            kind, W, res, mark, self._version.feat_dim, swept)
+
+    def _swept(self, kind: str) -> tuple:
+        """What weighs a batch's tally of class sweeps on the host: for
+        each loop of the kind's program that tallies, in the tally's
+        order, its labels in the ELL family and the
+        ``ellmat.class_slots`` of the matrix it sweeps, of the CURRENT
+        version (plans outlive a swap; a batch's weights are those of
+        the version it was launched on, as ``feat_dim`` is)."""
+        from ..models.bc import BC_PHASES
+        from ..parallel.ellmat import class_slots
+
+        if kind == "bc":
+            return tuple(
+                ({"phase": phase}, class_slots(M))
+                for phase, M in zip(BC_PHASES, (self.E, self.ET)))
+        return (({}, class_slots(self._plan_args(kind)[0])),)
 
     def _collect(self, handle: "_Launched") -> dict:
         kind, W, res, mark = handle.kind, handle.width, handle.res, handle.mark
@@ -1149,35 +1183,25 @@ class GraphEngine:
             )
             # (a few bytes a batch, not part of the result: kept out
             # of the byte counter above)
+            if handle.swept:
+                handle.work = self._count_sweeps(
+                    kind, W, res[_TALLY_AT], handle.swept)
             if kind == "bfs":
                 from ..models.bfs import PUSH_OUTCOMES
-                from ..parallel.ellmat import SWEEP_MODES
 
-                sweeps, push = counted
-                for mode, taken in zip(SWEEP_MODES, np.asarray(sweeps)):
-                    obs.count("serve.bfs.sweeps", int(taken), mode=mode)
                 obs.count(
-                    "serve.bfs.push", outcome=PUSH_OUTCOMES[int(push)]
+                    "serve.bfs.push",
+                    outcome=PUSH_OUTCOMES[int(counted[-1])],
                 )
             if kind == "sssp":
-                from ..parallel.ellmat import SWEEP_MODES
-
                 obs.count("serve.sssp.rounds", int(niter), width=W)
                 obs.count("serve.sssp.batches", 1, width=W)
-                for mode, taken in zip(SWEEP_MODES, np.asarray(counted[0])):
-                    obs.count("serve.sssp.class_sweeps", int(taken),
-                              mode=mode)
             if kind == "bc":
                 from ..models.bc import BC_PHASES
-                from ..parallel.ellmat import SWEEP_MODES
 
-                sweeps, by_class = (np.asarray(c) for c in counted)
-                for phase, ran, classes in zip(BC_PHASES, sweeps, by_class):
+                for phase, ran in zip(BC_PHASES, np.asarray(counted[0])):
                     obs.count("serve.bc.sweeps", int(ran),
                               phase=phase, width=W)
-                    for mode, taken in zip(SWEEP_MODES, classes):
-                        obs.count("serve.bc.class_sweeps", int(taken),
-                                  phase=phase, mode=mode)
                 obs.count("serve.bc.batches", 1, width=W)
         with mark("to_global"):
             out = {
@@ -1185,6 +1209,25 @@ class GraphEngine:
             }
             out["batch_niter"] = int(niter)
             return out
+
+    @staticmethod
+    def _count_sweeps(kind: str, width: int, tally, swept: tuple) -> dict:
+        """A batch's tally of class sweeps (``int32[pr, pc, classes, 2]``;
+        "bc" alone has two loops, one a phase in front) read back and
+        added, the one way for every kind, to ``ell.class_sweeps`` /
+        ``ell.slots`` / ``ell.batches`` (``ellmat.count_sweep_work``,
+        once a loop of ``swept``).  Returns the batch's own work for its
+        stage record (``_Launched.work``), its loops added up: the slots
+        the busiest tile gathered and skipped."""
+        from ..parallel.ellmat import count_sweep_work
+
+        tally = np.asarray(tally)
+        slots = sum(
+            count_sweep_work(kind, width, counts, weights, **labels)
+            for (labels, weights), counts in zip(
+                swept, tally if kind == "bc" else tally[None]))
+        obs.count("ell.batches", 1, kind=kind, width=width)
+        return {"slots": int(slots[0]), "slots_skipped": int(slots[1])}
 
     def stats(self) -> dict:
         # _plans_lock only: polling stats during a long batch must not

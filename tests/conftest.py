@@ -124,6 +124,12 @@ def pytest_collection_modifyitems(items):
     # assertion of the first is held, the pin as an order check, for
     # every entry in ``test_per_layer_contract.py``; of the two CC cases
     # in ``test_boot_cells.py``, the sets turned into subsets.
+    #
+    # PR 50: seven more are appended, three of them in the three served
+    # closed-loop cells of one chip.  ``test_chipbench_bc.py:410`` holds the lists
+    # the BC cell is in to the eleven it joined; its other assertions
+    # are held, the set three larger, by ``test_boot_cells.py::
+    # test_the_bc_cell_is_in_the_lists_it_joined_and_pr_50_s_three``.
     known = {
         "test_chipbench_parts_cell.py::test_traced_served_cell_prints":
             "asserts the serial worker's batch_gap_ms >= 0",
@@ -135,6 +141,9 @@ def pytest_collection_modifyitems(items):
             "pins the CC cell's per-layer metrics to three",
         "test_chipbench_cc.py::test_the_cell_through_the_real_command":
             "pins the CC cell's traced line to three metrics",
+        "test_chipbench_bc.py::"
+        "test_the_cell_is_appended_and_its_readers_wait_for_a_benchmark_pr":
+            "pins the lists the BC cell is in to eleven",
     }
     for item in items:
         for case, reason in known.items():
@@ -218,3 +227,43 @@ def random_dense(rng, m, n, density=0.3, dtype=np.float32):
     """Random dense matrix with ~density nonzeros (shared test helper)."""
     d = rng.random((m, n)) * (rng.random((m, n)) < density)
     return d.astype(dtype)
+
+
+def idle_classes(E, table, mask):
+    """``bool[pr, pc, classes]``: the degree classes each tile of ``E``
+    (an ``EllParMat``) skips in a masked sweep, replayed on the host from
+    its own bucket rows.  ``table [ncols, W]`` bool: where the sweep's
+    input holds anything but the semiring zero; ``mask [nrows, W]`` bool:
+    the rows the caller keeps.  A tile skips a class none of whose rows
+    the mask keeps in a lane whose table is not all zero in the tile's
+    column block (``ellmat._active_rows``, ``_class_idle``)."""
+    pr, pc = E.grid.pr, E.grid.pc
+    lr, lc = E.local_rows, E.local_cols
+    W = table.shape[1]
+    live = np.zeros((pc * lc, W), bool)
+    live[:len(table)] = table
+    kept = np.zeros((pr * lr, W), bool)
+    kept[:len(mask)] = mask
+    rows = [np.asarray(br) for _, _, br in E.buckets]  # [pr, pc, nb]
+    out = np.zeros((pr, pc, len(rows)), bool)
+    for i in range(pr):
+        for j in range(pc):
+            lanes = live[j * lc:(j + 1) * lc].any(axis=0)
+            busy = (kept[i * lr:(i + 1) * lr] & lanes).any(axis=1)
+            busy = np.append(busy, False)  # padded bucket rows read this
+            out[i, j] = [not busy[br[i, j]].any() for br in rows]
+    return out
+
+
+def counter_sum(name: str, **labels):
+    """The registry's counter series ``name`` whose labels hold
+    ``labels``, added up (the ELL family is one series a degree class);
+    None where there is none."""
+    from combblas_tpu import obs
+
+    found = [
+        rec["value"] for rec in obs.registry.snapshot()
+        if rec.get("kind") == "counter" and rec.get("name") == name
+        and labels.items() <= rec.get("labels", {}).items()
+    ]
+    return int(sum(found)) if found else None

@@ -22,6 +22,8 @@ from combblas_tpu.models import PAD_ROOT  # noqa: E402
 from combblas_tpu.parallel.grid import Grid  # noqa: E402
 from combblas_tpu.serve import GraphEngine, ServeConfig  # noqa: E402
 
+from conftest import counter_sum, idle_classes  # noqa: E402
+
 SCALE = 9
 PATH, LEAVES = 6, 5
 
@@ -252,6 +254,30 @@ def _masked_case(shapes, case):
     }[case]
 
 
+def _numpy_skips(E, ref, sources, forward, cut):
+    """``int[2, pr, pc, classes]``: how often each tile skips each degree
+    class in the ``forward`` sweeps of a batch and in the backward ones
+    after them (``cut``: the depth bound ended the forward loop), from the
+    reference's BFS levels and ``E``'s own bucket rows.  A sweep's table
+    is non-zero on the vertices AT a level; going out the mask keeps the
+    rows no lane has reached yet, going back those one level nearer; a
+    tile skips a class none of whose rows the mask keeps in a lane whose
+    table is not all zero in the tile's column block
+    (``conftest.idle_classes``)."""
+    n = ref.n
+    lvl = np.full((n, len(sources)), -1)
+    for lane, root in enumerate(sources):
+        if root != PAD_ROOT:
+            lv = ref.levels(root)
+            lvl[:, lane] = np.where((lv >= 0) & (lv <= forward), lv, -1)
+    out = np.zeros((2, E.grid.pr, E.grid.pc, len(E.buckets)), int)
+    for d in range(forward):
+        out[0] += idle_classes(E, lvl == d, (lvl < 0) | (lvl > d))
+    for d in range(forward - (0 if cut else 1), 1, -1):
+        out[1] += idle_classes(E, lvl == d, lvl == d - 1)
+    return out
+
+
 @pytest.mark.parametrize("case", [
     "16 live roots", "unequal depths", "a long lane alone", "pad lanes",
     "depth bound", "no edge", "2x2 grid", "2x2 grid, depth bound"])
@@ -298,9 +324,12 @@ def test_masked_sweeps_answer_as_all_dense(
     np.testing.assert_allclose(delta, unbranched[0], rtol=2e-6, atol=0)
     for other in (swept, unbranched):
         assert depth == other[1] and sweeps.tolist() == other[2].tolist()
+    classes = len(E.buckets)
+    assert by_class.shape == (2, *shape, classes, 2)  # phase, tile, class
     assert not unbranched[3].any()  # no class chose, no tally
-    assert not swept[3][:, 1].any()  # every class chose, none skipped
-    np.testing.assert_array_equal(swept[3].sum(axis=1), by_class.sum(axis=1))
+    assert not swept[3][..., 1].any()  # every class chose, none skipped
+    np.testing.assert_array_equal(
+        swept[3].sum(axis=-1), by_class.sum(axis=-1))
 
     levels = ref.level_count([s for s in sources if s != PAD_ROOT])
     cut = max_depth is not None and max_depth < levels - 1
@@ -308,11 +337,14 @@ def test_masked_sweeps_answer_as_all_dense(
     forward = max_depth if cut else levels
     assert sweeps.tolist() == [
         forward, max(forward - (1 if cut else 2), 0)]
-    tiles = shape[0] * shape[1]
-    assert by_class.sum(axis=1).tolist() == [
-        len(E.buckets) * tiles * int(ran) for ran in sweeps]
+    # a choice a sweep, tile and class; which were skipped is what a
+    # numpy replay of the two loops' masks finds, entry for entry
+    for phase, ran in enumerate(sweeps):
+        assert (by_class[phase].sum(axis=-1) == ran).all()
+    np.testing.assert_array_equal(
+        by_class[..., 1], _numpy_skips(E, ref, sources, forward, cut))
     # which loops thinned: (forward, backward)
-    thinned = (bool(by_class[0, 1]), bool(by_class[1, 1]))
+    thinned = (bool(by_class[0, ..., 1].any()), bool(by_class[1, ..., 1].any()))
     assert thinned == {
         # a lone root's lane is live for its one sweep: nothing is reached
         "no edge": (False, False),
@@ -323,37 +355,51 @@ def test_masked_sweeps_answer_as_all_dense(
         "a long lane alone": (False, True),
     }.get(case, (True, True)), by_class
     if case == "a long lane alone":
-        assert by_class[1, 1] >= by_class[1, 0]
+        assert by_class[1, ..., 1].sum() >= by_class[1, ..., 0].sum()
 
 
 def test_class_sweeps_are_counted_with_telemetry_on(engine, shapes):
     """A served batch adds classes x sweeps to
-    ``serve.bc.class_sweeps{phase, mode}``; with telemetry off nothing is
-    read back and nothing counted."""
+    ``ell.class_sweeps{kind=bc, width, phase, mode}`` and the same counts
+    weighed by the swept matrix's ``class_slots`` to ``ell.slots``; with
+    telemetry off nothing is read back and the registry stays empty."""
+    import jax.numpy as jnp
+
     from combblas_tpu.models.bc import BC_PHASES
-    from combblas_tpu.parallel.ellmat import SWEEP_MODES
+    from combblas_tpu.parallel.ellmat import SWEEP_MODES, class_slots
 
     _, _, _, named, _ = shapes
     srcs = np.asarray([named["path"][0], _roots(shapes)["rmat"][0],
                        PAD_ROOT, named["lone"]], np.int32)
 
-    def counted():
+    def counted(series):
         return {
-            (p, m): obs.registry.get_counter(
-                "serve.bc.class_sweeps", phase=p, mode=m)
+            (p, m): counter_sum(series, kind="bc", width=4, phase=p, mode=m)
             for p in BC_PHASES for m in SWEEP_MODES}
 
     obs.reset()
     engine.execute("bc", srcs)  # telemetry off
-    assert not any(counted().values())
+    assert obs.registry.snapshot() == []
     obs.enable(install_hooks=False)
     try:
         engine.execute("bc", srcs)
-        got = counted()
+        got, slots = counted("ell.class_sweeps"), counted("ell.slots")
+        assert obs.registry.get_counter("ell.batches", kind="bc", width=4) == 1
     finally:
         obs.disable()
         obs.reset()
     classes = len(engine.E.buckets)
+    # a symmetric graph: E is its own transpose, which both phases sweep
+    weights = class_slots(engine.E)
+    assert engine._swept("bc") == tuple(
+        ({"phase": phase}, weights) for phase in BC_PHASES)
+    tally = np.asarray(engine.plan("bc", 4).fn(jnp.asarray(srcs))[3])
+    for p, phase in enumerate(BC_PHASES):
+        by_class = tally[p, 0, 0]
+        assert by_class.sum(axis=0).tolist() == [
+            got[phase, m] for m in SWEEP_MODES]
+        assert (np.asarray(weights) @ by_class).tolist() == [
+            slots[phase, m] for m in SWEEP_MODES]
     for phase, ran in (("forward", PATH), ("backward", PATH - 2)):
         assert got[phase, "dense"] + got[phase, "skipped"] == classes * ran
     # the path's lane runs on alone: going out it keeps every class busy

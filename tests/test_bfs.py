@@ -504,10 +504,11 @@ def test_thin_sweeps_match_all_dense(case, shape, all_dense_sweeps):
     dense = run()
     all_dense_sweeps(False)
     thin = run()
-    assert dense[3].tolist() == [0, 0]  # no class chose, no tally
-    ntiles = shape[0] * shape[1]
-    assert thin[3].sum() == len(E.buckets) * ntiles * int(thin[2])
-    assert thin[3][1] > 0, thin[3]
+    assert not dense[3].any()  # no class chose, no tally
+    # one choice a tile, class and level
+    assert thin[3].shape == (*shape, len(E.buckets), 2)
+    assert (thin[3].sum(axis=-1) == int(thin[2])).all()
+    assert thin[3][..., 1].sum() > 0, thin[3]
     for a, b in zip(dense[:3] + dense[4:], thin[:3] + thin[4:]):
         np.testing.assert_array_equal(a, b)
 
@@ -562,11 +563,12 @@ def test_sweep_tally_on_a_path():
         lambda E, r: _bfs_batch_tallied(E, r, None, True)
     )(E, jnp.asarray(roots, jnp.int32))
     assert push is None  # no companion handed in: no push in the program
-    dense, skipped = (int(t) for t in tally)
+    tally = np.asarray(tally)
     assert SWEEP_MODES == ("dense", "skipped")
     assert len(E.buckets) == 2  # the two ends, and the rest
-    assert skipped > 0
-    assert dense + skipped == len(E.buckets) * int(niter)
+    assert tally.shape == (1, 1, 2, 2)  # a tile, by class and mode
+    assert tally[..., 1].sum() > 0
+    assert tally.sum(axis=-1).tolist() == [[[int(niter)] * 2]]
 
 
 @pytest.mark.parametrize("busy,swept", [

@@ -19,6 +19,8 @@ from combblas_tpu.models import bfs as bfs_mod
 from combblas_tpu.parallel import ellmat
 from combblas_tpu.parallel.grid import Grid
 
+from conftest import idle_classes
+
 GRIDS = [(1, 1), (2, 2), (2, 4), (4, 2)]
 WIDTHS = [1, 4, 16, 64]  # 64: two membership words a column
 
@@ -109,28 +111,15 @@ def _numpy_bfs(rows, cols, n, roots):
 
 
 def _numpy_tally(E, history):
-    """``[dense, skipped]`` class sweeps of the loop's iterations
-    ``history``, tile by tile: a tile skips a degree class none of whose
-    bucket rows is unvisited in a lane with a frontier vertex in the
-    tile's column block (``ellmat._active_rows``, ``_class_idle``)."""
-    pr, pc = E.grid.pr, E.grid.pc
-    lr, lc = E.local_rows, E.local_cols
-    rowids = [np.asarray(br) for _, _, br in E.buckets]
-    tally = [0, 0]
-    for frontier, unvisited in history:
-        W = frontier.shape[1]
-        f = np.zeros((pc * lc, W), bool)
-        f[: E.ncols] = frontier
-        u = np.zeros((pr * lr, W), bool)
-        u[: E.nrows] = unvisited
-        for i in range(pr):
-            for j in range(pc):
-                live = f[j * lc:(j + 1) * lc].any(axis=0)
-                active = (u[i * lr:(i + 1) * lr] & live).any(axis=1)
-                active = np.append(active, False)  # padded bucket rows
-                for br in rowids:
-                    tally[0 if active[br[i, j]].any() else 1] += 1
-    return tally
+    """``[pr, pc, classes, 2]``: each tile's and degree class's sweeps
+    run dense / skipped over the loop's iterations ``history``: a tile
+    skips a degree class none of whose bucket rows is unvisited in a lane
+    with a frontier vertex in the tile's column block
+    (``conftest.idle_classes``)."""
+    skipped = sum(
+        (idle_classes(E, frontier, unvisited) for frontier, unvisited in history),
+        np.zeros((E.grid.pr, E.grid.pc, len(E.buckets)), np.int64))
+    return np.stack([len(history) - skipped, skipped], axis=-1).tolist()
 
 
 def _lanes(blocks, n):

@@ -2,7 +2,11 @@
 CC cell's per-layer metrics to exactly three (``conftest.py`` says why
 that file stands as it is and they are expected to fail), with the sets
 turned into subsets: every other assertion they made, and on the traced
-rehearsal the six set-up metrics a library cell reads too."""
+rehearsal the six set-up metrics a library cell reads too.  Since PR 50
+the cell is in two per-layer lists of its own (``ELL``: the work of its
+sweeps), which the same two cases hold; and the case of
+``test_chipbench_bc.py`` that pins the lists the BC cell is in, PR 50's
+three added, likewise."""
 
 import os
 import sys
@@ -16,6 +20,8 @@ READERS = ["cc_device_ms", "cc_round_ms", "cc_rounds", "cc_spmv_share",
 EVERY_CELL = {"compiles_in_window", "load_s", "warmup_s"}
 BOOT = {"graph_ready_s", "upload_s", "boot_trace_s", "boot_fetch_s",
         "boot_probe_s", "boot_unspanned_s"}
+#: PR 50: what the cell's sweeps gathered, and what an index cost
+ELL = ["cc_mslots_per_job", "cc_ns_per_index"]
 
 
 def _rehearse():
@@ -44,17 +50,20 @@ def test_the_cc_cell_is_appended_and_its_readers_wait_for_a_benchmark_pr():
     assert len(cell["why"]) <= 200
     reported = {m["name"] for m in spec.metrics_for(CELL, "end_to_end")}
     assert reported == {"mteps", "setup_s"}
-    # it joined one list, after the cell that was there
+    # it joined one list, after the cell that was there; PR 50's two
+    # lists are its own
     joined = [m for sec in ("end_to_end", "per_layer")
               for m in spec.doc[sec] if CELL in m.get("workloads", ())]
-    assert [m["name"] for m in joined] == ["mteps"]
+    assert [m["name"] for m in joined] == ["mteps"] + ELL
     at = joined[0]["workloads"].index
     assert at("g500-s20.k2-batch") < at(CELL)
+    assert all(m["workloads"] == [CELL] for m in joined[1:])
     # and reports the per-layer metrics every cell reports, and no other
     mine = {m["name"] for m in spec.metrics_for(CELL, "per_layer")}
     assert EVERY_CELL <= mine
     assert all("workloads" not in m
-               for m in spec.metrics_for(CELL, "per_layer"))
+               for m in spec.metrics_for(CELL, "per_layer")
+               if m["name"] not in ELL)
     cfg = spec.config(CONFIG)
     assert list(cfg["reduced"]) == ["scale"] and cfg["kinds"] == []
     assert (cfg["scale"], cfg["edgefactor"], cfg["graph_seed"]) == (20, 16, 1)
@@ -88,7 +97,8 @@ def test_the_cc_cell_through_the_real_command(tmp_path):
     assert r.returncode == 0, r.stderr[-2000:]
     assert f"deployment {CONFIG}: snapshot" in r.stderr
     m = reh.check_line(line)
-    assert set(m) == EVERY_CELL | BOOT
+    # (no device plane here: the trace's reader of the two finds nothing)
+    assert set(m) == EVERY_CELL | BOOT | {"cc_mslots_per_job"}
     assert m["compiles_in_window"] == 0
     # a library cell's boot: the restore is the program's, the warm-up
     # call the benchmark's (outside every span), the probe a top-level
@@ -104,3 +114,39 @@ def test_the_cc_cell_through_the_real_command(tmp_path):
     assert list(logged) == READERS
     assert float(logged.pop("cc_rounds")) == 4.0
     assert set(logged.values()) == {"nothing to read"}
+
+
+def test_the_bc_cell_is_in_the_lists_it_joined_and_pr_50_s_three():
+    """``test_chipbench_bc.py::
+    test_the_cell_is_appended_and_its_readers_wait_for_a_benchmark_pr``
+    holds the lists the BC cell is in to the eleven it joined; PR 50
+    appended three (the work of its sweeps), so that case is expected to
+    fail (``conftest.py``) and its assertions are held here, the set
+    three larger."""
+    sys.path.insert(0, os.path.join(os.path.dirname(__file__), "chipbench"))
+    try:
+        import test_chipbench_bc as bc
+    finally:
+        sys.path.pop(0)
+    spec = Spec(os.path.join(CHECKOUT, "BENCHMARK.json"))
+    names = [m["name"] for m in spec.doc["per_layer"]]
+    assert not set(bc.READERS) & set(names)
+    assert list(spec.load_module(
+        "drivers", "serve_closed_bc").LAYERS) == bc.READERS
+    cells = [w["name"] for w in spec.doc["workloads"]]
+    assert cells.index(bc.K3_CELL) < cells.index(bc.CELL)
+    cell = spec.cell(bc.CELL)
+    assert (cell["config"], cell["traffic"], cell["chips"]) == (
+        bc.CONFIG, bc.MIX, 1)
+    reported = {m["name"] for m in spec.metrics_for(bc.CELL, "end_to_end")}
+    assert reported == {"qps", "setup_s"}
+    mine = {m["name"] for m in spec.metrics_for(bc.CELL, "per_layer")}
+    assert bc.SHARED <= mine
+    assert not any(m.startswith(("bfs_", "k2_")) for m in mine)
+    joined = [m for sec in ("end_to_end", "per_layer")
+              for m in spec.doc[sec] if bc.CELL in m.get("workloads", ())]
+    assert {m["name"] for m in joined} == bc.SHARED | {"qps"} | {
+        "ell_mslots_per_batch", "ell_skipped_share", "ell_ns_per_index"}
+    for m in joined:
+        at = m["workloads"].index
+        assert at(bc.K3_CELL) < at(bc.CELL), m["name"]

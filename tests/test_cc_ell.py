@@ -124,13 +124,29 @@ def _counters():
             if rec["name"].startswith("models.cc.")}
 
 
-def test_counters_add_a_job_s_own_counts_once_and_nothing_when_off():
+def _ell_family():
+    """The ELL family's series of kind "cc", by name and mode, the
+    degree classes added up."""
+    out = {}
+    for rec in obs.registry.snapshot():
+        if rec["name"].startswith("ell."):
+            assert (rec["labels"]["kind"], rec["labels"]["width"]) == ("cc", 1)
+            key = rec["name"], rec["labels"].get("mode")
+            out[key] = out.get(key, 0) + rec["value"]
+    return out
+
+
+@pytest.mark.parametrize("shape", [(1, 1), (2, 2)], ids=["1x1", "2x2"])
+def test_counters_add_a_job_s_own_counts_once_and_nothing_when_off(shape):
+    from combblas_tpu.parallel.ellmat import class_slots
+
     n, rows, cols = _path300()
     E = EllParMat.from_host_coo(
-        Grid.make(1, 1), rows, cols, np.ones(len(rows), np.float32), n, n)
+        Grid.make(*shape), rows, cols, np.ones(len(rows), np.float32), n, n)
+    tiles, slots = shape[0] * shape[1], class_slots(E)
     obs.reset()
     _, rounds, jumps = cc.fastsv(E)
-    assert _counters() == {}
+    assert obs.registry.snapshot() == [] and obs.spans() == []
     # the sweeps a job ran come from the program (its fourth output):
     # the rounds whose grandparents were not the round before's
     sweeps = int(cc.cc_fastsv_ell(E, None)[3])
@@ -140,10 +156,26 @@ def test_counters_add_a_job_s_own_counts_once_and_nothing_when_off():
         cc.fastsv(E)
         assert _counters() == {
             "models.cc.jobs": 1, "models.cc.rounds": int(rounds),
-            "models.cc.jumps": int(jumps), "models.cc.sweeps": sweeps}
-        cc.connected_components(E)
+            "models.cc.jumps": int(jumps)}
+        # the one-lane sweep has no choice in it: every sweep gathers
+        # every class of every tile, and a tile's slots are the job's
+        assert _ell_family() == {
+            ("ell.batches", None): 1,
+            ("ell.class_sweeps", "dense"): sweeps * len(slots) * tiles,
+            ("ell.class_sweeps", "skipped"): 0,
+            ("ell.slots", "dense"): sweeps * sum(slots),
+            ("ell.slots", "skipped"): 0}
+        # an SpParMat's sweep has no classes: a job, and no ELL work
+        A = SpParMat.from_global_coo(
+            Grid.make(*shape), rows, cols, np.ones(len(rows), np.float32),
+            n, n)
+        cc.fastsv(A)
         assert _counters()["models.cc.jobs"] == 2
-        assert _counters()["models.cc.rounds"] == 2 * int(rounds)
+        assert _ell_family()["ell.batches", None] == 1
+        cc.connected_components(E)
+        assert _counters()["models.cc.jobs"] == 3
+        assert _counters()["models.cc.rounds"] == 3 * int(rounds)
+        assert _ell_family()["ell.slots", "dense"] == 2 * sweeps * sum(slots)
         # the first traced call published the program's op names
         assert any("cc.iter" in nm for nm in obs.opnames.tables()[
             "jit_cc_fastsv_ell"].values())

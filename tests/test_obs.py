@@ -90,11 +90,8 @@ def test_forced_span_still_accumulates_when_obs_disabled():
 # --- zero-cost-when-disabled ------------------------------------------------
 
 
-def test_disabled_instrumentation_is_free(rng):
-    """The served batch path with telemetry off against the bare plan
-    call and readback it wraps: nothing recorded, and under 5% of wall
-    time between them (interleaved, min-filtered: a load spike cannot
-    land on one side only)."""
+def _served_bfs(rng):
+    """A small served BFS engine, its width-16 plan warm, and a batch."""
     from combblas_tpu.serve import GraphEngine
 
     n = 64
@@ -104,21 +101,88 @@ def test_disabled_instrumentation_is_free(rng):
         Grid.make(2, 2), np.concatenate([r, c]), np.concatenate([c, r]),
         n, kinds=("bfs",),
     )
-    sources = np.arange(16, dtype=np.int32)
     engine.warmup(kinds=("bfs",), widths=(16,))
+    return engine, np.arange(16, dtype=np.int32)
+
+
+def _bare(engine, sources):
+    """What ``engine.execute`` wraps: the plan's call and the readback of
+    its result blocks and iteration count."""
+    p, l, niter, *_ = engine.plan("bfs", 16).fn(jnp.asarray(sources))
+    return (engine._lanes_to_global(np.asarray(p)),
+            engine._lanes_to_global(np.asarray(l)), int(niter))
+
+
+def test_disabled_instrumentation_is_free(rng, monkeypatch):
+    """The served batch path with telemetry off, held to the contract and
+    not to a clock: it enters no method of ``obs.registry``,
+    ``obs._spans`` or ``obs.trace`` (every one raises here), and it reads
+    back no output of the plan beyond its result blocks: the sweep tally
+    and the push's outcome stay on the device, as many ``np.asarray`` /
+    ``device_get`` calls as the bare plan call and readback it wraps."""
+    import types
+
+    from combblas_tpu.serve import engine as engine_mod
+
+    engine, sources = _served_bfs(rng)
     plan = engine.plan("bfs", 16)
-
-    def bare():
-        p, l, niter, *_ = plan.fn(jnp.asarray(sources))
-        return (engine._lanes_to_global(np.asarray(p)),
-                engine._lanes_to_global(np.asarray(l)), int(niter))
-
-    def served():
-        return engine.execute("bfs", sources)
-
     assert not obs.ENABLED
-    out = served()
-    np.testing.assert_array_equal(bare()[1], out["levels"])
+
+    def refuse(what):
+        def entered(*a, **kw):
+            raise AssertionError(f"telemetry off, and {what} was entered")
+        return entered
+
+    outputs, read = [], []
+    fn = plan.fn
+
+    def counting_asarray(x, *a, **kw):
+        if isinstance(x, jax.Array):
+            read.append(x)
+        return np.asarray(x, *a, **kw)
+
+    def recording_fn(srcs):
+        outputs.append(fn(srcs))
+        return outputs[-1]
+
+    with monkeypatch.context() as m:
+        for holder in (obs.registry, obs._spans, obs.trace):
+            for name in dir(holder):
+                attr = getattr(holder, name)
+                if not name.startswith("__") and isinstance(
+                        attr, (types.MethodType, types.FunctionType)):
+                    m.setattr(holder, name, refuse(
+                        f"{getattr(holder, '__name__', type(holder).__name__)}"
+                        f".{name}"))
+        m.setattr(jax, "device_get", refuse("jax.device_get"))
+        # the engine's own numpy: everything but ``asarray`` as it is
+        m.setattr(engine_mod, "np", types.SimpleNamespace(
+            **{**vars(np), "asarray": counting_asarray}))
+        m.setattr(plan, "fn", recording_fn)
+        out = engine.execute("bfs", sources)
+    # parents and levels, in the program's order, and nothing after them
+    # (the iteration count is one ``int()``; the tally and the push's
+    # outcome, outputs 3 and 4, were left where they are)
+    (res,) = outputs
+    assert len(res) == 5 and [id(x) for x in read] == [
+        id(res[0]), id(res[1])]
+    bare = _bare(engine, sources)
+    np.testing.assert_array_equal(bare[0], out["parents"])
+    np.testing.assert_array_equal(bare[1], out["levels"])
+    assert bare[2] == out["batch_niter"]
+    assert set(out) == {"parents", "levels", "batch_niter"}
+    assert obs.registry.empty() and obs._spans.empty()
+    assert not obs.trace.records()
+
+
+@pytest.mark.slow
+def test_disabled_instrumentation_costs_no_time(rng):
+    """The same path against the same bare call on the clock: under 5%
+    of wall time between them (interleaved, min-filtered: a load spike
+    cannot land on one side only).  Two wall clocks under ``-n 6`` decide
+    nothing (ROADMAP, PR 35), so this runs with the slow tests; tier-1
+    holds the contract itself (the test above)."""
+    engine, sources = _served_bfs(rng)
 
     def sample(fn):
         t0 = time.perf_counter()
@@ -128,12 +192,11 @@ def test_disabled_instrumentation_is_free(rng):
 
     bare_t, served_t = [], []
     for _ in range(9):
-        bare_t.append(sample(bare))
-        served_t.append(sample(served))
+        bare_t.append(sample(lambda: _bare(engine, sources)))
+        served_t.append(sample(lambda: engine.execute("bfs", sources)))
     assert min(served_t) <= min(bare_t) * 1.05 + 0.005, (
         min(served_t), min(bare_t))
     assert obs.registry.empty() and obs._spans.empty()
-    assert not obs.trace.records()
 
 
 def test_windowed_dot_counters_gated(rng):

@@ -17,8 +17,9 @@ from chipbench.spec import CHECKOUT, Spec
 
 BENCH = os.path.join(CHECKOUT, "BENCHMARK.json")
 
-#: PR 23's eleven, PR 34's six, PR 37's six, PR 40's seven and PR 44's
-#: seven, each run in the order its issue gave
+#: PR 23's eleven, PR 34's six, PR 37's six, PR 40's seven, PR 44's
+#: seven, PR 48's ten and PR 50's seven, each run in the order its issue
+#: gave
 RUNS = [
     ["launch_ms", "readback_ms", "to_global_ms", "readback_mb_per_query",
      "scatter_copied_mb", "batch_gap_ms", "bfs_gather_share",
@@ -31,6 +32,12 @@ RUNS = [
      "sq_mnnz_out_per_s", "sq_hbm_share", "sq_hbm_peak_gb"],
     ["mcl_device_ms", "mcl_expand_ms", "mcl_select_ms", "mcl_host_gap_ms",
      "mcl_iters", "mcl_hbm_share", "mcl_hbm_peak_gb"],
+    ["sqm_device_ms", "sqm_dot_ms", "sqm_extract_ms", "sqm_exchange_ms",
+     "sqm_host_gap_ms", "sqm_collective_share", "sqm_device_skew",
+     "sqm_mnnz_out_per_s", "sqm_hbm_share", "sqm_hbm_peak_gb"],
+    ["ell_mslots_per_batch", "ell_skipped_share", "ell_ns_per_index",
+     "open_mslots_per_query", "open_wave_ns_per_slot", "cc_mslots_per_job",
+     "cc_ns_per_index"],
 ]
 
 
@@ -57,7 +64,7 @@ def test_a_per_layer_entry_follows_the_contract(name):
     assert callable(spec.load_module("layers", name).read)
 
 
-@pytest.mark.parametrize("run", RUNS, ids=["pr23", "pr34", "pr37", "pr40", "pr44"])
+@pytest.mark.parametrize("run", RUNS, ids=["pr23", "pr34", "pr37", "pr40", "pr44", "pr48", "pr50"])
 def test_appended_entries_keep_their_issues_order(run):
     names = _names()
     assert [n for n in names if n in run] == run

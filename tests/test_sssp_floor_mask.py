@@ -22,6 +22,7 @@ from combblas_tpu.parallel.ellmat import SWEEP_MODES  # noqa: E402
 from combblas_tpu.parallel.grid import Grid  # noqa: E402
 from combblas_tpu.serve import GraphEngine  # noqa: E402
 
+from conftest import counter_sum, idle_classes  # noqa: E402
 from test_sssp_k3 import ties_coo  # noqa: E402
 
 SCALE = 9
@@ -79,26 +80,12 @@ def _host_rounds(n, r, c, w, roots):
 
 
 def _host_skips(E, rounds):
-    """Class sweeps a tile skips, by round, counted on the host from
-    ``E``'s own bucket rows: a class none of whose rows is active in a
-    lane that holds a finite distance in the tile's column block
-    (``ellmat._active_rows``)."""
-    lr, lc = E.local_rows, E.local_cols
-    pr, pc = E.grid.pr, E.grid.pc
-    rows = [np.asarray(b[2]) for b in E.buckets]  # [pr, pc, nb], pad = lr
-    out = []
-    for d, active, _ in rounds:
-        skipped = 0
-        for i in range(pr):
-            act = active[i * lr:(i + 1) * lr]
-            act = np.concatenate(
-                [act, np.zeros((lr + 1 - len(act), act.shape[1]), bool)])
-            for j in range(pc):
-                live = np.isfinite(d[j * lc:(j + 1) * lc]).any(axis=0)
-                busy = (act & live).any(axis=1)
-                skipped += sum(not busy[br[i, j]].any() for br in rows)
-        out.append(skipped)
-    return out
+    """``bool[rounds, pr, pc, classes]``: the class sweeps a tile skips,
+    by round: a class none of whose rows is active in a lane that holds a
+    finite distance in the tile's column block
+    (``conftest.idle_classes``)."""
+    return np.asarray(
+        [idle_classes(E, np.isfinite(d), active) for d, active, _ in rounds])
 
 
 @pytest.mark.parametrize("case", ["rmat", "ties"])
@@ -123,12 +110,16 @@ def test_masked_rounds_give_the_all_dense_answer(grid, case, all_dense_sweeps):
     assert np.array_equal(parents, dparents)
     assert rounds == drounds >= 2
     E = eng.E_weighted
-    assert tally.shape == (len(SWEEP_MODES),) and not dtally.any()
-    assert tally.sum() == rounds * len(E.buckets) * grid[0] * grid[1]
+    assert tally.shape == (*grid, len(E.buckets), len(SWEEP_MODES))
+    assert not dtally.any()
+    # a choice a tile, class and round; the skipped ones are the host's,
+    # tile by tile and class by class
+    assert (tally.sum(axis=-1) == rounds).all()
     skips = _host_skips(E, _host_rounds(n, r, c, w, roots))
-    assert len(skips) == rounds and sum(skips) == tally[1]
+    assert len(skips) == rounds
+    assert np.array_equal(skips.sum(axis=0), tally[..., 1])
     if case == "rmat":
-        assert sum(skips[:-1]) > 0 and tally[0] > 0, skips
+        assert skips[:-1].any() and tally[..., 0].any(), skips
 
 
 def test_a_finished_lane_keeps_no_row_active():
@@ -153,30 +144,52 @@ def test_a_finished_lane_keeps_no_row_active():
 
 def test_class_sweeps_are_counted_with_telemetry_on():
     """A served batch adds rounds x classes (x tiles) to
-    ``serve.sssp.class_sweeps{mode}`` beside ``serve.sssp.rounds``; with
-    telemetry off the tally is not read back and nothing is counted."""
+    ``ell.class_sweeps{kind=sssp, width, mode}`` beside
+    ``serve.sssp.rounds``, and the same counts weighed by the plan's
+    ``class_slots`` to ``ell.slots``; with telemetry off the tally is not
+    read back and the registry stays empty."""
+    from combblas_tpu.parallel.ellmat import class_slots
+
     n, r, c, w, roots = _coo("rmat")
     eng = GraphEngine.from_coo(
         Grid.make(1, 1), r, c, n, weights=w, kinds=("sssp",))
     srcs = np.asarray(roots[:8], np.int32)
 
-    def counted():
-        return {m: obs.registry.get_counter(
-            "serve.sssp.class_sweeps", mode=m) for m in SWEEP_MODES}
+    def counted(series):
+        return {m: counter_sum(series, kind="sssp", width=8, mode=m)
+                for m in SWEEP_MODES}
 
     obs.reset()
     eng.execute("sssp", srcs)  # telemetry off
-    assert not any(counted().values())
-    assert not obs.registry.get_counter("serve.sssp.rounds", width=8)
+    assert obs.registry.snapshot() == []
     obs.enable(install_hooks=False)
     try:
         res = eng.execute("sssp", srcs)
-        got = counted()
+        got, slots = counted("ell.class_sweeps"), counted("ell.slots")
+        obs_counts = {
+            (i, m): obs.registry.get_counter(
+                "ell.slots", kind="sssp", width=8, cls=i, mode=m)
+            for i in range(len(eng.E_weighted.buckets)) for m in SWEEP_MODES}
         rounds = obs.registry.get_counter("serve.sssp.rounds", width=8)
+        batches = obs.registry.get_counter(
+            "ell.batches", kind="sssp", width=8)
     finally:
         obs.disable()
         obs.reset()
-    assert rounds == res["batch_niter"]
+    assert rounds == res["batch_niter"] and batches == 1
     assert got["dense"] + got["skipped"] == rounds * len(
         eng.E_weighted.buckets)
     assert got["skipped"] > 0 and got["dense"] > 0
+    # the weights of the matrix the rounds sweep, and what the program
+    # counted by class
+    weights = class_slots(eng.E_weighted)
+    assert eng._swept("sssp") == (({}, weights),)
+    tally = _run(eng, srcs)[3][0, 0]
+    assert tally.sum(axis=0).tolist() == [got["dense"], got["skipped"]]
+    assert [slots[m] for m in SWEEP_MODES] == (
+        np.asarray(weights) @ tally).tolist()
+    # class by class too: the family's series carry the class
+    for i, size in enumerate(weights):
+        assert [obs_counts[i, m] for m in SWEEP_MODES] == (
+            size * tally[i]).tolist()
+    assert slots["dense"] + slots["skipped"] == rounds * sum(weights)

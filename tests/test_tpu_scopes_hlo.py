@@ -404,12 +404,14 @@ def test_a_mesh_tiles_frontier_table_is_placed_for_the_v5e(topo):
 #: has moved the unmasked sweep.  ``sssp`` was re-pinned by PR 26, which
 #: gave the program its parents pass and the loop its record of the round
 #: that settled each distance (``0525dc40...`` before, ``8adb1928...``
-#: after), and by PR 33, which sent the round through the masked sweep
-#: under the floor of what the round before lowered.  To repeat:
-#: run ``_plan_hlo`` in a checkout of the commit.
+#: after), by PR 33, which sent the round through the masked sweep
+#: under the floor of what the round before lowered, and by PR 50, whose
+#: loop carries the sweeps' tally class by class (``08f4bab2...``
+#: before: an ``s32[1,1,2]`` carry became ``s32[1,1,classes,2]``).  To
+#: repeat: run ``_plan_hlo`` in a checkout of the commit.
 PARENT_HLO = {
     "jax": "0.9.0",
-    "sssp": "08f4bab2ae05717077baff167c9a64db68edaceba21fab30ce6ba6ac34bf57d8",
+    "sssp": "19b004d3845ed852a55d197809e4bc0297af0f42d3e6b87417646a8f5e544c9c",
     "pagerank":
         "7b0a71bdfe7b85447c85b6739d71ebc537dddfed3a7b659fca2fdab9d566705c",
 }
@@ -549,7 +551,8 @@ def test_sssp_round_names_the_loop_of_the_one_chip_program(operands):
     assert (dist.dtype, parents.dtype) == (jnp.float32, jnp.int32)
     assert dist.shape == parents.shape == (1, E.nrows, 16)
     assert rounds.shape == ()
-    assert (by_class.shape, by_class.dtype) == ((2,), jnp.int32)
+    assert (by_class.shape, by_class.dtype) == (
+        (1, 1, len(E.buckets), 2), jnp.int32)  # tile, class, mode
 
 
 def test_bc_scopes_name_both_loops_of_the_one_chip_program(
@@ -605,7 +608,8 @@ def test_bc_scopes_name_both_loops_of_the_one_chip_program(
     assert scores.shape == (1, E.nrows, 16)
     assert (depth.shape, depth.dtype) == ((), jnp.int32)
     assert (sweeps.shape, sweeps.dtype) == ((2,), jnp.int32)
-    assert (by_class.shape, by_class.dtype) == ((2, 2), jnp.int32)
+    assert (by_class.shape, by_class.dtype) == (
+        (2, 1, 1, len(E.buckets), 2), jnp.int32)  # phase, tile, class, mode
     all_dense_sweeps(True)
     assert " conditional(" not in compiled()
 
