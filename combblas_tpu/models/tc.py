@@ -88,7 +88,10 @@ EDGE_HARVEST_BITS_MAX_DIM = 262144
 #: (docs/observability.md "Named scopes"), so a rename is a change of
 #: yardstick.
 TC_SCOPES = (
-    "tc.dedup",  # the sorts of every stored slot, the repeat mask, kept first
+    # the (row, col) sort of every stored slot, which carries the list
+    # itself (no permutation, no gather through one), the repeat mask,
+    # and the sort that brings the kept pairs to the front
+    "tc.dedup",
     # the packed table written: on the fused path one kernel (pack_rows)
     # that assembles every row on the chip and stores it once, behind the
     # fill and the binary searches that find a group's slots; elsewhere

@@ -779,17 +779,31 @@ def hash_merge(
 
 
 def coo_sort_dedup(rows: Array, cols: Array) -> tuple[Array, Array, Array]:
-    """Stable two-key sort (rows major, cols minor) + adjacent-repeat
-    mask for a COO edge list.  Every bit-packed kernel must group and
-    mask duplicated input entries on device (a duplicate would double-ADD
-    a bit, carrying into the NEXT bit — ADVICE r5).  Returns the
-    reordered (rows, cols) and the per-slot ``dup`` mask (True on every
-    repeat after the first of a group).  Shared by the edge-harvest TC
-    kernels (models/tc.py) and ``pack_support_bits``."""
-    order_c = jnp.argsort(cols, stable=True)
-    r1, c1 = rows[order_c], cols[order_c]
-    order_r = jnp.argsort(r1, stable=True)
-    rows, cols = r1[order_r], c1[order_r]
+    """Two-key sort (rows major, cols minor) + adjacent-repeat mask for
+    a COO edge list.  Every bit-packed kernel must group and mask
+    duplicated input entries on device (a duplicate would double-ADD a
+    bit, carrying into the NEXT bit — ADVICE r5).  Returns the reordered
+    (rows, cols) and the per-slot ``dup`` mask (True on every repeat
+    after the first of a group).  Shared by the edge-harvest TC kernels
+    (models/tc.py), ``pack_support_bits`` and
+    ``parallel/spgemm.py:coo_has_duplicates``.
+
+    The sort carries the list it orders: ONE ``lax.sort`` whose two
+    operands are both keys, so no permutation exists and nothing is
+    gathered through one (an element gather is 28 ns a slot on a v5e,
+    one more operand of a sort next to nothing).  It is not stable and
+    need not be: slots it may swap are equal in both lists, and a stable
+    sort is compiled with an iota as one more operand.
+    ``chiprun -- python scripts/tc_dedup_ladder.py`` at
+    ``g500-s18tc.tc-batch``'s shape (n = 2^18, 7,611,536 slots; my chip
+    run, PR 51, one v5e, best of three, the three within 0.3 ms), the
+    ordered list with its mask: two stable ``argsort``s, each followed
+    by two gathers through its permutation, 315.7 ms; this sort **12.9**
+    (1.7 ns a slot), 19.0 if stable; two one-key passes, columns then
+    rows, each carrying the other list, 38.6 both stable and 31.2 the
+    first not.  Every rung's ``(rows, cols, dup)`` has the first's
+    digest.  Re-run before changing the form."""
+    rows, cols = lax.sort((rows, cols), num_keys=2, is_stable=False)
     dup = jnp.concatenate([
         jnp.zeros((1,), bool),
         (rows[1:] == rows[:-1]) & (cols[1:] == cols[:-1]),

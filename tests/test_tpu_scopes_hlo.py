@@ -697,6 +697,20 @@ def test_the_triangle_count_job_fits_the_chip_at_the_cell_s_size(
     assert len(writers) == 1
     assert not re.search(r"= u32\[16777216,128\]\S* scatter\(", text)
     assert not re.search(r"= u32\[16777216,128\]\S* broadcast\(", text)
+    # the list is ordered INSIDE the job, under tc.dedup, by sorts that
+    # carry it: the (row, col) sort of every stored slot and the sort
+    # that front-packs the kept pairs, three at most, and nothing of the
+    # list's length is gathered through a permutation, alone or inside a
+    # fusion (PERF.md section 6, PR 51: four such gathers were 243 ms of
+    # a 673 ms job)
+    dedup = [ln for ln in text.splitlines()
+             if re.search(r"op_name=\"[^\"]*/tc\.dedup/", ln)]
+    sorts = [ln for ln in dedup if re.search(r"= \(.*\) sort\(", ln)]
+    assert 1 <= len(sorts) <= 3, sorts
+    assert not [ln for ln in dedup if re.search(
+        rf"= s32\[{stored}\]\S* gather\(", ln)]
+    assert not [ln for ln in dedup if re.search(
+        rf"= s32\[{stored}\]\S* fusion\(.*op_name=\"[^\"]*/gather\"", ln)]
     assert (hilo.shape, hilo.dtype) == ((2,), jnp.int32)
     assert pairs.shape == edges.shape == () and pairs.dtype == jnp.int32
 
