@@ -841,8 +841,8 @@ def apply_delta(version, batch: DeltaBatch, *,
         # weight-only change) leaves it exactly valid.  It is an
         # operand of the BFS plan, so a structural change keeps the
         # parent's arrays too, for their SHAPES, and marks them
-        # not-current: the swap stays zero-retrace, level 0 of a batch
-        # runs in the loop, and ``GraphEngine.csc_companion`` rebuilds
+        # not-current: the swap stays zero-retrace, a batch sweeps every
+        # level, and ``GraphEngine.csc_companion`` rebuilds
         # it off the query path.  coldeg is no plan's operand: reset,
         # lazily rebuilt (out-degrees are untouched when no edge moved).
         csc=version.csc,

@@ -22,6 +22,7 @@ The sparse-frontier SpMSpV path still exists (``parallel/spmv.py`` +
 from __future__ import annotations
 
 from functools import lru_cache, partial
+from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -40,10 +41,11 @@ from ..parallel.vec import DistVec
 #: trace's per-scope and per-level times are read by these names
 #: (docs/observability.md "Named scopes"), so a rename is a change of
 #: yardstick.  ``bfs.parents`` exists in the compact program only,
-#: ``bfs.push`` (level 0 as a walk of the roots' columns, before the
-#: loop; ``ell.reduce``, ``bfs.update``, ``vec.realign`` and
-#: ``bfs.active`` recur under it) in a program handed the CSC companion
-#: only: the served plan.
+#: ``bfs.push`` (a level as a walk of its frontier's columns, inside the
+#: loop: the fit test every level, and under the level's ``cond`` the
+#: walk's ``push.columns``, ``push.lay``, ``push.walk``, ``push.scatter``
+#: and its ``ell.reduce``) in a program handed the CSC companion only:
+#: the served plan.
 BFS_SCOPES = (
     "bfs.init",
     "bfs.push",
@@ -455,8 +457,8 @@ def bfs_batch(
     dataclass-wrapped jit output tripled the batch child's wall time in
     the round-5 A/B on a machine that is gone; ROADMAP D14).  A library
     caller holds no CSC companion, so this is the all-pull program;
-    the served plan takes level 0 as a push (``_bfs_batch_tallied``):
-    same answer."""
+    the served plan takes its thin levels as pushes
+    (``_bfs_batch_tallied``): same answer."""
     from ..parallel.vec import DistMultiVec
 
     p, l, niter = _bfs_batch_impl(
@@ -501,17 +503,64 @@ def _bfs_batch_impl(
     return _bfs_batch_tallied(A, sources, max_iters, track_levels)[:3]
 
 
-#: Edge slots a tile has for the columns of a batch's roots: level 0 as
-#: a push (``_bfs_batch_tallied``).  Host count, Graph500 scale 20, a
-#: million drawn batches of 16 roots: the degrees sum to 442 at the
-#: median, 4,801 at the 99th percentile, past 2^16 in 5 draws and past
-#: 2^17 in none (ISSUE 29); a batch that does not fit runs level 0 in the
-#: loop, as every batch did.  Static: it sizes the walk.
-PUSH_EDGE_CAPACITY = 1 << 17
+#: Edge slots a tile has for the columns of one level's frontiers, all
+#: W lanes' together: a level that fits is walked (``ell_frontier_push``),
+#: any other is swept (``_bfs_batch_tallied``).  Host counts of 16-root
+#: batches (``chipbench.graph.draw_roots``, three seeds each, the lanes'
+#: frontiers added up) on the two graph laws the benchmark has, PR 52.
+#: The random geometric graph (DIMACS10 ``rgg_n_2_20``: degree 13,
+#: 799-854 levels a batch): a level's frontiers hold 251-312 K edges at
+#: the median and 477 K, 506 K, 546 K at most (23.6 K columns at the
+#: median, 41.1 K at most), so 2^19 would leave up to 78 levels of a
+#: batch to the sweep and 2^20 leaves none (``rgg_n_2_19``, the size
+#: the benchmark's cell runs at: 175-204 K at the median, 355 K at
+#: most, 561-630 levels a batch).  Graph500 scale 20 (R-MAT,
+#: 7-8 levels a batch): 145-4,105 edges at level 0 (the roots' own: 442
+#: at the median of a million draws, past 2^17 in none, ISSUE 29), 318 K,
+#: 849 K and 6.2 M at level 1, 14.6-348 M at levels 2 to 4, 64-67 K at
+#: level 5, about 200 at level 6: under 2^20 the first level, the second
+#: in two batches of three and the last two or three are walked, and no
+#: power of two short of 2^24 would add one.  What a walked level costs
+#: follows its frontier, not this number, which sizes three arrays of one
+#: word a slot: a level over it costs a sweep, as every level did.
+#: Static: it sizes the walk.
+PUSH_EDGE_CAPACITY = 1 << 20
 
-#: What the device chose for level 0, the scalar a program handed the
-#: companion returns: ``serve.bfs.push{outcome}``.
+#: ... and never more than this share of the slots a sweep of the matrix
+#: gathers: a walked edge is an indexed read and an indexed write, a
+#: swept slot one read in a dense fold, so past an eighth of the matrix
+#: the sweep is the cheaper level.  It binds on a small matrix only
+#: (under 2^23 slots: neither benchmark graph), where without it every
+#: level of every search would fit and nothing would ever be swept.
+PUSH_SLOT_SHARE = 1 / 8
+
+
+def push_capacity(E) -> int:
+    """Edges a tile's frontier columns may hold for a level of a search
+    over ``E`` to be walked: ``PUSH_EDGE_CAPACITY``, or
+    ``PUSH_SLOT_SHARE`` of a tile's ELL slots where that is less.
+    Static (from shapes): it sizes the walk."""
+    from ..parallel.ellmat import class_slots
+
+    return max(1, min(
+        PUSH_EDGE_CAPACITY, int(PUSH_SLOT_SHARE * sum(class_slots(E)))))
+
+
+#: What the device chose for level 0, a scalar of the ``PushReport`` a
+#: program handed the companion returns: ``serve.bfs.push{outcome}``.
 PUSH_OUTCOMES = ("taken", "over_budget", "stale")
+
+#: ``serve.bfs.levels{mode}``: how a level of a served batch was run.
+LEVEL_MODES = ("push", "pull")
+
+
+class PushReport(NamedTuple):
+    """What a program handed the CSC companion says of its pushes."""
+
+    outcome: jax.Array  # int32 scalar, level 0's, into ``PUSH_OUTCOMES``
+    levels: jax.Array  # int32 scalar: levels taken as a push
+    edges: jax.Array  # int32[pr, pc]: edges each tile's pushes walked
+
 
 #: What a served BFS level gathers from (``serve.warmup``'s ``payload``
 #: attribute): the frontier as membership bits, one int32 word a column
@@ -533,10 +582,10 @@ def _bfs_batch_tallied(A, sources, max_iters, track_levels, csc=None):
     classes, 2]`` tally over the whole search of each tile's and degree
     class's sweeps run dense / skipped (``ellmat.SWEEP_MODES``; left per
     tile: a level waits for its busiest one, and the host weighs the
-    counts by ``ellmat.class_slots``) and, as a fifth, what level 0 did
-    (an index into ``PUSH_OUTCOMES``; None for a program with no push in
-    it).  Not jitted: the served plan (``engine._build_plan``) traces it
-    into its own program.
+    counts by ``ellmat.class_slots``) and, as a fifth, what its pushes
+    did (a ``PushReport``; None for a program with no push in it).  Not
+    jitted: the served plan (``engine._build_plan``) traces it into its
+    own program.
 
     The loop carries the frontier as MEMBERSHIP, not as ids: ``member
     [pc, lc, ceil(W / 32)]`` int32, col-aligned, bit ``l`` of word ``w``
@@ -546,25 +595,31 @@ def _bfs_batch_tallied(A, sources, max_iters, track_levels, csc=None):
     (``ellmat.ell_frontier_sweep``): a sixteenth of the 16-lane table to
     gather from and to realign, and one table at every width to 32.
 
-    Level 0 is the one level whose work is known before it starts: its
-    frontier is the batch's roots, W columns, which the pull sweep finds
-    by gathering every slot of the matrix.  Given ``csc`` (``(indptr,
-    rowidx, current)``: ``ellmat.build_csc_companion`` of ``A``'s edges
-    and a bool scalar, False once they have moved on) it is taken BEFORE
-    the loop as a walk of those columns (``ellmat.ell_roots_push``, scope
-    ``bfs.push``), and the loop starts at level 1.  The device decides,
-    from the input: the companion is current and the roots' columns fit
-    ``PUSH_EDGE_CAPACITY`` on every tile; otherwise the loop starts at
-    level 0, the state as it always was.  One ``cond`` whose other branch
-    is the identity: the loop body is the same text either way, and no
+    A pull sweep costs what the matrix holds, whatever the frontier: it
+    finds the frontier's neighbours by gathering every slot of every
+    degree class that still has an unvisited row.  Given ``csc``
+    (``(indptr, rowidx, current)``: ``ellmat.build_csc_companion`` of
+    ``A``'s edges and a bool scalar, False once they have moved on) a
+    level is taken instead as a walk of its frontiers' own columns
+    (``ellmat.ell_frontier_push``, scope ``bfs.push`` inside the loop)
+    whenever the device finds, from the level's frontier, that the
+    companion is current and those columns hold at most
+    ``push_capacity(A)`` edges on every tile (``PUSH_EDGE_CAPACITY``;
+    ``ellmat.ell_frontier_fit``: one pass over the membership words).
+    That is level 0 of any search (W columns), the thin last levels of a
+    Graph500 search, and every level of a search over a bounded-degree,
+    high-diameter graph, whose frontier never holds more than a few
+    thousand vertices a lane.  Any other level is the class sweep.  One
+    ``cond`` a level, both branches give the level's candidates; no
     gather table crosses a branch (``ellmat._ell_class_sweeps``).
     Parents, levels, ``niter`` and every tie are the all-pull program's,
-    bit for bit; a caller without a companion gets the all-pull
+    bit for bit (the push folds with the same max over in-frontier
+    neighbours' ids); a caller without a companion gets the all-pull
     program."""
     from ..parallel.vec import DistMultiVec
     from ..parallel.ellmat import (
-        SWEEP_MODES, ell_frontier_sweep, ell_roots_fit, ell_roots_push,
-        pack_lanes,
+        SWEEP_MODES, ell_frontier_fit, ell_frontier_push,
+        ell_frontier_sweep, pack_lanes, tile_lines,
     )
 
     grid = A.grid
@@ -572,12 +627,14 @@ def _bfs_batch_tallied(A, sources, max_iters, track_levels, csc=None):
     pr_, lr = grid.pr, grid.local_rows(n)
     pc_, lc = grid.pc, grid.local_cols(A.ncols)
     iters = max_iters if max_iters is not None else n
+    pushing = csc is not None and iters > 0
 
     with jax.named_scope("bfs.init"):
         row_gids = _global_ids(grid, pr_, lr, n, "row")  # [pr, lr]
         col_gids = _global_ids(grid, pc_, lc, A.ncols, "col")
 
         src = sources.astype(jnp.int32)[None, None, :]  # [1, 1, W]
+        W = src.shape[-1]
         # PAD_ROOT lanes (the serve batcher's lane padding) are inert:
         # the live guard keeps a pad source from matching the -1 padding
         # slots of the gid tables, so a pad lane starts (and stays) empty.
@@ -590,9 +647,16 @@ def _bfs_batch_tallied(A, sources, max_iters, track_levels, csc=None):
             else jnp.zeros((1, 1, 1), jnp.int32)  # placeholder carry
         )
         member0 = pack_lanes((col_gids[:, :, None] == src) & live)
+        if pushing:
+            indptr, rowidx, current = csc
+            current = jnp.asarray(current, jnp.bool_)
+            # what the levels walk, laid out for them ONCE, here
+            coldeg, indptr, rowidx = tile_lines(
+                grid, indptr[..., 1:] - indptr[..., :-1], indptr, rowidx)
+            capacity = push_capacity(A)
 
     def cond(state):
-        _, _, _, level, active, _ = state
+        _, _, _, level, active, _, _ = state
         return active & (level < iters)
 
     def advance(parents, levels, level, y):
@@ -613,46 +677,54 @@ def _bfs_batch_tallied(A, sources, max_iters, track_levels, csc=None):
             active = jnp.any(new)
         return parents, levels, member, level + 1, active
 
+    def pull(parents, member):
+        return ell_frontier_sweep(A, member, parents < 0)
+
     def step(state):
-        parents, levels, member, level, _, tally = state
-        y, sweeps = ell_frontier_sweep(A, member, parents < 0)
-        return (*advance(parents, levels, level, y), tally + sweeps)
+        parents, levels, member, level, _, tally, report = state
+        if not pushing:
+            y, sweeps = pull(parents, member)
+        else:
+            with jax.named_scope("bfs.push"):
+                fits, edges = ell_frontier_fit(A, coldeg, member, capacity)
+                take = current & fits
+
+            def push(_parents, member):
+                with jax.named_scope("bfs.push"):
+                    y = ell_frontier_push(
+                        A, indptr, rowidx, member, W, capacity)
+                return y, jnp.zeros_like(tally)
+
+            y, sweeps = jax.lax.cond(take, push, pull, parents, member)
+            report = PushReport(
+                outcome=jnp.where(
+                    level > 0, report.outcome,
+                    jnp.where(current, jnp.where(fits, 0, 1), 2),
+                ).astype(jnp.int32),
+                levels=report.levels + take.astype(jnp.int32),
+                edges=report.edges + jnp.where(take, edges, 0),
+            )
+        return (*advance(parents, levels, level, y), tally + sweeps, report)
 
     state = (
         parents0, levels0, member0, jnp.int32(0), jnp.bool_(True),
         jnp.zeros((pr_, pc_, len(A.buckets), len(SWEEP_MODES)), jnp.int32),
+        PushReport(
+            outcome=jnp.int32(PUSH_OUTCOMES.index("stale")),
+            levels=jnp.int32(0), edges=jnp.zeros((pr_, pc_), jnp.int32),
+        ) if pushing else None,
     )
-    outcome = None
-    if csc is not None and iters > 0:
-        indptr, rowidx, current = csc
-        current = jnp.asarray(current, jnp.bool_)
-        roots = src[0, 0]
-
-        def pushed(state):
-            parents, levels, _, level, _, tally = state
-            y = ell_roots_push(A, indptr, rowidx, roots, PUSH_EDGE_CAPACITY)
-            return (*advance(parents, levels, level, y), tally)
-
-        with jax.named_scope("bfs.push"):
-            fits = ell_roots_fit(A, indptr, roots, PUSH_EDGE_CAPACITY)
-            state = jax.lax.cond(
-                current & fits, pushed, lambda state: state, state
-            )
-            outcome = jnp.where(
-                current, jnp.where(fits, 0, 1), 2
-            ).astype(jnp.int32)
-
     # the whole loop, condition included, is one scope: a level is one
     # iteration of it in the device trace
     with jax.named_scope("bfs.level"):
-        parents, levels, _, niter, _, tally = jax.lax.while_loop(
+        parents, levels, _, niter, _, tally, report = jax.lax.while_loop(
             cond, step, state
         )
     if not track_levels:
         # levels were not tracked: return discovery indicator (0 for the
         # sources / discovered? -1 undiscovered) — parents' sign carries it.
         levels = jnp.where(parents >= 0, 0, -1)
-    return parents, levels, niter, tally, outcome
+    return parents, levels, niter, tally, report
 
 
 @lru_cache(maxsize=16)
@@ -1144,7 +1216,17 @@ def bfs_batch_compact(A, sources, max_iters: int | None = None,
     """Eager wrapper: the jitted program returns plain block arrays (the
     plain-outputs law — DistVec/DistMultiVec dataclass wrapping inside
     jit measured 60x slower on the round-5 machine);
-    this wrapper rebuilds the DistMultiVecs outside."""
+    this wrapper rebuilds the DistMultiVecs outside.
+
+    For graphs no deeper than 126 levels from any root (its levels are
+    int8): a Graph500 R-MAT is 6-8.  A road network, a mesh or a random
+    geometric graph is hundreds to thousands; there a search stops at
+    level 126 with the rest unreached.  A deep graph goes through the
+    served path (``GraphEngine.from_coo(kinds=("bfs",))`` ->
+    ``Server.submit("bfs", root)``: int32 levels, and every thin level
+    a walk of its frontier's columns, ``_bfs_batch_tallied``) or, as a
+    library call without a companion, through ``bfs_batch`` (int32
+    levels, a whole sweep a level)."""
     from ..parallel.vec import DistMultiVec
 
     opts = dict(
@@ -1193,7 +1275,10 @@ def _bfs_batch_compact_program(A, sources, max_iters: int | None = None,
     ~3-4x at W=256 and halves the memory footprint (int8 state).
 
     Level range: int8 caps at 126 levels — far beyond any Graph500 R-MAT
-    diameter; ``max_iters`` defaults to that cap.
+    diameter; ``max_iters`` defaults to that cap, and a search of a
+    deeper graph (a road network, a random geometric graph: hundreds of
+    levels) ends there with the rest unreached: see ``bfs_batch_compact``
+    for what to call instead.
 
     ``ring=True`` folds each level's partials with the explicit
     ppermute carousel schedule (``collectives.axis_ring_reduce`` — the
@@ -1231,8 +1316,10 @@ def _bfs_batch_compact_program(A, sources, max_iters: int | None = None,
     if max_iters is not None and max_iters > 126:
         raise ValueError(
             f"bfs_batch_compact stores levels as int8 (max depth 126); "
-            f"max_iters={max_iters} cannot be honored — use bfs_batch for "
-            "graphs with eccentricity beyond 126"
+            f"max_iters={max_iters} cannot be honored — a graph deeper "
+            "than 126 levels goes through the served path "
+            "(Server.submit('bfs', root): int32 levels, thin levels walked "
+            "from the frontier) or bfs_batch (int32 levels, a sweep a level)"
         )
     iters = max_iters if max_iters is not None else 126
 

@@ -546,8 +546,23 @@ name                                   kind       meaning
                                                   / stale: the CSC
                                                   companion is not this
                                                   version's; the last
-                                                  two ran level 0 in
-                                                  the loop)
+                                                  two swept level 0,
+                                                  as every level was)
+``serve.bfs.levels``                   counter    levels of served BFS
+                                                  batches by how the
+                                                  device ran each
+                                                  (labels ``mode`` =
+                                                  push: a walk of the
+                                                  frontier's columns /
+                                                  pull: the class
+                                                  sweep; ``width``);
+                                                  both modes add up to
+                                                  the batches' ``niter``
+``serve.bfs.push_edges``               counter    edges the pushed
+                                                  levels of served BFS
+                                                  batches walked, all
+                                                  tiles (label
+                                                  ``width``)
 ``serve.bfs.companion_rebuilds``       counter    rebuilds of a stale
                                                   CSC companion by the
                                                   write lane, once its

@@ -387,7 +387,8 @@ def count_sweep_work(kind: str, width: int, tally, slots, **labels):
     return busiest.sum(axis=0)
 
 
-def _active_rows(row_active: Array, lane_live: Array) -> Array:
+def _active_rows(row_active: Array, lane_live: Array,
+                 lanewise: bool = False) -> Array:
     """``[lr + 1]`` bool: rows that can still change in this tile's sweep.
 
     ``row_active [lr, W]`` is the mask the caller applies to the result,
@@ -397,8 +398,17 @@ def _active_rows(row_active: Array, lane_live: Array) -> Array:
     what the mask leaves of it whether or not it is swept; without this a
     single finished or ``PAD_ROOT`` lane keeps every row active for ever.
     The last entry, False, is what padded bucket rows (row id ``lr``)
-    read."""
-    active = jnp.any(row_active & lane_live[None, :], axis=1)
+    read.  ``lanewise``: the same, lane by lane and OR like
+    ``pack_lanes``, for the served BFS sweep: inside the level's ``cond``
+    (``models.bfs._bfs_batch_tallied``) a fold over the lane axis lays
+    the ``[n, W]`` mask out lane-minor for the whole branch, W of a
+    register's 128 lanes in use."""
+    if lanewise:
+        active = reduce(jnp.logical_or, (
+            row_active[:, l] & lane_live[l]
+            for l in range(row_active.shape[1])))
+    else:
+        active = jnp.any(row_active & lane_live[None, :], axis=1)
     return jnp.concatenate([active, jnp.zeros((1,), jnp.bool_)])
 
 
@@ -597,7 +607,7 @@ def _ell_reduce_rows_jit(E: EllParMat, sr: Semiring, map_fn) -> DistVec:
 def _ell_class_sweeps(
     sr: Semiring, buckets, lr: int, lc: int, table, contract, *,
     slot_bytes: int, dtype=None, row_active: Array | None = None,
-    lane_live: Array | None = None,
+    lane_live: Array | None = None, lanewise: bool = False,
 ):
     """The ONE multi-lane class loop: per degree class gather, contract
     the k axis, combine by row id into ``y [lr, lanes]``.  What a sweep
@@ -616,7 +626,8 @@ def _ell_class_sweeps(
     choice carries before the first class exists.  Given them, a class
     none of whose rows can still change is skipped (``_class_sweep``):
     ``y`` is then right on every entry the mask keeps and may hold the
-    zero elsewhere, and ``tally`` is the ``int32[classes, 2]`` count of
+    zero elsewhere (``lanewise``: ``_active_rows``'s), and ``tally`` is
+    the ``int32[classes, 2]`` count of
     each class's sweeps by ``SWEEP_MODES`` (a one and a zero a class: a
     loop adds them up, and the host weighs them by ``class_slots``).
     Without a mask every class is swept
@@ -656,7 +667,7 @@ def _ell_class_sweeps(
 
     y = tally = active = None
     if row_active is not None:
-        active = _active_rows(row_active, lane_live)
+        active = _active_rows(row_active, lane_live, lanewise)
         tally = jnp.zeros((len(buckets), len(SWEEP_MODES)), jnp.int32)
         # the choice carries y, so it exists before the first class
         y = _tile_varying(
@@ -862,10 +873,9 @@ def pack_lanes(mask: Array) -> Array:
     # lane by lane and OR: a fold over the lane axis makes the compiler
     # lay the loop's [n, W] state out lane-minor, W of a register's 128
     # lanes in use
-    bits = mask.astype(jnp.uint32)
     words = [
         reduce(jnp.bitwise_or, (
-            bits[..., s0 + l] << np.uint32(l)
+            jnp.where(mask[..., s0 + l], np.uint32(1 << l), np.uint32(0))
             for l in range(min(WORD_LANES, W - s0))
         ))
         for s0 in range(0, W, WORD_LANES)
@@ -937,6 +947,7 @@ def _ell_local_frontier(buckets, member: Array, lr: int, lc: int,
         # the candidates a slot is worth, should they be materialised
         slot_bytes=4 * W, dtype=jnp.int32, row_active=row_active,
         lane_live=unpack_lanes(jnp.bitwise_or.reduce(member, axis=0), W),
+        lanewise=True,
     )
 
 
@@ -1235,8 +1246,8 @@ def build_csc_companion(grid: Grid, rows, cols, nrows: int, ncols: int,
     """Host build of per-tile CSC structure arrays for column walks:
     (indptr [pr, pc, lc+1], rowidx [pr, pc, cap]) int32, cap = max tile
     nnz. The EllParMat's row buckets cannot walk COLUMNS; sparse
-    union-frontier steps and the served BFS plan's first level
-    (``ell_roots_push``) need exactly that (the reference's SpImpl CSC
+    union-frontier steps and the served BFS plan's walked levels
+    (``ell_frontier_push``) need exactly that (the reference's SpImpl CSC
     kernels, SpImpl.cpp:345-600).  ``headroom`` / ``cap``: see
     ``build_csc_companion_host``."""
     indptr, rowidx = build_csc_companion_host(
@@ -1332,8 +1343,8 @@ def _companion_host(grid, rows, cols, nrows, ncols, *, major,
 def _walk_columns(indptr, rowid, fcols, capacity: int, lr: int, lc: int):
     """The edges of a tile's local columns ``fcols`` ([F] int32; ``lc``
     = no column) laid into ``capacity`` static slots (``expand_ranges``
-    over their degrees), one CSC walk shared by the union-frontier step
-    and the roots' push.  Returns per slot ``(owner, tgt_row, valid)``:
+    over their degrees): the union-frontier step's CSC walk.  Returns per
+    slot ``(owner, tgt_row, valid)``:
     which entry of ``fcols`` the edge leaves from, the local row it
     enters (``lr`` where ``valid`` is False: dropped by a scatter), and
     whether the slot holds an edge at all.  Edges past ``capacity`` are
@@ -1404,74 +1415,223 @@ def _ell_union_sparse_step(
     )(csc_indptr, csc_rowidx, x8, undiscovered8)
 
 
-# --- the roots' own columns: level 0 of a served BFS as a push -------------
+# --- a served BFS level as a push: walking the frontier's own columns -------
+
+#: Frontier columns one trip of ``ell_frontier_push``'s first loop lays
+#: out, and edge slots one trip of its second walks.  A level's cost
+#: follows its frontier through the trip counts, which the device reads
+#: from the level; a trip's arrays are static.  Small enough that the
+#: last, partly empty trip is a small share of a level of thousands of
+#: columns, large enough that a trip's fixed cost is not.
+PUSH_COLUMN_CHUNK = 1 << 12
+PUSH_SLOT_CHUNK = 1 << 14
 
 
-def _root_columns(src, lc: int, ncols: int):
-    """This tile's local column of each of the batch's ``[W]`` roots,
-    ``lc`` for a root another column block owns and for a lane that has
-    none (``PAD_ROOT``, any id outside the matrix)."""
-    j = lax.axis_index(COL_AXIS)
-    lcol = src - j * lc
-    mine = (src >= 0) & (src < ncols) & (lcol >= 0) & (lcol < lc)
-    return jnp.where(mine, lcol, lc)
+#: a tile's own line of a global 1-D array laid tile after tile
+TILE_LINE = P((ROW_AXIS, COL_AXIS))
 
 
-def ell_roots_fit(E: EllParMat, csc_indptr, sources, capacity: int):
-    """Scalar bool: on every tile, the columns of the batch's roots hold
-    at most ``capacity`` edges between them, so ``ell_roots_push`` walks
-    them all.  ``[W]`` reads of ``indptr`` a tile and one ``all``."""
-    lc = E.local_cols
+def tile_lines(grid: Grid, *arrays):
+    """Per-tile arrays ``[pr, pc, L]`` as lines ``[pr * pc * L]``, each
+    tile's ``L`` its own block (``TILE_LINE``).  A tile's block of a
+    ``[pr, pc, L]`` array is ``[1, 1, L]``, tiled over its last TWO
+    axes; reading it as ``[L]`` is a pass over the whole array (3 ms for
+    a scale-20 companion's 58 MB ``rowidx``, by the v5e compiler's own
+    count), which a program pays once, here, before its loop, and not
+    in every iteration that walks the array."""
+    return jax.shard_map(
+        lambda *blocks: tuple(b.reshape(-1) for b in blocks),
+        mesh=grid.mesh,
+        in_specs=(TILE_SPEC,) * len(arrays),
+        out_specs=(TILE_LINE,) * len(arrays),
+    )(*arrays)
 
-    def body(ipt, src):
-        _, deg = _column_ranges(ipt[0, 0], _root_columns(src, lc, E.ncols))
-        # clamped, so that the sum cannot wrap whatever a column holds
-        total = jnp.sum(jnp.minimum(deg, capacity + 1))
-        return (total <= capacity)[None, None]
 
-    fits = jax.shard_map(
+def _frontier_any(member: Array) -> Array:
+    """``[lc]`` int32, nonzero where the column is in any lane's
+    frontier (``member [lc, nw]``, ``pack_lanes``)."""
+    return reduce(jnp.bitwise_or, (member[:, w] for w in range(member.shape[1])))
+
+
+def ell_frontier_fit(E: EllParMat, coldeg, member, capacity: int):
+    """``(fits, edges)``: scalar bool, on every tile the columns of the
+    W lanes' frontiers (their union: a column in two lanes is walked
+    once) hold at most ``capacity`` edges between them, so
+    ``ell_frontier_push`` walks them all; and ``int32[pr, pc]``, what
+    each tile would walk (0 where it does not fit).  ``coldeg``: the
+    CSC companion's column degrees (``indptr``'s differences), ``lc`` a
+    tile, as ``tile_lines``; ``member [pc, lc, nw]`` as
+    ``ell_frontier_sweep`` takes it.  One pass over a tile's ``lc`` words and one ``all``: no
+    gather, no scatter."""
+
+    def body(deg, mblk):
+        inside = _frontier_any(mblk[0]) != 0
+        # float32: exact up to 2^24, where ``capacity`` lies far below,
+        # and no sum of clamped degrees can wrap it
+        edges = jnp.sum(jnp.where(
+            inside, jnp.minimum(deg, capacity + 1), 0
+        ).astype(jnp.float32))
+        # (columns without an edge, a lane's isolated root, count too:
+        # the walk lays out ``capacity`` columns at most)
+        fits = (edges <= capacity) & (
+            jnp.sum(inside, dtype=jnp.int32) <= capacity)
+        return fits[None, None], jnp.where(fits, edges, 0).astype(
+            jnp.int32)[None, None]
+
+    assert capacity < 1 << 24
+    fits, edges = jax.shard_map(
         body,
         mesh=E.grid.mesh,
-        in_specs=(TILE_SPEC, P()),
-        out_specs=TILE_SPEC,
-    )(csc_indptr, sources)
-    return jnp.all(fits)
+        in_specs=(TILE_LINE, P(COL_AXIS)),
+        out_specs=(TILE_SPEC, TILE_SPEC),
+    )(coldeg, member)
+    return jnp.all(fits), edges
 
 
-def ell_roots_push(E: EllParMat, csc_indptr, csc_rowidx, sources,
-                   capacity: int):
-    """``E (x) X`` under ``SELECT2ND_MAX`` for the ``X`` a batched BFS
-    starts from, lane ``w`` holding root ``sources[w]`` at its own column
-    and nothing else: ``[pr, lr, W]`` int32 row-aligned blocks with the
-    root's id wherever the row has an edge from it, -1 elsewhere.  The
-    pull sweep finds those rows by gathering every slot of the matrix;
-    this walks the roots' columns in the CSC companion
-    (``build_csc_companion``) and scatters: ``capacity`` slots a tile,
-    which the caller has tested with ``ell_roots_fit``.
+def ell_frontier_push(E: EllParMat, csc_indptr, csc_rowidx, member,
+                      width: int, capacity: int):
+    """``E (x) X`` under ``SELECT2ND_MAX`` for the ``X`` a level of the
+    batched BFS holds, its ``width`` frontiers as membership bits
+    (``member [pc, lc, nw]``, ``pack_lanes``): ``[pr, lr, width]`` int32
+    row-aligned blocks, for every (row, lane) the largest id among the
+    row's in-neighbours in the lane's frontier, -1 where there is none:
+    what ``ell_frontier_sweep`` gives on every row its mask keeps, by
+    the same max, so every tie falls the same way.  The sweep finds
+    those rows by gathering every slot of the matrix; this walks the
+    frontier's columns in the CSC companion (``build_csc_companion``,
+    both arrays as ``tile_lines``) and scatters.  The caller has tested
+    ``ell_frontier_fit``: at most ``capacity`` edges a tile.
 
-    One frontier vertex a lane, so the max the scatter folds with has
-    nothing to choose between: the result is the sweep's, entry for
-    entry, unmasked (the caller's update keeps unvisited rows only).
-    Equal roots in two lanes are two lanes; a lane without a root
-    (``PAD_ROOT``) walks nothing; the companion comes from the COO, so a
-    directed matrix walks out-edges."""
+    What it costs follows the frontier, not the matrix and not
+    ``capacity``.  One sort of a tile's ``lc`` column ids brings the
+    frontier's to the front, ascending.  A first loop, a trip every
+    ``PUSH_COLUMN_CHUNK`` of them, lays each column's run of edge slots
+    out: because the columns ascend, a slot's place in ``rowidx``, its
+    column and its lanes are running maxima and sums of what each
+    column writes at its first slot (``expand_ranges``' trick), so no
+    slot gathers from a per-column table.  A second loop, a trip every
+    ``PUSH_SLOT_CHUNK`` slots in use, runs those maxima and sums over
+    its slots, fetches each slot's row from
+    ``rowidx`` and folds the column's id into that row of each of the
+    column's lanes, a word a slot and lane (``walk``).  Pad
+    lanes hold no column; a directed matrix walks out-edges (the
+    companion comes from the COO); an edge stored twice folds twice to
+    the same."""
     lr, lc = E.local_rows, E.local_cols
+    # (a tile holds no more edges than its companion has slots; columns
+    # without an edge, a lane's isolated root, are columns all the same)
+    nslots = min(int(capacity), csc_rowidx.shape[0] // E.grid.size)
+    ncols = -(-min(int(capacity), lc) // PUSH_COLUMN_CHUNK) * PUSH_COLUMN_CHUNK
+    ktrip = min(PUSH_SLOT_CHUNK, nslots)
+    nslots = -(-nslots // ktrip) * ktrip  # whole trips: no slice is clamped
+    assert int(width) * lr < 1 << 31  # a (lane, row) is one int32
 
-    def body(ipt, ridx, src):
-        W = src.shape[0]
-        fcols = _root_columns(src, lc, E.ncols)
-        owner, tgt_row, valid = _walk_columns(
-            ipt[0, 0], ridx[0, 0], fcols, capacity, lr, lc
-        )
-        y = jnp.full((lr, W), -1, jnp.int32).at[tgt_row, owner].max(
-            jnp.where(valid, src[owner], -1), mode="drop"
-        )
+    def body(indptr, rowid, mblk):
+        member = mblk[0]
+        nw, W = member.shape[1], int(width)
+        base = lax.axis_index(COL_AXIS) * lc
+        with jax.named_scope("push.columns"):
+            inside = _frontier_any(member) != 0
+            count = jnp.sum(inside, dtype=jnp.int32)
+            ids = jnp.arange(lc, dtype=jnp.int32)
+            # (not stable: the ids differ, and the sentinels are alike)
+            fcols = lax.sort(jnp.where(inside, ids, lc), is_stable=False)
+            fcols = jnp.concatenate([
+                fcols[:ncols],
+                jnp.full((max(ncols - lc, 0),), lc, jnp.int32),
+            ])
+            # a column's lanes, one table a word (one-dimensional each,
+            # so that the layout pads nothing), the empty word for no
+            # column; a column block's, typed as a tile's own like what
+            # the loops below carry
+            mpad = tuple(
+                lax.pcast(
+                    jnp.concatenate(
+                        [member[:, w], jnp.zeros((1,), jnp.int32)]),
+                    (ROW_AXIS,), to="varying")
+                for w in range(nw))
+
+        def lay(t, carry):
+            """Columns ``[t, t + 1) * PUSH_COLUMN_CHUNK`` of the
+            frontier write, at their first slot, what every slot of
+            theirs reads after the running maxima / sums."""
+            total, prev, place, col, lanes = carry
+            cs = lax.dynamic_slice(
+                fcols, (t * PUSH_COLUMN_CHUNK,), (PUSH_COLUMN_CHUNK,))
+            start, deg = _column_ranges(indptr, cs)
+            first = total + jnp.cumsum(deg) - deg
+            # ascending columns: ``start - first`` never falls
+            place = place.at[first].max(start - first, mode="drop")
+            col = col.at[first].max(cs, mode="drop")
+            words = tuple(m[cs] for m in mpad)
+            lanes = tuple(
+                ln.at[first].add(
+                    wd - jnp.concatenate([pv[None], wd[:-1]]), mode="drop")
+                for ln, wd, pv in zip(lanes, words, prev))
+            return (total + jnp.sum(deg), tuple(wd[-1] for wd in words),
+                    place, col, lanes)
+
+        with jax.named_scope("push.lay"):
+            total, _, place, col, lanes = lax.fori_loop(
+                0, -(-count // PUSH_COLUMN_CHUNK), lay,
+                jax.tree.map(_tile_varying, (
+                    jnp.int32(0), (jnp.int32(0),) * nw,
+                    jnp.zeros((nslots,), jnp.int32),
+                    jnp.zeros((nslots,), jnp.int32),
+                    (jnp.zeros((nslots,), jnp.int32),) * nw,
+                )))
+
+        def walk(k, carry):
+            """Edge slots ``[k, k + 1) * ktrip``.  The running maxima
+            and sums that fill each column's run go on from the trip
+            before (so they pass over the slots in use, not over the
+            capacity); then each slot's row, and its column's id folded
+            into ``y [W * lr]`` (a plane a lane) at that row of each of
+            its lanes, one lane of every slot a pass: the slot's lowest
+            set bit, until no slot has one left.  A scatter of single
+            words costs this chip 12 ns a slot and a scatter of
+            ``[ktrip, W]`` rows 62-104 (my chip runs, PR 52), and the
+            lanes of a thin frontier rarely share a column: one pass,
+            where they do not."""
+            y, place0, col0, lanes0 = carry
+            at = k * ktrip
+            cut = lambda a: lax.dynamic_slice_in_dim(a, at, ktrip)
+            with jax.named_scope("push.walk"):
+                slot = at + jnp.arange(ktrip, dtype=jnp.int32)
+                run = jnp.maximum(lax.cummax(cut(place)), place0)
+                ids = jnp.maximum(lax.cummax(cut(col)), col0)
+                words = tuple(
+                    jnp.cumsum(cut(ln)) + l0 for ln, l0 in zip(lanes, lanes0))
+                tgt = jnp.where(
+                    slot < total,
+                    rowid[jnp.minimum(run + slot, rowid.shape[0] - 1)], lr)
+            with jax.named_scope("push.scatter"):
+                for w, word in enumerate(words):
+
+                    def fold(state):
+                        y, left = state
+                        low = left & -left  # the lowest lane still to go
+                        lane = w * WORD_LANES + lax.population_count(low - 1)
+                        y = y.at[jnp.where(low != 0, lane * lr + tgt, W * lr)
+                                 ].max(ids + base, mode="drop")
+                        return y, left ^ low
+
+                    y, _ = lax.while_loop(
+                        lambda state: jnp.any(state[1] != 0), fold,
+                        (y, jnp.where(tgt < lr, word, 0)))
+            return y, run[-1], ids[-1], tuple(wd[-1] for wd in words)
+
+        y, *_ = lax.fori_loop(
+            0, -(-total // ktrip), walk, jax.tree.map(_tile_varying, (
+                jnp.full((W * lr,), -1, jnp.int32), jnp.int32(0),
+                jnp.int32(0), (jnp.int32(0),) * nw)))
+        y = y.reshape(W, lr).T
         with jax.named_scope("ell.reduce"):
             return lax.pmax(y, COL_AXIS)[None]
 
     return jax.shard_map(
         body,
         mesh=E.grid.mesh,
-        in_specs=(TILE_SPEC, TILE_SPEC, P()),
+        in_specs=(TILE_LINE, TILE_LINE, P(COL_AXIS)),
         out_specs=P(ROW_AXIS),
-    )(csc_indptr, csc_rowidx, sources)
+    )(csc_indptr, csc_rowidx, member)

@@ -965,7 +965,7 @@ class Server:
     def _refresh_companion(self) -> None:
         """On the write lane's own thread, once the buffer is empty: a
         structural merge left the BFS plan's CSC companion marked
-        not-current (level 0 of a batch runs in the loop meanwhile);
+        not-current (a batch sweeps every level meanwhile);
         rebuild it (``GraphEngine.csc_companion``: a host sort and an
         upload outside every lock).  A write stream that never pauses
         never pays for it."""

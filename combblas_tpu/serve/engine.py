@@ -10,7 +10,7 @@ both halves for one graph:
   the transpose (BC on directed graphs) and the row/column degree
   vectors (``coldeg``) — built host-side once at load, uploaded once;
   where BFS is served, the CSC companion (``csc_companion()``): the
-  column structure level 0 of the BFS plan walks from the roots;
+  column structure a thin level of the BFS plan walks its frontier in;
 * a **plan cache** keyed by (query kind, lane width): each plan is one
   jitted program whose trace increments both a host-side counter and
   the ``trace.serve`` obs counter (trace-time side effects count
@@ -155,8 +155,8 @@ class GraphVersion:
     csc_current: bool = True       # False: ``csc`` has the shapes the
     #                                plans were traced with but not this
     #                                version's edges (a structural merge,
-    #                                a snapshot without one): level 0
-    #                                runs in the loop until
+    #                                a snapshot without one): every
+    #                                level is swept until
     #                                ``csc_companion()`` rebuilds it
     coldeg: object = None          # lazy col-degree DistVec cache
     host_coo: tuple | None = None  # retained iff keep_coo=True
@@ -719,8 +719,8 @@ class GraphEngine:
     def csc_companion(self, grow: bool = True):
         """The CSC companion of the served graph
         (``ellmat.build_csc_companion``): the ``(indptr, rowidx)``
-        device pair whose columns level 0 of the BFS plan walks from
-        the batch's roots (``models.bfs._bfs_batch_tallied``).
+        device pair whose columns a thin level of the BFS plan walks
+        from its frontier (``models.bfs._bfs_batch_tallied``).
         ``from_coo`` builds it with the matrices when ``"bfs"`` is
         served, snapshots carry it, and this returns it.
 
@@ -771,7 +771,7 @@ class GraphEngine:
         """``(indptr, rowidx, current)`` for the BFS plan.  A version
         without a companion (built by hand, or restored from a snapshot
         that predates it) gets the smallest stand-in, marked
-        not-current: its batches run level 0 in the loop."""
+        not-current: its batches sweep every level."""
         import jax.numpy as jnp
 
         v = self._version
@@ -846,7 +846,7 @@ class GraphEngine:
         if kind == "bfs":
 
             def impl(E, csc, sources):
-                # (parents, levels, niter, sweep tally, what level 0
+                # (parents, levels, niter, sweep tally, what the pushes
                 # did): the last two are read back only with telemetry
                 # on (``execute``)
                 trace_mark()
@@ -1187,12 +1187,9 @@ class GraphEngine:
                 handle.work = self._count_sweeps(
                     kind, W, res[_TALLY_AT], handle.swept)
             if kind == "bfs":
-                from ..models.bfs import PUSH_OUTCOMES
-
-                obs.count(
-                    "serve.bfs.push",
-                    outcome=PUSH_OUTCOMES[int(counted[-1])],
-                )
+                handle.work = dict(
+                    handle.work or {},
+                    **self._count_levels(W, int(niter), counted[-1]))
             if kind == "sssp":
                 obs.count("serve.sssp.rounds", int(niter), width=W)
                 obs.count("serve.sssp.batches", 1, width=W)
@@ -1209,6 +1206,25 @@ class GraphEngine:
             }
             out["batch_niter"] = int(niter)
             return out
+
+    @staticmethod
+    def _count_levels(width: int, niter: int, report) -> dict:
+        """A BFS batch's ``models.bfs.PushReport`` read back and added to
+        ``serve.bfs.push{outcome}`` (level 0's), ``serve.bfs.levels
+        {mode, width}`` (the batch's levels by how each was run:
+        ``niter`` in all) and ``serve.bfs.push_edges{width}`` (the edges
+        its pushes walked, all tiles).  Returns the same for the batch's
+        stage record."""
+        from ..models.bfs import LEVEL_MODES, PUSH_OUTCOMES
+
+        pushed = int(report.levels)
+        edges = int(np.asarray(report.edges, np.int64).sum())
+        obs.count(
+            "serve.bfs.push", outcome=PUSH_OUTCOMES[int(report.outcome)])
+        for mode, ran in zip(LEVEL_MODES, (pushed, niter - pushed)):
+            obs.count("serve.bfs.levels", ran, mode=mode, width=width)
+        obs.count("serve.bfs.push_edges", edges, width=width)
+        return {"levels": niter, "push_levels": pushed, "push_edges": edges}
 
     @staticmethod
     def _count_sweeps(kind: str, width: int, tally, swept: tuple) -> dict:

@@ -510,8 +510,8 @@ def _load_version(path: str, grid: Grid, writable: bool = True):
             dangling=dangling,
             ET=mats["ET"],
             # a snapshot from before the companion has none: the engine
-            # serves it with a stand-in marked not-current (level 0 in
-            # the loop) until ``csc_companion()`` can rebuild one
+            # serves it with a stand-in marked not-current (every level
+            # swept) until ``csc_companion()`` can rebuild one
             csc=csc,
             csc_current=bool(meta.get("csc_current", csc is not None)),
             host_coo=host_coo,

@@ -79,6 +79,8 @@ os.environ["COMBBLAS_SHARD_WIRE"] = ""
 # exercise tracing call obs.trace.set_sample_rate explicitly.
 os.environ["COMBBLAS_OBS_TRACE_SAMPLE"] = "0"
 
+import contextlib
+
 import jax
 
 jax.config.update("jax_default_matmul_precision", "highest")
@@ -253,6 +255,42 @@ def idle_classes(E, table, mask):
             busy = np.append(busy, False)  # padded bucket rows read this
             out[i, j] = [not busy[br[i, j]].any() for br in rows]
     return out
+
+
+def walked_edges(E, rows, cols, frontier):
+    """``int64[pr, pc]``: the edges each tile of ``E`` (an ``EllParMat``
+    of the COO ``rows`` / ``cols``, entry ``(r, c)`` the edge ``c -> r``)
+    holds in the columns of ``frontier [ncols, W]`` bool, a column in
+    several lanes counted once: what ``ellmat.ell_frontier_fit`` holds
+    against the capacity and ``ell_frontier_push`` walks."""
+    pr, pc = E.grid.pr, E.grid.pc
+    inside = np.asarray(frontier).any(axis=1)[cols]
+    tile = (rows[inside] // E.local_rows) * pc + cols[inside] // E.local_cols
+    return np.bincount(tile, minlength=pr * pc).reshape(pr, pc)
+
+
+def pushed_levels(E, rows, cols, history, capacity: int):
+    """For each iteration of a batched BFS's loop (``history[k]`` =
+    ``(frontier, unvisited)``, ``test_bfs_bits._numpy_bfs``): the edges
+    a push of it walks by tile, or None where the device sweeps it (some
+    tile's frontier columns hold more than ``capacity`` edges)."""
+    walks = [walked_edges(E, rows, cols, f) for f, _ in history]
+    return [w if w.max() <= capacity else None for w in walks]
+
+
+@contextlib.contextmanager
+def push_capacity(capacity: int):
+    """``models.bfs.push_capacity`` made ``capacity`` for every matrix
+    (``PUSH_EDGE_CAPACITY`` set to it, and the share of a small matrix's
+    slots that would undercut it lifted) while a program is TRACED: it
+    is static, a jitted function or a served plan keeps the value it was
+    traced with."""
+    from combblas_tpu.models import bfs as bfs_mod
+
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(bfs_mod, "PUSH_EDGE_CAPACITY", capacity)
+        mp.setattr(bfs_mod, "PUSH_SLOT_SHARE", float(1 << 30))
+        yield
 
 
 def counter_sum(name: str, **labels):

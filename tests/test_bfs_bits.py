@@ -4,8 +4,10 @@ membership bits, ``ellmat.ell_frontier_sweep`` gathers one int32 word a
 column for every 32 lanes and makes the candidate parent from the slot's
 own column id).  Held here, entry for entry, to a plain numpy BFS that
 picks the largest in-frontier in-neighbour id: parents, levels, ``niter``,
-the tally of class sweeps by what each tile's device chose, and what
-level 0 did, with the push and without it."""
+the tally of class sweeps by what each tile's device chose, and which
+levels it walked instead (``ellmat.ell_frontier_push``: those whose
+frontiers' columns fit ``CAPACITY`` edges on every tile), with the
+companion and without it."""
 
 from functools import lru_cache
 
@@ -19,10 +21,14 @@ from combblas_tpu.models import bfs as bfs_mod
 from combblas_tpu.parallel import ellmat
 from combblas_tpu.parallel.grid import Grid
 
-from conftest import idle_classes
+from conftest import idle_classes, push_capacity, pushed_levels
 
 GRIDS = [(1, 1), (2, 2), (2, 4), (4, 2)]
 WIDTHS = [1, 4, 16, 64]  # 64: two membership words a column
+
+#: ``PUSH_EDGE_CAPACITY`` for this module's programs: the roots' level
+#: and a search's thin last levels fit it, the wide ones between do not
+CAPACITY = 40
 
 #: the tie's vertices: root R reaches A (the first column block of every
 #: grid here) and B (the last) at level 1, and V hears from both at level 2
@@ -65,8 +71,13 @@ def _all_pull(E, roots):
 
 
 @jax.jit
-def _push_first(E, csc, roots):
+def _pushing_program(E, csc, roots):
     return bfs_mod._bfs_batch_tallied(E, roots, None, True, csc)
+
+
+def _pushing(E, csc, roots):
+    with push_capacity(CAPACITY):  # read when the program is traced
+        return _pushing_program(E, csc, roots)
 
 
 def _roots(case, name, width):
@@ -144,11 +155,18 @@ def test_a_level_of_bits_is_the_numpy_bfs(shape, width, case, name):
         assert np.all(levels[[7, 90, 150], -1] == 1)  # its own edges
 
     pulled = _all_pull(E, jnp.asarray(roots))
-    pushed = _push_first(E, companion, jnp.asarray(roots))
+    pushed = _pushing(E, companion, jnp.asarray(roots))
     assert pulled[4] is None  # no companion: no push in the program
-    assert int(pushed[4]) == bfs_mod.PUSH_OUTCOMES.index("taken")
-    # a level taken as a push is no iteration of the loop
-    for got, loop in ((pulled, history), (pushed, history[1:])):
+    walks = pushed_levels(E, rows, cols, history, CAPACITY)
+    report = pushed[4]
+    assert bfs_mod.PUSH_OUTCOMES[int(report.outcome)] == (
+        "over_budget" if walks[0] is None else "taken")
+    assert int(report.levels) == sum(w is not None for w in walks)
+    assert np.asarray(report.edges).tolist() == sum(
+        w for w in walks if w is not None).tolist()
+    # a level taken as a push sweeps no degree class
+    swept = [h for h, w in zip(history, walks) if w is None]
+    for got, loop in ((pulled, history), (pushed, swept)):
         np.testing.assert_array_equal(_lanes(got[0], n), parents)
         np.testing.assert_array_equal(_lanes(got[1], n), levels)
         assert int(got[2]) == niter
@@ -157,14 +175,15 @@ def test_a_level_of_bits_is_the_numpy_bfs(shape, width, case, name):
         assert np.all(np.asarray(got[0]).reshape(-1, width)[n:] == -1)
 
 
-def test_a_stale_companion_leaves_level_0_to_the_loop():
+def test_a_stale_companion_leaves_every_level_to_the_sweep():
     rows, cols, n = _graph("ragged")
     E, (indptr, rowidx, _) = _operands("ragged", (2, 2))
     roots = _roots("tie", "ragged", 16)
     parents, levels, niter, history = _numpy_bfs(rows, cols, n, roots)
-    got = _push_first(E, (indptr, rowidx, jnp.asarray(False)),
-                      jnp.asarray(roots))
-    assert int(got[4]) == bfs_mod.PUSH_OUTCOMES.index("stale")
+    got = _pushing(E, (indptr, rowidx, jnp.asarray(False)),
+                   jnp.asarray(roots))
+    assert int(got[4].outcome) == bfs_mod.PUSH_OUTCOMES.index("stale")
+    assert int(got[4].levels) == 0 and not np.any(np.asarray(got[4].edges))
     np.testing.assert_array_equal(_lanes(got[0], n), parents)
     np.testing.assert_array_equal(_lanes(got[1], n), levels)
     assert int(got[2]) == niter

@@ -1,5 +1,5 @@
 """Level 0 of the served BFS plan as a push over the roots' columns
-(``models.bfs._bfs_batch_tallied(csc=...)``, ``ellmat.ell_roots_push``):
+(``models.bfs._bfs_batch_tallied(csc=...)``, ``ellmat.ell_frontier_push``):
 the answer is the all-pull program's bit for bit, whatever the device
 chose, and the CSC companion it walks lives with the graph version
 (built by ``from_coo``, carried by snapshots and merges)."""
@@ -106,15 +106,16 @@ def test_push_first_is_the_all_pull_answer(
     eng, rows, cols = engines(grid, case == "directed", capacity)
     src = _roots(case, width, rows, cols)
     if case != "stale_companion":
-        *got, tally, outcome = eng.plan("bfs", width).fn(jnp.asarray(src))
+        *got, tally, push = eng.plan("bfs", width).fn(jnp.asarray(src))
         want = _all_pull(eng.E, src)
         _same_answer(got, want)
-        assert int(outcome) == (
+        assert int(push.outcome) == (
             OVER_BUDGET if case == "over_budget" else TAKEN)
         # a level taken as a push sweeps no degree class
         tiles, classes = eng.grid.size, len(eng.E.buckets)
-        skipped_level = tiles * classes * (int(outcome) == TAKEN)
-        assert int(np.sum(tally)) + skipped_level == int(np.sum(want[3]))
+        assert int(push.levels) >= (int(push.outcome) == TAKEN)
+        pushed_levels = tiles * classes * int(push.levels)
+        assert int(np.sum(tally)) + pushed_levels == int(np.sum(want[3]))
         return
 
     # a structural merge: the parent's companion rides along for its
@@ -134,10 +135,13 @@ def test_push_first_is_the_all_pull_answer(
     assert child.csc is parent.csc and not child.csc_current
     eng.swap(child)
     try:
-        *got, _, outcome = eng.plan("bfs", width).fn(jnp.asarray(src))
+        *got, tally, push = eng.plan("bfs", width).fn(jnp.asarray(src))
         want = _all_pull(eng.E, src)
         _same_answer(got, want)
-        assert int(outcome) == STALE
+        # a stale companion pushes nothing, at any level
+        assert int(push.outcome) == STALE and int(push.levels) == 0
+        assert not np.any(np.asarray(push.edges))
+        assert np.array_equal(np.asarray(tally), np.asarray(want[3]))
         # the edge is gone from the answer, though the companion has it
         lane0 = np.asarray(got[0])[..., 0].reshape(-1)
         assert lane0[nbr] != root
@@ -145,9 +149,9 @@ def test_push_first_is_the_all_pull_answer(
         eng.csc_companion()
         assert eng.version.csc_current
         assert eng.version.csc[1].shape == parent.csc[1].shape
-        *got, _, outcome = eng.plan("bfs", width).fn(jnp.asarray(src))
+        *got, _, push = eng.plan("bfs", width).fn(jnp.asarray(src))
         _same_answer(got, want)
-        assert int(outcome) == TAKEN
+        assert int(push.outcome) == TAKEN
         assert eng.retraces_since(mark) == 0
     finally:
         eng.swap(parent)  # the module's engine, as the other cases know it
@@ -247,9 +251,10 @@ def test_snapshot_from_before_the_companion_serves_stale(
     served = GraphEngine(eng.grid, version=back, kinds=("bfs",))
     served.warmup(widths=(4,))  # no host COO: nothing to rebuild from
     src = _roots("fresh_roots", 4, rows, cols)
-    *got, _, outcome = served.plan("bfs", 4).fn(jnp.asarray(src))
+    *got, _, push = served.plan("bfs", 4).fn(jnp.asarray(src))
     _same_answer(got, _all_pull(eng.E, src))
-    assert int(outcome) == STALE and not served.version.csc_current
+    assert int(push.outcome) == STALE and int(push.levels) == 0
+    assert not served.version.csc_current
     with pytest.raises(ValueError, match="keep_coo"):
         served.csc_companion()
 
@@ -284,6 +289,6 @@ def test_write_lane_rebuilds_a_stale_companion_when_quiet():
         assert eng.version.csc[1].shape == shape
         out = srv.submit("bfs", a).result(timeout=60)
         assert out["levels"][b] == 1 and out["parents"][b] == a
-        *_, outcome = eng.plan("bfs", 1).fn(jnp.asarray([a], jnp.int32))
-        assert int(outcome) == TAKEN
+        *_, push = eng.plan("bfs", 1).fn(jnp.asarray([a], jnp.int32))
+        assert int(push.outcome) == TAKEN
         assert eng.retraces_since(mark) == 0

@@ -3,7 +3,10 @@ the plan returns how often each tile swept or skipped each degree class,
 ``GraphEngine._collect`` weighs that by the ``class_slots`` of the version
 the batch was launched on into the one counter family
 (``ell.class_sweeps`` / ``ell.slots`` / ``ell.batches``), and the batch's
-``execute`` stage record carries ``slots`` and ``slots_skipped``.  Held
+``execute`` stage record carries ``slots`` and ``slots_skipped``, and,
+since PR 52, ``levels``, ``push_levels`` and ``push_edges``: the levels
+the device walked instead of sweeping (``serve.bfs.levels{mode}``,
+``serve.bfs.push_edges``).  Held
 on one small directed graph
 to a numpy replay of the levels' masks (``test_bfs_bits.py``'s), on one
 tile and on a 2x2 grid, whose busiest tile the slots are; kernel 3's and
@@ -21,8 +24,9 @@ from combblas_tpu.parallel.ellmat import (
 from combblas_tpu.parallel.grid import Grid
 from combblas_tpu.serve import GraphEngine, ServeConfig
 
-from conftest import counter_sum
-from test_bfs_bits import _graph, _numpy_bfs, _numpy_tally, _roots
+from conftest import counter_sum, push_capacity, pushed_levels
+from test_bfs_bits import (
+    CAPACITY, _graph, _numpy_bfs, _numpy_tally, _roots)
 
 WIDTH = 4
 GRIDS = [(1, 1), (2, 2)]
@@ -34,7 +38,8 @@ def engine(request):
     eng = GraphEngine.from_coo(
         Grid.make(*request.param), rows, cols, n, kinds=("bfs",),
         symmetric=False)
-    eng.warmup(kinds=("bfs",), widths=(WIDTH,))
+    with push_capacity(CAPACITY):  # static: read when the plan is traced
+        eng.warmup(kinds=("bfs",), widths=(WIDTH,))
     return eng
 
 
@@ -48,12 +53,21 @@ def _clean_obs():
     obs.trace.set_sample_rate(None)
 
 
-def _replay(engine, roots, graph=None):
-    """The per-tile, per-class tally a numpy BFS of ``roots`` finds for
-    the batch's loop: level 0 is taken as a push, before the loop."""
+def _walks(engine, roots, graph=None, capacity=CAPACITY):
+    """``(history, walks)`` of a numpy BFS of ``roots``: every level of
+    the batch's loop, and the edges by tile of those the device walks
+    (None: a level it sweeps; ``conftest.pushed_levels``)."""
     rows, cols, n = graph or _graph("ragged")
     history = _numpy_bfs(rows, cols, n, roots)[3]
-    return np.asarray(_numpy_tally(engine.E, history[1:]))
+    return history, pushed_levels(engine.E, rows, cols, history, capacity)
+
+
+def _replay(engine, roots, graph=None, capacity=CAPACITY):
+    """The per-tile, per-class tally a numpy BFS of ``roots`` finds for
+    the batch's loop: the levels that are swept, not walked."""
+    history, walks = _walks(engine, roots, graph, capacity)
+    return np.asarray(_numpy_tally(
+        engine.E, [h for h, w in zip(history, walks) if w is None]))
 
 
 def _busiest(tally, slots):
@@ -70,10 +84,11 @@ def test_the_plan_s_tally_is_the_numpy_replay_class_by_class(engine):
     g = engine.grid
     assert tally.shape == (g.pr, g.pc, len(engine.E.buckets), 2)
     assert np.array_equal(tally, _replay(engine, roots))
-    # a choice a level of the loop, tile and class (level 0 was a push,
-    # before the loop); the weights are the served version's
-    assert PUSH_OUTCOMES[int(push)] == "taken"
-    assert (tally.sum(axis=-1) == int(niter) - 1).all()
+    # a choice a swept level, tile and class (the others were walked);
+    # the weights are the served version's
+    assert PUSH_OUTCOMES[int(push.outcome)] == "taken"
+    assert 0 < int(push.levels) < int(niter)
+    assert (tally.sum(axis=-1) == int(niter) - int(push.levels)).all()
     assert engine._swept("bfs") == (({}, class_slots(engine.E)),)
     assert class_slots(engine.E) == tuple(
         bc.shape[2] * bc.shape[3] for bc, _, _ in engine.E.buckets)
@@ -92,7 +107,14 @@ def test_a_batch_adds_its_counts_times_its_version_s_slots(engine):
     by = dict(kind="bfs", width=WIDTH)
     assert obs.registry.get_counter("ell.batches", **by) == 2
     slots = class_slots(engine.E)
-    iters = out["batch_niter"] - 1  # level 0 was a push, before the loop
+    walks = [w for w in _walks(engine, roots)[1] if w is not None]
+    iters = out["batch_niter"] - len(walks)  # the swept levels
+    # levels by how each was run, and the edges the walked ones held
+    for mode, ran in (("push", len(walks)), ("pull", iters)):
+        assert obs.registry.get_counter(
+            "serve.bfs.levels", mode=mode, width=WIDTH) == 2 * ran
+    assert obs.registry.get_counter(
+        "serve.bfs.push_edges", width=WIDTH) == 2 * int(sum(walks).sum())
     for m, mode in enumerate(SWEEP_MODES):
         assert counter_sum("ell.class_sweeps", mode=mode, **by) == (
             2 * want[..., m].sum())
@@ -131,6 +153,7 @@ def test_the_stage_records_carry_their_batch_s_work(engine):
         assert lab["width"] == WIDTH and lab["plan"] == "warm"
         assert "class_sweeps" not in lab
         assert (lab["slots"] + lab["slots_skipped"]) % sum(slots) == 0
+        assert 0 < lab["push_levels"] < lab["levels"]
         execute = [s for s in rec["stages"] if s["stage"] == "execute"][0]
         assert [p["stage"] for p in execute["parts"]][:2] == [
             "launch", "device"]
@@ -150,6 +173,10 @@ def test_the_stage_records_carry_their_batch_s_work(engine):
         want = _replay(engine, np.asarray(lanes, np.int32))
         assert [lab["slots"], lab["slots_skipped"]] == _busiest(
             want, slots).tolist()
+        history, walks = _walks(engine, np.asarray(lanes, np.int32))
+        walks = [w for w in walks if w is not None]
+        assert [lab["levels"], lab["push_levels"], lab["push_edges"]] == [
+            len(history), len(walks), int(sum(walks).sum())]
 
 
 def test_the_busiest_tile_is_the_one_that_gathered_most():
@@ -209,7 +236,8 @@ def test_a_swap_weighs_a_batch_by_the_version_it_was_launched_on():
         if at:
             eng.swap(version)
         obs.reset()
-        eng.execute("bfs", roots)
+        with push_capacity(CAPACITY):  # the first two turns trace
+            eng.execute("bfs", roots)
         want = _replay(eng, roots, graph)
         slots = class_slots(version.E)
         for m, mode in enumerate(SWEEP_MODES):

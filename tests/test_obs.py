@@ -118,7 +118,8 @@ def test_disabled_instrumentation_is_free(rng, monkeypatch):
     not to a clock: it enters no method of ``obs.registry``,
     ``obs._spans`` or ``obs.trace`` (every one raises here), and it reads
     back no output of the plan beyond its result blocks: the sweep tally
-    and the push's outcome stay on the device, as many ``np.asarray`` /
+    and the pushes' report (level 0's outcome, the levels walked and
+    their edges, PR 52) stay on the device, as many ``np.asarray`` /
     ``device_get`` calls as the bare plan call and readback it wraps."""
     import types
 
@@ -161,8 +162,8 @@ def test_disabled_instrumentation_is_free(rng, monkeypatch):
         m.setattr(plan, "fn", recording_fn)
         out = engine.execute("bfs", sources)
     # parents and levels, in the program's order, and nothing after them
-    # (the iteration count is one ``int()``; the tally and the push's
-    # outcome, outputs 3 and 4, were left where they are)
+    # (the iteration count is one ``int()``; the tally and the pushes'
+    # report, outputs 3 and 4, were left where they are)
     (res,) = outputs
     assert len(res) == 5 and [id(x) for x in read] == [
         id(res[0]), id(res[1])]

@@ -315,14 +315,18 @@ def _assert_own_fast_tables(text, loop, E):
         assert "S(1)" in layout and loop in line, (loop, cls, line[:200])
 
 
-def test_push_is_peeled_and_the_loop_keeps_its_fast_tables(
+def test_push_lies_inside_the_loop_and_both_keep_their_fast_tables(
         operands, companion):
-    """Level 0 runs BEFORE the loop under ``bfs.push``; the loop is still
+    """A level's push runs INSIDE the loop under ``bfs.push`` (PR 52;
+    PR 29 peeled level 0 alone, before it); the loop is still
     ``bfs.level/while``, and inside it every class's gather table is
     still built in the branch that gathers from it and placed in the
-    compiler's fast memory (``S(1)`` on its layout).  A table handed
+    compiler's fast memory (``S(1)`` on its layout), though the sweep is
+    now one branch of the level's own ``conditional``.  A table handed
     into a ``conditional`` stays in HBM and gathers three times slower:
-    what made PR 24's first build 17% slower (PERF.md section 6)."""
+    what made PR 24's first build 17% slower (PERF.md section 6).  The
+    push's ``[W n]`` candidates, which every edge slot scatters a word
+    into, are placed too."""
     from combblas_tpu.obs import opnames
 
     E, grid = operands
@@ -331,11 +335,17 @@ def test_push_is_peeled_and_the_loop_keeps_its_fast_tables(
     loops = [nm for i, nm in names.items() if i.startswith("while")]
     assert any(nm.endswith("bfs.level/while") for nm in loops), loops
     seen = set(names.values())
-    assert any("/bfs.push/" in nm and "bfs.level" not in nm for nm in seen)
-    assert not any("bfs.push" in nm and "bfs.level" in nm for nm in seen)
-    for scope in ("ell.reduce", "bfs.update", "vec.realign", "bfs.active"):
+    pushes = [nm for nm in seen if "bfs.push" in nm]
+    assert pushes and all("bfs.level/while/body/" in nm for nm in pushes)
+    for scope in ("push.columns", "push.lay", "push.walk", "push.scatter"):
         assert any(f"/bfs.push/" in nm and f"/{scope}/" in nm
                    for nm in seen), scope
+    # the sweep's scopes are not the push's: a trace tells them apart
+    assert not any("bfs.push" in nm and "ell.bucket" in nm for nm in seen)
+    scatters = re.findall(
+        r"= (s32\[%d\]\S*) scatter\([^\n]*push\.scatter/"
+        % (16 * E.nrows), text)
+    assert scatters and all("S(1)" in layout for layout in scatters)
     _assert_fast_frontier_tables(text, E)
 
 
