@@ -560,6 +560,10 @@ class PushReport(NamedTuple):
     outcome: jax.Array  # int32 scalar, level 0's, into ``PUSH_OUTCOMES``
     levels: jax.Array  # int32 scalar: levels taken as a push
     edges: jax.Array  # int32[pr, pc]: edges each tile's pushes walked
+    #: int32[pr, pc]: passes each tile's pushes made over a trip of
+    #: ``ellmat.PUSH_SLOT_CHUNK`` slots to scatter them, a lane of every
+    #: slot a pass; times the trip over ``edges``, scattered slots an edge
+    passes: jax.Array
 
 
 #: What a served BFS level gathers from (``serve.warmup``'s ``payload``
@@ -691,11 +695,13 @@ def _bfs_batch_tallied(A, sources, max_iters, track_levels, csc=None):
 
             def push(_parents, member):
                 with jax.named_scope("bfs.push"):
-                    y = ell_frontier_push(
+                    y, passes = ell_frontier_push(
                         A, indptr, rowidx, member, W, capacity)
-                return y, jnp.zeros_like(tally)
+                return y, jnp.zeros_like(tally), passes
 
-            y, sweeps = jax.lax.cond(take, push, pull, parents, member)
+            y, sweeps, passes = jax.lax.cond(
+                take, push, lambda *level: (*pull(*level), jnp.zeros_like(
+                    report.passes)), parents, member)
             report = PushReport(
                 outcome=jnp.where(
                     level > 0, report.outcome,
@@ -703,6 +709,7 @@ def _bfs_batch_tallied(A, sources, max_iters, track_levels, csc=None):
                 ).astype(jnp.int32),
                 levels=report.levels + take.astype(jnp.int32),
                 edges=report.edges + jnp.where(take, edges, 0),
+                passes=report.passes + passes,
             )
         return (*advance(parents, levels, level, y), tally + sweeps, report)
 
@@ -712,6 +719,7 @@ def _bfs_batch_tallied(A, sources, max_iters, track_levels, csc=None):
         PushReport(
             outcome=jnp.int32(PUSH_OUTCOMES.index("stale")),
             levels=jnp.int32(0), edges=jnp.zeros((pr_, pc_), jnp.int32),
+            passes=jnp.zeros((pr_, pc_), jnp.int32),
         ) if pushing else None,
     )
     # the whole loop, condition included, is one scope: a level is one

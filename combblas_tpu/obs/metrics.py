@@ -563,6 +563,18 @@ name                                   kind       meaning
                                                   batches walked, all
                                                   tiles (label
                                                   ``width``)
+``serve.bfs.push_passes``              counter    passes the pushed
+                                                  levels made over a
+                                                  trip of ``ellmat.
+                                                  PUSH_SLOT_CHUNK`` edge
+                                                  slots to scatter them,
+                                                  a lane of every slot
+                                                  a pass, all tiles
+                                                  (label ``width``);
+                                                  times the trip over
+                                                  ``push_edges``:
+                                                  scattered slots an
+                                                  edge
 ``serve.bfs.companion_rebuilds``       counter    rebuilds of a stale
                                                   CSC companion by the
                                                   write lane, once its

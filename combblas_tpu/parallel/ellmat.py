@@ -1492,31 +1492,50 @@ def ell_frontier_push(E: EllParMat, csc_indptr, csc_rowidx, member,
                       width: int, capacity: int):
     """``E (x) X`` under ``SELECT2ND_MAX`` for the ``X`` a level of the
     batched BFS holds, its ``width`` frontiers as membership bits
-    (``member [pc, lc, nw]``, ``pack_lanes``): ``[pr, lr, width]`` int32
-    row-aligned blocks, for every (row, lane) the largest id among the
-    row's in-neighbours in the lane's frontier, -1 where there is none:
-    what ``ell_frontier_sweep`` gives on every row its mask keeps, by
-    the same max, so every tie falls the same way.  The sweep finds
-    those rows by gathering every slot of the matrix; this walks the
-    frontier's columns in the CSC companion (``build_csc_companion``,
-    both arrays as ``tile_lines``) and scatters.  The caller has tested
-    ``ell_frontier_fit``: at most ``capacity`` edges a tile.
+    (``member [pc, lc, nw]``, ``pack_lanes``): ``(y, passes)``, ``y
+    [pr, lr, width]`` int32 row-aligned blocks, for every (row, lane)
+    the largest id among the row's in-neighbours in the lane's frontier,
+    -1 where there is none: what ``ell_frontier_sweep`` gives on every
+    row its mask keeps, by the same max, so every tie falls the same
+    way; and ``passes`` ``int32[pr, pc]``, the scatter passes each tile
+    ran (below).  The sweep finds those rows by gathering every slot of
+    the matrix; this walks the frontier's columns in the CSC companion
+    (``build_csc_companion``, both arrays as ``tile_lines``) and
+    scatters.  The caller has tested ``ell_frontier_fit``: at most
+    ``capacity`` edges a tile.
 
     What it costs follows the frontier, not the matrix and not
-    ``capacity``.  One sort of a tile's ``lc`` column ids brings the
-    frontier's to the front, ascending.  A first loop, a trip every
+    ``capacity``.  One sort of a tile's ``lc`` columns brings the
+    frontier's to the front, BY HOW MANY LANES HOLD EACH first, the most
+    first, and by id second (the key is ``(width - lanes) * stride +
+    id``, so the id comes back under a mask).  A first loop, a trip every
     ``PUSH_COLUMN_CHUNK`` of them, lays each column's run of edge slots
-    out: because the columns ascend, a slot's place in ``rowidx``, its
-    column and its lanes are running maxima and sums of what each
-    column writes at its first slot (``expand_ranges``' trick), so no
-    slot gathers from a per-column table.  A second loop, a trip every
-    ``PUSH_SLOT_CHUNK`` slots in use, runs those maxima and sums over
-    its slots, fetches each slot's row from
-    ``rowidx`` and folds the column's id into that row of each of the
-    column's lanes, a word a slot and lane (``walk``).  Pad
-    lanes hold no column; a directed matrix walks out-edges (the
-    companion comes from the COO); an edge stored twice folds twice to
-    the same."""
+    out: a slot's place in ``rowidx``, its column's key and its lanes
+    are running sums and maxima of what each column writes at its first
+    slot (``expand_ranges``' trick), so no slot gathers from a
+    per-column table; the keys ascend over the whole list and run under
+    a max, the place and the lanes rise and fall with the ids and are
+    written as differences and summed.  A second loop, a trip every
+    ``PUSH_SLOT_CHUNK`` slots in use, runs those sums and maxima over
+    its slots, fetches each slot's row from ``rowidx`` and folds the
+    column's id into that row of each of the column's lanes, a word a
+    slot and lane, one lane of every slot a pass (``walk``): a trip
+    costs its ``PUSH_SLOT_CHUNK`` slots times the most lanes any ONE of
+    them holds.  Hence the order.  In id order, on a road-like graph
+    whose 16 searches' rings cross here and there, 2% of a level's slots
+    sit in a column two lanes share and 95% of its trips held such a
+    slot, so nearly every trip ran a second pass for some 300 slots of
+    its 16,384 (host count, ISSUE 53); ordered by lane count a trip
+    holds one count (two at a boundary between groups) and runs that
+    many passes.  No order runs fewer: whatever the order, the slots of
+    ``k`` lanes or more, ``S_k`` of them, lie in ``ceil(S_k / trip)``
+    trips at least, each of which runs a ``k``-th pass; with the most
+    lanes first those slots are the list's first ``S_k``, and lie in
+    exactly that many (with the fewest first they end in the last,
+    partly empty trip, and may take one more).  The fold is a max, so
+    the order of slots changes no answer.  Pad lanes hold no column; a
+    directed matrix walks out-edges (the companion comes from the COO);
+    an edge stored twice folds twice to the same."""
     lr, lc = E.local_rows, E.local_cols
     # (a tile holds no more edges than its companion has slots; columns
     # without an edge, a lane's isolated root, are columns all the same)
@@ -1525,20 +1544,27 @@ def ell_frontier_push(E: EllParMat, csc_indptr, csc_rowidx, member,
     ktrip = min(PUSH_SLOT_CHUNK, nslots)
     nslots = -(-nslots // ktrip) * ktrip  # whole trips: no slice is clamped
     assert int(width) * lr < 1 << 31  # a (lane, row) is one int32
+    # a column's sort key: the lanes that do NOT hold it above
+    # ``stride`` (the most shared first), its id (``lc``: no column) below
+    stride = 1 << lc.bit_length()
+    nokey = int(width) * stride + lc  # above every column's, its id ``lc``
+    assert (int(width) + 1) * stride < 1 << 31
 
     def body(indptr, rowid, mblk):
         member = mblk[0]
         nw, W = member.shape[1], int(width)
         base = lax.axis_index(COL_AXIS) * lc
         with jax.named_scope("push.columns"):
-            inside = _frontier_any(member) != 0
-            count = jnp.sum(inside, dtype=jnp.int32)
+            held = sum(lax.population_count(member[:, w]) for w in range(nw))
+            count = jnp.sum(held != 0, dtype=jnp.int32)
             ids = jnp.arange(lc, dtype=jnp.int32)
-            # (not stable: the ids differ, and the sentinels are alike)
-            fcols = lax.sort(jnp.where(inside, ids, lc), is_stable=False)
-            fcols = jnp.concatenate([
-                fcols[:ncols],
-                jnp.full((max(ncols - lc, 0),), lc, jnp.int32),
+            # (not stable: the keys differ, and the sentinels are alike)
+            fkeys = lax.sort(
+                jnp.where(held != 0, (W - held) * stride + ids, nokey),
+                is_stable=False)
+            fkeys = jnp.concatenate([
+                fkeys[:ncols],
+                jnp.full((max(ncols - lc, 0),), nokey, jnp.int32),
             ])
             # a column's lanes, one table a word (one-dimensional each,
             # so that the layout pads nothing), the empty word for no
@@ -1554,52 +1580,57 @@ def ell_frontier_push(E: EllParMat, csc_indptr, csc_rowidx, member,
         def lay(t, carry):
             """Columns ``[t, t + 1) * PUSH_COLUMN_CHUNK`` of the
             frontier write, at their first slot, what every slot of
-            theirs reads after the running maxima / sums."""
-            total, prev, place, col, lanes = carry
-            cs = lax.dynamic_slice(
-                fcols, (t * PUSH_COLUMN_CHUNK,), (PUSH_COLUMN_CHUNK,))
+            theirs reads after the running sums / maxima."""
+            total, prev, place, key, lanes = carry
+            ks = lax.dynamic_slice(
+                fkeys, (t * PUSH_COLUMN_CHUNK,), (PUSH_COLUMN_CHUNK,))
+            cs = ks & (stride - 1)
             start, deg = _column_ranges(indptr, cs)
             first = total + jnp.cumsum(deg) - deg
-            # ascending columns: ``start - first`` never falls
-            place = place.at[first].max(start - first, mode="drop")
-            col = col.at[first].max(cs, mode="drop")
-            words = tuple(m[cs] for m in mpad)
-            lanes = tuple(
-                ln.at[first].add(
-                    wd - jnp.concatenate([pv[None], wd[:-1]]), mode="drop")
-                for ln, wd, pv in zip(lanes, words, prev))
-            return (total + jnp.sum(deg), tuple(wd[-1] for wd in words),
-                    place, col, lanes)
+            # the keys ascend over the whole list, group after group ...
+            key = key.at[first].max(ks, mode="drop")
+            # ... the ids only inside a group: what a column's slots add
+            # to their own number to find their place, and its lanes,
+            # are written as the step from the column before (columns
+            # without an edge share a first slot, and their steps add up)
+            steps = (start - first, *(m[cs] for m in mpad))
+            place, *lanes = (
+                a.at[first].add(
+                    v - jnp.concatenate([pv[None], v[:-1]]), mode="drop")
+                for a, v, pv in zip((place, *lanes), steps, prev))
+            return (total + jnp.sum(deg), tuple(v[-1] for v in steps),
+                    place, key, tuple(lanes))
 
         with jax.named_scope("push.lay"):
-            total, _, place, col, lanes = lax.fori_loop(
+            total, _, place, key, lanes = lax.fori_loop(
                 0, -(-count // PUSH_COLUMN_CHUNK), lay,
                 jax.tree.map(_tile_varying, (
-                    jnp.int32(0), (jnp.int32(0),) * nw,
+                    jnp.int32(0), (jnp.int32(0),) * (1 + nw),
                     jnp.zeros((nslots,), jnp.int32),
                     jnp.zeros((nslots,), jnp.int32),
                     (jnp.zeros((nslots,), jnp.int32),) * nw,
                 )))
 
         def walk(k, carry):
-            """Edge slots ``[k, k + 1) * ktrip``.  The running maxima
-            and sums that fill each column's run go on from the trip
+            """Edge slots ``[k, k + 1) * ktrip``.  The running sums and
+            maxima that fill each column's run go on from the trip
             before (so they pass over the slots in use, not over the
             capacity); then each slot's row, and its column's id folded
             into ``y [W * lr]`` (a plane a lane) at that row of each of
             its lanes, one lane of every slot a pass: the slot's lowest
-            set bit, until no slot has one left.  A scatter of single
-            words costs this chip 12 ns a slot and a scatter of
-            ``[ktrip, W]`` rows 62-104 (my chip runs, PR 52), and the
-            lanes of a thin frontier rarely share a column: one pass,
-            where they do not."""
-            y, place0, col0, lanes0 = carry
+            set bit, until no slot has one left.  A pass costs this
+            chip 8.3 ns a slot in range and 10.3 a slot with no lane
+            left (my chip run, PR 53; a scatter of ``[ktrip, W]`` rows
+            62-104, PR 52): the trip's slots hold one lane count, so no
+            pass is made for a few of them (``passes`` counts them)."""
+            y, passes, place0, key0, lanes0 = carry
             at = k * ktrip
             cut = lambda a: lax.dynamic_slice_in_dim(a, at, ktrip)
             with jax.named_scope("push.walk"):
                 slot = at + jnp.arange(ktrip, dtype=jnp.int32)
-                run = jnp.maximum(lax.cummax(cut(place)), place0)
-                ids = jnp.maximum(lax.cummax(cut(col)), col0)
+                run = jnp.cumsum(cut(place)) + place0
+                keys = jnp.maximum(lax.cummax(cut(key)), key0)
+                ids = keys & (stride - 1)
                 words = tuple(
                     jnp.cumsum(cut(ln)) + l0 for ln, l0 in zip(lanes, lanes0))
                 tgt = jnp.where(
@@ -1609,29 +1640,30 @@ def ell_frontier_push(E: EllParMat, csc_indptr, csc_rowidx, member,
                 for w, word in enumerate(words):
 
                     def fold(state):
-                        y, left = state
+                        y, passes, left = state
                         low = left & -left  # the lowest lane still to go
                         lane = w * WORD_LANES + lax.population_count(low - 1)
                         y = y.at[jnp.where(low != 0, lane * lr + tgt, W * lr)
                                  ].max(ids + base, mode="drop")
-                        return y, left ^ low
+                        return y, passes + 1, left ^ low
 
-                    y, _ = lax.while_loop(
-                        lambda state: jnp.any(state[1] != 0), fold,
-                        (y, jnp.where(tgt < lr, word, 0)))
-            return y, run[-1], ids[-1], tuple(wd[-1] for wd in words)
+                    y, passes, _ = lax.while_loop(
+                        lambda state: jnp.any(state[2] != 0), fold,
+                        (y, passes, jnp.where(tgt < lr, word, 0)))
+            return (y, passes, run[-1], keys[-1],
+                    tuple(wd[-1] for wd in words))
 
-        y, *_ = lax.fori_loop(
+        y, passes, *_ = lax.fori_loop(
             0, -(-total // ktrip), walk, jax.tree.map(_tile_varying, (
                 jnp.full((W * lr,), -1, jnp.int32), jnp.int32(0),
-                jnp.int32(0), (jnp.int32(0),) * nw)))
+                jnp.int32(0), jnp.int32(0), (jnp.int32(0),) * nw)))
         y = y.reshape(W, lr).T
         with jax.named_scope("ell.reduce"):
-            return lax.pmax(y, COL_AXIS)[None]
+            return lax.pmax(y, COL_AXIS)[None], passes[None, None]
 
     return jax.shard_map(
         body,
         mesh=E.grid.mesh,
         in_specs=(TILE_LINE, TILE_LINE, P(COL_AXIS)),
-        out_specs=P(ROW_AXIS),
+        out_specs=(P(ROW_AXIS), TILE_SPEC),
     )(csc_indptr, csc_rowidx, member)

@@ -1212,19 +1212,24 @@ class GraphEngine:
         """A BFS batch's ``models.bfs.PushReport`` read back and added to
         ``serve.bfs.push{outcome}`` (level 0's), ``serve.bfs.levels
         {mode, width}`` (the batch's levels by how each was run:
-        ``niter`` in all) and ``serve.bfs.push_edges{width}`` (the edges
-        its pushes walked, all tiles).  Returns the same for the batch's
-        stage record."""
+        ``niter`` in all), ``serve.bfs.push_edges{width}`` (the edges
+        its pushes walked, all tiles) and ``serve.bfs.push_passes{width}``
+        (the passes over a trip of ``ellmat.PUSH_SLOT_CHUNK`` slots that
+        scattered them).  Returns the same for the batch's stage
+        record."""
         from ..models.bfs import LEVEL_MODES, PUSH_OUTCOMES
 
         pushed = int(report.levels)
-        edges = int(np.asarray(report.edges, np.int64).sum())
+        edges, passes = (int(np.asarray(tiles, np.int64).sum())
+                         for tiles in (report.edges, report.passes))
         obs.count(
             "serve.bfs.push", outcome=PUSH_OUTCOMES[int(report.outcome)])
         for mode, ran in zip(LEVEL_MODES, (pushed, niter - pushed)):
             obs.count("serve.bfs.levels", ran, mode=mode, width=width)
         obs.count("serve.bfs.push_edges", edges, width=width)
-        return {"levels": niter, "push_levels": pushed, "push_edges": edges}
+        obs.count("serve.bfs.push_passes", passes, width=width)
+        return {"levels": niter, "push_levels": pushed, "push_edges": edges,
+                "push_passes": passes}
 
     @staticmethod
     def _count_sweeps(kind: str, width: int, tally, swept: tuple) -> dict:

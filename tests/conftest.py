@@ -269,6 +269,48 @@ def walked_edges(E, rows, cols, frontier):
     return np.bincount(tile, minlength=pr * pc).reshape(pr, pc)
 
 
+def walked_passes(E, rows, cols, frontier, trip: int, by_id: bool = False):
+    """``int64[pr, pc]``: the scatter passes ``ell_frontier_push`` makes
+    on each tile of ``E`` to walk ``frontier [ncols, W]`` bool.  A
+    tile's frontier columns go by how many lanes hold them first, the
+    most first, and by id second (``by_id``: by id alone, the order
+    before PR 53), their edge slots end to end are cut into trips of
+    ``trip`` (``push_trip``), and a trip takes a pass for every lane of
+    the slot that holds the most, membership word by word
+    (``ellmat.WORD_LANES`` lanes a word)."""
+    from combblas_tpu.parallel.ellmat import WORD_LANES
+
+    pr, pc, lr, lc = E.grid.pr, E.grid.pc, E.local_rows, E.local_cols
+    frontier = np.asarray(frontier, bool)
+    held = frontier.sum(axis=1)
+    words = [frontier[:, w:w + WORD_LANES].sum(axis=1)
+             for w in range(0, frontier.shape[1], WORD_LANES)]
+    ids = np.flatnonzero(held)
+    if not by_id:
+        ids = ids[np.argsort(-held[ids], kind="stable")]
+    out = np.zeros((pr, pc), np.int64)
+    for i in range(pr):
+        deg = np.bincount(cols[rows // lr == i], minlength=len(held))
+        for j in range(pc):
+            mine = ids[ids // lc == j]
+            for word in words:
+                lanes = np.repeat(word[mine], deg[mine])
+                out[i, j] += sum(
+                    int(lanes[at:at + trip].max())
+                    for at in range(0, len(lanes), trip))
+    return out
+
+
+def push_trip(csc, capacity: int) -> int:
+    """The slots a trip of the walk's second loop holds, for
+    ``walked_passes``: ``ellmat.PUSH_SLOT_CHUNK``, or all the slots a
+    tile's walk has (the ``capacity``, the companion ``csc``'s length)
+    where those are fewer."""
+    from combblas_tpu.parallel.ellmat import PUSH_SLOT_CHUNK
+
+    return min(PUSH_SLOT_CHUNK, int(capacity), int(csc[1].shape[-1]))
+
+
 def pushed_levels(E, rows, cols, history, capacity: int):
     """For each iteration of a batched BFS's loop (``history[k]`` =
     ``(frontier, unvisited)``, ``test_bfs_bits._numpy_bfs``): the edges

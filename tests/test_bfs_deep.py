@@ -25,7 +25,8 @@ from combblas_tpu.models import bfs as bfs_mod  # noqa: E402
 from combblas_tpu.parallel.grid import Grid  # noqa: E402
 from combblas_tpu.serve import GraphEngine, ServeConfig  # noqa: E402
 
-from conftest import push_capacity, walked_edges  # noqa: E402
+from conftest import (  # noqa: E402
+    push_capacity, push_trip, walked_edges, walked_passes)
 
 GRIDS = {"1x1": (1, 1), "2x2": (2, 2)}
 
@@ -189,8 +190,9 @@ def test_a_level_at_the_capacity_is_walked_and_one_edge_over_it_is_swept(
 
 def test_levels_and_edges_are_counted_where_the_sweeps_are(served):
     """Telemetry on: a batch's levels by mode add up to its ``niter``,
-    the edges its pushes walked are the host's count, and its ``execute``
-    stage record carries the three; telemetry off nothing of it is read
+    the edges its pushes walked and the passes that scattered them are
+    the host's count, and its ``execute`` stage record carries the four;
+    telemetry off nothing of it is read
     (``test_obs.py::test_disabled_instrumentation_is_free``)."""
     eng, ref = served("rgg", "2x2")
     roots = graph.draw_roots(ref.deg, 5, 16)
@@ -206,9 +208,14 @@ def test_levels_and_edges_are_counted_where_the_sweeps_are(served):
         niter = out["batch_niter"]
         walks = [walked_edges(eng.E, rows, cols, out["levels"] == k)
                  for k in range(niter)]
-        walks = [w for w in walks
-                 if w.max() <= bfs_mod.push_capacity(eng.E)]
+        capacity = bfs_mod.push_capacity(eng.E)
+        fit = [k for k, w in enumerate(walks) if w.max() <= capacity]
+        walks = [walks[k] for k in fit]
         walked = int(sum(walks).sum())
+        trip = push_trip(eng.version.csc, capacity)
+        passes = int(sum(walked_passes(
+            eng.E, rows, cols, out["levels"] == k, trip).sum() for k in fit))
+        assert passes >= len(walks)  # a walked level: a pass at least
         by = dict(width=16)
         get = obs.registry.get_counter
         pushed = len(walks)
@@ -216,6 +223,7 @@ def test_levels_and_edges_are_counted_where_the_sweeps_are(served):
         assert get("serve.bfs.levels", mode="push", **by) == pushed
         assert get("serve.bfs.levels", mode="pull", **by) == niter - pushed
         assert get("serve.bfs.push_edges", **by) == walked
+        assert get("serve.bfs.push_passes", **by) == passes
         assert get("serve.bfs.push", outcome="taken") == 1
         srv = eng.serve(ServeConfig(lane_widths=(16,)))
         futures = [srv.submit("bfs", int(r)) for r in roots]
@@ -224,8 +232,8 @@ def test_levels_and_edges_are_counted_where_the_sweeps_are(served):
         labels = [rec["labels"] for rec in obs.trace.records()]
         assert len(labels) == 16
         for lab in labels:
-            assert [lab["levels"], lab["push_levels"], lab["push_edges"]] == [
-                niter, pushed, walked]
+            assert [lab["levels"], lab["push_levels"], lab["push_edges"],
+                    lab["push_passes"]] == [niter, pushed, walked, passes]
     finally:
         obs.disable()
         obs.reset()

@@ -109,8 +109,13 @@ def test_push_first_is_the_all_pull_answer(
         *got, tally, push = eng.plan("bfs", width).fn(jnp.asarray(src))
         want = _all_pull(eng.E, src)
         _same_answer(got, want)
+        assert want[4] is None  # a program without a push reports nothing
         assert int(push.outcome) == (
             OVER_BUDGET if case == "over_budget" else TAKEN)
+        # a walk makes a pass at least; no walk, none
+        assert push.passes.shape == (eng.grid.pr, eng.grid.pc)
+        assert bool(np.any(np.asarray(push.passes))) == bool(
+            np.any(np.asarray(push.edges)))
         # a level taken as a push sweeps no degree class
         tiles, classes = eng.grid.size, len(eng.E.buckets)
         assert int(push.levels) >= (int(push.outcome) == TAKEN)
@@ -141,6 +146,7 @@ def test_push_first_is_the_all_pull_answer(
         # a stale companion pushes nothing, at any level
         assert int(push.outcome) == STALE and int(push.levels) == 0
         assert not np.any(np.asarray(push.edges))
+        assert not np.any(np.asarray(push.passes))
         assert np.array_equal(np.asarray(tally), np.asarray(want[3]))
         # the edge is gone from the answer, though the companion has it
         lane0 = np.asarray(got[0])[..., 0].reshape(-1)
@@ -254,6 +260,7 @@ def test_snapshot_from_before_the_companion_serves_stale(
     *got, _, push = served.plan("bfs", 4).fn(jnp.asarray(src))
     _same_answer(got, _all_pull(eng.E, src))
     assert int(push.outcome) == STALE and int(push.levels) == 0
+    assert not np.any(np.asarray(push.passes))
     assert not served.version.csc_current
     with pytest.raises(ValueError, match="keep_coo"):
         served.csc_companion()

@@ -4,10 +4,10 @@ the plan returns how often each tile swept or skipped each degree class,
 the batch was launched on into the one counter family
 (``ell.class_sweeps`` / ``ell.slots`` / ``ell.batches``), and the batch's
 ``execute`` stage record carries ``slots`` and ``slots_skipped``, and,
-since PR 52, ``levels``, ``push_levels`` and ``push_edges``: the levels
-the device walked instead of sweeping (``serve.bfs.levels{mode}``,
-``serve.bfs.push_edges``).  Held
-on one small directed graph
+since PR 52, ``levels``, ``push_levels``, ``push_edges`` and (PR 53)
+``push_passes``: the levels the device walked instead of sweeping
+(``serve.bfs.levels{mode}``, ``serve.bfs.push_edges``,
+``serve.bfs.push_passes``).  Held on one small directed graph
 to a numpy replay of the levels' masks (``test_bfs_bits.py``'s), on one
 tile and on a 2x2 grid, whose busiest tile the slots are; kernel 3's and
 BC's loops are held to theirs in ``test_sssp_floor_mask.py`` and
@@ -24,7 +24,8 @@ from combblas_tpu.parallel.ellmat import (
 from combblas_tpu.parallel.grid import Grid
 from combblas_tpu.serve import GraphEngine, ServeConfig
 
-from conftest import counter_sum, push_capacity, pushed_levels
+from conftest import (
+    counter_sum, push_capacity, push_trip, pushed_levels, walked_passes)
 from test_bfs_bits import (
     CAPACITY, _graph, _numpy_bfs, _numpy_tally, _roots)
 
@@ -60,6 +61,17 @@ def _walks(engine, roots, graph=None, capacity=CAPACITY):
     rows, cols, n = graph or _graph("ragged")
     history = _numpy_bfs(rows, cols, n, roots)[3]
     return history, pushed_levels(engine.E, rows, cols, history, capacity)
+
+
+def _passes(engine, roots, graph=None, capacity=CAPACITY):
+    """The scatter passes of the levels of ``_walks`` the device walks,
+    all tiles (``conftest.walked_passes``)."""
+    rows, cols, n = graph or _graph("ragged")
+    history, walks = _walks(engine, roots, graph, capacity)
+    trip = push_trip(engine.version.csc, capacity)
+    return int(sum(
+        walked_passes(engine.E, rows, cols, frontier, trip).sum()
+        for (frontier, _), w in zip(history, walks) if w is not None))
 
 
 def _replay(engine, roots, graph=None, capacity=CAPACITY):
@@ -115,6 +127,8 @@ def test_a_batch_adds_its_counts_times_its_version_s_slots(engine):
             "serve.bfs.levels", mode=mode, width=WIDTH) == 2 * ran
     assert obs.registry.get_counter(
         "serve.bfs.push_edges", width=WIDTH) == 2 * int(sum(walks).sum())
+    assert obs.registry.get_counter(
+        "serve.bfs.push_passes", width=WIDTH) == 2 * _passes(engine, roots)
     for m, mode in enumerate(SWEEP_MODES):
         assert counter_sum("ell.class_sweeps", mode=mode, **by) == (
             2 * want[..., m].sum())
@@ -175,8 +189,10 @@ def test_the_stage_records_carry_their_batch_s_work(engine):
             want, slots).tolist()
         history, walks = _walks(engine, np.asarray(lanes, np.int32))
         walks = [w for w in walks if w is not None]
-        assert [lab["levels"], lab["push_levels"], lab["push_edges"]] == [
-            len(history), len(walks), int(sum(walks).sum())]
+        assert [lab["levels"], lab["push_levels"], lab["push_edges"],
+                lab["push_passes"]] == [
+            len(history), len(walks), int(sum(walks).sum()),
+            _passes(engine, np.asarray(lanes, np.int32))]
 
 
 def test_the_busiest_tile_is_the_one_that_gathered_most():
