@@ -624,6 +624,48 @@ name                                   kind       meaning
                                                   count, the one that
                                                   changed nothing
                                                   included)
+``models.mcm.jobs``                    counter    matching jobs run
+                                                  through ``models/
+                                                  matching.py:mcm_job``
+``models.mcm.init_rounds``             counter    Karp-Sipser rounds of
+                                                  those jobs (the
+                                                  program's own count,
+                                                  the rounds that match
+                                                  nothing included)
+``models.mcm.init_matched``            counter    pairs the maximal
+                                                  matching held when
+                                                  the phases began
+``models.mcm.phases``                  counter    augmenting phases
+                                                  (the one that
+                                                  augments nothing
+                                                  included)
+``models.mcm.augmented``               counter    paths the phases
+                                                  augmented: the
+                                                  cardinality less
+                                                  ``init_matched``
+``models.mcm.init_steps``              counter    a round's two steps
+                                                  (its proposals, its
+                                                  free degrees) by how
+                                                  the device took them
+                                                  (label ``mode`` =
+                                                  push: a walk of the
+                                                  live lists / pull: a
+                                                  class sweep of the
+                                                  matrix); a
+                                                  ``BipartiteEll`` job
+``models.mcm.layers``                  counter    a phase's alternating
+                                                  layers, likewise
+                                                  (label ``mode``)
+``models.mcm.push_edges``              counter    edges the walked
+                                                  steps and layers
+                                                  held, the busiest
+                                                  tile's
+``models.mcm.host_turns``              counter    launches the host
+                                                  waited on: 1 a
+                                                  ``BipartiteEll`` job,
+                                                  a round's and a
+                                                  phase's scalar each
+                                                  over an ``SpParMat``
 ``ell.class_sweeps``                   counter    ELL sweep work, one
                                                   family for every loop
                                                   that runs the class
@@ -640,7 +682,11 @@ name                                   kind       meaning
                                                   both loops; cc: a
                                                   FastSV job's swept
                                                   rounds, which never
-                                                  skip; ``width``;
+                                                  skip; mcm: a matching
+                                                  job's swept steps and
+                                                  layers, with ``way``
+                                                  = A / AT, the matrix
+                                                  swept; ``width``;
                                                   ``cls``: the degree
                                                   class; ``mode`` =
                                                   dense / skipped: no

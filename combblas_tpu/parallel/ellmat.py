@@ -1488,8 +1488,20 @@ def ell_frontier_fit(E: EllParMat, coldeg, member, capacity: int):
     return jnp.all(fits), edges
 
 
+#: What ``ell_frontier_push`` folds into a (row, lane) for each edge that
+#: enters it from a frontier column: ``(empty, scatter, across tiles)``.
+#: ``max``: the column's global id, -1 where no edge enters (a BFS
+#: level's candidates, a matching's parents and grants); ``count``: one
+#: an edge (how many of a row's columns are in the frontier: what a
+#: Karp-Sipser round takes off the free degrees).
+PUSH_FOLDS = {
+    "max": (-1, "max", lax.pmax),
+    "count": (0, "add", lax.psum),
+}
+
+
 def ell_frontier_push(E: EllParMat, csc_indptr, csc_rowidx, member,
-                      width: int, capacity: int):
+                      width: int, capacity: int, fold: str = "max"):
     """``E (x) X`` under ``SELECT2ND_MAX`` for the ``X`` a level of the
     batched BFS holds, its ``width`` frontiers as membership bits
     (``member [pc, lc, nw]``, ``pack_lanes``): ``(y, passes)``, ``y
@@ -1535,7 +1547,12 @@ def ell_frontier_push(E: EllParMat, csc_indptr, csc_rowidx, member,
     partly empty trip, and may take one more).  The fold is a max, so
     the order of slots changes no answer.  Pad lanes hold no column; a
     directed matrix walks out-edges (the companion comes from the COO);
-    an edge stored twice folds twice to the same."""
+    an edge stored twice folds twice to the same.
+
+    ``fold`` (``PUSH_FOLDS``) is what a slot is worth to its row: under
+    ``"count"`` ``y`` is how many of the row's in-neighbours the lane's
+    frontier holds, 0 where none (a sum commutes like the max; an edge
+    stored twice counts twice).  The walk is the same walk."""
     lr, lc = E.local_rows, E.local_cols
     # (a tile holds no more edges than its companion has slots; columns
     # without an edge, a lane's isolated root, are columns all the same)
@@ -1549,6 +1566,7 @@ def ell_frontier_push(E: EllParMat, csc_indptr, csc_rowidx, member,
     stride = 1 << lc.bit_length()
     nokey = int(width) * stride + lc  # above every column's, its id ``lc``
     assert (int(width) + 1) * stride < 1 << 31
+    empty, scatter, across = PUSH_FOLDS[fold]
 
     def body(indptr, rowid, mblk):
         member = mblk[0]
@@ -1643,8 +1661,11 @@ def ell_frontier_push(E: EllParMat, csc_indptr, csc_rowidx, member,
                         y, passes, left = state
                         low = left & -left  # the lowest lane still to go
                         lane = w * WORD_LANES + lax.population_count(low - 1)
-                        y = y.at[jnp.where(low != 0, lane * lr + tgt, W * lr)
-                                 ].max(ids + base, mode="drop")
+                        y = getattr(
+                            y.at[jnp.where(low != 0, lane * lr + tgt, W * lr)],
+                            scatter)(
+                                ids + base if scatter == "max" else 1,
+                                mode="drop")
                         return y, passes + 1, left ^ low
 
                     y, passes, _ = lax.while_loop(
@@ -1655,11 +1676,11 @@ def ell_frontier_push(E: EllParMat, csc_indptr, csc_rowidx, member,
 
         y, passes, *_ = lax.fori_loop(
             0, -(-total // ktrip), walk, jax.tree.map(_tile_varying, (
-                jnp.full((W * lr,), -1, jnp.int32), jnp.int32(0),
+                jnp.full((W * lr,), empty, jnp.int32), jnp.int32(0),
                 jnp.int32(0), jnp.int32(0), (jnp.int32(0),) * nw)))
         y = y.reshape(W, lr).T
         with jax.named_scope("ell.reduce"):
-            return lax.pmax(y, COL_AXIS)[None], passes[None, None]
+            return across(y, COL_AXIS)[None], passes[None, None]
 
     return jax.shard_map(
         body,
